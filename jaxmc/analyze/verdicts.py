@@ -3,7 +3,7 @@ r"""Per-arm compilability prediction (ISSUE 9 tentpole, consumer 2).
 `kernel2.compile_action2` discovers an arm's uncompilability at forced-
 trace time — after grounding and (for recursive operators) after an
 exponentially expensive unroll attempt.  This module recasts the
-CompileError taxonomy as a syntactic/type scan over the arm's AST so
+CompileError classification as a syntactic/type scan over the arm's AST so
 `tpu/bfs.py` can skip the doomed build outright, generalizing the corpus
 manifest's measured `pin_interp_arms` pins to derived ones.
 
@@ -224,7 +224,7 @@ class _ArmScan:
         if isinstance(e, A.Except):
             return self.fatal(e.fn, stack, local)
         if isinstance(e, A.Quant):
-            # ISSUE 15 taxonomy: a quantifier whose binder has NO
+            # ISSUE 15 classification: a quantifier whose binder has NO
             # domain, or whose domain is an infinite constant set,
             # is certain to raise at trace time (kernel2's
             # _binder_combos / set_elements) — the predictor names it

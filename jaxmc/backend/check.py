@@ -76,7 +76,7 @@ def _run_leg(plat: str, out_dir: str, timeout_s: float) -> dict:
 
 
 #: one-shot cold-start walls excluded from the per-backend phase gate:
-#: they time XLA compiles and plugin init, which swing with box load in
+#: they time XLA compiles and device init, which swing with box load in
 #: a way the measured search window does not (the meshbench legs avoid
 #: the problem by gating a WARM timed window; this leg is deliberately
 #: cold end-to-end, so it gates states/sec + search instead)

@@ -81,8 +81,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import lax, shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import obs
 from .. import faults
@@ -173,6 +173,7 @@ class MeshExplorer(TpuExplorer):
             mesh = Mesh(np.array(jax.devices()), ("d",))
         self.mesh = mesh
         self.D = mesh.devices.size
+        self._shard0 = NamedSharding(mesh, P("d"))  # see _put
         # re-describe the backend with the ACTUAL mesh extent (the
         # base descriptor reports the whole visible device set): the
         # profile namespace and the mesh shape must describe the mesh
@@ -346,7 +347,7 @@ class MeshExplorer(TpuExplorer):
             tel.counter("tier.spilled_keys", total)
         empty = np.full((self.D, SC, self.K), SENTINEL, np.int32)
         empty[:, :, 0] = 1
-        return jnp.asarray(empty), jnp.asarray(
+        return self._put(empty), self._put(
             np.zeros(self.D, np.int32))
 
     def _mesh_tier_filter(self, frontier, fcount, tr_rows, tr_src,
@@ -389,11 +390,11 @@ class MeshExplorer(TpuExplorer):
             if new_src is not None:
                 new_src[dd, :k] = src_slot[dd, :c][keep]
             fc_np[dd] = k
-        frontier = jnp.asarray(new_fr)
-        fcount = jnp.asarray(fc_np)
+        frontier = self._put(new_fr)
+        fcount = self._put(fc_np)
         if self.store_trace:
-            tr_rows = tr_rows.at[:, depth - 1].set(jnp.asarray(new_fr))
-            tr_src = tr_src.at[:, depth - 1].set(jnp.asarray(new_src))
+            tr_rows = tr_rows.at[:, depth - 1].set(self._put(new_fr))
+            tr_src = tr_src.at[:, depth - 1].set(self._put(new_src))
         return frontier, fcount, tr_rows, tr_src, n_dup
 
     # ---- the sharded level step ----
@@ -802,13 +803,6 @@ class MeshExplorer(TpuExplorer):
                                  inv_slot)
         return inv_which, inv_slot
 
-    def _shard_map(self):
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax
-            from jax.experimental.shard_map import shard_map
-        return shard_map
-
     def _get_mesh_step(self, SC: int, FC: int,
                        out_cap: Optional[int] = None) -> Callable:
         """The LEGACY exchange step: out_cap=None drives the host-loop
@@ -940,7 +934,6 @@ class MeshExplorer(TpuExplorer):
                              gsrc.reshape(1, R))
             return out
 
-        shard_map = self._shard_map()
         n_out = 21 if out_cap is not None else \
             (21 if need_edges else 18)
         step = obs.prof_wrap("mesh.level_step", jax.jit(shard_map(
@@ -1275,7 +1268,6 @@ class MeshExplorer(TpuExplorer):
             outs.append(aux_f.reshape(1, _NA))
             return tuple(outs)
 
-        shard_map = self._shard_map()
         n_in = 10 if with_trace else 8
         n_out = 9 if with_trace else 7
         in_specs = tuple([P("d")] * (n_in - 4)) + (P(), P(), P(), P())
@@ -1284,7 +1276,7 @@ class MeshExplorer(TpuExplorer):
         # XLA:CPU ignores donation with a warning, JAXMC_DONATE forces)
         donate = ((0, 2, 4, 5) if with_trace else (0, 2)) \
             if self.donate else ()
-        # check_rep=False: shard_map's replication checker has no rule
+        # check_vma=False: shard_map's replication checker has no rule
         # for lax.while_loop (the superstep level loop); every output
         # is P("d")-sharded anyway, so nothing relied on inferred
         # replication
@@ -1292,7 +1284,7 @@ class MeshExplorer(TpuExplorer):
             device_step, mesh=self.mesh,
             in_specs=in_specs,
             out_specs=tuple([P("d")] * n_out),
-            check_rep=False),
+            check_vma=False),
             donate_argnums=donate))
         self._mesh_step_cache[key] = step
         return step
@@ -1312,7 +1304,6 @@ class MeshExplorer(TpuExplorer):
         K, PW, W = self.K, self.PW, self.W
         plan = self.plan
         keys_of = self._keys_of
-        shard_map = self._shard_map()
         fused_max = self._mesh_fused_max
         # independence-driven group plan (ISSUE 15) shared with the
         # bfs host_seen path; inst_blocks carry each group's original
@@ -1453,14 +1444,13 @@ class MeshExplorer(TpuExplorer):
             outs.append(aux.reshape(1, _NA))
             return tuple(outs)
 
-        shard_map = self._shard_map()
         n_shard = (16 if with_trace else 14)
         n_out = 9 if with_trace else 7
         jtail = obs.prof_wrap("mesh.grouped_tail", jax.jit(shard_map(
             tail_dev, mesh=self.mesh,
             in_specs=tuple([P("d")] * n_shard) + (P(), P(), P()),
             out_specs=tuple([P("d")] * n_out),
-            check_rep=False)))
+            check_vma=False)))
 
         def step(seen, seen_count, frontier, fcount, *args):
             if with_trace:
@@ -1707,6 +1697,15 @@ class MeshExplorer(TpuExplorer):
     # the MESH-RESIDENT loop (ISSUE 8 tentpole)
     # ------------------------------------------------------------------
 
+    def _put(self, host_arr):
+        """Place a [D, ...] host array on the mesh SHARDED over its
+        leading axis — each device receives only its own shard.  A
+        plain jnp.asarray lands all D shards on device 0 and leaves the
+        first shard_map dispatch to reshard them: D x a shard's memory
+        on one chip at every (re)seed and capacity regrowth, and a
+        buffer that changes sharding cannot be donated."""
+        return jax.device_put(np.asarray(host_arr), self._shard0)
+
     def _pad_dev(self, arr, axis: int, newdim: int, fill: int,
                  lane1: bool = False):
         """Grow a [D, ...] device array along `axis` with constant fill
@@ -1716,7 +1715,7 @@ class MeshExplorer(TpuExplorer):
         pad = np.full(shape, fill, np.int32)
         if lane1:
             pad[..., 0] = 1
-        return jnp.concatenate([arr, jnp.asarray(pad)], axis=axis)
+        return jnp.concatenate([arr, self._put(pad)], axis=axis)
 
     def _ring_levels(self, tr_rows, tr_src, upto: int) -> None:
         """Materialize self._levels[1..upto] from the device trace ring
@@ -1778,13 +1777,13 @@ class MeshExplorer(TpuExplorer):
             seen_np = np.full((D, SC, K), SENTINEL, np.int32)
             seen_np[:, :, 0] = 1
             seen_np[:, :ck["SC"]] = ck["seen"]
-            seen = jnp.asarray(seen_np)
-            seen_count = jnp.asarray(
+            seen = self._put(seen_np)
+            seen_count = self._put(
                 ck["seen_counts"].astype(np.int32))
             fr_np = np.full((D, FC, PW), SENTINEL, np.int32)
             fr_np[:, :ck["FC"]] = ck["frontier"]
-            frontier = jnp.asarray(fr_np)
-            fcount = jnp.asarray(ck["fcount"].astype(np.int32))
+            frontier = self._put(fr_np)
+            fcount = self._put(ck["fcount"].astype(np.int32))
             if ck.get("levels") is not None:
                 self._levels = list(ck["levels"])
             elif self.store_trace:
@@ -1831,10 +1830,10 @@ class MeshExplorer(TpuExplorer):
                     keys=init_keys, packed=init_packed, owner=owner)
             if self.store_trace:
                 self._levels.append((frontier_np.copy(), None, FC))
-            seen = jnp.asarray(seen_np)
-            frontier = jnp.asarray(frontier_np)
-            fcount = jnp.asarray(fcount_np.astype(np.int32))
-            seen_count = jnp.asarray(scount_np)
+            seen = self._put(seen_np)
+            frontier = self._put(frontier_np)
+            fcount = self._put(fcount_np.astype(np.int32))
+            seen_count = self._put(scount_np)
             depth = 0
 
         tr_rows = tr_src = None
@@ -1845,8 +1844,8 @@ class MeshExplorer(TpuExplorer):
                 k = min(rows.shape[1], FC)
                 ring_np[:, l, :k] = rows[:, :k]
                 src_np_[:, l, :k] = src[:, :k]
-            tr_rows = jnp.asarray(ring_np)
-            tr_src = jnp.asarray(src_np_)
+            tr_rows = self._put(ring_np)
+            tr_src = self._put(src_np_)
             # _levels beyond the init level will be re-materialized from
             # the ring on demand; keep only level 0 host-side
             del self._levels[1:]
@@ -2200,8 +2199,8 @@ class MeshExplorer(TpuExplorer):
             # frontier over the full seen set
             self._ring_levels(tr_rows, tr_src, depth)
             self._mesh_ck(seen, np.asarray(seen_count),
-                          jnp.asarray(np.zeros((D, FC, PW), np.int32)),
-                          jnp.asarray(np.zeros(D, np.int32)),
+                          np.zeros((D, FC, PW), np.int32),
+                          np.zeros(D, np.int32),
                           FC, SC, depth, generated, distinct)
         self.log("Model checking completed. No error has been found.")
         self.log(f"{generated} states generated, {distinct} distinct "
@@ -2299,7 +2298,6 @@ class MeshExplorer(TpuExplorer):
         route, R, B, SB = self._route_fn(C, FC)
         block_fn = self._candidate_block_fn(FC)
         plan = self.plan
-        shard_map = self._shard_map()
 
         def expand_step(frontier_p, fcount):
             frontier = plan.unpack_rows(frontier_p.reshape(FC, PW))
@@ -2359,10 +2357,10 @@ class MeshExplorer(TpuExplorer):
                 in_specs=(P("d"),) * 5, out_specs=(P("d"),) * 5)))
             for s in ("rank", "fullsort")}
 
-        seen = jnp.asarray(seen_np)
-        scount = jnp.asarray(scount_np)
-        frontier = jnp.asarray(frontier_np)
-        fcount = jnp.asarray(fcount_np.astype(np.int32))
+        seen = self._put(seen_np)
+        scount = self._put(scount_np)
+        frontier = self._put(frontier_np)
+        fcount = self._put(fcount_np.astype(np.int32))
 
         def timed(f, *a):
             t0 = time.time()
@@ -2417,12 +2415,13 @@ class MeshExplorer(TpuExplorer):
         TRLp = _pow2_at_least(max_levels + 2, lo=16)
         try:
             jstep = self._get_mesh_resident_step(SC, FC, TRLp, VCe)
-            s_seen = jnp.asarray(seen_np)
-            s_scnt = jnp.asarray(scount_np)
-            s_front = jnp.asarray(frontier_np)
-            s_fcnt = jnp.asarray(fcount_np.astype(np.int32))
-            s_tr = (jnp.full((D, TRLp, FC, PW), SENTINEL, jnp.int32),
-                    jnp.full((D, TRLp, FC), -1, jnp.int32)) \
+            s_seen = self._put(seen_np)
+            s_scnt = self._put(scount_np)
+            s_front = self._put(frontier_np)
+            s_fcnt = self._put(fcount_np.astype(np.int32))
+            s_tr = (self._put(np.full((D, TRLp, FC, PW), SENTINEL,
+                                      np.int32)),
+                    self._put(np.full((D, TRLp, FC), -1, np.int32))) \
                 if self.store_trace else ()
             warm = True
             while step_levels < max_levels and \
@@ -2544,10 +2543,10 @@ class MeshExplorer(TpuExplorer):
             depth = ck["depth"]
             generated = ck["generated"]
             distinct = ck["distinct"]
-            seen = jnp.asarray(ck["seen"])
+            seen = self._put(ck["seen"])
             seen_counts = ck["seen_counts"].astype(np.int64)
-            frontier = jnp.asarray(ck["frontier"])
-            fcount = jnp.asarray(ck["fcount"])
+            frontier = self._put(ck["frontier"])
+            fcount = self._put(ck["fcount"])
             if ck.get("levels") is not None:
                 self._levels = ck["levels"]
             elif self.store_trace:
@@ -2586,9 +2585,9 @@ class MeshExplorer(TpuExplorer):
                             frontier[d, i].tobytes()]
             if self.store_trace:
                 self._levels.append((frontier.copy(), None, FC))
-            frontier = jnp.asarray(frontier)
-            seen = jnp.asarray(seen)
-            fcount = jnp.asarray(fcount)
+            frontier = self._put(frontier)
+            seen = self._put(seen)
+            fcount = self._put(fcount)
             seen_counts = init_scounts.astype(np.int64)
             depth = 0
 
@@ -2603,13 +2602,13 @@ class MeshExplorer(TpuExplorer):
                 SC2 = _pow2_at_least(need, SC)
                 pad = np.full((D, SC2 - SC, K), SENTINEL, np.int32)
                 pad[:, :, 0] = 1
-                seen = jnp.concatenate([seen, jnp.asarray(pad)], axis=1)
+                seen = jnp.concatenate([seen, self._put(pad)], axis=1)
                 SC = SC2
             expanding_FC = FC
             while True:
                 step = self._get_mesh_step(SC, FC)
                 outs = step(seen,
-                            jnp.asarray(seen_counts.astype(np.int32)),
+                            self._put(seen_counts.astype(np.int32)),
                             frontier, fcount)
                 # count THIS attempt's exchange with the gamma it ran
                 # at: gamma-doubling reruns each pay a full exchange
@@ -2786,7 +2785,7 @@ class MeshExplorer(TpuExplorer):
                 k = min(front_rows_np.shape[1], FC)
                 nf = np.full((D, FC, self.PW), SENTINEL, np.int32)
                 nf[:, :k] = front_rows_np[:, :k]
-                frontier = jnp.asarray(nf)
+                frontier = self._put(nf)
             else:
                 frontier = front_rows[:, :FC]
             if graph is not None:

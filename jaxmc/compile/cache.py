@@ -1,11 +1,45 @@
-r"""Persistent XLA compilation-cache wiring (JAXMC_COMPILE_CACHE).
+r"""Persistent XLA compilation-cache wiring: ONE resolver, one enabler.
 
-The per-arm XLA compiles have repeatedly eaten the bench deadline
-(BENCH_r03..r05: every device child pays the full compile bill even when
-the previous child compiled the identical programs minutes earlier).
-JAX's persistent compilation cache (`jax_compilation_cache_dir`) makes
-repeat compiles disk hits; this module is the ONE place that enables it
-and exposes its effectiveness as obs counters:
+Where the cache lives (`resolve_cache_dir`, used by every call site —
+`check`, the serve device owner, the corpus sweep, the harnesses):
+
+  JAX_COMPILATION_CACHE_DIR set   the cache is THERE.  jax reads that
+                                  variable itself; jaxmc never writes
+                                  the directory knob, and never renames
+                                  or moves the directory.
+  unset                           `<checkout>/.jax_cache` (gitignored).
+
+No temp dir, pid or clock value appears in any cache or profile path:
+the path is part of what makes a second process hit, so a cache that
+moves never hits.  Capacity profiles (below) live INSIDE the resolved
+directory (`<cache>/profiles/` — jax looks entries up by exact
+filename, so a subdirectory is invisible to it), because a learned-caps
+file is what turns the resident engine's growth recompiles into zero
+and must travel with the compiled programs it belongs to.
+
+Every device-backend run uses the cache.  JAXMC_COMPILE_CACHE=0|off|none
+is the opt-out (the CPU test suite sets it: tests/conftest.py records
+why XLA:CPU blob reloads are kept out of the tests).
+
+`enable_guarded_cache` wraps the enable in a guard battery — every
+step fails COLD, never broken: a cache problem degrades to cold
+compilation with a named reason, it cannot fail or hang the run:
+
+  1. build fingerprint: `<dir>/jaxmc.cache.meta.json` records
+     {python, jax, machine}.  A mismatch is the cross-build reload-hang
+     class: this process compiles cold and SAYS SO (remove the
+     directory to start a fresh cache) — the directory is not touched.
+  2. corruption scan: zero-length `*-cache` entries and stale `*.tmp`
+     writer droppings are moved into `<dir>/.quarantine/` and the cache
+     continues — one bad entry never disables the cache.
+  3. health probe: a SUBPROCESS (pinned to CPU, so it never contends
+     for an exclusive accelerator) jits a trivial program against the
+     dir under a hard timeout (JAXMC_CACHE_GUARD_TIMEOUT, default
+     60 s).  A wedge or crash falls back cold.  The probe result is
+     stamped (`<dir>/jaxmc.cache.probe.ok`) so a round of processes
+     pays for it ONCE (JAXMC_CACHE_PROBE=0 skips it entirely).
+
+Effectiveness is exposed as obs counters:
 
   compile.persistent_cache_hits    (jax monitoring event
                                     '/jax/compilation_cache/cache_hits')
@@ -14,48 +48,10 @@ and exposes its effectiveness as obs counters:
   gauge compile.persistent_cache_guard   ("ok[...]" | "cold-fallback:..")
   counter compile.persistent_cache_fallbacks / _quarantines
 
-Two entry points:
-
-  enable_persistent_cache  the RAW enabler (PR 3).  Opt-in only: point
-                           it at a dir and it trusts the dir.
-  enable_guarded_cache     the DEFAULT for bench.py children and sweep
-                           subprocesses (ISSUE 5).  Same cache, wrapped
-                           in the guard battery below, because XLA:CPU
-                           blob reloads written by a DIFFERENT
-                           machine/build have been observed to HANG
-                           (tests/conftest.py) — a shared default cache
-                           must never be able to wedge a run.
-
-The guard battery (every step fails COLD, never broken — a cache
-problem degrades to cold compilation, it cannot fail or hang the run):
-
-  1. flock scope: every user holds a SHARED flock on `<dir>.lock` for
-     the life of the process; quarantining (steps 2/4) requires a
-     NON-BLOCKING EXCLUSIVE upgrade.  If another live process holds the
-     lock, the guard skips the quarantine and falls back cold for this
-     process only — it never yanks a directory under a reader.
-  2. build fingerprint: `<dir>/jaxmc.cache.meta.json` records
-     {python, jax, machine}.  A mismatch is exactly the cross-build
-     reload-hang class — the whole dir is quarantined (renamed aside to
-     `<dir>.quarantined.<ts>`) and a fresh one started.
-  3. corruption scan: zero-length `*-cache` entries and stale `*.tmp`
-     writer droppings are moved into `<dir>/.quarantine/` (jax looks
-     entries up by exact filename, so the subdir is invisible to it)
-     and the cache continues — one bad entry never disables the cache.
-  4. health probe: a SUBPROCESS jits a trivial program against the dir
-     under a hard timeout (JAXMC_CACHE_GUARD_TIMEOUT, default 60 s).  A
-     wedge or crash quarantines the dir and falls back cold.  The probe
-     result is stamped (`<dir>/jaxmc.cache.probe.ok`) so a round of
-     sweep children pays for it ONCE, not per case
-     (JAXMC_CACHE_PROBE=0 skips it entirely).
-
 Fault sites (jaxmc/faults.py, chaos suite): `cache_hang` wedges the
-health probe, `cache_corrupt` zero-truncates one entry before the scan,
-`cache_lock` simulates a held exclusive lock.  tests/test_cache_guard.py
-pins that each one degrades to cold compilation with the run intact.
-
-JAXMC_COMPILE_CACHE=0|off|none disables the cache outright (both entry
-points); any other value is the cache dir.
+health probe, `cache_corrupt` zero-truncates one entry before the scan.
+tests/test_cache_guard.py pins that each one degrades to cold
+compilation with the run intact.
 """
 
 from __future__ import annotations
@@ -64,43 +60,35 @@ import json
 import os
 import subprocess
 import sys
-import tempfile
 import time
 from typing import Optional, Tuple
 
 _OFF_VALUES = ("0", "off", "none", "disabled")
 
-# the process-lifetime shared flock fd (step 1); module global so the
-# lock lives exactly as long as the process uses the cache
-_LOCK_FD: Optional[int] = None
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 _META_NAME = "jaxmc.cache.meta.json"
 _PROBE_STAMP = "jaxmc.cache.probe.ok"
 _PROBE_FRESH_S = 3600.0  # one probe per dir per hour, not per process
 
 
-def cache_dir_from_env() -> Optional[str]:
-    d = os.environ.get("JAXMC_COMPILE_CACHE")
-    if d is None or d.strip().lower() in _OFF_VALUES or not d.strip():
-        return None
-    return d
+def checkout_cache_dir() -> str:
+    """The cache's home when nobody placed it from outside."""
+    return os.path.join(_REPO, ".jax_cache")
+
+
+def resolve_cache_dir() -> str:
+    """THE cache location (module docstring): where
+    JAX_COMPILATION_CACHE_DIR says, else `<checkout>/.jax_cache`."""
+    return os.environ.get(_CACHE_ENV) or checkout_cache_dir()
 
 
 def cache_disabled_by_env() -> bool:
-    """True when JAXMC_COMPILE_CACHE explicitly opts OUT (0/off/none) —
-    the default-on call sites (bench children, sweep subprocesses)
-    honor it; an unset env var is not an opt-out there."""
+    """True when JAXMC_COMPILE_CACHE opts OUT (0/off/none)."""
     d = os.environ.get("JAXMC_COMPILE_CACHE")
     return d is not None and d.strip().lower() in _OFF_VALUES
-
-
-def default_cache_dir() -> str:
-    """The box-wide default dir for the default-on call sites: shared
-    across bench children, sweep subprocesses and rounds on one box
-    (JAXMC_PROBE_DIR keeps parallel harnesses apart, same as the bench
-    probe artifacts)."""
-    base = os.environ.get("JAXMC_PROBE_DIR", tempfile.gettempdir())
-    return os.path.join(base, "jaxmc_xla_cache")
 
 
 _LISTENER_REGISTERED = False
@@ -108,9 +96,7 @@ _LISTENER_REGISTERED = False
 
 def _count_entries(path: str) -> Optional[int]:
     try:
-        return sum(1 for n in os.listdir(path)
-                   if not n.endswith(".tmp")
-                   and n not in (_META_NAME, _PROBE_STAMP, ".quarantine"))
+        return sum(1 for n in os.listdir(path) if n.endswith("-cache"))
     except OSError:
         return None
 
@@ -129,92 +115,36 @@ def _fingerprint() -> dict:
     return fp
 
 
-def _flock(fd: int, exclusive: bool) -> bool:
-    """Non-blocking flock; False on contention or any failure."""
-    try:
-        import fcntl
-        fcntl.flock(fd, (fcntl.LOCK_EX if exclusive else fcntl.LOCK_SH)
-                    | fcntl.LOCK_NB)
-        return True
-    except OSError:
-        return False
-
-
-def _quarantine_dir(path: str) -> Optional[str]:
-    """Rename the whole cache dir aside; returns the new path or None."""
-    dst = f"{path}.quarantined.{int(time.time())}.{os.getpid()}"
-    try:
-        os.rename(path, dst)
-        os.makedirs(path, exist_ok=True)
-        return dst
-    except OSError:
-        return None
-
-
 def _guard(path: str, timeout_s: float, tel) -> Tuple[bool, str]:
-    """Run the guard battery over `path`. Returns (enable?, detail).
-    Mutates module state only to park the shared flock fd."""
+    """Run the guard battery over `path`. Returns (enable?, detail)."""
     from .. import faults
-    global _LOCK_FD
     os.makedirs(path, exist_ok=True)
-
-    # -- step 1: the flock scope ------------------------------------
-    lock_path = path.rstrip("/") + ".lock"
-    fd = os.open(lock_path, os.O_CREAT | os.O_RDWR, 0o644)
-    if faults.fire("cache_lock") is not None or not _flock(fd, False):
-        # someone holds the exclusive lock (a quarantine in flight):
-        # this process compiles cold rather than racing the rename
-        os.close(fd)
-        return False, "lock contention on the cache writer lock"
-
-    def _upgrade_exclusive() -> bool:
-        return _flock(fd, True)
-
-    def _downgrade_shared() -> None:
-        _flock(fd, False)
-
     notes = []
 
-    # -- step 2: build fingerprint ----------------------------------
+    # -- step 1: build fingerprint ----------------------------------
     meta_path = os.path.join(path, _META_NAME)
     fp = _fingerprint()
-    stale = None
     try:
         with open(meta_path) as fh:
             old = json.load(fh)
         if old != fp:
-            stale = f"cache written by another build ({old})"
+            return False, (f"cache written by another build ({old}) — "
+                           f"compiling cold; remove {path} to start a "
+                           f"fresh cache")
     except FileNotFoundError:
-        pass
-    except (OSError, ValueError):
-        stale = "unreadable cache fingerprint"
-    if stale:
-        if not _upgrade_exclusive():
-            os.close(fd)
-            return False, (f"{stale} and still in use by another "
-                           f"process — compiling cold")
-        q = _quarantine_dir(path)
-        if q is None:
-            # the rename failed (permissions, a concurrent re-create):
-            # the foreign dir is STILL there, and it is exactly the
-            # reload-hang class — never enable over it, compile cold
-            os.close(fd)
-            return False, (f"{stale} and the quarantine rename failed "
-                           f"— compiling cold")
-        tel.counter("compile.persistent_cache_quarantines")
-        notes.append(f"quarantined stale dir -> {q}")
-        _downgrade_shared()
-    if not os.path.exists(meta_path):
         try:
-            tmp = meta_path + f".tmp.{os.getpid()}"
+            tmp = meta_path + ".tmp"
             with open(tmp, "w") as fh:
                 json.dump(fp, fh)
             os.replace(tmp, meta_path)
         except OSError:
             pass  # another process won the race; theirs matches or
-            # the next enable quarantines
+            # the next enable falls back cold
+    except (OSError, ValueError):
+        return False, (f"unreadable cache fingerprint — compiling cold; "
+                       f"remove {path} to start a fresh cache")
 
-    # -- step 3: corruption scan ------------------------------------
+    # -- step 2: corruption scan ------------------------------------
     # chaos site: damage one entry right before the scan so the test
     # harness can pin "detected, quarantined, run continues"
     if faults.fire("cache_corrupt") is not None:
@@ -231,8 +161,6 @@ def _guard(path: str, timeout_s: float, tel) -> Tuple[bool, str]:
     try:
         now = time.time()
         for name in os.listdir(path):
-            if name in (_META_NAME, _PROBE_STAMP, ".quarantine"):
-                continue
             p = os.path.join(path, name)
             if not os.path.isfile(p):
                 continue
@@ -256,7 +184,7 @@ def _guard(path: str, timeout_s: float, tel) -> Tuple[bool, str]:
         notes.append(f"quarantined {bad} corrupt entr"
                      f"{'y' if bad == 1 else 'ies'}")
 
-    # -- step 4: health probe under a hard timeout ------------------
+    # -- step 3: health probe under a hard timeout ------------------
     if os.environ.get("JAXMC_CACHE_PROBE", "1") != "0":
         stamp = os.path.join(path, _PROBE_STAMP)
         fresh = False
@@ -267,40 +195,31 @@ def _guard(path: str, timeout_s: float, tel) -> Tuple[bool, str]:
         if not fresh:
             ok, why = _health_probe(path, timeout_s)
             if not ok:
-                if _upgrade_exclusive():
-                    q = _quarantine_dir(path)
-                    tel.counter("compile.persistent_cache_quarantines")
-                    why += f"; dir quarantined -> {q}"
-                    _downgrade_shared()
-                os.close(fd)
                 return False, f"health probe failed ({why})"
             try:
                 with open(stamp, "w") as fh:
-                    fh.write(str(time.time()))
+                    fh.write("ok\n")
             except OSError:
                 pass
             notes.append("probed ok")
 
-    _LOCK_FD = fd  # park the shared lock for the process lifetime
     return True, "; ".join(notes) if notes else "ok"
 
 
 def _health_probe(path: str, timeout_s: float) -> Tuple[bool, str]:
     """Jit one trivial program against the cache dir in a SUBPROCESS so
     a wedged blob reload (the known failure class) hits OUR timeout, not
-    the run's deadline. The `cache_hang` fault site wedges the child."""
-    repo = os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
+    the run's deadline. The child is pinned to CPU — it must never ask
+    for an accelerator its parent is about to own — and is handed the
+    dir the way every process is: through JAX_COMPILATION_CACHE_DIR.
+    The `cache_hang` fault site wedges the child."""
     code = (
         "import os, sys, time\n"
-        "sys.path.insert(0, " + repr(repo) + ")\n"
+        "sys.path.insert(0, " + repr(_REPO) + ")\n"
         "from jaxmc import faults\n"
         "if faults.fire('cache_hang') is not None:\n"
         "    time.sleep(3600)  # the simulated wedge\n"
         "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
-        "jax.config.update('jax_compilation_cache_dir', " + repr(path) +
-        ")\n"
         "import jax.numpy as jnp\n"
         "jax.jit(lambda x: x * 2 + 1)(jnp.arange(3)).block_until_ready()"
         "\n")
@@ -310,7 +229,8 @@ def _health_probe(path: str, timeout_s: float) -> Tuple[bool, str]:
                            capture_output=True, text=True,
                            timeout=timeout_s,
                            env=dict(trace_context.child_env(),
-                                    JAX_PLATFORMS="cpu"))
+                                    JAX_PLATFORMS="cpu",
+                                    **{_CACHE_ENV: path}))
     except subprocess.TimeoutExpired:
         return False, f"wedged past {timeout_s:.0f}s"
     except OSError as ex:
@@ -321,24 +241,23 @@ def _health_probe(path: str, timeout_s: float) -> Tuple[bool, str]:
     return True, "ok"
 
 
-def enable_guarded_cache(path: Optional[str] = None, tel=None,
-                         timeout_s: Optional[float] = None
+def enable_guarded_cache(tel=None, timeout_s: Optional[float] = None
                          ) -> Optional[str]:
-    """The DEFAULT-ON entry (bench children, sweep subprocesses): run
-    the guard battery, then enable the cache.  Returns the cache dir
-    when enabled, None on opt-out or cold fallback.  NEVER raises and
-    never hangs: every guard defect degrades to cold compilation."""
+    """Run the guard battery over the resolved cache dir, then enable
+    the cache there (call with jax importable, before the first
+    compile).  Returns the cache dir when enabled, None on opt-out or
+    cold fallback.  Never hangs: every guard defect degrades to cold
+    compilation."""
     from .. import obs
     if tel is None:
         tel = obs.current()
-    # the env opt-out governs the DEFAULT-ON call sites only: an
-    # explicit `path` (cli --compile-cache DIR) is a direct request and
-    # overrides a box-wide JAXMC_COMPILE_CACHE=off
-    if path is None and cache_disabled_by_env():
+    _register_listeners()
+    if cache_disabled_by_env():
+        _compile_cold()
         tel.gauge("compile.persistent_cache_guard",
                   "disabled:JAXMC_COMPILE_CACHE opt-out")
         return None
-    path = path or cache_dir_from_env() or default_cache_dir()
+    path = resolve_cache_dir()
     if timeout_s is None:
         timeout_s = float(os.environ.get("JAXMC_CACHE_GUARD_TIMEOUT",
                                          "60"))
@@ -347,85 +266,76 @@ def enable_guarded_cache(path: Optional[str] = None, tel=None,
     except Exception as ex:  # noqa: BLE001 — guard bugs degrade cold
         ok, detail = False, f"guard error: {type(ex).__name__}: {ex}"
     if not ok:
+        _compile_cold()
         tel.gauge("compile.persistent_cache_guard",
                   f"cold-fallback:{detail}")
         tel.counter("compile.persistent_cache_fallbacks")
         return None
-    d = enable_persistent_cache(path, tel=tel)
-    if d is None:
-        # the guard battery passed but the raw enabler could not turn
-        # the cache on (jax unavailable/config failure): the verdict
-        # gauge must say COLD, not "ok" — an artifact claiming an
-        # enabled cache with zero hits would misattribute the compile
-        tel.gauge("compile.persistent_cache_guard",
-                  "cold-fallback:enable failed (jax unavailable or "
-                  "cache config rejected)")
-        tel.counter("compile.persistent_cache_fallbacks")
-        return None
+    _enable(path, tel)
     tel.gauge("compile.persistent_cache_guard",
               f"ok ({detail})" if detail != "ok" else "ok")
-    return d
+    return path
 
 
-def enable_persistent_cache(path: Optional[str] = None,
-                            tel=None) -> Optional[str]:
-    """Configure jax's persistent compilation cache at `path` (default:
-    env JAXMC_COMPILE_CACHE) and register a monitoring listener that
-    mirrors cache hits into the active obs telemetry.  Pass `tel` when
-    the caller's recorder is not yet installed process-wide (bench
-    children enable the cache inside their device_init span, before
-    obs.use).  Returns the cache dir when enabled, None when not
-    requested or jax is unavailable.  Never raises: a broken cache setup
-    must not break a check run.  This is the RAW enabler — default-on
-    call sites go through enable_guarded_cache."""
-    path = path or cache_dir_from_env()
-    if not path:
-        return None
-    try:
-        import jax
-        from .. import obs
-        os.makedirs(path, exist_ok=True)
+def _compile_cold() -> None:
+    """Make an opt-out or a cold fallback REAL: with
+    JAX_COMPILATION_CACHE_DIR set jax would read the directory on its
+    own."""
+    import jax
+    jax.config.update("jax_enable_compilation_cache", False)
+
+
+def _enable(path: str, tel) -> None:
+    """Point jax's persistent compilation cache at `path` — a no-op on
+    the directory knob when the environment already did."""
+    import jax
+    if os.environ.get(_CACHE_ENV) != path:
         jax.config.update("jax_compilation_cache_dir", path)
-        # cache everything: the per-arm kernels are small but numerous,
-        # and the default min-compile-time floor would skip most of them
-        for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                          ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(knob, val)
-            except Exception:  # noqa: BLE001 — knob absent on old jax
-                pass
-        if tel is None:
-            tel = obs.current()
-        tel.gauge("compile.persistent_cache_dir", path)
-        n0 = _count_entries(path)
-        if n0 is not None:
-            tel.gauge("compile.persistent_cache_entries_start", n0)
+    jax.config.update("jax_enable_compilation_cache", True)
+    # cache everything: the per-arm kernels are small but numerous,
+    # and the default min-compile-time floor would skip most of them
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    tel.gauge("compile.persistent_cache_dir", path)
+    n0 = _count_entries(path)
+    if n0 is not None:
+        tel.gauge("compile.persistent_cache_entries_start", n0)
 
-        def _on_event(event: str, **kw) -> None:
-            # route through current() at fire time: the telemetry active
-            # when the compile runs, not when the cache was enabled
-            if "compilation_cache" not in event:
-                return
-            from .. import obs as _obs
-            name = event.rsplit("/", 1)[-1]  # e.g. 'cache_hits'
-            if name.startswith("cache_"):
-                name = name[len("cache_"):]
-            _obs.current().counter(f"compile.persistent_cache_{name}")
 
-        # register exactly once per process: jax.monitoring keeps every
-        # listener, so a second enable call (library user running two
-        # checks) would double-count every cache event
-        global _LISTENER_REGISTERED
-        if not _LISTENER_REGISTERED:
-            try:
-                from jax import monitoring
-                monitoring.register_event_listener(_on_event)
-                _LISTENER_REGISTERED = True
-            except Exception:  # noqa: BLE001 — monitoring API drift
-                pass
-        return path
-    except Exception:  # noqa: BLE001
-        return None
+def _register_listeners() -> None:
+    """Mirror jax's compile monitoring into the active obs telemetry:
+    every XLA backend compile (count + seconds — set-up cost, reported
+    beside a run, never a metric of record) and every persistent-cache
+    event.  Registered exactly once per process: jax.monitoring keeps
+    every listener, so a second enable call (the serve owner enables
+    per session) would double-count."""
+    global _LISTENER_REGISTERED
+    if _LISTENER_REGISTERED:
+        return
+    from jax import monitoring
+
+    # both route through current() at fire time: the telemetry active
+    # when the compile runs, not when the cache was enabled
+    def _on_event(event: str, **kw) -> None:
+        if "compilation_cache" not in event:
+            return
+        from .. import obs as _obs
+        name = event.rsplit("/", 1)[-1]  # e.g. 'cache_hits'
+        if name.startswith("cache_"):
+            name = name[len("cache_"):]
+        _obs.current().counter(f"compile.persistent_cache_{name}")
+
+    def _on_duration(event: str, secs: float, **kw) -> None:
+        if not event.endswith("/backend_compile_duration"):
+            return
+        from .. import obs as _obs
+        tel = _obs.current()
+        tel.counter("compile.xla_compiles")
+        tel.counter("compile.xla_compile_s", secs)
+
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    _LISTENER_REGISTERED = True
 
 
 def record_entries_end(path: Optional[str], tel=None) -> None:
@@ -447,8 +357,8 @@ def record_entries_end(path: Optional[str], tel=None) -> None:
 # by overflow-growth — and every growth is a full XLA recompile of the
 # whole while_loop program, potentially inside somebody's measured
 # window.  A capacity profile persists the caps a completed resident run
-# ended with, keyed by (module, layout signature), NEXT TO the compile
-# cache: the next run on the same spec starts at the learned caps, its
+# ended with, keyed by (module, layout signature), INSIDE the compile
+# cache dir (profile_dir): the next run on the same spec starts at the learned caps, its
 # one warm-up compile covers the whole run, and `window_recompiles`
 # reads 0 in the steady-state bench.
 #
@@ -472,7 +382,7 @@ def profile_dir() -> str:
     d = os.environ.get("JAXMC_PROFILE_STORE")
     if d:
         return d
-    return (cache_dir_from_env() or default_cache_dir()) + ".profiles"
+    return os.path.join(resolve_cache_dir(), "profiles")
 
 
 def profile_path(module: str, layout_sig: str, variant: str = "") -> str:
@@ -591,7 +501,7 @@ def save_capacity_profile(module: str, layout_sig: str,
         d = profile_dir()
         os.makedirs(d, exist_ok=True)
         path = profile_path(module, layout_sig, variant)
-        tmp = path + f".tmp.{os.getpid()}"
+        tmp = path + ".tmp"
         with open(tmp, "w", encoding="utf-8") as fh:
             json.dump({"schema": _PROFILE_SCHEMA, "module": module,
                        "layout_sig": layout_sig, "variant": variant,
@@ -605,13 +515,3 @@ def save_capacity_profile(module: str, layout_sig: str,
     except Exception:  # noqa: BLE001 — a profile is a hint, never a crash
         return None
 
-
-def release_lock_for_tests() -> None:
-    """Drop the parked shared flock so tests can exercise contention."""
-    global _LOCK_FD
-    if _LOCK_FD is not None:
-        try:
-            os.close(_LOCK_FD)
-        except OSError:
-            pass
-        _LOCK_FD = None

@@ -2166,7 +2166,7 @@ class UnrollLimitError(CompileError):
 # read the one constant, so the wording cannot diverge
 SUBSET_SYMBOLIC_MSG = "SUBSET of symbolic set"
 
-# ISSUE 15 taxonomy additions: a quantifier with no domain at all, and
+# ISSUE 15 classification additions: a quantifier with no domain at all, and
 # a quantifier/enumeration over an infinite constant set (Nat, Int,
 # STRING, Seq(S)) — both certain demotions the predictor can name
 # before any build
@@ -2734,19 +2734,14 @@ def introspect_kernel(fn: Callable, args, want_cost: bool = True
     this run's arm compiles were eligible for disk hits."""
     jx = jax.make_jaxpr(fn)(*args)  # propagates trace-time errors
     out: Dict[str, int] = {"jaxpr_eqns": len(jx.eqns)}
-    try:
-        if jax.config.jax_compilation_cache_dir:
-            from .. import obs
-            obs.current().gauge("compile.persistent_cache_active", True)
-    except AttributeError:  # config knob absent on old jax
-        pass
+    if jax.config.jax_compilation_cache_dir:
+        from .. import obs
+        obs.current().gauge("compile.persistent_cache_active", True)
     if not want_cost or \
             os.environ.get("JAXMC_COMPILE_INTROSPECT") == "0":
         return out
     try:
         ca = jax.jit(fn).lower(*args).cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax: one per device
-            ca = ca[0] if ca else None
         if ca:
             flops = ca.get("flops")
             nbytes = ca.get("bytes accessed")

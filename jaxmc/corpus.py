@@ -56,7 +56,7 @@ class Case:
     # and logs each arm's demotion reason.
     mode: Optional[str] = None
     # DERIVED mode pin (ISSUE 15): for mode="interp-arms" cases whose
-    # demotions the analyze/verdicts.py taxonomy covers, the PREDICTOR
+    # demotions the analyze/verdicts.py classification covers, the PREDICTOR
     # (not the measured pin) skips the futile kernel builds — and the
     # sweep asserts full coverage: a predictor that stops predicting
     # every arm FAILS the case loudly instead of silently re-paying
@@ -212,6 +212,26 @@ CASES: List[Case] = [
                    "VC": 1 << 13, "chunk": 2048},
          mesh_caps={"SC": 1 << 17, "FC": 1 << 13, "TRL": 32,
                     "GAM16": 32, "MSL": 32}),
+    # the chip's REAL rung (ISSUE 21, chip_smoke.py legs B/C/E): four
+    # processes.  Counts confirmed by the exact interpreter (--workers
+    # 8, 686 s here): 13 BFS levels, largest frontier 1,883,904
+    Case("specs/transfer_scaled.tla", root="repo",
+         cfg="specs/transfer_scaled_4p.cfg",
+         distinct=9394019, generated=24035597, slow=True, jax="yes",
+         mode="compiled",
+         res_caps={"SC": 1 << 24, "FCap": 1 << 21, "AccCap": 1 << 22,
+                   "VC": 1 << 14, "chunk": 2048},
+         mesh_caps={"SC": 1 << 22, "FC": 1 << 19, "TRL": 32,
+                    "GAM16": 32, "MSL": 32}),
+    # the FLOOR rung (ISSUE 21): what chip_smoke.py's leg B runs —
+    # the real rung's cold resident run alone costs more XLA compile
+    # time than the smoke's 1200 s contract leaves (PERF.md).  Counts
+    # confirmed by the exact interpreter: 13 levels, largest frontier
+    # 374,504
+    Case("specs/transfer_scaled.tla", root="repo",
+         cfg="specs/transfer_scaled_4p8.cfg",
+         distinct=1859252, generated=4767576, slow=True, jax="yes",
+         mode="compiled"),
     Case("specs/MCraftMicro.tla", root="repo",
          cfg="specs/MCraft_micro.cfg", includes=("examples",),
          distinct=694, generated=6185, jax="yes", mode="compiled",
@@ -225,7 +245,7 @@ CASES: List[Case] = [
          cfg="specs/MCraft_3s_bench.cfg", includes=("examples",),
          distinct=76654, generated=1138651, slow=True, jax="yes",
          mode="compiled",
-         # the bench.py full rung's steady caps (one warm-up compile
+         # the full rung's steady caps (one warm-up compile
          # covers the run; the persisted profile max-merges over this)
          res_caps={"SC": 1 << 18, "FCap": 1 << 16, "AccCap": 1 << 17,
                    "VC": 1 << 13},
@@ -368,7 +388,7 @@ CASES: List[Case] = [
          jax="yes", mode="compiled"),
     # DERIVED interp-arms fixture (ISSUE 15): both arms are unsized
     # dynamic \E shapes (multi-binder / nested) that the verdict
-    # taxonomy predicts with ground.py's exact reason strings — the
+    # classification predicts with ground.py's exact reason strings — the
     # repo-local pin_derived representative (no /root/reference needed)
     Case("specs/dyntoy.tla", root="repo", cfg="specs/dyntoy.cfg",
          distinct=8, generated=49, jax="yes", mode="interp-arms",
@@ -397,8 +417,8 @@ def mode_pins_enabled() -> bool:
 
 
 def case_for_cfg(cfg_basename: str) -> Optional[Case]:
-    """Manifest lookup by cfg basename (bench.py uses it to assert the
-    full rung's resumed counts against the pinned totals)."""
+    """Manifest lookup by cfg basename (the harnesses and chip_smoke.py
+    assert their counts against the pinned totals)."""
     for c in CASES:
         p = c.cfg_path()
         if p and os.path.basename(p) == cfg_basename:

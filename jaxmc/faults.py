@@ -2,7 +2,7 @@ r"""Deterministic fault injection (JAXMC_FAULTS) — the chaos harness.
 
 Long exact-enumeration runs die in a handful of boring ways: an
 OOM-killed pool worker, a transient chunk failure, a clipped checkpoint
-file, a device plugin that refuses to come up.  The fault-tolerance
+file, a device that refuses to come up.  The fault-tolerance
 layer (engine/parallel.py requeue/respawn, engine/ckpt.py integrity
 checks, cli.py device fallback) exists to survive exactly those — and
 this registry lets tests and `make chaos` trigger each one on demand,
@@ -44,7 +44,7 @@ Sites wired in this PR:
     ckpt_corrupt      every checkpoint write leaves a truncated
                       (mode=truncate, default) or bit-flipped
                       (mode=flip) file behind
-    device_init_fail  device/plugin init raises (cli.py retries)
+    device_init_fail  device init raises (session.py retries)
     compile_fail      a per-arm kernel compile raises transiently
                       (tpu/bfs.py retries)
     device_run_fail   the device search loop raises entering a level
@@ -61,14 +61,10 @@ tests/test_cache_guard.py):
 
     cache_hang        the cache health-probe subprocess wedges (the
                       known cross-build blob-reload hang): the guard's
-                      timeout fires, the dir is quarantined, the run
-                      compiles cold
+                      timeout fires and the run compiles cold
     cache_corrupt     one cache entry is zero-truncated before the
                       corruption scan: the entry is quarantined into
                       <dir>/.quarantine and the cache stays enabled
-    cache_lock        the guard's flock acquisition reports contention
-                      (another process mid-quarantine): cold fallback
-                      for this process only
 
 Fleet-serving sites (ISSUE 19, serve/{queue,daemon}.py — the chaos
 surface for `make fleet-check` and tests/test_chaos.py):

@@ -1,8 +1,12 @@
 r"""Kernel-vs-interpreter bench leg (ISSUE 6): `python -m jaxmc.kernelbench`.
 
-The whole point of the compiled path is to outrun the exact interpreter —
-BENCH_r04 measured it at 0.678x instead.  This driver turns that into a
-GATE: for one spec it measures, on the same workload,
+A CPU TEST GATE, not a chip path: with no JAXMC_PLATFORM/JAX_PLATFORMS
+it pins jax to "cpu" (main()), and what it times is XLA:CPU — never a
+device metric.  The chip's proof of life is chip_smoke.py.
+
+The compiled path must not lose to the exact interpreter it replaces.
+This driver makes that a GATE: for one spec it measures, on the same
+workload,
 
   interp  the serial exact interpreter (engine/explore.py), fresh
           Explorer per repeat, min-of-repeats wall;

@@ -7,9 +7,15 @@ manifest pins at several device counts, and (b) a SCALING CURVE
 (states/sec/chip over D) published as a MULTICHIP_r* artifact.  Both
 run per-D in fresh subprocesses because the device count is fixed at
 jax init: each child forces `XLA_FLAGS=--xla_force_host_platform_
-device_count=D` virtual CPU devices (real chips when
-JAXMC_MESHBENCH_PLATFORM names an accelerator platform with enough
-devices).
+device_count=D` virtual CPU devices.
+
+`check` and `bench` are CPU TEST GATES, not chip paths:
+JAXMC_MESHBENCH_PLATFORM defaults to "cpu", and what they time on
+virtual devices is never a device metric.  The one chip path through
+this module is chip_smoke.py's mesh leg, which sets
+JAXMC_MESHBENCH_PLATFORM=tpu explicitly and drives `child` once in ONE
+process over all four chips; the child pins jax to the named platform
+and fails when jax delivers another.
 
 Subcommands
   check   D in {2,4} (default) parity legs over the repo-local rungs
@@ -40,7 +46,7 @@ Subcommands
 
 Rungs that need the reference corpus (the MCraft family EXTENDS the
 reference raft.tla) emit a parseable `MESHBENCH SKIP` line in builder
-containers instead of failing, exactly like bench.py (ISSUE 6).
+containers instead of failing (ISSUE 6).
 """
 
 from __future__ import annotations
@@ -334,8 +340,7 @@ def cmd_child(args) -> int:
             f" --xla_force_host_platform_device_count={args.devices}")
     import numpy as np
     import jax
-    if plat == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_platforms", plat)
     from jax.sharding import Mesh
     from . import obs
     from .front.cfg import ModelConfig, parse_cfg
@@ -364,15 +369,18 @@ def cmd_child(args) -> int:
         search += case.include_dirs()
     model = bind_model(Loader(search).load_path(spec), mc)
 
-    devs = jax.devices()
-    if len(devs) < args.devices:
-        print(f"error: need {args.devices} devices, have {len(devs)}",
-              file=sys.stderr)
-        return 2
-    mesh = Mesh(np.array(devs[:args.devices]), ("d",))
-
     tel = obs.Telemetry(meta={"backend": "jax-mesh",
                               "devices": args.devices})
+    from .compile.cache import enable_guarded_cache
+    enable_guarded_cache(tel=tel)
+    devs = jax.devices()
+    if devs[0].platform != plat or len(devs) < args.devices:
+        print(f"error: need {args.devices} {plat} devices, have "
+              f"{len(devs)} {devs[0].platform}", file=sys.stderr)
+        return 2
+    mesh = Mesh(np.array(devs[:args.devices]), ("d",))
+    obs.stamp_device(tel, devs)
+
     with obs.use(tel):
         mesh_caps = dict(case.mesh_caps) \
             if case is not None and case.mesh_caps else None
@@ -400,6 +408,13 @@ def cmd_child(args) -> int:
                 1 for lv in tel.levels[lvl0:] if lv.get("fresh_compile"))
         phase_walls = me.probe_phase_walls() if args.phase_probe \
             else None
+        # where the tables really live: per-device peak allocation
+        # (accelerators; XLA:CPU reports none) — seen shards placed at
+        # creation keep every device near the mean
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in mesh.devices.flat]
+        if all(p is not None for p in peaks):
+            tel.gauge("mesh.device_peak_bytes", peaks)
     levels = len(tel.levels) - (lvl0 if args.timed else 0)
     host_syncs = tel.counters.get("mesh.host_syncs", 0) - \
         (sync0 if args.timed else 0)

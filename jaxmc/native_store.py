@@ -1,13 +1,17 @@
 r"""ctypes binding for the native host fingerprint store (native/fps_store.cc).
 
 Builds the shared library on first use with g++ (pybind11 is not in the
-image; the C ABI + ctypes keeps the binding dependency-free). Falls back
+image; the C ABI + ctypes keeps the binding dependency-free) into the
+gitignored native/build/: no binary is committed, and the library is
+named by a hash of its source, so a checkout copied without mtimes —
+or an edited fps_store.cc — can never load a stale build. Falls back
 cleanly when no toolchain exists: callers must check is_available().
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,7 +21,7 @@ import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_REPO, "native", "fps_store.cc")
-_SO = os.path.join(_REPO, "native", "build", "libjaxmc_fps.so")
+_BUILD_DIR = os.path.join(_REPO, "native", "build")
 _lock = threading.Lock()
 _lib = None
 _build_err: Optional[str] = None
@@ -29,14 +33,20 @@ def _load():
         if _lib is not None or _build_err is not None:
             return _lib
         try:
-            if not os.path.exists(_SO) or \
-                    os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-                os.makedirs(os.path.dirname(_SO), exist_ok=True)
+            with open(_SRC, "rb") as fh:
+                tag = hashlib.sha256(fh.read()).hexdigest()[:12]
+            so = os.path.join(_BUILD_DIR, f"libjaxmc_fps.{tag}.so")
+            if not os.path.exists(so):
+                os.makedirs(_BUILD_DIR, exist_ok=True)
+                # build aside, then rename: a concurrent first use (the
+                # sweep's children) never loads a half-written library
+                tmp = f"{so}.{os.getpid()}.tmp"
                 subprocess.run(
                     ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                     "-pthread", _SRC, "-o", _SO],
+                     "-pthread", _SRC, "-o", tmp],
                     check=True, capture_output=True, text=True)
-            lib = ctypes.CDLL(_SO)
+                os.replace(tmp, so)
+            lib = ctypes.CDLL(so)
             lib.jaxmc_fps_create.restype = ctypes.c_void_p
             lib.jaxmc_fps_create_ex.restype = ctypes.c_void_p
             lib.jaxmc_fps_create_ex.argtypes = [ctypes.c_char_p,
@@ -95,7 +105,7 @@ class FingerprintStore:
     spill_dir (default: env JAXMC_FPS_SPILL_DIR) switches large runs to
     file-backed mmap so seen-sets beyond RAM page out to disk instead of
     OOM-killing the search — the MCraft_3s-scale prerequisite (SURVEY.md
-    §7.5; VERDICT r4 #8). spill_threshold_bytes (env
+    §7.5). spill_threshold_bytes (env
     JAXMC_FPS_SPILL_MB, in MB) is the per-run size that triggers
     file backing."""
 

@@ -28,7 +28,7 @@ artifacts.
 from . import context
 from .telemetry import (Logger, NullTelemetry, Telemetry, current,
                         device_mem_high_water, environment_meta,
-                        prom_name, rss_bytes, use, use_local,
+                        live_devices, prom_name, stamp_device, rss_bytes, use, use_local,
                         write_json_atomic)
 from .context import TraceContext, child_env
 from .ledger import append_summary, ledger_path
@@ -43,9 +43,10 @@ __all__ = ["Logger", "NullTelemetry", "Profiler", "Telemetry",
            "Watchdog", "TraceContext", "ProgressEstimator",
            "append_summary", "attach_estimator", "child_env", "context",
            "current", "device_mem_high_water", "environment_meta",
-           "eta_suffix", "ledger_path", "note_buffer",
+           "eta_suffix", "ledger_path", "live_devices", "note_buffer",
            "prof_attribution", "prof_wrap", "prom_name", "rss_bytes",
-           "use", "use_local", "write_json_atomic", "SCHEMA", "SCHEMAS",
+           "stamp_device", "use", "use_local", "write_json_atomic",
+           "SCHEMA", "SCHEMAS",
            "REQUIRED_KEYS", "CHECK_KEYS", "RESULT_KEYS",
            "HEARTBEAT_KEYS", "STALL_KEYS", "validate_summary",
            "validate_trace_event"]

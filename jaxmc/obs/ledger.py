@@ -23,8 +23,8 @@ memory:
             best-of-`--window`), with env-change attribution reused
             from `obs diff` (report._env_changes) so a drop caused by a
             jax upgrade or a device-count change reads as such.
-  --import  backfills committed artifacts (BENCH_r01..r05,
-            MULTICHIP_r01..r08, any --metrics-out JSON) through
+  --import  backfills artifacts (driver BENCH_r* records,
+            MULTICHIP_r02..r08, any --metrics-out JSON) through
             report.load_record so the trajectory starts at r01.
 
 Pure stdlib (no jax): the CLI must work in interp-only environments.

@@ -1,6 +1,6 @@
 r"""The `--metrics-out` / `--trace` event schema, as data.
 
-One place pins what every artifact must carry so the CLI, bench.py, the
+One place pins what every artifact must carry so the CLI, the harnesses, the
 sweep driver, and tests/test_obs.py agree. `validate_summary` raises
 ValueError with the missing/ill-typed field names — it is deliberately
 structural (required keys + types + level-index monotonicity), not
@@ -95,22 +95,14 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       `compile.persistent_cache_guard` — "ok" / "ok (<notes>)" when the
       cache enabled (notes name quarantined entries / a fresh probe),
       "cold-fallback:<reason>" when the guard degraded the run to cold
-      compilation (wedged probe, corrupt dir, lock contention, foreign
-      build), "disabled:..." on explicit opt-out; counters
+      compilation (wedged probe, foreign build — the reason says so
+      and the dir is left untouched), "disabled:..." on explicit
+      opt-out; counters
       `compile.persistent_cache_fallbacks` and
       `compile.persistent_cache_quarantines`.  The existing
       `compile.persistent_cache_hits` counter is the CROSS-PROCESS
       proof: >0 means this process reloaded a program some other
       process compiled.
-    - steady-state bench window (bench.py full rung): the emitted line
-      gains a `steady_state` block {source, path, resumed_generated,
-      resumed_distinct, resumed_depth, window_generated, window_wall_s,
-      window_recompiles}; the parent's orchestration block gains
-      `compile_excluded_from_window` {phases: {name -> wall_s},
-      total_s} — the one-time compile bill, separated from the
-      steady-state states/sec claim.  New child phase spans
-      `warmup_run {warm_source}` and `warm_ckpt_build {warm_states}`;
-      bench-warm runs emit `warmgen_bench` / `warmgen_3s` spans.
     - expansion-mode pins (corpus.py): sweep case records/details note
       `[mode pinned]` for manifest-pinned interp-arms cases (kernel
       construction skipped) and carry a per-arm demotion reason table
@@ -522,6 +514,25 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       expiry takeover provenance); job status gains "quarantined".
     - batch counters: `batch.resume_refused` (a cohort member's
       checkpoint could not seed the merged layout; it ran fresh).
+
+  (PR 21, still jaxmc.metrics/4 — additive; the chip bring-up surface:)
+    - the device is NAMED everywhere: meta block `env` gains
+      `device_kind`, `jaxlib_version`, `libtpu_version` beside
+      {jax_version, platform, device_count}; gauge `device.kind`
+      beside `device.platform` / `device.count`.  The three device
+      fields are null until THIS process has a live jax backend
+      (obs.live_devices — telemetry never initializes one);
+    - `result.finished_on`: the engine that produced the result —
+      the requested backend, or "interp" when the run demoted
+      (session.demote_to_cpu, which now fires only with a host
+      snapshot to resume);
+    - counters `compile.xla_compiles` / `compile.xla_compile_s`: every
+      XLA backend compile request this process made and the seconds
+      they took (a persistent-cache hit counts its retrieval time) —
+      set-up cost, never a metric of record;
+    - gauge `mesh.device_peak_bytes` (meshbench child): per-device
+      `memory_stats()["peak_bytes_in_use"]`, where the backend
+      reports it.
 """
 
 from __future__ import annotations

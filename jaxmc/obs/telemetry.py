@@ -22,12 +22,13 @@ user asked for an artifact.
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import os
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from . import context as trace_context
 from .prof import Profiler  # per-dispatch attribution + HBM model
@@ -554,45 +555,74 @@ def rss_bytes() -> Optional[int]:
         return None
 
 
+def live_devices():
+    """The devices of a jax backend THIS process already initialized,
+    else None.  NEVER initializes one: a chip belongs to one process,
+    and a process that merely imported jax (the serve daemon stamping a
+    job record or rolling up a profile) must not become the chip's
+    owner for telemetry's sake."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    return jax.devices()
+
+
+@functools.lru_cache(maxsize=None)
+def _package_versions() -> Tuple[Tuple[str, Optional[str]], ...]:
+    """Installed jax/jaxlib/libtpu versions — a metadata read (no
+    import, no device init), once per process: the serve daemon stamps
+    every job record with them."""
+    from importlib.metadata import PackageNotFoundError, version
+    out = []
+    for pkg in ("jax", "jaxlib", "libtpu"):
+        try:
+            out.append((f"{pkg}_version", version(pkg)))
+        except PackageNotFoundError:
+            out.append((f"{pkg}_version", None))
+    return tuple(out)
+
+
 def environment_meta() -> Dict[str, Any]:
     """The environment fingerprint recorded in the metrics `meta` block
-    (and the bench JSON line) so `python -m jaxmc.obs diff` can
-    attribute a regression to an environment change instead of a code
-    change. Deliberately does NOT import jax: an interp run must not pay
-    (or hang on) device-plugin init for telemetry's sake — platform and
-    device count appear only when the caller already initialized jax."""
+    so `python -m jaxmc.obs diff` can attribute a regression to an
+    environment change instead of a code change.  platform, device_kind
+    and device_count appear only once the caller has a live backend
+    (live_devices; stamp_device re-stamps them)."""
     out: Dict[str, Any] = {"python": sys.version.split()[0],
-                           "jax_version": None, "platform": None,
+                           "platform": None, "device_kind": None,
                            "device_count": None}
-    jax = sys.modules.get("jax")
-    if jax is not None:
-        out["jax_version"] = getattr(jax, "__version__", None)
-        try:
-            devs = jax.devices()
-            out["platform"] = devs[0].platform
-            out["device_count"] = len(devs)
-        except Exception:  # noqa: BLE001 — backend init may be broken
-            pass
-    else:
-        try:  # metadata read only — no import, no device init
-            from importlib.metadata import version
-            out["jax_version"] = version("jax")
-        except Exception:  # noqa: BLE001
-            pass
+    out.update(_package_versions())
+    devs = live_devices()
+    if devs:
+        out["platform"] = devs[0].platform
+        out["device_kind"] = devs[0].device_kind
+        out["device_count"] = len(devs)
     return out
 
 
+def stamp_device(tel, devs) -> None:
+    """Name the device in `tel`, once a backend is live: the three
+    `device.*` gauges and the re-stamped `env` block every artifact and
+    job summary carries."""
+    tel.gauge("device.platform", devs[0].platform)
+    tel.gauge("device.kind", devs[0].device_kind)
+    tel.gauge("device.count", len(devs))
+    tel.set_meta(env=environment_meta())
+
+
 def device_mem_high_water() -> Optional[int]:
-    """Sum of per-device peak allocation bytes, when the jax backend
+    """Sum of per-device peak allocation bytes, when a live jax backend
     exposes memory_stats (TPU/GPU; CPU usually returns None). Never
-    raises — telemetry must not break a run."""
+    raises — telemetry must not break a run — and never initializes a
+    backend (live_devices)."""
     try:
-        import jax
         total = 0
         seen = False
-        for d in jax.devices():
-            ms = getattr(d, "memory_stats", None)
-            st = ms() if callable(ms) else None
+        for d in live_devices() or ():
+            st = d.memory_stats()
             if not st:
                 continue
             peak = st.get("peak_bytes_in_use", st.get("bytes_in_use"))

@@ -1159,7 +1159,8 @@ class ServeDaemon:
                     if cfg.backend == "interp":
                         raise
                     # the CLI's device->CPU fallback, same policy
-                    # (session.demote_to_cpu is the shared path)
+                    # (session.demote_to_cpu is the shared path: it
+                    # re-raises unless a host snapshot exists)
                     res = sess.demote_to_cpu(ex)
                 with self._cv:
                     self.warm[sig] = {"session": sess,
@@ -1187,6 +1188,7 @@ class ServeDaemon:
             "generated": res.generated, "diameter": res.diameter,
             "truncated": bool(res.truncated),
             "wall_s": round(res.wall_s, 6),
+            "finished_on": sess.finished_on,
             "warnings": list(getattr(res, "warnings", []))}
         if drained:
             result_block["drained"] = True
@@ -1714,6 +1716,10 @@ class ServeDaemon:
             "batch_enabled": self.batch_enabled,
             "device_owner_pid": self.owner.pid
             if self.owner is not None else None,
+            # one process per chip: with the owner on, THIS process
+            # must never hold a jax backend (the owner's init would
+            # fail against an exclusive accelerator)
+            "daemon_holds_device": obs.live_devices() is not None,
             "warm_sessions": {
                 s: sess.describe() for s, sess in warm.items()},
             "workers": self.n_workers,

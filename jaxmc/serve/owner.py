@@ -3,10 +3,14 @@ r"""The device-owner worker process (ISSUE 13).
 The daemon's workers are THREADS: good for overlapping many jobs'
 host-side work, but (a) CPU-bound interp jobs contend with the HTTP
 loop for the GIL, and (b) one wedged XLA dispatch would stall every
-thread behind the device.  With JAXMC_SERVE_DEVICE_OWNER=1 (or
-`serve run --device-owner`) the daemon routes DEVICE work — cross-model
-vmapped batches and solo device-backend jobs — to one spawned
-child process that owns the accelerator:
+thread behind the device.  By default (JAXMC_SERVE_DEVICE_OWNER=0
+opts out) the daemon routes DEVICE work — cross-model vmapped batches
+and solo device-backend jobs — to one spawned child process that owns
+the accelerator.  A chip belongs to ONE process at a time, so this is
+also what lets the daemon serve a real chip at all: run ONE DAEMON PER
+CHIP (two daemons on a host each spawn an owner that contends for the
+same chip; with the owner off the daemon's own threads initialize jax
+and the daemon IS the chip's process — there is no placement layer):
 
   - the daemon process never initializes jax: HTTP + interp jobs keep
     the GIL to themselves;
@@ -37,7 +41,8 @@ from .. import obs
 
 
 def _member_summary(res, jt, backend: str, spec: str,
-                    serve_block: Dict[str, Any]) -> Dict[str, Any]:
+                    serve_block: Dict[str, Any],
+                    finished_on: Optional[str] = None) -> Dict[str, Any]:
     """ONE result-summary builder for every owner-run job (vbatch
     member or solo): the jaxmc.metrics result block, the rendered
     violation trace, and the serve block — shared so the two paths
@@ -48,6 +53,7 @@ def _member_summary(res, jt, backend: str, spec: str,
         "generated": res.generated, "diameter": res.diameter,
         "truncated": bool(res.truncated),
         "wall_s": round(res.wall_s, 6),
+        "finished_on": finished_on or backend,
         "warnings": list(getattr(res, "warnings", []))}
     if drained:
         result_block["drained"] = True
@@ -260,7 +266,7 @@ def run_solo(md: Dict[str, Any]) -> Dict[str, Any]:
         "device_owner": True,
         "batched_with": [],
         "job_wall_s": round(time.time() - t0, 6),
-    })
+    }, finished_on=sess.finished_on)
 
 
 def _owner_main(conn) -> None:

@@ -10,7 +10,7 @@ Pins, all on repo-local fixtures:
     turns in [0,2]^P — proven element lanes, zero guarded lanes,
     bits/state halved, counts/traces bit-identical analyze on/off;
     record fields keep PER-KEY intervals.
-  * verdict taxonomy: dyntoy's multi-binder and nested dynamic \E
+  * verdict classification: dyntoy's multi-binder and nested dynamic \E
     arms are predicted with ground.py's exact reason strings (zero
     futile builds), quantifiers over Nat / unbounded quantifiers
     predict kernel2's exact wording, and the corpus pin_derived
@@ -351,9 +351,9 @@ Spec == Init /\ [][Next]_<<r>>
         assert state_space_estimate(m, infer_state_bounds(m)) is None
 
 
-# ------------------------------------------------- verdict taxonomy
+# ------------------------------------------------- verdict classification
 
-class TestVerdictTaxonomy:
+class TestVerdictClasses:
     def test_dyntoy_predicted_equals_built(self):
         pytest.importorskip("jax")
         from jaxmc import native_store
@@ -381,7 +381,7 @@ class TestVerdictTaxonomy:
         assert built == pred  # identical wording, both classes
 
     def test_quantifier_domain_classes_predicted(self, tmp_path):
-        """The two new taxonomy classes carry kernel2's raise-site
+        """The two new classification classes carry kernel2's raise-site
         constants (UNBOUNDED_QUANTIFIER_MSG / cannot_enumerate_message
         — the same one-constant contract the unroll message pins).  No
         engine build here: a spec quantifying over Nat in an enabled

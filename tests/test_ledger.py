@@ -1,7 +1,7 @@
 r"""Persistent run ledger (ISSUE 17, jaxmc/obs/ledger.py): append /
-flock-concurrency / torn-line tolerance, artifact backfill over the
-COMMITTED BENCH_r* + MULTICHIP_r* history, trajectory rendering via
-`python -m jaxmc.obs history`, and the --fail-on-regress gate firing
+flock-concurrency / torn-line tolerance, artifact backfill over
+BENCH_r*-shaped records + the committed MULTICHIP_r* history,
+trajectory rendering via `python -m jaxmc.obs history`, and the --fail-on-regress gate firing
 (exit 1) on a synthesized degraded run.
 
 Pure stdlib + tmp ledgers throughout — conftest pins JAXMC_LEDGER=off
@@ -106,7 +106,16 @@ class TestAppendRead:
 class TestBackfill:
     def test_import_committed_history_idempotent(self, tmp_path):
         lp = str(tmp_path / "ledger.jsonl")
-        pats = [os.path.join(REPO, "BENCH_r*.json"),
+        # a driver bench record (the family's shape; the committed
+        # BENCH_r01-r05 files went with bench.py) and a dead one
+        (tmp_path / "BENCH_r02.json").write_text(json.dumps({
+            "n": 2, "rc": 0, "parsed": {
+                "metric": "states/sec, exhaustive (platform=cpu)",
+                "value": 16638.7, "unit": "states/sec",
+                "vs_baseline": 2.694}}))
+        (tmp_path / "BENCH_r03.json").write_text(json.dumps({
+            "n": 3, "rc": 124, "parsed": None}))
+        pats = [str(tmp_path / "BENCH_r*.json"),
                 os.path.join(REPO, "MULTICHIP_r*.json")]
         skipped = []
         n = ledger.import_artifacts(pats, lp, skipped=skipped)

@@ -129,16 +129,6 @@ class TestDiff:
         assert "REGRESS backend demotion r04 -> r05" in out
         assert "cpu -> interp" in out
 
-    def test_repo_bench_artifacts_ingest(self, tmp_path):
-        r4 = os.path.join(REPO, "BENCH_r04.json")
-        r5 = os.path.join(REPO, "BENCH_r05.json")
-        if not (os.path.exists(r4) and os.path.exists(r5)):
-            pytest.skip("repo bench artifacts not present")
-        rc, out = run_cli(["diff", r4, r5, "--fail-on-regress"])
-        # r05 demoted to the interpreter: the flag (and gate) must fire
-        assert rc == 1
-        assert "REGRESS backend demotion" in out
-
     def test_mixed_kinds_and_three_way(self, tmp_path):
         a = mk_artifact(tmp_path / "a.json", rate=4000.0, platform="cpu",
                         phases={"search": 5.0})
@@ -307,7 +297,7 @@ class TestOracleHighlights:
         tel.gauge("backend.oracle_wall_s", 1.23)
         tel.gauge("backend.oracle_probe", {
             "tpu": {"live": False,
-                    "error": "probe wedged past 7.0s (dead tunnel?)"},
+                    "error": "probe wedged past 7.0s (device hung at init?)"},
             "cpu": {"live": True, "devices": 1, "compile_s": 0.4,
                     "dispatch_s": 0.012}})
         tel.set_meta(backend="jax", spec="specs/symtoy.tla",

@@ -292,9 +292,11 @@ class TestWarmReuse:
         # checkpoint (resume), the capacity profile (caps), and the
         # persistent compile cache (the one fresh XLA program becomes a
         # disk hit)
+        # the cache is placed from OUTSIDE, the way every process is
+        # told where it lives; the capacity profiles travel inside it
         extra_env = {
-            "JAXMC_PROFILE_STORE": str(tmp_path / "profiles"),
-            "JAXMC_COMPILE_CACHE": str(tmp_path / "xla_cache"),
+            "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "xla_cache"),
+            "JAXMC_COMPILE_CACHE": "on",  # conftest opts the suite out
             "JAXMC_CACHE_PROBE": "0",
         }
         q = JobQueue(spool)
@@ -319,6 +321,7 @@ class TestWarmReuse:
             assert sv["resumed_from_checkpoint"] is True
             assert sv["profile_hits"] >= 1
             assert sv["persistent_cache_hits"] >= 1
+            assert os.listdir(tmp_path / "xla_cache" / "profiles")
             assert (r2["distinct"], r2["generated"]) == \
                 (r1["distinct"], r1["generated"])
             c2.drain()

@@ -81,38 +81,6 @@ def test_res_caps_hint_respected():
     assert ex._res_caps["SC"] >= (1 << 16)
 
 
-def test_warm_start_skips_garbage_and_uses_probe_dir_ck(tmp_path,
-                                                        monkeypatch):
-    # bench._warm_start's source ladder: a garbage committed artifact is
-    # REFUSED by the container integrity checks and the probe-dir copy
-    # from a previous round is used instead — the warm start can never
-    # corrupt the measurement
-    import bench
-    from jaxmc import obs
-    spec = os.path.join(SPECS, "transfer_scaled.tla")
-    cfg = os.path.join(SPECS, "transfer_scaled.cfg")
-    monkeypatch.setattr(bench, "SPEC", spec)
-    monkeypatch.setattr(bench, "CFG_FULL", cfg)
-    monkeypatch.setattr(bench, "_PROBE_DIR", str(tmp_path))
-    garbage = tmp_path / "committed.ck"
-    garbage.write_bytes(b"not a checkpoint at all")
-    monkeypatch.setattr(bench, "_WARM_CK_COMMITTED", str(garbage))
-    # a previous round's scratch checkpoint:
-    scratch = str(tmp_path / "jaxmc_bench_warm_full.ck")
-    TpuExplorer(load("transfer_scaled.tla", "transfer_scaled.cfg"),
-                store_trace=False, resident=True, max_states=600,
-                checkpoint_path=scratch).run()
-    tel = obs.Telemetry()
-    ex = TpuExplorer(load("transfer_scaled.tla", "transfer_scaled.cfg"),
-                     store_trace=False, resident=True)
-    with obs.use(tel):
-        steady, r_warm = bench._warm_start(tel, ex)
-    assert steady is not None and steady["source"] == "probe-dir"
-    assert r_warm is None, "checkpoint resume needs no full warm pass"
-    assert ex.resume_from == scratch and ex.max_states is None
-    assert steady["resumed_generated"] > 0
-
-
 @pytest.mark.slow
 def test_bench_model_warm_resume_parity(tmp_path):
     # the ISSUE 5 acceptance pin on the REAL bench model (needs the
