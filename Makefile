@@ -335,8 +335,7 @@ batch-check:
 # transfer_scaled + symtoy_scaled under `--profile` — per-site walls
 # must attribute >= 90% of the search wall (JAXMC_PROF_CHECK_MIN_SHARE
 # overrides), profile-on vs profile-off counts must be bit-identical,
-# the HBM model must have registered the resident buffers, and the
-# legs' TEMP run ledger must pass `python -m jaxmc.obs history
+# and the legs' TEMP run ledger must pass `python -m jaxmc.obs history
 # --fail-on-regress` (with a synthesized 2x slowdown proven to trip
 # it).  Prints parseable `PROF-CHECK …` lines; SKIPs without jax.
 prof-check:

@@ -199,6 +199,7 @@ def _lsd_sort(key_cols, extra_cols):
     return cols[:nk], cols[nk:]
 
 
+@jax.named_scope("jaxmc.merge.probe")
 def _seen_probe(seen, seen_count, keys, SC):
     """Membership of each key row in the seen table's sorted valid
     prefix — the newness verdict the rank-merge computes, exposed
@@ -216,6 +217,7 @@ def _seen_probe(seen, seen_count, keys, SC):
     return found, lb
 
 
+@jax.named_scope("jaxmc.expand")
 def _por_mask(found, cvalid, inst_arm, arm_safe, A, FC):
     """Device persistent-set filter (ISSUE 18): per frontier slot f,
     pick the FIRST por-safe arm whose successor set is nonempty and
@@ -285,6 +287,7 @@ def _por_mask_np(found, cvalid, inst_arm, arm_safe, A, FC):
     return keep, n_ample, n_expanded
 
 
+@jax.named_scope("jaxmc.merge.scatter")
 def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
     """The O(new) seen-merge core SHARED by the single-chip resident
     level and the mesh rank-merge strategy (ISSUE 10; the
@@ -319,14 +322,15 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
     the single-chip resident engine keeps the LSD chain its compile
     envelope was measured with."""
     sidx = jnp.arange(N, dtype=jnp.int32)
-    if multikey:
-        res = lax.sort(tuple(keys[:, j] for j in range(K)) + (sidx,),
-                       num_keys=K, is_stable=True)
-        kc = list(res[:K])
-        sidx_s = res[K]
-    else:
-        kc, ec = _lsd_sort([keys[:, j] for j in range(K)], [sidx])
-        sidx_s = ec[0]
+    with jax.named_scope("jaxmc.merge.sort"):
+        if multikey:
+            res = lax.sort(tuple(keys[:, j] for j in range(K)) + (sidx,),
+                           num_keys=K, is_stable=True)
+            kc = list(res[:K])
+            sidx_s = res[K]
+        else:
+            kc, ec = _lsd_sort([keys[:, j] for j in range(K)], [sidx])
+            sidx_s = ec[0]
     skeys = jnp.stack(kc, axis=1)
     svalid = skeys[:, 0] == 0
     neq_prev = jnp.concatenate([
@@ -1409,6 +1413,7 @@ class TpuExplorer:
             # (jnp.stack refuses empty lists; shapes stay [0, FC(, W)])
             W = self.W
 
+            @jax.named_scope("jaxmc.expand")
             def expand_none(frontier):
                 FC = frontier.shape[0]
                 z = jnp.zeros((0, FC), bool)
@@ -1418,6 +1423,7 @@ class TpuExplorer:
 
             return expand_none
 
+        @jax.named_scope("jaxmc.expand")
         def expand(frontier):
             ens, aoks, ovs, succs = [], [], [], []
             for ca in acts:
@@ -1584,6 +1590,7 @@ class TpuExplorer:
         return [SYMMETRY_WARNING + (f" ({self._sym_fallback})"
                                     if self._sym_fallback else "")]
 
+    @jax.named_scope("jaxmc.keys")
     def _keys_of(self, rows, valid):
         """(keys, packed_rows, pack_ovf) for a block of UNPACKED rows.
 
@@ -1768,22 +1775,23 @@ class TpuExplorer:
 
         @partial(jax.jit, donate_argnums=donate)
         def step(seen_keys, seen_count, frontier_p, fcount):
-            frontier = plan.unpack_rows(frontier_p)
-            fvalid = jnp.arange(FC) < fcount
-            en, aok, ov, succ = expand(frontier)
-            valid = en & fvalid[None, :]
-            assert_bad = (~aok) & fvalid[None, :]
-            # ov carries the int overflow CODE (kernel2.OV_*): keep the
-            # max so the engine can tell demotion aborts from capacity
-            overflow = jnp.where(fvalid[None, :], ov, 0)
-            dead = fvalid & ~jnp.any(en, axis=0)
-            gen = jnp.sum(valid)
+            with jax.named_scope("jaxmc.expand"):
+                frontier = plan.unpack_rows(frontier_p)
+                fvalid = jnp.arange(FC) < fcount
+                en, aok, ov, succ = expand(frontier)
+                valid = en & fvalid[None, :]
+                assert_bad = (~aok) & fvalid[None, :]
+                # ov carries the int overflow CODE (kernel2.OV_*): keep the
+                # max so the engine can tell demotion aborts from capacity
+                overflow = jnp.where(fvalid[None, :], ov, 0)
+                dead = fvalid & ~jnp.any(en, axis=0)
+                gen = jnp.sum(valid)
 
-            C = A * FC
-            cand_u = succ.reshape(C, W)
-            cvalid = valid.reshape(C)
-            prov = jnp.arange(C, dtype=jnp.int32)
-            cand_u = jnp.where(cvalid[:, None], cand_u, SENTINEL)
+                C = A * FC
+                cand_u = succ.reshape(C, W)
+                cvalid = valid.reshape(C)
+                prov = jnp.arange(C, dtype=jnp.int32)
+                cand_u = jnp.where(cvalid[:, None], cand_u, SENTINEL)
             ckeys, cand, pack_ovf = keys_of(cand_u, cvalid)
 
             por_ample = por_expanded = por_masked = jnp.int32(0)
@@ -1797,14 +1805,15 @@ class TpuExplorer:
                 found, _ = _seen_probe(seen_keys, seen_count, ckeys, SC)
                 keep, por_ample, por_expanded = _por_mask(
                     found, cvalid, por_inst, por_safe_v, A, FC)
-                por_masked = jnp.sum(cvalid & ~keep, dtype=jnp.int32)
-                inv_key = jnp.concatenate([
-                    jnp.ones((C, 1), jnp.int32),
-                    jnp.full((C, K - 1), SENTINEL, jnp.int32)], axis=1)
-                ckeys = jnp.where(keep[:, None], ckeys, inv_key)
-                cand_u = jnp.where(keep[:, None], cand_u, SENTINEL)
-                cvalid = keep
-                gen = jnp.sum(keep)
+                with jax.named_scope("jaxmc.expand"):
+                    por_masked = jnp.sum(cvalid & ~keep, dtype=jnp.int32)
+                    inv_key = jnp.concatenate([
+                        jnp.ones((C, 1), jnp.int32),
+                        jnp.full((C, K - 1), SENTINEL, jnp.int32)], axis=1)
+                    ckeys = jnp.where(keep[:, None], ckeys, inv_key)
+                    cand_u = jnp.where(keep[:, None], cand_u, SENTINEL)
+                    cvalid = keep
+                    gen = jnp.sum(keep)
 
             if rank:
                 # O(new): sort only the C candidate keys, dedup against
@@ -1821,91 +1830,96 @@ class TpuExplorer:
                 seen2 = rm["seen2"]
                 seen_count2 = rm["seen_count2"]
             else:
-                # argsort on keys only, then gather payloads by
-                # permutation — a variadic sort carrying all W lanes
-                # compiles and runs far slower than sort(keys, index) +
-                # take
-                allk = jnp.concatenate([seen_keys, ckeys])   # [SC+C, K]
-                flag = jnp.concatenate([
-                    jnp.zeros(SC, jnp.int32), jnp.ones(C, jnp.int32)])
-                idx0 = jnp.arange(SC + C, dtype=jnp.int32)
-                ops = tuple(allk[:, i] for i in range(K)) + (flag, idx0)
-                sorted_ = lax.sort(ops, num_keys=K + 1, is_stable=True)
-                skeys = jnp.stack(sorted_[:K], axis=1)
-                sflag = sorted_[K]
-                perm = sorted_[K + 1]
-                # candidate payload indices: position in cand (<0: seen)
-                cidx = perm - SC  # >=0 only for candidate entries
-                rvalid = skeys[:, 0] == 0
-                neq_prev = jnp.concatenate([
-                    jnp.array([True]),
-                    jnp.any(skeys[1:] != skeys[:-1], axis=1)])
-                new = (sflag == 1) & rvalid & neq_prev
-                new_count = jnp.sum(new)
+                with jax.named_scope("jaxmc.merge.sort"):
+                    # argsort on keys only, then gather payloads by
+                    # permutation — a variadic sort carrying all W lanes
+                    # compiles and runs far slower than sort(keys, index) +
+                    # take
+                    allk = jnp.concatenate([seen_keys, ckeys])   # [SC+C, K]
+                    flag = jnp.concatenate([
+                        jnp.zeros(SC, jnp.int32), jnp.ones(C, jnp.int32)])
+                    idx0 = jnp.arange(SC + C, dtype=jnp.int32)
+                    ops = tuple(allk[:, i] for i in range(K)) + (flag, idx0)
+                    sorted_ = lax.sort(ops, num_keys=K + 1, is_stable=True)
+                    skeys = jnp.stack(sorted_[:K], axis=1)
+                    sflag = sorted_[K]
+                    perm = sorted_[K + 1]
+                    # candidate payload indices: position in cand (<0: seen)
+                    cidx = perm - SC  # >=0 only for candidate entries
+                    rvalid = skeys[:, 0] == 0
+                    neq_prev = jnp.concatenate([
+                        jnp.array([True]),
+                        jnp.any(skeys[1:] != skeys[:-1], axis=1)])
+                    new = (sflag == 1) & rvalid & neq_prev
+                    new_count = jnp.sum(new)
 
-                # compact new entries to the front (stable, keeps key
-                # order)
-                ops2 = ((1 - new.astype(jnp.int32)), cidx)
-                comp = lax.sort(ops2, num_keys=1, is_stable=True)
-                new_cidx = comp[1][:C]
-                safe_cidx = jnp.clip(new_cidx, 0, C - 1)
+                    # compact new entries to the front (stable, keeps key
+                    # order)
+                    ops2 = ((1 - new.astype(jnp.int32)), cidx)
+                    comp = lax.sort(ops2, num_keys=1, is_stable=True)
+                    new_cidx = comp[1][:C]
+                    safe_cidx = jnp.clip(new_cidx, 0, C - 1)
 
-                # merged seen keys, compacted and sorted
-                keep = ((sflag == 0) & rvalid) | new
-                ops3 = ((1 - keep.astype(jnp.int32)),) + \
-                    tuple(skeys[:, i] for i in range(K))
-                comp3 = lax.sort(ops3, num_keys=1, is_stable=True)
-                seen2 = jnp.stack(comp3[1:], axis=1)[:SC]
-                seen_count2 = jnp.sum(keep)
+                    # merged seen keys, compacted and sorted
+                    keep = ((sflag == 0) & rvalid) | new
+                    ops3 = ((1 - keep.astype(jnp.int32)),) + \
+                        tuple(skeys[:, i] for i in range(K))
+                    comp3 = lax.sort(ops3, num_keys=1, is_stable=True)
+                    seen2 = jnp.stack(comp3[1:], axis=1)[:SC]
+                    seen_count2 = jnp.sum(keep)
 
-            new_rows = jnp.take(cand, safe_cidx, axis=0)      # packed
-            new_rows_u = jnp.take(cand_u, safe_cidx, axis=0)  # lanes
-            new_prov = jnp.take(prov, safe_cidx)
-            nvalid = jnp.arange(C) < new_count
-            new_rows = jnp.where(nvalid[:, None], new_rows, SENTINEL)
+            with jax.named_scope("jaxmc.compact"):
+                new_rows = jnp.take(cand, safe_cidx, axis=0)      # packed
+                new_rows_u = jnp.take(cand_u, safe_cidx, axis=0)  # lanes
+                new_prov = jnp.take(prov, safe_cidx)
+                nvalid = jnp.arange(C) < new_count
+                new_rows = jnp.where(nvalid[:, None], new_rows, SENTINEL)
 
-            # constraints FIRST: violating states are fingerprinted (they
-            # are in seen2 above) but discarded — never counted distinct,
-            # never invariant-checked, never explored. TLC semantics,
-            # pinned by the golden run (testout2:265, 195 distinct)
-            explore = nvalid
-            for nm, f in con_fns:
-                explore = explore & jax.vmap(f)(new_rows_u)
-            explore_count = jnp.sum(explore)
-            # the next frontier is ordered by PROVENANCE (frontier-slot
-            # major, action minor — the interpreter's discovery order),
-            # not by dedup-key order: key order depends on the packed
-            # encoding, so ordering by it would let the bit layout pick
-            # WHICH equally-short counterexample gets reported (packed
-            # and unpacked runs must produce identical traces)
-            fmaj = (new_prov % FC) * jnp.int32(max(A, 1)) + \
-                new_prov // FC
-            idx4 = jnp.arange(C, dtype=jnp.int32)
-            ops4 = ((1 - explore.astype(jnp.int32)), fmaj, idx4)
-            comp4 = lax.sort(ops4, num_keys=2, is_stable=True)
-            perm4 = comp4[2]
-            front_rows = jnp.take(new_rows, perm4, axis=0)
-            front_rows_u = jnp.take(new_rows_u, perm4, axis=0)
-            front_prov = jnp.take(new_prov, perm4)
-            frontvalid = jnp.arange(C) < explore_count
-            front_keys = None
-            if tiered:
-                new_keys = jnp.take(ckeys, safe_cidx, axis=0)
-                front_keys = jnp.take(new_keys, perm4, axis=0)
+            with jax.named_scope("jaxmc.scan"):
+                # constraints FIRST: violating states are fingerprinted (they
+                # are in seen2 above) but discarded — never counted distinct,
+                # never invariant-checked, never explored. TLC semantics,
+                # pinned by the golden run (testout2:265, 195 distinct)
+                explore = nvalid
+                for nm, f in con_fns:
+                    explore = explore & jax.vmap(f)(new_rows_u)
+                explore_count = jnp.sum(explore)
+            with jax.named_scope("jaxmc.compact"):
+                # the next frontier is ordered by PROVENANCE (frontier-slot
+                # major, action minor — the interpreter's discovery order),
+                # not by dedup-key order: key order depends on the packed
+                # encoding, so ordering by it would let the bit layout pick
+                # WHICH equally-short counterexample gets reported (packed
+                # and unpacked runs must produce identical traces)
+                fmaj = (new_prov % FC) * jnp.int32(max(A, 1)) + \
+                    new_prov // FC
+                idx4 = jnp.arange(C, dtype=jnp.int32)
+                ops4 = ((1 - explore.astype(jnp.int32)), fmaj, idx4)
+                comp4 = lax.sort(ops4, num_keys=2, is_stable=True)
+                perm4 = comp4[2]
+                front_rows = jnp.take(new_rows, perm4, axis=0)
+                front_rows_u = jnp.take(new_rows_u, perm4, axis=0)
+                front_prov = jnp.take(new_prov, perm4)
+                frontvalid = jnp.arange(C) < explore_count
+                front_keys = None
+                if tiered:
+                    new_keys = jnp.take(ckeys, safe_cidx, axis=0)
+                    front_keys = jnp.take(new_keys, perm4, axis=0)
 
-            # invariants over the kept (explored) states only
-            inv_bad_any = jnp.asarray(False)
-            inv_bad_idx = jnp.asarray(0, jnp.int32)
-            inv_bad_which = jnp.asarray(-1, jnp.int32)
-            for wi, (nm, f) in enumerate(inv_fns):
-                ok = jax.vmap(f)(front_rows_u)
-                bad = frontvalid & ~ok
-                any_ = jnp.any(bad)
-                idx = jnp.argmax(bad)
-                first = jnp.logical_and(any_, ~inv_bad_any)
-                inv_bad_idx = jnp.where(first, idx, inv_bad_idx)
-                inv_bad_which = jnp.where(first, wi, inv_bad_which)
-                inv_bad_any = inv_bad_any | any_
+            with jax.named_scope("jaxmc.scan"):
+                # invariants over the kept (explored) states only
+                inv_bad_any = jnp.asarray(False)
+                inv_bad_idx = jnp.asarray(0, jnp.int32)
+                inv_bad_which = jnp.asarray(-1, jnp.int32)
+                for wi, (nm, f) in enumerate(inv_fns):
+                    ok = jax.vmap(f)(front_rows_u)
+                    bad = frontvalid & ~ok
+                    any_ = jnp.any(bad)
+                    idx = jnp.argmax(bad)
+                    first = jnp.logical_and(any_, ~inv_bad_any)
+                    inv_bad_idx = jnp.where(first, idx, inv_bad_idx)
+                    inv_bad_which = jnp.where(first, wi, inv_bad_which)
+                    inv_bad_any = inv_bad_any | any_
 
             # kernel overflow codes outrank the pack guard: OV_DEMOTED
             # must reach the engine so the hybrid restart can fire
@@ -1926,9 +1940,10 @@ class TpuExplorer:
             if front_keys is not None:
                 out["front_keys"] = front_keys
             if need_edges:
-                exp_all = cvalid
-                for nm, f in con_fns:
-                    exp_all = exp_all & jax.vmap(f)(cand_u)
+                with jax.named_scope("jaxmc.scan"):
+                    exp_all = cvalid
+                    for nm, f in con_fns:
+                        exp_all = exp_all & jax.vmap(f)(cand_u)
                 out["cand"] = cand
                 out["cvalid"] = cvalid
                 out["explore_all"] = exp_all
@@ -2314,46 +2329,48 @@ class TpuExplorer:
             def chunk_body(carry):
                 (ci, acc_keys, acc_rows, acc_n, gen, stat,
                  bad_row, ovcode, pora, porx, porm) = carry
-                base = ci * CH
-                chunk_p = lax.dynamic_slice(frontier, (base, 0),
-                                            (CH, PW))
-                chunk = plan.unpack_rows(chunk_p)
-                fvalid = (jnp.arange(CH) + base) < fcount
-                en, aok, ov, succ = expand(chunk)
-                valid = en & fvalid[None, :]
-                gen = gen + jnp.sum(valid, dtype=jnp.int32)
+                with jax.named_scope("jaxmc.expand"):
+                    base = ci * CH
+                    chunk_p = lax.dynamic_slice(frontier, (base, 0),
+                                                (CH, PW))
+                    chunk = plan.unpack_rows(chunk_p)
+                    fvalid = (jnp.arange(CH) + base) < fcount
+                    en, aok, ov, succ = expand(chunk)
+                    valid = en & fvalid[None, :]
+                    gen = gen + jnp.sum(valid, dtype=jnp.int32)
 
-                # lane-capacity overflow inside an enabled action: abort.
-                # The max OV_* CODE rides along so the host can tell a
-                # compile-recovery demotion (OV_DEMOTED — raise no caps,
-                # run host_seen) from a real capacity overflow
-                ov_codes = jnp.where(fvalid[None, :], ov, 0)
-                ovf_lanes = jnp.any(ov_codes != 0)
-                ovcode = jnp.maximum(ovcode,
-                                     jnp.max(ov_codes).astype(jnp.int32))
-                # Assert(FALSE) inside an enabled action
-                abad = (~aok) & fvalid[None, :]
-                assert_any = jnp.any(abad)
-                a_f = jnp.argmax(abad.reshape(-1)) % CH
-                # deadlock: a frontier state with no enabled action at all
-                dead = fvalid & ~jnp.any(en, axis=0)
-                dead_any = check_deadlock & jnp.any(dead)
-                d_f = jnp.argmax(dead)
+                    # lane-capacity overflow inside an enabled action: abort.
+                    # The max OV_* CODE rides along so the host can tell a
+                    # compile-recovery demotion (OV_DEMOTED — raise no caps,
+                    # run host_seen) from a real capacity overflow
+                    ov_codes = jnp.where(fvalid[None, :], ov, 0)
+                    ovf_lanes = jnp.any(ov_codes != 0)
+                    ovcode = jnp.maximum(ovcode,
+                                         jnp.max(ov_codes).astype(jnp.int32))
+                    # Assert(FALSE) inside an enabled action
+                    abad = (~aok) & fvalid[None, :]
+                    assert_any = jnp.any(abad)
+                    a_f = jnp.argmax(abad.reshape(-1)) % CH
+                    # deadlock: a frontier state with no enabled action at all
+                    dead = fvalid & ~jnp.any(en, axis=0)
+                    dead_any = check_deadlock & jnp.any(dead)
+                    d_f = jnp.argmax(dead)
 
-                cand = succ.reshape(C, W)
-                cvalid = valid.reshape(C)
-                vcnt = jnp.sum(cvalid, dtype=jnp.int32)
-                # compact valid candidates to a VC-bounded block before
-                # hashing: ~95% of the dense (state x action) grid is
-                # disabled, so hashing only the survivors is the win
-                ops = ((1 - cvalid.astype(jnp.int32)),
-                       jnp.arange(C, dtype=jnp.int32))
-                comp = lax.sort(ops, num_keys=1, is_stable=True)
-                cidx = comp[1][:VC]
-                rows_cu = jnp.take(cand, jnp.clip(cidx, 0, C - 1),
-                                   axis=0)
-                vmask = jnp.arange(VC) < vcnt
-                rows_cu = jnp.where(vmask[:, None], rows_cu, SENTINEL)
+                with jax.named_scope("jaxmc.compact"):
+                    cand = succ.reshape(C, W)
+                    cvalid = valid.reshape(C)
+                    vcnt = jnp.sum(cvalid, dtype=jnp.int32)
+                    # compact valid candidates to a VC-bounded block before
+                    # hashing: ~95% of the dense (state x action) grid is
+                    # disabled, so hashing only the survivors is the win
+                    ops = ((1 - cvalid.astype(jnp.int32)),
+                           jnp.arange(C, dtype=jnp.int32))
+                    comp = lax.sort(ops, num_keys=1, is_stable=True)
+                    cidx = comp[1][:VC]
+                    rows_cu = jnp.take(cand, jnp.clip(cidx, 0, C - 1),
+                                       axis=0)
+                    vmask = jnp.arange(VC) < vcnt
+                    rows_cu = jnp.where(vmask[:, None], rows_cu, SENTINEL)
                 keys_c, rows_c, pack_ovf = keys_of(rows_cu, vmask)
                 # pack-guard overflow aborts exactly like a lane
                 # overflow (OV_PACK: the host names JAXMC_PACK=0);
@@ -2366,42 +2383,44 @@ class TpuExplorer:
                     ovcode)
 
                 if por:
-                    # persistent-set filter (ISSUE 18): probe the
-                    # compacted candidate keys against the pre-level
-                    # seen prefix, scatter the verdicts back onto the
-                    # dense [A, CH] grid, mask every non-ample arm's
-                    # candidates.  Deadlock/assert above read PRE-mask
-                    # enabledness; gen drops to the reduced stream.
-                    found_c, _ = _seen_probe(seen, seen_count, keys_c,
-                                             SC)
-                    found_g = jnp.zeros(C, dtype=bool).at[cidx].set(
-                        found_c & vmask, mode="drop",
-                        unique_indices=True)
-                    keep_g, n_amp, n_exp = _por_mask(
-                        found_g, cvalid, por_inst, por_safe_v, A, CH)
-                    keep_c = jnp.take(keep_g, jnp.clip(cidx, 0, C - 1)) \
-                        & vmask
-                    n_masked = jnp.sum(vmask & ~keep_c,
-                                       dtype=jnp.int32)
-                    inv_key = jnp.concatenate([
-                        jnp.ones((VC, 1), jnp.int32),
-                        jnp.full((VC, K - 1), SENTINEL, jnp.int32)],
-                        axis=1)
-                    keys_c = jnp.where(keep_c[:, None], keys_c, inv_key)
-                    rows_c = jnp.where(keep_c[:, None], rows_c, SENTINEL)
-                    gen = gen - n_masked
-                    pora = pora + n_amp
-                    porx = porx + n_exp
-                    porm = porm + n_masked
+                    with jax.named_scope("jaxmc.expand"):
+                        # persistent-set filter (ISSUE 18): probe the
+                        # compacted candidate keys against the pre-level
+                        # seen prefix, scatter the verdicts back onto the
+                        # dense [A, CH] grid, mask every non-ample arm's
+                        # candidates.  Deadlock/assert above read PRE-mask
+                        # enabledness; gen drops to the reduced stream.
+                        found_c, _ = _seen_probe(seen, seen_count, keys_c,
+                                                 SC)
+                        found_g = jnp.zeros(C, dtype=bool).at[cidx].set(
+                            found_c & vmask, mode="drop",
+                            unique_indices=True)
+                        keep_g, n_amp, n_exp = _por_mask(
+                            found_g, cvalid, por_inst, por_safe_v, A, CH)
+                        keep_c = jnp.take(keep_g, jnp.clip(cidx, 0, C - 1)) \
+                            & vmask
+                        n_masked = jnp.sum(vmask & ~keep_c,
+                                           dtype=jnp.int32)
+                        inv_key = jnp.concatenate([
+                            jnp.ones((VC, 1), jnp.int32),
+                            jnp.full((VC, K - 1), SENTINEL, jnp.int32)],
+                            axis=1)
+                        keys_c = jnp.where(keep_c[:, None], keys_c, inv_key)
+                        rows_c = jnp.where(keep_c[:, None], rows_c, SENTINEL)
+                        gen = gen - n_masked
+                        pora = pora + n_amp
+                        porx = porx + n_exp
+                        porm = porm + n_masked
 
-                # append the block at acc_n (clamped; overflow redoes the
-                # level so clobbered rows never count)
-                off = jnp.clip(acc_n, 0, AccCap - VC)
-                acc_keys = lax.dynamic_update_slice(acc_keys, keys_c,
-                                                    (off, 0))
-                acc_rows = lax.dynamic_update_slice(acc_rows, rows_c,
-                                                    (off, 0))
-                acc_n = acc_n + vcnt
+                with jax.named_scope("jaxmc.compact"):
+                    # append the block at acc_n (clamped; overflow redoes the
+                    # level so clobbered rows never count)
+                    off = jnp.clip(acc_n, 0, AccCap - VC)
+                    acc_keys = lax.dynamic_update_slice(acc_keys, keys_c,
+                                                        (off, 0))
+                    acc_rows = lax.dynamic_update_slice(acc_rows, rows_c,
+                                                        (off, 0))
+                    acc_n = acc_n + vcnt
 
                 stat = jnp.where(
                     stat != ST_CONTINUE, stat,
@@ -2435,9 +2454,10 @@ class TpuExplorer:
                 ci, _, _, _, _, stat, _, _, _, _, _ = carry
                 return (ci < nchunks) & (stat == ST_CONTINUE)
 
-            acc_keys0 = jnp.full((AccCap, K), SENTINEL, jnp.int32)
-            acc_rows0 = jnp.full((AccCap, PW), SENTINEL, jnp.int32)
-            bad_row0 = jnp.full((PW,), SENTINEL, jnp.int32)
+            with jax.named_scope("jaxmc.compact"):
+                acc_keys0 = jnp.full((AccCap, K), SENTINEL, jnp.int32)
+                acc_rows0 = jnp.full((AccCap, PW), SENTINEL, jnp.int32)
+                bad_row0 = jnp.full((PW,), SENTINEL, jnp.int32)
             (_, acc_keys, acc_rows, acc_n, gen, stat, bad_row,
              ovcode, pora, porx, porm) = \
                 lax.while_loop(chunk_cond, chunk_body,
@@ -2460,53 +2480,57 @@ class TpuExplorer:
             # vectorized binary searches + scatters), so the sort work
             # is O(new), not O(seen), per level.
             rm = _rank_merge(seen, seen_count, acc_keys, AccCap, SC, K)
-            new_count = rm["new_count"]
-            nvalid = jnp.arange(AccCap) < new_count
-            new_rows = jnp.take(acc_rows,
-                                jnp.clip(rm["nk_sidx"], 0, AccCap - 1),
-                                axis=0)
-            new_rows = jnp.where(nvalid[:, None], new_rows, SENTINEL)
+            with jax.named_scope("jaxmc.compact"):
+                new_count = rm["new_count"]
+                nvalid = jnp.arange(AccCap) < new_count
+                new_rows = jnp.take(acc_rows,
+                                    jnp.clip(rm["nk_sidx"], 0, AccCap - 1),
+                                    axis=0)
+                new_rows = jnp.where(nvalid[:, None], new_rows, SENTINEL)
             seen2 = rm["seen2"]
             seen_count2 = rm["seen_count2"]
 
-            # constraints: violating states stay fingerprinted in seen2
-            # but are discarded (not distinct / checked / explored).
-            # new_rows are PACKED; the predicate kernels read lanes
-            new_rows_u = plan.unpack_rows(new_rows) \
-                if (con_fns or inv_fns) else new_rows
-            explore = nvalid
-            for nm, f in con_fns:
-                explore = explore & jax.vmap(f)(new_rows_u)
-            explore_count = jnp.sum(explore, dtype=jnp.int32)
+            with jax.named_scope("jaxmc.scan"):
+                # constraints: violating states stay fingerprinted in seen2
+                # but are discarded (not distinct / checked / explored).
+                # new_rows are PACKED; the predicate kernels read lanes
+                new_rows_u = plan.unpack_rows(new_rows) \
+                    if (con_fns or inv_fns) else new_rows
+                explore = nvalid
+                for nm, f in con_fns:
+                    explore = explore & jax.vmap(f)(new_rows_u)
+                explore_count = jnp.sum(explore, dtype=jnp.int32)
             stat = jnp.where((stat == ST_CONTINUE) &
                              (explore_count > FCap), ST_OVF_FRONT, stat)
 
-            idx4 = jnp.arange(AccCap, dtype=jnp.int32)
-            ops4 = ((1 - explore.astype(jnp.int32)), idx4)
-            comp4 = lax.sort(ops4, num_keys=1, is_stable=True)
-            fidx = comp4[1][:FCap]
-            front_rows = jnp.take(new_rows,
-                                  jnp.clip(fidx, 0, AccCap - 1), axis=0)
-            frontvalid = jnp.arange(FCap) < explore_count
-            front_rows = jnp.where(frontvalid[:, None], front_rows,
-                                   SENTINEL)
+            with jax.named_scope("jaxmc.compact"):
+                idx4 = jnp.arange(AccCap, dtype=jnp.int32)
+                ops4 = ((1 - explore.astype(jnp.int32)), idx4)
+                comp4 = lax.sort(ops4, num_keys=1, is_stable=True)
+                fidx = comp4[1][:FCap]
+                front_rows = jnp.take(new_rows,
+                                      jnp.clip(fidx, 0, AccCap - 1), axis=0)
+                frontvalid = jnp.arange(FCap) < explore_count
+                front_rows = jnp.where(frontvalid[:, None], front_rows,
+                                       SENTINEL)
 
-            inv_bad_any = jnp.asarray(False)
-            inv_bad_idx = jnp.asarray(0, jnp.int32)
-            inv_bad_which = jnp.asarray(-1, jnp.int32)
-            front_rows_u = plan.unpack_rows(front_rows) if inv_fns \
-                else front_rows
-            for wi, (nm, f) in enumerate(inv_fns):
-                ok = jax.vmap(f)(front_rows_u)
-                bad = frontvalid & ~ok
-                any_ = jnp.any(bad)
-                idx = jnp.argmax(bad).astype(jnp.int32)
-                first = jnp.logical_and(any_, ~inv_bad_any)
-                inv_bad_idx = jnp.where(first, idx, inv_bad_idx)
-                inv_bad_which = jnp.where(first, wi, inv_bad_which)
-                inv_bad_any = inv_bad_any | any_
-            inv_row = lax.dynamic_slice(front_rows, (inv_bad_idx, 0),
-                                        (1, PW))[0]
+            with jax.named_scope("jaxmc.scan"):
+                inv_bad_any = jnp.asarray(False)
+                inv_bad_idx = jnp.asarray(0, jnp.int32)
+                inv_bad_which = jnp.asarray(-1, jnp.int32)
+                front_rows_u = plan.unpack_rows(front_rows) if inv_fns \
+                    else front_rows
+                for wi, (nm, f) in enumerate(inv_fns):
+                    ok = jax.vmap(f)(front_rows_u)
+                    bad = frontvalid & ~ok
+                    any_ = jnp.any(bad)
+                    idx = jnp.argmax(bad).astype(jnp.int32)
+                    first = jnp.logical_and(any_, ~inv_bad_any)
+                    inv_bad_idx = jnp.where(first, idx, inv_bad_idx)
+                    inv_bad_which = jnp.where(first, wi, inv_bad_which)
+                    inv_bad_any = inv_bad_any | any_
+                inv_row = lax.dynamic_slice(front_rows, (inv_bad_idx, 0),
+                                            (1, PW))[0]
             bad_row = jnp.where(inv_bad_any & (stat == ST_CONTINUE),
                                 inv_row, bad_row)
             stat = jnp.where((stat == ST_CONTINUE) & inv_bad_any,
@@ -2960,8 +2984,9 @@ class TpuExplorer:
         warnings.extend(self._symmetry_warnings())
         warnings.extend(self._por_warnings())
 
-        init_rows, explored_init, n_init, err = \
-            self._prepare_init(t0, warnings)
+        with tel.span("search.init"):
+            init_rows, explored_init, n_init, err = \
+                self._prepare_init(t0, warnings)
         if err is not None:
             return err
         generated = n_init
@@ -3014,18 +3039,6 @@ class TpuExplorer:
         # slice of the accumulator taken for the next frontier
         caps["VC"] = min(caps["VC"], self.A * CH)
         caps["AccCap"] = max(caps["AccCap"], 2 * caps["VC"], caps["FCap"])
-        # HBM model (ISSUE 17): the caps ARE the device buffers the
-        # resident loop carries — registered here and again after every
-        # growth, so the profiler's hbm_peak_bytes watermark tracks it
-        def note_caps():
-            obs.note_buffer("resident.seen", caps["SC"] * self.K * 4)
-            obs.note_buffer("resident.frontier",
-                            caps["FCap"] * self.PW * 4)
-            obs.note_buffer("resident.accumulator",
-                            caps["AccCap"] * (self.K + self.PW) * 4)
-            obs.note_buffer("resident.candidates",
-                            caps["VC"] * (self.K + self.PW) * 4)
-        note_caps()
         # levels per dispatch: the host only sees status (and can only
         # checkpoint / log progress) between dispatches, so maxlvl adapts
         # to measured dispatch wall time — targeting the tighter of
@@ -3050,24 +3063,26 @@ class TpuExplorer:
 
         # packed init boundary: keys + packed rows in one pass; a pack
         # overflow at init is an observation gap (abort exactly)
-        init_keys, init_packed, init_povf = self._host_keys(init_rows)
-        if init_povf:
-            return self._mk_result(
-                False, distinct, generated, 0, t0, warnings,
-                Violation("error", "capacity overflow", [],
-                          self._pack_ovf_msg()))
-        frontier = np.full((caps["FCap"], self.PW), SENTINEL, np.int32)
-        frontier[:distinct] = init_packed[explored_init]
-        frontier = jnp.asarray(frontier)
-        fcount = distinct
+        with tel.span("search.seed"):
+            init_keys, init_packed, init_povf = self._host_keys(init_rows)
+            if init_povf:
+                return self._mk_result(
+                    False, distinct, generated, 0, t0, warnings,
+                    Violation("error", "capacity overflow", [],
+                              self._pack_ovf_msg()))
+            frontier = np.full((caps["FCap"], self.PW), SENTINEL,
+                               np.int32)
+            frontier[:distinct] = init_packed[explored_init]
+            frontier = jnp.asarray(frontier)
+            fcount = distinct
 
-        seen = np.full((caps["SC"], K), SENTINEL, np.int32)
-        if n_init:
-            order = np.lexsort(tuple(init_keys[:, i]
-                                     for i in reversed(range(K))))
-            seen[:n_init] = init_keys[order]
-        seen = jnp.asarray(seen)
-        seen_count = n_init
+            seen = np.full((caps["SC"], K), SENTINEL, np.int32)
+            if n_init:
+                order = np.lexsort(tuple(init_keys[:, i]
+                                         for i in reversed(range(K))))
+                seen[:n_init] = init_keys[order]
+            seen = jnp.asarray(seen)
+            seen_count = n_init
 
         depth = 0
         if self.resume_from:
@@ -3116,12 +3131,14 @@ class TpuExplorer:
                 return self._mk_result(True, distinct, generated,
                                        depth - 1, t0, warnings)
 
-        max_states = jnp.int32(self.max_states or 0)
-        gen_lo = int(np.int32(np.uint32(generated & 0xFFFFFFFF)))
-        gen_hi = generated >> 32
-        state = (seen, jnp.int32(seen_count), frontier, jnp.int32(fcount),
-                 jnp.int32(distinct), jnp.int32(gen_lo), jnp.int32(gen_hi),
-                 jnp.int32(depth))
+        with tel.span("search.seed"):  # the scalar operands' uploads
+            max_states = jnp.int32(self.max_states or 0)
+            gen_lo = int(np.int32(np.uint32(generated & 0xFFFFFFFF)))
+            gen_hi = generated >> 32
+            state = (seen, jnp.int32(seen_count), frontier,
+                     jnp.int32(fcount), jnp.int32(distinct),
+                     jnp.int32(gen_lo), jnp.int32(gen_hi),
+                     jnp.int32(depth))
         grow_flag = {ST_OVF_SEEN: "SC", ST_OVF_FRONT: "FCap",
                      ST_OVF_ACC: "AccCap", ST_OVF_VC: "VC"}
         # first progress line immediately (ISSUE 2): short runs get at
@@ -3158,9 +3175,11 @@ class TpuExplorer:
             # ONE level so the host sees each committed frontier
             eff_maxlvl = 1 if (self._tiers is not None
                                and self._tiers.active) else maxlvl
-            seen, frontier, summary, brow = runf(*state, max_states,
-                                                 jnp.int32(eff_maxlvl))
-            jax.block_until_ready(summary)
+            with tel.span("search.dispatch", maxlvl=eff_maxlvl,
+                          fresh_compile=fresh_compile):
+                seen, frontier, summary, brow = runf(
+                    *state, max_states, jnp.int32(eff_maxlvl))
+                jax.block_until_ready(summary)
             disp_wall = time.time() - t_disp
             # adapt levels-per-dispatch toward the host-attention target;
             # a dispatch that just paid an XLA recompile (cap growth) is
@@ -3172,64 +3191,76 @@ class TpuExplorer:
             elif disp_wall < target_s / 4 and \
                     maxlvl < self._res_maxlvl:
                 maxlvl = min(self._res_maxlvl, maxlvl * 2)
-            summary = np.asarray(summary)
-            fcount_in, gen_in, dist_in = fcount, generated, distinct
-            stat = int(summary[0])
-            seen_count = int(summary[1])
-            fcount = int(summary[2])
-            distinct = int(summary[3])
-            generated = (int(np.uint32(summary[5])) << 32) | \
-                int(np.uint32(summary[4]))
-            depth = int(summary[6])
-            which = int(summary[7])
-            ovcode = int(summary[8])
-            # per-dispatch POR deltas: run() zero-seeds them per
-            # dispatch and rolls back overflowed levels, so summing
-            # across dispatches (including redos) never double-counts
-            self._por_stats["ample"] += int(summary[9])
-            self._por_stats["expanded"] += int(summary[10])
-            self._por_stats["masked"] += int(summary[11])
-            # cold-tier filter (ISSUE 12): after a spill the device
-            # table restarted empty, so a committed level's frontier
-            # may hold rows whose keys live in the host/disk runs —
-            # exactly the rows the uncapped table would have deduped.
-            # Probe and drop them (order-preserving) before counts,
-            # truncation decisions, or the next dispatch see them.
-            # Rolled-back levels (grow statuses) keep their frontier —
-            # it was already filtered when it was admitted.
-            if self._tiers is not None and self._tiers.active and \
-                    fcount > 0 and stat not in grow_flag and \
-                    stat not in (ST_OVF_LANES, ST_DONE):
-                fr_np = np.asarray(frontier[:fcount])
-                keep = self._tier_keep_mask(fr_np)
-                n_dup = int((~keep).sum())
-                if n_dup:
-                    kept_rows = np.ascontiguousarray(fr_np[keep])
-                    distinct -= n_dup
-                    fcount = len(kept_rows)
-                    fr_full = np.full((int(frontier.shape[0]), self.PW),
-                                      SENTINEL, np.int32)
-                    fr_full[:fcount] = kept_rows
-                    frontier = jnp.asarray(fr_full)
-                if stat == ST_TRUNC and self.max_states and \
-                        distinct < self.max_states:
-                    stat = ST_CONTINUE  # phantom limit: dups un-counted
-                if fcount == 0 and stat == ST_CONTINUE:
-                    stat = ST_DONE  # the whole level was cold dups
-                self._tiers.publish_gauges(seen_count)
-            self._res_caps = dict(caps)
-            # one record per DISPATCH (the host only sees level batches
-            # in resident mode): `level` is the depth reached, so indices
-            # stay monotone — equal across an overflow-redo dispatch.
-            # frontier/generated/new keep the other paths' semantics:
-            # frontier going IN, per-dispatch generated/new deltas (so
-            # summing `generated` across records gives the run total)
-            tel.level(depth, dispatch=True, frontier=fcount_in,
-                      generated=generated - gen_in,
-                      new=distinct - dist_in, distinct=distinct,
-                      seen=seen_count, status=stat,
-                      fresh_compile=fresh_compile,
-                      wall_s=round(disp_wall, 6))
+            with tel.span("search.fetch"):
+                summary = np.asarray(summary)
+                fcount_in, gen_in, dist_in, depth_in = \
+                    fcount, generated, distinct, depth
+                stat = int(summary[0])
+                seen_count = int(summary[1])
+                fcount = int(summary[2])
+                distinct = int(summary[3])
+                generated = (int(np.uint32(summary[5])) << 32) | \
+                    int(np.uint32(summary[4]))
+                depth = int(summary[6])
+                which = int(summary[7])
+                ovcode = int(summary[8])
+                # per-dispatch POR deltas: run() zero-seeds them per
+                # dispatch and rolls back overflowed levels, so summing
+                # across dispatches (including redos) never double-counts
+                self._por_stats["ample"] += int(summary[9])
+                self._por_stats["expanded"] += int(summary[10])
+                self._por_stats["masked"] += int(summary[11])
+                # cold-tier filter (ISSUE 12): after a spill the device
+                # table restarted empty, so a committed level's frontier
+                # may hold rows whose keys live in the host/disk runs —
+                # exactly the rows the uncapped table would have deduped.
+                # Probe and drop them (order-preserving) before counts,
+                # truncation decisions, or the next dispatch see them.
+                # Rolled-back levels (grow statuses) keep their frontier —
+                # it was already filtered when it was admitted.
+                if self._tiers is not None and self._tiers.active and \
+                        fcount > 0 and stat not in grow_flag and \
+                        stat not in (ST_OVF_LANES, ST_DONE):
+                    fr_np = np.asarray(frontier[:fcount])
+                    keep = self._tier_keep_mask(fr_np)
+                    n_dup = int((~keep).sum())
+                    if n_dup:
+                        kept_rows = np.ascontiguousarray(fr_np[keep])
+                        distinct -= n_dup
+                        fcount = len(kept_rows)
+                        fr_full = np.full((int(frontier.shape[0]), self.PW),
+                                          SENTINEL, np.int32)
+                        fr_full[:fcount] = kept_rows
+                        frontier = jnp.asarray(fr_full)
+                    if stat == ST_TRUNC and self.max_states and \
+                            distinct < self.max_states:
+                        stat = ST_CONTINUE  # phantom limit: dups un-counted
+                    if fcount == 0 and stat == ST_CONTINUE:
+                        stat = ST_DONE  # the whole level was cold dups
+                    self._tiers.publish_gauges(seen_count)
+                self._res_caps = dict(caps)
+                # one record per DISPATCH (the host only sees level batches
+                # in resident mode): `level` is the depth reached, so indices
+                # stay monotone — equal across an overflow-redo dispatch.
+                # frontier/generated/new keep the other paths' semantics:
+                # frontier going IN, per-dispatch generated/new deltas (so
+                # summing `generated` across records gives the run total)
+                tel.level(depth, dispatch=True, frontier=fcount_in,
+                          generated=generated - gen_in,
+                          new=distinct - dist_in, distinct=distinct,
+                          seen=seen_count, status=stat,
+                          fresh_compile=fresh_compile,
+                          wall_s=round(disp_wall, 6))
+            # work against capacity: every level the dispatch ran sorted
+            # AccCap slots and rewrote SC seen rows, whatever was valid (a
+            # level that ended in a rollback or a verdict ran too, and
+            # left depth where it was)
+            lvls = depth - depth_in + (stat in grow_flag or stat in (
+                ST_OVF_LANES, ST_DEADLOCK, ST_ASSERT))
+            tel.counter("search.slots_sorted", lvls * caps["AccCap"])
+            tel.counter("search.rows_valid", generated - gen_in)
+            tel.counter("search.seen_slots", lvls * caps["SC"])
+            tel.counter("search.rows_new", distinct - dist_in)
             self._fp_occupancy = seen_count
 
             if stat in grow_flag:
@@ -3296,7 +3327,6 @@ class TpuExplorer:
                 # the accumulator)
                 caps["AccCap"] = max(caps["AccCap"], 2 * caps["VC"],
                                      caps["FCap"])
-                note_caps()
                 self.log(f"-- resident: growing {what} to {caps[what]} "
                          f"(level {depth} redone)")
             elif stat == ST_CONTINUE:
@@ -3317,40 +3347,41 @@ class TpuExplorer:
                         distinct=distinct, generated=generated,
                         depth=depth)
             elif stat == ST_DONE:
-                # remember enough levels-per-dispatch to cover the whole
-                # search in ONE dispatch on a warm re-run (tiny models:
-                # per-dispatch overhead dominated the r04 inversion)
-                self._res_maxlvl_warm = min(
-                    max(depth + 1, maxlvl), self._res_maxlvl)
-                self.log("Model checking completed. No error has been "
-                         "found.")
-                self.log(f"{generated} states generated, {distinct} "
-                         f"distinct states found, 0 states left on queue.")
-                self.log(f"The depth of the complete state graph search "
-                         f"is {depth}.")
-                if self._tiers is not None and self._tiers.active:
-                    # tier sizes are LEARNED per (module, layout_sig,
-                    # platform) like SC/FCap: persist the cold-tier
-                    # key total so the next run on this engine knows
-                    # the out-of-core magnitude up front
-                    self._save_caps_profile(
-                        dict(caps, TIERK=_pow2_at_least(
-                            max(len(self._tiers), 1), lo=256)),
-                        optional=("TIERK",))
-                else:
-                    self._save_caps_profile(caps)
-                if self.checkpoint_path and self.final_checkpoint:
-                    # COMPLETED-run checkpoint (serve warm resume): an
-                    # empty frontier over the full seen set — resuming
-                    # it replays the stored totals in one dispatch
-                    self._write_ck(
-                        "resident", caps=dict(caps),
-                        seen=np.asarray(seen[:seen_count]),
-                        frontier=np.zeros((0, self.PW), np.int32),
-                        distinct=distinct, generated=generated,
-                        depth=depth)
-                return self._mk_result(True, distinct, generated,
-                                       depth - 1, t0, warnings)
+                with tel.span("search.finish"):
+                    # remember enough levels-per-dispatch to cover the whole
+                    # search in ONE dispatch on a warm re-run (tiny models:
+                    # per-dispatch overhead dominated the r04 inversion)
+                    self._res_maxlvl_warm = min(
+                        max(depth + 1, maxlvl), self._res_maxlvl)
+                    self.log("Model checking completed. No error has been "
+                             "found.")
+                    self.log(f"{generated} states generated, {distinct} "
+                             f"distinct states found, 0 states left on queue.")
+                    self.log(f"The depth of the complete state graph search "
+                             f"is {depth}.")
+                    if self._tiers is not None and self._tiers.active:
+                        # tier sizes are LEARNED per (module, layout_sig,
+                        # platform) like SC/FCap: persist the cold-tier
+                        # key total so the next run on this engine knows
+                        # the out-of-core magnitude up front
+                        self._save_caps_profile(
+                            dict(caps, TIERK=_pow2_at_least(
+                                max(len(self._tiers), 1), lo=256)),
+                            optional=("TIERK",))
+                    else:
+                        self._save_caps_profile(caps)
+                    if self.checkpoint_path and self.final_checkpoint:
+                        # COMPLETED-run checkpoint (serve warm resume): an
+                        # empty frontier over the full seen set — resuming
+                        # it replays the stored totals in one dispatch
+                        self._write_ck(
+                            "resident", caps=dict(caps),
+                            seen=np.asarray(seen[:seen_count]),
+                            frontier=np.zeros((0, self.PW), np.int32),
+                            distinct=distinct, generated=generated,
+                            depth=depth)
+                    return self._mk_result(True, distinct, generated,
+                                           depth - 1, t0, warnings)
             elif stat == ST_TRUNC:
                 self.log("-- state limit reached, search truncated")
                 self._save_caps_profile(caps)
@@ -4142,46 +4173,48 @@ class TpuExplorer:
                 "wide state (W={}): dedup on 128-bit fingerprints; "
                 "collision probability < n^2 * 2^-129".format(W))
 
-        init_rows, explored_init, n_init, err = \
-            self._prepare_init(t0, warnings)
+        with tel.span("search.init"):
+            init_rows, explored_init, n_init, err = \
+                self._prepare_init(t0, warnings)
         if err is not None:
             return err
         generated = n_init
         distinct = len(explored_init)
 
-        init_keys, init_packed, init_povf = self._host_keys(init_rows)
-        if init_povf:
-            return self._mk_result(
-                False, distinct, generated, 0, t0, warnings,
-                Violation("error", "capacity overflow", [],
-                          self._pack_ovf_msg()))
-        graph = _LiveGraph(self.labels_flat, self.collect_edges) \
-            if self.live_obligations else None
-        frontier_sids = graph.add_inits(init_packed, explored_init) \
-            if graph is not None else None
+        with tel.span("search.seed"):
+            init_keys, init_packed, init_povf = self._host_keys(init_rows)
+            if init_povf:
+                return self._mk_result(
+                    False, distinct, generated, 0, t0, warnings,
+                    Violation("error", "capacity overflow", [],
+                              self._pack_ovf_msg()))
+            graph = _LiveGraph(self.labels_flat, self.collect_edges) \
+                if self.live_obligations else None
+            frontier_sids = graph.add_inits(init_packed, explored_init) \
+                if graph is not None else None
 
-        FC = _pow2_at_least(max(n_init, 1))
-        SC = _pow2_at_least(4 * max(n_init, 1))
+            FC = _pow2_at_least(max(n_init, 1))
+            SC = _pow2_at_least(4 * max(n_init, 1))
 
-        front_init = init_packed[explored_init] if n_init else init_packed
-        n_front = len(front_init)
-        frontier = np.full((FC, self.PW), SENTINEL, np.int32)
-        frontier[:n_front] = front_init
-        frontier = jnp.asarray(frontier)
-        fcount = n_front
+            front_init = init_packed[explored_init] if n_init else init_packed
+            n_front = len(front_init)
+            frontier = np.full((FC, self.PW), SENTINEL, np.int32)
+            frontier[:n_front] = front_init
+            frontier = jnp.asarray(frontier)
+            fcount = n_front
 
-        seen = np.full((SC, K), SENTINEL, np.int32)
-        if n_init:
-            order = np.lexsort(tuple(init_keys[:, i]
-                                     for i in reversed(range(K))))
-            seen[:n_init] = init_keys[order]
-        seen = jnp.asarray(seen)
-        seen_count = n_init
+            seen = np.full((SC, K), SENTINEL, np.int32)
+            if n_init:
+                order = np.lexsort(tuple(init_keys[:, i]
+                                         for i in reversed(range(K))))
+                seen[:n_init] = init_keys[order]
+            seen = jnp.asarray(seen)
+            seen_count = n_init
 
-        trace_levels: List[Tuple[np.ndarray, Optional[np.ndarray], int]] = []
-        trace_levels.append((np.asarray(init_packed), None, 0))
-        frontier_maps: List[np.ndarray] = [np.asarray(explored_init,
-                                                      dtype=np.int64)]
+            trace_levels: List[Tuple[np.ndarray, Optional[np.ndarray], int]] = []
+            trace_levels.append((np.asarray(init_packed), None, 0))
+            frontier_maps: List[np.ndarray] = [np.asarray(explored_init,
+                                                          dtype=np.int64)]
 
         depth = 0
         if self.resume_from:
@@ -4230,171 +4263,177 @@ class TpuExplorer:
                                        t0, warnings, None,
                                        truncated=True, drained=True)
             lvl_t0 = time.time()
-            C = self.A * FC
-            if seen_count + C > SC:
-                SC2 = _pow2_at_least(seen_count + C, SC)
-                if self.seen_cap is not None and SC2 > self.seen_cap \
-                        and seen_count > 0:
-                    # device tier full (ISSUE 12): compact the sorted
-                    # prefix out to the cold tiers and restart the
-                    # device table empty, instead of growing past the
-                    # cap — kept rows are cold-probed after each step
-                    with tel.span("tier.spill", keys=seen_count):
-                        self._tier_spill_prefix(np.asarray(seen),
-                                                seen_count)
-                    seen = jnp.asarray(
-                        np.full((SC, K), SENTINEL, np.int32))
-                    seen_count = 0
-                    SC2 = _pow2_at_least(C, SC)
-                    if SC2 > max(SC, self.seen_cap):
-                        # the per-level candidate block alone exceeds
-                        # the cap: the rank-merge no-overflow invariant
-                        # (seen_count + C <= SC) forces a soft breach
-                        self.log(f"-- tier: device cap "
-                                 f"{self.seen_cap} < one level's "
-                                 f"candidate block ({C}); growing "
-                                 f"anyway (soft cap)")
-                if SC2 > SC:
-                    pad = jnp.full((SC2 - SC, K), SENTINEL, jnp.int32)
-                    seen = jnp.concatenate([seen, pad])
-                    SC = SC2
-            step = self._get_step(SC, FC)
-            # HBM model (ISSUE 17): the level loop's two device-resident
-            # buffers at their current (possibly re-grown) capacities
-            obs.note_buffer("level.seen", SC * K * 4)
-            obs.note_buffer("level.frontier", FC * self.PW * 4)
-            out = step(seen, seen_count, frontier, fcount)
+            with tel.span("level.dispatch"):
+                C = self.A * FC
+                if seen_count + C > SC:
+                    SC2 = _pow2_at_least(seen_count + C, SC)
+                    if self.seen_cap is not None and SC2 > self.seen_cap \
+                            and seen_count > 0:
+                        # device tier full (ISSUE 12): compact the sorted
+                        # prefix out to the cold tiers and restart the
+                        # device table empty, instead of growing past the
+                        # cap — kept rows are cold-probed after each step
+                        with tel.span("tier.spill", keys=seen_count):
+                            self._tier_spill_prefix(np.asarray(seen),
+                                                    seen_count)
+                        seen = jnp.asarray(
+                            np.full((SC, K), SENTINEL, np.int32))
+                        seen_count = 0
+                        SC2 = _pow2_at_least(C, SC)
+                        if SC2 > max(SC, self.seen_cap):
+                            # the per-level candidate block alone exceeds
+                            # the cap: the rank-merge no-overflow invariant
+                            # (seen_count + C <= SC) forces a soft breach
+                            self.log(f"-- tier: device cap "
+                                     f"{self.seen_cap} < one level's "
+                                     f"candidate block ({C}); growing "
+                                     f"anyway (soft cap)")
+                    if SC2 > SC:
+                        pad = jnp.full((SC2 - SC, K), SENTINEL, jnp.int32)
+                        seen = jnp.concatenate([seen, pad])
+                        SC = SC2
+                step = self._get_step(SC, FC)
+                out = step(seen, seen_count, frontier, fcount)
 
-            ovc = int(out["overflow"])
-            if ovc:
-                if ovc == OV_DEMOTED:
-                    msg = ("a demoted compile-recovery fired (the kernel "
-                           "under-approximates here): run the host_seen "
-                           "mode, which demotes the arm to the "
-                           "interpreter and restarts")
-                elif ovc == OV_PACK:
-                    msg = self._pack_ovf_msg()
-                else:
-                    msg = ("a container exceeded its lane capacity "
-                           f"({self._caps_note()}); "
-                           "counts would no longer be exact")
-                return self._mk_result(
-                    False, distinct, generated, depth, t0, warnings,
-                    Violation("error", "capacity overflow", [], msg))
-            if bool(jnp.any(out["assert_bad"])):
-                ab = np.asarray(out["assert_bad"])
-                a, f = np.unravel_index(np.argmax(ab), ab.shape)
-                trace = self._trace_to(trace_levels, frontier_maps,
-                                       depth, int(f))
-                return self._mk_result(
-                    False, distinct, generated, depth, t0, warnings,
-                    Violation("assert", "Assert",
-                              [x for x in trace if x[0] is not None],
-                              f"assertion in {self.labels_flat[int(a)]}"))
-            if model.check_deadlock and bool(jnp.any(out["dead"])):
-                f = int(jnp.argmax(out["dead"]))
-                trace = self._trace_to(trace_levels, frontier_maps,
-                                       depth, f)
-                return self._mk_result(
-                    False, distinct, generated, depth, t0, warnings,
-                    Violation("deadlock", "deadlock", trace))
-
-            if self.refiners:
-                rviol = self._refine_edges(frontier, out["cand"],
-                                           out["cvalid"],
-                                           out["explore_all"], FC)
-                if rviol is not None:
-                    a, f, sst, rc = rviol
+            with tel.span("level.sync"):
+                ovc = int(out["overflow"])
+                if ovc:
+                    if ovc == OV_DEMOTED:
+                        msg = ("a demoted compile-recovery fired (the kernel "
+                               "under-approximates here): run the host_seen "
+                               "mode, which demotes the arm to the "
+                               "interpreter and restarts")
+                    elif ovc == OV_PACK:
+                        msg = self._pack_ovf_msg()
+                    else:
+                        msg = ("a container exceeded its lane capacity "
+                               f"({self._caps_note()}); "
+                               "counts would no longer be exact")
+                    return self._mk_result(
+                        False, distinct, generated, depth, t0, warnings,
+                        Violation("error", "capacity overflow", [], msg))
+                if bool(jnp.any(out["assert_bad"])):
+                    ab = np.asarray(out["assert_bad"])
+                    a, f = np.unravel_index(np.argmax(ab), ab.shape)
+                    trace = self._trace_to(trace_levels, frontier_maps,
+                                           depth, int(f))
+                    return self._mk_result(
+                        False, distinct, generated, depth, t0, warnings,
+                        Violation("assert", "Assert",
+                                  [x for x in trace if x[0] is not None],
+                                  f"assertion in {self.labels_flat[int(a)]}"))
+                if model.check_deadlock and bool(jnp.any(out["dead"])):
+                    f = int(jnp.argmax(out["dead"]))
                     trace = self._trace_to(trace_levels, frontier_maps,
                                            depth, f)
                     return self._mk_result(
                         False, distinct, generated, depth, t0, warnings,
-                        self._refine_violation(rc, sst, a, trace))
+                        Violation("deadlock", "deadlock", trace))
 
-            front_count = int(out["front_count"])
-            generated += int(out["gen"])
-            if "por_ample" in out:
-                self._por_stats["ample"] += int(out["por_ample"])
-                self._por_stats["expanded"] += int(out["por_expanded"])
-                self._por_stats["masked"] += int(out["por_masked"])
-            # cold-tier membership filter (ISSUE 12): rows the device
-            # rank-merge called new may duplicate keys spilled to the
-            # host/disk tiers — drop them (order-preserving) before
-            # they are counted, traced, or explored: exactly the rows
-            # the uncapped run's device merge would have dropped, so
-            # counts and traces stay bit-identical
-            tier_keep = None
-            fr_host = fp_host = None
-            if self._tiers is not None and self._tiers.active \
-                    and front_count:
-                fkeys = np.asarray(out["front_keys"][:front_count, 1:])
-                dup = self._tiers.probe(fkeys)
-                if dup.any():
-                    tier_keep = ~dup
-                    fr_host = np.ascontiguousarray(np.asarray(
-                        out["front_rows"][:front_count])[tier_keep])
-                    fp_host = np.ascontiguousarray(np.asarray(
-                        out["front_prov"][:front_count])[tier_keep])
-                self._tiers.publish_gauges(int(out["seen_count"]))
-            kept_count = len(fr_host) if fr_host is not None \
-                else front_count
-            distinct += kept_count  # kept states only (discards excluded)
-            seen = out["seen"]
-            seen_count = int(out["seen_count"])
-            tel.level(depth, frontier=fcount, generated=int(out["gen"]),
-                      new=kept_count, distinct=distinct, seen=seen_count,
-                      wall_s=round(time.time() - lvl_t0, 6))
+                if self.refiners:
+                    rviol = self._refine_edges(frontier, out["cand"],
+                                               out["cvalid"],
+                                               out["explore_all"], FC)
+                    if rviol is not None:
+                        a, f, sst, rc = rviol
+                        trace = self._trace_to(trace_levels, frontier_maps,
+                                               depth, f)
+                        return self._mk_result(
+                            False, distinct, generated, depth, t0, warnings,
+                            self._refine_violation(rc, sst, a, trace))
+
+                front_count = int(out["front_count"])
+                generated += int(out["gen"])
+                if "por_ample" in out:
+                    self._por_stats["ample"] += int(out["por_ample"])
+                    self._por_stats["expanded"] += int(out["por_expanded"])
+                    self._por_stats["masked"] += int(out["por_masked"])
+                # cold-tier membership filter (ISSUE 12): rows the device
+                # rank-merge called new may duplicate keys spilled to the
+                # host/disk tiers — drop them (order-preserving) before
+                # they are counted, traced, or explored: exactly the rows
+                # the uncapped run's device merge would have dropped, so
+                # counts and traces stay bit-identical
+                tier_keep = None
+                fr_host = fp_host = None
+                if self._tiers is not None and self._tiers.active \
+                        and front_count:
+                    fkeys = np.asarray(out["front_keys"][:front_count, 1:])
+                    dup = self._tiers.probe(fkeys)
+                    if dup.any():
+                        tier_keep = ~dup
+                        fr_host = np.ascontiguousarray(np.asarray(
+                            out["front_rows"][:front_count])[tier_keep])
+                        fp_host = np.ascontiguousarray(np.asarray(
+                            out["front_prov"][:front_count])[tier_keep])
+                    self._tiers.publish_gauges(int(out["seen_count"]))
+                kept_count = len(fr_host) if fr_host is not None \
+                    else front_count
+                distinct += kept_count  # kept states only (discards excluded)
+                seen = out["seen"]
+                seen_count = int(out["seen_count"])
+                tel.level(depth, frontier=fcount, generated=int(out["gen"]),
+                          new=kept_count, distinct=distinct, seen=seen_count,
+                          wall_s=round(time.time() - lvl_t0, 6))
+            # work against capacity: the step sorted the whole candidate
+            # block and rewrote the whole seen table for gen valid rows
+            tel.counter("search.slots_sorted", C)
+            tel.counter("search.rows_valid", int(out["gen"]))
+            tel.counter("search.seen_slots", SC)
+            tel.counter("search.rows_new", kept_count)
             self._fp_occupancy = seen_count
 
-            if graph is not None:
-                new_sids = graph.add_level(
-                    fr_host if fr_host is not None else
-                    np.asarray(out["front_rows"][:front_count]),
-                    fp_host if fp_host is not None else
-                    np.asarray(out["front_prov"][:front_count]),
-                    FC, frontier_sids)
-                if graph.collect_edges:
-                    # the step emits cand/explore_all iff need_edges —
-                    # which collect_edges implies
-                    mask = np.asarray(out["cvalid"]) & np.asarray(
-                        out["explore_all"])
-                    idx = np.nonzero(mask)[0]
-                    rows = np.asarray(jnp.take(
-                        out["cand"], jnp.asarray(idx, dtype=jnp.int32),
-                        axis=0)) if len(idx) \
-                        else np.zeros((0, self.PW), np.int32)
-                    graph.add_edges(rows, idx % FC, frontier_sids)
-                frontier_sids = new_sids
+            with tel.span("level.rows"):
+                if graph is not None:
+                    new_sids = graph.add_level(
+                        fr_host if fr_host is not None else
+                        np.asarray(out["front_rows"][:front_count]),
+                        fp_host if fp_host is not None else
+                        np.asarray(out["front_prov"][:front_count]),
+                        FC, frontier_sids)
+                    if graph.collect_edges:
+                        # the step emits cand/explore_all iff need_edges —
+                        # which collect_edges implies
+                        mask = np.asarray(out["cvalid"]) & np.asarray(
+                            out["explore_all"])
+                        idx = np.nonzero(mask)[0]
+                        rows = np.asarray(jnp.take(
+                            out["cand"], jnp.asarray(idx, dtype=jnp.int32),
+                            axis=0)) if len(idx) \
+                            else np.zeros((0, self.PW), np.int32)
+                        graph.add_edges(rows, idx % FC, frontier_sids)
+                    frontier_sids = new_sids
 
-            if self.store_trace:
-                # trace levels hold the kept states; every kept state is
-                # explored, so the frontier map is the identity
-                if fr_host is not None:
-                    trace_levels.append((fr_host, fp_host, FC))
-                else:
-                    fr_h = np.asarray(
-                        out["front_rows"][:max(front_count, 1)])
-                    fp_h = np.asarray(
-                        out["front_prov"][:max(front_count, 1)])
-                    trace_levels.append(
-                        (fr_h[:front_count], fp_h[:front_count], FC))
-                frontier_maps.append(
-                    np.arange(kept_count, dtype=np.int64))
-            if bool(out["inv_bad_any"]):
-                idx = int(out["inv_bad_idx"])
-                if tier_keep is not None:
-                    # a tier-duplicate row can never violate (its state
-                    # was invariant-checked when first admitted), so
-                    # the violating row survives the filter: re-index
-                    # it into the filtered level
-                    idx = int(np.sum(tier_keep[:idx]))
-                which = int(out["inv_bad_which"])
-                nm = self.inv_fns[which][0]
-                trace = self._trace_to(trace_levels, frontier_maps,
-                                       depth + 1, idx, from_new=True)
-                return self._mk_result(
-                    False, distinct, generated, depth + 1, t0, warnings,
-                    Violation("invariant", nm, trace))
+                if self.store_trace:
+                    # trace levels hold the kept states; every kept state is
+                    # explored, so the frontier map is the identity
+                    if fr_host is not None:
+                        trace_levels.append((fr_host, fp_host, FC))
+                    else:
+                        fr_h = np.asarray(
+                            out["front_rows"][:max(front_count, 1)])
+                        fp_h = np.asarray(
+                            out["front_prov"][:max(front_count, 1)])
+                        trace_levels.append(
+                            (fr_h[:front_count], fp_h[:front_count], FC))
+                    frontier_maps.append(
+                        np.arange(kept_count, dtype=np.int64))
+            with tel.span("level.sync"):
+                if bool(out["inv_bad_any"]):
+                    idx = int(out["inv_bad_idx"])
+                    if tier_keep is not None:
+                        # a tier-duplicate row can never violate (its state
+                        # was invariant-checked when first admitted), so
+                        # the violating row survives the filter: re-index
+                        # it into the filtered level
+                        idx = int(np.sum(tier_keep[:idx]))
+                    which = int(out["inv_bad_which"])
+                    nm = self.inv_fns[which][0]
+                    trace = self._trace_to(trace_levels, frontier_maps,
+                                           depth + 1, idx, from_new=True)
+                    return self._mk_result(
+                        False, distinct, generated, depth + 1, t0, warnings,
+                        Violation("invariant", nm, trace))
             depth += 1
 
             if self.max_states and distinct >= self.max_states:
@@ -4405,18 +4444,19 @@ class TpuExplorer:
                     trunc_reason=f"max_states: distinct {distinct} >= "
                                  f"limit {self.max_states}")
 
-            if kept_count > FC:
-                FC = _pow2_at_least(kept_count, FC)
-            if fr_host is not None:
-                nf_np = np.full((FC, self.PW), SENTINEL, np.int32)
-                nf_np[:kept_count] = fr_host
-                frontier = jnp.asarray(nf_np)
-            else:
-                nf = jnp.full((FC, self.PW), SENTINEL, jnp.int32)
-                nf = nf.at[:min(front_count, FC)].set(
-                    out["front_rows"][:min(front_count, FC)])
-                frontier = nf
-            fcount = kept_count
+            with tel.span("level.dispatch"):
+                if kept_count > FC:
+                    FC = _pow2_at_least(kept_count, FC)
+                if fr_host is not None:
+                    nf_np = np.full((FC, self.PW), SENTINEL, np.int32)
+                    nf_np[:kept_count] = fr_host
+                    frontier = jnp.asarray(nf_np)
+                else:
+                    nf = jnp.full((FC, self.PW), SENTINEL, jnp.int32)
+                    nf = nf.at[:min(front_count, FC)].set(
+                        out["front_rows"][:min(front_count, FC)])
+                    frontier = nf
+                fcount = kept_count
 
             now = time.time()
             if self.checkpoint_path and \
@@ -4442,23 +4482,24 @@ class TpuExplorer:
             if viol is not None:
                 return self._mk_result(False, distinct, generated,
                                        depth - 1, t0, warnings, viol)
-        self.log("Model checking completed. No error has been found.")
-        self.log(f"{generated} states generated, {distinct} distinct states "
-                 f"found, 0 states left on queue.")
-        self.log(f"The depth of the complete state graph search is "
-                 f"{depth}.")
-        if self.checkpoint_path and self.final_checkpoint:
-            # COMPLETED-run checkpoint (serve warm resume): an empty
-            # frontier over the full seen table — resuming it skips the
-            # level loop and replays the stored totals
-            self._write_ck(
-                "level", seen=np.asarray(seen[:seen_count]),
-                frontier=np.zeros((0, self.PW), np.int32),
-                **self._ck_state_kwargs(distinct, generated, depth,
-                                        trace_levels, frontier_maps,
-                                        graph, frontier_sids))
-        return self._mk_result(True, distinct, generated, depth - 1, t0,
-                               warnings)
+        with tel.span("search.finish"):
+            self.log("Model checking completed. No error has been found.")
+            self.log(f"{generated} states generated, {distinct} distinct states "
+                     f"found, 0 states left on queue.")
+            self.log(f"The depth of the complete state graph search is "
+                     f"{depth}.")
+            if self.checkpoint_path and self.final_checkpoint:
+                # COMPLETED-run checkpoint (serve warm resume): an empty
+                # frontier over the full seen table — resuming it skips the
+                # level loop and replays the stored totals
+                self._write_ck(
+                    "level", seen=np.asarray(seen[:seen_count]),
+                    frontier=np.zeros((0, self.PW), np.int32),
+                    **self._ck_state_kwargs(distinct, generated, depth,
+                                            trace_levels, frontier_maps,
+                                            graph, frontier_sids))
+            return self._mk_result(True, distinct, generated, depth - 1, t0,
+                                   warnings)
 
     def _mk_result(self, ok, distinct, generated, diameter, t0, warnings,
                    violation=None, truncated=False,

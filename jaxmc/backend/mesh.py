@@ -474,10 +474,6 @@ class MeshExplorer(TpuExplorer):
         B = self._a2a_bucket(C, FC)
         SB = self._a2a_spill_bucket(B)
         R = D * (B + SB)
-        # HBM model (ISSUE 17): the two a2a payload staging buffers
-        # ([D*B, Pw] + [D*SB, Pw] words, both directions), per device
-        obs.note_buffer("mesh.a2a_buckets",
-                        2 * D * (B + SB) * (K + PW + 1) * 4 * D)
 
         def route_a2a(ckeys, cand, cvalid, me):
             invalid_key = jnp.asarray(invalid_key_np)
@@ -1892,13 +1888,6 @@ class MeshExplorer(TpuExplorer):
             step_key = self._mesh_resident_key(SC, FC, TRL, VC)
             fresh_compile = step_key not in self._mesh_step_cache
             step = self._get_mesh_resident_step(SC, FC, TRL, VC)
-            # HBM model (ISSUE 17): the sharded tables at their current
-            # (possibly re-grown) capacities, summed over the D devices
-            obs.note_buffer("mesh.seen_shards", D * SC * K * 4)
-            obs.note_buffer("mesh.frontier", D * FC * PW * 4)
-            if self.store_trace:
-                obs.note_buffer("mesh.trace_ring",
-                                D * TRL * FC * (PW + 1) * 4)
             args = (seen, seen_count, frontier, fcount)
             if self.store_trace:
                 args = args + (tr_rows, tr_src)

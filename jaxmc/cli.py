@@ -45,7 +45,9 @@ def cmd_check(args) -> int:
         # per-dispatch device profiling (ISSUE 17, obs/prof.py): wall
         # mode adds block-until-ready walls + byte accounting to the
         # always-on dispatch counters; a sync cannot change values, so
-        # counts/traces stay bit-identical to a profile-off run
+        # counts/traces stay bit-identical to a profile-off run.  xla
+        # mode stays cheap (no forced sync): the trace is the run a
+        # user has
         tel.prof.mode = args.profile
     log = obs.Logger(tel, quiet=args.quiet)
     # the watchdog names a wedged phase (device init, a pathological BFS
@@ -72,9 +74,12 @@ def cmd_check(args) -> int:
 def _start_xla_trace(args, tel) -> bool:
     """--profile=xla: wrap the whole run in a jax.profiler trace
     capture to a named artifact dir (JAXMC_XLA_TRACE_DIR, else next to
-    --metrics-out, else a fresh tempdir).  Best-effort: a backend
-    without profiler support degrades to wall-mode profiling with a
-    warning, never a failed run."""
+    --metrics-out, else a fresh tempdir), recorded as the benchmark
+    records (bench/drivers/recheck.py): no Python tracer, host events
+    at level 2, so the program's `jaxmc.<span>` annotations lie beside
+    the runtime's own events and the device line.  Best-effort: a
+    backend without profiler support degrades to wall-mode profiling
+    with a warning, never a failed run."""
     tdir = os.environ.get("JAXMC_XLA_TRACE_DIR") or \
         (args.metrics_out + ".xla" if args.metrics_out else None)
     if tdir is None:
@@ -82,11 +87,15 @@ def _start_xla_trace(args, tel) -> bool:
         tdir = tempfile.mkdtemp(prefix="jaxmc-xla-")
     try:
         import jax
-        jax.profiler.start_trace(tdir)
+        opt = jax.profiler.ProfileOptions()
+        opt.python_tracer_level = 0
+        opt.host_tracer_level = 2
+        jax.profiler.start_trace(tdir, profiler_options=opt)
     except Exception as e:  # noqa: BLE001 — profiling is best-effort
         print(f"warning: --profile=xla trace capture unavailable "
               f"({e}); continuing with wall-mode profiling",
               file=sys.stderr)
+        tel.prof.mode = tel.prof.WALL
         return False
     tel.prof.xla_trace_dir = tdir
     print(f"-- profile: xla trace capture -> {tdir}", file=sys.stderr)
@@ -517,14 +526,15 @@ def main(argv=None) -> int:
                    choices=("wall", "xla"),
                    help="per-dispatch device profiling (obs/prof.py): "
                         "block-until-ready wall, bytes and recompiles "
-                        "per named dispatch site plus the HBM buffer "
-                        "model, stamped into --metrics-out as the "
-                        "prof{} block (render with python -m "
-                        "jaxmc.obs top). --profile=xla additionally "
-                        "captures a jax.profiler trace to "
-                        "JAXMC_XLA_TRACE_DIR (default: "
-                        "METRICS_OUT.xla/). Profiling never changes "
-                        "counts or traces")
+                        "per named dispatch site, stamped into "
+                        "--metrics-out as the prof{} block (render "
+                        "with python -m jaxmc.obs top). --profile=xla "
+                        "instead captures a jax.profiler trace of the "
+                        "run as it is (no forced sync, no Python "
+                        "tracer) to JAXMC_XLA_TRACE_DIR (default: "
+                        "METRICS_OUT.xla/), with the program's spans "
+                        "and kernel scopes in it. Profiling never "
+                        "changes counts or traces")
     c.set_defaults(fn=cmd_check)
 
     m = sub.add_parser("simulate",

@@ -47,6 +47,8 @@ Effectiveness is exposed as obs counters:
   gauge compile.persistent_cache_entries_start / _end
   gauge compile.persistent_cache_guard   ("ok[...]" | "cold-fallback:..")
   counter compile.persistent_cache_fallbacks / _quarantines
+  counter compile.xla_compiles / compile.xla_compile_s, and the same by
+  program: gauge compile.by_fun {fun_name: [compiles, seconds]}
 
 Fault sites (jaxmc/faults.py, chaos suite): `cache_hang` wedges the
 health probe, `cache_corrupt` zero-truncates one entry before the scan.
@@ -60,6 +62,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 from typing import Optional, Tuple
 
@@ -92,6 +95,7 @@ def cache_disabled_by_env() -> bool:
 
 
 _LISTENER_REGISTERED = False
+_BY_FUN_LOCK = threading.Lock()  # compiles run on any thread
 
 
 def _count_entries(path: str) -> Optional[int]:
@@ -325,13 +329,26 @@ def _register_listeners() -> None:
             name = name[len("cache_"):]
         _obs.current().counter(f"compile.persistent_cache_{name}")
 
-    def _on_duration(event: str, secs: float, **kw) -> None:
+    def _on_duration(event: str, secs: float, fun_name: str = "?",
+                     **kw) -> None:
         if not event.endswith("/backend_compile_duration"):
             return
         from .. import obs as _obs
         tel = _obs.current()
         tel.counter("compile.xla_compiles")
         tel.counter("compile.xla_compile_s", secs)
+        # the same seconds by program (jax names it `jit(<function>)`):
+        # which of a first contact's programs the time went to
+        if fun_name.startswith("jit(") and fun_name.endswith(")"):
+            fun_name = fun_name[4:-1]
+        if tel.enabled:
+            with _BY_FUN_LOCK:
+                table = tel.gauges.get("compile.by_fun") or {}
+                n, total = table.get(fun_name, (0, 0.0))
+                # gauge() installs a NEW table: a snapshot that a scrape
+                # thread holds is never written to
+                tel.gauge("compile.by_fun",
+                          {**table, fun_name: [n + 1, total + secs]})
 
     monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)

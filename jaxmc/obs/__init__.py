@@ -19,8 +19,8 @@ artifact was requested. See obs/telemetry.py for the model,
 obs/schema.py for the artifact schema (jaxmc.metrics/4),
 obs/context.py for the JAXMC_TRACE_CTX propagation contract,
 obs/progress.py for the ETA estimator, obs/watchdog.py for live stall
-diagnosis, obs/prof.py for the per-dispatch device profiler + HBM
-model, obs/ledger.py for the persistent run ledger, and obs/report.py
+diagnosis, obs/prof.py for the per-dispatch device profiler,
+obs/ledger.py for the persistent run ledger, and obs/report.py
 for `python -m jaxmc.obs report|diff|timeline|top|history` over
 artifacts.
 """
@@ -32,7 +32,7 @@ from .telemetry import (Logger, NullTelemetry, Telemetry, current,
                         write_json_atomic)
 from .context import TraceContext, child_env
 from .ledger import append_summary, ledger_path
-from .prof import Profiler, note_buffer, prof_attribution, prof_wrap
+from .prof import Profiler, prof_attribution, prof_wrap
 from .progress import ProgressEstimator, attach_estimator, eta_suffix
 from .schema import (CHECK_KEYS, HEARTBEAT_KEYS, REQUIRED_KEYS,
                      RESULT_KEYS, SCHEMA, SCHEMAS, STALL_KEYS,
@@ -43,7 +43,7 @@ __all__ = ["Logger", "NullTelemetry", "Profiler", "Telemetry",
            "Watchdog", "TraceContext", "ProgressEstimator",
            "append_summary", "attach_estimator", "child_env", "context",
            "current", "device_mem_high_water", "environment_meta",
-           "eta_suffix", "ledger_path", "live_devices", "note_buffer",
+           "eta_suffix", "ledger_path", "live_devices",
            "prof_attribution", "prof_wrap", "prom_name", "rss_bytes",
            "stamp_device", "use", "use_local", "write_json_atomic",
            "SCHEMA", "SCHEMAS",

@@ -1,4 +1,4 @@
-r"""Device profiler (ISSUE 17): per-dispatch attribution + HBM accounting.
+r"""Device profiler (ISSUE 17): per-dispatch attribution.
 
 PR 11 left one perf target unmet — merge wall <30% of step wall — partly
 because nothing below the PHASE level said where device time went:
@@ -16,21 +16,18 @@ layer:
             no byte walks, so profile-off runs stay byte-identical and
             effectively free.
   wall      `--profile`: additionally blocks until the output pytree is
-            ready and charges the wall to the site, sums argument /
-            result bytes per dispatch, and asks the AOT lowering's
-            cost_analysis once per site for flops / bytes-accessed.
-            Synchronization cannot change counts or traces — profile-on
-            vs profile-off stays bit-identical (pinned by tests and
-            `make prof-check`).
-  xla       wall + the CLI wraps the run in a jax.profiler.trace
-            capture to a named artifact dir.
-  hbm       a device-memory MODEL from the capacity profile / LanePlan:
-            engines register named buffers (seen shards, frontier,
-            trace ring, a2a buckets, tier tables) as byte sizes the
-            moment their capacities are known; the running sum's
-            high-water is `prof.hbm_peak_bytes`, cross-checked against
-            `jax.local_devices()[0].memory_stats()` where the backend
-            exposes it.
+            ready and charges the wall to the site, and sums argument /
+            result bytes per dispatch.  Synchronization cannot change
+            counts or traces — profile-on vs profile-off stays
+            bit-identical (pinned by tests and `make prof-check`).
+  xla       cheap + the CLI wraps the run in a jax.profiler capture
+            (no Python tracer) to a named artifact dir: the run a user
+            has, with the program's spans (`jaxmc.<span>`, obs/
+            telemetry.py) and kernel scopes (backend/bfs.py) in it;
+            per-operation bytes and flops are the trace's own.
+  hbm       the MEASURED device peak, `memory_stats()
+            ["peak_bytes_in_use"]` summed over the live devices; absent
+            where the backend reports none (XLA:CPU).
 
 The rollup lands in the metrics artifact as the `prof{}` block (schema
 jaxmc.metrics/4, obs/schema.py) and renders via `python -m jaxmc.obs
@@ -41,7 +38,6 @@ environments); jax is imported lazily inside the wall-mode paths only.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -75,33 +71,25 @@ class SiteStats:
     """Per-site accumulators.  Mutated under the owning Profiler's
     lock; read via Profiler.snapshot()."""
 
-    __slots__ = ("name", "dispatches", "wall_s", "analysis_wall_s",
-                 "arg_bytes", "res_bytes", "recompiles", "cost",
-                 "_analyzed")
+    __slots__ = ("name", "dispatches", "wall_s", "arg_bytes",
+                 "res_bytes", "recompiles")
 
     def __init__(self, name: str):
         self.name = name
         self.dispatches = 0
         self.wall_s = 0.0
-        self.analysis_wall_s = 0.0
         self.arg_bytes = 0
         self.res_bytes = 0
         self.recompiles = 0
-        self.cost: Optional[Dict[str, Any]] = None
-        self._analyzed = False
 
     def as_dict(self) -> Dict[str, Any]:
         d: Dict[str, Any] = {"dispatches": self.dispatches,
                              "recompiles": self.recompiles}
         if self.wall_s:
             d["wall_s"] = round(self.wall_s, 6)
-        if self.analysis_wall_s:
-            d["analysis_wall_s"] = round(self.analysis_wall_s, 6)
         if self.arg_bytes or self.res_bytes:
             d["arg_bytes"] = self.arg_bytes
             d["res_bytes"] = self.res_bytes
-        if self.cost:
-            d["cost"] = dict(self.cost)
         return d
 
 
@@ -117,8 +105,6 @@ class Profiler:
         self._clock = clock
         self._lock = threading.Lock()
         self.sites: Dict[str, SiteStats] = {}
-        self._buffers: Dict[str, int] = {}
-        self.hbm_peak_bytes = 0
         self.xla_trace_dir: Optional[str] = None
 
     # ---- dispatch sites ------------------------------------------------
@@ -142,10 +128,10 @@ class Profiler:
     def record(self, name: str, fn, args, kwargs):
         """One profiled dispatch.  Cheap mode: count + recompile delta
         only.  Wall mode: + block-until-ready wall and arg/result
-        bytes, + a one-time AOT cost_analysis per site."""
+        bytes."""
         st = self._site(name)
         cs0 = self._cache_size(fn)
-        if self.mode == self.CHEAP:
+        if self.mode != self.WALL:
             out = fn(*args, **kwargs)
             cs1 = self._cache_size(fn)
             with self._lock:
@@ -167,17 +153,6 @@ class Profiler:
             st.res_bytes += rb
             if cs0 is not None and cs1 is not None and cs1 > cs0:
                 st.recompiles += cs1 - cs0
-            analyze = not st._analyzed
-            if analyze:
-                st._analyzed = True
-        if analyze:
-            # the one-shot lowering retrace is PROFILER-caused wall
-            # inside the search phase; charge it to the site (its own
-            # column, not wall_s) so the attribution metric stays honest
-            ta = self._clock()
-            self._analyze(st, fn, args, kwargs)
-            with self._lock:
-                st.analysis_wall_s += self._clock() - ta
         return out
 
     @staticmethod
@@ -191,28 +166,12 @@ class Profiler:
         except Exception:  # noqa: BLE001 — non-jax outputs pass through
             return out
 
-    def _analyze(self, st: SiteStats, fn, args, kwargs) -> None:
-        """One-shot AOT cost analysis for the site (wall mode only;
-        JAXMC_PROF_COST=0 disables — the lowering retrace costs a few
-        hundred ms on big programs)."""
-        if os.environ.get("JAXMC_PROF_COST", "").strip() == "0":
-            return
-        try:
-            lowered = fn.lower(*args, **kwargs)
-            ca = lowered.cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            cost = {}
-            for key, out_key in (("flops", "flops"),
-                                 ("bytes accessed", "bytes_accessed")):
-                v = ca.get(key) if isinstance(ca, dict) else None
-                if isinstance(v, (int, float)):
-                    cost[out_key] = int(v)
-            if cost:
-                with self._lock:
-                    st.cost = cost
-        except Exception:  # noqa: BLE001 — cost analysis is best-effort
-            pass
+    @property
+    def hbm_peak_bytes(self) -> Optional[int]:
+        """The measured device peak (`memory_stats()`), None where the
+        backend reports none."""
+        from .telemetry import device_mem_high_water
+        return device_mem_high_water()
 
     def dominant_site(self) -> Optional[Tuple[str, float]]:
         """(site name, share) of the site holding the largest wall
@@ -233,32 +192,6 @@ class Profiler:
                 return name, disp[name] / total
             return None
 
-    # ---- HBM accounting ------------------------------------------------
-    def note_buffer(self, name: str, nbytes) -> None:
-        """Register (or resize) one named device buffer in the memory
-        model; the running total's high-water is hbm_peak_bytes."""
-        try:
-            nb = int(nbytes)
-        except (TypeError, ValueError):
-            return
-        with self._lock:
-            self._buffers[name] = nb
-            cur = sum(self._buffers.values())
-            if cur > self.hbm_peak_bytes:
-                self.hbm_peak_bytes = cur
-
-    def drop_buffer(self, name: str) -> None:
-        with self._lock:
-            self._buffers.pop(name, None)
-
-    def hbm_current_bytes(self) -> int:
-        with self._lock:
-            return sum(self._buffers.values())
-
-    def hbm_buffers(self) -> Dict[str, int]:
-        with self._lock:
-            return dict(self._buffers)
-
     # ---- rollup --------------------------------------------------------
     def snapshot(self, force: bool = False) -> Optional[Dict[str, Any]]:
         """The `prof{}` artifact block (schema notes in obs/schema.py).
@@ -267,25 +200,15 @@ class Profiler:
         `force`."""
         with self._lock:
             sites = {n: s.as_dict() for n, s in self.sites.items()}
-            buffers = dict(self._buffers)
-            peak = self.hbm_peak_bytes
-        if not force and not sites and not buffers \
-                and self.mode == self.CHEAP:
+        if not force and not sites and self.mode == self.CHEAP:
             return None
         out: Dict[str, Any] = {"mode": self.mode, "sites": sites}
-        hbm: Dict[str, Any] = {"buffers": buffers, "peak_bytes": peak}
-        measured = _measured_peak()
-        if measured is not None:
-            hbm["measured_peak_bytes"] = measured
-        out["hbm"] = hbm
+        peak = self.hbm_peak_bytes
+        if peak is not None:
+            out["hbm"] = {"peak_bytes": peak}
         if self.xla_trace_dir:
             out["xla_trace_dir"] = self.xla_trace_dir
         return out
-
-
-def _measured_peak() -> Optional[int]:
-    from .telemetry import device_mem_high_water
-    return device_mem_high_water()
 
 
 def wrap(name: str, fn):
@@ -305,14 +228,6 @@ def wrap(name: str, fn):
     return profiled
 
 
-def note_buffer(name: str, nbytes) -> None:
-    """Module-level HBM-model convenience for engine code: a no-op
-    unless a live recorder (with a Profiler) is installed."""
-    prof = getattr(_cur(), "prof", None)
-    if prof is not None:
-        prof.note_buffer(name, nbytes)
-
-
 # ------------------------------------------------------- rollup helpers
 
 def attribution(summary: Dict[str, Any]) -> Dict[str, Any]:
@@ -321,9 +236,7 @@ def attribution(summary: Dict[str, Any]) -> Dict[str, Any]:
     works on any jaxmc.metrics/4 artifact."""
     prof = summary.get("prof") or {}
     sites = prof.get("sites") or {}
-    attributed = sum((s.get("wall_s") or 0.0)
-                     + (s.get("analysis_wall_s") or 0.0)
-                     for s in sites.values())
+    attributed = sum(s.get("wall_s") or 0.0 for s in sites.values())
     search = None
     for ph in summary.get("phases", []) or []:
         if ph.get("name") == "search":
@@ -355,7 +268,8 @@ def _fmt_bytes(n) -> str:
 def cmd_top(args, out=None) -> int:
     """`python -m jaxmc.obs top FILE` — the per-site table: wall,
     share of the search wall, dispatches, bytes per dispatch,
-    recompiles; plus the HBM model.  Exit 2 when the artifact carries
+    recompiles; plus the measured device peak and the compile seconds
+    per program (`compile.by_fun`).  Exit 2 when the artifact carries
     no prof block (pre-/4 artifact, or an un-instrumented run)."""
     import json
     import sys
@@ -401,14 +315,15 @@ def cmd_top(args, out=None) -> int:
         print(f"attributed {att['share'] * 100.0:.1f}% of the search "
               f"wall ({att['attributed_wall_s']:.3f}s of "
               f"{search:.3f}s)", file=out)
-    hbm = prof.get("hbm") or {}
-    bufs = hbm.get("buffers") or {}
-    if bufs or hbm.get("peak_bytes"):
-        meas = hbm.get("measured_peak_bytes")
-        print(f"hbm model: peak {_fmt_bytes(hbm.get('peak_bytes'))}"
-              + (f" (measured {_fmt_bytes(meas)})"
-                 if meas is not None else ""), file=out)
-        for bname in sorted(bufs, key=lambda b: -bufs[b]):
-            print(f"  {bname:<28} {_fmt_bytes(bufs[bname]):>12}",
-                  file=out)
+    peak = (prof.get("hbm") or {}).get("peak_bytes")
+    if peak:
+        print(f"hbm: measured peak {_fmt_bytes(peak)}", file=out)
+    by_fun = (summary.get("gauges") or {}).get("compile.by_fun") or {}
+    if by_fun:
+        print("xla compiles by program (a persistent-cache load "
+              "counts, at its load time):", file=out)
+        w = max(len(n) for n in by_fun)
+        for fname, (n, secs) in sorted(by_fun.items(),
+                                       key=lambda kv: -kv[1][1]):
+            print(f"  {fname:<{w}}  {n:>4}  {secs:9.3f}s", file=out)
     return 0

@@ -25,7 +25,8 @@ against import rot):
   top FILE               per-dispatch-site device profile of one
                          --profile artifact: wall, share of the
                          search wall, dispatches, bytes, recompiles,
-                         plus the HBM buffer model (obs/prof.py).
+                         plus the measured device peak and compile
+                         seconds per program (obs/prof.py).
   history [...]          per-rung states/sec trajectory across ALL
                          ledger-recorded runs, latest-vs-best-of-
                          window regression flags with env attribution
@@ -713,7 +714,8 @@ def main(argv: Optional[List[str]] = None, out=sys.stdout) -> int:
     tp = sub.add_parser(
         "top",
         help="per-dispatch-site profile table (wall, share, "
-             "dispatches, bytes, recompiles) + the HBM model from one "
+             "dispatches, bytes, recompiles), the measured device peak "
+             "and compile seconds per program from one "
              "--profile metrics artifact (jaxmc.metrics/4 prof{})")
     tp.add_argument("file")
     h = sub.add_parser(

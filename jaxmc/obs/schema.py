@@ -16,8 +16,10 @@ trace id, obs/context.py):
                                               span lineage + monotonic
                                               clock anchor
   run_start  {t, meta}
-  span_open  {name, t, parent, attrs}      -- partial-span forensics
-  span       {name, t0, wall_s, attrs[, error]}
+  span_open  {name, t, parent, attrs[, id, parent_id]}  -- partial-
+  span       {name, t0, wall_s, attrs[, id, parent_id, error]}  span
+                                              forensics; ids count
+                                              spans per recorder
   level      {level, t, frontier?, generated?, new?, distinct?, ...}
   heartbeat  {t, wall_s, rss_bytes, open_spans, last_level,
               progress_seq}                -- periodic watchdog beat
@@ -415,33 +417,22 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
             dispatches: int,              #   "mesh.superstep",
             recompiles: int,              #   "batch.vstep", ...
             wall_s?: float,               # block-until-ready wall
-            analysis_wall_s?: float,      # one-shot lowering retrace
             arg_bytes?: int,              # cumulative argument bytes
-            res_bytes?: int,              # cumulative result bytes
-            cost?: {flops?: int,          # one-shot AOT
-                    bytes_accessed?: int} # lowering cost_analysis
+            res_bytes?: int               # cumulative result bytes
           }, ... },
-          hbm: {
-            buffers: { <name>: bytes },   # the device-memory MODEL:
-                                          # resident.seen/.frontier/
-                                          # .accumulator/.candidates,
-                                          # mesh.seen_shards/.frontier/
-                                          # .trace_ring/.a2a_buckets,
-                                          # level.seen/.frontier, ...
-            peak_bytes: int,              # model high-water
-            measured_peak_bytes?: int     # cross-check: sum of
-                                          # device memory_stats()
-                                          # peak_bytes_in_use, when
-                                          # the backend exposes it
-          },
+          hbm?: {peak_bytes: int},        # MEASURED: sum of device
+                                          # memory_stats() peak_bytes_
+                                          # in_use; absent where the
+                                          # backend reports none
           xla_trace_dir?: str             # --profile=xla capture dir
         }
-      Cheap mode records counts/recompiles only; wall/xla add the
-      sync + byte surfaces.  Profiling NEVER changes results: counts
-      and traces stay bit-identical profile-on vs profile-off
-      (pinned by tests and `make prof-check`).
+      Cheap and xla modes record counts/recompiles only; wall adds
+      the sync + byte surfaces.  Profiling NEVER changes results:
+      counts and traces stay bit-identical profile-on vs profile-off
+      (pinned by tests and `make prof-check`).  Gauge `compile.by_fun`
+      {program: [compiles, seconds]} splits `compile.xla_compile_s`.
     - watchdog heartbeat events gain optional `device_mem_bytes` (the
-      HBM model's current total) next to `rss_bytes`; stall events
+      PROCESS's measured device peak) next to `rss_bytes`; stall events
       gain an optional dominant-site suffix in `msg` ("; 92% in
       mesh.superstep") naming where the wall concentrated at stall
       time.
@@ -617,6 +608,10 @@ def validate_trace_event(e: Dict[str, Any]) -> None:
     tkey = "t0" if e["ev"] == "span" else "t"
     if tkey not in e:
         raise ValueError(f"event {e['ev']!r} missing {tkey!r}")
+    if e["ev"] in ("span", "span_open"):
+        for key in ("id", "parent_id"):  # optional, since PR 24
+            if not isinstance(e.get(key), (int, type(None))):
+                raise ValueError(f"{e['ev']}.{key} is not an int")
     required = {"heartbeat": HEARTBEAT_KEYS, "stall": STALL_KEYS}.get(
         e["ev"])
     if required is None:

@@ -31,7 +31,7 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 from . import context as trace_context
-from .prof import Profiler  # per-dispatch attribution + HBM model
+from .prof import Profiler  # per-dispatch attribution
 from .schema import SCHEMA  # one source of truth for the artifact schema
 
 # every live recorder keeps the last N trace events in memory (the
@@ -73,7 +73,8 @@ class _SpanHandle:
     """Context manager for one phase span. Re-entrant use is not needed:
     each `span()` call makes a fresh handle."""
 
-    __slots__ = ("tel", "name", "attrs", "t0", "_done")
+    __slots__ = ("tel", "name", "attrs", "t0", "_done", "id", "parent_id",
+                 "_ann")
 
     def __init__(self, tel: "Telemetry", name: str, attrs: Dict[str, Any]):
         self.tel = tel
@@ -81,6 +82,7 @@ class _SpanHandle:
         self.attrs = attrs
         self.t0 = None
         self._done = False
+        self.id = self.parent_id = self._ann = None
 
     def __enter__(self):
         self.t0 = self.tel._clock()
@@ -188,6 +190,7 @@ class Telemetry(NullTelemetry):
         # watchdog's liveness signal: a run whose progress_seq stops
         # moving is wedged inside whatever span is still open
         self.progress_seq = 0
+        self._span_seq = 0  # span ids: 1.. in open order, per recorder
         self.meta: Dict[str, Any] = dict(meta or {})
         # phases aggregate spans by name, in first-start order
         self._phases: Dict[str, Dict[str, Any]] = {}
@@ -244,7 +247,7 @@ class Telemetry(NullTelemetry):
             return list(self._ring)
 
     # ---- spans ----
-    def _stack(self) -> List[str]:
+    def _stack(self) -> List[_SpanHandle]:
         st = getattr(self._local, "stack", None)
         if st is None:
             st = self._local.stack = []
@@ -257,21 +260,36 @@ class Telemetry(NullTelemetry):
     def _span_open(self, h: _SpanHandle) -> None:
         stack = self._stack()
         parent = stack[-1] if stack else None
-        stack.append(h.name)
+        stack.append(h)
+        h.parent_id = parent.id if parent else None
+        # the second sink: the same span on the profiler's clock, in
+        # /host:CPU of the xplane that holds the device line.  Outside a
+        # profiler session a TraceMe is a flag test; obs itself never
+        # imports jax
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            h._ann = jax.profiler.TraceAnnotation("jaxmc." + h.name)
+            h._ann.__enter__()
         with self._lock:
             self.progress_seq += 1
+            self._span_seq += 1
+            h.id = self._span_seq
             self._open_spans.append(h)
             ph = self._phases.setdefault(
                 h.name, {"name": h.name, "wall_s": 0.0, "count": 0,
                          "open": 0})
             ph["open"] += 1
         self._emit({"ev": "span_open", "name": h.name, "t": h.t0,
-                    "parent": parent, "attrs": h.attrs})
+                    "parent": parent.name if parent else None,
+                    "id": h.id, "parent_id": h.parent_id,
+                    "attrs": h.attrs})
 
     def _span_close(self, h: _SpanHandle, error: Optional[str]) -> None:
         t1 = self._clock()
+        if h._ann is not None:
+            h._ann.__exit__(None, None, None)
         stack = self._stack()
-        if stack and stack[-1] == h.name:
+        if stack and stack[-1] is h:
             stack.pop()
         with self._lock:
             self.progress_seq += 1
@@ -282,7 +300,8 @@ class Telemetry(NullTelemetry):
             ph["count"] += 1
             ph["open"] -= 1
         ev = {"ev": "span", "name": h.name, "t0": h.t0,
-              "wall_s": round(t1 - h.t0, 6), "attrs": h.attrs}
+              "wall_s": round(t1 - h.t0, 6), "id": h.id,
+              "parent_id": h.parent_id, "attrs": h.attrs}
         if error:
             ev["error"] = error
         self._emit(ev)

@@ -37,7 +37,7 @@ import threading
 import time
 from typing import Callable, Optional
 
-from .telemetry import rss_bytes
+from .telemetry import device_mem_high_water, rss_bytes
 
 
 def _median(xs):
@@ -134,7 +134,8 @@ class Watchdog:
     def _tick(self, now: float) -> None:
         tel = self.tel
         snap = tel.watch_snapshot()
-        if snap["progress_seq"] != self._last_seq:
+        moved = snap["progress_seq"] != self._last_seq
+        if moved:
             self._last_seq = snap["progress_seq"]
             self._last_change_t = now
             self._stalled = False
@@ -147,14 +148,15 @@ class Watchdog:
             last_level=snap["last_level"],
             progress_seq=snap["progress_seq"],
             stalled_for_s=round(stalled_for, 3))
-        prof = getattr(tel, "prof", None)
-        if prof is not None:
-            # ISSUE 17: device memory (the HBM model's current total)
-            # rides next to RSS — a beat that shows host memory flat
-            # while device buffers grew names the right suspect
-            dm = prof.hbm_current_bytes()
-            if dm:
-                beat["device_mem_bytes"] = dm
+        # device memory (the measured peak of the PROCESS, where the
+        # backend reports one) rides next to RSS — a beat that shows host
+        # memory flat while device buffers grew names the right suspect.
+        # Read only on a beat that saw progress: it is a call into the
+        # device runtime, and a quiet beat may be the start of the wedge
+        # this thread is here to name
+        dm = device_mem_high_water() if moved else None
+        if dm:
+            beat["device_mem_bytes"] = dm
         pe = getattr(tel, "progress_est", None)
         if pe is not None:  # ISSUE 16: the beat carries the live ETA
             ps = pe.snapshot()
@@ -180,6 +182,7 @@ class Watchdog:
             # ISSUE 17: name the dominant profiler site, turning "no
             # progress" into "no progress, 92% in mesh.superstep"
             dom = ""
+            prof = getattr(tel, "prof", None)
             if prof is not None:
                 ds = prof.dominant_site()
                 if ds is not None:

@@ -8,8 +8,7 @@ window is dispatch-dominated rather than compile-dominated:
   2. ON        `--profile` resume to the full cap, metrics artifact
                with a `prof{}` block: the per-site walls must account
                for >= JAXMC_PROF_CHECK_MIN_SHARE (default 0.90) of the
-               search phase wall (obs.prof_attribution), and the HBM
-               model must have registered resident buffers;
+               search phase wall (obs.prof_attribution);
   3. OFF       the identical resume WITHOUT --profile: generated /
                distinct / diameter / ok / truncated must be
                bit-identical to leg 2 — profiling observes the search,
@@ -190,15 +189,10 @@ def main(argv: Optional[List[str]] = None) -> int:
             print(f"PROF-CHECK ok {name} attribution: "
                   f"{share:.0%} of {att['search_wall_s']:.2f}s search "
                   f"wall across {len(prof['sites'])} sites")
-        hbm = (prof.get("hbm") or {})
-        if not hbm.get("peak_bytes"):
-            print(f"PROF-CHECK FAIL {name}: HBM model registered no "
-                  f"resident buffers", file=sys.stderr)
-            failures += 1
-        else:
-            print(f"PROF-CHECK ok {name} hbm: peak "
-                  f"{hbm['peak_bytes']:,} bytes over "
-                  f"{len(hbm.get('buffers') or {})} buffers")
+        peak = (prof.get("hbm") or {}).get("peak_bytes")
+        if peak:  # measured; XLA:CPU reports none
+            print(f"PROF-CHECK ok {name} hbm: measured peak "
+                  f"{peak:,} bytes")
 
     # ledger gate: the legs above appended; the real history must pass…
     if not os.path.exists(ledger):

@@ -81,7 +81,7 @@ class _ArtifactSeries:
             prof = self._Prof()
             prof.sites = {}
             prof.hbm_peak_bytes = \
-                (pb.get("hbm") or {}).get("peak_bytes", 0)
+                (pb.get("hbm") or {}).get("peak_bytes")
             for name, sd in sorted((pb.get("sites") or {}).items()):
                 st = self._Site()
                 st.dispatches = sd.get("dispatches", 0)
@@ -1602,8 +1602,10 @@ class ServeDaemon:
             fam[1].append((lbl, value))
 
         def add_prof(jid, jt):
-            # ISSUE 17: per-dispatch-site gauges plus the HBM model's
-            # peak, straight off the job recorder's always-on profiler
+            # per-dispatch-site gauges plus the MEASURED device peak
+            # (absent where the backend reports none; the PROCESS's
+            # peak, so the same under every job's label), straight off
+            # the job recorder's always-on profiler
             prof = getattr(jt, "prof", None)
             if prof is None:
                 return

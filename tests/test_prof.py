@@ -1,9 +1,10 @@
 r"""Device profiler (ISSUE 17, jaxmc/obs/prof.py): dispatch-site
-registry, profile-on/off parity, HBM accounting, the watchdog's new
-device-memory/dominant-site signals, and `python -m jaxmc.obs top`.
+registry, profile-on/off parity, the measured device peak, the
+watchdog's device-memory/dominant-site signals, and `python -m
+jaxmc.obs top`.
 
 The registry/rollup tests drive a Profiler directly with a fake clock
-(deterministic, no jax); the parity and HBM tests run the real resident
+(deterministic, no jax); the parity test runs the real resident
 engine on the constoy fixture, the same rung test_profile.py already
 pays for in tier-1.
 """
@@ -108,24 +109,29 @@ class TestSiteRegistry:
         assert wrapped.profiler_site == "t.wrapped"
 
 
-class TestHbmModel:
-    def test_note_buffer_peak_watermark(self):
-        p = Profiler()
-        p.note_buffer("seen", 1000)
-        p.note_buffer("frontier", 500)
-        assert p.hbm_current_bytes() == 1500
-        p.note_buffer("seen", 200)     # resize DOWN: current drops,
-        p.drop_buffer("frontier")      # peak stays
-        assert p.hbm_current_bytes() == 200
-        assert p.hbm_peak_bytes == 1500
-        assert p.hbm_buffers() == {"seen": 200}
+class TestMeasuredPeak:
+    """`prof.hbm.peak_bytes` is what the device reports
+    (`memory_stats()`), never a model; absent where it reports none."""
 
-    def test_module_level_note_buffer_needs_live_recorder(self):
-        obs.note_buffer("orphan", 99)  # NullTelemetry: silent no-op
-        tel = obs.Telemetry()
-        with obs.use(tel):
-            obs.note_buffer("live", 42)
-        assert tel.prof.hbm_buffers() == {"live": 42}
+    def test_peak_is_the_devices_own_or_absent(self, monkeypatch):
+        from jaxmc.obs import telemetry
+        p = Profiler()
+        p._site("t.site").dispatches = 1
+        monkeypatch.setattr(telemetry, "device_mem_high_water",
+                            lambda: None)
+        assert p.hbm_peak_bytes is None
+        assert "hbm" not in p.snapshot()
+        monkeypatch.setattr(telemetry, "device_mem_high_water",
+                            lambda: 208_000_000)
+        assert p.hbm_peak_bytes == 208_000_000
+        assert p.snapshot()["hbm"] == {"peak_bytes": 208_000_000}
+
+    def test_the_model_and_the_cost_analysis_are_gone(self):
+        p = Profiler(mode=Profiler.WALL)
+        assert not hasattr(p, "hbm_buffers")
+        p.record("t.site", lambda x: x, (1,), {})
+        assert set(p.sites["t.site"].as_dict()) == {
+            "dispatches", "recompiles", "wall_s"}
 
 
 class TestSnapshotRollup:
@@ -153,12 +159,11 @@ class TestSnapshotRollup:
         assert site["dispatches"] == 1
         assert site["wall_s"] == pytest.approx(0.5)
 
-    def test_attribution_sums_site_and_analysis_walls(self):
+    def test_attribution_sums_site_walls(self):
         summary = {
             "phases": [{"name": "search", "wall_s": 10.0}],
             "prof": {"mode": "wall", "sites": {
-                "a": {"dispatches": 2, "wall_s": 6.0,
-                      "analysis_wall_s": 1.0},
+                "a": {"dispatches": 2, "wall_s": 7.0},
                 "b": {"dispatches": 1, "wall_s": 2.0},
             }},
         }
@@ -166,11 +171,19 @@ class TestSnapshotRollup:
         assert att["attributed_wall_s"] == pytest.approx(9.0)
         assert att["share"] == pytest.approx(0.9)
 
+    def test_xla_mode_is_cheap_no_forced_sync(self):
+        clk = Clock()
+        p = Profiler(mode=Profiler.XLA, clock=clk)
+        p.record("t.site", lambda x: x, (np.zeros(4),), {})
+        st = p.sites["t.site"]
+        assert st.dispatches == 1
+        assert st.wall_s == 0.0 and st.arg_bytes == 0
+
 
 class TestResidentEngineProfiled:
     """The real thing: constoy through the resident engine with the
-    profiler in wall mode — named sites, HBM buffers, and profile-off
-    parity (the acceptance criterion at test scale)."""
+    profiler in wall mode — named sites and profile-off parity (the
+    acceptance criterion at test scale)."""
 
     @pytest.fixture()
     def model(self, monkeypatch, tmp_path):
@@ -191,8 +204,7 @@ class TestResidentEngineProfiled:
                             resident=True).run()
         return r
 
-    def test_profiled_run_names_sites_and_buffers_parity_off(
-            self, model):
+    def test_profiled_run_names_sites_parity_off(self, model):
         tel_on = obs.Telemetry()
         tel_on.prof.mode = Profiler.WALL
         r_on = self._run(model, tel_on)
@@ -200,15 +212,9 @@ class TestResidentEngineProfiled:
         assert "bfs.resident_run" in sites, sorted(sites)
         assert sites["bfs.resident_run"].dispatches >= 1
         assert sites["bfs.resident_run"].wall_s > 0
-        bufs = tel_on.prof.hbm_buffers()
-        assert any(b.startswith("resident.") for b in bufs), bufs
-        assert tel_on.prof.hbm_peak_bytes >= sum(bufs.values())
-        # envelope: the model never exceeds what the device reports
-        # (CPU usually exposes no memory_stats -> skip the cross-check)
+        # the peak is the device's own figure or absent, never a model
         from jaxmc.obs.telemetry import device_mem_high_water
-        measured = device_mem_high_water()
-        if measured:
-            assert tel_on.prof.hbm_peak_bytes <= measured
+        assert tel_on.prof.hbm_peak_bytes == device_mem_high_water()
         # parity: a cheap-mode (profile-off) run answers identically
         r_off = self._run(model, obs.Telemetry())
         assert (r_on.ok, r_on.generated, r_on.distinct,
@@ -228,9 +234,11 @@ class TestWatchdogSignals:
                           min_stall_s=30.0)
         return tel, wd, clk, trace, msgs
 
-    def test_heartbeat_carries_device_mem(self, tmp_path):
+    def test_heartbeat_carries_device_mem(self, tmp_path, monkeypatch):
+        from jaxmc.obs import watchdog
         tel, wd, clk, trace, _ = self._mk(tmp_path)
-        tel.prof.note_buffer("resident.seen", 4096)
+        monkeypatch.setattr(watchdog, "device_mem_high_water",
+                            lambda: 4096)
         clk.t += 5
         wd._tick(clk.t)
         tel.close()
@@ -238,6 +246,25 @@ class TestWatchdogSignals:
             evs = [json.loads(ln) for ln in fh if ln.strip()]
         (hb,) = [e for e in evs if e["ev"] == "heartbeat"]
         assert hb["device_mem_bytes"] == 4096
+
+    def test_a_quiet_beat_does_not_call_into_the_runtime(self, tmp_path,
+                                                         monkeypatch):
+        """The peak is a call into the device runtime: only a beat that
+        saw progress makes it, so it never stands before a stall line."""
+        from jaxmc.obs import watchdog
+        tel, wd, clk, trace, msgs = self._mk(tmp_path)
+        calls = []
+        monkeypatch.setattr(watchdog, "device_mem_high_water",
+                            lambda: calls.append(1) or 4096)
+        wd._tick(clk.t)          # latch: the first beat counts as progress
+        clk.t += 31
+        wd._tick(clk.t)          # 31 s of quiet: the stall line, no call
+        tel.close()
+        assert len(calls) == 1 and len(msgs) == 1
+        with open(trace) as fh:
+            evs = [json.loads(ln) for ln in fh if ln.strip()]
+        beats = [e for e in evs if e["ev"] == "heartbeat"]
+        assert ["device_mem_bytes" in b for b in beats] == [True, False]
 
     def test_stall_line_names_dominant_site(self, tmp_path):
         tel, wd, clk, trace, msgs = self._mk(tmp_path)
@@ -264,13 +291,14 @@ class TestObsTop:
                 "sites": {"bfs.resident_run": {
                     "dispatches": 3, "recompiles": 1, "wall_s": 3.6,
                     "arg_bytes": 3000, "res_bytes": 300}},
-                "hbm": {"buffers": {"resident.seen": 2048},
-                        "peak_bytes": 2048}}
+                "hbm": {"peak_bytes": 2048}}
+            art["gauges"]["compile.by_fun"] = {"run": [1, 61.25],
+                                               "step": [10, 4.5]}
         p = tmp_path / ("with.json" if with_prof else "without.json")
         p.write_text(json.dumps(art))
         return str(p)
 
-    def test_top_renders_sites_share_and_hbm(self, tmp_path):
+    def test_top_renders_sites_share_hbm_and_compiles(self, tmp_path):
         buf = io.StringIO()
         rc = obs_main(["top", self._artifact(tmp_path)], out=buf)
         out = buf.getvalue()
@@ -278,7 +306,12 @@ class TestObsTop:
         assert "bfs.resident_run" in out
         assert "90.0%" in out            # 3.6s of the 4.0s search wall
         assert "attributed" in out
-        assert "resident.seen" in out and "2.0KB" in out
+        assert "measured peak 2.0KB" in out
+        lines = out.splitlines()
+        (run_ln,) = [ln for ln in lines if ln.split()[:1] == ["run"]]
+        (step_ln,) = [ln for ln in lines if ln.split()[:1] == ["step"]]
+        assert run_ln.split()[1:] == ["1", "61.250s"]
+        assert lines.index(run_ln) < lines.index(step_ln)  # by seconds
 
     def test_top_exits_2_without_prof_block(self, tmp_path, capfd):
         rc = obs_main(["top", self._artifact(tmp_path,

@@ -396,8 +396,8 @@ class TestSigtermDrain:
 class TestMetricsRetention:
     """ISSUE 17 satellites: per-job /metrics series outlive the job for
     JAXMC_METRICS_JOB_TTL seconds (a coarse scraper still sees a short
-    job's final series), and jax jobs expose jaxmc_prof_site_* /
-    jaxmc_hbm_peak_bytes gauges from the always-on profiler."""
+    job's final series), and jax jobs expose jaxmc_prof_site_* gauges
+    from the always-on profiler plus the measured device peak."""
 
     def test_done_job_series_ttl_and_prof_gauges(self, daemon,
                                                  monkeypatch,
@@ -414,7 +414,16 @@ class TestMetricsRetention:
         assert f'jaxmc_job_running{{job="{jid}"}} 0' in body
         assert f'jaxmc_prof_site_dispatches{{job="{jid}",' \
                f'site="bfs.resident_run"}}' in body
-        assert f'jaxmc_hbm_peak_bytes{{job="{jid}"}}' in body
+        # the device peak is the MEASURED one or the series is omitted:
+        # XLA:CPU reports no memory_stats, so this job serves none...
+        assert "jaxmc_hbm_peak_bytes" not in body
+        # ...and a job whose artifact carries a measured peak serves it
+        from jaxmc.serve.daemon import _ArtifactSeries
+        with daemon._cv:
+            daemon._done_series["chipjob"] = (time.time(), _ArtifactSeries(
+                {"prof": {"sites": {}, "hbm": {"peak_bytes": 208273408}}}))
+        assert 'jaxmc_hbm_peak_bytes{job="chipjob"} 208273408' in \
+            daemon.metrics_text()
         # advance the metrics clock past the TTL: the series are pruned
         t0 = time.time()
         daemon._metrics_clock = \
