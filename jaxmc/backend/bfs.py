@@ -287,17 +287,35 @@ def _por_mask_np(found, cvalid, inst_arm, arm_safe, A, FC):
     return keep, n_ample, n_expanded
 
 
+# Rows of the seen table that _rank_merge gathers from at a time.  On
+# the TPU v5e a jnp.take of 2^22 distinct rows from the whole [2^22, 5]
+# table (84 MB) took 110 ms, 26 ns a row; from [2^20, 5] windows of it
+# the same rows took 15 ms, 3.5 ns a row, and [2^21, 5] windows did no
+# better (my chip runs, PR 25, PERF.md §6).
+_MERGE_BLOCK_ROWS = 1 << 20
+
+
 @jax.named_scope("jaxmc.merge.scatter")
 def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
     """The O(new) seen-merge core SHARED by the single-chip resident
-    level and the mesh rank-merge strategy (ISSUE 10; the
-    _candidate_block_fn-style shared-plumbing pattern): the seen table
-    keeps a sorted valid prefix [0:seen_count) as an INVARIANT, so a
-    level only sorts its ≤N incoming keys (_lsd_sort — while_loop
-    safe), dedups them against the prefix with vectorized binary
-    searches (_lower_bound) and scatters the genuinely-new keys at
-    their ranks.  No per-level re-sort of the seen table: the sort
-    work is O(N log N), not O((SC+N) log (SC+N)).
+    level, the level engine and the mesh rank-merge strategy (ISSUE 10;
+    the _candidate_block_fn-style shared-plumbing pattern): the seen
+    table keeps a sorted valid prefix [0:seen_count) as an INVARIANT,
+    so a level only sorts its ≤N incoming keys, dedups them against
+    the prefix with vectorized binary searches (_seen_probe) and
+    merges the genuinely-new keys in by rank.  No per-level re-sort of
+    the seen table: the sort work is O(N log N), not
+    O((SC+N) log (SC+N)).
+
+    Rows move by GATHER through an inverse index built from one SCALAR
+    scatter, never by a row scatter (ISSUE 25).  On the TPU v5e a row
+    scatter cost 39-117 ns a row (the [SC,5] seen2 write 117, the
+    [AccCap,4] compaction 39) against 4.3 ns a row for the jnp.take of
+    _lower_bound and 9 ns an index for a scalar scatter — all read in
+    one program's device trace (ledger, PR 24, cell desk-recheck-4p8)
+    — and the row scatters were 53-77 % of the device's busy time in
+    every benchmark cell.  On XLA:CPU, where this function was first
+    shaped, the two cost about the same.
 
     seen [SC, K] (validity lane first, prefix sorted by the K-1 data
     words), seen_count traced scalar, keys [N, K] unsorted candidate
@@ -316,11 +334,9 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
       seen_count2  seen_count + new_count (NOT cropped to SC).
 
     multikey=True sorts the candidate keys with ONE stable multi-key
-    lax.sort instead of the LSD chain — measured 3x faster on XLA:CPU
-    at mesh shapes, and a 5-key sort inside a while_loop compiles in
-    well under a second on current XLA (the mesh superstep uses it);
-    the single-chip resident engine keeps the LSD chain its compile
-    envelope was measured with."""
+    lax.sort instead of the LSD chain (the level and mesh engines use
+    it); the single-chip resident engine keeps the LSD chain its
+    compile envelope was measured with."""
     sidx = jnp.arange(N, dtype=jnp.int32)
     with jax.named_scope("jaxmc.merge.sort"):
         if multikey:
@@ -337,59 +353,67 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
         jnp.array([True]),
         jnp.any(skeys[1:] != skeys[:-1], axis=1)])
 
-    words = skeys[:, 1:]
     found, lb = _seen_probe(seen, seen_count, skeys, SC)
     new = svalid & ~found & neq_prev
     new_count = jnp.sum(new, dtype=jnp.int32)
 
-    # compact the new keys to the front (stable: key order kept).
-    # A cumsum-rank scatter, NOT a sort: the 1-key compaction sort
-    # this replaces was ~0.5s per level at mesh candidate-block
-    # shapes (ISSUE 11 phase-wall profile) while the scatter is tens
-    # of ms — and the order is identical, because cumsum ranks
-    # preserve the (already key-sorted) row order.  Dropped rows get
-    # DISTINCT out-of-range indices (N + sidx): unique_indices=True
-    # is a correctness promise to XLA (advisor r2 rule).
+    # the j-th new key (stable: key order kept) is the sorted row
+    # whose cumsum rank is j.  Only the original indices are wanted
+    # compacted, and a scalar scatter through the ranks does that.
+    # Dropped rows get DISTINCT out-of-range indices (N + sidx), here
+    # and below: unique_indices=True is a correctness promise to XLA
+    # (advisor r2 rule).
     npos = jnp.cumsum(new.astype(jnp.int32)) - 1
-    tgt = jnp.where(new, npos, N + sidx)
-    nk_words = jnp.zeros((N, K - 1), jnp.int32) \
-        .at[tgt].set(words, mode="drop", unique_indices=True)
     nk_sidx = jnp.zeros((N,), jnp.int32) \
-        .at[tgt].set(sidx_s, mode="drop", unique_indices=True)
-    nk_lb = jnp.zeros((N,), jnp.int32) \
-        .at[tgt].set(lb, mode="drop", unique_indices=True)
-    nvalid = sidx < new_count
+        .at[jnp.where(new, npos, N + sidx)] \
+        .set(sidx_s, mode="drop", unique_indices=True)
 
-    # rank merge into seen2: pos(new j) = lb_seen + j,
-    # pos(seen i) = i + ranks(i) — a bijection since new keys are
-    # distinct from seen keys.  ranks[i] = #{valid new j : key_j <
-    # seen[i]} needs NO second binary search: key_j < seen[i] iff its
-    # lower bound nk_lb[j] <= i, so a scatter-add histogram of the
-    # nk_lb values + one inclusive cumsum gives every seen row's
-    # shift in O(SC + N) cheap ops (the SC-query binary search this
-    # replaces measurably dominated the mesh merge wall, ISSUE 10)
-    hist = jnp.zeros((SC + 1,), jnp.int32)
-    hist = hist.at[jnp.where(nvalid, jnp.clip(nk_lb, 0, SC), SC)] \
-        .add(1)
-    ranks = jnp.cumsum(hist[:SC])
-    valid_seen_rows = jnp.arange(SC) < seen_count
-    # dropped (invalid) rows get DISTINCT out-of-range indices
-    # (SC + arange): unique_indices=True is a correctness promise to
-    # XLA, and funnelling every invalid row to the same index would be
-    # documented UB even though mode="drop" discards the writes
-    # (advisor r2)
-    pos_s = jnp.where(valid_seen_rows,
-                      jnp.arange(SC, dtype=jnp.int32) + ranks,
-                      SC + jnp.arange(SC, dtype=jnp.int32))
-    seen2 = jnp.full((SC, K), SENTINEL, jnp.int32)
-    seen2 = seen2.at[:, 0].set(1)  # invalid tail: validity lane 1
-    seen2 = seen2.at[pos_s].set(seen, mode="drop",
-                                unique_indices=True)
-    nk_full = jnp.concatenate(
-        [jnp.zeros((N, 1), jnp.int32), nk_words], axis=1)
-    pos_n = jnp.where(nvalid, nk_lb + sidx, SC + sidx)
-    seen2 = seen2.at[pos_n].set(nk_full, mode="drop",
-                                unique_indices=True)
+    # rank merge into seen2: pos(new j) = lb_j + j, strictly
+    # increasing, and the seen rows keep their order in the positions
+    # the new keys leave free — a bijection since new keys are
+    # distinct from seen keys.  So one scalar scatter writes each new
+    # key's sorted row number at its position (src, the inverse
+    # index; -1 elsewhere) and one inclusive cumsum c over the marked
+    # positions names every other row's source: seen row p - c below
+    # seen_count, the invalid tail (lane 1, SENTINEL words) past it.
+    # New keys whose position is >= SC park past the table and fall
+    # off, as do the seen rows they would have pushed past it.
+    #
+    # The seen rows of B consecutive output rows lie within B rows of
+    # the table, so seen2 is built B rows at a time, each block
+    # gathering from its own window (_MERGE_BLOCK_ROWS says why).  P
+    # rounds SC up to whole blocks; the engines' capacities are powers
+    # of two and P == SC.
+    B = min(SC, _MERGE_BLOCK_ROWS)
+    P = -(-SC // B) * B
+    pos_n = lb + npos
+    src = jnp.full((P,), -1, jnp.int32) \
+        .at[jnp.where(new & (pos_n < SC), pos_n, P + sidx)] \
+        .set(sidx, mode="drop", unique_indices=True)
+    c = jnp.cumsum((src >= 0).astype(jnp.int32))
+
+    tail = jnp.concatenate([jnp.ones((1, 1), jnp.int32),
+                            jnp.full((1, K - 1), SENTINEL, jnp.int32)],
+                           axis=1)
+
+    def block(p0):
+        src_b = lax.dynamic_slice(src, (p0,), (B,))
+        is_new = src_b >= 0
+        src_s = p0 + jnp.arange(B, dtype=jnp.int32) \
+            - lax.dynamic_slice(c, (p0,), (B,))
+        # the first seen row the block can need, and the window from
+        # it (the table's last B rows where that would run past SC)
+        at = jnp.minimum(src_s[0] + is_new[0], SC - B)
+        window = lax.dynamic_slice(seen, (at, 0), (B, K))
+        from_seen = jnp.take(window, jnp.clip(src_s - at, 0, B - 1),
+                             axis=0)
+        from_new = jnp.take(skeys, jnp.clip(src_b, 0, N - 1), axis=0)
+        is_seen = (src_s < seen_count)[:, None]
+        return jnp.where(is_new[:, None], from_new,
+                         jnp.where(is_seen, from_seen, tail))
+
+    seen2 = lax.map(block, jnp.arange(0, P, B, dtype=jnp.int32)) \
+        .reshape(P, K)[:SC]
     return dict(new_count=new_count, nk_sidx=nk_sidx, seen2=seen2,
                 seen_count2=seen_count + new_count)
 
@@ -1817,8 +1841,10 @@ class TpuExplorer:
 
             if rank:
                 # O(new): sort only the C candidate keys, dedup against
-                # the sorted seen prefix with binary searches, scatter
-                # the new keys at their ranks.  nk_sidx is each new
+                # the sorted seen prefix with binary searches, merge
+                # the new keys in by rank — rows fetched by gather, not
+                # scattered (a row scatter cost 9-27x a row gather on
+                # the v5e; ledger, PR 24).  nk_sidx is each new
                 # key's original candidate index in key-sorted order —
                 # exactly the full sort's new_cidx (stable ties keep
                 # first occurrence in both).  The caller pre-grows SC
@@ -2476,8 +2502,11 @@ class TpuExplorer:
             # The shared O(new) rank-merge core (_rank_merge, also the
             # mesh engine's merge strategy): the candidate block is
             # sorted by chained STABLE single-key passes and the
-            # seen-set is never re-sorted — new keys merge by rank (two
-            # vectorized binary searches + scatters), so the sort work
+            # seen-set is never re-sorted — new keys merge by rank
+            # (vectorized binary searches, then every row of seen2
+            # fetched by gather through an inverse index: the row
+            # scatters this replaced were 68-77 % of this engine's
+            # device time on the v5e; ledger, PR 24), so the sort work
             # is O(new), not O(seen), per level.
             rm = _rank_merge(seen, seen_count, acc_keys, AccCap, SC, K)
             with jax.named_scope("jaxmc.compact"):

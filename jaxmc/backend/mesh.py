@@ -27,7 +27,7 @@ path O(new) and multi-level): the seen shards, the packed frontier and
 the per-level trace ring all stay ON DEVICE across levels; one jitted
 shard_map dispatch runs up to maxlvl levels in a lax.while_loop — each
 level expands, exchanges, RANK-MERGES against the sorted seen shards
-(only the <=R incoming keys are sorted; two binary searches + scatters
+(only the <=R incoming keys are sorted; binary searches + row gathers
 shared with the single-chip resident engine, bfs._rank_merge — sort
 work no longer scales with the seen set; JAXMC_MESH_RANKMERGE=0 keeps
 the PR-8 full-sort as a bit-identical escape hatch, pinned to one
@@ -214,7 +214,7 @@ class MeshExplorer(TpuExplorer):
         # shard-local merge strategy (ISSUE 10): "rank" keeps each seen
         # shard's valid prefix SORTED as an invariant and merges only
         # the ≤R incoming keys by rank (the single-chip resident
-        # engine's O(new) binary-search scatter, shared via
+        # engine's O(new) binary-search merge, shared via
         # bfs._rank_merge); "fullsort" is the PR-8 full
         # [SC+R, K+1]-key stable sort, kept as the JAXMC_MESH_RANKMERGE=0
         # escape hatch (bit-identical counts/traces, pinned by tests).
@@ -623,8 +623,9 @@ class MeshExplorer(TpuExplorer):
         [VC]-bounded block (cumsum-rank scatter — stable, so candidate
         order and therefore counts/traces are bit-identical), then
         sort only those keys, dedup against the sorted seen prefix
-        with binary searches and scatter the new keys at their ranks —
-        the single-chip resident engine's merge (bfs._rank_merge),
+        with binary searches and merge the new keys in by rank (row
+        gathers) — the single-chip resident engine's merge
+        (bfs._rank_merge),
         shared rather than duplicated.  Sort work no longer scales
         with the seen shard OR the ~95%-padding candidate block;
         single-key-safe ops only, so the superstep while_loop can wrap

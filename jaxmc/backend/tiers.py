@@ -20,9 +20,9 @@ table empty; per-level survivors of the device rank-merge are then
 membership-probed against the cold runs (vectorized binary search per
 run) before they are counted distinct or explored.  Runs compact
 LSM-style with the SAME rank-merge row discipline as the device kernel
-(`_np_rank_merge` mirrors bfs._rank_merge's lower-bound + histogram
-scatter, host-side via numpy), and the host tier flushes to disk when
-it outgrows its key budget.
+(`_np_rank_merge` is bfs._rank_merge's rank arithmetic host-side via
+numpy: lower bound, histogram, fancy-index writes), and the host tier
+flushes to disk when it outgrows its key budget.
 
 Key order: rows of int32 words compared signed-lexicographically — the
 device sort order.  `_keyview` maps that order monotonically onto
