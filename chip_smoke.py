@@ -5,8 +5,8 @@ r"""chip_smoke.py — the quickest proof that jaxmc still starts on the chip.
 
 Drives the main path once through the entry points a user would call
 (`python -m jaxmc check`, `python -m jaxmc.serve run` + the HTTP
-protocol, and — with >= 4 devices — the sharded engine's
-`python -m jaxmc.meshbench child`), checks every count, verdict and
+protocol, and — with >= 4 devices — the sharded engine through
+`python -m jaxmc check --devices 4`), checks every count, verdict and
 trace against the corpus manifest pins (jaxmc/corpus.py: exact counts
 confirmed by the exact interpreter, the repo's semantic reference), and
 prints as its LAST stdout line
@@ -160,7 +160,7 @@ class Smoke:
             return rc, fh.read()
 
     def check(self, tag: str, leg: str, extra, want_rc: int = 0,
-              no_deadlock: bool = False):
+              no_deadlock: bool = False, same_count: bool = True):
         """`python -m jaxmc check` on the leg's rung; returns (artifact,
         stdout) after asserting rc, device and engine facts."""
         spec, cfg = self.paths(leg)
@@ -175,7 +175,7 @@ class Smoke:
              f"{_tail(os.path.join(self.out, tag + '.err'))})")
         with open(art) as fh:
             a = json.load(fh)
-        self.assert_device(tag, a)
+        self.assert_device(tag, a, same_count)
         need(a["result"].get("finished_on") == self.platform,
              f"{tag}: finished on {a['result'].get('finished_on')!r}")
         need(a["gauges"].get("expand.mode") == "compiled",
@@ -183,7 +183,7 @@ class Smoke:
         self.report(tag, a)
         return a, out
 
-    def assert_device(self, tag: str, a) -> None:
+    def assert_device(self, tag: str, a, same_count: bool = True) -> None:
         env = a.get("env") or {}
         need(env.get("platform") == self.platform,
              f"{tag}: ran on platform {env.get('platform')!r}, not "
@@ -198,6 +198,8 @@ class Smoke:
                "count": env["device_count"]}
         if self.device is None:
             self.device = dev
+        if not same_count:
+            dev = dict(dev, count=self.device["count"])
         need(dev == self.device,
              f"{tag}: device {dev} differs from the first leg's "
              f"{self.device}")
@@ -397,26 +399,18 @@ class Smoke:
             say(f"leg E: mesh: not run ({n} device) — a statement, "
                 f"not a pass")
             return
-        say("leg E: sharded engine over four devices, one process")
+        say("leg E: sharded engine over four devices, one process "
+            "(check --devices 4)")
         case = self.pin("E")
-        spec, cfg = self.paths("E")
-        art = os.path.join(self.out, "E_mesh.json")
-        rc, _ = self.run_child(
-            "E_mesh",
-            [sys.executable, "-m", "jaxmc.meshbench", "child", "--spec",
-             spec, "--cfg", cfg, "--devices", "4", "--metrics-out", art],
-            self.leg_timeout,
-            extra_env={"JAXMC_MESHBENCH_PLATFORM": self.platform})
-        need(rc == 0, f"E_mesh: exit {rc} (stderr tail: "
-                      f"{_tail(os.path.join(self.out, 'E_mesh.err'))})")
-        with open(art) as fh:
-            a = json.load(fh)
-        if not self.rehearsal:  # virtual CPU devices differ in count
-            self.assert_device("E_mesh", a)
-        need((a.get("env") or {}).get("platform") == self.platform,
-             f"E_mesh: ran on {(a.get('env') or {}).get('platform')!r}")
+        # the normal path; in the rehearsal the session asks XLA:CPU for
+        # its four host devices itself, so the count differs from leg A's
+        a, _ = self.check("E_mesh", "E", ["--devices", "4"],
+                          no_deadlock=case.no_deadlock,
+                          same_count=not self.rehearsal)
+        need(a["gauges"].get("mesh.devices") == 4,
+             f"E_mesh: mesh.devices={a['gauges'].get('mesh.devices')!r}, "
+             f"not the sharded engine over four")
         self.assert_counts("E_mesh", a["result"], case)
-        self.report("E_mesh", a)
         peaks = a["gauges"].get("mesh.device_peak_bytes")
         if self.rehearsal:
             return  # XLA:CPU reports no per-device memory

@@ -166,9 +166,36 @@ class MeshExplorer(TpuExplorer):
                  progress_every: float = 30.0, store_trace: bool = True,
                  exchange: Optional[str] = None,
                  mesh_caps: Optional[Dict[str, int]] = None, **kw):
+        # what this engine cannot honour it refuses BY NAME, before the
+        # kernel build is paid for — never dropped silently (ISSUE 26)
+        if kw.get("host_seen"):
+            raise ModeError(
+                "--host-seen is incompatible with the mesh engine "
+                "(--devices > 1): its seen set lives in the device "
+                "shards, there is no native host store to route to — "
+                "drop --host-seen or run on one device")
+        if kw.get("resident"):
+            raise ModeError(
+                "--resident is incompatible with the mesh engine "
+                "(--devices > 1): it selects the ONE-chip resident "
+                "loop; the mesh's own loop is already device-resident "
+                "for plain safety checks — drop --resident (--no-trace "
+                "skips the trace ring)")
         super().__init__(model, log=log, max_states=max_states,
                          progress_every=progress_every,
                          store_trace=store_trace, **kw)
+        if self.refiners or self.live_obligations:
+            # refinement/temporal PROPERTYs run the legacy host loop,
+            # which needs the per-level row stream and cannot resume
+            if not self.store_trace:
+                raise ModeError(
+                    "mesh refinement/temporal checking needs the "
+                    "per-level row stream: --no-trace is incompatible "
+                    "with PROPERTYs on the mesh engine")
+            if self.resume_from:
+                raise ModeError(
+                    "mesh resume with refinement/temporal PROPERTYs is "
+                    "not supported - use the single-chip device modes")
         if mesh is None:
             mesh = Mesh(np.array(jax.devices()), ("d",))
         self.mesh = mesh
@@ -186,7 +213,6 @@ class MeshExplorer(TpuExplorer):
         # refuse it the way bfs refuses resident/host_seen, instead of
         # silently fingerprinting past the requested contract
         if getattr(self, "seen_mode_req", "auto") == "exact":
-            from ..compile.vspec import ModeError
             raise ModeError(
                 "--seen exact is incompatible with the mesh engine "
                 "(seen shards store 128-bit fingerprints) — use the "
@@ -455,16 +481,18 @@ class MeshExplorer(TpuExplorer):
                 invalid_key = jnp.asarray(invalid_key_np)
                 # ICI exchange: gather all candidates + keys, keep my
                 # range
-                gcand = lax.all_gather(cand, "d", tiled=True)   # [R, PW]
-                gkeys = lax.all_gather(ckeys, "d", tiled=True)  # [R, K]
-                gsrc = jnp.arange(R, dtype=jnp.int32)
-                gvalid = gkeys[:, 0] == 0     # explicit validity lane
-                owner = self._owner_jnp(gkeys[:, 1])
-                mine = gvalid & (owner == me)
-                # foreign/invalid rows: validity lane 1 (sorts last),
-                # data lanes sentinel so equal keys cannot straddle the
-                # mask
-                gkeys = jnp.where(mine[:, None], gkeys, invalid_key)
+                with jax.named_scope("jaxmc.mesh.exchange"):
+                    gcand = lax.all_gather(cand, "d", tiled=True)
+                    gkeys = lax.all_gather(ckeys, "d", tiled=True)
+                with jax.named_scope("jaxmc.mesh.route"):
+                    gsrc = jnp.arange(R, dtype=jnp.int32)
+                    gvalid = gkeys[:, 0] == 0  # explicit validity lane
+                    owner = self._owner_jnp(gkeys[:, 1])
+                    mine = gvalid & (owner == me)
+                    # foreign/invalid rows: validity lane 1 (sorts
+                    # last), data lanes sentinel so equal keys cannot
+                    # straddle the mask
+                    gkeys = jnp.where(mine[:, None], gkeys, invalid_key)
                 zero = jnp.zeros((), jnp.int32)
                 return (gkeys, gcand, gsrc, zero, jnp.asarray(False),
                         zero, gvalid)
@@ -475,8 +503,8 @@ class MeshExplorer(TpuExplorer):
         SB = self._a2a_spill_bucket(B)
         R = D * (B + SB)
 
-        def route_a2a(ckeys, cand, cvalid, me):
-            invalid_key = jnp.asarray(invalid_key_np)
+        @jax.named_scope("jaxmc.mesh.route")
+        def place(ckeys, cand, cvalid, me):
             # hash-route each candidate straight to its owner:
             # bucket-sort by destination, scatter into [D, B] slots,
             # one all_to_all; rows past B land in the [D, SB] SPILL
@@ -516,6 +544,11 @@ class MeshExplorer(TpuExplorer):
             b2 = jnp.full((D * SB + 1, Pw), SENTINEL, jnp.int32)
             b2 = b2.at[:, 0].set(1)
             b2 = b2.at[slot2].set(payload, mode="drop")
+            return b1, b2, spill_local, a2a_ovf, maxdest_local
+
+        @jax.named_scope("jaxmc.mesh.exchange")
+        def swap(b1, b2):
+            invalid_key = jnp.asarray(invalid_key_np)
             recv1 = lax.all_to_all(
                 b1[:D * B].reshape(D, B, Pw), "d",
                 split_axis=0, concat_axis=0).reshape(D * B, Pw)
@@ -530,6 +563,12 @@ class MeshExplorer(TpuExplorer):
             # routed rows are mine by construction; invalid slots keep
             # the sorts-last key shape
             gkeys = jnp.where(gvalid[:, None], gkeys, invalid_key)
+            return gkeys, gcand, gsrc, gvalid
+
+        def route_a2a(ckeys, cand, cvalid, me):
+            b1, b2, spill_local, a2a_ovf, maxdest_local = place(
+                ckeys, cand, cvalid, me)
+            gkeys, gcand, gsrc, gvalid = swap(b1, b2)
             return (gkeys, gcand, gsrc, spill_local, a2a_ovf,
                     maxdest_local, gvalid)
 
@@ -593,12 +632,14 @@ class MeshExplorer(TpuExplorer):
         con_fns = self.constraint_fns
         inv_fns = self.inv_fns
 
+        @jax.named_scope("jaxmc.compact")
         def finish(new_rows, new_src, nvalid):
-            new_rows_u = plan.unpack_rows(new_rows) \
-                if (con_fns or inv_fns) else new_rows
-            explore = nvalid
-            for nm, f in con_fns:
-                explore = explore & jax.vmap(f)(new_rows_u)
+            with jax.named_scope("jaxmc.scan"):
+                new_rows_u = plan.unpack_rows(new_rows) \
+                    if (con_fns or inv_fns) else new_rows
+                explore = nvalid
+                for nm, f in con_fns:
+                    explore = explore & jax.vmap(f)(new_rows_u)
             idx4 = jnp.arange(R, dtype=jnp.int32)
             pos = jnp.cumsum(explore.astype(jnp.int32)) - 1
             tgt = jnp.where(explore, pos, R + idx4)
@@ -639,33 +680,38 @@ class MeshExplorer(TpuExplorer):
             v_ovf = jnp.asarray(False)
             v_need = jnp.asarray(0, jnp.int32)
             if compact:
-                gvalid = gkeys[:, 0] == 0
-                v_need = jnp.sum(gvalid, dtype=jnp.int32)
-                v_ovf = v_need > VC
-                pos = jnp.cumsum(gvalid.astype(jnp.int32)) - 1
-                # invalid rows park at R+i: distinct, >= VC (dropped),
-                # and disjoint from every valid pos (pos <= R-1) even
-                # when v_need > VC — duplicate indices, dropped or
-                # not, would break the unique_indices promise below
-                tgt = jnp.where(gvalid, pos,
-                                R + jnp.arange(R, dtype=jnp.int32))
-                ck = jnp.full((VC, K), SENTINEL, jnp.int32)
-                ck = ck.at[:, 0].set(1)  # empty slots: validity lane 1
-                gkeys = ck.at[tgt].set(gkeys, mode="drop",
-                                       unique_indices=True)
-                gcand = jnp.full((VC, PW), SENTINEL, jnp.int32) \
-                    .at[tgt].set(gcand, mode="drop",
-                                 unique_indices=True)
-                gsrc = jnp.zeros((VC,), jnp.int32) \
-                    .at[tgt].set(gsrc, mode="drop", unique_indices=True)
+                with jax.named_scope("jaxmc.compact"):
+                    gvalid = gkeys[:, 0] == 0
+                    v_need = jnp.sum(gvalid, dtype=jnp.int32)
+                    v_ovf = v_need > VC
+                    pos = jnp.cumsum(gvalid.astype(jnp.int32)) - 1
+                    # invalid rows park at R+i: distinct, >= VC
+                    # (dropped), and disjoint from every valid pos
+                    # (pos <= R-1) even when v_need > VC — duplicate
+                    # indices, dropped or not, would break the
+                    # unique_indices promise below
+                    tgt = jnp.where(gvalid, pos,
+                                    R + jnp.arange(R, dtype=jnp.int32))
+                    ck = jnp.full((VC, K), SENTINEL, jnp.int32)
+                    ck = ck.at[:, 0].set(1)  # empty: validity lane 1
+                    gkeys = ck.at[tgt].set(gkeys, mode="drop",
+                                           unique_indices=True)
+                    gcand = jnp.full((VC, PW), SENTINEL, jnp.int32) \
+                        .at[tgt].set(gcand, mode="drop",
+                                     unique_indices=True)
+                    gsrc = jnp.zeros((VC,), jnp.int32) \
+                        .at[tgt].set(gsrc, mode="drop",
+                                     unique_indices=True)
             rm = _rank_merge(seen_keys, seen_count, gkeys, N, SC, K,
                              multikey=True)
             new_count = rm["new_count"]
             nvalid = jnp.arange(N) < new_count
-            safe = jnp.clip(rm["nk_sidx"], 0, N - 1)
-            new_rows = jnp.take(gcand, safe, axis=0)
-            new_src = jnp.take(gsrc, safe)
-            new_rows = jnp.where(nvalid[:, None], new_rows, SENTINEL)
+            with jax.named_scope("jaxmc.compact"):
+                safe = jnp.clip(rm["nk_sidx"], 0, N - 1)
+                new_rows = jnp.take(gcand, safe, axis=0)
+                new_src = jnp.take(gsrc, safe)
+                new_rows = jnp.where(nvalid[:, None], new_rows,
+                                     SENTINEL)
             front_rows, front_rows_u, front_src, front_count = \
                 finish(new_rows, new_src, nvalid)
             return dict(seen2=rm["seen2"],
@@ -724,6 +770,7 @@ class MeshExplorer(TpuExplorer):
         invalid_key_np = np.concatenate(
             [np.ones(1, np.int32), np.full(K - 1, SENTINEL, np.int32)])
 
+        @jax.named_scope("jaxmc.merge.sort")
         def merge(seen_keys, seen_count, gkeys, gcand, gsrc):
             invalid_key = jnp.asarray(invalid_key_np)
             srow_valid = jnp.arange(SC) < seen_count
@@ -784,6 +831,7 @@ class MeshExplorer(TpuExplorer):
 
         return merge
 
+    @jax.named_scope("jaxmc.scan")
     def _inv_scan(self, front_rows_u, front_count, R: int):
         """Named invariants: index of the FIRST cfg invariant any kept
         row violates, plus the first violating slot."""
@@ -973,11 +1021,15 @@ class MeshExplorer(TpuExplorer):
             # ICI traffic the reduction is meant to save).
             pora = porx = porm = jnp.int32(0)
             if por_plan is not None:
-                allk = lax.all_gather(ckeys, "d")         # [D, C, K]
+                with jax.named_scope("jaxmc.mesh.exchange"):
+                    allk = lax.all_gather(ckeys, "d")     # [D, C, K]
                 fl, _ = _seen_probe(seen_keys, seen_count,
                                     allk.reshape(D * C, K), SC)
-                fg = lax.psum(fl.astype(jnp.int32), "d").reshape(D, C)
-                found = lax.dynamic_slice_in_dim(fg, me, 1, 0)[0] > 0
+                with jax.named_scope("jaxmc.mesh.exchange"):
+                    fg = lax.psum(fl.astype(jnp.int32),
+                                  "d").reshape(D, C)
+                    found = lax.dynamic_slice_in_dim(
+                        fg, me, 1, 0)[0] > 0
                 keep, pora, porx = _por_mask(
                     found, cvalid, por_inst, por_safe_v, A, FC)
                 porm = jnp.sum(cvalid & ~keep, dtype=jnp.int32)
@@ -1001,18 +1053,19 @@ class MeshExplorer(TpuExplorer):
                                                  front_count, N)
 
             # ---- capacity verdicts (replicated) ----
-            f_ovf = lax.psum((front_count > FC).astype(jnp.int32),
-                             "d") > 0
-            s_ovf = lax.psum((seen_count2 > SC).astype(jnp.int32),
-                             "d") > 0
-            t_ovf = (jnp.asarray(with_trace) & (lvl >= TRL)) \
-                if with_trace else jnp.asarray(False)
-            any_a2a_ovf = lax.psum(a2a_ovf.astype(jnp.int32),
-                                   "d") > 0
-            v_ovf = lax.psum(mg["v_ovf"].astype(jnp.int32),
-                             "d") > 0
-            grow = f_ovf | s_ovf | t_ovf | any_a2a_ovf | v_ovf
-            commit = ~grow
+            with jax.named_scope("jaxmc.mesh.scalars"):
+                f_ovf = lax.psum((front_count > FC).astype(jnp.int32),
+                                 "d") > 0
+                s_ovf = lax.psum((seen_count2 > SC).astype(jnp.int32),
+                                 "d") > 0
+                t_ovf = (jnp.asarray(with_trace) & (lvl >= TRL)) \
+                    if with_trace else jnp.asarray(False)
+                any_a2a_ovf = lax.psum(a2a_ovf.astype(jnp.int32),
+                                       "d") > 0
+                v_ovf = lax.psum(mg["v_ovf"].astype(jnp.int32),
+                                 "d") > 0
+                grow = f_ovf | s_ovf | t_ovf | any_a2a_ovf | v_ovf
+                commit = ~grow
 
             # ---- commit or roll back the device state ----
             seen_out = jnp.where(commit, mg["seen2"], seen_keys)
@@ -1043,61 +1096,62 @@ class MeshExplorer(TpuExplorer):
                 tr_rows_out = tr_src_out = None
 
             # ---- the per-level scalar vector (replicated) ----
-            tot_new = lax.psum(front_count, "d")
-            ovc = lax.pmax(overflow, "d")
-            tot_dead = lax.psum(dead_local.astype(jnp.int32), "d")
-            tot_assert = lax.psum(
-                assert_bad.astype(jnp.int32), "d")
-            inv_min = lax.pmin(inv_which, "d")
-            scal = jnp.zeros((_NS,), jnp.int32)
-            scal = scal.at[_S_GEN].set(
-                lax.psum(gen_local, "d"))
-            scal = scal.at[_S_NEW].set(tot_new)
-            scal = scal.at[_S_FRONT].set(tot_new)
-            scal = scal.at[_S_MAXF].set(lax.pmax(front_count, "d"))
-            scal = scal.at[_S_MAXS].set(lax.pmax(seen_count2, "d"))
-            scal = scal.at[_S_SUMS].set(lax.psum(seen_count2, "d"))
-            scal = scal.at[_S_OVC].set(ovc)
-            scal = scal.at[_S_DEAD].set(tot_dead)
-            scal = scal.at[_S_ASSERT].set(tot_assert)
-            scal = scal.at[_S_INVMIN].set(inv_min)
-            scal = scal.at[_S_FOVF].set(f_ovf.astype(jnp.int32))
-            scal = scal.at[_S_SOVF].set(s_ovf.astype(jnp.int32))
-            scal = scal.at[_S_TOVF].set(t_ovf.astype(jnp.int32))
-            scal = scal.at[_S_AOVF].set(
-                any_a2a_ovf.astype(jnp.int32))
-            scal = scal.at[_S_SPILL].set(
-                lax.psum(spill_local, "d"))
-            scal = scal.at[_S_MAXDEST].set(lax.pmax(maxdest, "d"))
-            scal = scal.at[_S_VOVF].set(v_ovf.astype(jnp.int32))
-            scal = scal.at[_S_MAXV].set(
-                lax.pmax(mg["v_need"], "d"))
-            scal = scal.at[_S_PORA].set(lax.psum(pora, "d"))
-            scal = scal.at[_S_PORX].set(lax.psum(porx, "d"))
-            scal = scal.at[_S_PORM].set(lax.psum(porm, "d"))
+            with jax.named_scope("jaxmc.mesh.scalars"):
+                tot_new = lax.psum(front_count, "d")
+                ovc = lax.pmax(overflow, "d")
+                tot_dead = lax.psum(dead_local.astype(jnp.int32), "d")
+                tot_assert = lax.psum(
+                    assert_bad.astype(jnp.int32), "d")
+                inv_min = lax.pmin(inv_which, "d")
+                scal = jnp.zeros((_NS,), jnp.int32)
+                scal = scal.at[_S_GEN].set(
+                    lax.psum(gen_local, "d"))
+                scal = scal.at[_S_NEW].set(tot_new)
+                scal = scal.at[_S_FRONT].set(tot_new)
+                scal = scal.at[_S_MAXF].set(lax.pmax(front_count, "d"))
+                scal = scal.at[_S_MAXS].set(lax.pmax(seen_count2, "d"))
+                scal = scal.at[_S_SUMS].set(lax.psum(seen_count2, "d"))
+                scal = scal.at[_S_OVC].set(ovc)
+                scal = scal.at[_S_DEAD].set(tot_dead)
+                scal = scal.at[_S_ASSERT].set(tot_assert)
+                scal = scal.at[_S_INVMIN].set(inv_min)
+                scal = scal.at[_S_FOVF].set(f_ovf.astype(jnp.int32))
+                scal = scal.at[_S_SOVF].set(s_ovf.astype(jnp.int32))
+                scal = scal.at[_S_TOVF].set(t_ovf.astype(jnp.int32))
+                scal = scal.at[_S_AOVF].set(
+                    any_a2a_ovf.astype(jnp.int32))
+                scal = scal.at[_S_SPILL].set(
+                    lax.psum(spill_local, "d"))
+                scal = scal.at[_S_MAXDEST].set(lax.pmax(maxdest, "d"))
+                scal = scal.at[_S_VOVF].set(v_ovf.astype(jnp.int32))
+                scal = scal.at[_S_MAXV].set(
+                    lax.pmax(mg["v_need"], "d"))
+                scal = scal.at[_S_PORA].set(lax.psum(pora, "d"))
+                scal = scal.at[_S_PORX].set(lax.psum(porx, "d"))
+                scal = scal.at[_S_PORM].set(lax.psum(porm, "d"))
 
-            # per-device localization vector (fetched only on
-            # violation — always the LAST executed level's, because
-            # every violation stops the superstep)
-            aux = jnp.zeros((_NA,), jnp.int32)
-            aux = aux.at[_A_INVW].set(inv_which)
-            aux = aux.at[_A_INVSLOT].set(inv_slot)
-            aux = aux.at[_A_DEAD].set(dead_local.astype(jnp.int32))
-            aux = aux.at[_A_DEADSLOT].set(dead_slot)
-            aux = aux.at[_A_ASSERT].set(
-                assert_bad.astype(jnp.int32))
-            aux = aux.at[_A_ASRTA].set(asrt_a)
-            aux = aux.at[_A_ASRTF].set(asrt_f)
+                # per-device localization vector (fetched only on
+                # violation — always the LAST executed level's, because
+                # every violation stops the superstep)
+                aux = jnp.zeros((_NA,), jnp.int32)
+                aux = aux.at[_A_INVW].set(inv_which)
+                aux = aux.at[_A_INVSLOT].set(inv_slot)
+                aux = aux.at[_A_DEAD].set(dead_local.astype(jnp.int32))
+                aux = aux.at[_A_DEADSLOT].set(dead_slot)
+                aux = aux.at[_A_ASSERT].set(
+                    assert_bad.astype(jnp.int32))
+                aux = aux.at[_A_ASRTA].set(asrt_a)
+                aux = aux.at[_A_ASRTF].set(asrt_f)
 
-            # ---- superstep exit verdict (replicated) ----
-            dist2 = jnp.where(commit, dist + tot_new, dist)
-            viol = (inv_min != _BIG) | (tot_dead > 0) | \
-                (tot_assert > 0) | (ovc != 0)
-            trunc = commit & (max_states > 0) & \
-                (dist2 >= max_states)
-            done = commit & (tot_new == 0)
-            stop = grow | viol | trunc | done
-            lvl2 = jnp.where(commit, lvl + 1, lvl)
+                # ---- superstep exit verdict (replicated) ----
+                dist2 = jnp.where(commit, dist + tot_new, dist)
+                viol = (inv_min != _BIG) | (tot_dead > 0) | \
+                    (tot_assert > 0) | (ovc != 0)
+                trunc = commit & (max_states > 0) & \
+                    (dist2 >= max_states)
+                done = commit & (tot_new == 0)
+                stop = grow | viol | trunc | done
+                lvl2 = jnp.where(commit, lvl + 1, lvl)
             return (seen_out, seen_count_out, frontier_out,
                     fcount_out, tr_rows_out, tr_src_out, lvl2,
                     dist2, scal, aux, stop)
@@ -1189,11 +1243,13 @@ class MeshExplorer(TpuExplorer):
                 the SHARED level tail (_mk_level_tail — the grouped
                 expansion step runs the same tail, so the two step
                 shapes cannot drift)."""
-                frontier = plan.unpack_rows(frontier_p)
-                fvalid = jnp.arange(FC) < fcount
-                blk = block_fn(frontier, fvalid)
-                dead_local = (jnp.any(blk["dead"]) if check_deadlock
-                              else jnp.asarray(False))
+                with jax.named_scope("jaxmc.expand"):
+                    frontier = plan.unpack_rows(frontier_p)
+                    fvalid = jnp.arange(FC) < fcount
+                    blk = block_fn(frontier, fvalid)
+                    dead_local = (jnp.any(blk["dead"])
+                                  if check_deadlock
+                                  else jnp.asarray(False))
                 return tail(seen_keys, seen_count, frontier_p, fcount,
                             tr_rows, tr_src, lvl, dist, max_states, me,
                             blk["ckeys"], blk["cand"], blk["cvalid"],
@@ -1221,8 +1277,9 @@ class MeshExplorer(TpuExplorer):
                         trs if with_trace else None, lvl, dist)
                     if with_trace:
                         trr, trs = trr2, trs2
-                    ring = lax.dynamic_update_slice(ring, scal[None],
-                                                    (nlv, 0))
+                    with jax.named_scope("jaxmc.mesh.scalars"):
+                        ring = lax.dynamic_update_slice(
+                            ring, scal[None], (nlv, 0))
                     return (sk, sc_, fp, fc_, trr, trs, lvl, dist,
                             nlv + 1, ring, aux, stop)
 
@@ -1736,26 +1793,35 @@ class MeshExplorer(TpuExplorer):
     def _run_mesh_resident(self) -> CheckResult:
         t0 = time.time()
         tel = obs.current()
-        model = self.model
-        D, K, PW = self.D, self.K, self.PW
         warnings = ["mesh backend: dedup on 128-bit fingerprints; "
                     "collision probability < n^2 * 2^-129"]
         warnings.extend(self._temporal_warnings())
         warnings.extend(self._symmetry_warnings())
         warnings.extend(self._por_warnings())
 
-        init_rows, explored_init, n_init, err = \
-            self._prepare_init(t0, warnings)
+        with tel.span("search.init"):
+            init_rows, explored_init, n_init, err = \
+                self._prepare_init(t0, warnings)
         if err is not None:
             return err
-        generated = n_init
         explored_mask = np.zeros(n_init, bool)
         explored_mask[explored_init] = True
-        distinct = int(explored_mask.sum())
 
         self._levels: List[Tuple[np.ndarray, Optional[np.ndarray], int]] \
             = []
         self._lvl_FC = []
+        with tel.span("search.seed"):
+            seeded = self._mesh_seed(init_rows, explored_mask)
+        return self._mesh_supersteps(t0, warnings, *seeded)
+
+    def _mesh_seed(self, init_rows, explored_mask):
+        """Shard construction for one search: the owner-hashed init
+        shards (or a checkpoint's) and the trace ring, `_put` on the
+        mesh.  The uploads are asynchronous: `search.seed` ends when
+        they are enqueued.  Returns what `_mesh_supersteps` takes."""
+        D, K, PW = self.D, self.K, self.PW
+        generated = len(explored_mask)
+        distinct = int(explored_mask.sum())
         hint = self._mesh_caps_hint
 
         if self.resume_from:
@@ -1846,7 +1912,19 @@ class MeshExplorer(TpuExplorer):
             # _levels beyond the init level will be re-materialized from
             # the ring on demand; keep only level 0 host-side
             del self._levels[1:]
+        return (seen, seen_count, frontier, fcount, tr_rows, tr_src, SC,
+                FC, TRL, depth, generated, distinct)
 
+    def _mesh_supersteps(self, t0, warnings, seen, seen_count, frontier,
+                         fcount, tr_rows, tr_src, SC: int, FC: int,
+                         TRL: int, depth: int, generated: int,
+                         distinct: int) -> CheckResult:
+        """The host side of the resident loop: one dispatch and one
+        scalar-ring drain per superstep, until the frontier is empty
+        or a verdict stops the search."""
+        tel = obs.current()
+        model = self.model
+        D, PW = self.D, self.PW
         last_progress = last_ck = time.time()
         lvl_frontier = int(np.sum(np.asarray(fcount)))
         # rank-merge valid-candidate capacity (ISSUE 11): starts at the
@@ -1896,27 +1974,39 @@ class MeshExplorer(TpuExplorer):
             # probe at the host boundary: pin supersteps to one level
             eff_maxlvl = 1 if (self._tiers is not None
                                and self._tiers.active) else maxlvl
-            args = args + (jnp.int32(depth), jnp.int32(eff_maxlvl),
-                           jnp.int32(distinct),
-                           jnp.int32(self.max_states or 0))
-            outs = step(*args)
-            if self.store_trace:
-                (seen2, seen_count2, frontier2, fcount2, tr_rows2,
-                 tr_src2, ring_d, nlv_d, aux_d) = outs
-            else:
-                (seen2, seen_count2, frontier2, fcount2, ring_d,
-                 nlv_d, aux_d) = outs
-                tr_rows2 = tr_src2 = None
+            with tel.span("search.dispatch", maxlvl=eff_maxlvl,
+                          fresh_compile=fresh_compile):
+                args = args + (jnp.int32(depth), jnp.int32(eff_maxlvl),
+                               jnp.int32(distinct),
+                               jnp.int32(self.max_states or 0))
+                outs = step(*args)
+                if self.store_trace:
+                    (seen2, seen_count2, frontier2, fcount2, tr_rows2,
+                     tr_src2, ring_d, nlv_d, aux_d) = outs
+                else:
+                    (seen2, seen_count2, frontier2, fcount2, ring_d,
+                     nlv_d, aux_d) = outs
+                    tr_rows2 = tr_src2 = None
+                jax.block_until_ready(ring_d)
             # THE one host sync of the superstep: the replicated
             # per-level scalar ring + its occupancy (every per-device
             # row is identical; tiny).  mesh.host_syncs therefore
             # counts SUPERSTEPS, not levels (obs/schema.py PR-10).
-            ring = np.asarray(ring_d)[0]
-            nlv = max(1, int(np.asarray(nlv_d)[0]))
+            with tel.span("search.fetch"):
+                ring = np.asarray(ring_d)[0]
+                nlv = max(1, int(np.asarray(nlv_d)[0]))
             disp_wall = time.time() - lvl_t0
             tel.counter("mesh.host_syncs")
             tel.counter("mesh.exchange_bytes",
                         self._exchange_bytes(C, B, SB) * nlv)
+            # work against capacity, summed over the shards (PERF.md
+            # §3): every level the dispatch ran — a rolled-back one too
+            # — sorted the merge's N key slots and rewrote SC seen rows
+            # on each of the D shards, whatever was valid
+            R = D * (B + SB) if B else D * C  # rows a shard receives
+            tel.counter("search.slots_sorted",
+                        nlv * D * self._merge_out_rows(R, VC))
+            tel.counter("search.seen_slots", nlv * D * SC)
             self._supersteps += 1
             self._superstep_levels_max = max(self._superstep_levels_max,
                                              nlv)
@@ -2092,6 +2182,8 @@ class MeshExplorer(TpuExplorer):
 
                 generated += int(scal[_S_GEN])
                 distinct += int(scal[_S_NEW])
+                tel.counter("search.rows_valid", int(scal[_S_GEN]))
+                tel.counter("search.rows_new", int(scal[_S_NEW]))
                 self._por_stats["ample"] += int(scal[_S_PORA])
                 self._por_stats["expanded"] += int(scal[_S_PORX])
                 self._por_stats["masked"] += int(scal[_S_PORM])
@@ -2175,28 +2267,31 @@ class MeshExplorer(TpuExplorer):
                               fcount, FC, SC, depth, generated,
                               distinct)
 
-        if self._ss_fixed is None and not self._ss_shrunk:
-            # fast models: remember enough budget to cover the whole
-            # search in ONE dispatch on a warm re-run (the early exit
-            # stops at the empty frontier, so over-budget is free) —
-            # but never after the controller had to shrink: a budget
-            # it judged too slow must stay retired
-            self._mesh_maxlvl_warm = min(
-                max(depth + 1, self._mesh_maxlvl_warm), _SS_RINGCAP)
-        self._save_mesh_profile(SC, FC, TRL, VC)
-        if self.checkpoint_path and self.final_checkpoint:
-            # COMPLETED-run checkpoint (serve warm resume): an empty
-            # frontier over the full seen set
-            self._ring_levels(tr_rows, tr_src, depth)
-            self._mesh_ck(seen, np.asarray(seen_count),
-                          np.zeros((D, FC, PW), np.int32),
-                          np.zeros(D, np.int32),
-                          FC, SC, depth, generated, distinct)
-        self.log("Model checking completed. No error has been found.")
-        self.log(f"{generated} states generated, {distinct} distinct "
-                 f"states found, 0 states left on queue.")
-        return self._mk(True, distinct, generated, depth - 1, t0,
-                        warnings)
+        with tel.span("search.finish"):
+            if self._ss_fixed is None and not self._ss_shrunk:
+                # fast models: remember enough budget to cover the
+                # whole search in ONE dispatch on a warm re-run (the
+                # early exit stops at the empty frontier, so
+                # over-budget is free) — but never after the controller
+                # had to shrink: a budget it judged too slow must stay
+                # retired
+                self._mesh_maxlvl_warm = min(
+                    max(depth + 1, self._mesh_maxlvl_warm), _SS_RINGCAP)
+            self._save_mesh_profile(SC, FC, TRL, VC)
+            if self.checkpoint_path and self.final_checkpoint:
+                # COMPLETED-run checkpoint (serve warm resume): an
+                # empty frontier over the full seen set
+                self._ring_levels(tr_rows, tr_src, depth)
+                self._mesh_ck(seen, np.asarray(seen_count),
+                              np.zeros((D, FC, PW), np.int32),
+                              np.zeros(D, np.int32),
+                              FC, SC, depth, generated, distinct)
+            self.log("Model checking completed. No error has been "
+                     "found.")
+            self.log(f"{generated} states generated, {distinct} "
+                     f"distinct states found, 0 states left on queue.")
+            return self._mk(True, distinct, generated, depth - 1, t0,
+                            warnings)
 
     def _remember_caps(self, SC: int, FC: int, TRL: int,
                        VC: Optional[int] = None) -> None:
@@ -2499,10 +2594,8 @@ class MeshExplorer(TpuExplorer):
             obs.current().gauge("por.enabled", False)
             warnings.append(f"--por requested but reduction disabled: "
                             f"{self.por_reason} (running unreduced)")
-        if need_props and not self.store_trace:
-            raise ModeError(
-                "mesh refinement/temporal checking needs the per-level "
-                "row stream: run with store_trace=True (default)")
+        # (PROPERTYs without store_trace are refused in __init__; the
+        # resume path can also arrive later, through explore()'s override)
         if need_props and self.resume_from:
             raise ModeError(
                 "mesh resume with refinement/temporal PROPERTYs is not "
@@ -2842,6 +2935,13 @@ class MeshExplorer(TpuExplorer):
         if self._shard_balance is not None:
             tel.gauge("mesh.shard_balance",
                       round(self._shard_balance, 4))
+        # where the tables really live: per-device peak allocation
+        # (accelerators; XLA:CPU reports none) — shards placed at
+        # creation keep every device near the mean
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in self.mesh.devices.flat]
+        if all(p is not None for p in peaks):
+            tel.gauge("mesh.device_peak_bytes", peaks)
         if self._supersteps:
             # host_syncs counts SUPERSTEPS (one scalar-ring read per
             # dispatch); the gauge records the deepest fused dispatch

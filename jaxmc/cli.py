@@ -498,6 +498,13 @@ def main(argv=None) -> int:
                         "(frontier, fingerprint set, level loop in one "
                         "jitted while_loop, zero host syncs per level); "
                         "no traces, no temporal properties")
+    c.add_argument("--devices", type=int, default=None, metavar="N",
+                   help="device backends: shard the frontier and the "
+                        "seen set over the first N devices of the "
+                        "platform (the mesh engine: owner-hashed "
+                        "128-bit fingerprints, all_to_all exchange per "
+                        "level, counts identical to one device); exits "
+                        "2 when fewer than N are visible")
     c.add_argument("--checkpoint", default=None,
                    help="write periodic checkpoints to this file "
                         "(TLC's states/ equivalent; both backends)")

@@ -9,13 +9,17 @@ run per-D in fresh subprocesses because the device count is fixed at
 jax init: each child forces `XLA_FLAGS=--xla_force_host_platform_
 device_count=D` virtual CPU devices.
 
-`check` and `bench` are CPU TEST GATES, not chip paths:
+What this module still is (ISSUE 26): a CPU PARITY / SCALING GATE.
+`check` and `bench` are test gates, not chip paths:
 JAXMC_MESHBENCH_PLATFORM defaults to "cpu", and what they time on
-virtual devices is never a device metric.  The one chip path through
-this module is chip_smoke.py's mesh leg, which sets
-JAXMC_MESHBENCH_PLATFORM=tpu explicitly and drives `child` once in ONE
-process over all four chips; the child pins jax to the named platform
-and fails when jax delivers another.
+virtual devices is never a device metric.  The chip path of the sharded
+engine is the normal one — `python -m jaxmc check --devices N`
+(session.py builds MeshExplorer), which chip_smoke.py's mesh leg and the
+benchmark cell `mesh-recheck-4p` drive; nothing on the chip goes through
+here any more.  `child` keeps building its engine by hand because its
+legs sweep D = 1 (the mesh engine on ONE device, ROADMAP C1), which
+`SessionConfig(devices=1)` by contract does not build, and pin
+`exchange` per leg, which is no session option.
 
 Subcommands
   check   D in {2,4} (default) parity legs over the repo-local rungs
@@ -408,13 +412,6 @@ def cmd_child(args) -> int:
                 1 for lv in tel.levels[lvl0:] if lv.get("fresh_compile"))
         phase_walls = me.probe_phase_walls() if args.phase_probe \
             else None
-        # where the tables really live: per-device peak allocation
-        # (accelerators; XLA:CPU reports none) — seen shards placed at
-        # creation keep every device near the mean
-        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
-                 for d in mesh.devices.flat]
-        if all(p is not None for p in peaks):
-            tel.gauge("mesh.device_peak_bytes", peaks)
     levels = len(tel.levels) - (lvl0 if args.timed else 0)
     host_syncs = tel.counters.get("mesh.host_syncs", 0) - \
         (sync0 if args.timed else 0)

@@ -521,7 +521,7 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       XLA backend compile request this process made and the seconds
       they took (a persistent-cache hit counts its retrieval time) —
       set-up cost, never a metric of record;
-    - gauge `mesh.device_peak_bytes` (meshbench child): per-device
+    - gauge `mesh.device_peak_bytes` (MeshExplorer._mk): per-device
       `memory_stats()["peak_bytes_in_use"]`, where the backend
       reports it.
 """

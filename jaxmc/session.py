@@ -79,6 +79,22 @@ def load_model(spec_path: str, cfg_path, no_deadlock: bool,
 _SENTINEL = object()  # "keep the configured value" for explore overrides
 
 
+def _ask_host_devices(jax, n: int) -> None:
+    """XLA:CPU comes up with one device unless told otherwise, BEFORE
+    the backend exists: ask for `n` (tests, `--rehearse-on-cpu`) unless
+    XLA_FLAGS already grants as many.  A backend that is already up
+    keeps its count; device_init then compares it with `n`."""
+    import re
+    m = re.search(r"--xla_force_host_platform_device_count=(\d+)",
+                  os.environ.get("XLA_FLAGS", ""))
+    if m and int(m.group(1)) >= n:
+        return
+    try:
+        jax.config.update("jax_num_cpu_devices", n)
+    except RuntimeError:
+        pass  # backends are initialized: the count check speaks
+
+
 class AnalyzeError(Exception):
     """--analyze=strict found error-severity diagnostics: the run must
     not proceed to compile/search (exit 2 on the CLI, a rejected job on
@@ -151,7 +167,15 @@ class SessionConfig:
     # serve-only knobs (no CLI flags):
     final_checkpoint: bool = False  # checkpoint COMPLETED runs too —
     # the daemon's warm-resume source
+    # the ONE capacity field: the resident engine's profile keys (SC,
+    # FCap, AccCap, VC) or, with devices > 1, the mesh profile's (SC,
+    # FC, TRL, GAM16, MSL, VC — per shard)
     res_caps: Optional[Dict[str, int]] = None
+    # device count (ISSUE 26): None/1 = the one-chip engines; N > 1
+    # shards the frontier and the seen set over the first N devices of
+    # the platform (backend/mesh.py).  Never fewer shards than asked:
+    # device_init raises when fewer than N devices are visible.
+    devices: Optional[int] = None
 
     @classmethod
     def from_args(cls, args) -> "SessionConfig":
@@ -172,7 +196,7 @@ class SessionConfig:
         layout/kernels.  Checkpoint/resume paths, telemetry, and pacing
         knobs (progress_every, checkpoint_every) are excluded — they
         change the run's plumbing, not its answer."""
-        return {
+        f = {
             "spec": self.spec, "cfg": self.cfg,
             "include": list(self.include), "backend": self.backend,
             "platform": self.platform, "max_states": self.max_states,
@@ -184,6 +208,18 @@ class SessionConfig:
             "seen": self.seen, "seen_cap": self.seen_cap,
             "por": self.por,
         }
+        if self.n_devices > 1:
+            # only a sharded job carries the key: the one-chip jobs'
+            # signatures (and the checkpoints keyed by them) stay what
+            # they were before the option existed
+            f["devices"] = self.n_devices
+        return f
+
+    @property
+    def n_devices(self) -> int:
+        """The shard count: 1 for the one-chip engines (None and 1 are
+        the same job)."""
+        return max(1, int(self.devices or 1))
 
     def batch_signature_fields(self) -> Dict[str, Any]:
         """job_signature_fields WITHOUT the model identity: the option
@@ -229,7 +265,7 @@ def batch_profile(cfg: SessionConfig,
     import hashlib
     import json
     if cfg.backend == "interp" or cfg.resident or not cfg.host_seen \
-            or cfg.seen_cap is not None or cfg.por:
+            or cfg.seen_cap is not None or cfg.por or cfg.n_devices > 1:
         return None
     if model is None:
         try:
@@ -472,6 +508,8 @@ class CheckSession:
                     faults.inject("device_init_fail")
                     if platform:
                         jax.config.update("jax_platforms", platform)
+                    if cfg.n_devices > 1 and platform in (None, "cpu"):
+                        _ask_host_devices(jax, cfg.n_devices)
                     # persistent XLA compile cache, every device run
                     # (compile/cache.py resolves where it lives):
                     # GUARDED — a wedged, corrupt or foreign-build cache
@@ -488,7 +526,6 @@ class CheckSession:
                             f"asked for platform {platform!r} but jax "
                             f"initialized {devs[0].platform!r}")
                     obs.stamp_device(tel, devs)
-                return cache_dir
             except (faults.FaultInjected, RuntimeError, OSError,
                     ConnectionError) as ex:
                 if attempt >= retries:
@@ -499,6 +536,16 @@ class CheckSession:
                 print(f"warning: device init failed ({ex}); retrying "
                       f"({attempt + 1}/{retries})", file=sys.stderr)
                 time.sleep(min(0.2 * (2 ** attempt), 5.0))
+                continue
+            if len(devs) < cfg.n_devices:
+                # not transient, so outside the retries: never fewer
+                # shards than asked, never the one-chip engine instead
+                raise RuntimeError(
+                    f"device init failed for platform "
+                    f"{devs[0].platform!r}: --devices {cfg.n_devices} "
+                    f"asked, {len(devs)} visible (a sharded run on "
+                    f"fewer devices gives no result)")
+            return cache_dir
 
     def compile(self) -> "CheckSession":
         """Build the engine for the configured backend.  For the jax
@@ -517,6 +564,12 @@ class CheckSession:
         assert self.kind == "model", "assumes sessions have no engine"
         cfg = self.cfg
         if cfg.backend == "interp":
+            if cfg.n_devices > 1:
+                from .compile.vspec import ModeError
+                raise ModeError(
+                    f"--devices {cfg.n_devices} needs a device backend "
+                    f"(--backend jax/tpu/...): the interpreter shards "
+                    f"nothing - drop --devices or use --workers")
             from .engine.parallel import ParallelExplorer, default_workers
             # None or 0 = auto (JAXMC_WORKERS, else min(cpu_count, 8))
             self.workers = default_workers() if not cfg.workers \
@@ -549,28 +602,41 @@ class CheckSession:
                 self.engine = Explorer(self.model, **kw)
         else:
             self.cache_dir = self.device_init()
-            from .backend.bfs import TpuExplorer
             bounds = Bounds(seq_cap=cfg.seq_cap, grow_cap=cfg.grow_cap,
                             kv_cap=cfg.kv_cap)
+            kw = dict(log=self.log, bounds=bounds,
+                      store_trace=not cfg.no_trace,
+                      progress_every=cfg.progress_every,
+                      host_seen=cfg.host_seen,
+                      chunk=cfg.chunk,
+                      resident=cfg.resident,
+                      sample_cfg=tuple(cfg.sample),
+                      checkpoint_path=cfg.checkpoint,
+                      checkpoint_every=cfg.checkpoint_every,
+                      resume_from=cfg.resume,
+                      max_states=cfg.max_states,
+                      por=cfg.por,
+                      final_checkpoint=cfg.final_checkpoint,
+                      seen_mode=cfg.seen,
+                      seen_cap=cfg.seen_cap,
+                      spill_dir=cfg.seen_spill)
             with self.tel.span("engine_build"):
-                self.engine = TpuExplorer(
-                    self.model, log=self.log, bounds=bounds,
-                    store_trace=not cfg.no_trace,
-                    progress_every=cfg.progress_every,
-                    host_seen=cfg.host_seen,
-                    chunk=cfg.chunk,
-                    resident=cfg.resident,
-                    sample_cfg=tuple(cfg.sample),
-                    checkpoint_path=cfg.checkpoint,
-                    checkpoint_every=cfg.checkpoint_every,
-                    resume_from=cfg.resume,
-                    max_states=cfg.max_states,
-                    por=cfg.por,
-                    res_caps=cfg.res_caps,
-                    final_checkpoint=cfg.final_checkpoint,
-                    seen_mode=cfg.seen,
-                    seen_cap=cfg.seen_cap,
-                    spill_dir=cfg.seen_spill)
+                if cfg.n_devices > 1:
+                    # the sharded engine over the first N devices; what
+                    # it cannot honour it refuses by name (ModeError)
+                    import jax
+                    import numpy as np
+                    from jax.sharding import Mesh
+                    from .backend.mesh import MeshExplorer
+                    mesh = Mesh(np.array(jax.devices()[:cfg.n_devices]),
+                                ("d",))
+                    self.engine = MeshExplorer(
+                        self.model, mesh=mesh, mesh_caps=cfg.res_caps,
+                        **kw)
+                else:
+                    from .backend.bfs import TpuExplorer
+                    self.engine = TpuExplorer(
+                        self.model, res_caps=cfg.res_caps, **kw)
             self.layout_sig = self.engine._layout_sig()
         self.stage = "compile"
         return self
