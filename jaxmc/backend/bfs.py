@@ -139,20 +139,54 @@ def fingerprint128(rows):
     return jnp.stack(out, axis=1)
 
 
-def _lower_bound(table, count, queries, cap):
+# The shape of the seen-table probe (ISSUE 27), from its tail measured
+# alone at the benchmark cells' shapes on the TPU v5e (PERF.md §6, PR
+# 27; ms a search's worth of calls, the resident 4-process cell): the
+# fixed-trip whole-capacity search 3,113; valid blocks of N/16 rows for
+# bit_length(seen_count) rounds 562; blocks of N/64 486 (N/32 510);
+# with every 16th sorted query searched first 210 (every 64th 217,
+# every 256th 251).  Blocks under 2^12 rows bought nothing (3-process
+# cell: 2^12 28.1, 2^11 27.5).  The tests lower the floor and the
+# stride to cut toy shapes into several blocks and groups.
+_PROBE_BLOCK_MIN = 1 << 12
+_PROBE_SAMPLE = 16
+
+
+def _probe_block_rows(n: int) -> int:
+    """QB: the rows of one query block of _seen_probe, a static
+    function of the query shape alone — a sixty-fourth of the N slots,
+    never under _PROBE_BLOCK_MIN nor over N."""
+    return min(n, max(_PROBE_BLOCK_MIN, -(-n // 64)))
+
+
+def _probe_blocks(n_live, n: int):
+    """How many query blocks _seen_probe searches for n_live live
+    queries of n slots: ceil(n_live / QB).  The ONE block rule: the
+    kernel bounds its loop with it on a traced count and the engines
+    count `search.slots_probed` with it (Python ints work too)."""
+    qb = _probe_block_rows(n)
+    return (n_live + (qb - 1)) // qb
+
+
+def _lower_bound(table, count, queries, cap, lo=None, hi=None):
     """Vectorized lexicographic lower bound: for each query row (i32
     words, signed order) the first index in table[0:count] whose row is
     not less than the query. table [cap, w]: sorted valid prefix of
-    length count (traced). Fixed-trip binary search — compiles to plain
-    gathers/selects (no sort comparators), safe inside while loops.
+    length count (traced).  lo / hi [n], given together, promise each
+    query's answer lies in [lo, hi]; the default is [0, count].  A
+    binary search of bit_length(widest interval) rounds — the fewest
+    that close it, so a table of thousands of rows costs 12 rounds and
+    not the ceil(log2(cap)) + 1 its capacity would, and a query between
+    two neighbours' answers a handful — each round one gather and
+    selects (no sort comparators), safe inside while loops.
+    _seen_probe hands it one block of queries at a time.
 
-    The log2(cap) search steps MUST be a lax loop, not a Python unroll:
+    The search steps MUST be a lax loop, not a Python unroll:
     unrolled, XLA's fusion pass duplicates the whole dependent
     gather/compare chain into every consumer (measured: 1 700+ copies of
     the [cap,w] gather in the optimized HLO, turning a ms-scale level
     step into minutes)."""
     n = queries.shape[0]
-    iters = max(1, int(np.ceil(np.log2(max(cap, 2)))) + 1)
 
     def step(_, lohi):
         lo, hi = lohi
@@ -169,12 +203,16 @@ def _lower_bound(table, count, queries, cap):
         hi = jnp.where(go & ~lt, mid, hi)
         return lo, hi
 
-    hi0 = jnp.broadcast_to(jnp.asarray(count, jnp.int32), (n,))
-    # zeros of hi0's TYPE: under a vma-checked shard_map a jnp.zeros
-    # carry enters the loop unvarying and leaves it device-varying,
-    # which fori_loop refuses
-    lo0 = hi0 - hi0
-    lo, _ = lax.fori_loop(0, iters, step, (lo0, hi0))
+    if hi is None:
+        widest = jnp.asarray(count, jnp.int32)
+        hi = jnp.broadcast_to(widest, (n,))
+        # zeros of hi's TYPE: under a vma-checked shard_map a jnp.zeros
+        # carry enters the loop unvarying and leaves it device-varying,
+        # which fori_loop refuses
+        lo = hi - hi
+    else:
+        widest = jnp.max(hi - lo)
+    lo, _ = lax.fori_loop(0, 32 - lax.clz(widest), step, (lo, hi))
     return lo
 
 
@@ -200,21 +238,67 @@ def _lsd_sort(key_cols, extra_cols):
 
 
 @jax.named_scope("jaxmc.merge.probe")
-def _seen_probe(seen, seen_count, keys, SC):
+def _seen_probe(seen, seen_count, keys, SC, n_live=None,
+                sorted_keys=False):
     """Membership of each key row in the seen table's sorted valid
     prefix — the newness verdict the rank-merge computes, exposed
     standalone so the device POR filter (ISSUE 18) can reuse it with
-    zero extra dispatches.  keys [N, K] need NOT be sorted
-    (_lower_bound binary-searches per query); invalid rows (validity
-    lane != 0, SENTINEL words) sort past the prefix and report False.
+    zero extra dispatches.
+
+    The work follows the rows that exist (ISSUE 27): the N query slots
+    are cut into blocks of QB = _probe_block_rows(N) rows and only the
+    first _probe_blocks(n_live, N) of them are searched — a lax loop
+    with that traced bound, each turn the searches, the gather of the
+    rows found and their compare over QB rows, written into the result
+    by dynamic_update_slice.  n_live (traced) promises that every row a
+    caller will read sits in keys[0:n_live] (76-93 % of the benchmark
+    cells' slots are padding: PERF.md §5); the default, N, searches
+    every block.  Rows of blocks never run report found False and lb
+    0.  The last block starts at N - QB where QB does not divide N, so
+    it may search rows of its neighbour again: the same answers.
+
+    keys need NOT be sorted (_lower_bound binary-searches per query);
+    invalid rows (validity lane != 0, SENTINEL words) sort past the
+    prefix and report False.  sorted_keys=True promises that the data
+    words of keys[0:n_live] ascend, as _rank_merge's sorted keys do.
+    Lower bounds are then monotone, so a block first searches every
+    _PROBE_SAMPLE-th query (and its last) in full, and then every query
+    between its two sampled neighbours' answers only — 5-8 rounds over
+    the QB rows instead of 18-21.  Rows past n_live are not trusted to
+    ascend: a sample among them answers seen_count.
 
     Returns (found [N] bool, lb [N] int32 lower-bound rank)."""
+    n = keys.shape[0]
     words = keys[:, 1:]
     seen_words = seen[:, 1:]
-    lb = _lower_bound(seen_words, seen_count, words, SC)
-    at_lb = jnp.take(seen_words, jnp.clip(lb, 0, SC - 1), axis=0)
-    found = (lb < seen_count) & jnp.all(at_lb == words, axis=1)
-    return found, lb
+    qb = _probe_block_rows(n)
+    live = n if n_live is None else jnp.minimum(n_live, n)
+    every = _PROBE_SAMPLE
+    # rows of a block searched in full first: every `every`-th, the last
+    at_s = np.append(np.arange(0, qb, every), qb - 1)
+
+    def block(b, out):
+        found, lb = out
+        at = jnp.minimum(b * qb, n - qb)
+        q = lax.dynamic_slice(words, (at, 0), (qb, words.shape[1]))
+        if sorted_keys:
+            lb_s = _lower_bound(seen_words, seen_count, q[at_s], SC)
+            lb_s = jnp.where(at + at_s < live, lb_s, seen_count)
+            lb_b = _lower_bound(seen_words, seen_count, q, SC,
+                                jnp.repeat(lb_s[:-1], every)[:qb],
+                                jnp.repeat(lb_s[1:], every)[:qb])
+        else:
+            lb_b = _lower_bound(seen_words, seen_count, q, SC)
+        at_lb = jnp.take(seen_words, jnp.clip(lb_b, 0, SC - 1), axis=0)
+        found_b = (lb_b < seen_count) & jnp.all(at_lb == q, axis=1)
+        return (lax.dynamic_update_slice(found, found_b, (at,)),
+                lax.dynamic_update_slice(lb, lb_b, (at,)))
+
+    # zeros of the keys' TYPE, as _lower_bound's lo: the carry leaves
+    # the loop device-varying under shard_map
+    lb0 = words[:, 0] - words[:, 0]
+    return lax.fori_loop(0, _probe_blocks(live, n), block,
+                         (lb0 != 0, lb0))
 
 
 @jax.named_scope("jaxmc.expand")
@@ -302,8 +386,11 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
     the _candidate_block_fn-style shared-plumbing pattern): the seen
     table keeps a sorted valid prefix [0:seen_count) as an INVARIANT,
     so a level only sorts its ≤N incoming keys, dedups them against
-    the prefix with vectorized binary searches (_seen_probe) and
-    merges the genuinely-new keys in by rank.  No per-level re-sort of
+    the prefix with vectorized binary searches over the VALID keys
+    alone, a block at a time and each key between its sampled
+    neighbours' answers (_seen_probe: the sorted keys carry their
+    valid rows first, ascending), and merges the genuinely-new keys in
+    by rank.  No per-level re-sort of
     the seen table: the sort work is O(N log N), not
     O((SC+N) log (SC+N)).
 
@@ -332,6 +419,8 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
                  roll the level back (seen_count2 still reports the
                  TRUE need, so growth can jump straight to it).
       seen_count2  seen_count + new_count (NOT cropped to SC).
+      probe_blocks  query blocks the probe searched (× _probe_block_rows(N)
+                 = the slots behind `search.slots_probed`).
 
     multikey=True sorts the candidate keys with ONE stable multi-key
     lax.sort instead of the LSD chain (the level and mesh engines use
@@ -348,12 +437,23 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
             kc, ec = _lsd_sort([keys[:, j] for j in range(K)], [sidx])
             sidx_s = ec[0]
     skeys = jnp.stack(kc, axis=1)
+    # the table is read only once the keys are sorted: without the tie
+    # XLA:TPU starts fetching the probe's copy of it into fast memory
+    # (S(1)) while the sort still runs, and the sort's last passes lose
+    # theirs (ISSUE 27: +12 % sort_device_s in the 4-process cell)
+    skeys, seen = lax.optimization_barrier((skeys, seen))
     svalid = skeys[:, 0] == 0
     neq_prev = jnp.concatenate([
         jnp.array([True]),
         jnp.any(skeys[1:] != skeys[:-1], axis=1)])
 
-    found, lb = _seen_probe(seen, seen_count, skeys, SC)
+    # the valid rows sorted first (lane 0 is the leading sort key), so
+    # their count is the prefix the probe has to search; nothing below
+    # reads found or lb of an invalid row (`new` masks them, pos_n is
+    # used where `new`)
+    n_live = jnp.sum(svalid, dtype=jnp.int32)
+    found, lb = _seen_probe(seen, seen_count, skeys, SC, n_live,
+                            sorted_keys=True)
     new = svalid & ~found & neq_prev
     new_count = jnp.sum(new, dtype=jnp.int32)
 
@@ -415,7 +515,8 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
     seen2 = lax.map(block, jnp.arange(0, P, B, dtype=jnp.int32)) \
         .reshape(P, K)[:SC]
     return dict(new_count=new_count, nk_sidx=nk_sidx, seen2=seen2,
-                seen_count2=seen_count + new_count)
+                seen_count2=seen_count + new_count,
+                probe_blocks=_probe_blocks(n_live, N))
 
 
 class _LiveGraph:
@@ -1745,6 +1846,10 @@ class TpuExplorer:
             return np.ones(len(rows_np), bool)
         return ~self._tiers.probe(self._packed_keys(rows_np))
 
+    @staticmethod
+    def _level_rank_merge() -> bool:
+        return os.environ.get("JAXMC_LEVEL_RANKMERGE", "").strip() != "0"
+
     # ---- jitted level step, compiled per (seen_cap, frontier_cap) ----
     def _get_step(self, SC: int, FC: int) -> Callable:
         # rank-merge port (ISSUE 11 tentpole b): the level mode is the
@@ -1758,7 +1863,7 @@ class TpuExplorer:
         # frontier order are bit-identical (pinned by tests);
         # JAXMC_LEVEL_RANKMERGE=0 keeps the full-sort as the escape
         # hatch / parity oracle.
-        rank = os.environ.get("JAXMC_LEVEL_RANKMERGE", "").strip() != "0"
+        rank = self._level_rank_merge()
         # tiered runs (ISSUE 12) also stream each kept row's dedup key
         # to the host, so the cold-tier membership probe never
         # recomputes keys; the flag joins the compile key — the one
@@ -1840,9 +1945,11 @@ class TpuExplorer:
                     gen = jnp.sum(keep)
 
             if rank:
-                # O(new): sort only the C candidate keys, dedup against
-                # the sorted seen prefix with binary searches, merge
-                # the new keys in by rank — rows fetched by gather, not
+                # O(new): sort only the C candidate keys, dedup the
+                # VALID ones against the sorted seen prefix with binary
+                # searches (a block of C/64 queries at a time, rounds
+                # from seen_count: the work follows gen, not A x FC),
+                # merge the new keys in by rank — rows fetched by gather, not
                 # scattered (a row scatter cost 9-27x a row gather on
                 # the v5e; ledger, PR 24).  nk_sidx is each new
                 # key's original candidate index in key-sorted order —
@@ -2417,7 +2524,7 @@ class TpuExplorer:
                         # candidates.  Deadlock/assert above read PRE-mask
                         # enabledness; gen drops to the reduced stream.
                         found_c, _ = _seen_probe(seen, seen_count, keys_c,
-                                                 SC)
+                                                 SC, vcnt)
                         found_g = jnp.zeros(C, dtype=bool).at[cidx].set(
                             found_c & vmask, mode="drop",
                             unique_indices=True)
@@ -2503,7 +2610,9 @@ class TpuExplorer:
             # mesh engine's merge strategy): the candidate block is
             # sorted by chained STABLE single-key passes and the
             # seen-set is never re-sorted — new keys merge by rank
-            # (vectorized binary searches, then every row of seen2
+            # (vectorized binary searches over the blocks of AccCap/64
+            # sorted keys that hold a valid row, rounds from seen_count
+            # and from the sampled neighbours; then every row of seen2
             # fetched by gather through an inverse index: the row
             # scatters this replaced were 68-77 % of this engine's
             # device time on the v5e; ledger, PR 24), so the sort work
@@ -2567,22 +2676,23 @@ class TpuExplorer:
 
             return (seen2, seen_count2, front_rows, explore_count, gen,
                     explore_count, stat, inv_bad_which, bad_row, ovcode,
-                    pora, porx, porm)
+                    pora, porx, porm, rm["probe_blocks"])
 
         def run(seen, seen_count, frontier, fcount, distinct,
                 gen_lo, gen_hi, depth, max_states, maxlvl):
             def cond(carry):
                 (_, _, _, _, _, _, _, _, lvls, stat, _, _, _,
-                 _, _, _) = carry
+                 _, _, _, _) = carry
                 return (stat == ST_CONTINUE) & (lvls < maxlvl)
 
             def body(carry):
                 (seen, seen_count, frontier, fcount, distinct,
                  gen_lo, gen_hi, depth, lvls, stat, which, brow,
-                 ovcode, pora, porx, porm) = carry
+                 ovcode, pora, porx, porm, pblocks) = carry
                 (seen2, seen_count2, front2, fcount2, gen_l, kept,
                  lstat, lwhich, lbrow, lovcode, lpora, lporx,
-                 lporm) = level(seen, seen_count, frontier, fcount)
+                 lporm, lpblocks) = level(seen, seen_count, frontier,
+                                          fcount)
                 ovf = (lstat == ST_OVF_SEEN) | (lstat == ST_OVF_FRONT) | \
                     (lstat == ST_OVF_ACC) | (lstat == ST_OVF_VC) | \
                     (lstat == ST_OVF_LANES)
@@ -2622,23 +2732,28 @@ class TpuExplorer:
                         gen_lo2, gen_hi2, depth2, lvls + 1, stat2,
                         jnp.where(lstat == ST_INV, lwhich, which), lbrow,
                         jnp.where(lstat == ST_OVF_LANES, lovcode,
-                                  ovcode), pora2, porx2, porm2)
+                                  ovcode), pora2, porx2, porm2,
+                        # work done, not work kept: a rolled-back level
+                        # searched its blocks too (as slots_sorted)
+                        pblocks + lpblocks)
 
             carry0 = (seen, seen_count, frontier, fcount, distinct,
                       gen_lo, gen_hi, depth, jnp.int32(0),
                       jnp.int32(ST_CONTINUE), jnp.int32(-1),
                       jnp.full((PW,), SENTINEL, jnp.int32),
                       jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                      jnp.int32(0))
+                      jnp.int32(0), jnp.int32(0))
             (seen, seen_count, frontier, fcount, distinct, gen_lo,
              gen_hi, depth, _, stat, which, brow, ovcode, pora, porx,
-             porm) = \
+             porm, pblocks) = \
                 lax.while_loop(cond, body, carry0)
             # indices 0-8 are the PR-6 summary; 9-11 are the per-
-            # dispatch POR counters (ISSUE 18; zero when POR is off)
+            # dispatch POR counters (ISSUE 18; zero when POR is off);
+            # 12 is the query blocks the merge's probe searched over
+            # the dispatch's levels (ISSUE 27: search.slots_probed)
             summary = jnp.stack([stat, seen_count, fcount, distinct,
                                  gen_lo, gen_hi, depth, which, ovcode,
-                                 pora, porx, porm])
+                                 pora, porx, porm, pblocks])
             return seen, frontier, summary, brow
 
         # DONATED dispatch (ISSUE 6): the seen table (arg 0) and the
@@ -3239,6 +3354,7 @@ class TpuExplorer:
                 self._por_stats["ample"] += int(summary[9])
                 self._por_stats["expanded"] += int(summary[10])
                 self._por_stats["masked"] += int(summary[11])
+                probe_blocks = int(summary[12])
                 # cold-tier filter (ISSUE 12): after a spill the device
                 # table restarted empty, so a committed level's frontier
                 # may hold rows whose keys live in the host/disk runs —
@@ -3288,6 +3404,10 @@ class TpuExplorer:
                 ST_OVF_LANES, ST_DEADLOCK, ST_ASSERT))
             tel.counter("search.slots_sorted", lvls * caps["AccCap"])
             tel.counter("search.rows_valid", generated - gen_in)
+            # ... and binary-searched only the query blocks that held a
+            # valid key: the dispatch's loop carry counted them
+            tel.counter("search.slots_probed", probe_blocks
+                        * _probe_block_rows(caps["AccCap"]))
             tel.counter("search.seen_slots", lvls * caps["SC"])
             tel.counter("search.rows_new", distinct - dist_in)
             self._fp_occupancy = seen_count
@@ -4406,8 +4526,16 @@ class TpuExplorer:
                           wall_s=round(time.time() - lvl_t0, 6))
             # work against capacity: the step sorted the whole candidate
             # block and rewrote the whole seen table for gen valid rows
+            # ... and binary-searched the query blocks that hold them
+            # (every valid candidate is a live query of _rank_merge: the
+            # host counts with the kernel's own block rule; the
+            # full-sort escape hatch searches nothing)
+            gen_l = int(out["gen"])
             tel.counter("search.slots_sorted", C)
-            tel.counter("search.rows_valid", int(out["gen"]))
+            tel.counter("search.rows_valid", gen_l)
+            tel.counter("search.slots_probed",
+                        _probe_blocks(gen_l, C) * _probe_block_rows(C)
+                        if self._level_rank_merge() else 0)
             tel.counter("search.seen_slots", SC)
             tel.counter("search.rows_new", kept_count)
             self._fp_occupancy = seen_count

@@ -91,7 +91,8 @@ from ..engine.explore import CheckResult, Violation
 from ..compile.vspec import ModeError
 from ..compile.kernel2 import OV_DEMOTED, OV_PACK
 from .bfs import (SENTINEL, TpuExplorer, _LiveGraph, _pow2_at_least,
-                  _por_mask, _rank_merge, _seen_probe)
+                  _por_mask, _probe_block_rows, _rank_merge,
+                  _seen_probe)
 
 _BIG = np.int32(2 ** 31 - 1)
 
@@ -139,7 +140,8 @@ _S_MAXV = 17      # pmax per-shard valid-candidate need (grows VC)
 _S_PORA = 18      # psum POR singleton-ample states this level (ISSUE 18)
 _S_PORX = 19      # psum POR expanded (any-arm-enabled) states this level
 _S_PORM = 20      # psum POR-masked candidate rows this level
-_NS = 21
+_S_PROBED = 21    # psum query blocks the shards' rank merges searched
+_NS = 22
 
 # per-device violation-localization vector (fetched only on violation)
 _A_INVW = 0
@@ -718,7 +720,8 @@ class MeshExplorer(TpuExplorer):
                         seen_count2=rm["seen_count2"],
                         front_rows=front_rows, front_rows_u=front_rows_u,
                         front_src=front_src, front_count=front_count,
-                        new_count=new_count, v_ovf=v_ovf, v_need=v_need)
+                        new_count=new_count, v_ovf=v_ovf, v_need=v_need,
+                        probe_blocks=rm["probe_blocks"])
 
         return merge
 
@@ -827,7 +830,9 @@ class MeshExplorer(TpuExplorer):
                         # uniform surface with the rank strategy: the
                         # fullsort merge has no valid-candidate cap
                         v_ovf=jnp.asarray(False),
-                        v_need=jnp.asarray(0, jnp.int32))
+                        v_need=jnp.asarray(0, jnp.int32),
+                        # one sort, no binary search
+                        probe_blocks=jnp.asarray(0, jnp.int32))
 
         return merge
 
@@ -1129,6 +1134,8 @@ class MeshExplorer(TpuExplorer):
                 scal = scal.at[_S_PORA].set(lax.psum(pora, "d"))
                 scal = scal.at[_S_PORX].set(lax.psum(porx, "d"))
                 scal = scal.at[_S_PORM].set(lax.psum(porm, "d"))
+                scal = scal.at[_S_PROBED].set(
+                    lax.psum(mg["probe_blocks"], "d"))
 
                 # per-device localization vector (fetched only on
                 # violation — always the LAST executed level's, because
@@ -2004,9 +2011,15 @@ class MeshExplorer(TpuExplorer):
             # — sorted the merge's N key slots and rewrote SC seen rows
             # on each of the D shards, whatever was valid
             R = D * (B + SB) if B else D * C  # rows a shard receives
-            tel.counter("search.slots_sorted",
-                        nlv * D * self._merge_out_rows(R, VC))
+            n_keys = self._merge_out_rows(R, VC)
+            tel.counter("search.slots_sorted", nlv * D * n_keys)
             tel.counter("search.seen_slots", nlv * D * SC)
+            # ... and binary-searched only the query blocks that held a
+            # valid key on each shard: the ring carries their number a
+            # level, summed over the shards
+            tel.counter("search.slots_probed",
+                        int(ring[:nlv, _S_PROBED].sum())
+                        * _probe_block_rows(n_keys))
             self._supersteps += 1
             self._superstep_levels_max = max(self._superstep_levels_max,
                                              nlv)

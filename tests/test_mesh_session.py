@@ -145,6 +145,53 @@ def test_pinned_mesh_caps_compile_once(tmp_path):
     assert tel.counters["search.rows_new"] == 2 * (166 - 9)
 
 
+def test_slots_probed_follows_each_shards_live_keys(tmp_path, monkeypatch):
+    """The probe's loops are bounded per shard (no collective inside):
+    evenly hashed, the shards' valid keys and seen counts differ a
+    little; under the mesh_skew fault shard 0 holds every key and the
+    other three search nothing.  Same answer either way, the one-chip
+    engine's; and the counter is the blocks that held a key."""
+    from jaxmc import faults
+    from jaxmc.backend import bfs
+    monkeypatch.setattr(bfs, "_PROBE_BLOCK_MIN", 4)
+    cfg = tmp_path / "t.cfg"
+    cfg.write_text(_cfg_text(2, 3, 4))
+    caps = {"SC": 1 << 10, "FC": 256, "TRL": 16, "GAM16": 32, "MSL": 16,
+            "VC": 256}
+    qb = bfs._probe_block_rows(caps["VC"])
+    assert qb == 4
+    answers = []
+    try:
+        for skew in (False, True):
+            if skew:
+                monkeypatch.setenv("JAXMC_FAULTS", "mesh_skew")
+                faults.reset_for_tests()
+            tel = obs.Telemetry()
+            with obs.use(tel):
+                sess = _session(TRANSFER, str(cfg), tel, devices=4,
+                                res_caps=caps)
+                answers.append(_answer(sess.explore()))
+            assert sess.engine._skew is skew
+            gen = [lv["generated"] for lv in tel.levels]
+            assert sum(gen) == 256 - 9 and len(gen) == 7
+            probed = tel.counters["search.slots_probed"]
+            # one shard holding every valid key of a level searches
+            # ceil(valid / QB) blocks; four sharing them at most three more
+            least = sum(-(-g // qb) for g in gen) * qb
+            assert probed % qb == 0
+            if skew:
+                assert probed == least
+            else:
+                assert least <= probed <= least + 3 * qb * len(gen)
+            assert probed < tel.counters["search.slots_sorted"] \
+                == 7 * 4 * caps["VC"]
+    finally:
+        faults.reset_for_tests()
+    res = _session(TRANSFER, str(cfg), resident=True, no_trace=True)
+    assert answers[0] == answers[1] == _answer(res.explore())
+    assert answers[0][:2] == (256, 166)
+
+
 # ------------------------------------------------ a violation's trace
 
 def test_violating_cfg_gives_the_level_engines_trace():

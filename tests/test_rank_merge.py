@@ -5,7 +5,14 @@ scalar scatters.  The row-scatter formulation it replaced lives on here
 alone, as the bit-for-bit oracle, beside a numpy sorted-set-union
 reference that shares no code with either; a lowering guard keeps row
 scatters (39-117 ns a row on the TPU v5e against 4.3 ns for a gathered
-row; ledger, PR 24) from coming back."""
+row; ledger, PR 24) from coming back.
+
+Since ISSUE 27 the probe inside it (`_seen_probe`, `_lower_bound`)
+searches the valid prefix of the sorted keys alone, a block of QB
+queries at a time, for bit_length(seen_count) rounds: the oracle calls
+`_seen_probe` without a live count (every block), so the cases here
+also hold the prefix form against the general one; a second lowering
+guard keeps whole-capacity gathers out of the probe."""
 
 import functools
 import re
@@ -19,7 +26,8 @@ from jax import lax  # noqa: E402
 
 from jaxmc.backend import bfs  # noqa: E402
 from jaxmc.backend.bfs import (  # noqa: E402
-    SENTINEL, _lsd_sort, _rank_merge, _seen_probe)
+    SENTINEL, _lsd_sort, _probe_block_rows, _probe_blocks, _rank_merge,
+    _seen_probe)
 
 
 def _scatter_rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
@@ -73,6 +81,10 @@ def _scatter_rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
                 seen_count2=seen_count + new_count)
 
 
+# what both references answer (the oracle predates `probe_blocks`)
+ANSWER = ("new_count", "nk_sidx", "seen2", "seen_count2")
+
+
 def _lexsorted(words):
     """Distinct rows of `words` in signed lexicographic order."""
     return np.unique(words, axis=0)
@@ -114,6 +126,21 @@ def _np_reference(seen, seen_count, keys, SC, K):
 
 
 N, SC = 48, 64
+# query blocks of the probe at these toy shapes: 16 rows, so the 48 keys
+# are three blocks and a level's valid keys end inside any of them.
+# Within a block every 4th sorted key (and the last) is searched in
+# full first: 5 samples, 4 groups.  Every trace of this file runs under
+# the same values (jit caches by function, and they are read when a
+# shape is first traced)
+PROBE_MIN, PROBE_SAMPLE = 16, 4
+
+
+@pytest.fixture(autouse=True)
+def _toy_probe_shape(monkeypatch):
+    monkeypatch.setattr(bfs, "_PROBE_BLOCK_MIN", PROBE_MIN)
+    monkeypatch.setattr(bfs, "_PROBE_SAMPLE", PROBE_SAMPLE)
+
+
 SCENARIOS = ("empty_seen", "full_seen", "no_valid_keys", "all_seen",
              "all_equal", "random", "overflow")
 
@@ -184,7 +211,9 @@ def test_rank_merge_equals_oracle_and_set_union(K, multikey, scenario,
         want = oracle(jnp.asarray(seen), jnp.int32(n_seen),
                       jnp.asarray(keys), N, SC, K, multikey)
         ref = _np_reference(seen, n_seen, keys, SC, K)
-        for name in ("new_count", "nk_sidx", "seen2", "seen_count2"):
+        n_valid = int((keys[:, 0] == 0).sum())
+        assert int(got["probe_blocks"]) == -(-n_valid // PROBE_MIN)
+        for name in ANSWER:
             g = np.asarray(got[name])
             assert g.dtype == np.asarray(want[name]).dtype, name
             assert np.array_equal(g, np.asarray(want[name])), \
@@ -205,6 +234,186 @@ def test_rank_merge_equals_oracle_and_set_union(K, multikey, scenario,
             assert np.array_equal(s2, seen)
         if scenario == "all_equal":
             assert int(got["new_count"]) <= 1
+
+
+# ---- the probe sized by what is live (ISSUE 27) ----
+#
+# (N, K, multikey): the resident engine's shape cut to 48 keys (QB 16,
+# three even blocks) and the level engine's A x FC = 163,840 cut by
+# 4,096 to 40 (QB 16: blocks at 0, 16 and — clamped — 24, so the last
+# overlaps its neighbour)
+PROBE_SHAPES = {"even": (48, 5, False), "uneven": (40, 3, True)}
+QB = 16
+N_LIVE = {"0": 0, "1": 1, "QB-1": QB - 1, "QB": QB, "QB+1": QB + 1,
+          "N": None}
+SEEN_COUNT = {"0": 0, "1": 1, "pow2": 16, "SC": SC}
+
+
+def _probe_case(n, K, n_live, n_seen, rng):
+    """n_live valid keys (at random slots: the merge sorts them to the
+    front) over n slots, n_seen seen rows; the small alphabet makes
+    duplicates and keys already seen."""
+    def draw(m):
+        return rng.integers(-4, 5, size=(m, K - 1)).astype(np.int32)
+
+    pool = _lexsorted(draw(16 * SC))
+    swords = pool[np.sort(rng.choice(len(pool), n_seen, replace=False))]
+    valid = np.zeros(n, bool)
+    valid[rng.choice(n, n_live, replace=False)] = True
+    return _table(swords, SC, K), _keys(draw(n), valid, K)
+
+
+@pytest.mark.parametrize("seen_count", SEEN_COUNT)
+@pytest.mark.parametrize("n_live", N_LIVE)
+@pytest.mark.parametrize("shape", PROBE_SHAPES)
+def test_probe_follows_live_queries_and_seen_count(shape, n_live,
+                                                   seen_count):
+    n, K, multikey = PROBE_SHAPES[shape]
+    assert _probe_block_rows(n) == QB
+    live = n if N_LIVE[n_live] is None else N_LIVE[n_live]
+    n_seen = SEEN_COUNT[seen_count]
+    for trial in range(3):
+        rng = np.random.default_rng([n, live, n_seen, trial])
+        seen, keys = _probe_case(n, K, live, n_seen, rng)
+        args = (jnp.asarray(seen), jnp.int32(n_seen), jnp.asarray(keys),
+                n, SC, K, multikey)
+        # the merge's own block size, as the engines run it
+        got = _FNS["one_block"](*args)
+        want = _ORACLE(*args)
+        ref = _np_reference(seen, n_seen, keys, SC, K)
+        # the blocks that hold a live key, and no other
+        assert int(got["probe_blocks"]) == -(-live // QB) \
+            == _probe_blocks(live, n)
+        for name in ANSWER:
+            g = np.asarray(got[name])
+            assert np.array_equal(g, np.asarray(want[name])), \
+                (name, "oracle", trial)
+            assert np.array_equal(g, ref[name]), (name, "numpy", trial)
+
+
+def _np_probe(seen, n_seen, keys):
+    """(found, lower bound) of every key row, by bisection over tuples."""
+    import bisect
+    table = [tuple(r) for r in seen[:n_seen, 1:]]
+    lb = np.array([bisect.bisect_left(table, tuple(r)) for r in keys[:, 1:]])
+    found = np.array([i < n_seen and table[i] == tuple(r)
+                      for i, r in zip(lb, keys[:, 1:])], bool)
+    return found, lb
+
+
+@pytest.mark.parametrize("K", [3, 5])
+def test_seen_probe_of_unsorted_keys_searches_every_block(K):
+    """The POR filter's form: no live count, keys in candidate order,
+    invalid rows anywhere."""
+    probe = jax.jit(_seen_probe, static_argnums=(3,))
+    for trial in range(4):
+        rng = np.random.default_rng([K, trial, 27])
+        n_seen = int(rng.integers(0, SC + 1))
+        seen, keys = _probe_case(N, K, int(rng.integers(0, N + 1)), n_seen,
+                                 rng)
+        found, lb = probe(jnp.asarray(seen), jnp.int32(n_seen),
+                          jnp.asarray(keys), SC)
+        valid = keys[:, 0] == 0
+        want_found, want_lb = _np_probe(seen, n_seen, keys)
+        assert np.array_equal(np.asarray(found)[valid], want_found[valid])
+        assert np.array_equal(np.asarray(lb)[valid], want_lb[valid])
+        # SENTINEL words sort past every row of the prefix
+        assert not np.asarray(found)[~valid].any()
+        assert np.all((0 <= np.asarray(lb)) & (np.asarray(lb) <= n_seen))
+
+
+def test_seen_probe_leaves_blocks_past_the_live_prefix_alone():
+    """Every key IS in the table; with a live count the blocks past it
+    are never searched and say so: found False, lb 0."""
+    K = 5
+    rng = np.random.default_rng(27)
+    words = _lexsorted(rng.integers(-9, 10, size=(4 * SC, K - 1))
+                       .astype(np.int32))[:SC]
+    seen = _table(words, SC, K)
+    keys = _keys(words[np.sort(rng.choice(SC, N, replace=False))],
+                 np.ones(N, bool), K)
+    probe = jax.jit(_seen_probe, static_argnums=(3,))
+    for live in (0, 1, QB, QB + 1, N, N + 7):
+        found, lb = probe(jnp.asarray(seen), jnp.int32(SC),
+                          jnp.asarray(keys), SC, jnp.int32(live))
+        ran = min(-(-live // QB) * QB, N)
+        assert np.asarray(found)[:ran].all()
+        assert np.array_equal(np.asarray(lb)[:ran],
+                              _np_probe(seen, SC, keys)[1][:ran])
+        assert not np.asarray(found)[ran:].any()
+        assert not np.asarray(lb)[ran:].any()
+
+
+@pytest.mark.parametrize("K", [3, 5])
+def test_sorted_probe_trusts_only_the_live_prefix_to_ascend(K):
+    """sorted_keys: the first n_live rows ascend (with duplicates, seen
+    and unseen); what follows them is garbage in no order, as nothing
+    promises otherwise.  The live rows' answers are the bisection's."""
+    probe = jax.jit(_seen_probe, static_argnums=(3, 5))
+    for trial in range(8):
+        rng = np.random.default_rng([K, trial, 270])
+        n_seen = int(rng.integers(0, SC + 1))
+        live = int(rng.integers(0, N + 1))
+        seen, keys = _probe_case(N, K, N, n_seen, rng)
+        order = np.lexsort(tuple(keys[:live, j]
+                                 for j in reversed(range(1, K))))
+        keys[:live] = keys[:live][order]
+        found, lb = probe(jnp.asarray(seen), jnp.int32(n_seen),
+                          jnp.asarray(keys), SC, jnp.int32(live), True)
+        want_found, want_lb = _np_probe(seen, n_seen, keys)
+        assert np.array_equal(np.asarray(found)[:live], want_found[:live])
+        assert np.array_equal(np.asarray(lb)[:live], want_lb[:live])
+
+
+def _gathers(jaxpr, loops=0):
+    """(enclosing while loops, operand shape, result shape) of every
+    gather under a jaxpr."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            out.append((loops, eqn.invars[0].aval.shape,
+                        eqn.outvars[0].aval.shape))
+        inner = loops + (eqn.primitive.name == "while")
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _gathers(sub, inner)
+    return out
+
+
+_GATHER = re.compile(
+    r'"?stablehlo\.gather"?\(.*? : \(tensor<([^>]*)>, tensor<([^>]*)>\)'
+    r' -> tensor<([^>]*)>')
+
+
+@pytest.mark.parametrize("multikey", [False, True])
+@pytest.mark.parametrize("K", [3, 5])
+def test_rank_merge_probes_by_block_inside_loops(K, multikey):
+    shapes = (jax.ShapeDtypeStruct((SC, K), jnp.int32),
+              jax.ShapeDtypeStruct((), jnp.int32),
+              jax.ShapeDtypeStruct((N, K), jnp.int32))
+    text = jax.jit(_rank_merge, static_argnums=(3, 4, 5, 6)).lower(
+        *shapes, N, SC, K, multikey).as_text()
+    found = _GATHER.findall(text)
+    assert len(found) == len(re.findall(r'stablehlo\.gather"?\(', text))
+    # the probe gathers from the table's K-1 data words; the merge's
+    # tail from whole K-word rows
+    probe = [g for g in found if g[0] == f"{SC}x{K - 1}xi32"]
+    samples = QB // PROBE_SAMPLE + 1
+    # QB rows a gather, or the block's samples: never the N query slots
+    # (jit lowers equal takes to one shared function)
+    assert {int(r.split("x")[0]) for _, _, r in probe} == {samples, QB}, \
+        found
+    jaxpr = jax.make_jaxpr(
+        lambda s, c, k: _rank_merge(s, c, k, N, SC, K, multikey))(*shapes)
+    depth = sorted(loops for loops, operand, result in _gathers(jaxpr.jaxpr)
+                   if operand == (SC, K - 1))
+    # the rows found, once a block; the sampled and the bounded search,
+    # once a round each
+    assert depth == [1, 2, 2], depth
+    for loops, operand, result in _gathers(jaxpr.jaxpr):
+        assert result[0] != N or operand[1] == K, (operand, result)
 
 
 _SCATTER = re.compile(

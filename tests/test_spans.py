@@ -297,6 +297,15 @@ class TestNothingMovesWithTheSpansIn:
         # ... and every distinct one but those a new row of the seen table
         assert tel.counters["search.rows_new"] == 153701 - 12 ** 3
         if engine == "level":
+            # the probe searched the query blocks that held a valid row
+            # and no other: QB = max(2^12, A x FC / 64) with A = 10 and
+            # FC 2^11, 2^13, 2^14, 2^15, then 2^16 for six levels, over
+            # the levels' 5184, 15552, 31104, 57888, 70422, 62889, 39810,
+            # 19664, 5184, 1728 generated states (bench/pins): (2 + 4 +
+            # 8) x 4096 + 12 x 5120 + (7 + 7 + 4 + 2 + 1 + 1) x 10240 of
+            # the 4,526,080 slots it used to search
+            assert tel.counters["search.slots_probed"] == 344064
+            assert tel.counters["search.slots_sorted"] == 4526080
             assert tel.prof.sites["bfs.level_step"].dispatches == 10
             assert tel.prof.sites["bfs.level_step"].recompiles == \
                 tel.counters["compile.cache_misses"]
@@ -330,6 +339,37 @@ def test_capacity_counters_by_hand(engine):
         # SC seen rows, whatever the level holds
         assert c["search.slots_sorted"] == 6 * (1 << 13)
         assert c["search.seen_slots"] == 6 * (1 << 12)
+
+
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+def test_slots_probed_by_hand(engine, monkeypatch):
+    """constoy again: level k = 0..5 hands the merge 2 (k + 1) valid keys.
+    `search.slots_probed` is the sum over the levels of ceil(valid / QB)
+    x QB, QB = max(floor, N / 64) of the merge's N key slots — the blocks
+    of sorted keys the binary searches visited."""
+    pytest.importorskip("jax")
+    from jaxmc.backend import bfs
+    monkeypatch.setattr(bfs, "_PROBE_BLOCK_MIN", 4)
+    tel = obs.Telemetry()
+    # chunk 64 lets the caps be this small (FCap's floor is the chunk)
+    caps = {"SC": 256, "FCap": 64, "AccCap": 128, "VC": 64}
+    res = _checked("constoy", "constoy", engine, tel,
+                   **({"res_caps": caps, "chunk": 64}
+                      if engine == "resident" else {}))
+    assert (res.generated, res.distinct) == (43, 21)
+    c = tel.counters
+    if engine == "level":
+        # N = A x FC = 512, QB 8: 2, 4, 6, 8, 10, 12 keys are
+        # 1 + 1 + 1 + 1 + 2 + 2 blocks
+        assert bfs._probe_block_rows(512) == 8
+        assert c["search.slots_probed"] == 8 * 8
+        assert c["search.slots_sorted"] == 6 * 512
+    else:
+        # N = AccCap = 128 (its floor), QB 4: 2, 4, 6, 8, 10, 12 keys
+        # are 1 + 1 + 2 + 2 + 3 + 3 blocks
+        assert bfs._probe_block_rows(128) == 4
+        assert c["search.slots_probed"] == 12 * 4
+        assert c["search.slots_sorted"] == 6 * 128
 
 
 def test_compile_seconds_by_program():
