@@ -223,9 +223,6 @@ bench-check:
 # `python -m jaxmc.obs diff --fail-on-regress` against a saved
 # baseline (first run snapshots it; baselines live in
 # $(BENCH_CHECK_DIR)/jaxmc_multichip_*.baseline.json).
-# The RANK-MERGE leg (ISSUE 10): the default check runs the rank
-# strategy; a second fullsort leg on one rung proves the
-# JAXMC_MESH_RANKMERGE=0 escape hatch answers bit-identically.
 # Finally, when two committed MULTICHIP_r* scaling artifacts exist,
 # `obs diff` gates the newer per-rung states/sec/chip against the
 # older (wired into `make bench-check` through this target).
@@ -237,9 +234,6 @@ MULTICHIP_DEVICES ?= 2,4
 MULTICHIP_GLOB ?= MULTICHIP_r0[6-9].json
 multichip-check:
 	$(PY) -m jaxmc.meshbench check --devices $(MULTICHIP_DEVICES) \
-	    --out-dir $(BENCH_CHECK_DIR)
-	$(PY) -m jaxmc.meshbench check --devices 2 \
-	    --rung specs/viewtoy_scaled.tla --merge fullsort \
 	    --out-dir $(BENCH_CHECK_DIR)
 	@if ls $(MULTICHIP_GLOB) >/dev/null 2>&1; then \
 	  echo "== multichip scaling curve: $(MULTICHIP_GLOB) =="; \
@@ -296,10 +290,8 @@ por-check:
 # timed fully-warm mesh runs over D in {1,2,4,8} virtual devices
 # (real chips when JAXMC_MESHBENCH_PLATFORM names an accelerator) —
 # states/sec/chip, per-level exchange bytes, shard balance,
-# host_syncs <= levels (supersteps), window_recompiles == 0, and the
-# measured expand/exchange/merge phase-wall breakdown (incl. the
-# rank-vs-fullsort merge wall and the fused-step hot_share) — written
-# to MULTICHIP_r08.json and gated per leg like multichip-check.
+# host_syncs <= levels (supersteps) and window_recompiles == 0 —
+# written to MULTICHIP_r08.json and gated per leg like multichip-check.
 MULTICHIP_BENCH_DEVICES ?= 1,2,4,8
 MULTICHIP_OUT ?= MULTICHIP_r08.json
 multichip-bench:

@@ -382,7 +382,7 @@ _MERGE_BLOCK_ROWS = 1 << 20
 @jax.named_scope("jaxmc.merge.scatter")
 def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
     """The O(new) seen-merge core SHARED by the single-chip resident
-    level, the level engine and the mesh rank-merge strategy (ISSUE 10;
+    level, the level engine and the mesh engine's shards (ISSUE 10;
     the _candidate_block_fn-style shared-plumbing pattern): the seen
     table keeps a sorted valid prefix [0:seen_count) as an INVARIANT,
     so a level only sorts its ≤N incoming keys, dedups them against
@@ -1846,24 +1846,8 @@ class TpuExplorer:
             return np.ones(len(rows_np), bool)
         return ~self._tiers.probe(self._packed_keys(rows_np))
 
-    @staticmethod
-    def _level_rank_merge() -> bool:
-        return os.environ.get("JAXMC_LEVEL_RANKMERGE", "").strip() != "0"
-
     # ---- jitted level step, compiled per (seen_cap, frontier_cap) ----
     def _get_step(self, SC: int, FC: int) -> Callable:
-        # rank-merge port (ISSUE 11 tentpole b): the level mode is the
-        # LEGACY host loop refinement/temporal-PROPERTY checking runs on
-        # (the resident loop cannot stream edges), and it full-sorted
-        # seen+candidates — a [SC+C, K+1]-key stable sort EVERY level —
-        # long after the resident engines went O(new).  The seen table
-        # already keeps a sorted valid prefix (init lexsorts, the merge
-        # writes sorted output), so bfs._rank_merge drops the per-level
-        # sort work to the C candidate keys alone.  Counts, traces and
-        # frontier order are bit-identical (pinned by tests);
-        # JAXMC_LEVEL_RANKMERGE=0 keeps the full-sort as the escape
-        # hatch / parity oracle.
-        rank = self._level_rank_merge()
         # tiered runs (ISSUE 12) also stream each kept row's dedup key
         # to the host, so the cold-tier membership probe never
         # recomputes keys; the flag joins the compile key — the one
@@ -1873,7 +1857,7 @@ class TpuExplorer:
         # compile key — the mask arrays are baked constants
         por_plan = self._por_plan() if self.por else None
         por = por_plan is not None
-        key = (SC, FC, rank, tiered, por)
+        key = (SC, FC, tiered, por)
         if key in self._step_cache:
             obs.current().counter("compile.cache_hits")
             return self._step_cache[key]
@@ -1944,62 +1928,24 @@ class TpuExplorer:
                     cvalid = keep
                     gen = jnp.sum(keep)
 
-            if rank:
-                # O(new): sort only the C candidate keys, dedup the
-                # VALID ones against the sorted seen prefix with binary
-                # searches (a block of C/64 queries at a time, rounds
-                # from seen_count: the work follows gen, not A x FC),
-                # merge the new keys in by rank — rows fetched by gather, not
-                # scattered (a row scatter cost 9-27x a row gather on
-                # the v5e; ledger, PR 24).  nk_sidx is each new
-                # key's original candidate index in key-sorted order —
-                # exactly the full sort's new_cidx (stable ties keep
-                # first occurrence in both).  The caller pre-grows SC
-                # so seen_count + C <= SC: seen_count2 never overflows.
-                rm = _rank_merge(seen_keys, seen_count, ckeys, C, SC, K,
-                                 multikey=True)
-                new_count = rm["new_count"]
-                safe_cidx = jnp.clip(rm["nk_sidx"], 0, C - 1)
-                seen2 = rm["seen2"]
-                seen_count2 = rm["seen_count2"]
-            else:
-                with jax.named_scope("jaxmc.merge.sort"):
-                    # argsort on keys only, then gather payloads by
-                    # permutation — a variadic sort carrying all W lanes
-                    # compiles and runs far slower than sort(keys, index) +
-                    # take
-                    allk = jnp.concatenate([seen_keys, ckeys])   # [SC+C, K]
-                    flag = jnp.concatenate([
-                        jnp.zeros(SC, jnp.int32), jnp.ones(C, jnp.int32)])
-                    idx0 = jnp.arange(SC + C, dtype=jnp.int32)
-                    ops = tuple(allk[:, i] for i in range(K)) + (flag, idx0)
-                    sorted_ = lax.sort(ops, num_keys=K + 1, is_stable=True)
-                    skeys = jnp.stack(sorted_[:K], axis=1)
-                    sflag = sorted_[K]
-                    perm = sorted_[K + 1]
-                    # candidate payload indices: position in cand (<0: seen)
-                    cidx = perm - SC  # >=0 only for candidate entries
-                    rvalid = skeys[:, 0] == 0
-                    neq_prev = jnp.concatenate([
-                        jnp.array([True]),
-                        jnp.any(skeys[1:] != skeys[:-1], axis=1)])
-                    new = (sflag == 1) & rvalid & neq_prev
-                    new_count = jnp.sum(new)
-
-                    # compact new entries to the front (stable, keeps key
-                    # order)
-                    ops2 = ((1 - new.astype(jnp.int32)), cidx)
-                    comp = lax.sort(ops2, num_keys=1, is_stable=True)
-                    new_cidx = comp[1][:C]
-                    safe_cidx = jnp.clip(new_cidx, 0, C - 1)
-
-                    # merged seen keys, compacted and sorted
-                    keep = ((sflag == 0) & rvalid) | new
-                    ops3 = ((1 - keep.astype(jnp.int32)),) + \
-                        tuple(skeys[:, i] for i in range(K))
-                    comp3 = lax.sort(ops3, num_keys=1, is_stable=True)
-                    seen2 = jnp.stack(comp3[1:], axis=1)[:SC]
-                    seen_count2 = jnp.sum(keep)
+            # O(new): the seen table keeps a sorted valid prefix (init
+            # lexsorts, the merge writes sorted output), so only the C
+            # candidate keys are sorted; the VALID ones are deduped
+            # against that prefix with binary searches (a block of C/64
+            # queries at a time, rounds from seen_count: the work
+            # follows gen, not A x FC) and the new keys merged in by
+            # rank — rows fetched by gather, not scattered (a row
+            # scatter cost 9-27x a row gather on the v5e; ledger, PR
+            # 24).  nk_sidx is each new key's original candidate index
+            # in key-sorted order (stable ties keep the first
+            # occurrence).  The caller pre-grows SC so seen_count + C
+            # <= SC: seen_count2 never overflows.
+            rm = _rank_merge(seen_keys, seen_count, ckeys, C, SC, K,
+                             multikey=True)
+            new_count = rm["new_count"]
+            safe_cidx = jnp.clip(rm["nk_sidx"], 0, C - 1)
+            seen2 = rm["seen2"]
+            seen_count2 = rm["seen_count2"]
 
             with jax.named_scope("jaxmc.compact"):
                 new_rows = jnp.take(cand, safe_cidx, axis=0)      # packed
@@ -2607,7 +2553,7 @@ class TpuExplorer:
 
             # ---- merge-dedup the level's candidates against seen ----
             # The shared O(new) rank-merge core (_rank_merge, also the
-            # mesh engine's merge strategy): the candidate block is
+            # level and mesh engines' merge): the candidate block is
             # sorted by chained STABLE single-key passes and the
             # seen-set is never re-sorted — new keys merge by rank
             # (vectorized binary searches over the blocks of AccCap/64
@@ -3707,10 +3653,8 @@ class TpuExplorer:
             # full wasted chunk on every early exit (OV_DEMOTED
             # restarts included). Cost when active: TWO chunks'
             # [A*CH, W] outputs live at once — size --chunk with that
-            # 2x in mind, or set JAXMC_NO_PREFETCH=1 to restore the
-            # sequential loop when the doubled working set won't fit
-            prefetch = getattr(hstep, "is_async", False) and \
-                os.environ.get("JAXMC_NO_PREFETCH") != "1"
+            # 2x in mind
+            prefetch = getattr(hstep, "is_async", False)
 
             def _dispatch(b, fnp=frontier_np, ll=L):
                 c = min(CH, ll - b)
@@ -4528,14 +4472,12 @@ class TpuExplorer:
             # block and rewrote the whole seen table for gen valid rows
             # ... and binary-searched the query blocks that hold them
             # (every valid candidate is a live query of _rank_merge: the
-            # host counts with the kernel's own block rule; the
-            # full-sort escape hatch searches nothing)
+            # host counts with the kernel's own block rule)
             gen_l = int(out["gen"])
             tel.counter("search.slots_sorted", C)
             tel.counter("search.rows_valid", gen_l)
             tel.counter("search.slots_probed",
-                        _probe_blocks(gen_l, C) * _probe_block_rows(C)
-                        if self._level_rank_merge() else 0)
+                        _probe_blocks(gen_l, C) * _probe_block_rows(C))
             tel.counter("search.seen_slots", SC)
             tel.counter("search.rows_new", kept_count)
             self._fp_occupancy = seen_count

@@ -27,23 +27,23 @@ path O(new) and multi-level): the seen shards, the packed frontier and
 the per-level trace ring all stay ON DEVICE across levels; one jitted
 shard_map dispatch runs up to maxlvl levels in a lax.while_loop — each
 level expands, exchanges, RANK-MERGES against the sorted seen shards
-(only the <=R incoming keys are sorted; binary searches + row gathers
-shared with the single-chip resident engine, bfs._rank_merge — sort
-work no longer scales with the seen set; JAXMC_MESH_RANKMERGE=0 keeps
-the PR-8 full-sort as a bit-identical escape hatch, pinned to one
-level per dispatch), appends the trace ring and pushes one replicated
-[16]-i32 scalar vector into a device-side ring.  The host drains that
+(only the valid incoming keys, compacted to a [VC] block, are sorted;
+binary searches + row gathers shared with the single-chip resident
+engine, bfs._rank_merge — sort work does not scale with the seen
+set), appends the trace ring and pushes one replicated [_NS]-i32
+scalar vector into a device-side ring.  The host drains that
 ring once per superstep (mesh.host_syncs counts SUPERSTEPS, < level
 count — no row traffic), pre-sizes nothing, and only pulls rows on a
 violation (trace assembly), at a checkpoint, or never.  The loop exits
 early on violation / deadlock / assert / kernel overflow / truncation
 / empty frontier, so violation localization, SIGTERM drain and
 checkpointing keep their exact level-boundary semantics; capacity
-overflows (seen / frontier / trace ring / a2a bucket) roll the
-offending level back inside the step, so the host can grow the named
-capacity and redo it.  JAXMC_MESH_SUPERSTEP pins the level budget per
-dispatch (1 = the one-level escape hatch); unset, it adapts to
-measured dispatch wall like the single-chip resident controller.
+overflows (seen / frontier / trace ring / a2a bucket / valid
+candidates) roll the offending level back inside the step, so the host
+can grow the named capacity and redo it.  JAXMC_MESH_SUPERSTEP pins
+the level budget per dispatch (1 = the one-level escape hatch);
+unset, it adapts to measured dispatch wall like the single-chip
+resident controller.
 Learned capacities (and the settled levels-per-dispatch, MSL) persist
 as a profile keyed by (module, layout_sig, D, exchange)
 (compile/cache.py variants), so a second mesh run compiles once and
@@ -112,9 +112,9 @@ _SS_RINGCAP = 64
 # skips the VC growth redo too.  Profiles saved before PR 10/11 simply
 # lack MSL/VC (hints max-merge, absent keys default).
 _MESH_PROFILE_KEYS = ("SC", "FC", "TRL", "GAM16", "MSL")
-# optional cap: only rank-merge runs learn VC (the fullsort escape
-# hatch and JAXMC_MESH_VC=off never do) — absent in their profiles,
-# never a reason to drop the whole save/load (compile/cache.py)
+# optional on READ: profiles saved before PR 11, or by a run whose
+# merge never had padding to compact, lack VC — never a reason to drop
+# the whole save/load (compile/cache.py)
 _MESH_PROFILE_OPT = ("VC",)
 
 # resident-step scalar vector layout (one replicated [NS] i32 vector is
@@ -239,24 +239,11 @@ class MeshExplorer(TpuExplorer):
             raise ValueError(f"exchange must be 'gather' or 'a2a', "
                              f"got {exchange!r}")
         self.exchange = exchange
-        # shard-local merge strategy (ISSUE 10): "rank" keeps each seen
-        # shard's valid prefix SORTED as an invariant and merges only
-        # the ≤R incoming keys by rank (the single-chip resident
-        # engine's O(new) binary-search merge, shared via
-        # bfs._rank_merge); "fullsort" is the PR-8 full
-        # [SC+R, K+1]-key stable sort, kept as the JAXMC_MESH_RANKMERGE=0
-        # escape hatch (bit-identical counts/traces, pinned by tests).
-        self.merge = "fullsort" \
-            if os.environ.get("JAXMC_MESH_RANKMERGE", "").strip() == "0" \
-            else "rank"
         # levels per resident dispatch (ISSUE 10 supersteps):
         # JAXMC_MESH_SUPERSTEP=<n> pins it (1 = the one-level-per-
         # dispatch escape hatch); unset/auto adapts to measured
         # dispatch wall like the single-chip resident maxlvl
-        # controller.  The fullsort merge cannot run under the
-        # superstep while_loop (multi-key sort comparators explode XLA
-        # compile time there), so it always runs one level per
-        # dispatch.
+        # controller.
         ss = os.environ.get("JAXMC_MESH_SUPERSTEP", "").strip().lower()
         self._ss_fixed: Optional[int] = None
         if ss not in ("", "0", "auto"):
@@ -264,8 +251,6 @@ class MeshExplorer(TpuExplorer):
                 self._ss_fixed = max(1, min(int(ss), _SS_RINGCAP))
             except ValueError:
                 self._ss_fixed = None
-        if self.merge == "fullsort":
-            self._ss_fixed = 1
         # GROUPED expansion (ISSUE 11: PR 7's fused arm groups ported
         # onto the mesh expand path): on XLA:CPU a single jit holding
         # every kernel instance compiles superlinearly (the host_seen
@@ -586,37 +571,6 @@ class MeshExplorer(TpuExplorer):
             return D * D * (B + SB) * (K + PW + 1) * 4
         return D * D * C * (K + PW) * 4
 
-    def _merge_fn(self, SC: int, R: int,
-                  VC: Optional[int] = None) -> Callable:
-        """The shard-local merge-dedup shared by both step builders:
-        (seen_keys [SC,K], seen_count scalar, gkeys [R,K], gcand [R,PW],
-        gsrc [R]) -> dict(seen2, seen_count2, front_rows, front_rows_u,
-        front_src, front_count, new_count, v_ovf, v_need).
-
-        Two strategies, bit-identical counts/traces (ISSUE 10, pinned
-        by tests): "rank" (default) shares bfs._rank_merge — the seen
-        shard's sorted-prefix invariant means only the ≤R incoming keys
-        are sorted per level; "fullsort" (JAXMC_MESH_RANKMERGE=0) is
-        the PR-8 full stable sort over [SC+R, K+1] keys.  Both report
-        seen_count2 as the TRUE per-shard need BEFORE any [:SC] crop,
-        so the resident loop's grow-and-rerun path is strategy-blind;
-        both leave constraint-discarded states fingerprinted but never
-        counted, checked, or explored (TLC semantics).
-
-        `VC` (rank only, ISSUE 11): the valid-candidate capacity — the
-        exchanged block is ~95% masked padding, and the 5-key sort
-        over all R rows DOMINATED the measured merge wall
-        (MULTICHIP_r07: 11.6s of a 25s step wall on transfer_scaled
-        D=1).  The rank merge now compacts the valid rows to a
-        [VC]-bounded block first (cumsum-rank scatter, order
-        preserved) and sorts/searches/scatters only that.  Overflow
-        (`v_ovf`, with `v_need` the true count) rolls the level back
-        so the caller can grow VC and redo — same contract as every
-        other mesh capacity."""
-        if self.merge == "rank":
-            return self._merge_rank_fn(SC, R, VC)
-        return self._merge_fullsort_fn(SC, R)
-
     def _merge_finish_fn(self, R: int):
         """Shared merge epilogue: constraint-mask the compacted new
         rows and compact the explore-kept ones to the frontier front.
@@ -661,18 +615,35 @@ class MeshExplorer(TpuExplorer):
 
     def _merge_rank_fn(self, SC: int, R: int,
                        VC: Optional[int] = None) -> Callable:
-        """O(new) rank-merge (ISSUE 10 tentpole; ISSUE 11 made it
-        O(valid) too): compact the valid exchanged rows to a
-        [VC]-bounded block (cumsum-rank scatter — stable, so candidate
-        order and therefore counts/traces are bit-identical), then
-        sort only those keys, dedup against the sorted seen prefix
-        with binary searches and merge the new keys in by rank (row
-        gathers) — the single-chip resident engine's merge
-        (bfs._rank_merge),
-        shared rather than duplicated.  Sort work no longer scales
-        with the seen shard OR the ~95%-padding candidate block;
-        single-key-safe ops only, so the superstep while_loop can wrap
-        it.  VC=None (or >= R) disables the compaction."""
+        """The shard-local merge-dedup of every step builder:
+        (seen_keys [SC,K], seen_count scalar, gkeys [R,K], gcand [R,PW],
+        gsrc [R]) -> dict(seen2, seen_count2, front_rows, front_rows_u,
+        front_src, front_count, new_count, v_ovf, v_need, probe_blocks).
+
+        O(new) and O(valid): the exchanged block is ~95% masked padding
+        (its 5-key sort over all R rows was 11.6s of a 25s step wall on
+        transfer_scaled D=1 — XLA:CPU, MULTICHIP_r07), so the valid rows are
+        first compacted to a [VC]-bounded block (cumsum-rank scatter —
+        stable, so candidate order and therefore counts/traces are
+        unchanged), then only those keys are sorted, deduped against
+        the seen shard's sorted valid prefix with binary searches and
+        merged in by rank (row gathers) — the single-chip resident
+        engine's merge (bfs._rank_merge), shared rather than
+        duplicated; single-key-safe ops only, so the superstep
+        while_loop can wrap it.  seen_count2 is the TRUE per-shard need
+        BEFORE any [:SC] crop, which the resident loop grows SC to;
+        constraint-discarded states stay fingerprinted but are never
+        counted, checked or explored (TLC semantics).
+
+        `VC` is the valid-candidate capacity: overflow (`v_ovf`, with
+        `v_need` the true count) rolls the level back so the caller
+        can grow VC and redo — same contract as every other mesh
+        capacity.  The resident and grouped steps always pass one
+        (_initial_vc).  VC=None sorts all R rows uncompacted and is
+        the LEGACY exchange step's form alone (_get_mesh_step: the
+        host loop for PROPERTYs, and multihost.py), which has no
+        grow-and-redo for it; VC >= R has nothing to compact
+        either."""
         K, PW = self.K, self.PW
         compact = VC is not None and VC < R
         N = VC if compact else R
@@ -725,23 +696,14 @@ class MeshExplorer(TpuExplorer):
 
         return merge
 
-    def _initial_vc(self, FC: int) -> Optional[int]:
+    def _initial_vc(self, FC: int) -> int:
         """The rank merge's starting valid-candidate capacity (ISSUE
         11): the learned profile value when one exists, else 4*FC —
         generously above the typical valid-row count routed to one
         shard (revisits included), so most runs never pay the growth
         redo, while staying far under R's ~95% padding.  Always >= FC
         (the committed frontier is cropped to [FC] from the compacted
-        block).  JAXMC_MESH_VC pins it (growth still applies);
-        JAXMC_MESH_VC=off disables the compaction entirely."""
-        env = os.environ.get("JAXMC_MESH_VC", "").strip().lower()
-        if env == "off":
-            return None
-        if env:
-            try:
-                return max(FC, _pow2_at_least(int(env), lo=64))
-            except ValueError:
-                pass
+        block)."""
         hint = int(self._mesh_caps_hint.get("VC", 0))
         if hint:
             # a learned profile records the OBSERVED need (pow2-rounded
@@ -752,89 +714,10 @@ class MeshExplorer(TpuExplorer):
             return max(FC, _pow2_at_least(hint, lo=256))
         return max(FC, _pow2_at_least(4 * FC, lo=256))
 
-    def _merge_out_rows(self, R: int, VC: Optional[int]) -> int:
-        """Row count of the merge's compacted output block: VC when the
-        rank strategy's valid-compaction is active, else R."""
-        if self.merge == "rank" and VC is not None and VC < R:
-            return VC
-        return R
-
-    def _merge_fullsort_fn(self, SC: int, R: int) -> Callable:
-        """The PR-8 full-sort merge (JAXMC_MESH_RANKMERGE=0 escape
-        hatch): one stable [SC+R, K+1]-key sort with the seen-first
-        flag tiebreaker, then stable compactions.  The seen INPUT is
-        masked to its valid prefix [0:seen_count) and the OUTPUT tail
-        re-masked invalid, so the shard always satisfies the rank
-        strategy's sorted-valid-prefix invariant (a checkpoint written
-        by either strategy resumes under the other) and stale tail
-        rows can never re-enter the occupancy count."""
-        K = self.K
-        finish = self._merge_finish_fn(R)
-        invalid_key_np = np.concatenate(
-            [np.ones(1, np.int32), np.full(K - 1, SENTINEL, np.int32)])
-
-        @jax.named_scope("jaxmc.merge.sort")
-        def merge(seen_keys, seen_count, gkeys, gcand, gsrc):
-            invalid_key = jnp.asarray(invalid_key_np)
-            srow_valid = jnp.arange(SC) < seen_count
-            seen_keys = jnp.where(srow_valid[:, None], seen_keys,
-                                  invalid_key)
-            allk = jnp.concatenate([seen_keys, gkeys])    # [SC+R, K]
-            flag = jnp.concatenate([jnp.zeros(SC, jnp.int32),
-                                    jnp.ones(R, jnp.int32)])
-            idx0 = jnp.arange(SC + R, dtype=jnp.int32)
-            ops = tuple(allk[:, i] for i in range(K)) + (flag, idx0)
-            sorted_ = lax.sort(ops, num_keys=K + 1, is_stable=True)
-            skeys = jnp.stack(sorted_[:K], axis=1)
-            sflag = sorted_[K]
-            perm = sorted_[K + 1]
-            cidx = perm - SC              # candidate position (<0: seen)
-            rvalid = skeys[:, 0] == 0
-            neq_prev = jnp.concatenate([
-                jnp.array([True]),
-                jnp.any(skeys[1:] != skeys[:-1], axis=1)])
-            new = (sflag == 1) & rvalid & neq_prev
-            new_count = jnp.sum(new)
-
-            # compact the new rows (gather payload by sorted position);
-            # new_src is each new row's GLOBAL candidate index (gsrc
-            # lane) — the provenance the host needs for traces
-            ops2 = ((1 - new.astype(jnp.int32)), cidx)
-            comp = lax.sort(ops2, num_keys=1, is_stable=True)
-            new_cidx = comp[1][:R]
-            safe = jnp.clip(new_cidx, 0, R - 1)
-            new_rows = jnp.take(gcand, safe, axis=0)
-            new_src = jnp.take(gsrc, safe)
-            nvalid = jnp.arange(R) < new_count
-            new_rows = jnp.where(nvalid[:, None], new_rows, SENTINEL)
-
-            # merged seen keys, compacted (keeps key order).  NOTE
-            # seen_count2 counts BEFORE the [:SC] crop, so it reports
-            # the TRUE per-shard need — the resident loop grows SC to
-            # exactly this on overflow
-            keep = ((sflag == 0) & rvalid) | new
-            ops3 = ((1 - keep.astype(jnp.int32)),) + \
-                tuple(skeys[:, i] for i in range(K))
-            comp3 = lax.sort(ops3, num_keys=1, is_stable=True)
-            seen2 = jnp.stack(comp3[1:], axis=1)[:SC]
-            seen_count2 = jnp.sum(keep)
-            out_valid = jnp.arange(SC) < seen_count2
-            seen2 = jnp.where(out_valid[:, None], seen2, invalid_key)
-
-            front_rows, front_rows_u, front_src, front_count = \
-                finish(new_rows, new_src, nvalid)
-            return dict(seen2=seen2, seen_count2=seen_count2,
-                        front_rows=front_rows, front_rows_u=front_rows_u,
-                        front_src=front_src, front_count=front_count,
-                        new_count=new_count,
-                        # uniform surface with the rank strategy: the
-                        # fullsort merge has no valid-candidate cap
-                        v_ovf=jnp.asarray(False),
-                        v_need=jnp.asarray(0, jnp.int32),
-                        # one sort, no binary search
-                        probe_blocks=jnp.asarray(0, jnp.int32))
-
-        return merge
+    def _merge_out_rows(self, R: int, VC: int) -> int:
+        """Row count of the merge's compacted output block: VC, unless
+        the whole exchanged block is no larger."""
+        return min(VC, R)
 
     @jax.named_scope("jaxmc.scan")
     def _inv_scan(self, front_rows_u, front_count, R: int):
@@ -873,7 +756,7 @@ class MeshExplorer(TpuExplorer):
         plan = self.plan
         con_fns = self.constraint_fns
         block_fn = self._candidate_block_fn(FC)
-        merge_fn = self._merge_fn(SC, R)
+        merge_fn = self._merge_rank_fn(SC, R)
         # refinement/temporal PROPERTYs: stream every exchanged
         # candidate (revisits included) to the host, which runs the SAME
         # stepwise refinement and behavior-graph checkers as the
@@ -1165,18 +1048,17 @@ class MeshExplorer(TpuExplorer):
 
         return tail
 
-    def _mesh_resident_key(self, SC: int, FC: int, TRL: int,
-                           VC: Optional[int]):
+    def _mesh_resident_key(self, SC: int, FC: int, TRL: int, VC: int):
         """The resident step's compile-cache key — shared with the run
         loop's fresh_compile detection so the two can never disagree."""
         C = self.A * FC
         B = self._a2a_bucket(C, FC) if self.exchange == "a2a" else 0
         SB = self._a2a_spill_bucket(B) if B else 0
         return ("grp" if self._grouped else "res", SC, FC, TRL, B, SB,
-                self.store_trace, self.merge, VC)
+                self.store_trace, VC)
 
     def _get_mesh_resident_step(self, SC: int, FC: int, TRL: int,
-                                VC: Optional[int] = None) -> Callable:
+                                VC: int) -> Callable:
         """The MESH-RESIDENT superstep (ISSUE 8 tentpole, ISSUE 10
         multi-level fusion): one jitted shard_map dispatch that runs UP
         TO `maxlvl` levels in a lax.while_loop — each level expands,
@@ -1194,11 +1076,7 @@ class MeshExplorer(TpuExplorer):
 
         maxlvl, the level budget per dispatch, is a TRACED argument
         (like the single-chip resident maxlvl) so the host adapts it
-        without recompiling.  The "fullsort" merge strategy cannot live
-        inside a while_loop (multi-key sort comparators explode XLA
-        compile time there), so it compiles the single-level body
-        applied once — the one-level-per-dispatch escape-hatch program
-        — with the identical ring-of-one output surface.
+        without recompiling.
 
         Many-instance models on XLA:CPU (self._grouped) get the
         GROUPED-expansion variant instead: same signature, same
@@ -1209,17 +1087,15 @@ class MeshExplorer(TpuExplorer):
         C = self.A * FC
         route, R, B, SB = self._route_fn(C, FC)
         with_trace = self.store_trace
-        superstep = self.merge == "rank"
         key = self._mesh_resident_key(SC, FC, TRL, VC)
         if key in self._mesh_step_cache:
             return self._mesh_step_cache[key]
         K, D, PW = self.K, self.D, self.PW
         plan = self.plan
         block_fn = self._candidate_block_fn(FC)
-        merge_fn = self._merge_fn(SC, R, VC)
-        # N: the merge's compacted output block (VC when the rank
-        # valid-compaction is active) — the shapes every post-merge
-        # consumer (inv scan, frontier crop) runs at
+        merge_fn = self._merge_rank_fn(SC, R, VC)
+        # N: the merge's compacted output block — the shapes every
+        # post-merge consumer (inv scan, frontier crop) runs at
         N = self._merge_out_rows(R, VC)
         check_deadlock = self.model.check_deadlock
 
@@ -1268,54 +1144,43 @@ class MeshExplorer(TpuExplorer):
             ring0 = jnp.zeros((_SS_RINGCAP, _NS), jnp.int32)
             aux0 = jnp.zeros((_NA,), jnp.int32)
 
-            if superstep:
-                # one body serves both trace configurations: without
-                # tracing the two trace-ring carry slots hold scalar
-                # dummies that thread through unchanged (while_loop
-                # carries need consistent pytrees; one_level never
-                # touches its tr args when with_trace is False)
-                def body(carry):
-                    (sk, sc_, fp, fc_, trr, trs, lvl, dist, nlv, ring,
-                     aux, stop) = carry
-                    (sk, sc_, fp, fc_, trr2, trs2, lvl, dist, scal,
-                     aux, stop) = one_level(
-                        sk, sc_, fp, fc_,
-                        trr if with_trace else None,
-                        trs if with_trace else None, lvl, dist)
-                    if with_trace:
-                        trr, trs = trr2, trs2
-                    with jax.named_scope("jaxmc.mesh.scalars"):
-                        ring = lax.dynamic_update_slice(
-                            ring, scal[None], (nlv, 0))
-                    return (sk, sc_, fp, fc_, trr, trs, lvl, dist,
-                            nlv + 1, ring, aux, stop)
+            # one body serves both trace configurations: without
+            # tracing the two trace-ring carry slots hold scalar
+            # dummies that thread through unchanged (while_loop
+            # carries need consistent pytrees; one_level never
+            # touches its tr args when with_trace is False)
+            def body(carry):
+                (sk, sc_, fp, fc_, trr, trs, lvl, dist, nlv, ring,
+                 aux, stop) = carry
+                (sk, sc_, fp, fc_, trr2, trs2, lvl, dist, scal,
+                 aux, stop) = one_level(
+                    sk, sc_, fp, fc_,
+                    trr if with_trace else None,
+                    trs if with_trace else None, lvl, dist)
+                if with_trace:
+                    trr, trs = trr2, trs2
+                with jax.named_scope("jaxmc.mesh.scalars"):
+                    ring = lax.dynamic_update_slice(
+                        ring, scal[None], (nlv, 0))
+                return (sk, sc_, fp, fc_, trr, trs, lvl, dist,
+                        nlv + 1, ring, aux, stop)
 
-                def cond(carry):
-                    nlv, stop = carry[8], carry[11]
-                    return (~stop) & (nlv < jnp.minimum(
-                        maxlvl, jnp.int32(_SS_RINGCAP)))
+            def cond(carry):
+                nlv, stop = carry[8], carry[11]
+                return (~stop) & (nlv < jnp.minimum(
+                    maxlvl, jnp.int32(_SS_RINGCAP)))
 
-                dummy = jnp.int32(0)
-                carry0 = (seen_keys, seen_count0, frontier_p, fcount0,
-                          tr_rows if with_trace else dummy,
-                          tr_src if with_trace else dummy,
-                          lvl0, dist0, jnp.int32(0), ring0, aux0,
-                          jnp.asarray(False))
-                carry = lax.while_loop(cond, body, carry0)
-                (seen_f, seen_count_f, frontier_f, fcount_f) = carry[:4]
-                tr_rows_f, tr_src_f = (carry[4], carry[5]) \
-                    if with_trace else (None, None)
-                nlv_f, ring_f, aux_f = carry[8], carry[9], carry[10]
-            else:
-                # fullsort escape hatch: the identical body, applied
-                # once outside any while_loop — a ring of one entry
-                (seen_f, seen_count_f, frontier_f, fcount_f, tr_rows_f,
-                 tr_src_f, _lvl, _dist, scal, aux_f, _stop) = one_level(
-                    seen_keys, seen_count0, frontier_p, fcount0,
-                    tr_rows, tr_src, lvl0, dist0)
-                ring_f = lax.dynamic_update_slice(ring0, scal[None],
-                                                  (0, 0))
-                nlv_f = jnp.int32(1)
+            dummy = jnp.int32(0)
+            carry0 = (seen_keys, seen_count0, frontier_p, fcount0,
+                      tr_rows if with_trace else dummy,
+                      tr_src if with_trace else dummy,
+                      lvl0, dist0, jnp.int32(0), ring0, aux0,
+                      jnp.asarray(False))
+            carry = lax.while_loop(cond, body, carry0)
+            (seen_f, seen_count_f, frontier_f, fcount_f) = carry[:4]
+            tr_rows_f, tr_src_f = (carry[4], carry[5]) \
+                if with_trace else (None, None)
+            nlv_f, ring_f, aux_f = carry[8], carry[9], carry[10]
 
             outs = [seen_f.reshape(1, SC, K),
                     seen_count_f.reshape(1),
@@ -1433,7 +1298,7 @@ class MeshExplorer(TpuExplorer):
         return out
 
     def _get_mesh_grouped_step(self, SC: int, FC: int, TRL: int,
-                               VC: Optional[int] = None) -> Callable:
+                               VC: int) -> Callable:
         """The grouped-expansion resident level (ISSUE 11): expansion
         as ceil(A/fused_max) group dispatches (host-combined fault
         scalars, numpy), then ONE merge/tail dispatch running the
@@ -1449,7 +1314,7 @@ class MeshExplorer(TpuExplorer):
         if key in self._mesh_step_cache:
             return self._mesh_step_cache[key]
         K, D, PW = self.K, self.D, self.PW
-        merge_fn = self._merge_fn(SC, R, VC)
+        merge_fn = self._merge_rank_fn(SC, R, VC)
         N = self._merge_out_rows(R, VC)
         check_deadlock = self.model.check_deadlock
         tail = self._mk_level_tail(SC, FC, TRL, N, route, merge_fn,
@@ -1587,7 +1452,7 @@ class MeshExplorer(TpuExplorer):
         rule, so host and device dedup can never diverge. Returns
         (seen [D,SC,K], frontier [D,FC,PW], fcount [D],
         seen_counts [D]) as numpy — the per-shard valid-prefix lengths
-        the merge strategies key on, returned here so no caller
+        the rank merge keys on, returned here so no caller
         re-derives them from the validity lane."""
         K = self.K
         if keys is None:
@@ -1722,18 +1587,18 @@ class MeshExplorer(TpuExplorer):
                         == "0")
         self.log(f"-- mesh: {self.D} device(s), exchange="
                  f"{self.exchange} ({self._exchange_src}), "
-                 f"gamma={self._a2a_gamma:g}, merge={self.merge}, "
+                 f"gamma={self._a2a_gamma:g}, "
                  f"loop={'resident' if resident else 'host'}"
                  + (" [mesh_skew fault armed]" if self._skew else ""))
         tel = obs.current()
         tel.gauge("mesh.exchange", self.exchange)
         tel.gauge("mesh.devices", self.D)
-        # the mesh engine's own strategy stamps (ISSUE 10 satellite):
+        # the mesh engine's own dedup stamp (ISSUE 10 satellite):
         # TpuExplorer.__init__ gauges dedup.mode BEFORE the mesh
         # subclass forces fp128 keys, so multichip artifacts carried a
         # stale (or, under serve/bench telemetry scoping, no) value —
-        # re-stamp both here so `obs report` highlights name the dedup
-        # and merge strategy that actually ran
+        # re-stamp it here so `obs report` highlights name the dedup
+        # mode that actually ran
         tel.gauge("dedup.mode",
                   "fp128" + ("-view" if self.view_fn is not None
                              else ("-packed" if not self.plan.identity
@@ -1741,7 +1606,6 @@ class MeshExplorer(TpuExplorer):
         # likewise seen.mode (ISSUE 12): the base constructor stamped
         # it before the mesh subclass forced fp128 keys
         tel.gauge("seen.mode", "fingerprint")
-        tel.gauge("mesh.merge", self.merge)
         if resident:
             return self._run_mesh_resident()
         if self.seen_cap is not None:
@@ -2141,9 +2005,9 @@ class MeshExplorer(TpuExplorer):
                         # (it rode the scalar vector), like gamma —
                         # pure recompile, no device buffers to pad
                         VC = max(FC, _pow2_at_least(
-                            int(scal[_S_MAXV]), lo=2 * (VC or FC)))
+                            int(scal[_S_MAXV]), lo=2 * VC))
                         grew.append(f"VC->{VC}")
-                    if scal[_S_FOVF] and VC is not None:
+                    if scal[_S_FOVF]:
                         # the compacted block must still cover the
                         # frontier crop after FC growth
                         VC = max(VC, FC)
@@ -2307,7 +2171,7 @@ class MeshExplorer(TpuExplorer):
                             warnings)
 
     def _remember_caps(self, SC: int, FC: int, TRL: int,
-                       VC: Optional[int] = None) -> None:
+                       VC: int) -> None:
         """Keep the learned caps on the INSTANCE so warm re-runs (bench
         timed windows) start at them — zero growth redos, zero
         recompiles — exactly like the single-chip resident engine's
@@ -2321,16 +2185,15 @@ class MeshExplorer(TpuExplorer):
         # MSL is the SETTLED levels-per-dispatch, not a floor: it must
         # follow the controller down when a budget proved too slow
         h["MSL"] = max(1, int(self._mesh_maxlvl_warm))
-        if VC is not None:
-            h["VC"] = max(int(h.get("VC", 0)), VC)
+        h["VC"] = max(int(h.get("VC", 0)), VC)
 
     def _save_mesh_profile(self, SC: int, FC: int, TRL: int,
-                           VC: Optional[int] = None) -> None:
+                           VC: int) -> None:
         self._remember_caps(SC, FC, TRL, VC)
         caps = {"SC": SC, "FC": FC, "TRL": TRL,
                 "GAM16": max(1, int(round(self._a2a_gamma * 16))),
                 "MSL": max(1, int(self._mesh_maxlvl_warm))}
-        if VC is not None and self._vc_seen_need:
+        if self._vc_seen_need:
             # persist the OBSERVED need, not the running capacity
             # (which starts at the conservative 4*FC default and only
             # grows): the next process warm-starts its merge at the
@@ -2338,247 +2201,15 @@ class MeshExplorer(TpuExplorer):
             # pays one growth redo if its workload needs more.  The
             # in-process hint (_remember_caps) keeps the capacity so a
             # warm re-run in THIS process never recompiles.  Runs that
-            # never observed a need (fullsort escape hatch, compaction
-            # disabled) save NO VC at all — persisting the 4*FC
-            # heuristic would max-merge over a learned lean value and
-            # permanently inflate every future rank merge
-            # (_MESH_PROFILE_OPT contract above).
+            # never observed a need (VC >= R: no padding to compact)
+            # save NO VC at all — persisting the 4*FC heuristic would
+            # max-merge over a learned lean value and permanently
+            # inflate every future rank merge (_MESH_PROFILE_OPT above).
             caps["VC"] = max(FC, _pow2_at_least(
                 self._vc_seen_need, lo=256))
         self._save_caps_profile(
             caps, variant=self._profile_variant(),
             keys=_MESH_PROFILE_KEYS, optional=_MESH_PROFILE_OPT)
-
-    # ------------------------------------------------------------------
-    # phase-wall probe (ISSUE 10 obs satellite)
-    # ------------------------------------------------------------------
-
-    def probe_phase_walls(self, max_levels: int = 4
-                          ) -> Optional[Dict[str, float]]:
-        """Measured expand / exchange / merge wall breakdown.
-
-        The fused superstep makes the hot path unobservable from the
-        host (one dispatch covers many levels), so the breakdown comes
-        from a PROBE: the three phases built as SEPARATE jitted
-        shard_map programs at the run's learned capacities, driven a
-        few levels over the real initial shards, each phase timed with
-        block_until_ready (compile excluded by an untimed warm-up
-        pass).  BOTH merge strategies are timed on identical inputs
-        every level, so the artifact shows the rank-vs-fullsort merge
-        wall directly — the merge win lands in the obs artifact, not
-        just the scaling curve.  Best-effort perf probe only (stops if
-        the probe outgrows its fixed caps); counts are never consumed.
-
-        Gauges: mesh.phase_levels, mesh.phase_expand_s,
-        mesh.phase_exchange_s, mesh.phase_merge_rank_s,
-        mesh.phase_merge_fullsort_s, mesh.phase_merge_s (the active
-        strategy's total); one `mesh.phase_walls` trace event per
-        probed level."""
-        tel = obs.current()
-        t_all = time.time()
-        init_rows, explored_init, n_init, err = \
-            self._prepare_init(t_all, [])
-        if err is not None:
-            return None
-        D, K, PW = self.D, self.K, self.PW
-        hint = self._mesh_caps_hint
-        explored_mask = np.zeros(n_init, bool)
-        explored_mask[explored_init] = True
-        FC = _pow2_at_least(
-            max(int(hint.get("FC", 1)), max(1,
-                                            int(explored_mask.sum()))),
-            lo=64)
-        SC = _pow2_at_least(max(4 * FC, int(hint.get("SC", 1))),
-                            lo=256)
-        seen_np, frontier_np, fcount_np, scount_np = self._init_shards(
-            init_rows, np.nonzero(explored_mask)[0], D, SC, FC)
-        C = self.A * FC
-        route, R, B, SB = self._route_fn(C, FC)
-        block_fn = self._candidate_block_fn(FC)
-        plan = self.plan
-
-        def expand_step(frontier_p, fcount):
-            frontier = plan.unpack_rows(frontier_p.reshape(FC, PW))
-            fvalid = jnp.arange(FC) < fcount[0]
-            blk = block_fn(frontier, fvalid)
-            return (blk["ckeys"].reshape(1, C, K),
-                    blk["cand"].reshape(1, C, PW),
-                    blk["cvalid"].reshape(1, C))
-
-        def route_step(ckeys, cand, cvalid):
-            me_ = lax.axis_index("d")
-            gkeys, gcand, gsrc = route(ckeys.reshape(C, K),
-                                       cand.reshape(C, PW),
-                                       cvalid.reshape(C), me_)[:3]
-            return (gkeys.reshape(1, R, K), gcand.reshape(1, R, PW),
-                    gsrc.reshape(1, R))
-
-        # the rank merge is probed WITH the engine's valid-compaction
-        # capacity (ISSUE 11): probing the uncompacted path would
-        # report a merge wall the real run no longer pays.  When this
-        # engine has already run (the meshbench flow: warm-up + timed
-        # run, then the probe), the probe builds its own jits at the
-        # OBSERVED need — the size the durable profile hands the next
-        # process — so the artifact reports the warm-started merge
-        # wall, not the conservative first-process default.
-        VCp = self._initial_vc(FC)
-        if self._vc_seen_need and VCp is not None:
-            VCp = max(FC, _pow2_at_least(self._vc_seen_need, lo=256))
-
-        def mk_merge(strategy):
-            if strategy == "rank":
-                mfn = self._merge_rank_fn(SC, R, VCp)
-            else:
-                mfn = self._merge_fullsort_fn(SC, R)
-
-            def merge_step(seen_keys, seen_count, gkeys, gcand, gsrc):
-                mg = mfn(seen_keys.reshape(SC, K), seen_count[0],
-                         gkeys.reshape(R, K), gcand.reshape(R, PW),
-                         gsrc.reshape(R))
-                return (mg["seen2"].reshape(1, SC, K),
-                        mg["seen_count2"].reshape(1),
-                        mg["front_rows"][:FC].reshape(1, FC, PW),
-                        mg["front_count"].reshape(1),
-                        mg["v_need"].reshape(1))
-
-            return merge_step
-
-        jexp = obs.prof_wrap("mesh.probe_expand", jax.jit(shard_map(
-            expand_step, mesh=self.mesh,
-            in_specs=(P("d"), P("d")), out_specs=(P("d"),) * 3)))
-        jrt = obs.prof_wrap("mesh.probe_route", jax.jit(shard_map(
-            route_step, mesh=self.mesh,
-            in_specs=(P("d"),) * 3, out_specs=(P("d"),) * 3)))
-        jmg = {s: obs.prof_wrap(f"mesh.probe_merge_{s}", jax.jit(
-            shard_map(
-                mk_merge(s), mesh=self.mesh,
-                in_specs=(P("d"),) * 5, out_specs=(P("d"),) * 5)))
-            for s in ("rank", "fullsort")}
-
-        seen = self._put(seen_np)
-        scount = self._put(scount_np)
-        frontier = self._put(frontier_np)
-        fcount = self._put(fcount_np.astype(np.int32))
-
-        def timed(f, *a):
-            t0 = time.time()
-            out = f(*a)
-            jax.block_until_ready(out)
-            return out, time.time() - t0
-
-        # untimed warm-up pass: compile all four programs once
-        o1 = jexp(frontier, fcount)
-        jax.block_until_ready(o1)
-        o2 = jrt(*o1)
-        jax.block_until_ready(o2)
-        for s in jmg:
-            jax.block_until_ready(jmg[s](seen, scount, *o2))
-
-        walls = {"expand": 0.0, "exchange": 0.0,
-                 "merge_rank": 0.0, "merge_fullsort": 0.0}
-        lv = 0
-        while lv < max_levels and int(np.sum(np.asarray(fcount))) > 0:
-            o1, w_e = timed(jexp, frontier, fcount)
-            walls["expand"] += w_e
-            o2, w_x = timed(jrt, *o1)
-            walls["exchange"] += w_x
-            outs = {}
-            w_m = {}
-            for s in ("fullsort", "rank"):
-                outs[s], w_m[s] = timed(jmg[s], seen, scount, *o2)
-                walls["merge_" + s] += w_m[s]
-            seen2, scount2, frontier2, fcount2, v_need2 = outs["rank"]
-            tel.event("mesh.phase_walls", level=lv,
-                      expand_s=round(w_e, 6), exchange_s=round(w_x, 6),
-                      merge_rank_s=round(w_m["rank"], 6),
-                      merge_fullsort_s=round(w_m["fullsort"], 6))
-            if int(np.max(np.asarray(scount2))) > SC or \
-                    int(np.max(np.asarray(fcount2))) > FC or \
-                    (VCp is not None and
-                     int(np.max(np.asarray(v_need2))) > VCp):
-                break  # probe caps outgrown: keep what we measured
-            seen, scount = seen2, scount2
-            frontier, fcount = frontier2, fcount2
-            lv += 1
-
-        # the DENOMINATOR (ISSUE 11 acceptance): the real fused
-        # one-level resident step, timed at the same capacities over
-        # its own state — "merge+expand share of the step wall" is
-        # (expand_s + merge_s) / step_s, phases and step measured by
-        # the same probe.  Rebuilt from the host-side initial shards
-        # because donation (accelerators) consumes the step's inputs.
-        step_wall = 0.0
-        step_levels = 0
-        VCe = VCp if self.merge == "rank" else None
-        TRLp = _pow2_at_least(max_levels + 2, lo=16)
-        try:
-            jstep = self._get_mesh_resident_step(SC, FC, TRLp, VCe)
-            s_seen = self._put(seen_np)
-            s_scnt = self._put(scount_np)
-            s_front = self._put(frontier_np)
-            s_fcnt = self._put(fcount_np.astype(np.int32))
-            s_tr = (self._put(np.full((D, TRLp, FC, PW), SENTINEL,
-                                      np.int32)),
-                    self._put(np.full((D, TRLp, FC), -1, np.int32))) \
-                if self.store_trace else ()
-            warm = True
-            while step_levels < max_levels and \
-                    int(np.sum(np.asarray(s_fcnt))) > 0:
-                args = (s_seen, s_scnt, s_front, s_fcnt) + s_tr + (
-                    jnp.int32(step_levels), jnp.int32(1),
-                    jnp.int32(0), jnp.int32(0))
-                souts, w_s = timed(jstep, *args)
-                if warm:
-                    # first call pays the compile: measure it again
-                    warm = False
-                    ring0 = np.asarray(souts[-3])[0]
-                    if ring0[0][_S_FOVF] or ring0[0][_S_SOVF] or \
-                            ring0[0][_S_TOVF] or ring0[0][_S_AOVF] or \
-                            ring0[0][_S_VOVF]:
-                        break  # probe caps too small for the real step
-                    s_seen, s_scnt, s_front, s_fcnt = souts[:4]
-                    s_tr = souts[4:6] if self.store_trace else ()
-                    continue
-                step_wall += w_s
-                step_levels += 1
-                ring = np.asarray(souts[-3])[0]
-                if ring[0][_S_FOVF] or ring[0][_S_SOVF] or \
-                        ring[0][_S_TOVF] or ring[0][_S_AOVF] or \
-                        ring[0][_S_VOVF] or ring[0][_S_OVC]:
-                    break
-                s_seen, s_scnt, s_front, s_fcnt = souts[:4]
-                s_tr = souts[4:6] if self.store_trace else ()
-        except Exception as ex:  # noqa: BLE001 — a perf probe must
-            # never fail the run it rides on
-            self.log(f"-- phase probe: step timing skipped ({ex})")
-
-        out = {"levels": lv,
-               "expand_s": round(walls["expand"], 6),
-               "exchange_s": round(walls["exchange"], 6),
-               "merge_rank_s": round(walls["merge_rank"], 6),
-               "merge_fullsort_s": round(walls["merge_fullsort"], 6)}
-        out["merge_s"] = out["merge_rank_s"] if self.merge == "rank" \
-            else out["merge_fullsort_s"]
-        if step_levels:
-            out["step_levels"] = step_levels
-            out["step_s"] = round(step_wall, 6)
-            # normalize to per-level before forming the share: the
-            # phase loop and the step loop can cover different level
-            # counts (either can hit a cap early)
-            hot = (out["expand_s"] + out["merge_s"]) / max(lv, 1)
-            out["hot_share"] = round(
-                hot / max(step_wall / step_levels, 1e-9), 4)
-        tel.gauge("mesh.phase_levels", lv)
-        tel.gauge("mesh.phase_expand_s", out["expand_s"])
-        tel.gauge("mesh.phase_exchange_s", out["exchange_s"])
-        tel.gauge("mesh.phase_merge_s", out["merge_s"])
-        tel.gauge("mesh.phase_merge_rank_s", out["merge_rank_s"])
-        tel.gauge("mesh.phase_merge_fullsort_s",
-                  out["merge_fullsort_s"])
-        if step_levels:
-            tel.gauge("mesh.phase_step_s", out["step_s"])
-            tel.gauge("mesh.phase_hot_share", out["hot_share"])
-        return out
 
     # ------------------------------------------------------------------
     # the LEGACY host loop (refinement/temporal PROPERTYs; the

@@ -115,11 +115,10 @@ def run_multihost_child(process_id: int, num_processes: int,
     init_rows = np.stack([me.layout.encode(st) for st in me.init_states])
     explored, viol = filter_init_states(model, me.layout, init_rows)
     assert viol is None, "initial-state violation in the dryrun model"
-    # per-shard seen occupancy (ISSUE 10): the step's merge now takes
-    # the valid-prefix length explicitly (the rank strategy binary-
-    # searches it; fullsort masks stale tail rows with it), so the
-    # loop carries the step's seen-count output back into the next
-    # level's input, seeded by the counts _init_shards built
+    # per-shard seen occupancy (ISSUE 10): the step's merge takes the
+    # valid-prefix length explicitly (the rank merge binary-searches
+    # it), so the loop carries the step's seen-count output back into
+    # the next level's input, seeded by the counts _init_shards built
     seen_h, front_h, fcount_h, scount_h = me._init_shards(
         init_rows, explored, D, SC, FC)
 

@@ -473,9 +473,8 @@ def load_capacity_profile(module: str, layout_sig: str, tel=None,
     tel.counter("profile.hits")
     out = {k: int(caps[k]) for k in keys}
     # `optional` names caps newer engines persist but older profiles
-    # (or strategy configurations that never learn them — ISSUE 11's
-    # mesh VC under the fullsort escape hatch) may lack: validated the
-    # same way when present, silently absent otherwise
+    # may lack (the mesh VC): validated the same way when present,
+    # silently absent otherwise
     for k in optional:
         if isinstance(caps.get(k), int) and 0 < caps[k] < (1 << 31):
             out[k] = int(caps[k])

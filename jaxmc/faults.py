@@ -97,9 +97,8 @@ per dispatch, because the routing is compiled into the jitted step):
                       forcing worst-case imbalance, the a2a spill pass
                       and — once the spill overflows — the
                       gamma-growth level rerun.  Counts and traces
-                      must stay exact throughout, under BOTH merge
-                      strategies (rank / fullsort, ISSUE 10) and any
-                      superstep size (tests/test_mesh_resident.py).
+                      must stay exact throughout, at any superstep
+                      size (tests/test_mesh_resident.py).
 
 Cross-process accounting: the first registry to activate creates a
 state directory and exports it as JAXMC_FAULTS_STATE, so forked pool

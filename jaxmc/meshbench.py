@@ -30,22 +30,18 @@ Subcommands
           below), and each leg's jaxmc.metrics/2 artifact gates like
           every bench-check leg via
           `python -m jaxmc.obs diff --fail-on-regress` against a saved
-          baseline (first run snapshots it).  `--merge fullsort` runs
-          the same leg under the JAXMC_MESH_RANKMERGE=0 escape hatch
-          (the Makefile's rank-merge parity leg).  Wired into
+          baseline (first run snapshots it).  Wired into
           `make bench-check` via `make multichip-check`.
   bench   D in {1,2,4,8} (default) timed legs over the bench rungs
           (MCraft_3s_bench + transfer_scaled): per D, one warm-up run
           (compile + capacity training + profile persist) then a timed
           fully-warm run — states/sec/chip, per-level exchange bytes,
-          shard balance, host_syncs <= levels (supersteps working),
-          window_recompiles (must be 0 on the warm run) and the
-          measured expand/exchange/merge phase-wall breakdown
-          (probe_phase_walls — both merge strategies timed, so the
-          rank win is in the artifact).  Writes the MULTICHIP_r*
-          artifact (--out) plus per-leg metrics artifacts, gated the
-          same way when baselines exist; two MULTICHIP_r* artifacts
-          diff directly via `python -m jaxmc.obs diff`.
+          shard balance, host_syncs <= levels (supersteps working)
+          and window_recompiles (must be 0 on the warm run).  Writes
+          the MULTICHIP_r* artifact (--out) plus per-leg metrics
+          artifacts, gated the same way when baselines exist; two
+          MULTICHIP_r* artifacts diff directly via
+          `python -m jaxmc.obs diff`.
   child   one (spec, D) leg — internal.
 
 Rungs that need the reference corpus (the MCraft family EXTENDS the
@@ -100,18 +96,15 @@ def _leg_name(spec: str, cfg: Optional[str]) -> str:
 def _run_child(spec: str, cfg: Optional[str], devices: int,
                exchange: Optional[str], timed: bool, out_dir: str,
                store_trace: bool, timeout_s: float,
-               merge: Optional[str] = None,
-               phase_probe: bool = False,
                log=print) -> Dict:
     name = _leg_name(spec, cfg)
-    suffix = f"_{merge}" if merge else ""
     # artifacts (and therefore the saved baselines _gate snapshots) are
     # NAMESPACED by platform (ISSUE 11): a cpu virtual-device baseline
     # must never gate a real-chip run — each backend regates its own
     plat = os.environ.get("JAXMC_MESHBENCH_PLATFORM", "cpu")
     metrics = os.path.join(
         out_dir,
-        f"jaxmc_multichip_{plat}_{name}_d{devices}{suffix}.json")
+        f"jaxmc_multichip_{plat}_{name}_d{devices}.json")
     # pre-ISSUE-11 baselines had no platform segment; those were all
     # measured on cpu virtual devices, so migrate them into the cpu
     # namespace instead of silently re-seeding the gate from current
@@ -119,7 +112,7 @@ def _run_child(spec: str, cfg: Optional[str], devices: int,
     base = metrics.replace(".json", ".baseline.json")
     legacy = os.path.join(
         out_dir,
-        f"jaxmc_multichip_{name}_d{devices}{suffix}.baseline.json")
+        f"jaxmc_multichip_{name}_d{devices}.baseline.json")
     if plat == "cpu" and not os.path.exists(base) \
             and os.path.exists(legacy):
         os.replace(legacy, base)
@@ -132,12 +125,8 @@ def _run_child(spec: str, cfg: Optional[str], devices: int,
         cmd += ["--cfg", cfg]
     if exchange:
         cmd += ["--exchange", exchange]
-    if merge:
-        cmd += ["--merge", merge]
     if timed:
         cmd += ["--timed"]
-    if phase_probe:
-        cmd += ["--phase-probe"]
     if store_trace:
         cmd += ["--store-trace"]
     env = dict(os.environ, PYTHONPATH=_REPO)
@@ -205,7 +194,7 @@ def cmd_check(args) -> int:
             # loaded box
             r = _run_child(spec, cfg, D, args.exchange, True,
                            args.out_dir, store_trace=False,
-                           timeout_s=args.timeout, merge=args.merge)
+                           timeout_s=args.timeout)
             if not r.get("ok"):
                 print(f"MESHBENCH FAIL {name} D={D}: "
                       f"{r.get('error', r)}")
@@ -231,7 +220,7 @@ def cmd_check(args) -> int:
                 failures += 1
                 continue
             print(f"MESHBENCH ok {name} D={D} exchange="
-                  f"{r['exchange']} merge={r.get('merge')}: "
+                  f"{r['exchange']}: "
                   f"{r['generated']} gen / "
                   f"{r['distinct']} distinct "
                   f"({r['states_per_sec']:,.0f} st/s, host_syncs="
@@ -260,8 +249,7 @@ def cmd_bench(args) -> int:
         for D in args.devices:
             r = _run_child(spec, cfg, D, args.exchange, True,
                            args.out_dir, store_trace=False,
-                           timeout_s=args.timeout, merge=args.merge,
-                           phase_probe=True)
+                           timeout_s=args.timeout)
             if not r.get("ok"):
                 print(f"MESHBENCH FAIL {name} D={D}: "
                       f"{r.get('error', r)}")
@@ -270,13 +258,13 @@ def cmd_bench(args) -> int:
                               "error": r.get("error", "failed")})
                 continue
             point = {k: r[k] for k in
-                     ("devices", "exchange", "merge", "generated",
+                     ("devices", "exchange", "generated",
                       "distinct", "wall_s", "warmup_wall_s",
                       "states_per_sec",
                       "states_per_sec_per_chip", "window_recompiles",
                       "host_syncs", "levels", "supersteps",
                       "superstep_levels", "exchange_bytes",
-                      "exchange_bytes_per_level", "phase_walls")
+                      "exchange_bytes_per_level")
                      if k in r}
             for k in ("a2a_gamma", "a2a_spill", "a2a_max_bucket",
                       "shard_balance"):
@@ -328,11 +316,6 @@ def cmd_bench(args) -> int:
 
 
 def cmd_child(args) -> int:
-    if args.merge:
-        # the merge strategy is read from the environment at engine
-        # build (tpu/mesh.py): rank is the default, 0 forces fullsort
-        os.environ["JAXMC_MESH_RANKMERGE"] = \
-            "0" if args.merge == "fullsort" else "1"
     plat = os.environ.get("JAXMC_MESHBENCH_PLATFORM", "cpu")
     if plat == "cpu":
         # must precede ANY jax import in this process
@@ -410,8 +393,6 @@ def cmd_child(args) -> int:
             wall = time.time() - t0
             window_recompiles = sum(
                 1 for lv in tel.levels[lvl0:] if lv.get("fresh_compile"))
-        phase_walls = me.probe_phase_walls() if args.phase_probe \
-            else None
     levels = len(tel.levels) - (lvl0 if args.timed else 0)
     host_syncs = tel.counters.get("mesh.host_syncs", 0) - \
         (sync0 if args.timed else 0)
@@ -421,7 +402,6 @@ def cmd_child(args) -> int:
         "ok": bool(result.ok),
         "devices": args.devices,
         "exchange": me.exchange,
-        "merge": me.merge,
         "generated": int(result.generated),
         "distinct": int(result.distinct),
         "diameter": int(result.diameter),
@@ -440,8 +420,6 @@ def cmd_child(args) -> int:
         "exchange_bytes": int(xbytes),
         "exchange_bytes_per_level": int(xbytes / max(levels, 1)),
     }
-    if phase_walls:
-        out["phase_walls"] = phase_walls
     for k, src in (("superstep_levels", "mesh.superstep_levels"),
                    ("a2a_gamma", "mesh.a2a_gamma"),
                    ("a2a_spill", "mesh.a2a_spill"),
@@ -459,12 +437,12 @@ def cmd_child(args) -> int:
         summary["backend"] = "jax"
         summary["spec"] = args.spec
         summary["multichip"] = {k: out[k] for k in
-                                ("devices", "exchange", "merge",
+                                ("devices", "exchange",
                                  "states_per_sec",
                                  "states_per_sec_per_chip",
                                  "window_recompiles", "host_syncs",
                                  "supersteps", "superstep_levels",
-                                 "levels", "phase_walls",
+                                 "levels",
                                  "exchange_bytes_per_level")
                                 if k in out}
         obs.write_json_atomic(args.metrics_out, summary)
@@ -500,11 +478,6 @@ def main(argv=None) -> int:
         p.add_argument("--exchange", default=None,
                        choices=(None, "a2a", "gather"),
                        help="override the per-D default strategy")
-        p.add_argument("--merge", default=None,
-                       choices=(None, "rank", "fullsort"),
-                       help="pin the dedup-merge strategy (default: "
-                            "the engine default, rank; the fullsort "
-                            "leg proves escape-hatch parity)")
         p.add_argument("--rung", action="append", default=None,
                        help="spec[=cfg], repeatable (repo-relative)")
         p.add_argument("--out-dir", default=os.environ.get(
@@ -523,10 +496,7 @@ def main(argv=None) -> int:
     pch.add_argument("--cfg", default=None)
     pch.add_argument("--devices", type=int, required=True)
     pch.add_argument("--exchange", default=None)
-    pch.add_argument("--merge", default=None,
-                     choices=(None, "rank", "fullsort"))
     pch.add_argument("--timed", action="store_true")
-    pch.add_argument("--phase-probe", action="store_true")
     pch.add_argument("--store-trace", action="store_true")
     pch.add_argument("--metrics-out", default=None)
     args = ap.parse_args(argv)

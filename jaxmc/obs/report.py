@@ -228,12 +228,14 @@ def cmd_report(args, out=sys.stdout) -> int:
                     f"st/s/chip",
                     f"syncs={pt.get('host_syncs')}/"
                     f"{pt.get('levels')} lvls"]
+            # `merge` / `phase_walls`: only the committed
+            # MULTICHIP_r07/r08.json carry them (schema.py)
             if pt.get("merge"):
                 bits.append(f"merge={pt['merge']}")
             pw = pt.get("phase_walls")
             if isinstance(pw, dict):
                 # tolerate missing-phase rows: a probe that hit its cap
-                # early (or an older artifact) reports what it measured
+                # early reports what it measured
                 bits.append(
                     f"walls expand={pw.get('expand_s', '-')}s "
                     f"exchange={pw.get('exchange_s', '-')}s "
@@ -347,13 +349,9 @@ def cmd_report(args, out=sys.stdout) -> int:
               "layout.packed_width_lanes", "layout.bits_per_state",
               "device.donation", "profile.status",
               "fingerprint.occupancy", "mesh.exchange", "mesh.devices",
-              "mesh.merge", "mesh.supersteps", "mesh.superstep_levels",
+              "mesh.supersteps", "mesh.superstep_levels",
               "mesh.a2a_gamma", "mesh.a2a_spill", "mesh.a2a_max_bucket",
               "mesh.shard_balance",
-              "mesh.phase_expand_s", "mesh.phase_exchange_s",
-              "mesh.phase_merge_s", "mesh.phase_merge_rank_s",
-              "mesh.phase_merge_fullsort_s",
-              "mesh.phase_step_s", "mesh.phase_hot_share",
               "backend.oracle_choice", "backend.oracle_wall_s",
               "device.mem_high_water_bytes", "watchdog.max_stall_s"):
         if k in g:

@@ -236,12 +236,11 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
 
   (PR 10, still jaxmc.metrics/2 — all additive/optional; the mesh
    rank-merge + superstep surface, tpu/mesh.py + jaxmc/meshbench.py:)
-    - merge strategy: gauge `mesh.merge` ("rank" | "fullsort") — the
-      shard-local dedup-merge that actually ran (rank is the default;
-      JAXMC_MESH_RANKMERGE=0 forces the PR-8 fullsort); the mesh
-      engine now also re-stamps `dedup.mode` at run start (the PR-6
+    - the mesh engine re-stamps `dedup.mode` at run start (the PR-6
       gauge was stamped before the mesh subclass forced fp128 keys,
-      so multichip artifacts carried a stale value).
+      so multichip artifacts carried a stale value).  The shard-local
+      merge is bfs._rank_merge and nothing else (PR 28): no gauge
+      names it.
     - supersteps: `mesh.host_syncs` now counts SUPERSTEPS — one
       scalar-RING read per dispatch, each dispatch fusing up to
       JAXMC_MESH_SUPERSTEP levels in a device-side lax.while_loop —
@@ -250,20 +249,17 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       `mesh.superstep_levels` (deepest fused dispatch).  Mesh level
       records gain `superstep` (which dispatch the level rode) and
       their `wall_s` is the dispatch wall amortized over its levels.
-    - phase walls (jaxmc.meshbench bench legs, MeshExplorer
-      .probe_phase_walls): gauges `mesh.phase_levels`,
-      `mesh.phase_expand_s`, `mesh.phase_exchange_s`,
-      `mesh.phase_merge_s`, `mesh.phase_merge_rank_s`,
-      `mesh.phase_merge_fullsort_s` — a measured expand / exchange /
-      merge wall breakdown at the run's learned capacities (both
-      merge strategies timed on identical inputs, so the rank win is
-      in the artifact); per-probed-level trace event
-      `mesh.phase_walls {level, expand_s, exchange_s, merge_rank_s,
-      merge_fullsort_s}`.
-    - multichip artifacts add per-point `merge`, `supersteps`,
-      `superstep_levels` and `phase_walls`; `python -m jaxmc.obs
-      diff` accepts two+ jaxmc.multichip/1 artifacts directly and
-      gates per-(rung, D) states/sec/chip with REGRESS flags.
+    - multichip artifacts add per-point `supersteps` and
+      `superstep_levels`; `python -m jaxmc.obs diff` accepts two+
+      jaxmc.multichip/1 artifacts directly and gates per-(rung, D)
+      states/sec/chip with REGRESS flags.  The COMMITTED
+      MULTICHIP_r07/r08.json also carry per-point `merge` and
+      `phase_walls {expand_s, exchange_s, merge_rank_s,
+      merge_fullsort_s, step_s, hot_share}`: XLA:CPU host walls from
+      a probe that timed the rank merge beside the full-sort merge it
+      replaced; probe and full-sort merge were removed in PR 28.
+      Nothing writes these keys any more; `obs report` still renders
+      them from those files.
     - serve warm-registry eviction (ROADMAP item 3): counter
       `serve.evictions` + trace event `serve.evicted {sig}` when the
       bounded LRU (JAXMC_SERVE_WARM_MAX, default 32) drops the

@@ -580,13 +580,16 @@ JumpSpec == HCini /\\ [][Jump]_hr
 
 
 class TestLevelRankMergeParity:
-    """Parity pins for the host-loop rank-merge port (ISSUE 11
-    tentpole b): the level mode — the LEGACY host loop refinement and
-    temporal PROPERTY checking runs on — merges each level's candidates
-    into the sorted seen prefix by rank instead of full-sorting
-    seen+candidates.  JAXMC_LEVEL_RANKMERGE=0 keeps the full sort as
-    the parity oracle; counts, verdicts and traces must be
-    bit-identical either way."""
+    """The level engine against the INTERPRETER
+    (jaxmc.engine.explore.Explorer, the semantic reference of every
+    engine) on PROPERTY cfgs.  The level mode is the host loop
+    refinement and temporal PROPERTY checking runs on; it merges each
+    level's candidates into the sorted seen prefix by rank
+    (bfs._rank_merge), and the edge stream the checkers read follows
+    the frontier that merge produces.  Counts, diameter and verdict
+    must equal the interpreter's on passing cfgs; verdict, violated
+    property and the whole counterexample trace on failing ones
+    (`generated` there is an early-exit count and is not compared)."""
 
     REFINE_OK = """---- MODULE rmhc ----
 EXTENDS Naturals
@@ -616,68 +619,64 @@ Cycles == []<><<Next>>_hr
 ====
 """.replace("%%", "%")
 
-    def _pair(self, monkeypatch, mk):
-        """One run per merge strategy on fresh explorers."""
-        out = []
-        for flag in ("0", "1"):
-            monkeypatch.setenv("JAXMC_LEVEL_RANKMERGE", flag)
-            out.append(mk().run())
-        return out
-
-    def _write(self, tmp_path, name, text):
+    def _pair(self, tmp_path, name, text, cfg):
+        """(level engine, interpreter) results on one inline spec."""
+        from jaxmc.engine.explore import Explorer
+        from jaxmc.tpu.bfs import TpuExplorer
         p = tmp_path / name
         p.write_text(text)
-        return str(p)
+        return (TpuExplorer(load(str(p), cfg)).run(),
+                Explorer(load(str(p), cfg)).run())
 
-    def test_refinement_counts_identical(self, tmp_path, monkeypatch):
-        from jaxmc.tpu.bfs import TpuExplorer
-        spec = self._write(tmp_path, "rmhc.tla", self.REFINE_OK)
-        cfg = ModelConfig(specification="HC", properties=["HC"],
-                          check_deadlock=False)
-        full, rank = self._pair(
-            monkeypatch, lambda: TpuExplorer(load(spec, cfg)))
-        assert full.ok and rank.ok
-        assert (full.distinct, full.generated, full.diameter) == \
-            (rank.distinct, rank.generated, rank.diameter)
+    @staticmethod
+    def _same_passing_run(dev, ref):
+        assert dev.ok and ref.ok
+        assert (dev.distinct, dev.generated, dev.diameter) == \
+            (ref.distinct, ref.generated, ref.diameter)
 
-    def test_refinement_violation_trace_identical(self, tmp_path,
-                                                  monkeypatch):
-        from jaxmc.tpu.bfs import TpuExplorer
-        spec = self._write(tmp_path, "rmbad.tla", self.REFINE_BAD)
-        cfg = ModelConfig(specification="HC", properties=["JumpSpec"],
-                          check_deadlock=False)
-        full, rank = self._pair(
-            monkeypatch, lambda: TpuExplorer(load(spec, cfg)))
-        assert not full.ok and not rank.ok
-        assert full.violation.name == rank.violation.name == "JumpSpec"
-        # bit-identical trace: same states, same action labels
-        assert full.violation.trace == rank.violation.trace
+    @staticmethod
+    def _same_counterexample(dev, ref):
+        assert not dev.ok and not ref.ok
+        assert dev.violation.kind == ref.violation.kind
+        assert dev.violation.name == ref.violation.name
+        # the whole trace: same states, same action labels
+        assert dev.violation.trace == ref.violation.trace
 
-    def test_temporal_counts_identical(self, tmp_path, monkeypatch):
-        # the behavior-graph liveness path streams every level's edges
-        # through the same merged frontier the rank merge produces
-        from jaxmc.tpu.bfs import TpuExplorer
-        spec = self._write(tmp_path, "rmlive.tla", self.TEMPORAL)
-        cfg = ModelConfig(specification="Spec", properties=["Cycles"],
-                          check_deadlock=False)
-        full, rank = self._pair(
-            monkeypatch, lambda: TpuExplorer(load(spec, cfg)))
-        assert full.ok and rank.ok
-        assert (full.distinct, full.generated, full.diameter) == \
-            (rank.distinct, rank.generated, rank.diameter)
+    def test_refinement_counts_identical(self, tmp_path):
+        """Reference: the interpreter."""
+        dev, ref = self._pair(
+            tmp_path, "rmhc.tla", self.REFINE_OK,
+            ModelConfig(specification="HC", properties=["HC"],
+                        check_deadlock=False))
+        self._same_passing_run(dev, ref)
 
-    def test_temporal_violation_parity(self, tmp_path, monkeypatch):
-        # without fairness the cycle property fails: both merges must
-        # agree on the verdict and the counterexample prefix
-        from jaxmc.tpu.bfs import TpuExplorer
-        spec = self._write(tmp_path, "rmlive.tla", self.TEMPORAL)
-        cfg = ModelConfig(init="Init", next="Next",
-                          properties=["Cycles"], check_deadlock=False)
-        full, rank = self._pair(
-            monkeypatch, lambda: TpuExplorer(load(spec, cfg)))
-        assert not full.ok and not rank.ok
-        assert full.violation.name == rank.violation.name
-        assert full.violation.trace == rank.violation.trace
+    def test_refinement_violation_trace_identical(self, tmp_path):
+        """Reference: the interpreter."""
+        dev, ref = self._pair(
+            tmp_path, "rmbad.tla", self.REFINE_BAD,
+            ModelConfig(specification="HC", properties=["JumpSpec"],
+                        check_deadlock=False))
+        assert dev.violation.name == "JumpSpec"
+        self._same_counterexample(dev, ref)
+
+    def test_temporal_counts_identical(self, tmp_path):
+        """Reference: the interpreter.  The behavior-graph liveness
+        path streams every level's edges through the merged frontier
+        the rank merge produces."""
+        dev, ref = self._pair(
+            tmp_path, "rmlive.tla", self.TEMPORAL,
+            ModelConfig(specification="Spec", properties=["Cycles"],
+                        check_deadlock=False))
+        self._same_passing_run(dev, ref)
+
+    def test_temporal_violation_parity(self, tmp_path):
+        """Reference: the interpreter.  Without fairness the cycle
+        property fails: same verdict, same counterexample."""
+        dev, ref = self._pair(
+            tmp_path, "rmlive.tla", self.TEMPORAL,
+            ModelConfig(init="Init", next="Next",
+                        properties=["Cycles"], check_deadlock=False))
+        self._same_counterexample(dev, ref)
 
 
 @pytest.mark.slow

@@ -3,16 +3,18 @@ dedup, O(new) rank-merge, multi-level fused supersteps.
 
 Pins, on repo-local models only (no reference corpus needed):
   * a2a is the DEFAULT exchange for D > 1 (JAXMC_MESH_EXCHANGE
-    overrides); rank-merge is the DEFAULT dedup-merge
-    (JAXMC_MESH_RANKMERGE=0 forces the PR-8 fullsort);
+    overrides);
   * the resident loop reads ONE scalar ring per SUPERSTEP —
     mesh.host_syncs counts supersteps (<= level records, < on any
     multi-level run), no row traffic; JAXMC_MESH_SUPERSTEP=1 restores
     one-sync-per-level exactly;
-  * rank vs fullsort and superstep vs one-level are BIT-IDENTICAL:
-    counts, distinct totals, violation traces, and (post the PR-10
-    stale-tail fix) seen-shard occupancy — including under the
-    mesh_skew fault and mid-superstep capacity growth;
+  * the shard-local rank merge against references that share no
+    code with it: counts equal the INTERPRETER's, seen-shard occupancy
+    equals the host_seen engine's native store size, a violation
+    trace has the interpreter's kind, depth and labels and replays
+    through its Next — at D in {1, 2, 4} and under the mesh_skew
+    fault; superstep vs one-level is BIT-IDENTICAL (counts, violation
+    traces), mid-superstep capacity growth included;
   * a second run on a warm engine has window_recompiles == 0, and a
     FRESH engine starting from the persisted (module, layout, D,
     exchange) capacity profile compiles exactly once with zero
@@ -65,10 +67,14 @@ def _no_profile_store(tmp_path, monkeypatch):
     monkeypatch.setenv("JAXMC_PROFILE_STORE", str(tmp_path / "prof"))
 
 
-def mesh4():
+def meshd(D):
     import jax
     from jax.sharding import Mesh
-    return Mesh(np.array(jax.devices()[:4]), ("d",))
+    return Mesh(np.array(jax.devices()[:D]), ("d",))
+
+
+def mesh4():
+    return meshd(4)
 
 
 class TestExchangeDefault:
@@ -124,7 +130,6 @@ class TestResidentLoop:
         assert "mesh.row_syncs" not in tel.counters
         assert tel.counters["mesh.exchange_bytes"] > 0
         assert tel.gauges["mesh.exchange"] == "a2a"
-        assert tel.gauges["mesh.merge"] == "rank"
         assert tel.gauges["dedup.mode"].startswith("fp128")
         assert tel.gauges["mesh.shard_balance"] >= 1.0
 
@@ -212,80 +217,112 @@ class TestResidentLoop:
             [a for _, a in r_host.violation.trace]
 
 
+def _store_occupancy(model):
+    """Distinct dedup keys the host_seen engine's native store ends
+    with (`len(store)`): a count that shares nothing with
+    bfs._rank_merge or the mesh's seen shards."""
+    from jaxmc.tpu.bfs import TpuExplorer
+    ex = TpuExplorer(model, host_seen=True)
+    r = ex.run()
+    return r, ex._fp_occupancy
+
+
 class TestMergeStrategies:
-    """ISSUE 10: rank-merge vs fullsort bit-identical parity."""
+    """The shard-local rank merge (bfs._rank_merge under the mesh's
+    valid-candidate compaction) against references that share no code
+    with it."""
 
-    def test_rankmerge_env_escape_hatch(self, monkeypatch):
+    @pytest.mark.parametrize("D", [1, 2, 4])
+    def test_counts_and_occupancy_against_interp_and_host_store(self, D):
+        """References: the interpreter for the counts; for the
+        seen-shard occupancy the host_seen engine's native store,
+        whose size is `len(store)`.  constoy discards states by
+        CONSTRAINT: they stay fingerprinted (occupancy) and are never
+        counted (distinct), so the two numbers differ and a stale or
+        re-counted shard tail shows."""
+        from jaxmc.engine.explore import Explorer
         from jaxmc.tpu.mesh import MeshExplorer
-        assert MeshExplorer(load("constoy")).merge == "rank"
-        monkeypatch.setenv("JAXMC_MESH_RANKMERGE", "0")
-        me = MeshExplorer(load("constoy"))
-        assert me.merge == "fullsort"
-        # fullsort cannot run under the superstep while_loop: it is
-        # pinned to the one-level-per-dispatch program
-        assert me._ss_fixed == 1
+        ri = Explorer(load("constoy")).run()
+        rh, occ = _store_occupancy(load("constoy"))
+        me = MeshExplorer(load("constoy"), mesh=meshd(D))
+        r = me.run()
+        assert me.D == D
+        assert (r.generated, r.distinct, r.ok) == \
+            (ri.generated, ri.distinct, ri.ok) == \
+            (rh.generated, rh.distinct, rh.ok)
+        assert me._fp_occupancy == occ > r.distinct
 
-    def test_rank_vs_fullsort_counts_and_occupancy_d2(self,
-                                                      monkeypatch):
+    def test_violation_trace_against_interpreter(self):
+        """Reference: the interpreter.  On the default mesh (the 8
+        virtual devices the fullsort pairing this replaces ran on) the
+        resident mesh reports a counterexample of the same kind, the
+        same depth and the same action labels.  Its states are another
+        equally short behavior (frontier order differs across shards),
+        so they are not compared: every step must be a transition the
+        interpreter's Next gives that label."""
+        from jaxmc.engine.explore import Explorer
+        from jaxmc.sem.enumerate import (enumerate_init, enumerate_next,
+                                         label_str)
         from jaxmc.tpu.mesh import MeshExplorer
-        ma = MeshExplorer(load("constoy"), exchange="a2a")
-        ra = ma.run()
-        monkeypatch.setenv("JAXMC_MESH_RANKMERGE", "0")
-        mf = MeshExplorer(load("constoy"), exchange="a2a")
-        rf = mf.run()
-        assert (ra.generated, ra.distinct, ra.ok) == \
-            (rf.generated, rf.distinct, rf.ok)
-        # the PR-10 stale-tail fix: both strategies agree on the TRUE
-        # fingerprint occupancy (the PR-8 fullsort re-counted dup tail
-        # rows across levels)
-        assert ma._fp_occupancy == mf._fp_occupancy
-
-    def test_rank_vs_fullsort_violation_trace_d2(self, monkeypatch):
-        from jaxmc.tpu.mesh import MeshExplorer
-        ra = MeshExplorer(load("pcal_intro_buggy"),
-                          exchange="a2a").run()
-        monkeypatch.setenv("JAXMC_MESH_RANKMERGE", "0")
-        rf = MeshExplorer(load("pcal_intro_buggy"),
-                          exchange="a2a").run()
-        assert not ra.ok and not rf.ok
-        assert (ra.generated, ra.distinct, ra.violation.kind) == \
-            (rf.generated, rf.distinct, rf.violation.kind)
-        assert [s for s, _ in ra.violation.trace] == \
-            [s for s, _ in rf.violation.trace]
-        assert [a for _, a in ra.violation.trace] == \
-            [a for _, a in rf.violation.trace]
+        model = load("pcal_intro_buggy")
+        ri = Explorer(model).run()
+        r = MeshExplorer(load("pcal_intro_buggy"), exchange="a2a").run()
+        assert not r.ok and not ri.ok
+        assert r.violation.kind == ri.violation.kind == "assert"
+        trace = r.violation.trace
+        assert len(trace) == len(ri.violation.trace)
+        assert [a for _, a in trace] == \
+            [a for _, a in ri.violation.trace]
+        ctx = model.ctx()
+        assert trace[0][0] in enumerate_init(model.init, ctx,
+                                             model.vars)
+        for (st, _), (succ, lab) in zip(trace, trace[1:]):
+            steps = []
+            try:
+                for s2, lbl in enumerate_next(model.next, ctx,
+                                              model.vars, st):
+                    steps.append((s2, label_str(lbl)))
+            except Exception:
+                pass  # the assert may fire during full expansion
+            assert (succ, lab) in steps
 
     @pytest.mark.slow
-    def test_rank_vs_fullsort_view_symmetry_d4(self, monkeypatch):
-        # the VIEW and SYMMETRY rungs at D=4: the key basis (cfg VIEW
-        # lanes / orbit-canonical packing) must dedup identically
-        # under both merge strategies
+    def test_view_symmetry_occupancy_d4(self):
+        """References: the interpreter (counts) and the host_seen
+        store (occupancy) on the VIEW and SYMMETRY rungs at D=4: the
+        key basis (cfg VIEW lanes / orbit-canonical packing) must
+        dedup in the shards as it does in the store."""
+        from jaxmc.engine.explore import Explorer
         from jaxmc.tpu.mesh import MeshExplorer
         for name, kw in (("viewtoy", {}),
                          ("symtoy", dict(no_deadlock=True))):
-            monkeypatch.delenv("JAXMC_MESH_RANKMERGE", raising=False)
-            ra = MeshExplorer(load(name, **kw), mesh=mesh4(),
-                              exchange="a2a").run()
-            monkeypatch.setenv("JAXMC_MESH_RANKMERGE", "0")
-            rf = MeshExplorer(load(name, **kw), mesh=mesh4(),
-                              exchange="a2a").run()
-            assert (ra.generated, ra.distinct, ra.ok) == \
-                (rf.generated, rf.distinct, rf.ok), name
+            ri = Explorer(load(name, **kw)).run()
+            _, occ = _store_occupancy(load(name, **kw))
+            me = MeshExplorer(load(name, **kw), mesh=mesh4(),
+                              exchange="a2a")
+            r = me.run()
+            assert (r.generated, r.distinct, r.ok) == \
+                (ri.generated, ri.distinct, ri.ok), name
+            assert me._fp_occupancy == occ, name
 
     @pytest.mark.slow
-    def test_rank_vs_fullsort_under_skew_spill(self, monkeypatch):
-        # hash-skew (every state on shard 0) exercises the spill pass
-        # and the most imbalanced merge inputs — both strategies must
-        # stay exact
+    def test_under_skew_spill(self, monkeypatch):
+        """References: the interpreter (counts) and the host_seen
+        store (occupancy).  Hash skew (every state on shard 0) drives
+        the spill pass and the most imbalanced merge inputs."""
         from jaxmc import faults
+        from jaxmc.engine.explore import Explorer
         from jaxmc.tpu.mesh import MeshExplorer
-        monkeypatch.setenv("JAXMC_FAULTS", "mesh_skew:n=2")
+        ri = Explorer(load("constoy")).run()
+        _, occ = _store_occupancy(load("constoy"))
+        monkeypatch.setenv("JAXMC_FAULTS", "mesh_skew:n=1")
         faults.reset_for_tests()
-        ra = MeshExplorer(load("constoy"), exchange="a2a").run()
-        monkeypatch.setenv("JAXMC_MESH_RANKMERGE", "0")
-        rf = MeshExplorer(load("constoy"), exchange="a2a").run()
-        assert (ra.generated, ra.distinct, ra.ok) == \
-            (rf.generated, rf.distinct, rf.ok)
+        me = MeshExplorer(load("constoy"), exchange="a2a")
+        assert me._skew
+        r = me.run()
+        assert (r.generated, r.distinct, r.ok) == \
+            (ri.generated, ri.distinct, ri.ok)
+        assert me._fp_occupancy == occ
         faults.reset_for_tests()
 
 
@@ -540,9 +577,7 @@ class TestMeshbenchChild:
         assert r["supersteps"] == r["host_syncs"] <= r["levels"]
         assert r["host_syncs"] < r["levels"]
         assert r["exchange"] == "a2a"
-        assert r["merge"] == "rank"
         art = json.load(open(out))
         assert art["schema"] == "jaxmc.metrics/4"
         assert art["multichip"]["devices"] == 2
-        assert art["multichip"]["merge"] == "rank"
         assert art["multichip"]["supersteps"] == r["supersteps"]

@@ -217,10 +217,11 @@ class TestDiff:
 
 
 class TestPhaseWallsParsing:
-    """probe_phase_walls rows in multichip artifacts (ISSUE 11
-    satellite): missing-phase and malformed rows render instead of
-    crashing, and the hot-share acceptance metric surfaces when the
-    probe timed the fused step."""
+    """`phase_walls` rows in multichip artifacts (ISSUE 11 satellite;
+    since PR 28 only the committed MULTICHIP_r07/r08.json carry them —
+    the probe that wrote them is gone, the reader stays): missing-phase
+    and malformed rows render instead of crashing, and the hot-share
+    acceptance metric surfaces when the probe timed the fused step."""
 
     def art(self, tmp_path, name, pw):
         obj = {"schema": "jaxmc.multichip/1", "platform": "cpu",

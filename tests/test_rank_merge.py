@@ -12,7 +12,11 @@ searches the valid prefix of the sorted keys alone, a block of QB
 queries at a time, for bit_length(seen_count) rounds: the oracle calls
 `_seen_probe` without a live count (every block), so the cases here
 also hold the prefix form against the general one; a second lowering
-guard keeps whole-capacity gathers out of the probe."""
+guard keeps whole-capacity gathers out of the probe.
+
+Since ISSUE 28 `_rank_merge` is the engines' ONLY dedup merge; a third
+lowering guard keeps a sort over seen + candidates out of the level
+step and the mesh superstep."""
 
 import functools
 import re
@@ -449,3 +453,66 @@ def test_rank_merge_lowers_to_scalar_scatters_only(K, multikey):
     # the guard has teeth: the formulation it replaced fails it
     old = _scatters(_scatter_rank_merge, K, multikey)
     assert any("x" in u.split("xi32")[0] for _, _, u in old), old
+
+
+# ------------------------------------------- the engines' one dedup merge
+
+_SORT = re.compile(
+    r'"?stablehlo\.sort"?\(.*?\}\) : \(([^)]*)\) -> ', re.S)
+
+
+def _constoy():
+    import os
+    from jaxmc.front.cfg import parse_cfg
+    from jaxmc.sem.modules import Loader, bind_model
+    specs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "specs")
+    with open(os.path.join(specs, "constoy.cfg")) as fh:
+        cfg = parse_cfg(fh.read())
+    return bind_model(
+        Loader([specs]).load_path(os.path.join(specs, "constoy.tla")), cfg)
+
+
+def _lowered_level_step(SC, FC):
+    from jaxmc.backend.bfs import TpuExplorer
+    ex = TpuExplorer(_constoy())
+    i32 = jnp.int32
+    text = ex._get_step(SC, FC).__wrapped__.lower(
+        jnp.zeros((SC, ex.K), i32), i32(0),
+        jnp.zeros((FC, ex.PW), i32), i32(0)).as_text()
+    return text, ex.A * FC
+
+
+def _lowered_mesh_superstep(SC, FC):
+    from jax.sharding import Mesh
+    from jaxmc.backend.mesh import MeshExplorer
+    D, TRL, VC = 4, 16, 2 * FC
+    ex = MeshExplorer(_constoy(), exchange="a2a",
+                      mesh=Mesh(np.array(jax.devices()[:D]), ("d",)))
+    i32 = jnp.int32
+    text = ex._get_mesh_resident_step(SC, FC, TRL, VC).__wrapped__.lower(
+        jnp.zeros((D, SC, ex.K), i32), jnp.zeros((D,), i32),
+        jnp.zeros((D, FC, ex.PW), i32), jnp.zeros((D,), i32),
+        jnp.zeros((D, TRL, FC, ex.PW), i32), jnp.zeros((D, TRL, FC), i32),
+        i32(0), i32(0), i32(0), i32(0)).as_text()
+    return text, ex._route_fn(ex.A * FC, FC)[1]
+
+
+@pytest.mark.parametrize("lowered", [_lowered_level_step,
+                                     _lowered_mesh_superstep],
+                         ids=["level_step", "mesh_superstep"])
+def test_engine_steps_sort_no_seen_sized_block(lowered):
+    """The engines merge a level's candidates into the seen set through
+    `_rank_merge` and nothing else: no sort in the lowered step has
+    SC + C operands (level engine; SC + R on a mesh shard, R the
+    exchanged block), the signature of the full-sort merge PR 28
+    deleted — nor any other length that follows the seen capacity."""
+    SC, FC = 1 << 14, 64
+    text, block = lowered(SC, FC)
+    found = _SORT.findall(text)
+    assert found and len(found) == len(
+        re.findall(r'stablehlo\.sort"?\(', text))
+    lengths = {int(t.split("x")[0]) for m in found
+               for t in re.findall(r"tensor<([^>]*)>", m)}
+    assert block < SC and SC + block not in lengths, lengths
+    assert max(lengths) <= block, (lengths, block)
