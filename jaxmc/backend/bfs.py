@@ -371,12 +371,43 @@ def _por_mask_np(found, cvalid, inst_arm, arm_safe, A, FC):
     return keep, n_ample, n_expanded
 
 
-# Rows of the seen table that _rank_merge gathers from at a time.  On
-# the TPU v5e a jnp.take of 2^22 distinct rows from the whole [2^22, 5]
-# table (84 MB) took 110 ms, 26 ns a row; from [2^20, 5] windows of it
-# the same rows took 15 ms, 3.5 ns a row, and [2^21, 5] windows did no
-# better (my chip runs, PR 25, PERF.md §6).
-_MERGE_BLOCK_ROWS = 1 << 20
+# Rows of seen2 that _rank_merge builds at a time, and so the rows of
+# the seen table one block gathers from.  Only the blocks that hold a
+# live row are built (ISSUE 29), so a block has to be small against the
+# table; and on the TPU v5e the gathers are cheap only from a small
+# window: a jnp.take of 2^22 distinct rows from the whole [2^22, 5]
+# table (84 MB) took 26 ns a row, from [2^20, 5] windows of it 3.5 ns
+# (my chip runs, PR 25).  The merge's tail measured alone at the four
+# benchmark cells' shapes, a call a level with the pinned counts (ms a
+# search's worth; my chip runs, PR 29, PERF.md §6): all blocks of 2^20
+# rows 766 / 96 / 319 / 189 (the form up to PR 28); live blocks of 2^20
+# 265 / 252 / 119 / -, 2^18 337 / 89 / 50 / 185, 2^17 324 / 51 / 23 /
+# 119, 2^16 185 / 18.1 / 18.7 / 97, 2^15 181 / 15.6 / 16.9 / 91 —
+# not monotone (2^17 and 2^18 are slower than 2^19 at SC 2^22), so one
+# size that was best at every shape, not a share of SC.  The tests
+# lower it to cut toy tables into several blocks.
+_MERGE_BLOCK_ROWS = 1 << 15
+
+
+def _merge_block_rows(sc: int) -> int:
+    """B: the rows of one block of _rank_merge's seen2 build, a static
+    function of the table's capacity alone."""
+    return min(sc, _MERGE_BLOCK_ROWS)
+
+
+def _merge_blocks(seen_count2, sc: int):
+    """How many blocks of seen2 _rank_merge builds for a table of sc
+    slots that holds seen_count2 rows after the merge (the true need:
+    past sc every block is built): ceil(min(seen_count2, sc) / B).  The
+    ONE block rule: the kernel bounds its loop with it on a traced
+    count and the engines count `search.slots_merged` with it (Python
+    ints work too)."""
+    b = _merge_block_rows(sc)
+    blocks = (seen_count2 + (b - 1)) // b
+    most = -(-sc // b)
+    if isinstance(blocks, (int, np.integer)):
+        return min(int(blocks), most)
+    return jnp.minimum(blocks, most)
 
 
 @jax.named_scope("jaxmc.merge.scatter")
@@ -404,23 +435,37 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
     every benchmark cell.  On XLA:CPU, where this function was first
     shaped, the two cost about the same.
 
+    The tail follows the rows that are live, as the probe does (ISSUE
+    29): seen2 is the incoming table with its first
+    _merge_blocks(seen_count2, SC) blocks of B = _merge_block_rows(SC)
+    rows rebuilt, in place and from the last such block down (a
+    backward memmove with insertions: a block reads seen rows at or
+    below the rows it writes, and later turns read only below it), and
+    the two index scatters push only the first _probe_blocks(n_live, N)
+    blocks of sorted rows.  Every row past seen_count2 is left as it
+    came in.
+
     seen [SC, K] (validity lane first, prefix sorted by the K-1 data
-    words), seen_count traced scalar, keys [N, K] unsorted candidate
-    keys (invalid rows: lane 0 != 0, SENTINEL data — they sort last).
+    words; every row from seen_count on INVALID, lane != 0), seen_count
+    traced scalar, keys [N, K] unsorted candidate keys (invalid rows:
+    lane 0 != 0, SENTINEL data — they sort last).
 
     Returns dict:
       new_count  how many sorted candidate keys are genuinely new
       nk_sidx    [N] each compacted new key's ORIGINAL row index in
                  `keys` (key-sorted order; ties keep first occurrence)
       seen2      [SC, K] merged table — sorted valid prefix of length
-                 seen_count + new_count, invalid tail (lane 1,
-                 SENTINEL data).  Positions past SC are DROPPED: the
+                 seen_count + new_count, invalid tail (lane 1, SENTINEL
+                 data in the blocks built; the incoming rows past
+                 them).  Positions past SC are DROPPED: the
                  caller must treat seen_count2 > SC as an overflow and
                  roll the level back (seen_count2 still reports the
                  TRUE need, so growth can jump straight to it).
       seen_count2  seen_count + new_count (NOT cropped to SC).
       probe_blocks  query blocks the probe searched (× _probe_block_rows(N)
                  = the slots behind `search.slots_probed`).
+      merge_blocks  blocks of seen2 built (× _merge_block_rows(SC) = the
+                 slots behind `search.slots_merged`).
 
     multikey=True sorts the candidate keys with ONE stable multi-key
     lax.sort instead of the LSD chain (the level and mesh engines use
@@ -456,18 +501,12 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
                             sorted_keys=True)
     new = svalid & ~found & neq_prev
     new_count = jnp.sum(new, dtype=jnp.int32)
+    seen_count2 = seen_count + new_count
 
     # the j-th new key (stable: key order kept) is the sorted row
     # whose cumsum rank is j.  Only the original indices are wanted
     # compacted, and a scalar scatter through the ranks does that.
-    # Dropped rows get DISTINCT out-of-range indices (N + sidx), here
-    # and below: unique_indices=True is a correctness promise to XLA
-    # (advisor r2 rule).
-    npos = jnp.cumsum(new.astype(jnp.int32)) - 1
-    nk_sidx = jnp.zeros((N,), jnp.int32) \
-        .at[jnp.where(new, npos, N + sidx)] \
-        .set(sidx_s, mode="drop", unique_indices=True)
-
+    #
     # rank merge into seen2: pos(new j) = lb_j + j, strictly
     # increasing, and the seen rows keep their order in the positions
     # the new keys leave free — a bijection since new keys are
@@ -479,44 +518,80 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
     # New keys whose position is >= SC park past the table and fall
     # off, as do the seen rows they would have pushed past it.
     #
-    # The seen rows of B consecutive output rows lie within B rows of
-    # the table, so seen2 is built B rows at a time, each block
-    # gathering from its own window (_MERGE_BLOCK_ROWS says why).  P
-    # rounds SC up to whole blocks; the engines' capacities are powers
-    # of two and P == SC.
-    B = min(SC, _MERGE_BLOCK_ROWS)
+    # Only a valid row can be new, and the valid rows sorted first: the
+    # two scatters take the blocks of QB sorted rows the probe searched
+    # and no other, QB indices a turn into the carried vectors (a
+    # scalar scatter cost ~5 ns an index over all N slots, 76-93 % of
+    # them padding).  Dropped rows get DISTINCT out-of-range indices
+    # (N + sidx, P + sidx): unique_indices=True is a correctness
+    # promise to XLA (advisor r2 rule).  The last block starts at
+    # N - QB where QB does not divide N and writes its neighbour's rows
+    # again: the same values.  P rounds SC up to whole blocks of B; the
+    # engines' capacities are powers of two and P == SC.
+    B = _merge_block_rows(SC)
     P = -(-SC // B) * B
+    QB = _probe_block_rows(N)
+    npos = jnp.cumsum(new.astype(jnp.int32)) - 1
     pos_n = lb + npos
-    src = jnp.full((P,), -1, jnp.int32) \
-        .at[jnp.where(new & (pos_n < SC), pos_n, P + sidx)] \
-        .set(sidx, mode="drop", unique_indices=True)
+    nk_tgt = jnp.where(new, npos, N + sidx)
+    src_tgt = jnp.where(new & (pos_n < SC), pos_n, P + sidx)
+
+    def index_block(b, out):
+        nk_sidx, src = out
+        at = jnp.minimum(b * QB, N - QB)
+        rows = at + jnp.arange(QB, dtype=jnp.int32)
+        nk_sidx = nk_sidx.at[lax.dynamic_slice(nk_tgt, (at,), (QB,))] \
+            .set(lax.dynamic_slice(sidx_s, (at,), (QB,)), mode="drop",
+                 unique_indices=True)
+        src = src.at[lax.dynamic_slice(src_tgt, (at,), (QB,))] \
+            .set(rows, mode="drop", unique_indices=True)
+        return nk_sidx, src
+
+    # constants of the operands' TYPE (n_live - n_live), as
+    # _seen_probe's lb0: the carries leave the loops device-varying
+    # under shard_map
+    zero = n_live - n_live
+    nk_sidx, src = lax.fori_loop(
+        0, _probe_blocks(n_live, N), index_block,
+        (jnp.zeros((N,), jnp.int32) + zero,
+         jnp.full((P,), -1, jnp.int32) + zero))
     c = jnp.cumsum((src >= 0).astype(jnp.int32))
 
     tail = jnp.concatenate([jnp.ones((1, 1), jnp.int32),
                             jnp.full((1, K - 1), SENTINEL, jnp.int32)],
                            axis=1)
+    merge_blocks = _merge_blocks(seen_count2, SC)
 
-    def block(p0):
+    # The seen rows of B consecutive output rows lie within B rows of
+    # the table, so a block gathers from its own window of it
+    # (_MERGE_BLOCK_ROWS says why) — the window of the table AS CARRIED:
+    # block p0 needs seen rows [p0 - c(p0), p0 + B), the turns before
+    # it wrote [p0 + B, ...) and the turns after it read below p0 + B
+    # only, so the build needs no second table.
+    def block(i, table):
+        p0 = (merge_blocks - 1 - i) * B
         src_b = lax.dynamic_slice(src, (p0,), (B,))
         is_new = src_b >= 0
         src_s = p0 + jnp.arange(B, dtype=jnp.int32) \
             - lax.dynamic_slice(c, (p0,), (B,))
-        # the first seen row the block can need, and the window from
-        # it (the table's last B rows where that would run past SC)
-        at = jnp.minimum(src_s[0] + is_new[0], SC - B)
-        window = lax.dynamic_slice(seen, (at, 0), (B, K))
+        # the first seen row the block can need: in [0, p0]
+        at = src_s[0] + is_new[0]
+        window = lax.dynamic_slice(table, (at, 0), (B, K))
         from_seen = jnp.take(window, jnp.clip(src_s - at, 0, B - 1),
                              axis=0)
         from_new = jnp.take(skeys, jnp.clip(src_b, 0, N - 1), axis=0)
         is_seen = (src_s < seen_count)[:, None]
-        return jnp.where(is_new[:, None], from_new,
+        rows = jnp.where(is_new[:, None], from_new,
                          jnp.where(is_seen, from_seen, tail))
+        return lax.dynamic_update_slice(table, rows, (p0, 0))
 
-    seen2 = lax.map(block, jnp.arange(0, P, B, dtype=jnp.int32)) \
-        .reshape(P, K)[:SC]
+    if P != SC:
+        seen = jnp.concatenate([seen, jnp.broadcast_to(tail, (P - SC, K))])
+    seen2 = lax.fori_loop(0, merge_blocks, block, seen)[:SC]
     return dict(new_count=new_count, nk_sidx=nk_sidx, seen2=seen2,
-                seen_count2=seen_count + new_count,
-                probe_blocks=_probe_blocks(n_live, N))
+                seen_count2=seen_count2,
+                probe_blocks=_probe_blocks(n_live, N),
+                merge_blocks=merge_blocks)
 
 
 class _LiveGraph:
@@ -1936,10 +2011,13 @@ class TpuExplorer:
             # follows gen, not A x FC) and the new keys merged in by
             # rank — rows fetched by gather, not scattered (a row
             # scatter cost 9-27x a row gather on the v5e; ledger, PR
-            # 24).  nk_sidx is each new key's original candidate index
-            # in key-sorted order (stable ties keep the first
-            # occurrence).  The caller pre-grows SC so seen_count + C
-            # <= SC: seen_count2 never overflows.
+            # 24), into the blocks of the DONATED table that hold a
+            # live row after the level and no other (in place: the
+            # work follows seen_count2, not SC).  nk_sidx is each new
+            # key's original candidate index in key-sorted order
+            # (stable ties keep the first occurrence).  The caller
+            # pre-grows SC so seen_count + C <= SC: seen_count2 never
+            # overflows.
             rm = _rank_merge(seen_keys, seen_count, ckeys, C, SC, K,
                              multikey=True)
             new_count = rm["new_count"]
@@ -2558,11 +2636,12 @@ class TpuExplorer:
             # seen-set is never re-sorted — new keys merge by rank
             # (vectorized binary searches over the blocks of AccCap/64
             # sorted keys that hold a valid row, rounds from seen_count
-            # and from the sampled neighbours; then every row of seen2
+            # and from the sampled neighbours; then the rows of seen2
             # fetched by gather through an inverse index: the row
             # scatters this replaced were 68-77 % of this engine's
             # device time on the v5e; ledger, PR 24), so the sort work
-            # is O(new), not O(seen), per level.
+            # is O(new), not O(seen), per level; only the blocks of the
+            # table that hold a live row after the level are built.
             rm = _rank_merge(seen, seen_count, acc_keys, AccCap, SC, K)
             with jax.named_scope("jaxmc.compact"):
                 new_count = rm["new_count"]
@@ -2622,29 +2701,35 @@ class TpuExplorer:
 
             return (seen2, seen_count2, front_rows, explore_count, gen,
                     explore_count, stat, inv_bad_which, bad_row, ovcode,
-                    pora, porx, porm, rm["probe_blocks"])
+                    pora, porx, porm, rm["probe_blocks"],
+                    rm["merge_blocks"])
 
         def run(seen, seen_count, frontier, fcount, distinct,
                 gen_lo, gen_hi, depth, max_states, maxlvl):
             def cond(carry):
                 (_, _, _, _, _, _, _, _, lvls, stat, _, _, _,
-                 _, _, _, _) = carry
+                 _, _, _, _, _) = carry
                 return (stat == ST_CONTINUE) & (lvls < maxlvl)
 
             def body(carry):
                 (seen, seen_count, frontier, fcount, distinct,
                  gen_lo, gen_hi, depth, lvls, stat, which, brow,
-                 ovcode, pora, porx, porm, pblocks) = carry
+                 ovcode, pora, porx, porm, pblocks, mblocks) = carry
                 (seen2, seen_count2, front2, fcount2, gen_l, kept,
                  lstat, lwhich, lbrow, lovcode, lpora, lporx,
-                 lporm, lpblocks) = level(seen, seen_count, frontier,
-                                          fcount)
+                 lporm, lpblocks, lmblocks) = level(seen, seen_count,
+                                                    frontier, fcount)
                 ovf = (lstat == ST_OVF_SEEN) | (lstat == ST_OVF_FRONT) | \
                     (lstat == ST_OVF_ACC) | (lstat == ST_OVF_VC) | \
                     (lstat == ST_OVF_LANES)
                 # overflow rolls the whole level back (growable caps are
                 # redone after growth; lane overflow aborts with the
-                # last completed level's exact counts)
+                # last completed level's exact counts).  The select
+                # over the table also holds the loop's carry to the
+                # layout the table arrives in (PERF.md §6, PR 29:
+                # without it XLA:TPU keeps a row-major copy padded to
+                # 128 lanes, 537 MB for a 21 MB table, and relayouts it
+                # every level)
                 seen2 = jnp.where(ovf, seen, seen2)
                 seen_count2 = jnp.where(ovf, seen_count, seen_count2)
                 front2 = jnp.where(ovf, frontier, front2)
@@ -2680,26 +2765,29 @@ class TpuExplorer:
                         jnp.where(lstat == ST_OVF_LANES, lovcode,
                                   ovcode), pora2, porx2, porm2,
                         # work done, not work kept: a rolled-back level
-                        # searched its blocks too (as slots_sorted)
-                        pblocks + lpblocks)
+                        # searched and built its blocks too (as
+                        # slots_sorted)
+                        pblocks + lpblocks, mblocks + lmblocks)
 
             carry0 = (seen, seen_count, frontier, fcount, distinct,
                       gen_lo, gen_hi, depth, jnp.int32(0),
                       jnp.int32(ST_CONTINUE), jnp.int32(-1),
                       jnp.full((PW,), SENTINEL, jnp.int32),
                       jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                      jnp.int32(0), jnp.int32(0))
+                      jnp.int32(0), jnp.int32(0), jnp.int32(0))
             (seen, seen_count, frontier, fcount, distinct, gen_lo,
              gen_hi, depth, _, stat, which, brow, ovcode, pora, porx,
-             porm, pblocks) = \
+             porm, pblocks, mblocks) = \
                 lax.while_loop(cond, body, carry0)
             # indices 0-8 are the PR-6 summary; 9-11 are the per-
             # dispatch POR counters (ISSUE 18; zero when POR is off);
             # 12 is the query blocks the merge's probe searched over
-            # the dispatch's levels (ISSUE 27: search.slots_probed)
+            # the dispatch's levels (ISSUE 27: search.slots_probed), 13
+            # the blocks of seen2 it built (ISSUE 29:
+            # search.slots_merged)
             summary = jnp.stack([stat, seen_count, fcount, distinct,
                                  gen_lo, gen_hi, depth, which, ovcode,
-                                 pora, porx, porm, pblocks])
+                                 pora, porx, porm, pblocks, mblocks])
             return seen, frontier, summary, brow
 
         # DONATED dispatch (ISSUE 6): the seen table (arg 0) and the
@@ -3301,6 +3389,7 @@ class TpuExplorer:
                 self._por_stats["expanded"] += int(summary[10])
                 self._por_stats["masked"] += int(summary[11])
                 probe_blocks = int(summary[12])
+                merge_blocks = int(summary[13])
                 # cold-tier filter (ISSUE 12): after a spill the device
                 # table restarted empty, so a committed level's frontier
                 # may hold rows whose keys live in the host/disk runs —
@@ -3355,6 +3444,10 @@ class TpuExplorer:
             tel.counter("search.slots_probed", probe_blocks
                         * _probe_block_rows(caps["AccCap"]))
             tel.counter("search.seen_slots", lvls * caps["SC"])
+            # ... and built only the blocks of the table that held a
+            # live row after each level: counted in the carry as well
+            tel.counter("search.slots_merged", merge_blocks
+                        * _merge_block_rows(caps["SC"]))
             tel.counter("search.rows_new", distinct - dist_in)
             self._fp_occupancy = seen_count
 
@@ -4479,6 +4572,11 @@ class TpuExplorer:
             tel.counter("search.slots_probed",
                         _probe_blocks(gen_l, C) * _probe_block_rows(C))
             tel.counter("search.seen_slots", SC)
+            # ... of which the merge built the blocks that hold a live
+            # row after the level (the kernel's own block rule again)
+            tel.counter("search.slots_merged",
+                        _merge_blocks(seen_count, SC)
+                        * _merge_block_rows(SC))
             tel.counter("search.rows_new", kept_count)
             self._fp_occupancy = seen_count
 

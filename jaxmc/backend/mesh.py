@@ -90,9 +90,9 @@ from ..sem.modules import Model
 from ..engine.explore import CheckResult, Violation
 from ..compile.vspec import ModeError
 from ..compile.kernel2 import OV_DEMOTED, OV_PACK
-from .bfs import (SENTINEL, TpuExplorer, _LiveGraph, _pow2_at_least,
-                  _por_mask, _probe_block_rows, _rank_merge,
-                  _seen_probe)
+from .bfs import (SENTINEL, TpuExplorer, _LiveGraph, _merge_block_rows,
+                  _por_mask, _pow2_at_least, _probe_block_rows,
+                  _rank_merge, _seen_probe)
 
 _BIG = np.int32(2 ** 31 - 1)
 
@@ -141,7 +141,8 @@ _S_PORA = 18      # psum POR singleton-ample states this level (ISSUE 18)
 _S_PORX = 19      # psum POR expanded (any-arm-enabled) states this level
 _S_PORM = 20      # psum POR-masked candidate rows this level
 _S_PROBED = 21    # psum query blocks the shards' rank merges searched
-_NS = 22
+_S_MERGED = 22    # psum blocks of seen2 the shards' rank merges built
+_NS = 23
 
 # per-device violation-localization vector (fetched only on violation)
 _A_INVW = 0
@@ -618,7 +619,8 @@ class MeshExplorer(TpuExplorer):
         """The shard-local merge-dedup of every step builder:
         (seen_keys [SC,K], seen_count scalar, gkeys [R,K], gcand [R,PW],
         gsrc [R]) -> dict(seen2, seen_count2, front_rows, front_rows_u,
-        front_src, front_count, new_count, v_ovf, v_need, probe_blocks).
+        front_src, front_count, new_count, v_ovf, v_need, probe_blocks,
+        merge_blocks).
 
         O(new) and O(valid): the exchanged block is ~95% masked padding
         (its 5-key sort over all R rows was 11.6s of a 25s step wall on
@@ -627,7 +629,9 @@ class MeshExplorer(TpuExplorer):
         stable, so candidate order and therefore counts/traces are
         unchanged), then only those keys are sorted, deduped against
         the seen shard's sorted valid prefix with binary searches and
-        merged in by rank (row gathers) — the single-chip resident
+        merged in by rank (row gathers into the blocks of the shard's
+        table that hold a live row after the level: each shard bounds
+        its own loops with its own counts) — the single-chip resident
         engine's merge (bfs._rank_merge), shared rather than
         duplicated; single-key-safe ops only, so the superstep
         while_loop can wrap it.  seen_count2 is the TRUE per-shard need
@@ -692,7 +696,8 @@ class MeshExplorer(TpuExplorer):
                         front_rows=front_rows, front_rows_u=front_rows_u,
                         front_src=front_src, front_count=front_count,
                         new_count=new_count, v_ovf=v_ovf, v_need=v_need,
-                        probe_blocks=rm["probe_blocks"])
+                        probe_blocks=rm["probe_blocks"],
+                        merge_blocks=rm["merge_blocks"])
 
         return merge
 
@@ -956,6 +961,8 @@ class MeshExplorer(TpuExplorer):
                 commit = ~grow
 
             # ---- commit or roll back the device state ----
+            # (the one table-sized pass a level outside the merge's
+            # bounded loops: the roll-back keeps the old table)
             seen_out = jnp.where(commit, mg["seen2"], seen_keys)
             seen_count_out = jnp.where(commit, seen_count2,
                                        seen_count)
@@ -1019,6 +1026,8 @@ class MeshExplorer(TpuExplorer):
                 scal = scal.at[_S_PORM].set(lax.psum(porm, "d"))
                 scal = scal.at[_S_PROBED].set(
                     lax.psum(mg["probe_blocks"], "d"))
+                scal = scal.at[_S_MERGED].set(
+                    lax.psum(mg["merge_blocks"], "d"))
 
                 # per-device localization vector (fetched only on
                 # violation — always the LAST executed level's, because
@@ -1884,6 +1893,11 @@ class MeshExplorer(TpuExplorer):
             tel.counter("search.slots_probed",
                         int(ring[:nlv, _S_PROBED].sum())
                         * _probe_block_rows(n_keys))
+            # ... and built only the blocks of each shard's table that
+            # held a live row after the level
+            tel.counter("search.slots_merged",
+                        int(ring[:nlv, _S_MERGED].sum())
+                        * _merge_block_rows(SC))
             self._supersteps += 1
             self._superstep_levels_max = max(self._superstep_levels_max,
                                              nlv)

@@ -192,6 +192,82 @@ def test_slots_probed_follows_each_shards_live_keys(tmp_path, monkeypatch):
     assert answers[0][:2] == (256, 166)
 
 
+def test_slots_merged_follows_each_shards_live_rows(tmp_path, monkeypatch):
+    """The merge's build is bounded per shard as the probe is: a shard
+    rebuilds the blocks of ITS table that hold a live row after the
+    level.  Under the mesh_skew fault shard 0 holds every row, so the
+    counter is the one-chip sum over the levels of ceil(seen / B) x B;
+    evenly hashed, four shards share the rows and at most three more
+    blocks a level are begun."""
+    from jaxmc import faults
+    from jaxmc.backend import bfs
+    monkeypatch.setattr(bfs, "_MERGE_BLOCK_ROWS", 16)
+    cfg = tmp_path / "t.cfg"
+    cfg.write_text(_cfg_text(2, 3, 4))
+    caps = {"SC": 1 << 10, "FC": 256, "TRL": 16, "GAM16": 32, "MSL": 16,
+            "VC": 256}
+    B = bfs._merge_block_rows(caps["SC"])
+    assert B == 16
+    try:
+        for skew in (False, True):
+            if skew:
+                monkeypatch.setenv("JAXMC_FAULTS", "mesh_skew")
+                faults.reset_for_tests()
+            tel = obs.Telemetry()
+            with obs.use(tel):
+                sess = _session(TRANSFER, str(cfg), tel, devices=4,
+                                res_caps=caps)
+                assert _answer(sess.explore())[:2] == (256, 166)
+            seen = [lv["seen"] for lv in tel.levels]
+            assert len(seen) == 7 and seen[-1] == 166
+            merged = tel.counters["search.slots_merged"]
+            least = sum(-(-n // B) for n in seen) * B
+            assert merged % B == 0
+            if skew:
+                assert merged == least
+            else:
+                assert least <= merged <= least + 3 * B * len(seen)
+            assert merged < tel.counters["search.seen_slots"] \
+                == 7 * 4 * caps["SC"]
+    finally:
+        faults.reset_for_tests()
+
+
+def test_slots_merged_of_the_pinned_model_on_four_shards(monkeypatch):
+    """The 3-process model the benchmark pins (`bench/pins`), on four
+    virtual devices at capacities that hold it without regrowth: after
+    each level the shards together hold what the pins' levels say, and
+    `search.slots_merged` is, shard by shard, the blocks that hold those
+    rows — between the one-chip count and three more blocks a level."""
+    import json
+    from jaxmc.backend import bfs
+    with open(os.path.join(REPO, "bench", "pins",
+                           "transfer_scaled.json")) as fh:
+        pins = json.load(fh)
+    new = [lv[2] for lv in pins["levels"]]
+    have = pins["distinct"] - sum(new)
+    seen2 = [have := have + n for n in new]
+    monkeypatch.setattr(bfs, "_MERGE_BLOCK_ROWS", 1 << 12)
+    caps = {"SC": 1 << 16, "FC": 1 << 14, "TRL": 16, "GAM16": 32,
+            "MSL": 16, "VC": 1 << 15}
+    B = bfs._merge_block_rows(caps["SC"])
+    assert B == 1 << 12
+    tel = obs.Telemetry()
+    with obs.use(tel):
+        sess = _session(os.path.join(SPECS, "transfer_scaled.tla"),
+                        os.path.join(SPECS, "transfer_scaled.cfg"), tel,
+                        devices=4, res_caps=caps)
+        res = sess.explore()
+    assert (res.generated, res.distinct) == (pins["generated"],
+                                             pins["distinct"])
+    assert [lv["seen"] for lv in tel.levels] == seen2
+    c = tel.counters
+    assert c["search.seen_slots"] == len(seen2) * 4 * caps["SC"]
+    least = sum(-(-n // B) for n in seen2) * B
+    assert least <= c["search.slots_merged"] <= least + 3 * B * len(seen2)
+    assert c["search.slots_merged"] % B == 0
+
+
 # ------------------------------------------------ a violation's trace
 
 def test_violating_cfg_gives_the_level_engines_trace():

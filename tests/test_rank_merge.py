@@ -16,7 +16,15 @@ guard keeps whole-capacity gathers out of the probe.
 
 Since ISSUE 28 `_rank_merge` is the engines' ONLY dedup merge; a third
 lowering guard keeps a sort over seen + candidates out of the level
-step and the mesh superstep."""
+step and the mesh superstep.
+
+Since ISSUE 29 the merge's tail follows what is live too: seen2 is the
+incoming table with the blocks that hold a live row rebuilt in place
+(a loop with a traced trip count), the index scatters push the valid
+blocks of sorted rows alone; the cases below land seen_count2 on every
+kind of block border over consecutive merges and hold the rows past
+the built blocks to what came in, and a fourth lowering guard keeps a
+whole-table gather out of the three engines' programs."""
 
 import functools
 import re
@@ -30,8 +38,8 @@ from jax import lax  # noqa: E402
 
 from jaxmc.backend import bfs  # noqa: E402
 from jaxmc.backend.bfs import (  # noqa: E402
-    SENTINEL, _lsd_sort, _probe_block_rows, _probe_blocks, _rank_merge,
-    _seen_probe)
+    SENTINEL, _lsd_sort, _merge_block_rows, _merge_blocks,
+    _probe_block_rows, _probe_blocks, _rank_merge, _seen_probe)
 
 
 def _scatter_rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
@@ -85,7 +93,8 @@ def _scatter_rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
                 seen_count2=seen_count + new_count)
 
 
-# what both references answer (the oracle predates `probe_blocks`)
+# what both references answer (the oracle predates `probe_blocks` and
+# `merge_blocks`)
 ANSWER = ("new_count", "nk_sidx", "seen2", "seen_count2")
 
 
@@ -186,7 +195,7 @@ def _case(scenario, K, rng):
 
 
 # rows of seen2 built at a time: the whole table (the engines' case up
-# to SC 2^20), four even blocks, three blocks that overhang SC
+# to SC 2^15), four even blocks, three blocks that overhang SC
 BLOCKS = {"one_block": bfs._MERGE_BLOCK_ROWS, "even_blocks": 16,
           "uneven_blocks": 24}
 # a partial per block size: jit's cache is keyed on the function, and
@@ -217,6 +226,9 @@ def test_rank_merge_equals_oracle_and_set_union(K, multikey, scenario,
         ref = _np_reference(seen, n_seen, keys, SC, K)
         n_valid = int((keys[:, 0] == 0).sum())
         assert int(got["probe_blocks"]) == -(-n_valid // PROBE_MIN)
+        B = min(SC, BLOCKS[blocks])
+        assert int(got["merge_blocks"]) == min(
+            -(-int(got["seen_count2"]) // B), -(-SC // B))
         for name in ANSWER:
             g = np.asarray(got[name])
             assert g.dtype == np.asarray(want[name]).dtype, name
@@ -238,6 +250,168 @@ def test_rank_merge_equals_oracle_and_set_union(K, multikey, scenario,
             assert np.array_equal(s2, seen)
         if scenario == "all_equal":
             assert int(got["new_count"]) <= 1
+
+
+# ---- the tail sized by what is live (ISSUE 29) ----
+#
+# seen2 is built MB = 16 rows at a time (SC = 64: four blocks), only the
+# blocks that hold a live row after the merge.  (seen rows before the
+# first merge, new keys of three consecutive merges): seen_count2 lands
+# at 0, inside a block, on a block's edge, at SC, past SC
+MB = 16
+LANDINGS = {"zero": (0, (0, 0, 0)),
+            "mid_block": (5, (7, 4, 7)),        # 12, 16, 23
+            "block_edge": (0, (16, 10, 6)),     # 16, 26, 32
+            "at_SC": (30, (20, 0, 14)),         # 50, 50, 64
+            "past_SC": (40, (10, 10, 20))}      # 50, 60, 80: 16 dropped
+
+
+@pytest.fixture
+def _toy_merge_blocks(monkeypatch):
+    monkeypatch.setattr(bfs, "_MERGE_BLOCK_ROWS", MB)
+    assert _merge_block_rows(SC) == MB
+
+
+def _levels(landing, K, rng):
+    """(table, seen count, keys of three merges).  The table's tail is
+    what the engines seed and grow it with: SENTINEL in EVERY lane, the
+    validity lane too — a block the merge builds writes lane 1 there, a
+    block it skips leaves the row as it came."""
+    n0, news = LANDINGS[landing]
+    uni = _lexsorted(rng.integers(-99, 100, size=(8 * SC, K - 1))
+                     .astype(np.int32))
+    uni = uni[rng.permutation(len(uni))]
+    table = np.full((SC, K), SENTINEL, np.int32)
+    table[:n0, 0] = 0
+    table[:n0, 1:] = _lexsorted(uni[:n0])
+    have, levels = n0, []
+    for nw in news:
+        pool = uni[:have + nw]
+        n_dup = int(rng.integers(0, N - nw - 4)) if len(pool) else 0
+        words = np.concatenate(
+            [uni[have:have + nw],
+             pool[rng.integers(0, max(len(pool), 1), n_dup)]])
+        slots = rng.permutation(N)[:len(words)]
+        valid = np.zeros(N, bool)
+        valid[slots] = True
+        kwords = np.zeros((N, K - 1), np.int32)
+        kwords[slots] = words
+        levels.append(_keys(kwords, valid, K))
+        have += nw
+    return table, n0, levels
+
+
+def _np_live_merge(table, count, keys, K):
+    """What _rank_merge answers for a table whose tail is not (1,
+    SENTINEL): the set union in the blocks built, the incoming rows
+    past them."""
+    ref = _np_reference(table, count, keys, SC, K)
+    blocks = -(-min(ref["seen_count2"], SC) // MB)
+    seen2 = table.copy()
+    seen2[:blocks * MB] = ref["seen2"][:blocks * MB]
+    return dict(ref, seen2=seen2, merge_blocks=blocks)
+
+
+def _check_level(got, table, count, keys, K, tag):
+    want = _np_live_merge(table, count, keys, K)
+    for name in ANSWER + ("merge_blocks",):
+        assert np.array_equal(np.asarray(got[name]), want[name]), (name, tag)
+    assert want["merge_blocks"] == _merge_blocks(want["seen_count2"], SC) \
+        == int(_merge_blocks(jnp.int32(want["seen_count2"]), SC))
+    return want
+
+
+_MERGE = jax.jit(_rank_merge, static_argnums=(3, 4, 5, 6))
+
+
+@pytest.mark.parametrize("landing", LANDINGS)
+@pytest.mark.parametrize("multikey", [False, True])
+@pytest.mark.parametrize("K", [3, 5])
+def test_merge_builds_the_live_blocks_over_three_levels(
+        K, multikey, landing, _toy_merge_blocks):
+    for trial in range(3):
+        rng = np.random.default_rng(
+            [K, int(multikey), list(LANDINGS).index(landing), trial, 29])
+        table, count, levels = _levels(landing, K, rng)
+        for lvl, keys in enumerate(levels):
+            got = _MERGE(jnp.asarray(table), jnp.int32(count),
+                         jnp.asarray(keys), N, SC, K, multikey)
+            want = _check_level(got, table, count, keys, K, (trial, lvl))
+            table, count = want["seen2"], want["seen_count2"]
+        assert count == LANDINGS[landing][0] + sum(LANDINGS[landing][1])
+        # rows past the last block built are the seed's, lane and all
+        built = want["merge_blocks"] * MB
+        assert np.all(table[built:] == SENTINEL)
+        assert np.all(table[min(count, SC):built, 0] == 1)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _three_levels_in_a_loop(table, count, keys3, K, multikey):
+    """The resident engine's form: the merges of consecutive levels
+    inside one lax.while_loop, table and count in its carry."""
+    def body(carry):
+        lvl, table, count, blocks = carry
+        rm = _rank_merge(table, count, keys3[lvl], N, SC, K, multikey)
+        return (lvl + 1, rm["seen2"], rm["seen_count2"],
+                blocks.at[lvl].set(rm["merge_blocks"]))
+
+    return lax.while_loop(lambda c: c[0] < 3, body,
+                          (jnp.int32(0), table, count,
+                           jnp.zeros((3,), jnp.int32)))[1:]
+
+
+@pytest.mark.parametrize("landing", LANDINGS)
+def test_merge_inside_a_while_loop(landing, _toy_merge_blocks):
+    K = 5
+    rng = np.random.default_rng([list(LANDINGS).index(landing), 290])
+    table, count, levels = _levels(landing, K, rng)
+    seen2, count2, blocks = _three_levels_in_a_loop(
+        jnp.asarray(table), jnp.int32(count), jnp.asarray(np.stack(levels)),
+        K, False)
+    want_blocks = []
+    for keys in levels:
+        want = _np_live_merge(table, count, keys, K)
+        table, count = want["seen2"], want["seen_count2"]
+        want_blocks.append(want["merge_blocks"])
+    assert np.array_equal(np.asarray(seen2), table)
+    assert int(count2) == count
+    assert list(np.asarray(blocks)) == want_blocks
+
+
+@pytest.mark.parametrize("shift", [0, 1])
+def test_merge_under_shard_map(shift, _toy_merge_blocks):
+    """A mesh shard's form: four shards, each its own table, counts and
+    landing, so each bounds its loops with its own traced counts (the
+    loops' carries must be device-varying: fori_loop refuses others)."""
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+    D, K = 4, 5
+    if len(jax.devices()) < D:
+        pytest.skip("needs four (virtual) devices")
+    mesh = Mesh(np.array(jax.devices()[:D]), ("d",))
+
+    def shard(table, count, keys):
+        rm = _rank_merge(table[0], count[0], keys[0], N, SC, K, True)
+        return tuple(rm[name][None] for name in ANSWER + ("merge_blocks",))
+
+    step = jax.jit(shard_map(shard, mesh=mesh, in_specs=(P("d"),) * 3,
+                             out_specs=(P("d"),) * 5))
+    names = [list(LANDINGS)[(d + shift) % len(LANDINGS)] for d in range(D)]
+    cases = [_levels(nm, K, np.random.default_rng([d, shift, 2900]))
+             for d, nm in enumerate(names)]
+    tables = [c[0] for c in cases]
+    counts = [c[1] for c in cases]
+    for lvl in range(3):
+        keys = [c[2][lvl] for c in cases]
+        got = step(jnp.asarray(np.stack(tables)),
+                   jnp.asarray(counts, jnp.int32),
+                   jnp.asarray(np.stack(keys)))
+        for d in range(D):
+            got_d = {name: np.asarray(g)[d]
+                     for name, g in zip(ANSWER + ("merge_blocks",), got)}
+            want = _check_level(got_d, tables[d], counts[d], keys[d], K,
+                                (names[d], lvl))
+            tables[d], counts[d] = want["seen2"], want["seen_count2"]
 
 
 # ---- the probe sized by what is live (ISSUE 27) ----
@@ -446,13 +620,36 @@ def test_rank_merge_lowers_to_scalar_scatters_only(K, multikey):
     found = _scatters(_rank_merge, K, multikey)
     assert 1 <= len(found) <= 2, found
     for operand, indices, updates in found:
-        # N indices, one i32 each: no [SC, K] or [N, K-1] row updates
-        assert indices == f"{N}x1xi32", found
-        assert updates == f"{N}xi32", found
+        # one block of QB sorted rows a turn (ISSUE 29), one i32 each:
+        # no [SC, K] or [N, K-1] row updates
+        assert indices == f"{QB}x1xi32", found
+        assert updates == f"{QB}xi32", found
         assert operand in (f"{N}xi32", f"{SC}xi32"), found
     # the guard has teeth: the formulation it replaced fails it
     old = _scatters(_scatter_rank_merge, K, multikey)
     assert any("x" in u.split("xi32")[0] for _, _, u in old), old
+
+
+@pytest.mark.parametrize("multikey", [False, True])
+@pytest.mark.parametrize("K", [3, 5])
+def test_rank_merge_builds_seen2_in_a_traced_loop(K, multikey,
+                                                  _toy_merge_blocks):
+    """Whole K-word rows are gathered by the build alone (the probe
+    reads the K-1 data words): MB rows from an MB-row window of the
+    table and MB from the sorted keys, inside ONE loop whose trip count
+    is traced — a `while`; the lax.map over all SC / MB blocks that
+    ISSUE 29 replaced is a `scan`, which _gathers does not count."""
+    shapes = (jax.ShapeDtypeStruct((SC, K), jnp.int32),
+              jax.ShapeDtypeStruct((), jnp.int32),
+              jax.ShapeDtypeStruct((N, K), jnp.int32))
+    jaxpr = jax.make_jaxpr(
+        lambda s, c, k: _rank_merge(s, c, k, N, SC, K, multikey))(*shapes)
+    rows = sorted((loops, operand, result)
+                  for loops, operand, result in _gathers(jaxpr.jaxpr)
+                  if operand[1] == K)
+    assert rows == [(1, (MB, K), (MB, K)), (1, (N, K), (MB, K))], rows
+    names = [eqn.primitive.name for eqn in jaxpr.jaxpr.eqns]
+    assert "scan" not in names, names
 
 
 # ------------------------------------------- the engines' one dedup merge
@@ -473,29 +670,51 @@ def _constoy():
         Loader([specs]).load_path(os.path.join(specs, "constoy.tla")), cfg)
 
 
-def _lowered_level_step(SC, FC):
+def _level_step(SC, FC):
+    """(engine, the level engine's step program, its arguments, the
+    merge's key slots N)."""
     from jaxmc.backend.bfs import TpuExplorer
     ex = TpuExplorer(_constoy())
     i32 = jnp.int32
-    text = ex._get_step(SC, FC).__wrapped__.lower(
+    return ex, ex._get_step(SC, FC).__wrapped__, (
         jnp.zeros((SC, ex.K), i32), i32(0),
-        jnp.zeros((FC, ex.PW), i32), i32(0)).as_text()
-    return text, ex.A * FC
+        jnp.zeros((FC, ex.PW), i32), i32(0)), ex.A * FC
 
 
-def _lowered_mesh_superstep(SC, FC):
+def _resident_run(SC, FC):
+    """The same for the resident engine's while_loop program."""
+    from jaxmc.backend.bfs import TpuExplorer
+    ex = TpuExplorer(_constoy())
+    i32 = jnp.int32
+    AccCap, VC, CH = 4 * FC, 2 * FC, FC
+    return ex, ex._get_resident_run(SC, FC, AccCap, VC, CH).__wrapped__, (
+        jnp.zeros((SC, ex.K), i32), i32(0), jnp.zeros((FC, ex.PW), i32),
+        i32(0), i32(0), i32(0), i32(0), i32(0), i32(0), i32(1)), AccCap
+
+
+def _mesh_superstep(SC, FC):
+    """The same for the mesh engine's superstep (four shards)."""
     from jax.sharding import Mesh
     from jaxmc.backend.mesh import MeshExplorer
     D, TRL, VC = 4, 16, 2 * FC
     ex = MeshExplorer(_constoy(), exchange="a2a",
                       mesh=Mesh(np.array(jax.devices()[:D]), ("d",)))
     i32 = jnp.int32
-    text = ex._get_mesh_resident_step(SC, FC, TRL, VC).__wrapped__.lower(
+    return ex, ex._get_mesh_resident_step(SC, FC, TRL, VC).__wrapped__, (
         jnp.zeros((D, SC, ex.K), i32), jnp.zeros((D,), i32),
         jnp.zeros((D, FC, ex.PW), i32), jnp.zeros((D,), i32),
         jnp.zeros((D, TRL, FC, ex.PW), i32), jnp.zeros((D, TRL, FC), i32),
-        i32(0), i32(0), i32(0), i32(0)).as_text()
-    return text, ex._route_fn(ex.A * FC, FC)[1]
+        i32(0), i32(0), i32(0), i32(0)), VC
+
+
+def _lowered_level_step(SC, FC):
+    ex, fn, args, _ = _level_step(SC, FC)
+    return fn.lower(*args).as_text(), ex.A * FC
+
+
+def _lowered_mesh_superstep(SC, FC):
+    ex, fn, args, _ = _mesh_superstep(SC, FC)
+    return fn.lower(*args).as_text(), ex._route_fn(ex.A * FC, FC)[1]
 
 
 @pytest.mark.parametrize("lowered", [_lowered_level_step,
@@ -516,3 +735,32 @@ def test_engine_steps_sort_no_seen_sized_block(lowered):
                for t in re.findall(r"tensor<([^>]*)>", m)}
     assert block < SC and SC + block not in lengths, lengths
     assert max(lengths) <= block, (lengths, block)
+
+
+@pytest.mark.parametrize("program,outer", [
+    (_level_step, 0), (_resident_run, 1), (_mesh_superstep, 1)],
+    ids=["level_step", "resident_run", "mesh_superstep"])
+def test_engine_programs_build_seen2_by_block_in_a_traced_loop(
+        program, outer, monkeypatch):
+    """Every engine's program builds the merged table a block of B rows
+    at a time inside a loop of its own — a `while`, what a fori_loop
+    with a TRACED trip count lowers to (a static count gives a `scan`,
+    which _gathers does not count), `outer` levels loops round it — and
+    gathers no table-sized block anywhere: the fixed pass over all SC
+    slots a level (ISSUE 29) stays out."""
+    SC, FC = 1 << 14, 64
+    monkeypatch.setattr(bfs, "_MERGE_BLOCK_ROWS", 1 << 10)
+    B = _merge_block_rows(SC)
+    assert B == 1 << 10
+    ex, fn, args, n_keys = program(SC, FC)
+    K = ex.K
+    found = _gathers(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert found
+    for loops, operand, result in found:
+        assert result[0] < SC, (operand, result)
+    # the window of the table and the sorted keys, once a block each
+    window = [loops for loops, operand, result in found
+              if operand == (B, K) and result == (B, K)]
+    fresh = [loops for loops, operand, result in found
+             if operand == (n_keys, K) and result == (B, K)]
+    assert window == fresh == [outer + 1], (window, fresh)

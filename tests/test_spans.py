@@ -306,11 +306,79 @@ class TestNothingMovesWithTheSpansIn:
             # the 4,526,080 slots it used to search
             assert tel.counters["search.slots_probed"] == 344064
             assert tel.counters["search.slots_sorted"] == 4526080
+            # ... and the merge built the blocks of the table that held
+            # a live row after each level (bench/pins' levels): the
+            # table holds SC = 2^15, 2^17, 2^18, 2^19, then 2^20 slots
+            # for six levels (seen + A x FC candidates must fit), a
+            # block is B(SC) of them
+            from jaxmc.backend.bfs import _merge_block_rows
+            scs = [1 << 15, 1 << 17, 1 << 18, 1 << 19] + 6 * [1 << 20]
+            assert tel.counters["search.seen_slots"] == sum(scs)
+            assert tel.counters["search.slots_merged"] == sum(
+                -(-seen2 // _merge_block_rows(sc)) * _merge_block_rows(sc)
+                for seen2, sc in zip(_PINS_3P_SEEN2, scs))
             assert tel.prof.sites["bfs.level_step"].dispatches == 10
             assert tel.prof.sites["bfs.level_step"].recompiles == \
                 tel.counters["compile.cache_misses"]
         phases = {p["name"]: p["count"] for p in tel.phase_list()}
         assert phases["search.init"] == phases["search.finish"] == 1
+
+
+def _pins(name):
+    with open(os.path.join(REPO, "bench", "pins", name + ".json")) as fh:
+        return json.load(fh)
+
+
+def _seen_after_each_level(pins):
+    """Rows the seen table holds after each level: the initial states
+    and every level's new ones (the pinned models have no CONSTRAINT, so
+    every fingerprinted state is a distinct one)."""
+    new = [lv[2] for lv in pins["levels"]]
+    have = pins["distinct"] - sum(new)
+    return [have := have + n for n in new]
+
+
+_PINS_3P_SEEN2 = _seen_after_each_level(_pins("transfer_scaled"))
+
+
+def test_slots_merged_of_the_pinned_model_at_the_cells_caps():
+    """The resident engine at the capacities the benchmark pins for the
+    3-process model (no regrowth): `search.slots_merged` of a whole
+    search is the sum over the pins' levels of ceil(seen_count2 / B) x B,
+    B = B(SC 2^20) — what `merge_fill` divides by in `desk-recheck-3p`."""
+    pytest.importorskip("jax")
+    from jaxmc.backend.bfs import _merge_block_rows
+    pins = _pins("transfer_scaled")
+    tel = obs.Telemetry()
+    res = _checked("transfer_scaled", "transfer_scaled", "resident", tel,
+                   res_caps=dict(pins["res_caps"]))
+    assert (res.generated, res.distinct) == (pins["generated"],
+                                             pins["distinct"])
+    B = _merge_block_rows(pins["res_caps"]["SC"])
+    c = tel.counters
+    assert c["search.seen_slots"] == len(pins["levels"]) \
+        * pins["res_caps"]["SC"]
+    assert c["search.slots_merged"] == sum(
+        -(-seen2 // B) * B for seen2 in _PINS_3P_SEEN2)
+    assert c["search.slots_merged"] < c["search.seen_slots"]
+    assert c["search.rows_new"] == pins["distinct"] - 12 ** 3
+
+
+@pytest.mark.slow
+def test_slots_merged_of_the_4_process_pinned_model():
+    """The same for `desk-recheck-4p8`'s model and capacities (minutes
+    on XLA:CPU: not in the tier-1 run)."""
+    pytest.importorskip("jax")
+    from jaxmc.backend.bfs import _merge_block_rows
+    pins = _pins("transfer_scaled_4p8")
+    tel = obs.Telemetry()
+    res = _checked("transfer_scaled", "transfer_scaled_4p8", "resident", tel,
+                   res_caps=dict(pins["res_caps"]))
+    assert (res.generated, res.distinct) == (pins["generated"],
+                                             pins["distinct"])
+    B = _merge_block_rows(pins["res_caps"]["SC"])
+    assert tel.counters["search.slots_merged"] == sum(
+        -(-seen2 // B) * B for seen2 in _seen_after_each_level(pins))
 
 
 @pytest.mark.parametrize("engine", sorted(ENGINES))
@@ -370,6 +438,31 @@ def test_slots_probed_by_hand(engine, monkeypatch):
         assert bfs._probe_block_rows(128) == 4
         assert c["search.slots_probed"] == 12 * 4
         assert c["search.slots_sorted"] == 6 * 128
+
+
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+def test_slots_merged_by_hand(engine, monkeypatch):
+    """constoy once more: after level k = 0..5 the seen table holds the
+    (k + 2)(k + 3) / 2 states with a + b <= k + 1 — level 5's successors
+    break the CONSTRAINT and are fingerprinted all the same: 3, 6, 10,
+    15, 21, 28 rows.  `search.slots_merged` is the sum over the levels
+    of ceil(rows / B) x B, B rows the block of the merged table."""
+    pytest.importorskip("jax")
+    from jaxmc.backend import bfs
+    monkeypatch.setattr(bfs, "_MERGE_BLOCK_ROWS", 8)
+    tel = obs.Telemetry()
+    caps = {"SC": 256, "FCap": 64, "AccCap": 128, "VC": 64}
+    res = _checked("constoy", "constoy", engine, tel,
+                   **({"res_caps": caps, "chunk": 64}
+                      if engine == "resident" else {}))
+    assert (res.generated, res.distinct) == (43, 21)
+    c = tel.counters
+    assert bfs._merge_block_rows(256) == bfs._merge_block_rows(1024) == 8
+    # 1 + 1 + 2 + 2 + 3 + 4 blocks of the 32 (resident, SC 256) or 128
+    # (level engine, SC 1024) a level that the table is cut into
+    assert c["search.slots_merged"] == 13 * 8
+    assert c["search.seen_slots"] == 6 * (256 if engine == "resident"
+                                          else 1024)
 
 
 def test_compile_seconds_by_program():
