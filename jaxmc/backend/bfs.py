@@ -3261,6 +3261,9 @@ class TpuExplorer:
                 seen[:n_init] = init_keys[order]
             seen = jnp.asarray(seen)
             seen_count = n_init
+            # what scale adds (ISSUE 30): both tables are built on the
+            # host at full capacity and uploaded, every search
+            tel.counter("search.seed_bytes", frontier.nbytes + seen.nbytes)
 
         depth = 0
         if self.resume_from:
@@ -3347,6 +3350,14 @@ class TpuExplorer:
                       caps["VC"], CH)
             fresh_compile = ck_key not in self._res_cache
             runf = self._get_resident_run(*ck_key)
+            # the program's capacity-sized tables at the capacities in
+            # force: seen and frontier (handed in, handed back) and the
+            # level accumulator's keys and rows (re-made every level at
+            # AccCap and sorted whole); bench/SPANS.deep.md has the
+            # other engines' definitions
+            tel.gauge("search.table_bytes", 4 * (
+                caps["SC"] * K + caps["FCap"] * self.PW
+                + caps["AccCap"] * (K + self.PW)))
             t_disp = time.time()
             # once the run has spilled (ISSUE 12), every level needs a
             # cold-tier probe at the host boundary: pin the dispatch to
@@ -3512,9 +3523,14 @@ class TpuExplorer:
                     frontier = jnp.concatenate([frontier, pad])
                 # keep the cap invariants: AccCap >= 2*VC (block-append
                 # headroom) and AccCap >= FCap ([:FCap] frontier slice of
-                # the accumulator)
-                caps["AccCap"] = max(caps["AccCap"], 2 * caps["VC"],
-                                     caps["FCap"])
+                # the accumulator) — by x4 steps of AccCap's OWN ladder,
+                # so that what a cold run leaves follows from the model's
+                # levels alone and not from which overflow came first (a
+                # bare max() put AccCap on FCap's ladder: the 4-process
+                # rung then ended at AccCap 2^24 where 2^23 holds it,
+                # after 9 programs where 7 do: PERF.md §6, PR 30)
+                while caps["AccCap"] < max(2 * caps["VC"], caps["FCap"]):
+                    caps["AccCap"] *= 4
                 self.log(f"-- resident: growing {what} to {caps[what]} "
                          f"(level {depth} redone)")
             elif stat == ST_CONTINUE:
@@ -4396,6 +4412,7 @@ class TpuExplorer:
                 seen[:n_init] = init_keys[order]
             seen = jnp.asarray(seen)
             seen_count = n_init
+            tel.counter("search.seed_bytes", frontier.nbytes + seen.nbytes)
 
             trace_levels: List[Tuple[np.ndarray, Optional[np.ndarray], int]] = []
             trace_levels.append((np.asarray(init_packed), None, 0))
@@ -4479,6 +4496,10 @@ class TpuExplorer:
                         seen = jnp.concatenate([seen, pad])
                         SC = SC2
                 step = self._get_step(SC, FC)
+                # the tables carried from level to level (the candidate
+                # block lives inside the step)
+                tel.gauge("search.table_bytes",
+                          4 * (SC * K + FC * self.PW))
                 out = step(seen, seen_count, frontier, fcount)
 
             with tel.span("level.sync"):

@@ -155,6 +155,12 @@ _A_ASRTF = 6
 _NA = 7
 
 
+def _nbytes(*tables) -> int:
+    """Bytes of the sharded tables given, over all shards (None: the
+    trace ring of a --no-trace run)."""
+    return sum(t.nbytes for t in tables if t is not None)
+
+
 class MeshExplorer(TpuExplorer):
     """BFS with the frontier and seen-set sharded across a device mesh.
 
@@ -1792,6 +1798,10 @@ class MeshExplorer(TpuExplorer):
             # _levels beyond the init level will be re-materialized from
             # the ring on demand; keep only level 0 host-side
             del self._levels[1:]
+        # what scale adds (ISSUE 30): the shards and the ring are built
+        # on the host at full capacity and uploaded, every search
+        obs.current().counter("search.seed_bytes", _nbytes(
+            seen, frontier, tr_rows, tr_src))
         return (seen, seen_count, frontier, fcount, tr_rows, tr_src, SC,
                 FC, TRL, depth, generated, distinct)
 
@@ -1850,6 +1860,11 @@ class MeshExplorer(TpuExplorer):
             args = (seen, seen_count, frontier, fcount)
             if self.store_trace:
                 args = args + (tr_rows, tr_src)
+            # the tables the superstep carries from level to level at
+            # the capacities in force, over all D shards: seen,
+            # frontier, the trace ring
+            tel.gauge("search.table_bytes", _nbytes(
+                seen, frontier, tr_rows, tr_src))
             # once spilled (ISSUE 12) every level needs a cold-tier
             # probe at the host boundary: pin supersteps to one level
             eff_maxlvl = 1 if (self._tiers is not None

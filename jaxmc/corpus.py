@@ -214,12 +214,16 @@ CASES: List[Case] = [
                     "GAM16": 32, "MSL": 32}),
     # the chip's REAL rung (ISSUE 21, chip_smoke.py legs B/C/E): four
     # processes.  Counts confirmed by the exact interpreter (--workers
-    # 8, 686 s here): 13 BFS levels, largest frontier 1,883,904
+    # 8, 686 s here): 13 BFS levels, largest frontier 1,883,904.
+    # res_caps are bench/pins/transfer_scaled_4p.json's (ISSUE 30): the
+    # widest level's 4,873,404 candidates + VC need AccCap 2^23, the
+    # largest frontier FCap 2^22 — smaller ones cost a regrowth and its
+    # recompile (~60 s on the chip) in every run seeded from here
     Case("specs/transfer_scaled.tla", root="repo",
          cfg="specs/transfer_scaled_4p.cfg",
          distinct=9394019, generated=24035597, slow=True, jax="yes",
          mode="compiled",
-         res_caps={"SC": 1 << 24, "FCap": 1 << 21, "AccCap": 1 << 22,
+         res_caps={"SC": 1 << 24, "FCap": 1 << 22, "AccCap": 1 << 23,
                    "VC": 1 << 14, "chunk": 2048},
          mesh_caps={"SC": 1 << 22, "FC": 1 << 19, "TRL": 32,
                     "GAM16": 32, "MSL": 32}),

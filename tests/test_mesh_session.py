@@ -233,6 +233,31 @@ def test_slots_merged_follows_each_shards_live_rows(tmp_path, monkeypatch):
         faults.reset_for_tests()
 
 
+@pytest.mark.parametrize("no_trace", [False, True])
+def test_seed_and_table_bytes_on_the_mesh(no_trace, tmp_path):
+    """`search.seed_bytes` / `search.table_bytes` (ISSUE 30) on four
+    shards: the [D, SC, K] seen shards and [D, FC, PW] frontier shards
+    the host builds and `_put`s, and, where traces are kept, the trace
+    ring [D, TRL, FC, PW] + [D, TRL, FC]; nothing grows at these
+    capacities, so the carried tables are the seeded ones."""
+    cfg = tmp_path / "t.cfg"
+    cfg.write_text(_cfg_text(2, 3, 4))
+    caps = {"SC": 1 << 10, "FC": 256, "TRL": 16, "GAM16": 32, "MSL": 16,
+            "VC": 256}
+    tel = obs.Telemetry()
+    with obs.use(tel):
+        sess = _session(TRANSFER, str(cfg), tel, devices=4, res_caps=caps,
+                        no_trace=no_trace)
+        assert _answer(sess.explore())[:2] == (256, 166)
+    K, PW = sess.engine.K, sess.engine.PW
+    assert K == 5
+    want = 4 * 4 * (caps["SC"] * K + caps["FC"] * PW)
+    if not no_trace:
+        want += 4 * 4 * caps["TRL"] * caps["FC"] * (PW + 1)
+    assert tel.counters["search.seed_bytes"] == want
+    assert tel.gauges["search.table_bytes"] == want
+
+
 def test_slots_merged_of_the_pinned_model_on_four_shards(monkeypatch):
     """The 3-process model the benchmark pins (`bench/pins`), on four
     virtual devices at capacities that hold it without regrowth: after

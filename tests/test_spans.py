@@ -410,6 +410,43 @@ def test_capacity_counters_by_hand(engine):
 
 
 @pytest.mark.parametrize("engine", sorted(ENGINES))
+def test_seed_and_table_bytes_by_hand(engine):
+    """constoy at the capacities above: `search.seed_bytes` is the
+    host-built seen table and frontier handed to the device at the start
+    of a search, at their full capacities, int32; `search.table_bytes`
+    the engine's capacity-sized tables at the capacities in force at its
+    end, defined per engine (ISSUE 30; `bench/SPANS.deep.md`).  A second search uploads as much again;
+    the gauge stays."""
+    pytest.importorskip("jax")
+    tel = obs.Telemetry()
+    caps = {"SC": 1 << 12, "FCap": 1 << 11, "AccCap": 1 << 13, "VC": 128}
+    with obs.use(tel):
+        sess = _session("constoy", "constoy", engine, tel,
+                        **({"res_caps": caps} if engine == "resident"
+                           else {}))
+        assert sess.explore().distinct == 21
+        K, PW = sess.engine.K, sess.engine.PW
+        if engine == "level":
+            # exact 1-word keys under a validity lane; the seeded tables
+            # are the floors FC = SC = 256, the seen table then grows
+            # once, to 1024, to hold 1 + A x FC candidates
+            assert (K, PW) == (2, 1)
+            seed = 4 * (256 * K + 256 * PW)
+            table = 4 * (1024 * K + 256 * PW)
+        else:
+            # 128-bit fingerprints under a validity lane; the
+            # accumulator carries keys and rows
+            assert (K, PW) == (5, 1)
+            seed = 4 * (caps["SC"] * K + caps["FCap"] * PW)
+            table = seed + 4 * caps["AccCap"] * (K + PW)
+        assert tel.counters["search.seed_bytes"] == seed
+        assert tel.gauges["search.table_bytes"] == table
+        assert sess.explore().distinct == 21
+    assert tel.counters["search.seed_bytes"] == 2 * seed
+    assert tel.gauges["search.table_bytes"] == table
+
+
+@pytest.mark.parametrize("engine", sorted(ENGINES))
 def test_slots_probed_by_hand(engine, monkeypatch):
     """constoy again: level k = 0..5 hands the merge 2 (k + 1) valid keys.
     `search.slots_probed` is the sum over the levels of ceil(valid / QB)
