@@ -13,10 +13,15 @@ JAXMC_MESH_EXCHANGE overrides):
 
   a2a     hash-routes each candidate straight to its owner via
           all_to_all with per-peer buckets of B = C*gamma/D (traffic
-          ~C*gamma per device).  Hash skew past gamma lands overflow
-          rows in a small per-peer SPILL bucket drained by a second
-          all_to_all pass (mesh.a2a_spill); only when the spill also
-          overflows is the level rerun with gamma doubled (ISSUE 8).
+          ~C*gamma per device).  The sender sorts its candidates by
+          destination and cuts each peer's bucket out of the sorted
+          payload as ONE contiguous slice, masked past the run's end
+          (ISSUE 31: runs move, never single rows — _place_fn).  Hash
+          skew past gamma leaves a run longer than B: its next rows
+          are a small per-peer SPILL bucket, the following slice,
+          drained by a second all_to_all pass (mesh.a2a_spill); only
+          when the spill also overflows is the level rerun with gamma
+          doubled (ISSUE 8).
   gather  all_gathers every candidate to every device (traffic C*D per
           device, no routing state); each device keeps the rows whose
           fingerprint lands in its range — the structural analogue of
@@ -451,11 +456,83 @@ class MeshExplorer(TpuExplorer):
         return (key_lane1.astype(jnp.uint32)
                 % jnp.uint32(self.D)).astype(jnp.int32)
 
+    def _place_fn(self, C: int, B: int, SB: int) -> Callable:
+        """The a2a route's sender side: place(ckeys [C,K], cand [C,PW],
+        cvalid [C], me) -> (b1 [D,B,Pw], b2 [D,SB,Pw], spill_local,
+        a2a_ovf_local, maxdest_local) — the per-peer buckets and SPILL
+        buckets the two all_to_alls send, free slots holding the
+        invalid row [1, SENTINEL...].  It holds no collective.
+
+        The route moves RUNS, not rows (ISSUE 31): the stable sort by
+        destination leaves peer d's rows as the contiguous run
+        [excl[d], excl[d] + counts[d]) of the payload, in candidate
+        order, so bucket d is that run's first B rows and spill bucket
+        d its next SB — one slice each and a mask past the run's end,
+        never a per-row scatter (~60 ns a row on the TPU v5e against a
+        streamed copy; PERF.md §6, PR 31).  Rows past B + SB of a run
+        are dropped and reported (a2a_ovf): the level is redone with a
+        larger gamma."""
+        D, K, PW = self.D, self.K, self.PW
+        Pw = K + PW + 1  # a2a payload: [keys | packed row | src-index]
+        invalid_row_np = np.concatenate(
+            [np.ones(1, np.int32), np.full(Pw - 1, SENTINEL, np.int32)])
+
+        @jax.named_scope("jaxmc.mesh.route")
+        def place(ckeys, cand, cvalid, me):
+            # bucket-sort by destination (invalid candidates sort
+            # last, destination D); traffic per device: D*(B+SB) =
+            # ~C*gamma rows instead of gather's C*D
+            dest = jnp.where(cvalid, self._owner_jnp(ckeys[:, 1]), D)
+            sperm = lax.sort(
+                (dest, jnp.arange(C, dtype=jnp.int32)),
+                num_keys=1, is_stable=True)[1]
+            # run borders by compare-and-sum: excl[d] candidates have a
+            # destination below d (excl[D] = the valid ones)
+            excl = jnp.sum(
+                dest[:, None] < jnp.arange(D + 1, dtype=jnp.int32)[None],
+                axis=0, dtype=jnp.int32)
+            counts = excl[1:] - excl[:-1]                  # [D]
+            # overflow only when bucket AND spill are exhausted; the
+            # max per-destination occupancy rides the scalar vector so
+            # the host can grow gamma straight to the observed need
+            # (one rerun, not log2 doublings)
+            a2a_ovf = jnp.any(counts > B + SB)
+            spill_local = jnp.sum(
+                jnp.clip(counts - B, 0, SB)).astype(jnp.int32)
+            maxdest_local = jnp.max(counts).astype(jnp.int32)
+            srcid = me.astype(jnp.int32) * C + sperm
+            invalid_row = jnp.asarray(invalid_row_np)
+            # B + SB invalid rows behind the payload: no slice start is
+            # ever clamped (a clamped start would shift a run)
+            payload = jnp.concatenate(
+                [jnp.concatenate(
+                    [jnp.take(ckeys, sperm, axis=0),
+                     jnp.take(cand, sperm, axis=0),
+                     srcid[:, None]], axis=1),
+                 jnp.broadcast_to(invalid_row, (B + SB, Pw))])
+
+            def cut(start, n, live):
+                rows = lax.dynamic_slice(payload, (start, 0), (n, Pw))
+                return jnp.where(
+                    jnp.arange(n, dtype=jnp.int32)[:, None] < live,
+                    rows, invalid_row)
+
+            b1 = jnp.stack([cut(excl[d], B, jnp.minimum(counts[d], B))
+                            for d in range(D)])            # [D, B, Pw]
+            b2 = jnp.stack([cut(excl[d] + B, SB,
+                                jnp.clip(counts[d] - B, 0, SB))
+                            for d in range(D)])            # [D, SB, Pw]
+            return b1, b2, spill_local, a2a_ovf, maxdest_local
+
+        return place
+
     def _route_fn(self, C: int, FC: int) -> Tuple[Callable, int, int, int]:
-        """Build the exchange closure shared by the legacy and resident
-        steps: route(ckeys, cand, cvalid, me) ->
+        """Build the exchange closure shared by the legacy, resident
+        and grouped steps: route(ckeys, cand, cvalid, me) ->
         (gkeys [R,K], gcand [R,PW], gsrc [R], spill_local,
-        a2a_ovf_local, maxdest_local, evalid [R]).
+        a2a_ovf_local, maxdest_local, evalid [R]).  a2a: the sender's
+        buckets come from _place_fn (slices of the destination-sorted
+        payload), two all_to_alls swap them, the receiver unpacks.
         `evalid` is the EDGE-STREAM validity — every valid exchanged
         row BEFORE ownership masking (gather replicates the full
         candidate set, so the host's device-0 read must not lose
@@ -496,59 +573,15 @@ class MeshExplorer(TpuExplorer):
         B = self._a2a_bucket(C, FC)
         SB = self._a2a_spill_bucket(B)
         R = D * (B + SB)
-
-        @jax.named_scope("jaxmc.mesh.route")
-        def place(ckeys, cand, cvalid, me):
-            # hash-route each candidate straight to its owner:
-            # bucket-sort by destination, scatter into [D, B] slots,
-            # one all_to_all; rows past B land in the [D, SB] SPILL
-            # buckets drained by a second all_to_all (ISSUE 8) —
-            # traffic per device: D*(B+SB) = ~C*gamma rows instead of
-            # gather's C*D.
-            dest = jnp.where(cvalid, self._owner_jnp(ckeys[:, 1]), D)
-            sperm = lax.sort(
-                (dest, jnp.arange(C, dtype=jnp.int32)),
-                num_keys=1, is_stable=True)[1]
-            sdest = jnp.take(dest, sperm)
-            counts = jnp.zeros((D + 1,), jnp.int32).at[dest].add(1)
-            excl = jnp.concatenate(
-                [jnp.zeros(1, jnp.int32), jnp.cumsum(counts)[:-1]])
-            pos = jnp.arange(C, dtype=jnp.int32) - jnp.take(excl, sdest)
-            # overflow only when bucket AND spill are exhausted; the
-            # max per-destination occupancy rides the scalar vector so
-            # the host can grow gamma straight to the observed need
-            # (one rerun, not log2 doublings)
-            a2a_ovf = jnp.any(counts[:D] > B + SB)
-            spill_local = jnp.sum(
-                jnp.clip(counts[:D] - B, 0, SB)).astype(jnp.int32)
-            maxdest_local = jnp.max(counts[:D]).astype(jnp.int32)
-            srcid = me.astype(jnp.int32) * C + sperm
-            payload = jnp.concatenate(
-                [jnp.take(ckeys, sperm, axis=0),
-                 jnp.take(cand, sperm, axis=0),
-                 srcid[:, None]], axis=1)              # [C, Pw]
-            slot1 = jnp.where((sdest < D) & (pos < B),
-                              sdest * B + pos, D * B)
-            spos = pos - B
-            slot2 = jnp.where((sdest < D) & (spos >= 0) & (spos < SB),
-                              sdest * SB + spos, D * SB)
-            b1 = jnp.full((D * B + 1, Pw), SENTINEL, jnp.int32)
-            b1 = b1.at[:, 0].set(1)  # invalid slots
-            b1 = b1.at[slot1].set(payload, mode="drop")
-            b2 = jnp.full((D * SB + 1, Pw), SENTINEL, jnp.int32)
-            b2 = b2.at[:, 0].set(1)
-            b2 = b2.at[slot2].set(payload, mode="drop")
-            return b1, b2, spill_local, a2a_ovf, maxdest_local
+        place = self._place_fn(C, B, SB)
 
         @jax.named_scope("jaxmc.mesh.exchange")
         def swap(b1, b2):
             invalid_key = jnp.asarray(invalid_key_np)
             recv1 = lax.all_to_all(
-                b1[:D * B].reshape(D, B, Pw), "d",
-                split_axis=0, concat_axis=0).reshape(D * B, Pw)
+                b1, "d", split_axis=0, concat_axis=0).reshape(D * B, Pw)
             recv2 = lax.all_to_all(
-                b2[:D * SB].reshape(D, SB, Pw), "d",
-                split_axis=0, concat_axis=0).reshape(D * SB, Pw)
+                b2, "d", split_axis=0, concat_axis=0).reshape(D * SB, Pw)
             recv = jnp.concatenate([recv1, recv2])     # [R, Pw]
             gkeys = recv[:, :K]
             gcand = recv[:, K:K + PW]

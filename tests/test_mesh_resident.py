@@ -491,19 +491,25 @@ class TestForcedSpill:
             (ri.generated, ri.distinct, ri.ok)
         faults.reset_for_tests()
 
-    def test_forced_spill_parity(self, monkeypatch):
+    @pytest.mark.parametrize("D", [4, 8])
+    def test_forced_spill_parity(self, D, monkeypatch):
         # two passes: measure the peak per-destination bucket under
         # skew, then pin FC and size gamma so the peak level lands in
         # the SPILL window (B < need <= B+SB) — the spill pass must
         # drain it with counts and trace bit-identical to the
-        # spill-free skewed run
+        # spill-free skewed run, and (ISSUE 31: the buckets are slices
+        # of the destination-sorted payload) with the counts of the
+        # ONE-CHIP engine, which routes nothing
         from jaxmc import faults, obs
+        from jaxmc.backend.bfs import TpuExplorer
         from jaxmc.tpu.mesh import MeshExplorer
+        r0 = TpuExplorer(load("pcal_intro_buggy")).run()
         monkeypatch.setenv("JAXMC_FAULTS", "mesh_skew:n=3")
         faults.reset_for_tests()
         tel = obs.Telemetry()
         with obs.use(tel):
-            m1 = MeshExplorer(load("pcal_intro_buggy"), exchange="a2a")
+            m1 = MeshExplorer(load("pcal_intro_buggy"), mesh=meshd(D),
+                              exchange="a2a")
             assert m1._skew
             r1 = m1.run()
         assert m1._spill_rows == 0  # generous gamma: no spill yet
@@ -511,8 +517,9 @@ class TestForcedSpill:
               if r.get("max_bucket")]
         fcmax = max(fc for _, fc in lv)
         mb = max(v for v, _ in lv)
-        D, A = m1.D, m1.A
-        m2 = MeshExplorer(load("pcal_intro_buggy"), exchange="a2a",
+        A = m1.A
+        m2 = MeshExplorer(load("pcal_intro_buggy"), mesh=meshd(D),
+                          exchange="a2a",
                           mesh_caps={"SC": 1 << 15, "FC": fcmax,
                                      "TRL": 16, "GAM16": 1})
         assert m2._skew
@@ -522,6 +529,9 @@ class TestForcedSpill:
         assert (r2.ok, r2.violation.kind) == (r1.ok, r1.violation.kind)
         assert [s for s, _ in r2.violation.trace] == \
             [s for s, _ in r1.violation.trace]
+        assert (r2.generated, r2.distinct) == (r1.generated, r1.distinct) \
+            == (r0.generated, r0.distinct)
+        assert (r2.ok, r2.violation.kind) == (r0.ok, r0.violation.kind)
         faults.reset_for_tests()
 
 
