@@ -1295,6 +1295,7 @@ class TpuExplorer:
         self.spill_dir = spill_dir or os.environ.get("JAXMC_SPILL_DIR")
         self.host_tier_keys = host_tier_keys
         self._tiers = None  # created lazily at the first spill
+        self._cap_breached = None  # rows a soft breach grew the table to
         # LEARNED CAPACITY PROFILE (ISSUE 6): resident runs start at the
         # caps a previous completed run on this (module, layout) ended
         # with — persisted next to the compile cache — so the one
@@ -1597,6 +1598,7 @@ class TpuExplorer:
         self.spill_dir = None
         self.host_tier_keys = None
         self._tiers = None
+        self._cap_breached = None
         self._cvec = np.asarray([int(model.defs[n])
                                  for n in self._lift_names], np.int32)
         self._cvec_dev = None
@@ -1875,15 +1877,54 @@ class TpuExplorer:
                 spill_dir=self.spill_dir, log=self.log)
         return self._tiers
 
-    def _tier_spill_prefix(self, seen_np: np.ndarray, count: int) -> None:
+    def _begin_search(self) -> None:
+        """Cold tiers and a soft breach of the cap are state of ONE
+        search: an engine that has searched before starts the next with
+        no cold run (a `--resume` then loads what its checkpoint
+        carries, `_load_ck`).  Left in place they would answer the next
+        search's frontier with the last one's states."""
+        if self._tiers is not None:
+            self._tiers.reset()
+        self._cap_breached = None
+
+    @staticmethod
+    def _device_table(shape, head: Optional[np.ndarray] = None):
+        """A [rows, words] table of SENTINEL rows with `head` in its
+        first rows, made ON the device: a fill and the head's few rows,
+        where `np.full` + upload is a host buffer of the table's size
+        per call.  The capped path (ISSUE 32) makes its tables this way:
+        at 8-21 MB they fall under glibc's dynamic mmap threshold, and a
+        process then serves them from reused heap or from fresh,
+        page-faulting maps for its whole life, which made a capped
+        search 2 % faster or slower from one process to the next."""
+        table = jnp.full(shape, SENTINEL, jnp.int32)
+        if head is None or not len(head):
+            return table
+        return table.at[:len(head)].set(jnp.asarray(head, jnp.int32))
+
+    def _tier_spill(self, seen, count: int):
         """Compact the device table's sorted valid prefix out as ONE
         immutable sorted run (the validity lane is stripped — cold runs
-        hold data words only)."""
-        if count <= 0:
-            return
-        t = self._ensure_tiers()
-        t.spill(np.ascontiguousarray(seen_np[:count, 1:]))
-        obs.current().counter("tier.spilled_keys", int(count))
+        hold data words only) and hand back an empty table of the same
+        capacity."""
+        tel = obs.current()
+        with tel.span("tier.spill", keys=count, bytes=int(seen.nbytes)):
+            self._ensure_tiers().spill(
+                np.ascontiguousarray(np.asarray(seen)[:count, 1:]))
+            tel.counter("tier.spilled_keys", int(count))
+            return self._device_table(seen.shape)
+
+    def _note_cap_breach(self, rows: int, why: str) -> None:
+        """A soft breach made visible: the device table grew past the
+        cap because it must seat a level's candidates beside nothing
+        (`seen_count + candidates <= SC`), so the cap held less than it
+        says.  Gauge `tier.cap_breached` and `result.tiers` carry the
+        rows it grew to."""
+        self._cap_breached = max(self._cap_breached or 0, int(rows))
+        obs.current().gauge("tier.cap_breached", self._cap_breached)
+        self.log(f"-- tier: device cap {self.seen_cap} < {why}; growing "
+                 f"to {int(rows)} rows anyway (soft cap, "
+                 f"tier.cap_breached)")
 
     def _packed_keys(self, packed_np: np.ndarray) -> np.ndarray:
         """Dedup-key DATA words ([n, K-1], validity lane stripped) for a
@@ -1919,7 +1960,21 @@ class TpuExplorer:
         if self._tiers is None or not self._tiers.active \
                 or len(rows_np) == 0:
             return np.ones(len(rows_np), bool)
-        return ~self._tiers.probe(self._packed_keys(rows_np))
+        with obs.current().span("tier.keys", rows=len(rows_np)):
+            keys = self._packed_keys(rows_np)
+        return ~self._tier_probe(keys)
+
+    def _tier_probe(self, keys: np.ndarray) -> np.ndarray:
+        """[n] bool, True where a key lives in a cold run; the probe's
+        span and its two counters."""
+        tel = obs.current()
+        with tel.span("tier.probe", keys=len(keys),
+                      runs=len(self._tiers.host_runs)
+                      + len(self._tiers.disk_runs)):
+            dup = self._tiers.probe(keys)
+        tel.counter("tier.keys_probed", len(keys))
+        tel.counter("tier.keys_dropped", int(dup.sum()))
+        return dup
 
     # ---- jitted level step, compiled per (seen_cap, frontier_cap) ----
     def _get_step(self, SC: int, FC: int) -> Callable:
@@ -1927,7 +1982,7 @@ class TpuExplorer:
         # to the host, so the cold-tier membership probe never
         # recomputes keys; the flag joins the compile key — the one
         # recompile it costs happens at the first spill
-        tiered = self._tiers is not None
+        tiered = self._tiers is not None and self._tiers.active
         # device POR (ISSUE 18): the persistent-set filter joins the
         # compile key — the mask arrays are baked constants
         por_plan = self._por_plan() if self.por else None
@@ -3209,6 +3264,8 @@ class TpuExplorer:
         # 1600-init model would otherwise crash the seeding, not grow)
         caps["SC"] = max(caps["SC"],
                          _pow2_at_least(max(4 * n_init, 1), lo=256))
+        if self.seen_cap is not None and caps["SC"] > self.seen_cap:
+            self._note_cap_breach(caps["SC"], "the initial states' keys")
         caps["FCap"] = max(caps["FCap"], _pow2_at_least(max(n_init, 1),
                                                         lo=CH))
         # VC can never usefully exceed the dense candidate-grid size
@@ -3248,22 +3305,31 @@ class TpuExplorer:
                     False, distinct, generated, 0, t0, warnings,
                     Violation("error", "capacity overflow", [],
                               self._pack_ovf_msg()))
-            frontier = np.full((caps["FCap"], self.PW), SENTINEL,
-                               np.int32)
-            frontier[:distinct] = init_packed[explored_init]
-            frontier = jnp.asarray(frontier)
+            fr_head = init_packed[explored_init]
+            order = np.lexsort(tuple(init_keys[:, i]
+                                     for i in reversed(range(K))))
+            seen_head = init_keys[order]
+            if self.seen_cap is not None:
+                # capped: both tables made on the device (_device_table)
+                frontier = self._device_table(
+                    (caps["FCap"], self.PW), fr_head)
+                seen = self._device_table((caps["SC"], K), seen_head)
+                tel.counter("search.seed_bytes",
+                            fr_head.nbytes + seen_head.nbytes)
+            else:
+                frontier = np.full((caps["FCap"], self.PW), SENTINEL,
+                                   np.int32)
+                frontier[:distinct] = fr_head
+                frontier = jnp.asarray(frontier)
+                seen = np.full((caps["SC"], K), SENTINEL, np.int32)
+                seen[:n_init] = seen_head
+                seen = jnp.asarray(seen)
+                # what scale adds (ISSUE 30): both tables are built on
+                # the host at full capacity and uploaded, every search
+                tel.counter("search.seed_bytes",
+                            frontier.nbytes + seen.nbytes)
             fcount = distinct
-
-            seen = np.full((caps["SC"], K), SENTINEL, np.int32)
-            if n_init:
-                order = np.lexsort(tuple(init_keys[:, i]
-                                         for i in reversed(range(K))))
-                seen[:n_init] = init_keys[order]
-            seen = jnp.asarray(seen)
             seen_count = n_init
-            # what scale adds (ISSUE 30): both tables are built on the
-            # host at full capacity and uploaded, every search
-            tel.counter("search.seed_bytes", frontier.nbytes + seen.nbytes)
 
         depth = 0
         if self.resume_from:
@@ -3329,6 +3395,7 @@ class TpuExplorer:
                  f"{fcount} states left on queue."
                  f"{obs.eta_suffix(distinct)}")
         last_progress = last_ck = time.time()
+        redo_after_spill = False
         while True:
             # chaos sites: crash / device failure between dispatches
             # (the only host-attention points resident mode has)
@@ -3412,17 +3479,20 @@ class TpuExplorer:
                 if self._tiers is not None and self._tiers.active and \
                         fcount > 0 and stat not in grow_flag and \
                         stat not in (ST_OVF_LANES, ST_DONE):
-                    fr_np = np.asarray(frontier[:fcount])
+                    with tel.span("tier.pull", rows=fcount):
+                        fr_np = np.asarray(frontier[:fcount])
                     keep = self._tier_keep_mask(fr_np)
                     n_dup = int((~keep).sum())
                     if n_dup:
-                        kept_rows = np.ascontiguousarray(fr_np[keep])
-                        distinct -= n_dup
-                        fcount = len(kept_rows)
-                        fr_full = np.full((int(frontier.shape[0]), self.PW),
-                                          SENTINEL, np.int32)
-                        fr_full[:fcount] = kept_rows
-                        frontier = jnp.asarray(fr_full)
+                        with tel.span("tier.push", rows=fcount - n_dup):
+                            kept_rows = np.ascontiguousarray(fr_np[keep])
+                            distinct -= n_dup
+                            fcount = len(kept_rows)
+                            fr_full = np.full(
+                                (int(frontier.shape[0]), self.PW),
+                                SENTINEL, np.int32)
+                            fr_full[:fcount] = kept_rows
+                            frontier = jnp.asarray(fr_full)
                     if stat == ST_TRUNC and self.max_states and \
                             distinct < self.max_states:
                         stat = ST_CONTINUE  # phantom limit: dups un-counted
@@ -3460,6 +3530,12 @@ class TpuExplorer:
             tel.counter("search.slots_merged", merge_blocks
                         * _merge_block_rows(caps["SC"]))
             tel.counter("search.rows_new", distinct - dist_in)
+            if redo_after_spill and generated > gen_in:
+                # the level a spill rolled back has now run a second
+                # time (one level a dispatch once tiers are active):
+                # its candidates were expanded, sorted and merged twice
+                tel.counter("tier.redone_rows", generated - gen_in)
+                redo_after_spill = False
             self._fp_occupancy = seen_count
 
             if stat in grow_flag:
@@ -3473,12 +3549,9 @@ class TpuExplorer:
                     # and redo the level (the rollback preserved the
                     # pre-level state); subsequent dispatches run one
                     # level at a time with a cold-tier probe each
-                    with tel.span("tier.spill", keys=seen_count):
-                        self._tier_spill_prefix(np.asarray(seen),
-                                                seen_count)
-                    seen = jnp.asarray(
-                        np.full((old, K), SENTINEL, np.int32))
+                    seen = self._tier_spill(seen, seen_count)
                     seen_count = 0
+                    redo_after_spill = True
                     self.log(f"-- tier: device seen cap "
                              f"{self.seen_cap} reached; spilled the "
                              f"device tier to "
@@ -3509,10 +3582,8 @@ class TpuExplorer:
                         # engine's soft breach (a clamp here would be
                         # zero growth: an infinite redo of the same
                         # dispatch)
-                        self.log(f"-- tier: device cap "
-                                 f"{self.seen_cap} < one level's new "
-                                 f"keys; growing to {caps[what]} "
-                                 f"anyway (soft cap)")
+                        self._note_cap_breach(
+                            caps[what], "one level's candidates")
                 if what == "SC":
                     pad = jnp.full((caps[what] - old, K), SENTINEL,
                                    jnp.int32)
@@ -4312,6 +4383,7 @@ class TpuExplorer:
 
     # ---- host-side search loop ----
     def run(self) -> CheckResult:
+        self._begin_search()
         if self.resident:
             return self._run_resident()
         if self.host_seen:
@@ -4470,27 +4542,22 @@ class TpuExplorer:
                 C = self.A * FC
                 if seen_count + C > SC:
                     SC2 = _pow2_at_least(seen_count + C, SC)
-                    if self.seen_cap is not None and SC2 > self.seen_cap \
-                            and seen_count > 0:
-                        # device tier full (ISSUE 12): compact the sorted
-                        # prefix out to the cold tiers and restart the
-                        # device table empty, instead of growing past the
-                        # cap — kept rows are cold-probed after each step
-                        with tel.span("tier.spill", keys=seen_count):
-                            self._tier_spill_prefix(np.asarray(seen),
-                                                    seen_count)
-                        seen = jnp.asarray(
-                            np.full((SC, K), SENTINEL, np.int32))
-                        seen_count = 0
-                        SC2 = _pow2_at_least(C, SC)
+                    if self.seen_cap is not None and SC2 > self.seen_cap:
+                        if seen_count > 0:
+                            # device tier full (ISSUE 12): compact the
+                            # sorted prefix out to the cold tiers and
+                            # restart the device table empty, instead of
+                            # growing past the cap — kept rows are
+                            # cold-probed after each step
+                            seen = self._tier_spill(seen, seen_count)
+                            seen_count = 0
+                            SC2 = _pow2_at_least(C, SC)
                         if SC2 > max(SC, self.seen_cap):
                             # the per-level candidate block alone exceeds
                             # the cap: the rank-merge no-overflow invariant
                             # (seen_count + C <= SC) forces a soft breach
-                            self.log(f"-- tier: device cap "
-                                     f"{self.seen_cap} < one level's "
-                                     f"candidate block ({C}); growing "
-                                     f"anyway (soft cap)")
+                            self._note_cap_breach(
+                                SC2, f"one level's candidate block ({C})")
                     if SC2 > SC:
                         pad = jnp.full((SC2 - SC, K), SENTINEL, jnp.int32)
                         seen = jnp.concatenate([seen, pad])
@@ -4565,8 +4632,10 @@ class TpuExplorer:
                 fr_host = fp_host = None
                 if self._tiers is not None and self._tiers.active \
                         and front_count:
-                    fkeys = np.asarray(out["front_keys"][:front_count, 1:])
-                    dup = self._tiers.probe(fkeys)
+                    with tel.span("tier.pull", rows=front_count):
+                        fkeys = np.asarray(
+                            out["front_keys"][:front_count, 1:])
+                    dup = self._tier_probe(fkeys)
                     if dup.any():
                         tier_keep = ~dup
                         fr_host = np.ascontiguousarray(np.asarray(
@@ -4739,10 +4808,7 @@ class TpuExplorer:
         # tier-hierarchy summary, and the named exhausted resource on
         # truncations (a bare `truncated` flag cannot tell a deliberate
         # --max-states from a capacity wall)
-        tiers_stats = None
-        if self._tiers is not None and self._tiers.active:
-            tiers_stats = self._tiers.stats()
-            self._tiers.publish_gauges(occ or 0)
+        tiers_stats = self._tiers_result(occ)
         # device POR end-of-run counters (ISSUE 18): every engine funnels
         # its result through here, so the gauge surface is uniform
         self._por_finish(self._por_stats["ample"],
@@ -4767,6 +4833,24 @@ class TpuExplorer:
                            trunc_reason=trunc_reason,
                            seen_mode=seen_mode, collision_p=collision_p,
                            tiers=tiers_stats)
+
+    def _tiers_result(self, occ) -> Optional[Dict[str, Any]]:
+        """`result.tiers` and the end-of-search tier gauges: the cold
+        store's stats where it holds a run, and `cap_breached` (rows the
+        table grew to) where the cap was soft-breached — with or
+        without a spill."""
+        tiers_stats = None
+        if self._tiers is not None and self._tiers.active:
+            tiers_stats = self._tiers.stats()
+        if tiers_stats is not None or self.seen_cap is not None:
+            # where a capped search's keys ended, spilled or not: the
+            # gauge is sticky, and a search that never spilled must not
+            # leave the last one's host and disk counts standing
+            self._ensure_tiers().publish_gauges(occ or 0)
+        if self._cap_breached:
+            tiers_stats = dict(tiers_stats or {},
+                               cap_breached=self._cap_breached)
+        return tiers_stats
 
     def _drain_requested(self, warnings, engine: str) -> bool:
         """Cooperative drain poll at a device-safe boundary (between

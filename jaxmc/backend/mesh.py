@@ -361,8 +361,9 @@ class MeshExplorer(TpuExplorer):
         tel = obs.current()
         scounts = np.asarray(seen_count)
         total = int(scounts.sum())
-        seen_np = np.asarray(seen)
-        with tel.span("tier.spill", keys=total, shards=self.D):
+        with tel.span("tier.spill", keys=total, shards=self.D,
+                      bytes=int(seen.nbytes)):
+            seen_np = np.asarray(seen)
             t = self._ensure_tiers()
             for dd in range(self.D):
                 cnt = int(scounts[dd])
@@ -384,8 +385,10 @@ class MeshExplorer(TpuExplorer):
         SAME compaction so parent indices recorded by the next level
         keep resolving.  Returns (frontier, fcount, tr_rows, tr_src,
         n_dup)."""
-        fr_np = np.asarray(frontier)          # [D, FC, PW]
-        fc_np = np.asarray(fcount).astype(np.int32).copy()
+        tel = obs.current()
+        with tel.span("tier.pull", rows=self.D * FC):
+            fr_np = np.asarray(frontier)          # [D, FC, PW]
+            fc_np = np.asarray(fcount).astype(np.int32).copy()
         keeps = []
         n_dup = 0
         for dd in range(self.D):
@@ -404,7 +407,7 @@ class MeshExplorer(TpuExplorer):
         if self.store_trace:
             src_slot = np.asarray(tr_src[:, depth - 1])
             new_src = np.full((self.D, FC), -1, np.int32)
-            obs.current().counter("mesh.row_syncs")
+            tel.counter("mesh.row_syncs")
         for dd in range(self.D):
             c = int(fc_np[dd])
             if c == 0:
@@ -415,11 +418,12 @@ class MeshExplorer(TpuExplorer):
             if new_src is not None:
                 new_src[dd, :k] = src_slot[dd, :c][keep]
             fc_np[dd] = k
-        frontier = self._put(new_fr)
-        fcount = self._put(fc_np)
-        if self.store_trace:
-            tr_rows = tr_rows.at[:, depth - 1].set(self._put(new_fr))
-            tr_src = tr_src.at[:, depth - 1].set(self._put(new_src))
+        with tel.span("tier.push", rows=int(fc_np.sum())):
+            frontier = self._put(new_fr)
+            fcount = self._put(fc_np)
+            if self.store_trace:
+                tr_rows = tr_rows.at[:, depth - 1].set(self._put(new_fr))
+                tr_src = tr_src.at[:, depth - 1].set(self._put(new_src))
         return frontier, fcount, tr_rows, tr_src, n_dup
 
     # ---- the sharded level step ----
@@ -1619,6 +1623,7 @@ class MeshExplorer(TpuExplorer):
         # kept rows), so the mode guards key on the wider condition
         need_edges = bool(self.refiners) or self.collect_edges
         need_props = bool(self.refiners) or bool(self.live_obligations)
+        self._begin_search()
         # per-RUN accounting: the final gauges (_mk) must describe THIS
         # run — a warm re-run (bench timed window) must not inherit the
         # warm-up's spill/bucket peaks (review r8).  Learned caps and
@@ -2034,12 +2039,22 @@ class MeshExplorer(TpuExplorer):
                             # cap
                             seen, seen_count = self._mesh_tier_spill(
                                 seen, seen_count, SC)
+                            # the rolled-back level runs a second time
+                            tel.counter("tier.redone_rows",
+                                        int(scal[_S_GEN]))
                             grew.append(
                                 f"seen->tier-spill("
                                 f"{int(scounts_now.sum())} keys, "
                                 f"host={self._tiers.host_keys} "
                                 f"disk={self._tiers.disk_keys})")
                         else:
+                            if shard_cap is not None and SC2 > shard_cap:
+                                # nothing left to spill: one level's
+                                # keys alone exceed the shard's cap
+                                self._note_cap_breach(
+                                    SC2 * self.D,
+                                    f"one level's keys on a shard "
+                                    f"(cap {shard_cap} a shard)")
                             seen = self._pad_dev(seen, 1, SC2, SENTINEL,
                                                  lane1=True)
                             SC = SC2
@@ -2656,10 +2671,7 @@ class MeshExplorer(TpuExplorer):
                       self._superstep_levels_max)
         # ISSUE 12 result surface (mirrors bfs._mk_result): tier
         # summary, fingerprint collision bound, named truncations
-        tiers_stats = None
-        if self._tiers is not None and self._tiers.active:
-            tiers_stats = self._tiers.stats()
-            self._tiers.publish_gauges(occ or 0)
+        tiers_stats = self._tiers_result(occ)
         n = float((occ or 0) + (len(self._tiers)
                                 if self._tiers is not None else 0))
         collision_p = n * n * 2.0 ** -129

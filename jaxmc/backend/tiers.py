@@ -37,6 +37,7 @@ counts and simply stops using the disk rung.
 
 from __future__ import annotations
 
+import ctypes
 import os
 import tempfile
 import time
@@ -111,6 +112,25 @@ def _np_rank_merge(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     return _merge_sorted(a, b, _keyview(a), _keyview(b))
 
 
+def _keep_host_heap() -> None:
+    """The cold tiers' host work is numpy over temporaries of 1-21 MB,
+    a few hundred MB of them a search.  glibc serves blocks that size
+    from the heap or from fresh maps by a threshold that MOVES (128 KB
+    to 32 MB, with what was freed before), and gives the heap's top
+    back to the system past a second one — or cannot, where an early
+    allocation of the process happened to land above it.  So one
+    process page-faults those temporaries in again every search and
+    the next never does, for its whole life.  Pin both: blocks under
+    32 MB come from the heap, and the heap is kept.  Process-wide, and
+    a no-op where the C library is not glibc."""
+    try:
+        libc = ctypes.CDLL("libc.so.6")
+        libc.mallopt(-1, 1 << 30)   # M_TRIM_THRESHOLD
+        libc.mallopt(-3, 1 << 25)   # M_MMAP_THRESHOLD (its ceiling)
+    except (OSError, AttributeError):
+        pass
+
+
 class TieredSeen:
     """The cold (host + disk) tiers of the hierarchical seen set.
 
@@ -141,9 +161,7 @@ class TieredSeen:
         self.spill_dir = spill_dir
         self._own_dir = False
         self.log = log if log is not None else (lambda s: None)
-        self.host_runs: List[np.ndarray] = []  # keybyte form
         self.disk_runs: List[str] = []
-        self._disk_keys = 0
         self._run_seq = 0
         # run files referenced by the most recent path-mode checkpoint
         # (dump) or adopted from one (load): compaction must not
@@ -151,11 +169,8 @@ class TieredSeen:
         # and the next dump() drops the superseded ones
         self._ckpt_refs: set = set()
         self._retired: List[str] = []
-        # stats (obs gauges/counters ride these)
-        self.spills = 0
-        self.compactions = 0
-        self.probe_wall_s = 0.0
-        self.io_degraded: Optional[str] = None
+        _keep_host_heap()
+        self.reset()
 
     # ---- sizing ------------------------------------------------------
 
@@ -173,6 +188,34 @@ class TieredSeen:
     @property
     def active(self) -> bool:
         return bool(self.host_runs or self.disk_runs)
+
+    def reset(self) -> None:
+        """Forget every run and zero the stats: the cold tiers are state
+        of ONE search, so an engine calls this before each one (a resume
+        then `load`s what its checkpoint carries).  Run files a
+        checkpoint references stay on disk — they are its only copy —
+        and stay RETIRED, so the next search's dump() drops them when it
+        supersedes the reference; the spill directory is kept and
+        reused.  A disk write that failed in the last search is tried
+        again in this one (its files are gone, the disk may have room)."""
+        keep = []
+        for p in self.disk_runs + self._retired:
+            if os.path.abspath(p) in self._ckpt_refs:
+                keep.append(p)
+                continue
+            try:
+                os.unlink(p)
+            except OSError:
+                pass
+        self.host_runs: List[np.ndarray] = []  # keybyte form
+        self.disk_runs = []
+        self._retired = keep
+        self._disk_keys = 0
+        self.io_degraded = None
+        # stats (obs gauges/counters ride these)
+        self.spills = 0
+        self.compactions = 0
+        self.probe_wall_s = 0.0
 
     # ---- spill / compaction ------------------------------------------
 
@@ -286,7 +329,7 @@ class TieredSeen:
         self.compactions += 1
         obs.current().counter("tier.compactions")
         for p in old:
-            if p in self._ckpt_refs:
+            if os.path.abspath(p) in self._ckpt_refs:
                 # the most recent (path-mode) checkpoint references
                 # this file: unlinking it would make that checkpoint
                 # unresumable — retire it until a newer dump()
@@ -379,7 +422,7 @@ class TieredSeen:
         # referenced them are superseded by this dump — drop them
         keep = []
         for p in self._retired:
-            if p in self._ckpt_refs:
+            if os.path.abspath(p) in self._ckpt_refs:
                 keep.append(p)
                 continue
             try:

@@ -475,7 +475,13 @@ def main(argv=None) -> int:
                         "then disk tiers (out-of-core checking) "
                         "instead of growing device memory — counts and "
                         "traces stay bit-identical to the uncapped "
-                        "run. Default: no cap (grow on device)")
+                        "run. The cap must seat the widest level's "
+                        "candidates beside the hot keys; one that "
+                        "cannot is grown past, named by the gauge "
+                        "tier.cap_breached and result.tiers. Cold "
+                        "tiers last one search: a re-check on a warm "
+                        "session spills and probes again. Default: no "
+                        "cap (grow on device)")
     c.add_argument("--seen-spill", default=None, metavar="DIR",
                    help="jax backend: disk-tier directory for spilled "
                         "seen-set runs (env: JAXMC_SPILL_DIR; default "

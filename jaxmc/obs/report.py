@@ -342,7 +342,8 @@ def cmd_report(args, out=sys.stdout) -> int:
                   f"({100.0 * pv / (pv + gd):.0f}% of int lanes "
                   f"proven)")
     for k in ("expand.mode", "dedup.mode", "seen.mode",
-              "tier.device_cap", "tier.probe_wall_s",
+              "tier.device_cap", "tier.cap_breached",
+              "tier.probe_wall_s",
               "tier.io_degraded", "truncation.reason",
               "fingerprint.collision_p",
               "layout.width_lanes",

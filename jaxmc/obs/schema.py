@@ -282,10 +282,20 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       keys), gauge `tier.probe_wall_s` (cumulative cold-probe wall),
       gauge `tier.device_cap` (the configured device cap, rows),
       counters `tier.spills` / `tier.spilled_keys` /
-      `tier.compactions`; phase span `tier.spill {keys[, shards]}`
-      per device-prefix spill; `result.tiers` carries the final
-      stats() summary {host_keys, disk_keys, host_runs, disk_runs,
-      spills, compactions, probe_wall_s[, io_degraded]}.
+      `tier.compactions`; phase span `tier.spill {keys, bytes[,
+      shards]}` per device-prefix spill; `result.tiers` carries the
+      final stats() summary {host_keys, disk_keys, host_runs,
+      disk_runs, spills, compactions, probe_wall_s[, io_degraded]
+      [, cap_breached]}.  Since ISSUE 32 (bench/SPANS.ooc.md), per
+      probed level the spans `tier.pull {rows}`, `tier.keys {rows}`,
+      `tier.probe {keys, runs}`, `tier.push {rows}`; counters
+      `tier.keys_probed` / `tier.keys_dropped` / `tier.redone_rows`
+      (candidates of levels a spill rolled back and ran again);
+      gauge `tier.cap_breached` (rows a table grew to past a cap
+      that could not seat one level's candidates); `tier.occupancy`
+      is published at the end of every search of a capped engine
+      (host and disk 0 where it never spilled: cold tiers last ONE
+      search).
     - tier fault containment: trace event + gauge `tier.io_degraded
       {error}` when a disk-tier write fails (ENOSPC, the
       tier_io_error fault site) and the store degrades to

@@ -10,8 +10,16 @@ overflows; `test_the_engine_climbs_the_ladder_the_arithmetic_gives` holds the
 engine itself to it (ISSUE 30's repair: a frontier growth used to lift AccCap
 onto FCap's ladder, and the real rung's cold run ended at AccCap 2^24, not the
 pinned 2^23).  `desk-deep-4p` runs SC 2^24 = 512 merge blocks and AccCap 2^23
-= 64 query blocks; the last test runs those COUNTS of blocks at a size XLA:CPU
-answers in seconds, over several dispatches.
+= 64 query blocks; `test_resident_engine_in_the_deep_proportions` runs those
+COUNTS of blocks at a size XLA:CPU answers in seconds, over several dispatches.
+
+`desk-ooc-4p8` (ISSUE 32) pins a device CAP with its capacities: there SC is
+the cap, a seen-table overflow spills instead of growing, and what a search
+does — which levels spill, how many keys go cold, how many candidates are
+generated twice, how many keys the host probes and drops — is `_cold_spills`,
+the plain reference's successor function under the engine's spill rule.  The
+pins' `tier` block is that arithmetic at the cell's size, and the last test
+holds the resident engine's `tier.*` counters to it at toy size.
 """
 
 import glob
@@ -19,6 +27,7 @@ import importlib.util
 import json
 import os
 
+import numpy as np
 import pytest
 
 from jaxmc import obs
@@ -46,13 +55,17 @@ def _no_capacity_profiles(monkeypatch):
     monkeypatch.setenv("JAXMC_CAP_PROFILE", "0")
 
 
-def _needs(levels, initial, vc):
+def _needs(levels, initial, vc, cap=None):
     """What a whole search asks of each capacity, from the reference's
-    levels [frontier, candidates, new]: the three inequalities."""
+    levels [frontier, candidates, new]: the three inequalities.  Under a
+    device cap the table spills before it grows, so it need only seat the
+    widest level's candidates beside nothing."""
     seen, most = initial, 0
     for _, cand, new in levels:
         most = max(most, seen + cand)  # every candidate could be new
         seen += new
+    if cap is not None:
+        most = max(cand for _, cand, _ in levels)
     return {"SC": most,
             "FCap": max(max(f, new) for f, _, new in levels),
             "AccCap": max(cand for _, cand, _ in levels) + vc}
@@ -65,18 +78,24 @@ def _ladder_step(default, need):
     return cap
 
 
-def _cold_ladder(levels, initial, defaults):
+def _cold_ladder(levels, initial, defaults, cap=None):
     """The capacities of every program a cold resident run compiles, in
     order, from the reference's levels: a level is redone after each x4
     growth; the level's status names the accumulator first, then the seen
     table, then the frontier (`bfs._get_resident_run`'s `level`), and
-    AccCap keeps its invariants by steps of its own ladder."""
+    AccCap keeps its invariants by steps of its own ladder.  Under a device
+    `cap` a full table at the cap spills (and the level is redone against
+    an empty one) instead of growing."""
     caps = dict(defaults)
     programs, seen = [dict(caps)], initial
     for _, cand, new in levels:
         while True:
             if cand + caps["VC"] > caps["AccCap"]:
                 what = "AccCap"
+            elif seen + cand > caps["SC"] and cap is not None \
+                    and caps["SC"] >= cap and seen:
+                seen = 0
+                continue
             elif seen + cand > caps["SC"]:
                 what = "SC"
             elif new > caps["FCap"]:
@@ -91,9 +110,70 @@ def _cold_ladder(levels, initial, defaults):
     return programs
 
 
+def _reference():
+    spec = importlib.util.spec_from_file_location(
+        "plain_reference", os.path.join(REPO, "bench", "reference",
+                                        "transfer_scaled.py"))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+    return reference
+
+
+def _cold_spills(procs, max_money, cap):
+    """One search of the resident engine under a device cap of `cap` rows,
+    as arithmetic on real key sets: the plain reference's own successor
+    function (an int64 a state: exact keys) with the engine's rule.  A level
+    whose candidates do not fit beside the hot keys (`seen_count + candidates
+    > SC`) spills ALL hot keys as one cold run and runs again against an
+    empty table; the table then admits every candidate it has not seen
+    itself (a cold duplicate too: it sits in the table AND in a run from
+    then on); once a run exists the host probes each level's device-new
+    keys against the runs and drops the duplicates before they are counted
+    or explored.  The levels come back as [frontier, candidates,
+    device-new, new]."""
+    ref = _reference()
+    codec = ref._Codec(procs, max_money)
+    frontier = hot = np.unique(ref._init_states(codec))
+    runs, out = [], {"spills": [], "redone_rows": 0, "keys_probed": 0,
+                     "keys_dropped": 0, "levels": []}
+    generated = distinct = int(frontier.size)
+    depth = 0
+    while True:
+        succ = ref._successors(codec, frontier)
+        generated += int(succ.size)
+        if hot.size + succ.size > cap and hot.size:
+            runs.append(hot)
+            out["spills"].append([depth, int(hot.size)])
+            out["redone_rows"] += int(succ.size)
+            hot = np.empty(0, np.int64)
+        assert hot.size + succ.size <= cap, "the cap would be breached"
+        cand = np.unique(succ)
+        device_new = new = cand[~np.isin(cand, hot, assume_unique=True)]
+        hot = np.union1d(hot, device_new)
+        if runs and device_new.size:
+            dup = np.isin(device_new, np.concatenate(runs))
+            out["keys_probed"] += int(device_new.size)
+            out["keys_dropped"] += int(dup.sum())
+            new = device_new[~dup]
+        out["levels"].append([int(frontier.size), int(succ.size),
+                              int(device_new.size), int(new.size)])
+        distinct += int(new.size)
+        if not new.size:
+            break
+        frontier = new
+        depth += 1
+    cold = np.concatenate(runs) if runs else np.empty(0, np.int64)
+    out.update(generated=generated, distinct=distinct, diameter=depth,
+               spilled_keys=int(cold.size), cold_keys=int(cold.size),
+               cold_distinct=int(np.unique(cold).size),
+               hot_keys_at_end=int(hot.size))
+    return out
+
+
 def test_there_are_resident_pins():
     assert {"transfer_scaled", "transfer_scaled_4p8",
-            "transfer_scaled_4p"} <= set(RESIDENT_PINS)
+            "transfer_scaled_4p", "transfer_scaled_4p8_ooc"} \
+        <= set(RESIDENT_PINS)
 
 
 @pytest.mark.parametrize("name", RESIDENT_PINS)
@@ -104,7 +184,18 @@ def test_res_caps_are_the_ladder_steps_that_hold_the_levels(name):
     initial = pins["distinct"] - sum(new for _, _, new in levels)
     assert initial == levels[0][0]
     assert caps["VC"] == DEFAULTS["VC"]
-    need = _needs(levels, initial, caps["VC"])
+    cap = pins.get("seen_cap")
+    if cap is not None:
+        # under a cap the table holds a level's DEVICE-new rows, its cold
+        # duplicates too: the capacities follow those levels
+        sim = _cold_spills(pins["procs"], pins["max_money"], cap)
+        assert [[f, c, n] for f, c, _, n in sim["levels"]] == levels
+        levels = [[f, c, dn] for f, c, dn, _ in sim["levels"]]
+        # the cap IS the table, and the smallest power of two that is not
+        # breached: it seats the widest level beside an empty table
+        assert caps["SC"] == cap
+        assert cap // 2 < max(c for _, c, _ in levels) <= cap
+    need = _needs(levels, initial, caps["VC"], cap)
     for key in ("SC", "FCap", "AccCap"):
         assert need[key] <= caps[key], (key, need[key])
         # a step of the x4 ladder from the default, and the smallest
@@ -112,7 +203,7 @@ def test_res_caps_are_the_ladder_steps_that_hold_the_levels(name):
     # the engine's own invariants (`_run_resident`)
     assert caps["AccCap"] >= max(2 * caps["VC"], caps["FCap"])
     # and what one cold run from the defaults leaves
-    assert _cold_ladder(levels, initial, DEFAULTS)[-1] == caps
+    assert _cold_ladder(levels, initial, DEFAULTS, cap)[-1] == caps
 
 
 def test_the_real_rungs_cold_ladder():
@@ -124,15 +215,6 @@ def test_the_real_rungs_cold_ladder():
     assert len(ladder) == 7
     assert ladder[1] == dict(DEFAULTS, FCap=1 << 18, AccCap=1 << 19)
     assert ladder[-1] == pins["res_caps"]
-
-
-def _reference():
-    spec = importlib.util.spec_from_file_location(
-        "plain_reference", os.path.join(REPO, "bench", "reference",
-                                        "transfer_scaled.py"))
-    reference = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(reference)
-    return reference
 
 
 def _toy_cfg(tmp_path, procs, max_money):
@@ -235,3 +317,85 @@ def test_resident_engine_in_the_deep_proportions(tmp_path, monkeypatch):
                                            for _, cand, _ in levels)
     assert c["search.seen_slots"] == len(levels) * caps["SC"]
     assert c["search.slots_sorted"] == len(levels) * caps["AccCap"]
+
+
+def test_the_ooc_cells_spill_schedule_as_arithmetic():
+    """`desk-ooc-4p8`: 4 procs / MaxMoney 8 under a cap of 2^20 rows
+    (ISSUE 32, Motivation 4).  Four spills at levels 4 to 7, exactly
+    `MAX_HOST_RUNS` runs (no compaction), inside the host budget (no disk);
+    the counts are the uncapped reference's."""
+    from jaxmc.backend.tiers import TieredSeen
+    pins = _pins("transfer_scaled_4p8_ooc")
+    sim = _cold_spills(pins["procs"], pins["max_money"], pins["seen_cap"])
+    assert (sim["generated"], sim["distinct"], sim["diameter"]) == \
+        (pins["generated"], pins["distinct"], pins["diameter"]) == \
+        (4767576, 1859252, 12)
+    for key, value in pins["tier"].items():
+        if key != "what":
+            assert sim[key] == value, key
+    assert sim["spills"] == [[4, 398976], [5, 329472], [6, 374504],
+                             [7, 341856]]
+    assert (sim["spilled_keys"], sim["redone_rows"], sim["keys_probed"],
+            sim["keys_dropped"]) == (1444808, 3367456, 1470128, 9852)
+    assert len(sim["spills"]) == TieredSeen.MAX_HOST_RUNS
+    assert sim["cold_keys"] <= 1 << 22  # TieredSeen's default host budget
+    # the tables admitted every distinct key once and every cold duplicate
+    # once more (it sits in a run and in a later table: in a second run if
+    # that table was spilled too, else hot at the end)
+    assert sim["cold_keys"] + sim["hot_keys_at_end"] \
+        == sim["distinct"] + sim["keys_dropped"]
+    # a quarter of the states (ROADMAP B6's first cap) cannot hold
+    with pytest.raises(AssertionError, match="breached"):
+        _cold_spills(pins["procs"], pins["max_money"], 1 << 19)
+
+
+def test_the_engine_spills_as_the_arithmetic_says(tmp_path):
+    """The resident engine at 4 procs / MaxMoney 2 under a cap of 2^12
+    rows: three spills, 67 cold duplicates, and every `tier.*` counter
+    equal to `_cold_spills`, on two searches of one session."""
+    pytest.importorskip("jax")
+    sim = _cold_spills(4, 2, 1 << 12)
+    assert sim["spills"] == [[4, 1728], [5, 1452], [6, 1499]]
+    assert (sim["redone_rows"], sim["keys_probed"], sim["keys_dropped"],
+            sim["cold_keys"]) == (11015, 5632, 67, 4679)
+    caps = {"SC": 1 << 12, "FCap": 1 << 11, "AccCap": 1 << 13, "VC": 256}
+    need = _needs([[f, c, dn] for f, c, dn, _ in sim["levels"]],
+                  sim["levels"][0][0], caps["VC"], 1 << 12)
+    assert all(need[k] <= caps[k] for k in need)
+    tel = obs.Telemetry()
+    with obs.use(tel):
+        sess = CheckSession(SessionConfig(
+            spec=TRANSFER, cfg=_toy_cfg(tmp_path, 4, 2), backend="jax",
+            platform="cpu", resident=True, no_trace=True, res_caps=caps,
+            seen_cap=1 << 12, chunk=64), tel=tel)
+        for _ in range(2):
+            before = dict(tel.counters)
+            spans = {p["name"]: p["count"] for p in tel.phase_list()}
+            res = sess.explore()
+            assert (res.generated, res.distinct, res.diameter, res.ok) == \
+                (sim["generated"], sim["distinct"], sim["diameter"], True)
+            rise = {k: v - before.get(k, 0) for k, v in tel.counters.items()}
+            assert rise["tier.spills"] == len(sim["spills"])
+            for key in ("spilled_keys", "redone_rows", "keys_probed",
+                        "keys_dropped"):
+                assert rise["tier." + key] == sim[key], key
+            assert tel.gauges["tier.occupancy"]["host"] == sim["cold_keys"]
+            assert res.tiers["host_keys"] == sim["cold_keys"]
+            # `search.*` keep their meaning: new rows after the cold
+            # duplicates are taken off, generated rows once
+            assert rise["search.rows_new"] == \
+                sim["distinct"] - sim["levels"][0][0]
+            assert rise["search.rows_valid"] == \
+                sim["generated"] - sim["levels"][0][0]
+            # one span a spill; pull, keys and probe a probed level; a
+            # push where a level had cold duplicates
+            count = {p["name"]: p["count"] - spans.get(p["name"], 0)
+                     for p in tel.phase_list()}
+            probes = sum(1 for lv in sim["levels"][4:] if lv[2])
+            assert count["tier.spill"] == len(sim["spills"])
+            assert count["tier.pull"] == count["tier.keys"] == \
+                count["tier.probe"] == probes
+            assert count["tier.push"] == sum(
+                1 for lv in sim["levels"] if lv[2] != lv[3])
+        assert sess.engine._res_caps == caps  # nothing grew
+    assert "tier.cap_breached" not in tel.gauges

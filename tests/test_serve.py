@@ -234,6 +234,30 @@ class TestWarmReuse:
         # search span lands in THIS job's recorder, not the cold job's
         assert "search" in {p["name"] for p in res2["phases"]}
 
+    def test_warm_resubmission_under_a_seen_cap(self, daemon, monkeypatch,
+                                                tmp_path):
+        # ISSUE 32: cold tiers are state of ONE search.  A capped job
+        # spills; the identical job on the warm session resumes the
+        # first's final checkpoint (which carries the cold runs) on an
+        # engine that has searched before, and must answer the same
+        monkeypatch.setenv("JAXMC_PROFILE_STORE",
+                           str(tmp_path / "profiles"))
+        monkeypatch.setenv("JAXMC_SEEN_CAP", "512")
+        c = client(daemon)
+        answers = []
+        for _ in range(3):
+            _, j = c.submit(spec("ooc_scaled"), options=JAX_OPTS)
+            r = c.wait(j["id"], timeout=240)
+            assert r["status"] == "done", r
+            _, res = c.result(j["id"])
+            # the fresh search spilled; a resume holds what the
+            # checkpoint carried, whatever the engine searched before
+            assert res["gauges"]["tier.occupancy"]["host"] == 2784
+            answers.append((r["generated"], r["distinct"],
+                            res["serve"]["warm_engine"]))
+        assert answers == [(12289, 3072, False), (12289, 3072, True),
+                           (12289, 3072, True)]
+
     def test_warm_second_submission_jax_level_mode(self, daemon):
         # the DEFAULT device mode (level, traces on) also finalizes a
         # checkpoint on completion: a repeat submission must warm-resume

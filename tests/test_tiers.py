@@ -273,6 +273,58 @@ class TestTieredSeen:
                 if p not in p2.get("disk_paths", [])]
         assert gone and all(not os.path.exists(p) for p in gone)
 
+    def test_reset_retires_ckpt_referenced_runs(self, tmp_path,
+                                                monkeypatch):
+        # cold tiers last ONE search (ISSUE 32): reset() forgets every
+        # run, but the run files a path-mode checkpoint references are
+        # its only copy — they stay on disk, RETIRED, until the next
+        # search's dump() supersedes the reference; then they go.  One
+        # set of run files per re-explored search must not pile up
+        monkeypatch.setenv("JAXMC_TIER_CKPT_INLINE_KEYS", "0")
+        rng = np.random.default_rng(37)
+        t = self._store(tmp_path, budget=32)
+        spill = str(tmp_path / "spill")
+        listings = []
+        for search in range(3):
+            rows = []
+            for _ in range(3):
+                r, _ = _rand_runs(rng, 60, 0, kd=self.KD)
+                t.spill(r)
+                rows.append(r)
+            payload = t.dump()
+            assert sorted(payload["disk_paths"]) == sorted(
+                os.path.join(spill, f) for f in os.listdir(spill))
+            listings.append(sorted(os.listdir(spill)))
+            t.reset()
+            assert not t.active and len(t) == 0 and t.spills == 0
+            # the checkpoint of the search that just ended still resumes
+            t_ck = TieredSeen(self.KD, host_budget_keys=32)
+            t_ck.load(payload)
+            assert t_ck.probe(np.unique(np.vstack(rows), axis=0)).all()
+        assert len({len(ls) for ls in listings}) == 1, listings
+        assert not set(listings[0]) & set(listings[2])
+        # nothing referenced any more: a reset leaves the directory empty
+        t._ckpt_refs = set()
+        t.reset()
+        assert os.listdir(spill) == []
+
+    def test_reset_tries_the_disk_again(self, tmp_path, monkeypatch):
+        # io_degraded is state of one search too: the failed search's
+        # files are gone after reset(), so the next search flushes again
+        monkeypatch.setenv("JAXMC_FAULTS", "tier_io_error:op=write")
+        faults._CACHE = None
+        rng = np.random.default_rng(41)
+        t = self._store(tmp_path, budget=32)
+        r, _ = _rand_runs(rng, 60, 0, kd=self.KD)
+        t.spill(r)
+        assert t.io_degraded and t.disk_keys == 0
+        monkeypatch.delenv("JAXMC_FAULTS")
+        faults._CACHE = None
+        t.reset()
+        assert t.io_degraded is None and "io_degraded" not in t.stats()
+        t.spill(r)
+        assert t.io_degraded is None and t.disk_keys == len(r)
+
     def test_spill_shape_mismatch_rejected(self, tmp_path):
         t = self._store(tmp_path)
         with pytest.raises(ValueError, match="key_words"):
@@ -376,6 +428,201 @@ class TestCappedExhaustive:
         assert (res.generated, res.distinct) == OOC_WANT
         assert res.tiers and res.tiers.get("io_degraded")
         assert res.tiers["disk_keys"] == 0
+
+
+# ------------------------------------------ cold tiers last ONE search
+
+TRANSFER = os.path.join(REPO, "bench", "specs", "transfer_scaled.tla")
+#: 4 procs / MaxMoney 2: 19,101 generated / 7,293 distinct / 13 levels, the
+#: cell desk-ooc-4p8's model at a size XLA:CPU answers in seconds
+TOY = (4, 2)
+#: the resident engine at this cap spills three times and drops 67 cold
+#: duplicates (tests/test_bench_pins.py has the arithmetic)
+TOY_CAPS = {"SC": 4096, "FCap": 2048, "AccCap": 8192, "VC": 256}
+#: per engine: the model's size, the session options and a cap that makes
+#: it spill and drop cold duplicates without a breach (the level engine must
+#: seat its whole candidate BLOCK, A x FC; the mesh compiles longest, so it
+#: gets 3 procs / MaxMoney 3: 4,963 / 2,455)
+ENGINES = {
+    "level": (TOY, dict(), 1 << 15),
+    "resident": (TOY, dict(resident=True, no_trace=True, res_caps=TOY_CAPS,
+                           chunk=64), TOY_CAPS["SC"]),
+    "mesh": ((3, 3), dict(devices=2, no_trace=True), 1 << 11),
+}
+
+
+def _reference():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "plain_reference", os.path.join(REPO, "bench", "reference",
+                                        "transfer_scaled.py"))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+    return reference
+
+
+def _toy_cfg_text(procs, max_money):
+    return ("SPECIFICATION Spec\nINVARIANT AliceBounded\nCONSTANTS\n"
+            "  Procs = {%s}\n  MaxMoney = %d\n"
+            % (", ".join("p%d" % (i + 1) for i in range(procs)), max_money))
+
+
+def _toy_session(tmp_path, tel, size=TOY, **kw):
+    from jaxmc.session import CheckSession, SessionConfig
+    cfg = tmp_path / "toy.cfg"
+    cfg.write_text(_toy_cfg_text(*size))
+    return CheckSession(SessionConfig(
+        spec=TRANSFER, cfg=str(cfg), backend="jax", platform="cpu", **kw),
+        tel=tel)
+
+
+def _answer(res):
+    return (res.generated, res.distinct, res.diameter, res.ok,
+            res.truncated)
+
+
+class TestColdTiersLastOneSearch:
+    """ISSUE 32: `explore()` is re-runnable on one session (PR 7) and the
+    cold tiers (ISSUE 12) outlived the search that filled them: the second
+    search of a capped session probed its frontier against the FIRST
+    search's states, dropped all of it and answered `ok` with one distinct
+    state."""
+
+    @pytest.mark.parametrize("engine", sorted(ENGINES))
+    def test_reexplore_on_one_session_keeps_counts(self, engine, tmp_path,
+                                                   monkeypatch):
+        monkeypatch.setenv("JAXMC_CAP_PROFILE", "0")
+        size, opts, cap = ENGINES[engine]
+        want = _reference().explore(*size)
+        want = (want["generated"], want["distinct"], want["diameter"],
+                True, False)
+        tel = obs.Telemetry()
+        with obs.use(tel):
+            uncapped = _toy_session(tmp_path, tel, size, **opts).explore()
+            assert _answer(uncapped) == want and uncapped.tiers is None
+            sess = _toy_session(tmp_path, tel, size, seen_cap=cap, **opts)
+            firsts = None
+            for i in range(3):
+                before = dict(tel.counters)
+                res = sess.explore()
+                assert _answer(res) == want, (engine, i)
+                assert res.tiers and res.tiers["spills"] > 0
+                assert "cap_breached" not in res.tiers
+                rise = {k: v - before.get(k, 0)
+                        for k, v in tel.counters.items()
+                        if k.startswith("tier.")}
+                assert rise["tier.spills"] == res.tiers["spills"]
+                assert rise["tier.spilled_keys"] == \
+                    res.tiers["host_keys"] == \
+                    tel.gauges["tier.occupancy"]["host"]
+                assert rise["tier.keys_probed"] > \
+                    rise["tier.keys_dropped"] > 0
+                # every search does what the first did: same spills, same
+                # probes, nothing carried over
+                firsts = firsts or rise
+                assert rise == firsts, (engine, i)
+        assert "tier.cap_breached" not in tel.gauges
+
+    def test_resume_keeps_its_cold_tiers(self, tmp_path, monkeypatch):
+        """The checkpoint path is NOT reset: an engine that has searched
+        (and spilled) before, resumed from a truncation checkpoint written
+        after the first spill, probes the checkpoint's run again."""
+        monkeypatch.setenv("JAXMC_CAP_PROFILE", "0")
+        from jaxmc.backend.bfs import TpuExplorer
+        want = _reference().explore(*TOY)
+        mod = Loader([os.path.dirname(TRANSFER)]).load_path(TRANSFER)
+        ck = str(tmp_path / "toy.ck")
+        ex = TpuExplorer(bind_model(mod, parse_cfg(_toy_cfg_text(*TOY))),
+                         resident=True, store_trace=False, chunk=64,
+                         res_caps=TOY_CAPS, seen_cap=TOY_CAPS["SC"],
+                         max_states=3000, checkpoint_path=ck)
+        cut = ex.run()
+        # level 4 spilled 1,728 keys and committed 3,180 distinct states
+        assert cut.truncated and cut.distinct == 3180
+        assert cut.tiers["spills"] == 1 and cut.tiers["host_keys"] == 1728
+        ex.max_states, ex.checkpoint_path, ex.resume_from = None, None, ck
+        res = ex.run()
+        assert _answer(res) == (want["generated"], want["distinct"],
+                                want["diameter"], True, False)
+        # the checkpoint's run and the two spills after it
+        assert res.tiers["spills"] == 3 and res.tiers["host_keys"] == 4679
+        ex.resume_from = None
+        again = ex.run()
+        assert _answer(again) == _answer(res)
+        assert again.tiers["spills"] == 3
+
+    def test_soft_breach_is_named(self, tmp_path, monkeypatch):
+        """A cap below one level's candidates cannot hold: the table grows
+        past it, and says so in a gauge and in `result.tiers` on every
+        search, not only in a log line."""
+        monkeypatch.setenv("JAXMC_CAP_PROFILE", "0")
+        want = _reference().explore(*TOY)
+        widest = max(cand for _, cand, _ in want["levels"])
+        _, opts, _ = ENGINES["resident"]
+        tel = obs.Telemetry()
+        with obs.use(tel):
+            sess = _toy_session(tmp_path, tel, seen_cap=2048, **dict(
+                opts, res_caps=dict(TOY_CAPS, SC=2048)))
+            assert widest > 2048
+            for _ in range(2):
+                res = sess.explore()
+                assert (res.generated, res.distinct, res.diameter) == \
+                    (want["generated"], want["distinct"], want["diameter"])
+                assert res.tiers["cap_breached"] == 8192 >= widest
+                assert tel.gauges["tier.cap_breached"] == 8192
+                assert tel.gauges["tier.device_cap"] == 2048
+
+
+    def test_the_capped_tables_are_made_on_the_device(self, tmp_path,
+                                                      monkeypatch):
+        """`_device_table` is the host-built table, row for row; a capped
+        resident search hands the device its init rows alone where the
+        uncapped one builds both tables on the host at full capacity."""
+        monkeypatch.setenv("JAXMC_CAP_PROFILE", "0")
+        from jaxmc.backend.bfs import SENTINEL, TpuExplorer
+        head = np.arange(12, dtype=np.int32).reshape(4, 3)
+        want = np.full((16, 3), SENTINEL, np.int32)
+        want[:4] = head
+        assert np.array_equal(TpuExplorer._device_table((16, 3), head), want)
+        want[:4] = SENTINEL
+        for none in (None, head[:0]):
+            assert np.array_equal(
+                TpuExplorer._device_table((16, 3), none), want)
+        _, opts, cap = ENGINES["resident"]
+        seed = {}
+        for name, kw in (("host", {}), ("device", {"seen_cap": cap})):
+            tel = obs.Telemetry()
+            with obs.use(tel):
+                sess = _toy_session(tmp_path, tel, **opts, **kw)
+                sess.explore()
+                ex = sess.engine
+            seed[name] = tel.counters["search.seed_bytes"]
+        full = 4 * (TOY_CAPS["SC"] * ex.K + TOY_CAPS["FCap"] * ex.PW)
+        assert seed["host"] == full
+        assert 0 < seed["device"] < full // 16
+        assert seed["device"] % (4 * (ex.K + ex.PW)) == 0
+
+    def test_reexplore_leaks_no_run_files(self, tmp_path, monkeypatch):
+        """A re-explored search whose disk tier is past the checkpoint's
+        inline budget leaves the run files of ITS checkpoint in the spill
+        directory and no others: the set before it goes when its own
+        checkpoint supersedes the reference."""
+        monkeypatch.setenv("JAXMC_CAP_PROFILE", "0")
+        monkeypatch.setenv("JAXMC_TIER_CKPT_INLINE_KEYS", "0")
+        from jaxmc.backend.bfs import TpuExplorer
+        ex = TpuExplorer(load("ooc_scaled"), resident=True, chunk=256,
+                         checkpoint_path=str(tmp_path / "ooc.ck"),
+                         final_checkpoint=True, **_capped_kw(tmp_path))
+        spill = str(tmp_path / "spill")
+        listings = []
+        for _ in range(3):
+            res = ex.run()
+            assert res.ok and not res.truncated
+            assert (res.generated, res.distinct) == OOC_WANT
+            assert res.tiers["disk_keys"] > 0
+            listings.append(sorted(os.listdir(spill)))
+            assert len(listings[-1]) == res.tiers["disk_runs"], listings
+        assert not set(listings[0]) & set(listings[1])
 
 
 class TestTruncationAttribution:
