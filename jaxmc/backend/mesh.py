@@ -160,6 +160,13 @@ _A_ASRTF = 6
 _NA = 7
 
 
+def _invalid_row(width: int) -> np.ndarray:
+    """The row that marks an empty slot of a key or payload table:
+    validity lane 1 (sorts last), every data lane SENTINEL."""
+    return np.concatenate(
+        [np.ones(1, np.int32), np.full(width - 1, SENTINEL, np.int32)])
+
+
 def _nbytes(*tables) -> int:
     """Bytes of the sharded tables given, over all shards (None: the
     trace ring of a --no-trace run)."""
@@ -478,8 +485,7 @@ class MeshExplorer(TpuExplorer):
         larger gamma."""
         D, K, PW = self.D, self.K, self.PW
         Pw = K + PW + 1  # a2a payload: [keys | packed row | src-index]
-        invalid_row_np = np.concatenate(
-            [np.ones(1, np.int32), np.full(Pw - 1, SENTINEL, np.int32)])
+        invalid_row_np = _invalid_row(Pw)
 
         @jax.named_scope("jaxmc.mesh.route")
         def place(ckeys, cand, cvalid, me):
@@ -547,8 +553,7 @@ class MeshExplorer(TpuExplorer):
         D, K, PW = self.D, self.K, self.PW
         a2a = self.exchange == "a2a"
         Pw = K + PW + 1  # a2a payload: [keys | packed row | src-index]
-        invalid_key_np = np.concatenate(
-            [np.ones(1, np.int32), np.full(K - 1, SENTINEL, np.int32)])
+        invalid_key_np = _invalid_row(K)
         if not a2a:
             R = D * C
 
@@ -615,6 +620,13 @@ class MeshExplorer(TpuExplorer):
             return D * D * (B + SB) * (K + PW + 1) * 4
         return D * D * C * (K + PW) * 4
 
+    @property
+    def _finish_form(self) -> str:
+        """`prefix` | `scatter`: how _merge_finish_fn compacts the
+        explore-kept rows — decided by whether the model has a
+        CONSTRAINT, nothing else (gauge mesh.finish_form)."""
+        return "scatter" if self.constraint_fns else "prefix"
+
     def _merge_finish_fn(self, R: int):
         """Shared merge epilogue: constraint-mask the compacted new
         rows and compact the explore-kept ones to the frontier front.
@@ -622,21 +634,44 @@ class MeshExplorer(TpuExplorer):
         seen shard but are discarded — not distinct, not checked, not
         explored (TLC semantics, testout2:265).
 
-        The compaction is a cumsum-rank scatter since ISSUE 11 (the
-        1-key stable sort it replaces was a measurable slice of the
-        merge wall at mesh shapes); the kept-row ORDER is identical —
-        cumsum ranks preserve the input order exactly like the stable
-        sort did — and the tail is SENTINEL rows / -0 src, which every
-        consumer already masks by front_count."""
+        Two forms, chosen here from the model alone (ISSUE 33):
+
+        * `prefix` — no CONSTRAINT in the cfg: the kept rows are
+          `arange(R) < new_count`, ONE run that already sits at the
+          front, so the compaction is the identity and only the tail
+          past front_count is put in the empty form (SENTINEL rows, 0
+          src).  No scatter.
+        * `scatter` — with a CONSTRAINT the kept rows are not a run:
+          the cumsum-rank scatter of ISSUE 11 (the 1-key stable sort it
+          replaced was a measurable slice of the merge wall at mesh
+          shapes).  The kept-row ORDER is the input order, as the
+          stable sort's was.
+
+        Both leave the same four outputs to the bit where both apply;
+        the tail is SENTINEL rows / 0 src, which every consumer
+        already masks by front_count."""
         plan = self.plan
         con_fns = self.constraint_fns
         inv_fns = self.inv_fns
 
         @jax.named_scope("jaxmc.compact")
-        def finish(new_rows, new_src, nvalid):
+        def finish_prefix(new_rows, new_src, nvalid):
+            # new_rows is already SENTINEL past new_count (the caller's
+            # take is masked); the unpacked twin and src are not
+            front_rows_u = new_rows
+            if inv_fns:
+                with jax.named_scope("jaxmc.scan"):
+                    front_rows_u = jnp.where(
+                        nvalid[:, None], plan.unpack_rows(new_rows),
+                        SENTINEL)
+            front_src = jnp.where(nvalid, new_src, 0)
+            return (new_rows, front_rows_u, front_src,
+                    jnp.sum(nvalid, dtype=jnp.int32))
+
+        @jax.named_scope("jaxmc.compact")
+        def finish_scatter(new_rows, new_src, nvalid):
             with jax.named_scope("jaxmc.scan"):
-                new_rows_u = plan.unpack_rows(new_rows) \
-                    if (con_fns or inv_fns) else new_rows
+                new_rows_u = plan.unpack_rows(new_rows)
                 explore = nvalid
                 for nm, f in con_fns:
                     explore = explore & jax.vmap(f)(new_rows_u)
@@ -655,10 +690,111 @@ class MeshExplorer(TpuExplorer):
             front_count = jnp.sum(explore)
             return front_rows, front_rows_u, front_src, front_count
 
-        return finish
+        return finish_scatter if con_fns else finish_prefix
 
-    def _merge_rank_fn(self, SC: int, R: int,
-                       VC: Optional[int] = None) -> Callable:
+    @property
+    def _compact_form(self) -> str:
+        """`runs` | `scatter`: how _merge_rank_fn compacts the valid
+        received rows — decided by the exchange's kind, nothing else
+        (gauge mesh.compact_form)."""
+        return "runs" if self.exchange == "a2a" else "scatter"
+
+    def _compact_runs_fn(self, B: int, SB: int, VC: int) -> Callable:
+        """The a2a receiver's valid-candidate compaction (ISSUE 33):
+        compact(gkeys [R,K], gcand [R,PW], gsrc [R]) -> (ckeys [VC,K],
+        ccand [VC,PW], csrc [VC], v_need) — the valid rows of the
+        received block in received order, then the empty form
+        ([1, SENTINEL...] / SENTINEL / 0).
+
+        The receiver moves RUNS, not rows.  The block _route_fn's
+        swap() hands over is D buckets of B rows and then D spill
+        buckets of SB rows, and each of those 2*D segments left the
+        sender's place() as its `live` valid rows followed by invalid
+        rows — a valid prefix and padding, by construction.  So the
+        stable compaction is the concatenation of 2*D prefixes: segment
+        s goes, as ONE slice of its first min(len, VC) rows, to the
+        offset that is the valid count of the segments before it, and
+        each later segment overwrites the padding the previous one
+        brought along — never a per-row scatter (5-10 ns a received
+        slot on the TPU v5e, ~94% of them dropped; PERF.md §6, PR 33).
+        The rows a segment carries past its count are already in the
+        empty form, so nothing is masked but the src column (its
+        padding is SENTINEL on the wire, 0 in the block).
+
+        v_need > VC rolls the level back (the caller's v_ovf), so what
+        the block holds then does not matter; offsets are capped at VC,
+        where the buffer keeps a segment's length of slack that the
+        crop drops — no start is ever clamped back into live rows."""
+        D = self.D
+        segs = [(d * B, B) for d in range(D)] + \
+            [(D * B + d * SB, SB) for d in range(D)]
+        # what is cut out of a segment: its first min(len, VC) rows
+        cuts = [(s, min(n, VC)) for s, n in segs]
+        slack = max(n for _, n in cuts)
+
+        def compact(gkeys, gcand, gsrc):
+            gvalid = gkeys[:, 0] == 0
+            counts = jnp.stack([
+                jnp.sum(gvalid[s:s + n], dtype=jnp.int32)
+                for s, n in segs])
+            v_need = jnp.sum(counts)
+            offs = jnp.minimum(jnp.cumsum(counts) - counts, VC)
+
+            def runs(col, empty):
+                buf = jnp.broadcast_to(
+                    jnp.asarray(empty, jnp.int32),
+                    (VC + slack,) + col.shape[1:])
+                for i, (s, n) in enumerate(cuts):
+                    buf = lax.dynamic_update_slice_in_dim(
+                        buf, col[s:s + n], offs[i], 0)
+                return buf[:VC]
+
+            # the packed rows go lane by lane: their consumer is a row
+            # gather, which made XLA:TPU lay a [VC + slack, PW] buffer
+            # out row-major, PW lanes padded to 128, and every slice
+            # paid for it (PERF.md §6, PR 33); a 1-D lane has one
+            # layout, and the stack is one pass over VC rows
+            ccand = jnp.stack([runs(gcand[:, j], SENTINEL)
+                               for j in range(gcand.shape[1])], axis=1)
+            csrc = jnp.where(jnp.arange(VC, dtype=jnp.int32) < v_need,
+                             runs(gsrc, 0), 0)
+            return (runs(gkeys, _invalid_row(gkeys.shape[1])), ccand,
+                    csrc, v_need)
+
+        return compact
+
+    def _compact_scatter_fn(self, R: int, VC: int) -> Callable:
+        """The valid-candidate compaction of a block with no run
+        structure (the `gather` exchange: all D*C candidate rows,
+        masked by owner): a cumsum-rank scatter — stable, so candidate
+        order and therefore counts/traces are those of the uncompacted
+        sort.  Same signature and output as _compact_runs_fn."""
+        K, PW = self.K, self.PW
+
+        def compact(gkeys, gcand, gsrc):
+            gvalid = gkeys[:, 0] == 0
+            v_need = jnp.sum(gvalid, dtype=jnp.int32)
+            pos = jnp.cumsum(gvalid.astype(jnp.int32)) - 1
+            # invalid rows park at R+i: distinct, >= VC (dropped), and
+            # disjoint from every valid pos (pos <= R-1) even when
+            # v_need > VC — duplicate indices, dropped or not, would
+            # break the unique_indices promise below
+            tgt = jnp.where(gvalid, pos,
+                            R + jnp.arange(R, dtype=jnp.int32))
+            ck = jnp.full((VC, K), SENTINEL, jnp.int32)
+            ck = ck.at[:, 0].set(1)  # empty: validity lane 1
+            ckeys = ck.at[tgt].set(gkeys, mode="drop",
+                                   unique_indices=True)
+            ccand = jnp.full((VC, PW), SENTINEL, jnp.int32) \
+                .at[tgt].set(gcand, mode="drop", unique_indices=True)
+            csrc = jnp.zeros((VC,), jnp.int32) \
+                .at[tgt].set(gsrc, mode="drop", unique_indices=True)
+            return ckeys, ccand, csrc, v_need
+
+        return compact
+
+    def _merge_rank_fn(self, SC: int, R: int, VC: Optional[int] = None,
+                       B: int = 0, SB: int = 0) -> Callable:
         """The shard-local merge-dedup of every step builder:
         (seen_keys [SC,K], seen_count scalar, gkeys [R,K], gcand [R,PW],
         gsrc [R]) -> dict(seen2, seen_count2, front_rows, front_rows_u,
@@ -668,9 +804,9 @@ class MeshExplorer(TpuExplorer):
         O(new) and O(valid): the exchanged block is ~95% masked padding
         (its 5-key sort over all R rows was 11.6s of a 25s step wall on
         transfer_scaled D=1 — XLA:CPU, MULTICHIP_r07), so the valid rows are
-        first compacted to a [VC]-bounded block (cumsum-rank scatter —
-        stable, so candidate order and therefore counts/traces are
-        unchanged), then only those keys are sorted, deduped against
+        first compacted to a [VC]-bounded block — stably, so candidate
+        order and therefore counts/traces are unchanged — then only
+        those keys are sorted, deduped against
         the seen shard's sorted valid prefix with binary searches and
         merged in by rank (row gathers into the blocks of the shard's
         table that hold a live row after the level: each shard bounds
@@ -682,6 +818,15 @@ class MeshExplorer(TpuExplorer):
         constraint-discarded states stay fingerprinted but are never
         counted, checked or explored (TLC semantics).
 
+        The compaction has two forms, chosen from the exchange's kind
+        when the program is built (_compact_form; ISSUE 33): after an
+        a2a exchange the received block is 2*D valid prefixes of known
+        segments (`B`, `SB`: _route_fn's), and the block is built from
+        2*D slices (`runs`, _compact_runs_fn); the gather exchange's
+        block has no such structure and keeps the cumsum-rank scatter
+        (`scatter`, _compact_scatter_fn).  The output block is the
+        same to the bit.
+
         `VC` is the valid-candidate capacity: overflow (`v_ovf`, with
         `v_need` the true count) rolls the level back so the caller
         can grow VC and redo — same contract as every other mesh
@@ -691,38 +836,23 @@ class MeshExplorer(TpuExplorer):
         host loop for PROPERTYs, and multihost.py), which has no
         grow-and-redo for it; VC >= R has nothing to compact
         either."""
-        K, PW = self.K, self.PW
         compact = VC is not None and VC < R
         N = VC if compact else R
         finish = self._merge_finish_fn(N)
+        if compact:
+            compact_fn = self._compact_runs_fn(B, SB, VC) \
+                if self._compact_form == "runs" \
+                else self._compact_scatter_fn(R, VC)
 
         def merge(seen_keys, seen_count, gkeys, gcand, gsrc):
             v_ovf = jnp.asarray(False)
             v_need = jnp.asarray(0, jnp.int32)
             if compact:
                 with jax.named_scope("jaxmc.compact"):
-                    gvalid = gkeys[:, 0] == 0
-                    v_need = jnp.sum(gvalid, dtype=jnp.int32)
+                    gkeys, gcand, gsrc, v_need = compact_fn(
+                        gkeys, gcand, gsrc)
                     v_ovf = v_need > VC
-                    pos = jnp.cumsum(gvalid.astype(jnp.int32)) - 1
-                    # invalid rows park at R+i: distinct, >= VC
-                    # (dropped), and disjoint from every valid pos
-                    # (pos <= R-1) even when v_need > VC — duplicate
-                    # indices, dropped or not, would break the
-                    # unique_indices promise below
-                    tgt = jnp.where(gvalid, pos,
-                                    R + jnp.arange(R, dtype=jnp.int32))
-                    ck = jnp.full((VC, K), SENTINEL, jnp.int32)
-                    ck = ck.at[:, 0].set(1)  # empty: validity lane 1
-                    gkeys = ck.at[tgt].set(gkeys, mode="drop",
-                                           unique_indices=True)
-                    gcand = jnp.full((VC, PW), SENTINEL, jnp.int32) \
-                        .at[tgt].set(gcand, mode="drop",
-                                     unique_indices=True)
-                    gsrc = jnp.zeros((VC,), jnp.int32) \
-                        .at[tgt].set(gsrc, mode="drop",
-                                     unique_indices=True)
-            rm = _rank_merge(seen_keys, seen_count, gkeys, N, SC, K,
+            rm = _rank_merge(seen_keys, seen_count, gkeys, N, SC, self.K,
                              multikey=True)
             new_count = rm["new_count"]
             nvalid = jnp.arange(N) < new_count
@@ -1145,7 +1275,7 @@ class MeshExplorer(TpuExplorer):
         K, D, PW = self.K, self.D, self.PW
         plan = self.plan
         block_fn = self._candidate_block_fn(FC)
-        merge_fn = self._merge_rank_fn(SC, R, VC)
+        merge_fn = self._merge_rank_fn(SC, R, VC, B, SB)
         # N: the merge's compacted output block — the shapes every
         # post-merge consumer (inv scan, frontier crop) runs at
         N = self._merge_out_rows(R, VC)
@@ -1366,7 +1496,7 @@ class MeshExplorer(TpuExplorer):
         if key in self._mesh_step_cache:
             return self._mesh_step_cache[key]
         K, D, PW = self.K, self.D, self.PW
-        merge_fn = self._merge_rank_fn(SC, R, VC)
+        merge_fn = self._merge_rank_fn(SC, R, VC, B, SB)
         N = self._merge_out_rows(R, VC)
         check_deadlock = self.model.check_deadlock
         tail = self._mk_level_tail(SC, FC, TRL, N, route, merge_fn,
@@ -1645,6 +1775,11 @@ class MeshExplorer(TpuExplorer):
                  + (" [mesh_skew fault armed]" if self._skew else ""))
         tel = obs.current()
         tel.gauge("mesh.exchange", self.exchange)
+        # which form the merge's two compactions take (ISSUE 33); the
+        # host loop's legacy step compacts no valid candidates
+        if resident:
+            tel.gauge("mesh.compact_form", self._compact_form)
+        tel.gauge("mesh.finish_form", self._finish_form)
         tel.gauge("mesh.devices", self.D)
         # the mesh engine's own dedup stamp (ISSUE 10 satellite):
         # TpuExplorer.__init__ gauges dedup.mode BEFORE the mesh

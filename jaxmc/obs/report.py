@@ -350,6 +350,7 @@ def cmd_report(args, out=sys.stdout) -> int:
               "layout.packed_width_lanes", "layout.bits_per_state",
               "device.donation", "profile.status",
               "fingerprint.occupancy", "mesh.exchange", "mesh.devices",
+              "mesh.compact_form", "mesh.finish_form",
               "mesh.supersteps", "mesh.superstep_levels",
               "mesh.a2a_gamma", "mesh.a2a_spill", "mesh.a2a_max_bucket",
               "mesh.shard_balance",

@@ -179,7 +179,11 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
    resident multi-chip surface, tpu/mesh.py + jaxmc/meshbench.py:)
     - exchange strategy: gauges `mesh.exchange` ("a2a" | "gather"),
       `mesh.devices`; the strategy + gamma are also logged once per
-      run.
+      run.  Since PR 33 also `mesh.compact_form` ("runs" | "scatter":
+      the merge's valid-candidate compaction, by the exchange's kind;
+      resident loop only) and `mesh.finish_form` ("prefix" | "scatter":
+      the explore-kept compaction, by whether the cfg has a
+      CONSTRAINT).
     - resident-loop host traffic: counter `mesh.host_syncs` — one per
       level, counting the SINGLE replicated scalar-vector read the
       resident loop performs (on a clean run it EQUALS the level-record

@@ -13,7 +13,9 @@ collective, so it runs here without a mesh axis.
 
 Two structural guards: no scatter of any kind under `jaxmc.mesh.route`
 in the resident superstep (the walker is shown to have teeth on the
-merge's compaction, which still scatters rows under `jaxmc.compact`);
+merge's finish, which under this engine's CONSTRAINT still scatters the
+kept rows under `jaxmc.compact`; since ISSUE 33 the valid-candidate
+compaction ahead of it no longer does, tests/test_mesh_compact.py);
 and the forced-spill run of tests/test_mesh_resident.py, which since
 this issue also runs on four devices and holds its counts to the
 one-chip engine's."""
@@ -199,6 +201,6 @@ def test_the_superstep_routes_without_a_scatter():
     # the scope is there, with the sort, the gathers and the slices
     assert {"sort", "gather", "dynamic_slice"} <= set(route), set(route)
     assert not [p for p in route if p.startswith("scatter")], route
-    # the walk has teeth: the merge's valid-candidate compaction still
-    # scatters rows, under its own scope
+    # the walk has teeth: constoy's cfg has a CONSTRAINT, so the merge's
+    # finish still compacts the kept rows by scatter, under its own scope
     assert "scatter" in {p for stack, p in found if "jaxmc.compact" in stack}
