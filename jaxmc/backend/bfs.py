@@ -1857,7 +1857,7 @@ class TpuExplorer:
         jf = self._hostkeys_cache.get(cap)
         if jf is None:
             jf = obs.prof_wrap("bfs.host_keys", jax.jit(
-                lambda rows, valid: self._keys_of(rows, valid)))
+                lambda rows, valid: self._keys_of(rows, valid)), key=cap)
             self._hostkeys_cache[cap] = jf
         buf = np.repeat(np.asarray(rows_np[:1], np.int32), cap, axis=0)
         buf[:n] = rows_np
@@ -1946,7 +1946,7 @@ class TpuExplorer:
                 return keys_of(rows, valid)[0]
 
             self._pkeys_cache[cap] = jf = obs.prof_wrap(
-                "bfs.packed_keys", pk)
+                "bfs.packed_keys", pk, key=cap)
         buf = np.repeat(np.asarray(packed_np[:1], np.int32), cap, axis=0)
         buf[:n] = packed_np
         k = jf(jnp.asarray(buf), jnp.asarray(np.arange(cap) < n))
@@ -2161,7 +2161,7 @@ class TpuExplorer:
                 out["explore_all"] = exp_all
             return out
 
-        step = obs.prof_wrap("bfs.level_step", step)
+        step = obs.prof_wrap("bfs.level_step", step, key=key)
         self._step_cache[key] = step
         return step
 
@@ -2248,7 +2248,7 @@ class TpuExplorer:
 
         if not split:
             core_j = obs.prof_wrap("bfs.hstep",
-                                   jax.jit(self._hstep_core(FC)))
+                                   jax.jit(self._hstep_core(FC)), key=FC)
             cvec = self._cvec_jnp()
 
             def hstep(frontier_p, fcount):
@@ -2478,7 +2478,7 @@ class TpuExplorer:
                 return ok, ex_
 
             self._newcheck_cache[ckey] = jf = obs.prof_wrap(
-                "bfs.newcheck", chk)
+                "bfs.newcheck", chk, key=ckey)
         buf = np.repeat(rows_np[:1], cap, axis=0)
         buf[:n] = rows_np
         # the shared trace lock serializes first-call tracing of the
@@ -2850,7 +2850,7 @@ class TpuExplorer:
         # in place across dispatches instead of copying per batch
         donate = (0, 2) if self.donate else ()
         jitted = obs.prof_wrap("bfs.resident_run", jax.jit(
-            run, static_argnames=(), donate_argnums=donate))
+            run, static_argnames=(), donate_argnums=donate), key=key)
         self._res_cache[key] = jitted
         return jitted
 
@@ -3298,32 +3298,45 @@ class TpuExplorer:
 
         # packed init boundary: keys + packed rows in one pass; a pack
         # overflow at init is an observation gap (abort exactly)
+        # the host's pieces of the seed as seconds on the program's own
+        # clock (ISSUE 34; bench/SPANS.records.md): float counters, not
+        # spans — `seed.keys_s` the keys and their order, `seed.tables_s`
+        # the host-built tables, `seed.upload_s` the calls that hand
+        # them to the device, up to their return
         with tel.span("search.seed"):
-            init_keys, init_packed, init_povf = self._host_keys(init_rows)
+            with tel.timed("seed.keys_s"):
+                init_keys, init_packed, init_povf = \
+                    self._host_keys(init_rows)
             if init_povf:
                 return self._mk_result(
                     False, distinct, generated, 0, t0, warnings,
                     Violation("error", "capacity overflow", [],
                               self._pack_ovf_msg()))
-            fr_head = init_packed[explored_init]
-            order = np.lexsort(tuple(init_keys[:, i]
-                                     for i in reversed(range(K))))
-            seen_head = init_keys[order]
+            with tel.timed("seed.keys_s"):
+                fr_head = init_packed[explored_init]
+                order = np.lexsort(tuple(init_keys[:, i]
+                                         for i in reversed(range(K))))
+                seen_head = init_keys[order]
             if self.seen_cap is not None:
                 # capped: both tables made on the device (_device_table)
-                frontier = self._device_table(
-                    (caps["FCap"], self.PW), fr_head)
-                seen = self._device_table((caps["SC"], K), seen_head)
+                with tel.timed("seed.upload_s"):
+                    frontier = self._device_table(
+                        (caps["FCap"], self.PW), fr_head)
+                    seen = self._device_table((caps["SC"], K), seen_head)
                 tel.counter("search.seed_bytes",
                             fr_head.nbytes + seen_head.nbytes)
             else:
-                frontier = np.full((caps["FCap"], self.PW), SENTINEL,
-                                   np.int32)
-                frontier[:distinct] = fr_head
-                frontier = jnp.asarray(frontier)
-                seen = np.full((caps["SC"], K), SENTINEL, np.int32)
-                seen[:n_init] = seen_head
-                seen = jnp.asarray(seen)
+                with tel.timed("seed.tables_s"):
+                    frontier = np.full((caps["FCap"], self.PW), SENTINEL,
+                                       np.int32)
+                    frontier[:distinct] = fr_head
+                with tel.timed("seed.upload_s"):
+                    frontier = jnp.asarray(frontier)
+                with tel.timed("seed.tables_s"):
+                    seen = np.full((caps["SC"], K), SENTINEL, np.int32)
+                    seen[:n_init] = seen_head
+                with tel.timed("seed.upload_s"):
+                    seen = jnp.asarray(seen)
                 # what scale adds (ISSUE 30): both tables are built on
                 # the host at full capacity and uploaded, every search
                 tel.counter("search.seed_bytes",
@@ -3378,7 +3391,8 @@ class TpuExplorer:
                 return self._mk_result(True, distinct, generated,
                                        depth - 1, t0, warnings)
 
-        with tel.span("search.seed"):  # the scalar operands' uploads
+        # the scalar operands' uploads
+        with tel.span("search.seed"), tel.timed("seed.upload_s"):
             max_states = jnp.int32(self.max_states or 0)
             gen_lo = int(np.int32(np.uint32(generated & 0xFFFFFFFF)))
             gen_hi = generated >> 32
@@ -4455,8 +4469,12 @@ class TpuExplorer:
         generated = n_init
         distinct = len(explored_init)
 
+        # seed.keys_s / .tables_s / .upload_s: the host's pieces of the
+        # seed on the program's own clock, as in _run_resident
         with tel.span("search.seed"):
-            init_keys, init_packed, init_povf = self._host_keys(init_rows)
+            with tel.timed("seed.keys_s"):
+                init_keys, init_packed, init_povf = \
+                    self._host_keys(init_rows)
             if init_povf:
                 return self._mk_result(
                     False, distinct, generated, 0, t0, warnings,
@@ -4470,19 +4488,26 @@ class TpuExplorer:
             FC = _pow2_at_least(max(n_init, 1))
             SC = _pow2_at_least(4 * max(n_init, 1))
 
-            front_init = init_packed[explored_init] if n_init else init_packed
-            n_front = len(front_init)
-            frontier = np.full((FC, self.PW), SENTINEL, np.int32)
-            frontier[:n_front] = front_init
-            frontier = jnp.asarray(frontier)
+            with tel.timed("seed.tables_s"):
+                front_init = init_packed[explored_init] if n_init \
+                    else init_packed
+                n_front = len(front_init)
+                frontier = np.full((FC, self.PW), SENTINEL, np.int32)
+                frontier[:n_front] = front_init
+            with tel.timed("seed.upload_s"):
+                frontier = jnp.asarray(frontier)
             fcount = n_front
 
-            seen = np.full((SC, K), SENTINEL, np.int32)
+            with tel.timed("seed.tables_s"):
+                seen = np.full((SC, K), SENTINEL, np.int32)
             if n_init:
-                order = np.lexsort(tuple(init_keys[:, i]
-                                         for i in reversed(range(K))))
-                seen[:n_init] = init_keys[order]
-            seen = jnp.asarray(seen)
+                with tel.timed("seed.keys_s"):
+                    order = np.lexsort(tuple(init_keys[:, i]
+                                             for i in reversed(range(K))))
+                with tel.timed("seed.tables_s"):
+                    seen[:n_init] = init_keys[order]
+            with tel.timed("seed.upload_s"):
+                seen = jnp.asarray(seen)
             seen_count = n_init
             tel.counter("search.seed_bytes", frontier.nbytes + seen.nbytes)
 

@@ -1050,7 +1050,7 @@ class MeshExplorer(TpuExplorer):
         step = obs.prof_wrap("mesh.level_step", jax.jit(shard_map(
             device_step, mesh=self.mesh,
             in_specs=(P("d"), P("d"), P("d"), P("d")),
-            out_specs=tuple([P("d")] * n_out))))
+            out_specs=tuple([P("d")] * n_out))), key=key)
         self._mesh_step_cache[key] = step
         return step
 
@@ -1393,7 +1393,7 @@ class MeshExplorer(TpuExplorer):
             in_specs=in_specs,
             out_specs=tuple([P("d")] * n_out),
             check_vma=False),
-            donate_argnums=donate))
+            donate_argnums=donate), key=key)
         self._mesh_step_cache[key] = step
         return step
 
@@ -1643,21 +1643,26 @@ class MeshExplorer(TpuExplorer):
                 from ..compile.vspec import CompileError
                 raise CompileError(self._pack_ovf_msg())
             owner = self._owner_from_keys(keys)
-        exp = np.zeros(len(init_rows), bool)
-        exp[np.asarray(explored_idx, int)] = True
-        frontier = np.full((D, FC, self.PW), SENTINEL, np.int32)
-        seen = np.full((D, SC, K), SENTINEL, np.int32)
-        seen[:, :, 0] = 1  # empty slots: validity lane 1
-        fcount = np.zeros((D,), np.int32)
-        seen_counts = np.zeros((D,), np.int32)
+        tel = obs.current()  # seed.tables_s / .keys_s: _mesh_seed
+        with tel.timed("seed.tables_s"):
+            exp = np.zeros(len(init_rows), bool)
+            exp[np.asarray(explored_idx, int)] = True
+            frontier = np.full((D, FC, self.PW), SENTINEL, np.int32)
+            seen = np.full((D, SC, K), SENTINEL, np.int32)
+            seen[:, :, 0] = 1  # empty slots: validity lane 1
+            fcount = np.zeros((D,), np.int32)
+            seen_counts = np.zeros((D,), np.int32)
         for d in range(D):
-            p = packed[(owner == d) & exp]
-            frontier[d, :len(p)] = p
-            k = keys[owner == d]
+            with tel.timed("seed.tables_s"):
+                p = packed[(owner == d) & exp]
+                frontier[d, :len(p)] = p
+                k = keys[owner == d]
             if len(k):
-                order = np.lexsort(tuple(k[:, i]
-                                         for i in reversed(range(K))))
-                seen[d, :len(k)] = k[order]
+                with tel.timed("seed.keys_s"):
+                    order = np.lexsort(tuple(k[:, i]
+                                             for i in reversed(range(K))))
+                with tel.timed("seed.tables_s"):
+                    seen[d, :len(k)] = k[order]
             fcount[d] = len(p)
             seen_counts[d] = len(k)
         return seen, frontier, fcount, seen_counts
@@ -1882,6 +1887,13 @@ class MeshExplorer(TpuExplorer):
         generated = len(explored_mask)
         distinct = int(explored_mask.sum())
         hint = self._mesh_caps_hint
+        # the host's pieces of the seed on the program's own clock
+        # (ISSUE 34; bench/SPANS.records.md): `seed.keys_s` keys, owner
+        # hash and per-shard order, `seed.tables_s` the host-built
+        # shards and rings, `seed.upload_s` the `_put`s up to their
+        # return.  Float counters, not spans: `search.seed` keeps its
+        # idle seconds
+        tel = obs.current()
 
         if self.resume_from:
             ck = self._load_ck("mesh")
@@ -1921,14 +1933,16 @@ class MeshExplorer(TpuExplorer):
             self.log(f"Resuming mesh run at depth {depth} "
                      f"({distinct} distinct states)")
         else:
-            init_keys, init_packed, init_povf = \
-                self._host_keys(init_rows)
+            with tel.timed("seed.keys_s"):
+                init_keys, init_packed, init_povf = \
+                    self._host_keys(init_rows)
             if init_povf:
                 from ..compile.vspec import CompileError
                 raise CompileError(self._pack_ovf_msg())
-            owner = self._owner_from_keys(init_keys)
-            per_dev = [init_rows[(owner == d) & explored_mask]
-                       for d in range(D)]
+            with tel.timed("seed.keys_s"):
+                owner = self._owner_from_keys(init_keys)
+                per_dev = [init_rows[(owner == d) & explored_mask]
+                           for d in range(D)]
             FC = _pow2_at_least(
                 max(max((len(p) for p in per_dev), default=1), 1,
                     int(hint.get("FC", 1))), lo=64)
@@ -1951,29 +1965,33 @@ class MeshExplorer(TpuExplorer):
                     init_rows, explored_idx, D, SC, FC,
                     keys=init_keys, packed=init_packed, owner=owner)
             if self.store_trace:
-                self._levels.append((frontier_np.copy(), None, FC))
-            seen = self._put(seen_np)
-            frontier = self._put(frontier_np)
-            fcount = self._put(fcount_np.astype(np.int32))
-            seen_count = self._put(scount_np)
+                with tel.timed("seed.tables_s"):
+                    self._levels.append((frontier_np.copy(), None, FC))
+            with tel.timed("seed.upload_s"):
+                seen = self._put(seen_np)
+                frontier = self._put(frontier_np)
+                fcount = self._put(fcount_np.astype(np.int32))
+                seen_count = self._put(scount_np)
             depth = 0
 
         tr_rows = tr_src = None
         if self.store_trace:
-            ring_np = np.full((D, TRL, FC, PW), SENTINEL, np.int32)
-            src_np_ = np.full((D, TRL, FC), -1, np.int32)
-            for l, (rows, src, _fcl) in enumerate(self._levels[1:]):
-                k = min(rows.shape[1], FC)
-                ring_np[:, l, :k] = rows[:, :k]
-                src_np_[:, l, :k] = src[:, :k]
-            tr_rows = self._put(ring_np)
-            tr_src = self._put(src_np_)
+            with tel.timed("seed.tables_s"):
+                ring_np = np.full((D, TRL, FC, PW), SENTINEL, np.int32)
+                src_np_ = np.full((D, TRL, FC), -1, np.int32)
+                for l, (rows, src, _fcl) in enumerate(self._levels[1:]):
+                    k = min(rows.shape[1], FC)
+                    ring_np[:, l, :k] = rows[:, :k]
+                    src_np_[:, l, :k] = src[:, :k]
+            with tel.timed("seed.upload_s"):
+                tr_rows = self._put(ring_np)
+                tr_src = self._put(src_np_)
             # _levels beyond the init level will be re-materialized from
             # the ring on demand; keep only level 0 host-side
             del self._levels[1:]
         # what scale adds (ISSUE 30): the shards and the ring are built
         # on the host at full capacity and uploaded, every search
-        obs.current().counter("search.seed_bytes", _nbytes(
+        tel.counter("search.seed_bytes", _nbytes(
             seen, frontier, tr_rows, tr_src))
         return (seen, seen_count, frontier, fcount, tr_rows, tr_src, SC,
                 FC, TRL, depth, generated, distinct)

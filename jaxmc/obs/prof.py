@@ -28,6 +28,31 @@ layer:
   hbm       the MEASURED device peak, `memory_stats()
             ["peak_bytes_in_use"]` summed over the live devices; absent
             where the backend reports none (XLA:CPU).
+  programs  one record per executable (ISSUE 34): where a dispatch
+            grows a site's `_cache_size()` the profiler asks jax for
+            the executable THAT CALL made (`fn.lower(*args).compile()`
+            after the call returns the cached `MeshComputation`'s
+            executable: no second compile, no second cache load) and
+            keeps its identity: the site, the engine's cache key,
+            whether it was compiled or loaded from the persistent
+            cache, the XLA seconds, and `memory_analysis()` per device
+            — the temporaries `memory_stats()` never shows.  The
+            program with the largest `hbm_bytes` so far is published as
+            gauges `program.temp_bytes` / `program.hbm_bytes`.
+            `origin` and `xla_s` are the rise of PROCESS-WIDE counters
+            around the call: right where one thread compiles at a time
+            (the CLI, the bench, the serve daemon's single device
+            owner); a compile on another thread, or an eager helper
+            first compiled inside the same call, is charged to it too.
+            `dispatches` goes to the NEWEST executable of the jitted
+            function called: a function that holds several (a
+            recompile under one engine key; the site's `recompiles`
+            says so) charges them all to the last.
+  launch    every mode also charges the host seconds inside
+            `fn(*args)` up to its RETURN (the enqueue; on a new
+            executable the compile or load too) to the site and to the
+            float counter `dispatch.launch_s`: the host's part of a
+            dispatch on the program's own clock, tracer or not.
 
 The rollup lands in the metrics artifact as the `prof{}` block (schema
 jaxmc.metrics/4, obs/schema.py) and renders via `python -m jaxmc.obs
@@ -40,6 +65,7 @@ from __future__ import annotations
 
 import threading
 import time
+import weakref
 from typing import Any, Dict, List, Optional, Tuple
 
 # resolved lazily to avoid a telemetry<->prof import cycle (telemetry
@@ -67,17 +93,53 @@ def _nbytes(x) -> int:
     return 0
 
 
+def _compile_marks(tel) -> Tuple[float, int]:
+    """(XLA seconds, persistent-cache hits) as compile/cache.py's
+    listeners have counted them into `tel` so far."""
+    c = getattr(tel, "counters", None) or {}
+    return (c.get("compile.xla_compile_s", 0.0),
+            c.get("compile.persistent_cache_hits", 0))
+
+
+_BYTE_FIELDS = (("argument_bytes", "argument_size_in_bytes"),
+                ("output_bytes", "output_size_in_bytes"),
+                ("alias_bytes", "alias_size_in_bytes"),
+                ("temp_bytes", "temp_size_in_bytes"))
+
+
+def _executable_bytes(fn, args, kwargs) -> Dict[str, int]:
+    """`memory_analysis()` of the executable `fn(*args)` just ran, per
+    device, read where jax keeps it: lowering the same arguments again
+    returns the computation the call cached, executable and all (donated
+    arguments are deleted by now; their avals are not).  {} where jax
+    holds no executable there — this never compiles one."""
+    try:
+        lowered = fn.lower(*args, **kwargs)
+        if getattr(getattr(lowered, "_lowering", None),
+                   "_executable", None) is None:
+            return {}
+        ma = lowered.compile().memory_analysis()
+        out = {ours: int(getattr(ma, theirs))
+               for ours, theirs in _BYTE_FIELDS}
+    except Exception:  # noqa: BLE001 — profiling never breaks a run
+        return {}
+    out["hbm_bytes"] = out["argument_bytes"] + out["output_bytes"] \
+        - out["alias_bytes"] + out["temp_bytes"]
+    return out
+
+
 class SiteStats:
     """Per-site accumulators.  Mutated under the owning Profiler's
     lock; read via Profiler.snapshot()."""
 
     __slots__ = ("name", "dispatches", "wall_s", "arg_bytes",
-                 "res_bytes", "recompiles")
+                 "res_bytes", "recompiles", "launch_s")
 
     def __init__(self, name: str):
         self.name = name
         self.dispatches = 0
         self.wall_s = 0.0
+        self.launch_s = 0.0
         self.arg_bytes = 0
         self.res_bytes = 0
         self.recompiles = 0
@@ -85,6 +147,8 @@ class SiteStats:
     def as_dict(self) -> Dict[str, Any]:
         d: Dict[str, Any] = {"dispatches": self.dispatches,
                              "recompiles": self.recompiles}
+        if self.launch_s:
+            d["launch_s"] = round(self.launch_s, 6)
         if self.wall_s:
             d["wall_s"] = round(self.wall_s, 6)
         if self.arg_bytes or self.res_bytes:
@@ -105,6 +169,12 @@ class Profiler:
         self._clock = clock
         self._lock = threading.Lock()
         self.sites: Dict[str, SiteStats] = {}
+        # one record per executable, in first-dispatch order, and the
+        # newest of each jitted function (weak: an engine that drops
+        # its program drops the entry; the record stays in the list)
+        self.programs: List[Dict[str, Any]] = []
+        self._program_of: "weakref.WeakKeyDictionary[Any, Dict[str, Any]]" \
+            = weakref.WeakKeyDictionary()
         self.xla_trace_dir: Optional[str] = None
 
     # ---- dispatch sites ------------------------------------------------
@@ -125,35 +195,66 @@ class Profiler:
         except Exception:  # noqa: BLE001 — profiling never breaks a run
             return None
 
-    def record(self, name: str, fn, args, kwargs):
-        """One profiled dispatch.  Cheap mode: count + recompile delta
-        only.  Wall mode: + block-until-ready wall and arg/result
-        bytes."""
+    def record(self, name: str, fn, args, kwargs, key=None):
+        """One profiled dispatch.  Every mode: count, recompile delta,
+        the host seconds up to `fn`'s return (`dispatch.launch_s`) and,
+        where the call made a new executable, its program record.  Wall
+        mode: + block-until-ready wall and arg/result bytes."""
         st = self._site(name)
+        tel = _cur()  # the recorder jax's compile listeners write to
+        marks = _compile_marks(tel)
         cs0 = self._cache_size(fn)
-        if self.mode != self.WALL:
-            out = fn(*args, **kwargs)
-            cs1 = self._cache_size(fn)
-            with self._lock:
-                st.dispatches += 1
-                if cs0 is not None and cs1 is not None and cs1 > cs0:
-                    st.recompiles += cs1 - cs0
-            return out
         t0 = self._clock()
         out = fn(*args, **kwargs)
-        out = self._block(out)
-        dt = self._clock() - t0
+        launch = self._clock() - t0
+        dt = ab = rb = 0
+        if self.mode == self.WALL:
+            out = self._block(out)
+            dt = self._clock() - t0
+            ab = _nbytes(args) + _nbytes(kwargs)
+            rb = _nbytes(out)
         cs1 = self._cache_size(fn)
-        ab = _nbytes(args) + _nbytes(kwargs)
-        rb = _nbytes(out)
+        grew = cs1 - cs0 if cs0 is not None and cs1 is not None \
+            and cs1 > cs0 else 0
+        if grew:
+            self._new_program(name, key, fn, args, kwargs, tel, marks)
         with self._lock:
             st.dispatches += 1
+            st.launch_s += launch
             st.wall_s += dt
             st.arg_bytes += ab
             st.res_bytes += rb
-            if cs0 is not None and cs1 is not None and cs1 > cs0:
-                st.recompiles += cs1 - cs0
+            st.recompiles += grew
+            prog = self._program_of.get(fn)
+            if prog is not None:
+                prog["dispatches"] += 1
+        tel.counter("dispatch.launch_s", launch)
         return out
+
+    # ---- program records -------------------------------------------------
+    def _new_program(self, name, key, fn, args, kwargs, tel, marks):
+        """The record of the executable the call just made (module
+        docstring, `programs`).  Never raises and never compiles."""
+        xla_s, hits = (b - a for a, b in zip(marks, _compile_marks(tel)))
+        rec: Dict[str, Any] = {
+            "site": name,
+            "key": list(key) if isinstance(key, tuple) else key,
+            "origin": "loaded" if hits > 0 else "compiled",
+            "xla_s": round(xla_s, 6), "dispatches": 0}
+        rec.update(_executable_bytes(fn, args, kwargs))
+        with self._lock:
+            self.programs.append(rec)
+            self._program_of[fn] = rec
+            top = max(self.programs, key=lambda r: r.get("hbm_bytes", -1))
+        if top is rec and "hbm_bytes" in rec:
+            tel.gauge("program.temp_bytes", rec["temp_bytes"])
+            tel.gauge("program.hbm_bytes", rec["hbm_bytes"])
+
+    def dispatches_by_program(self) -> List[int]:
+        """Dispatches of each program record so far, in record order:
+        two of these around a search say which programs it ran."""
+        with self._lock:
+            return [r["dispatches"] for r in self.programs]
 
     @staticmethod
     def _block(out):
@@ -200,9 +301,12 @@ class Profiler:
         `force`."""
         with self._lock:
             sites = {n: s.as_dict() for n, s in self.sites.items()}
+            programs = [dict(r) for r in self.programs]
         if not force and not sites and self.mode == self.CHEAP:
             return None
         out: Dict[str, Any] = {"mode": self.mode, "sites": sites}
+        if programs:
+            out["programs"] = programs
         peak = self.hbm_peak_bytes
         if peak is not None:
             out["hbm"] = {"peak_bytes": peak}
@@ -211,16 +315,18 @@ class Profiler:
         return out
 
 
-def wrap(name: str, fn):
+def wrap(name: str, fn, key=None):
     """Register `fn` (typically a jitted callable) as the named
-    dispatch site.  The active recorder's Profiler is resolved at CALL
-    time; with no live recorder (NullTelemetry.prof is None) the
-    wrapper is one getattr + a None test."""
+    dispatch site; `key` is the engine's own cache key for it (the
+    capacities), kept in its program records.  The active recorder's
+    Profiler is resolved at CALL time; with no live recorder
+    (NullTelemetry.prof is None) the wrapper is one getattr + a None
+    test."""
     def profiled(*args, **kwargs):
         prof = getattr(_cur(), "prof", None)
         if prof is None:
             return fn(*args, **kwargs)
-        return prof.record(name, fn, args, kwargs)
+        return prof.record(name, fn, args, kwargs, key)
 
     profiled.__wrapped__ = fn
     profiled.__name__ = getattr(fn, "__name__", name)
@@ -265,11 +371,32 @@ def _fmt_bytes(n) -> str:
     return f"{n:,.1f}TB"
 
 
+def _programs_table(programs: List[Dict[str, Any]], out) -> None:
+    """One row per executable: what it holds on a device
+    (`memory_analysis()`; hbm = args + out - alias + temp) and whether
+    this process compiled it or loaded it."""
+    if not programs:
+        return
+    print("programs (one per executable; bytes per device):", file=out)
+    w = max(len(str(r.get("site"))) for r in programs)
+    print(f"  {'site':<{w}}  {'origin':>8}  {'xla':>8}  {'disp':>6}  "
+          f"{'args':>9}  {'out':>9}  {'alias':>9}  {'temp':>9}  "
+          f"{'hbm':>9}  key", file=out)
+    for r in programs:
+        cells = "  ".join(f"{_fmt_bytes(r.get(f)):>9}" for f in (
+            "argument_bytes", "output_bytes", "alias_bytes",
+            "temp_bytes", "hbm_bytes"))
+        print(f"  {str(r.get('site')):<{w}}  {r.get('origin', '-'):>8}  "
+              f"{r.get('xla_s', 0.0):7.2f}s  {r.get('dispatches', 0):>6}  "
+              f"{cells}  {r.get('key')}", file=out)
+
+
 def cmd_top(args, out=None) -> int:
     """`python -m jaxmc.obs top FILE` — the per-site table: wall,
     share of the search wall, dispatches, bytes per dispatch,
-    recompiles; plus the measured device peak and the compile seconds
-    per program (`compile.by_fun`).  Exit 2 when the artifact carries
+    recompiles, the host seconds inside its calls (`launch_s`); plus
+    the measured device peak, the program records and the compile
+    seconds per program (`compile.by_fun`).  Exit 2 when the artifact carries
     no prof block (pre-/4 artifact, or an un-instrumented run)."""
     import json
     import sys
@@ -278,7 +405,8 @@ def cmd_top(args, out=None) -> int:
         summary = json.load(fh)
     prof = summary.get("prof")
     if not isinstance(prof, dict) or not (prof.get("sites")
-                                          or prof.get("hbm")):
+                                          or prof.get("hbm")
+                                          or prof.get("programs")):
         print(f"error: {args.file}: no prof block (run with --profile, "
               f"or any telemetry-enabled run on jaxmc.metrics/4+)",
               file=sys.stderr)
@@ -296,7 +424,7 @@ def cmd_top(args, out=None) -> int:
         w = max(len(n) for n, _ in rows)
         print(f"  {'site':<{w}}  {'wall':>9}  {'share':>6}  "
               f"{'disp':>6}  {'arg/disp':>10}  {'res/disp':>10}  "
-              f"{'recomp':>6}", file=out)
+              f"{'recomp':>6}  {'launch':>9}", file=out)
         for name, s in rows:
             wall = s.get("wall_s")
             share = (wall / search * 100.0) if wall and search else None
@@ -308,7 +436,8 @@ def cmd_top(args, out=None) -> int:
                 f"{s.get('dispatches', 0):>6}  "
                 f"{_fmt_bytes(s.get('arg_bytes', 0) / d if s.get('arg_bytes') else None):>10}  "
                 f"{_fmt_bytes(s.get('res_bytes', 0) / d if s.get('res_bytes') else None):>10}  "
-                f"{s.get('recompiles', 0):>6}", file=out)
+                f"{s.get('recompiles', 0):>6}  "
+                f"{s.get('launch_s', 0.0):8.4f}s", file=out)
     else:
         print("  (no dispatch sites recorded)", file=out)
     if att["share"] is not None:
@@ -318,6 +447,7 @@ def cmd_top(args, out=None) -> int:
     peak = (prof.get("hbm") or {}).get("peak_bytes")
     if peak:
         print(f"hbm: measured peak {_fmt_bytes(peak)}", file=out)
+    _programs_table(prof.get("programs") or [], out)
     by_fun = (summary.get("gauges") or {}).get("compile.by_fun") or {}
     if by_fun:
         print("xla compiles by program (a persistent-cache load "

@@ -217,6 +217,49 @@ def _phase_table(phases: List[Dict[str, Any]], out) -> int:
     return len(phases)
 
 
+def _searches_table(requests: List[Dict[str, Any]], out) -> None:
+    """The search records (`requests`, obs/telemetry.py): how many, the
+    median and the largest wall, and for the slowest search the piece —
+    a child span's wall or one of the host-seconds counters — that grew
+    most over its median in the others."""
+    if not requests:
+        return
+
+    def _s4(x) -> str:  # searches are sub-second: _fmt_s rounds to 0.01
+        return f"{x:.4f}s"
+
+    walls = sorted(r["wall_s"] for r in requests)
+    median = walls[len(walls) // 2]
+    slow = max(requests, key=lambda r: r["wall_s"])
+    origins: Dict[str, int] = {}
+    for r in requests:
+        for o, n in (r.get("origins") or {}).items():
+            origins[o] = origins.get(o, 0) + n
+    print(f"searches: {len(requests)} records; wall median "
+          f"{_s4(median)}, largest {_s4(slow['wall_s'])} "
+          f"(rid {slow['rid']}); dispatches by origin {origins}",
+          file=out)
+    if len(requests) < 2 or slow["wall_s"] <= median:
+        return
+
+    def pieces(r):
+        return {**(r.get("spans") or {}), **(r.get("counters") or {})}
+
+    def rise_of(get):
+        vals = sorted(get(r) for r in requests if r is not slow)
+        return get(slow) - vals[len(vals) // 2]
+
+    rise, name = max((rise_of(lambda r, n=n: pieces(r).get(n, 0.0)), n)
+                     for n in pieces(slow))
+    late = slow["wall_s"] - median
+    cpu = rise_of(lambda r: r.get("cpu_s", 0.0))
+    print(f"  slowest search +{_s4(late)} over the median: {name} grew "
+          f"most, +{_s4(rise)} to {_s4(pieces(slow)[name])}; its CPU "
+          f"seconds {cpu:+.4f}s"
+          + ("" if cpu > 0.5 * late else " (the process was not running)"),
+          file=out)
+
+
 def cmd_report(args, out=sys.stdout) -> int:
     rec = load_record(args.file)
     print(f"== {rec['label']} ({rec['kind']}: {args.file})", file=out)
@@ -396,6 +439,7 @@ def cmd_report(args, out=sys.stdout) -> int:
             hl.append("serve[" + " ".join(cells) + "]")
     if hl:
         print("highlights: " + "  ".join(hl), file=out)
+    _searches_table(s.get("requests") or [], out)
     return 0 if rows else 1
 
 

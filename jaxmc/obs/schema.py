@@ -534,6 +534,49 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
     - gauge `mesh.device_peak_bytes` (MeshExplorer._mk): per-device
       `memory_stats()["peak_bytes_in_use"]`, where the backend
       reports it.
+
+  (PR 34, still jaxmc.metrics/4 — additive, optional; records by
+   IDENTITY beside the sums by name; bench/SPANS.records.md has the
+   table of what each is taken from and which metric reads it:)
+    - `prof.programs`: one record per executable, in first-dispatch
+      order, made where a dispatch site's `_cache_size()` grew:
+        {site, key, origin: "compiled" | "loaded", xla_s, dispatches,
+         argument_bytes?, output_bytes?, alias_bytes?, temp_bytes?,
+         hbm_bytes?}
+      `key` is the engine's own cache key (the capacities); `origin`
+      says whether jax's persistent cache answered for THAT call;
+      `xla_s` the rise of `compile.xla_compile_s` around it; the byte
+      fields are `memory_analysis()` of the executable the call made,
+      per device, read where jax keeps it (never a second compile),
+      absent where jax keeps none; hbm_bytes = argument + output -
+      alias + temp.  `python -m jaxmc.obs top` prints the table.
+      `origin` / `xla_s` are rises of process-wide counters (one
+      compiling thread at a time), `dispatches` goes to the newest
+      executable of the jitted function called (obs/prof.py).
+      `prof.sites[*].launch_s`: host seconds inside the site's calls
+      up to their return (the `launch` column of `obs top`).
+    - gauges `program.temp_bytes` / `program.hbm_bytes`: those fields
+      of the program with the largest hbm_bytes dispatched so far
+      (argument_bytes stays in the record, where `top` prints it).
+    - float counters, seconds on `time.perf_counter` taken where the
+      work happens (the form of `compile.xla_compile_s`; NOT spans,
+      so they take no idle seconds out of `search.seed`):
+      `seed.keys_s` (init keys, owner hash, lexsorts), `seed.tables_s`
+      (host-built tables), `seed.upload_s` (the calls that hand them
+      to the device, up to their return), `dispatch.launch_s` (every
+      dispatch site's `fn(*args)` up to its return).
+    - top-level `requests`: the last 512 search records, one per
+      closed `search` span of `CheckSession.explore()`:
+        {rid, name, t0, wall_s, cpu_s, spans: {name: wall_s},
+         counters: {the four above: rise}, dispatches,
+         origins: {"compiled" | "loaded": dispatches}}
+      `cpu_s` is `time.process_time()`: a search whose wall rose and
+      whose CPU seconds did not was not running.  `rid` counts the
+      recorder's searches from 1.  The summary is the records' only
+      sink: the trace stream carries no copy and its span events no
+      `rid` (nothing reads a stream by search).  `python -m jaxmc.obs
+      report` prints the searches' median and largest wall and the
+      piece that grew in the slowest.
 """
 
 from __future__ import annotations

@@ -34,6 +34,15 @@ from . import context as trace_context
 from .prof import Profiler  # per-dispatch attribution
 from .schema import SCHEMA  # one source of truth for the artifact schema
 
+# the float counters a search record reads the rise of: the host's
+# pieces of a search on the program's own clock (the engines' `timed`
+# blocks under `search.seed`, obs/prof.py's launch seconds)
+REQUEST_COUNTERS = ("seed.keys_s", "seed.tables_s", "seed.upload_s",
+                    "dispatch.launch_s")
+# a recorder keeps the last N search records (`requests`): a bench
+# window of 390 searches fits, a served session cannot grow
+_REQUESTS_MAX = 512
+
 # every live recorder keeps the last N trace events in memory (the
 # serve daemon's GET /jobs/<id>/events reads them mid-run); bounded so
 # a long search cannot grow the daemon without limit
@@ -69,20 +78,47 @@ def _jsonable(v):
         return str(v)
 
 
+class _Timed:
+    """`with tel.timed("seed.tables_s"):` — the block's seconds on
+    `time.perf_counter`, added to a float counter (the form of
+    `compile.xla_compile_s`).  Not a span: no event, no annotation, so
+    it takes nothing out of the span it stands in."""
+
+    __slots__ = ("tel", "name", "t0")
+
+    def __init__(self, tel: "Telemetry", name: str):
+        self.tel = tel
+        self.name = name
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *a):
+        self.tel.counter(self.name, time.perf_counter() - self.t0)
+        return False
+
+
 class _SpanHandle:
     """Context manager for one phase span. Re-entrant use is not needed:
     each `span()` call makes a fresh handle."""
 
     __slots__ = ("tel", "name", "attrs", "t0", "_done", "id", "parent_id",
-                 "_ann")
+                 "_ann", "req", "is_request")
 
-    def __init__(self, tel: "Telemetry", name: str, attrs: Dict[str, Any]):
+    def __init__(self, tel: "Telemetry", name: str, attrs: Dict[str, Any],
+                 request: bool = False):
         self.tel = tel
         self.name = name
         self.attrs = attrs
         self.t0 = None
         self._done = False
         self.id = self.parent_id = self._ann = None
+        # a request span makes its record-in-progress when it opens
+        # (Telemetry._request_open); every span opened under it holds
+        # the same dict, any other span None
+        self.is_request = request
+        self.req: Optional[Dict[str, Any]] = None
 
     def __enter__(self):
         self.t0 = self.tel._clock()
@@ -119,6 +155,12 @@ class NullTelemetry:
         return []
 
     def span(self, name: str, **attrs):
+        return _NULL_SPAN
+
+    def request(self, name: str, **attrs):
+        return _NULL_SPAN
+
+    def timed(self, name: str):
         return _NULL_SPAN
 
     def counter(self, name: str, inc: int = 1) -> None:
@@ -203,6 +245,11 @@ class Telemetry(NullTelemetry):
         # always-on cheap profiler (dispatch counts + recompiles only);
         # the CLI flips mode to wall/xla under --profile
         self.prof = Profiler()
+        # one compact record per closed request span (a search), the
+        # last _REQUESTS_MAX of them; ids count from 1 per recorder
+        self.requests: collections.deque = collections.deque(
+            maxlen=_REQUESTS_MAX)
+        self._rid_seq = 0
         self._ring: collections.deque = collections.deque(maxlen=_RING_MAX)
         # the trace context is derived once per process; every event
         # this recorder emits is stamped with its trace_id so fleet
@@ -257,11 +304,56 @@ class Telemetry(NullTelemetry):
         return _SpanHandle(self, name, {k: _jsonable(v)
                                         for k, v in attrs.items()})
 
+    def request(self, name: str, **attrs):
+        """A span that is also a REQUEST (one search): its close leaves
+        one record in `requests` (the summary carries them; `python -m
+        jaxmc.obs report` reads them) — what the host did in THIS
+        search, which the sums by name in `phases` cannot say."""
+        return _SpanHandle(self, name, {k: _jsonable(v)
+                                        for k, v in attrs.items()},
+                           request=True)
+
+    def timed(self, name: str):
+        return _Timed(self, name)
+
+    def _request_open(self, h: _SpanHandle) -> None:
+        with self._lock:
+            self._rid_seq += 1
+            rid = self._rid_seq
+            marks = [self.counters.get(c, 0.0) for c in REQUEST_COUNTERS]
+        h.req = {"rid": rid, "spans": {}, "marks": marks,
+                 "cpu0": time.process_time(),
+                 "programs": self.prof.dispatches_by_program()}
+
+    def _request_close(self, h: _SpanHandle, wall_s: float) -> None:
+        req = h.req
+        with self._lock:
+            rises = {c: round(self.counters.get(c, 0.0) - m, 6)
+                     for c, m in zip(REQUEST_COUNTERS, req["marks"])}
+        before, origins = req["programs"], {}
+        for i, n in enumerate(self.prof.dispatches_by_program()):
+            n -= before[i] if i < len(before) else 0
+            if n:
+                o = self.prof.programs[i]["origin"]
+                origins[o] = origins.get(o, 0) + n
+        rec = {"rid": req["rid"], "name": h.name, "t0": h.t0,
+               "wall_s": round(wall_s, 6),
+               "cpu_s": round(time.process_time() - req["cpu0"], 6),
+               "spans": {k: round(v, 6) for k, v in req["spans"].items()},
+               "counters": rises,
+               "dispatches": sum(origins.values()), "origins": origins}
+        with self._lock:
+            self.requests.append(rec)
+
     def _span_open(self, h: _SpanHandle) -> None:
         stack = self._stack()
         parent = stack[-1] if stack else None
         stack.append(h)
         h.parent_id = parent.id if parent else None
+        if h.is_request:
+            self._request_open(h)
+        elif parent is not None:
+            h.req = parent.req
         # the second sink: the same span on the profiler's clock, in
         # /host:CPU of the xplane that holds the device line.  Outside a
         # profiler session a TraceMe is a flag test; obs itself never
@@ -305,6 +397,12 @@ class Telemetry(NullTelemetry):
         if error:
             ev["error"] = error
         self._emit(ev)
+        if h.is_request:
+            self._request_close(h, t1 - h.t0)
+        elif h.req is not None:
+            # any depth: a span's wall also lies inside its parent's
+            walls = h.req["spans"]
+            walls[h.name] = walls.get(h.name, 0.0) + (t1 - h.t0)
 
     # ---- scalars ----
     def counter(self, name: str, inc: int = 1) -> None:
@@ -423,6 +521,7 @@ class Telemetry(NullTelemetry):
             gauges = dict(self.gauges)
             levels = list(self.levels)
             meta = dict(self.meta)
+            requests = list(self.requests)
         out = {
             "schema": SCHEMA,
             "started_at": self.t_start,
@@ -433,6 +532,8 @@ class Telemetry(NullTelemetry):
             "levels": levels,
         }
         out.update(meta)
+        if requests:
+            out["requests"] = requests  # additive /4 (obs/schema.py)
         prof = self.prof
         if prof is not None:
             pb = prof.snapshot()

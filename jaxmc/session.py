@@ -661,10 +661,10 @@ class CheckSession:
             ex.final_checkpoint = final_checkpoint
         self.explore_count += 1
         if self.cfg.backend == "interp":
-            with self.tel.span("search", workers=self.workers):
+            with self.tel.request("search", workers=self.workers):
                 self.result = ex.run()
         else:
-            with self.tel.span("search"):
+            with self.tel.request("search"):
                 self.result = ex.run()
             from .compile.cache import record_entries_end
             record_entries_end(self.cache_dir)

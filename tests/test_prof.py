@@ -130,8 +130,9 @@ class TestMeasuredPeak:
         p = Profiler(mode=Profiler.WALL)
         assert not hasattr(p, "hbm_buffers")
         p.record("t.site", lambda x: x, (1,), {})
+        # launch_s: the host seconds up to the call's return (ISSUE 34)
         assert set(p.sites["t.site"].as_dict()) == {
-            "dispatches", "recompiles", "wall_s"}
+            "dispatches", "recompiles", "wall_s", "launch_s"}
 
 
 class TestSnapshotRollup:
