@@ -25,7 +25,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -73,6 +73,22 @@ SYMMETRY_WARNING = (
 
 _FP_MIX = [(0x9E3779B1, 0x85EBCA6B), (0xC2B2AE35, 0x27D4EB2F),
            (0x165667B1, 0x9E3779B1), (0x85EBCA6B, 0xC2B2AE35)]
+
+
+@lru_cache(maxsize=8)
+def _table_program(sharding):
+    """The jitted fill behind `TpuExplorer._device_table`: one program
+    per (table shape, fill, head shape), so fill and head rows write ONE
+    table-sized buffer (the update is in place; an eager `.at[].set` on
+    a filled table is a second table and a whole-table copy).  Under a
+    `sharding` each device fills its own shard of the output."""
+    def table(head, shape, fill):
+        out = jnp.broadcast_to(jnp.asarray(fill, jnp.int32), shape)
+        if head is None:
+            return out
+        return lax.dynamic_update_slice(out, head, (0,) * len(shape))
+    return jax.jit(table, static_argnames=("shape", "fill"),
+                   out_shardings=sharding)
 
 
 def filter_init_states(model, layout, init_rows):
@@ -1888,19 +1904,39 @@ class TpuExplorer:
         self._cap_breached = None
 
     @staticmethod
-    def _device_table(shape, head: Optional[np.ndarray] = None):
-        """A [rows, words] table of SENTINEL rows with `head` in its
-        first rows, made ON the device: a fill and the head's few rows,
-        where `np.full` + upload is a host buffer of the table's size
-        per call.  The capped path (ISSUE 32) makes its tables this way:
-        at 8-21 MB they fall under glibc's dynamic mmap threshold, and a
-        process then serves them from reused heap or from fresh,
-        page-faulting maps for its whole life, which made a capped
-        search 2 % faster or slower from one process to the next."""
-        table = jnp.full(shape, SENTINEL, jnp.int32)
-        if head is None or not len(head):
-            return table
-        return table.at[:len(head)].set(jnp.asarray(head, jnp.int32))
+    def _device_table(shape, head: Optional[np.ndarray] = None,
+                      fill=SENTINEL, sharding=None):
+        """A table of `fill` rows (a word, or a tuple of one word per
+        lane of the last axis) with `head` in its leading corner, made
+        ON the device: the host hands over the head alone (KBs) where
+        `np.full` + upload is a fresh, page-faulting host buffer of the
+        table's size per search (1 ns a byte, ISSUE 35) and a transfer
+        the device waits for.  Every search start, resume and spill of
+        all three engines makes its capacity-sized tables here
+        (`_table_program`: fill and head in one buffer, enqueued and not
+        waited for, compiled once per shape).  `sharding`: the mesh's,
+        so that each device fills its own shard of a [D, ...] table."""
+        shape = tuple(int(n) for n in shape)
+        if head is not None:
+            head = np.asarray(head, np.int32)
+            if head.ndim != len(shape) or any(
+                    h > n for h, n in zip(head.shape, shape)):
+                # the update would be clamped into the table, silently
+                raise ValueError(f"a head of shape {head.shape} does "
+                                 f"not fit a table of shape {shape}")
+            head = jax.device_put(head, sharding) if head.size else None
+        fill = tuple(int(w) for w in fill) if np.ndim(fill) else int(fill)
+        return _table_program(sharding)(head, shape=shape, fill=fill)
+
+    def _seed_tables(self, SC: int, FC: int, seen_head, fr_head):
+        """The one-chip engines' two search tables, [SC, K] seen and
+        [FC, PW] frontier, made on the device from their heads (the init
+        rows, or a checkpoint's).  `search.seed_bytes` counts what the
+        host handed over: the heads."""
+        obs.current().counter("search.seed_bytes",
+                              fr_head.nbytes + seen_head.nbytes)
+        frontier = self._device_table((FC, self.PW), fr_head)
+        return self._device_table((SC, self.K), seen_head), frontier
 
     def _tier_spill(self, seen, count: int):
         """Compact the device table's sorted valid prefix out as ONE
@@ -3301,8 +3337,9 @@ class TpuExplorer:
         # the host's pieces of the seed as seconds on the program's own
         # clock (ISSUE 34; bench/SPANS.records.md): float counters, not
         # spans — `seed.keys_s` the keys and their order, `seed.tables_s`
-        # the host-built tables, `seed.upload_s` the calls that hand
-        # them to the device, up to their return
+        # what the host builds of the tables (their HEADS, the init rows:
+        # since ISSUE 35 no table), `seed.upload_s` the calls that hand
+        # the device the heads and make its tables, up to their return
         with tel.span("search.seed"):
             with tel.timed("seed.keys_s"):
                 init_keys, init_packed, init_povf = \
@@ -3313,36 +3350,11 @@ class TpuExplorer:
                     Violation("error", "capacity overflow", [],
                               self._pack_ovf_msg()))
             with tel.timed("seed.keys_s"):
-                fr_head = init_packed[explored_init]
                 order = np.lexsort(tuple(init_keys[:, i]
                                          for i in reversed(range(K))))
+            with tel.timed("seed.tables_s"):
+                fr_head = init_packed[explored_init]
                 seen_head = init_keys[order]
-            if self.seen_cap is not None:
-                # capped: both tables made on the device (_device_table)
-                with tel.timed("seed.upload_s"):
-                    frontier = self._device_table(
-                        (caps["FCap"], self.PW), fr_head)
-                    seen = self._device_table((caps["SC"], K), seen_head)
-                tel.counter("search.seed_bytes",
-                            fr_head.nbytes + seen_head.nbytes)
-            else:
-                with tel.timed("seed.tables_s"):
-                    frontier = np.full((caps["FCap"], self.PW), SENTINEL,
-                                       np.int32)
-                    frontier[:distinct] = fr_head
-                with tel.timed("seed.upload_s"):
-                    frontier = jnp.asarray(frontier)
-                with tel.timed("seed.tables_s"):
-                    seen = np.full((caps["SC"], K), SENTINEL, np.int32)
-                    seen[:n_init] = seen_head
-                with tel.timed("seed.upload_s"):
-                    seen = jnp.asarray(seen)
-                # what scale adds (ISSUE 30): both tables are built on
-                # the host at full capacity and uploaded, every search
-                tel.counter("search.seed_bytes",
-                            frontier.nbytes + seen.nbytes)
-            fcount = distinct
-            seen_count = n_init
 
         depth = 0
         if self.resume_from:
@@ -3354,21 +3366,14 @@ class TpuExplorer:
             caps["VC"] = min(caps["VC"], self.A * CH)
             caps["AccCap"] = max(caps["AccCap"], 2 * caps["VC"],
                                  caps["FCap"])
-            cs, fr = ck["seen"], ck["frontier"]
-            seen_np = np.full((caps["SC"], K), SENTINEL, np.int32)
-            seen_np[:len(cs)] = cs
-            seen = jnp.asarray(seen_np)
-            seen_count = len(cs)
-            fr_np = np.full((caps["FCap"], self.PW), SENTINEL, np.int32)
-            fr_np[:len(fr)] = fr
-            frontier = jnp.asarray(fr_np)
-            fcount = len(fr)
+            # the checkpoint's rows are the heads
+            seen_head, fr_head = ck["seen"], ck["frontier"]
             distinct = ck["distinct"]
             generated = ck["generated"]
             depth = ck["depth"]
             self.log(f"Resumed from {self.resume_from}: {distinct} "
-                     f"distinct states, {fcount} on queue.")
-            if fcount == 0:
+                     f"distinct states, {len(fr_head)} on queue.")
+            if not len(fr_head):
                 # a COMPLETED-run checkpoint (final_checkpoint, the
                 # serve daemon's warm-resume source): nothing left to
                 # explore — replay the stored verdict with ZERO kernel
@@ -3384,15 +3389,19 @@ class TpuExplorer:
                         self.checkpoint_path != self.resume_from:
                     self._write_ck(
                         "resident", caps=dict(caps),
-                        seen=np.asarray(seen[:seen_count]),
+                        seen=np.asarray(seen_head),
                         frontier=np.zeros((0, self.PW), np.int32),
                         distinct=distinct, generated=generated,
                         depth=depth)
                 return self._mk_result(True, distinct, generated,
                                        depth - 1, t0, warnings)
+        fcount, seen_count = len(fr_head), len(seen_head)
 
-        # the scalar operands' uploads
+        # both tables made on the device from their heads, capped or
+        # not (`_seed_tables`), and the scalar operands' uploads
         with tel.span("search.seed"), tel.timed("seed.upload_s"):
+            seen, frontier = self._seed_tables(
+                caps["SC"], caps["FCap"], seen_head, fr_head)
             max_states = jnp.int32(self.max_states or 0)
             gen_lo = int(np.int32(np.uint32(generated & 0xFFFFFFFF)))
             gen_hi = generated >> 32
@@ -4488,28 +4497,13 @@ class TpuExplorer:
             FC = _pow2_at_least(max(n_init, 1))
             SC = _pow2_at_least(4 * max(n_init, 1))
 
+            with tel.timed("seed.keys_s"):
+                order = np.lexsort(tuple(init_keys[:, i]
+                                         for i in reversed(range(K))))
             with tel.timed("seed.tables_s"):
-                front_init = init_packed[explored_init] if n_init \
+                fr_head = init_packed[explored_init] if n_init \
                     else init_packed
-                n_front = len(front_init)
-                frontier = np.full((FC, self.PW), SENTINEL, np.int32)
-                frontier[:n_front] = front_init
-            with tel.timed("seed.upload_s"):
-                frontier = jnp.asarray(frontier)
-            fcount = n_front
-
-            with tel.timed("seed.tables_s"):
-                seen = np.full((SC, K), SENTINEL, np.int32)
-            if n_init:
-                with tel.timed("seed.keys_s"):
-                    order = np.lexsort(tuple(init_keys[:, i]
-                                             for i in reversed(range(K))))
-                with tel.timed("seed.tables_s"):
-                    seen[:n_init] = init_keys[order]
-            with tel.timed("seed.upload_s"):
-                seen = jnp.asarray(seen)
-            seen_count = n_init
-            tel.counter("search.seed_bytes", frontier.nbytes + seen.nbytes)
+                seen_head = init_keys[order]
 
             trace_levels: List[Tuple[np.ndarray, Optional[np.ndarray], int]] = []
             trace_levels.append((np.asarray(init_packed), None, 0))
@@ -4525,17 +4519,16 @@ class TpuExplorer:
                 trace_levels, frontier_maps = tl, fm
             if graph is not None:
                 frontier_sids = fsids
-            cs, fr = ck["seen"], ck["frontier"]
-            SC = _pow2_at_least(len(cs), SC)
-            seen_np = np.full((SC, K), SENTINEL, np.int32)
-            seen_np[:len(cs)] = cs
-            seen = jnp.asarray(seen_np)
-            seen_count = len(cs)
-            FC = _pow2_at_least(max(len(fr), 1), FC)
-            fr_np = np.full((FC, self.PW), SENTINEL, np.int32)
-            fr_np[:len(fr)] = fr
-            frontier = jnp.asarray(fr_np)
-            fcount = len(fr)
+            # the checkpoint's rows are the heads
+            seen_head, fr_head = ck["seen"], ck["frontier"]
+            SC = _pow2_at_least(len(seen_head), SC)
+            FC = _pow2_at_least(max(len(fr_head), 1), FC)
+        fcount, seen_count = len(fr_head), len(seen_head)
+
+        # both tables made on the device from their heads
+        # (`_seed_tables`): no search start builds one on the host
+        with tel.span("search.seed"), tel.timed("seed.upload_s"):
+            seen, frontier = self._seed_tables(SC, FC, seen_head, fr_head)
 
         self.log(f"Progress({depth}): {generated} states generated, "
                  f"{distinct} distinct states found, "

@@ -378,10 +378,9 @@ class MeshExplorer(TpuExplorer):
                     t.spill(np.ascontiguousarray(
                         seen_np[dd, :cnt, 1:]))
             tel.counter("tier.spilled_keys", total)
-        empty = np.full((self.D, SC, self.K), SENTINEL, np.int32)
-        empty[:, :, 0] = 1
-        return self._put(empty), self._put(
-            np.zeros(self.D, np.int32))
+        return (self._mesh_table((self.D, SC, self.K),
+                                 fill=_invalid_row(self.K)),
+                self._put(np.zeros(self.D, np.int32)))
 
     def _mesh_tier_filter(self, frontier, fcount, tr_rows, tr_src,
                           depth: int, FC: int):
@@ -1628,14 +1627,16 @@ class MeshExplorer(TpuExplorer):
                      D: int, SC: int, FC: int,
                      keys=None, packed=None, owner=None):
         """Host-side initial shard construction shared by the
-        single-controller run() and the multi-host loop
-        (tpu/multihost.py): per-owner frontier fill and lexsorted seen
-        keys with the validity-lane-1 empty-slot convention. One layout
-        rule, so host and device dedup can never diverge. Returns
-        (seen [D,SC,K], frontier [D,FC,PW], fcount [D],
-        seen_counts [D]) as numpy — the per-shard valid-prefix lengths
-        the rank merge keys on, returned here so no caller
-        re-derives them from the validity lane."""
+        resident seed (`_mesh_seed`: at the size of the fullest shard —
+        the HEADS, which the devices extend to capacity with the same
+        `_invalid_row`), the host loop and the multi-host loop
+        (tpu/multihost.py; both at full capacity): per-owner frontier
+        fill and lexsorted seen keys with the validity-lane-1 empty-slot
+        convention. One layout rule, so host and device dedup can never
+        diverge. Returns (seen [D,SC,K], frontier [D,FC,PW],
+        fcount [D], seen_counts [D]) as numpy — the per-shard
+        valid-prefix lengths the rank merge keys on, returned here so
+        no caller re-derives them from the validity lane."""
         K = self.K
         if keys is None:
             keys, packed, povf = self._host_keys(init_rows)
@@ -1648,8 +1649,8 @@ class MeshExplorer(TpuExplorer):
             exp = np.zeros(len(init_rows), bool)
             exp[np.asarray(explored_idx, int)] = True
             frontier = np.full((D, FC, self.PW), SENTINEL, np.int32)
-            seen = np.full((D, SC, K), SENTINEL, np.int32)
-            seen[:, :, 0] = 1  # empty slots: validity lane 1
+            seen = np.empty((D, SC, K), np.int32)
+            seen[:] = _invalid_row(K)  # empty slots: validity lane 1
             fcount = np.zeros((D,), np.int32)
             seen_counts = np.zeros((D,), np.int32)
         for d in range(D):
@@ -1878,11 +1879,21 @@ class MeshExplorer(TpuExplorer):
             seeded = self._mesh_seed(init_rows, explored_mask)
         return self._mesh_supersteps(t0, warnings, *seeded)
 
+    def _mesh_table(self, shape, head=None, fill=SENTINEL):
+        """A [D, ...] table made on the mesh from its [D, H, ...] head
+        block (`_device_table`): each device fills its own shard, nothing
+        lands on device 0, and the sharding is the superstep program's,
+        to which the buffer is donated (what `_put` is for)."""
+        return self._device_table(shape, head, fill, sharding=self._shard0)
+
     def _mesh_seed(self, init_rows, explored_mask):
-        """Shard construction for one search: the owner-hashed init
-        shards (or a checkpoint's) and the trace ring, `_put` on the
-        mesh.  The uploads are asynchronous: `search.seed` ends when
-        they are enqueued.  Returns what `_mesh_supersteps` takes."""
+        """Shard construction for one search: the host computes the
+        owner-hashed HEADS — the init shards at the size of the fullest
+        one, `_init_shards`' layout rule, or a checkpoint's rows — and
+        the devices make the capacity-sized shards and the trace ring
+        from them (`_mesh_table`).  Nothing is waited for: `search.seed`
+        ends when the fills are enqueued.  Returns what
+        `_mesh_supersteps` takes."""
         D, K, PW = self.D, self.K, self.PW
         generated = len(explored_mask)
         distinct = int(explored_mask.sum())
@@ -1890,11 +1901,12 @@ class MeshExplorer(TpuExplorer):
         # the host's pieces of the seed on the program's own clock
         # (ISSUE 34; bench/SPANS.records.md): `seed.keys_s` keys, owner
         # hash and per-shard order, `seed.tables_s` the host-built
-        # shards and rings, `seed.upload_s` the `_put`s up to their
-        # return.  Float counters, not spans: `search.seed` keeps its
-        # idle seconds
+        # head blocks, `seed.upload_s` the calls that hand them over
+        # and make the tables, up to their return.  Float counters, not
+        # spans: `search.seed` keeps its idle seconds
         tel = obs.current()
 
+        ring_head = src_head = None
         if self.resume_from:
             ck = self._load_ck("mesh")
             if ck["D"] != D:
@@ -1908,16 +1920,12 @@ class MeshExplorer(TpuExplorer):
             depth = ck["depth"]
             generated = ck["generated"]
             distinct = ck["distinct"]
-            seen_np = np.full((D, SC, K), SENTINEL, np.int32)
-            seen_np[:, :, 0] = 1
-            seen_np[:, :ck["SC"]] = ck["seen"]
-            seen = self._put(seen_np)
-            seen_count = self._put(
-                ck["seen_counts"].astype(np.int32))
-            fr_np = np.full((D, FC, PW), SENTINEL, np.int32)
-            fr_np[:, :ck["FC"]] = ck["frontier"]
-            frontier = self._put(fr_np)
-            fcount = self._put(ck["fcount"].astype(np.int32))
+            # the checkpoint's rows are the heads: each table up to its
+            # fullest shard's count
+            scount_np = ck["seen_counts"].astype(np.int32)
+            fcount_np = ck["fcount"].astype(np.int32)
+            seen_head = ck["seen"][:, :int(scount_np.max())]
+            fr_head = ck["frontier"][:, :int(fcount_np.max())]
             if ck.get("levels") is not None:
                 self._levels = list(ck["levels"])
             elif self.store_trace:
@@ -1930,6 +1938,20 @@ class MeshExplorer(TpuExplorer):
             self._lvl_FC = [lv[2] for lv in self._levels[1:]]
             TRL = _pow2_at_least(
                 max(depth + 1, int(hint.get("TRL", 1)), 16), lo=16)
+            if self.store_trace and self._levels[1:]:
+                # the ring's head: the checkpoint's levels, each up to
+                # its occupied prefix (`_ring_levels` trimmed them)
+                with tel.timed("seed.tables_s"):
+                    kept = [(rows[:, :FC], src[:, :FC])
+                            for rows, src, _fcl in self._levels[1:]]
+                    widest = max(rows.shape[1] for rows, _ in kept)
+                    ring_head = np.full((D, len(kept), widest, PW),
+                                        SENTINEL, np.int32)
+                    src_head = np.full((D, len(kept), widest), -1,
+                                       np.int32)
+                    for l, (rows, src) in enumerate(kept):
+                        ring_head[:, l, :rows.shape[1]] = rows
+                        src_head[:, l, :src.shape[1]] = src
             self.log(f"Resuming mesh run at depth {depth} "
                      f"({distinct} distinct states)")
         else:
@@ -1941,11 +1963,11 @@ class MeshExplorer(TpuExplorer):
                 raise CompileError(self._pack_ovf_msg())
             with tel.timed("seed.keys_s"):
                 owner = self._owner_from_keys(init_keys)
-                per_dev = [init_rows[(owner == d) & explored_mask]
-                           for d in range(D)]
+                most_keys = int(np.bincount(owner, minlength=D).max())
+                most_rows = int(np.bincount(owner[explored_mask],
+                                            minlength=D).max())
             FC = _pow2_at_least(
-                max(max((len(p) for p in per_dev), default=1), 1,
-                    int(hint.get("FC", 1))), lo=64)
+                max(most_rows, 1, int(hint.get("FC", 1))), lo=64)
             SC = _pow2_at_least(max(4 * FC, int(hint.get("SC", 1))),
                                 lo=256)
             shard_cap = self._mesh_shard_cap()
@@ -1954,45 +1976,37 @@ class MeshExplorer(TpuExplorer):
                 # tier from the start, floored so every shard seats
                 # its init keys (a too-small cap soft-breaches)
                 SC = min(SC, shard_cap)
-                SC = max(SC, _pow2_at_least(
-                    max(int(np.bincount(owner, minlength=D).max()), 1),
-                    lo=64))
+                SC = max(SC, _pow2_at_least(max(most_keys, 1), lo=64))
             TRL = _pow2_at_least(max(int(hint.get("TRL", 1)), 16),
                                  lo=16)
             explored_idx = np.nonzero(explored_mask)[0]
-            seen_np, frontier_np, fcount_np, scount_np = \
+            # the one layout rule, at the size of the fullest shard
+            seen_head, fr_head, fcount_np, scount_np = \
                 self._init_shards(
-                    init_rows, explored_idx, D, SC, FC,
+                    init_rows, explored_idx, D, most_keys, most_rows,
                     keys=init_keys, packed=init_packed, owner=owner)
             if self.store_trace:
-                with tel.timed("seed.tables_s"):
-                    self._levels.append((frontier_np.copy(), None, FC))
-            with tel.timed("seed.upload_s"):
-                seen = self._put(seen_np)
-                frontier = self._put(frontier_np)
-                fcount = self._put(fcount_np.astype(np.int32))
-                seen_count = self._put(scount_np)
+                # level 0 for trace reconstruction: the head rows
+                self._levels.append((fr_head, None, FC))
             depth = 0
 
-        tr_rows = tr_src = None
-        if self.store_trace:
-            with tel.timed("seed.tables_s"):
-                ring_np = np.full((D, TRL, FC, PW), SENTINEL, np.int32)
-                src_np_ = np.full((D, TRL, FC), -1, np.int32)
-                for l, (rows, src, _fcl) in enumerate(self._levels[1:]):
-                    k = min(rows.shape[1], FC)
-                    ring_np[:, l, :k] = rows[:, :k]
-                    src_np_[:, l, :k] = src[:, :k]
-            with tel.timed("seed.upload_s"):
-                tr_rows = self._put(ring_np)
-                tr_src = self._put(src_np_)
-            # _levels beyond the init level will be re-materialized from
-            # the ring on demand; keep only level 0 host-side
-            del self._levels[1:]
-        # what scale adds (ISSUE 30): the shards and the ring are built
-        # on the host at full capacity and uploaded, every search
+        with tel.timed("seed.upload_s"):
+            seen = self._mesh_table((D, SC, K), seen_head,
+                                    fill=_invalid_row(K))
+            frontier = self._mesh_table((D, FC, PW), fr_head)
+            fcount = self._put(fcount_np)
+            seen_count = self._put(scount_np)
+            tr_rows = tr_src = None
+            if self.store_trace:
+                tr_rows = self._mesh_table((D, TRL, FC, PW), ring_head)
+                tr_src = self._mesh_table((D, TRL, FC), src_head, fill=-1)
+                # _levels beyond the init level will be re-materialized
+                # from the ring on demand; keep only level 0 host-side
+                del self._levels[1:]
+        # the bytes of the heads handed to the devices (ISSUE 35): the
+        # capacity-sized tables are made there
         tel.counter("search.seed_bytes", _nbytes(
-            seen, frontier, tr_rows, tr_src))
+            seen_head, fr_head, ring_head, src_head))
         return (seen, seen_count, frontier, fcount, tr_rows, tr_src, SC,
                 FC, TRL, depth, generated, distinct)
 

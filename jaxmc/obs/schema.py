@@ -562,9 +562,12 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       work happens (the form of `compile.xla_compile_s`; NOT spans,
       so they take no idle seconds out of `search.seed`):
       `seed.keys_s` (init keys, owner hash, lexsorts), `seed.tables_s`
-      (host-built tables), `seed.upload_s` (the calls that hand them
-      to the device, up to their return), `dispatch.launch_s` (every
-      dispatch site's `fn(*args)` up to its return).
+      (what the host builds of the tables: since ISSUE 35 their
+      HEADS, the init or checkpoint rows — the capacity-sized tables
+      are filled on the device), `seed.upload_s` (the calls that hand
+      the heads to the device and make its tables, up to their
+      return), `dispatch.launch_s` (every dispatch site's `fn(*args)`
+      up to its return).
     - top-level `requests`: the last 512 search records, one per
       closed `search` span of `CheckSession.explore()`:
         {rid, name, t0, wall_s, cpu_s, spans: {name: wall_s},

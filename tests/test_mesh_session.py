@@ -235,11 +235,15 @@ def test_slots_merged_follows_each_shards_live_rows(tmp_path, monkeypatch):
 
 @pytest.mark.parametrize("no_trace", [False, True])
 def test_seed_and_table_bytes_on_the_mesh(no_trace, tmp_path):
-    """`search.seed_bytes` / `search.table_bytes` (ISSUE 30) on four
-    shards: the [D, SC, K] seen shards and [D, FC, PW] frontier shards
-    the host builds and `_put`s, and, where traces are kept, the trace
-    ring [D, TRL, FC, PW] + [D, TRL, FC]; nothing grows at these
-    capacities, so the carried tables are the seeded ones."""
+    """`search.seed_bytes` / `search.table_bytes` on four shards.  The
+    gauge (ISSUE 30) is the [D, SC, K] seen shards and [D, FC, PW]
+    frontier shards and, where traces are kept, the trace ring
+    [D, TRL, FC, PW] + [D, TRL, FC]; nothing grows at these capacities.
+    The counter (ISSUE 35) is the bytes of the HEADS handed to the
+    devices, from which they fill those tables: the 9 init states' keys
+    and packed rows as one [D, H, .] block each, H the fullest shard's
+    count under `_owner_from_keys`; a fresh search's ring has no head."""
+    import time
     cfg = tmp_path / "t.cfg"
     cfg.write_text(_cfg_text(2, 3, 4))
     caps = {"SC": 1 << 10, "FC": 256, "TRL": 16, "GAM16": 32, "MSL": 16,
@@ -249,13 +253,19 @@ def test_seed_and_table_bytes_on_the_mesh(no_trace, tmp_path):
         sess = _session(TRANSFER, str(cfg), tel, devices=4, res_caps=caps,
                         no_trace=no_trace)
         assert _answer(sess.explore())[:2] == (256, 166)
-    K, PW = sess.engine.K, sess.engine.PW
+    ex = sess.engine
+    K, PW = ex.K, ex.PW
     assert K == 5
-    want = 4 * 4 * (caps["SC"] * K + caps["FC"] * PW)
+    table = 4 * 4 * (caps["SC"] * K + caps["FC"] * PW)
     if not no_trace:
-        want += 4 * 4 * caps["TRL"] * caps["FC"] * (PW + 1)
-    assert tel.counters["search.seed_bytes"] == want
-    assert tel.gauges["search.table_bytes"] == want
+        table += 4 * 4 * caps["TRL"] * caps["FC"] * (PW + 1)
+    assert tel.gauges["search.table_bytes"] == table
+    init_rows, explored, n_init, _ = ex._prepare_init(time.time(), [])
+    assert n_init == 9 == len(explored)
+    H = int(np.bincount(ex._owner_from_keys(ex._host_keys(init_rows)[0]),
+                        minlength=4).max())
+    assert 3 <= H < 9
+    assert tel.counters["search.seed_bytes"] == 4 * 4 * H * (K + PW)
 
 
 def test_slots_merged_of_the_pinned_model_on_four_shards(monkeypatch):

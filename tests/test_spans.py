@@ -411,12 +411,14 @@ def test_capacity_counters_by_hand(engine):
 
 @pytest.mark.parametrize("engine", sorted(ENGINES))
 def test_seed_and_table_bytes_by_hand(engine):
-    """constoy at the capacities above: `search.seed_bytes` is the
-    host-built seen table and frontier handed to the device at the start
-    of a search, at their full capacities, int32; `search.table_bytes`
+    """constoy at the capacities above: `search.seed_bytes` is the bytes
+    of the HEADS the host hands the device at the start of a search — the
+    one init state's key and its packed row, int32; the capacity-sized
+    tables are filled on the device (ISSUE 35; up to PR 34 it was both
+    tables at full capacity, built on the host).  `search.table_bytes` is
     the engine's capacity-sized tables at the capacities in force at its
-    end, defined per engine (ISSUE 30; `bench/SPANS.deep.md`).  A second search uploads as much again;
-    the gauge stays."""
+    end, defined per engine (ISSUE 30; `bench/SPANS.deep.md`).  A second
+    search hands over as much again; the gauge stays."""
     pytest.importorskip("jax")
     tel = obs.Telemetry()
     caps = {"SC": 1 << 12, "FCap": 1 << 11, "AccCap": 1 << 13, "VC": 128}
@@ -431,14 +433,15 @@ def test_seed_and_table_bytes_by_hand(engine):
             # are the floors FC = SC = 256, the seen table then grows
             # once, to 1024, to hold 1 + A x FC candidates
             assert (K, PW) == (2, 1)
-            seed = 4 * (256 * K + 256 * PW)
             table = 4 * (1024 * K + 256 * PW)
         else:
             # 128-bit fingerprints under a validity lane; the
             # accumulator carries keys and rows
             assert (K, PW) == (5, 1)
-            seed = 4 * (caps["SC"] * K + caps["FCap"] * PW)
-            table = seed + 4 * caps["AccCap"] * (K + PW)
+            table = 4 * (caps["SC"] * K + caps["FCap"] * PW
+                         + caps["AccCap"] * (K + PW))
+        n_init = 1
+        seed = 4 * n_init * (K + PW)
         assert tel.counters["search.seed_bytes"] == seed
         assert tel.gauges["search.table_bytes"] == table
         assert sess.explore().distinct == 21

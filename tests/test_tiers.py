@@ -575,9 +575,11 @@ class TestColdTiersLastOneSearch:
 
     def test_the_capped_tables_are_made_on_the_device(self, tmp_path,
                                                       monkeypatch):
-        """`_device_table` is the host-built table, row for row; a capped
-        resident search hands the device its init rows alone where the
-        uncapped one builds both tables on the host at full capacity."""
+        """`_device_table` is the host-built table, row for row; a
+        resident search hands the device its init rows alone, capped or
+        not: ONE path since ISSUE 35 (up to PR 34 the uncapped search
+        built both tables on the host at full capacity; the tables the
+        engines start from: `tests/test_device_seed.py`)."""
         monkeypatch.setenv("JAXMC_CAP_PROFILE", "0")
         from jaxmc.backend.bfs import SENTINEL, TpuExplorer
         head = np.arange(12, dtype=np.int32).reshape(4, 3)
@@ -590,17 +592,17 @@ class TestColdTiersLastOneSearch:
                 TpuExplorer._device_table((16, 3), none), want)
         _, opts, cap = ENGINES["resident"]
         seed = {}
-        for name, kw in (("host", {}), ("device", {"seen_cap": cap})):
+        for name, kw in (("uncapped", {}), ("capped", {"seen_cap": cap})):
             tel = obs.Telemetry()
             with obs.use(tel):
                 sess = _toy_session(tmp_path, tel, **opts, **kw)
                 sess.explore()
                 ex = sess.engine
             seed[name] = tel.counters["search.seed_bytes"]
+        # 4 procs / MaxMoney 2: 16 init states, their keys and packed rows
+        assert seed["uncapped"] == seed["capped"] == 4 * 16 * (ex.K + ex.PW)
         full = 4 * (TOY_CAPS["SC"] * ex.K + TOY_CAPS["FCap"] * ex.PW)
-        assert seed["host"] == full
-        assert 0 < seed["device"] < full // 16
-        assert seed["device"] % (4 * (ex.K + ex.PW)) == 0
+        assert seed["capped"] < full // 16
 
     def test_reexplore_leaks_no_run_files(self, tmp_path, monkeypatch):
         """A re-explored search whose disk tier is past the checkpoint's
