@@ -1116,6 +1116,9 @@ class ServeDaemon:
             warm = self.warm.get(sig)
             if warm is not None:
                 self._touch_warm_locked(sig)
+        # the warm/replay decision below mirrors owner.run_solo's, which
+        # is the reference (the owner is the default device path): a
+        # completed entry AND its finalized checkpoint on disk
         warm_engine = resumed = False
         with self._locked_sig(sig), obs.use_local(job_tel), \
                 self.tel.span("job", id=jid, sig=sig, spec=job["spec"],

@@ -216,6 +216,10 @@ def run_solo(md: Dict[str, Any]) -> Dict[str, Any]:
         "env": obs.environment_meta()})
     sig = md.get("sig")
     entry = _WARM.get(sig) if sig else None
+    # the warm/replay decision (a completed entry AND its finalized
+    # checkpoint on disk): THIS is the reference since the owner is the
+    # default device path; daemon._run_batch_inner mirrors it for
+    # in-process jobs
     warm_engine = bool(entry is not None and entry.get("completed")
                        and ck and os.path.exists(ck))
     resumed = bool(cfg.resume)

@@ -46,6 +46,30 @@ options, batch_leader?, error?, tenant?, daemon?, stolen_by?}.
 GET /jobs/<id> with the quarantine record: the named verdict, the
 captured fault context, and the trace tail at death.
 
+The `serve` block of a served job's artifact (GET /jobs/<id>/result):
+
+  sig, bsig?               the job's signature (and batch class)
+  warm_engine              answered by an already-built engine: the
+                           replay of its finalized checkpoint
+  resumed_from_checkpoint  the search started from a checkpoint (a warm
+                           replay, a previous life's, a takeover)
+  device_owner             ran in the device-owner child
+  job_wall_s               the run's wall where it ran (the owner's
+                           `run_solo`, config to summary)
+  window_recompiles        levels flagged `fresh_compile` (a NEW engine
+                           flags its first dispatch, compiled or loaded
+                           from the persistent cache: `prof.programs[]
+                           .origin` says which)
+  profile_hits, persistent_cache_hits   counters of the job's recorder
+  batched_with             ids answered by the same run
+  cost_estimate            analyze's state-space estimate, if any
+
+A job's clock, as far as the record keeps it: `submitted_at` (the spool's
+hard write in `submit()`), `started_at` (a worker marked it running) and
+`finished_at` (the final record's write, after the artifact's); the
+artifact's `job_wall_s` is the share of `started_at -> finished_at` spent
+in the run itself.
+
 Job SIGNATURES (`job_signature`) hash the spec/cfg CONTENTS plus every
 result-affecting option (session.SessionConfig.job_signature_fields),
 so "identical job" means identical model and identical search — the
