@@ -53,6 +53,7 @@ FP_THRESHOLD = 48  # lanes; beyond this, dedup on 128-bit fingerprints
 # (the cached report itself may legitimately be None = analysis bailed)
 _SENTINEL_NO_REPORT = object()
 _POR_UNSET = object()
+_SIG_UNSET = object()  # TpuExplorer._program_sig not asked for yet
 
 # resident-mode status codes (one summary scalar per dispatched batch)
 ST_CONTINUE = 0     # level budget exhausted, search not finished
@@ -610,6 +611,65 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
                 merge_blocks=merge_blocks)
 
 
+@jax.named_scope("jaxmc.keys")
+def _keys_of_rows(plan, view_fn, canon_fn, fp_mode, rows, valid):
+    """(keys, packed_rows, pack_ovf) for a block of UNPACKED rows.
+
+    keys: [N, K] dedup key lanes — an explicit validity lane FIRST
+    (0=valid, 1=invalid, sorting after all valid rows; SENTINEL
+    data), then the key basis: the cfg VIEW's value lanes when one
+    is declared, else the BIT-PACKED row (compile/pack.py) —
+    fingerprinted to 4 words in fp mode.
+
+    packed_rows: [N, PW] the packed rows for engine storage
+    (SENTINEL-filled where invalid).
+
+    pack_ovf: scalar bool — some VALID row had a guarded lane
+    outside its profiled bit range; the engines route it into the
+    overflow channel as kernel2.OV_PACK (an exact abort naming
+    JAXMC_PACK=0, never a silently wrong count).
+
+    With cfg SYMMETRY, the KEY basis is the orbit's canonical
+    representative (compile/symmetry2.py) while the stored packed
+    row keeps the original state — same partition, same traces, as
+    the unpacked engines."""
+    packed, povf = plan.pack_rows(rows)
+    pack_ovf = jnp.any(povf & valid)
+    packed = jnp.where(valid[:, None], packed, SENTINEL)
+    if view_fn is not None:
+        # SYMMETRY composes with VIEW exactly like the interp's
+        # state_fingerprint: the view evaluates over the orbit's
+        # CANONICAL representative (view of the raw row would count
+        # symmetric states as distinct — caught in review by a
+        # 2-process SYMMETRY+VIEW repro, 17/9 vs the interp's 12/6)
+        vrows = rows
+        if canon_fn is not None:
+            vrows = jnp.where(valid[:, None], canon_fn(rows),
+                              rows)
+        kb = jax.vmap(view_fn)(vrows)
+        if kb.ndim == 1:
+            kb = kb[:, None]
+    elif canon_fn is not None:
+        crows = jnp.where(valid[:, None], canon_fn(rows), rows)
+        kb, cpovf = plan.pack_rows(crows)
+        kb = jnp.where(valid[:, None], kb, SENTINEL)
+        pack_ovf = pack_ovf | jnp.any(cpovf & valid)
+    else:
+        kb = packed
+    k = fingerprint128(kb) if fp_mode else kb
+    k = jnp.where(valid[:, None], k, SENTINEL)
+    vlane = jnp.where(valid, 0, 1).astype(jnp.int32)
+    return (jnp.concatenate([vlane[:, None], k], axis=1), packed,
+            pack_ovf)
+
+
+def _has_executable(fn) -> bool:
+    """Does jax hold an executable for this (prof-wrapped) jitted
+    function already?"""
+    size = getattr(getattr(fn, "__wrapped__", fn), "_cache_size", None)
+    return bool(size and size())
+
+
 class _LiveGraph:
     """Host-side behavior-graph accumulator for device runs.
 
@@ -713,6 +773,7 @@ class TpuExplorer:
         self.por = bool(por)
         self.por_reason: Optional[str] = None
         self._por_memo: Any = _POR_UNSET
+        self._program_sig_memo: Any = _SIG_UNSET
         self._por_stats = {"ample": 0, "expanded": 0, "masked": 0}
         if donor is not None:
             self._clone_from_donor(
@@ -1808,56 +1869,19 @@ class TpuExplorer:
         return [SYMMETRY_WARNING + (f" ({self._sym_fallback})"
                                     if self._sym_fallback else "")]
 
-    @jax.named_scope("jaxmc.keys")
     def _keys_of(self, rows, valid):
-        """(keys, packed_rows, pack_ovf) for a block of UNPACKED rows.
+        """`_keys_of_rows` over this engine's plan, view, symmetry and
+        key mode."""
+        return _keys_of_rows(self.plan, self.view_fn, self.canon_fn,
+                             self.fp_mode, rows, valid)
 
-        keys: [N, K] dedup key lanes — an explicit validity lane FIRST
-        (0=valid, 1=invalid, sorting after all valid rows; SENTINEL
-        data), then the key basis: the cfg VIEW's value lanes when one
-        is declared, else the BIT-PACKED row (compile/pack.py) —
-        fingerprinted to 4 words in fp mode.
-
-        packed_rows: [N, PW] the packed rows for engine storage
-        (SENTINEL-filled where invalid).
-
-        pack_ovf: scalar bool — some VALID row had a guarded lane
-        outside its profiled bit range; the engines route it into the
-        overflow channel as kernel2.OV_PACK (an exact abort naming
-        JAXMC_PACK=0, never a silently wrong count).
-
-        With cfg SYMMETRY, the KEY basis is the orbit's canonical
-        representative (compile/symmetry2.py) while the stored packed
-        row keeps the original state — same partition, same traces, as
-        the unpacked engines."""
-        packed, povf = self.plan.pack_rows(rows)
-        pack_ovf = jnp.any(povf & valid)
-        packed = jnp.where(valid[:, None], packed, SENTINEL)
-        if self.view_fn is not None:
-            # SYMMETRY composes with VIEW exactly like the interp's
-            # state_fingerprint: the view evaluates over the orbit's
-            # CANONICAL representative (view of the raw row would count
-            # symmetric states as distinct — caught in review by a
-            # 2-process SYMMETRY+VIEW repro, 17/9 vs the interp's 12/6)
-            vrows = rows
-            if self.canon_fn is not None:
-                vrows = jnp.where(valid[:, None], self.canon_fn(rows),
-                                  rows)
-            kb = jax.vmap(self.view_fn)(vrows)
-            if kb.ndim == 1:
-                kb = kb[:, None]
-        elif self.canon_fn is not None:
-            crows = jnp.where(valid[:, None], self.canon_fn(rows), rows)
-            kb, cpovf = self.plan.pack_rows(crows)
-            kb = jnp.where(valid[:, None], kb, SENTINEL)
-            pack_ovf = pack_ovf | jnp.any(cpovf & valid)
-        else:
-            kb = packed
-        k = fingerprint128(kb) if self.fp_mode else kb
-        k = jnp.where(valid[:, None], k, SENTINEL)
-        vlane = jnp.where(valid, 0, 1).astype(jnp.int32)
-        return (jnp.concatenate([vlane[:, None], k], axis=1), packed,
-                pack_ovf)
+    def _keys_fn(self):
+        """`_keys_of` as a closure over the four things it reads and
+        not over the engine: a program the registry keeps
+        (`_held_program`) then pins kernels, not the engine that made
+        it with its init states and tables."""
+        return partial(_keys_of_rows, self.plan, self.view_fn,
+                       self.canon_fn, self.fp_mode)
 
     def _host_keys(self, rows_np):
         """Host-side (keys, packed, pack_ovf) over unpacked numpy rows —
@@ -1872,8 +1896,12 @@ class TpuExplorer:
         cap = _pow2_at_least(n, lo=8)
         jf = self._hostkeys_cache.get(cap)
         if jf is None:
-            jf = obs.prof_wrap("bfs.host_keys", jax.jit(
-                lambda rows, valid: self._keys_of(rows, valid)), key=cap)
+            keys_of = self._keys_fn()
+            jf = self._held_program(
+                "bfs.host_keys", cap, lambda: obs.prof_wrap(
+                    "bfs.host_keys", jax.jit(
+                        lambda rows, valid: keys_of(rows, valid)),
+                    key=cap))
             self._hostkeys_cache[cap] = jf
         buf = np.repeat(np.asarray(rows_np[:1], np.int32), cap, axis=0)
         buf[:n] = rows_np
@@ -2546,12 +2574,22 @@ class TpuExplorer:
             obs.current().counter("compile.cache_hits")
             return self._res_cache[key]
         obs.current().counter("compile.cache_misses")
+        # this engine has none: the process may (ISSUE 37) — an earlier
+        # engine with this program signature made the same program
+        jitted = self._held_program(
+            "bfs.resident_run", key,
+            lambda: self._make_resident_run(*key))
+        self._res_cache[key] = jitted
+        return jitted
+
+    def _make_resident_run(self, SC, FCap, AccCap, VC, CH):
+        key = (SC, FCap, AccCap, VC, CH)
         A, W, K, PW = self.A, self.W, self.K, self.PW
         plan = self.plan
         C = A * CH
         inv_fns = self.inv_fns
         con_fns = self.constraint_fns
-        keys_of = self._keys_of
+        keys_of = self._keys_fn()
         expand = self._expand_fn()
         check_deadlock = self.model.check_deadlock
         assert FCap % CH == 0
@@ -2885,10 +2923,8 @@ class TpuExplorer:
         # packed frontier (arg 2) — the two big device buffers — update
         # in place across dispatches instead of copying per batch
         donate = (0, 2) if self.donate else ()
-        jitted = obs.prof_wrap("bfs.resident_run", jax.jit(
+        return obs.prof_wrap("bfs.resident_run", jax.jit(
             run, static_argnames=(), donate_argnums=donate), key=key)
-        self._res_cache[key] = jitted
-        return jitted
 
 
     def _save_caps_profile(self, caps: Dict[str, int],
@@ -3055,6 +3091,77 @@ class TpuExplorer:
                      [str(v) for v in lay.uni.values],
                      lay.plan.signature()))
         return hashlib.sha256(desc.encode()).hexdigest()
+
+    def _program_sig(self) -> Optional[str]:
+        """Signature of everything this engine's traced closures read
+        (ISSUE 37): equal signatures => the same jitted programs, so
+        an engine may dispatch the program an earlier engine of its
+        process made (`compile/cache.py`'s registry) — a stamped or
+        reformatted copy of a spec parses to the identical AST
+        (`front/tla_ast.py` keeps no source positions) and pays a build
+        and a search, not a trace, a lowering and a load.  Over the
+        loaded model (`cache.model_canonical`: definitions with the
+        cfg's constants bound, the checked formulas), the layout
+        (`_layout_sig`), the engine's static shape, the backend, every
+        JAXMC_* variable of the environment and the jax / jaxlib
+        versions.  None — the engine keeps its own jits, as before the
+        registry — where that cannot be said for certain: a hybrid
+        engine (interpreter-fallback arms or predicates) or anything
+        `cache.canonical` cannot render.  Made at the first ask, once
+        (the mesh engine settles `fp_mode`, `K` and its backend
+        descriptor after this class's constructor); `_demote_arms`
+        forgets it."""
+        if self._program_sig_memo is _SIG_UNSET:
+            with obs.current().timed("compile.program_sig_s"):
+                self._program_sig_memo = self._make_program_sig()
+        return self._program_sig_memo
+
+    def _make_program_sig(self) -> Optional[str]:
+        import hashlib
+        from ..compile import cache as _cache
+        if self.fb_arms or self.fb_invs or self.fb_cons:
+            return None
+        try:
+            import jaxlib
+            por_plan = self._por_plan() if self.por else None
+            desc = (
+                "jaxmc.program/1", type(self).__name__,
+                _cache.model_canonical(self.model), self._layout_sig(),
+                _cache.canonical((
+                    self.A, self.W, self.PW, self.K, self.key_width,
+                    self.view_width, self.fp_mode, self.labels_flat,
+                    [ca.n_slots for ca in self.compiled], self._ca_arm,
+                    self._demotable, self.chunk, self.resident,
+                    self.host_seen, self.store_trace, self.collect_edges,
+                    self.por, None if por_plan is None else sorted(
+                        (k, np.asarray(v).tolist())
+                        for k, v in por_plan.items()),
+                    self.donate, self.seen_cap is not None,
+                    self.seen_mode_req, self._lift_names,
+                    self.canon_fn is not None, self.sym_identity,
+                    self._sym_fallback, self.sample_cfg,
+                    sorted(vars(self.bounds).items()),
+                    getattr(self, "D", None),
+                    # this module's tuning constants, which a trace
+                    # reads too (tests patch them)
+                    FP_THRESHOLD, _PROBE_BLOCK_MIN, _PROBE_SAMPLE,
+                    _MERGE_BLOCK_ROWS)),
+                (self.backend_desc.platform,
+                 jax.devices()[0].device_kind,
+                 self.backend_desc.profile_ns,
+                 self.backend_desc.device_count),
+                tuple(sorted((k, v) for k, v in os.environ.items()
+                             if k.startswith("JAXMC_"))),
+                jax.__version__, jaxlib.__version__)
+        except (_cache.Unrenderable, RecursionError):
+            return None
+        return hashlib.sha256(repr(desc).encode()).hexdigest()
+
+    def _held_program(self, site: str, key, make: Callable) -> Callable:
+        """This engine's program of `site` for `key`: the one its
+        process holds under `_program_sig()`, else `make()`'s."""
+        from ..compile.cache import held_program
+        return held_program(site, self._program_sig(), key, make)
 
     def _write_ck(self, mode: str, **state) -> None:
         # checksummed + schema-versioned container (engine/ckpt.py):
@@ -3438,8 +3545,13 @@ class TpuExplorer:
                                        truncated=True, drained=True)
             ck_key = (caps["SC"], caps["FCap"], caps["AccCap"],
                       caps["VC"], CH)
-            fresh_compile = ck_key not in self._res_cache
+            new_here = ck_key not in self._res_cache
             runf = self._get_resident_run(*ck_key)
+            # a program the process already holds (ISSUE 37) has its
+            # executable: nothing compiles or loads, and the span, the
+            # level record and what reads them (the watchdog,
+            # `window_recompiles`) say so
+            fresh_compile = new_here and not _has_executable(runf)
             # the program's capacity-sized tables at the capacities in
             # force: seen and frontier (handed in, handed back) and the
             # level accumulator's keys and rows (re-made every level at
@@ -3462,8 +3574,10 @@ class TpuExplorer:
             disp_wall = time.time() - t_disp
             # adapt levels-per-dispatch toward the host-attention target;
             # a dispatch that just paid an XLA recompile (cap growth) is
-            # not evidence about execution speed — skip it
-            if fresh_compile:
+            # not evidence about execution speed — skip it (an engine's
+            # first dispatch of a held program too: the schedule of
+            # dispatches stays what it was before there was a registry)
+            if new_here:
                 pass
             elif disp_wall > 1.5 * target_s and maxlvl > 1:
                 maxlvl = max(1, maxlvl // 2)
@@ -4395,6 +4509,7 @@ class TpuExplorer:
         # the interpreter expands out of the device's sight — recompute
         # (the hybrid refusal fires on the restarted run)
         self._por_memo = _POR_UNSET
+        self._program_sig_memo = _SIG_UNSET  # hybrid now: unkeyed
         self._step_cache.clear()
         self._hstep_cache.clear()
         # grouped-dispatch plans index the OLD compiled list: stale

@@ -54,6 +54,13 @@ Fault sites (jaxmc/faults.py, chaos suite): `cache_hang` wedges the
 health probe, `cache_corrupt` zero-truncates one entry before the scan.
 tests/test_cache_guard.py pins that each one degrades to cold
 compilation with the run intact.
+
+The persistent cache spares the COMPILE; a new engine still traces,
+lowers and loads.  The program registry (ISSUE 37, end of this file)
+spares those too where the process already holds the program:
+`held_program` keys an engine's jitted programs by a signature of
+everything their trace reads (`canonical`, `TpuExplorer._program_sig`).
+  counter compile.program_hits / compile.program_misses / _unkeyed
 """
 
 from __future__ import annotations
@@ -64,7 +71,8 @@ import subprocess
 import sys
 import threading
 import time
-from typing import Optional, Tuple
+from collections import OrderedDict
+from typing import Any, Callable, Optional, Tuple
 
 _OFF_VALUES = ("0", "off", "none", "disabled")
 
@@ -531,3 +539,169 @@ def save_capacity_profile(module: str, layout_sig: str,
     except Exception:  # noqa: BLE001 — a profile is a hint, never a crash
         return None
 
+
+
+# ------------------------------------------------------------------
+# The program registry (ISSUE 37).
+#
+# A served edit that leaves the model unchanged is a new content hash,
+# so a new session and a new engine, whose jitted programs are new
+# `jax.jit` objects: jax traces the while_loop program again, lowers
+# it, hashes the module and loads the executable from the persistent
+# cache — seconds of host time with the chip idle, for a program the
+# process has dispatched before.  The registry keeps, process-wide, the
+# jitted callables engines made, under (site, program signature, the
+# site's own key).  An engine whose own cache misses asks here before
+# it makes a new `jax.jit`; on a hit it dispatches the callable an
+# earlier engine made and jax's fast path finds the executable by the
+# function's identity: no trace, no lowering, no cache key, no load.
+#
+# Soundness rests on the SIGNATURE covering everything the trace reads
+# (`TpuExplorer._program_sig`): a wrong hit would answer one spec with
+# another's program.  So `canonical` FAILS CLOSED — anything it cannot
+# render without an address or an iteration order gives no signature,
+# and an engine without one keeps its own jits, exactly the path before
+# the registry (counter `compile.program_unkeyed`).
+#
+# What an entry pins on the host: the prof-wrapped jitted callable (and
+# on it the program record, `wrapper.program`), jax's executable for
+# it, and what the traced closures hold — the first engine's kernel
+# closures with their KernelCtx (model, layout, bounds), lane plan and
+# predicate kernels; NOT the engine (bfs.py hands its sites
+# `_keys_fn()`, free of `self`).  Bounded, least recently used out
+# first; no environment variable, no option.
+
+_PROGRAMS_MAX = 64
+_PROGRAMS: "OrderedDict[tuple, Callable]" = OrderedDict()
+_PROGRAMS_LOCK = threading.Lock()  # the in-process serve path builds
+# engines on worker threads
+
+
+class Unrenderable(Exception):
+    """`canonical` met a value it cannot render canonically."""
+
+
+def canonical(v: Any) -> Any:
+    """`v` as nested tuples of str / int / bool / None whose `repr` is
+    the same for equal values in every process and at every address:
+    AST nodes (`front/tla_ast.py`: frozen dataclasses, no source
+    positions) by class and fields, sets and dicts sorted, model values
+    and built-ins by name, definitions (`OpClosure`) by name, params,
+    body and captured bindings.  Raises `Unrenderable` for anything
+    else — a function, an object whose `repr` holds an address, a
+    lazily materialized value, a cycle."""
+    import dataclasses
+
+    from ..front import tla_ast as A
+    from ..front.cfg import CfgModelValue, ModelConfig
+    from ..sem.eval import BuiltinOp, OpClosure
+    from ..sem.modules import InstanceNamespace, LoadedModule
+    from ..sem.values import Fcn, FcnSetV, InfiniteSet, ModelValue
+
+    open_: set = set()  # ids on the path from the root: a cycle's guard
+
+    def unordered(items):
+        return tuple(sorted((walk(x) for x in items), key=repr))
+
+    def render(v):
+        t = type(v)
+        if t in (tuple, list):
+            return ("seq",) + tuple(walk(x) for x in v)
+        if t in (frozenset, set):
+            return ("set",) + unordered(v)
+        if t is dict:
+            return ("map",) + unordered(v.items())
+        if t in (ModelValue, CfgModelValue):
+            return ("mv", v.name)
+        if t is Fcn:
+            return ("fcn",) + unordered(v.d.items())
+        if t is InfiniteSet:
+            return ("inf", v.kind, walk(v.param))
+        if t is FcnSetV:
+            return ("fcnset", walk(v.dom), walk(v.rng))
+        if t is BuiltinOp:
+            return ("builtin", v.name)
+        if t is OpClosure:
+            return ("op", v.name, walk(v.params), walk(v.body),
+                    walk(v.bound), walk(v.defs))
+        if t is InstanceNamespace:
+            return ("instance", walk(v.module), walk(v.substs),
+                    walk(v.params))
+        if t is LoadedModule:
+            # never its path: a stamped copy lives somewhere else
+            return ("module", v.name, walk(v.ast.extends), walk(v.defs),
+                    walk(v.constants), walk(v.variables))
+        if isinstance(v, A.Node) or t is ModelConfig:
+            return (t.__name__,) + tuple(
+                walk(getattr(v, f.name)) for f in dataclasses.fields(v))
+        raise Unrenderable(f"a {t.__name__}")
+
+    def walk(v):
+        if v is None or type(v) in (bool, int, str, float):
+            return v
+        if id(v) in open_:
+            raise Unrenderable(f"cycle through a {type(v).__name__}")
+        open_.add(id(v))
+        try:
+            return render(v)
+        finally:
+            open_.discard(id(v))
+
+    return walk(v)
+
+
+def model_canonical(model) -> Any:
+    """Everything of a loaded `Model` (`sem/modules.py`) that an
+    engine's kernels are built from: the definition table with the
+    cfg's constants bound, the checked formulas, the module's name and
+    what it extends — and not where its files are."""
+    m = model
+    return canonical((
+        "model", m.module.name, m.module.ast.extends, m.module.constants,
+        m.vars, m.cfg, m.defs, m.init, m.next, m.invariants,
+        m.constraints, m.action_constraints, m.properties, m.symmetry,
+        m.view, bool(m.check_deadlock), m.fairness))
+
+
+def held_program(site: str, sig: Optional[str], key,
+                 make: Callable[[], Callable]) -> Callable:
+    """The jitted program of `site` for (`sig`, `key`): the one this
+    process already holds, else `make()`'s, kept for the next engine.
+    `sig` None (the engine could not sign what its trace reads): always
+    `make()`'s, and nothing is kept.  A hit tells the active recorder
+    what a new executable would have (`Profiler.hold`: the program
+    record with origin "held", the `program.*` gauges) and leaves
+    `compile.xla_compile_s` in its counters, at 0.0 if nothing loads."""
+    from .. import obs
+    tel = obs.current()
+    if sig is None:
+        tel.counter("compile.program_unkeyed")
+        return make()
+    rk = (site, sig, key)
+    with _PROGRAMS_LOCK:
+        fn = _PROGRAMS.get(rk)
+        hit = fn is not None
+        if hit:
+            _PROGRAMS.move_to_end(rk)
+        else:
+            # `make` builds closures and a `jax.jit` object: no trace,
+            # so the lock is held for microseconds, and two threads
+            # asking for one key get one callable
+            fn = _PROGRAMS[rk] = make()
+            while len(_PROGRAMS) > _PROGRAMS_MAX:
+                _PROGRAMS.popitem(last=False)
+    tel.counter("compile.program_hits" if hit
+                else "compile.program_misses")
+    if hit:
+        tel.counter("compile.xla_compile_s", 0.0)
+        prof = getattr(tel, "prof", None)
+        if prof is not None:
+            prof.hold(fn, tel)
+    return fn
+
+
+def forget_programs() -> None:
+    """Empty the registry (tests; a process that changed what a trace
+    reads behind the signature's back)."""
+    with _PROGRAMS_LOCK:
+        _PROGRAMS.clear()

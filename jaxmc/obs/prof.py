@@ -48,6 +48,16 @@ layer:
             function called: a function that holds several (a
             recompile under one engine key; the site's `recompiles`
             says so) charges them all to the last.
+  held      a third origin (ISSUE 37): a new engine that dispatches a
+            program its PROCESS already holds (compile/cache.py's
+            registry: same program signature, an earlier engine's
+            jitted callable) makes no executable, so no site grows and
+            `record` has nothing to read.  The registry calls `hold`
+            instead: the record the executable got when it came into
+            being (kept on the site's wrapper, `wrapper.program`) is
+            appended to THIS recorder's `programs` with origin "held",
+            `xla_s` 0.0 and its own `dispatches`, and the gauges are
+            published as for a new executable.
   launch    every mode also charges the host seconds inside
             `fn(*args)` up to its RETURN (the enqueue; on a new
             executable the compile or load too) to the site and to the
@@ -195,11 +205,14 @@ class Profiler:
         except Exception:  # noqa: BLE001 — profiling never breaks a run
             return None
 
-    def record(self, name: str, fn, args, kwargs, key=None):
+    def record(self, name: str, fn, args, kwargs, key=None,
+               wrapper=None):
         """One profiled dispatch.  Every mode: count, recompile delta,
         the host seconds up to `fn`'s return (`dispatch.launch_s`) and,
-        where the call made a new executable, its program record.  Wall
-        mode: + block-until-ready wall and arg/result bytes."""
+        where the call made a new executable, its program record (left
+        on `wrapper`, the site's `wrap()`, for a later engine's
+        recorder: `hold`).  Wall mode: + block-until-ready wall and
+        arg/result bytes."""
         st = self._site(name)
         tel = _cur()  # the recorder jax's compile listeners write to
         marks = _compile_marks(tel)
@@ -217,7 +230,10 @@ class Profiler:
         grew = cs1 - cs0 if cs0 is not None and cs1 is not None \
             and cs1 > cs0 else 0
         if grew:
-            self._new_program(name, key, fn, args, kwargs, tel, marks)
+            rec = self._new_program(name, key, fn, args, kwargs, tel,
+                                    marks)
+            if wrapper is not None:
+                wrapper.program = rec
         with self._lock:
             st.dispatches += 1
             st.launch_s += launch
@@ -242,6 +258,23 @@ class Profiler:
             "origin": "loaded" if hits > 0 else "compiled",
             "xla_s": round(xla_s, 6), "dispatches": 0}
         rec.update(_executable_bytes(fn, args, kwargs))
+        self._keep(rec, fn, tel)
+        return rec
+
+    def hold(self, wrapper, tel) -> Optional[Dict[str, Any]]:
+        """A program this process already holds enters THIS recorder
+        (module docstring, `held`): `wrapper` is the site's `wrap()` an
+        earlier engine made and dispatched.  None, and nothing kept,
+        where it carries no record (never dispatched under a live
+        recorder: its first dispatch here then reads as any other)."""
+        made = getattr(wrapper, "program", None)
+        if made is None:
+            return None
+        rec = dict(made, origin="held", xla_s=0.0, dispatches=0)
+        self._keep(rec, wrapper.__wrapped__, tel)
+        return rec
+
+    def _keep(self, rec, fn, tel) -> None:
         with self._lock:
             self.programs.append(rec)
             self._program_of[fn] = rec
@@ -326,9 +359,10 @@ def wrap(name: str, fn, key=None):
         prof = getattr(_cur(), "prof", None)
         if prof is None:
             return fn(*args, **kwargs)
-        return prof.record(name, fn, args, kwargs, key)
+        return prof.record(name, fn, args, kwargs, key, profiled)
 
     profiled.__wrapped__ = fn
+    profiled.program = None  # the newest executable's record
     profiled.__name__ = getattr(fn, "__name__", name)
     profiled.profiler_site = name
     return profiled
@@ -374,7 +408,7 @@ def _fmt_bytes(n) -> str:
 def _programs_table(programs: List[Dict[str, Any]], out) -> None:
     """One row per executable: what it holds on a device
     (`memory_analysis()`; hbm = args + out - alias + temp) and whether
-    this process compiled it or loaded it."""
+    this run compiled it, loaded it, or found it held by its process."""
     if not programs:
         return
     print("programs (one per executable; bytes per device):", file=out)

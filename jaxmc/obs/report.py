@@ -428,6 +428,8 @@ def cmd_report(args, out=sys.stdout) -> int:
                 "yes" if sv["resumed_from_checkpoint"] else "no"))
         if "window_recompiles" in sv:
             cells.append(f"recompiles={sv['window_recompiles']}")
+        if sv.get("program_hits"):
+            cells.append(f"held_programs={sv['program_hits']}")
         bw = sv.get("batched_with")
         if isinstance(bw, list) and bw:
             cells.append(f"batched_with={len(bw)}")
@@ -439,6 +441,15 @@ def cmd_report(args, out=sys.stdout) -> int:
             hl.append("serve[" + " ".join(cells) + "]")
     if hl:
         print("highlights: " + "  ".join(hl), file=out)
+    # the program registry (ISSUE 37, compile/cache.py): how many of
+    # the programs this run's engines asked for their process held
+    held, made, unkeyed = (c.get(f"compile.program_{k}", 0)
+                           for k in ("hits", "misses", "unkeyed"))
+    if held or made or unkeyed:
+        print(f"programs: {held} held by the process (no trace, no "
+              f"load), {made} made new, {unkeyed} unkeyed (no program "
+              f"signature); signing took "
+              f"{c.get('compile.program_sig_s', 0.0):.4f}s", file=out)
     _searches_table(s.get("requests") or [], out)
     return 0 if rows else 1
 

@@ -555,6 +555,25 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       executable of the jitted function called (obs/prof.py).
       `prof.sites[*].launch_s`: host seconds inside the site's calls
       up to their return (the `launch` column of `obs top`).
+      A THIRD origin, "held" (ISSUE 37): the engine's own cache
+      missed and its PROCESS held the program already (compile/
+      cache.py's registry, same program signature): an earlier
+      engine's jitted callable is dispatched, no executable is made
+      and no site's `_cache_size()` grows, so the record is a copy of
+      the one that executable got when it came into being (site, key,
+      the byte fields) with origin "held", `xla_s` 0.0 and this
+      recorder's own `dispatches`; the gauges below are published as
+      for a new executable.  Counters `compile.program_hits` /
+      `compile.program_misses` (asks of the registry that found /
+      made the program) and `compile.program_unkeyed` (the engine has
+      no program signature: hybrid, or something in its model that
+      does not render canonically — it keeps its own jits); a hit
+      leaves `compile.xla_compile_s` in the counters, 0.0 where
+      nothing else compiled or loaded; float counter
+      `compile.program_sig_s`, the host seconds the signature took.
+      `serve.program_hits` in a served job's `serve` block is
+      `compile.program_hits` of the job's recorder (serve/protocol.py);
+      `python -m jaxmc.obs report` prints a `programs:` line.
     - gauges `program.temp_bytes` / `program.hbm_bytes`: those fields
       of the program with the largest hbm_bytes dispatched so far
       (argument_bytes stays in the record, where `top` prints it).
@@ -572,7 +591,7 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       closed `search` span of `CheckSession.explore()`:
         {rid, name, t0, wall_s, cpu_s, spans: {name: wall_s},
          counters: {the four above: rise}, dispatches,
-         origins: {"compiled" | "loaded": dispatches}}
+         origins: {"compiled" | "loaded" | "held": dispatches}}
       `cpu_s` is `time.process_time()`: a search whose wall rose and
       whose CPU seconds did not was not running.  `rid` counts the
       recorder's searches from 1.  The summary is the records' only

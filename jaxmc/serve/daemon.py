@@ -1210,6 +1210,8 @@ class ServeDaemon:
             "profile_hits": job_tel.counters.get("profile.hits", 0),
             "persistent_cache_hits": job_tel.counters.get(
                 "compile.persistent_cache_hits", 0),
+            "program_hits": job_tel.counters.get(
+                "compile.program_hits", 0),
             "batched_with": [f["id"] for f in followers],
             "job_wall_s": round(wall, 6),
         }

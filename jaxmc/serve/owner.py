@@ -71,7 +71,8 @@ def _member_summary(res, jt, backend: str, spec: str,
                               if lv.get("fresh_compile")),
         profile_hits=jt.counters.get("profile.hits", 0),
         persistent_cache_hits=jt.counters.get(
-            "compile.persistent_cache_hits", 0))
+            "compile.persistent_cache_hits", 0),
+        program_hits=jt.counters.get("compile.program_hits", 0))
     jt.close()
     return {"summary": summary, "ok": res.ok, "distinct": res.distinct,
             "generated": res.generated, "drained": drained}

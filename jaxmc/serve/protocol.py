@@ -59,8 +59,14 @@ The `serve` block of a served job's artifact (GET /jobs/<id>/result):
   window_recompiles        levels flagged `fresh_compile` (a NEW engine
                            flags its first dispatch, compiled or loaded
                            from the persistent cache: `prof.programs[]
-                           .origin` says which)
+                           .origin` says which; not where the process
+                           held the program already, origin `held`)
   profile_hits, persistent_cache_hits   counters of the job's recorder
+  program_hits             programs this job's engine took from the
+                           process's registry instead of tracing,
+                           lowering and loading them again
+                           (`compile.program_hits`, compile/cache.py):
+                           an edit that left the model unchanged
   batched_with             ids answered by the same run
   cost_estimate            analyze's state-space estimate, if any
 

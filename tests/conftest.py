@@ -46,3 +46,20 @@ needs_reference = pytest.mark.skipif(
     not HAVE_REFERENCE,
     reason=f"needs the reference spec corpus at {REFERENCE} (driver "
            f"environment only; point JAXMC_REFERENCE at a checkout)")
+
+
+@pytest.fixture(autouse=True)
+def _forget_programs():
+    """The program registry (ISSUE 37, jaxmc/compile/cache.py) is state
+    of the PROCESS, and a worker runs many tests in one: a test that
+    counts its engine's compiles, or patches what a trace reads behind
+    the signature's back, must not be handed the program an earlier
+    test's engine made.  Every test starts with an empty registry;
+    tests/test_program_registry.py keeps its own warm."""
+    try:
+        from jaxmc.compile.cache import forget_programs
+    except ImportError:
+        yield
+        return
+    forget_programs()
+    yield

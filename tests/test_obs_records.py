@@ -21,6 +21,7 @@ import time
 import pytest
 
 from jaxmc import obs
+from jaxmc.compile.cache import forget_programs
 from jaxmc.obs import prof as prof_mod
 from jaxmc.obs import telemetry
 from jaxmc.session import CheckSession, SessionConfig
@@ -54,7 +55,10 @@ def _session(engine, tel, spec="constoy", cfg="constoy"):
 
 def _searched(engine, searches=1, **tel_kw):
     """(tel, results, session) of `searches` whole searches of constoy on
-    one session under a live recorder."""
+    one session under a live recorder.  The session's engine makes its
+    own programs, whatever an earlier engine of this test left in the
+    process's registry (ISSUE 37): what is counted here is a compile."""
+    forget_programs()
     tel = obs.Telemetry(**tel_kw)
     with obs.use(tel):
         sess = _session(engine, tel)
@@ -135,6 +139,55 @@ def test_reading_the_executable_is_no_second_compile(engine, monkeypatch):
     by_fun = tel_read.gauges["compile.by_fun"]
     named = by_fun[SITE[engine][1]][0] + by_fun["<lambda>"][0]
     assert named == len(tel_read.prof.programs) == 2
+
+
+def test_a_later_engine_records_the_program_its_process_holds(tmp_path):
+    """The third origin (ISSUE 37): a second engine of the same model asks
+    jax for nothing — its recorder still holds one record per program, the
+    maker's bytes under origin "held", its own dispatch counts, the two
+    gauges, `compile.xla_compile_s` at 0.0; the search record says what
+    it dispatched was held; `obs top` and `obs report` print it."""
+    pytest.importorskip("jax")
+    from jaxmc.obs.report import main
+    made, _, _ = _searched("resident")
+    tel = obs.Telemetry()
+    with obs.use(tel):
+        sess = _session("resident", tel)
+        res = [sess.explore() for _ in range(2)]
+    assert all((r.ok, r.generated, r.distinct) == (True, 43, 21)
+               for r in res)
+    assert [p["site"] for p in tel.prof.programs] == \
+        ["bfs.host_keys", "bfs.resident_run"]
+    for p, was in zip(tel.prof.programs, made.prof.programs):
+        assert (was["origin"], p["origin"]) == ("compiled", "held")
+        assert p["xla_s"] == 0.0 and p["dispatches"] >= 2
+        assert {f: p[f] for f in BYTES + ("hbm_bytes", "key", "site")} == \
+            {f: was[f] for f in BYTES + ("hbm_bytes", "key", "site")}
+    top = tel.prof.programs[-1]
+    assert tel.gauges["program.temp_bytes"] == top["temp_bytes"]
+    assert tel.gauges["program.hbm_bytes"] == top["hbm_bytes"]
+    assert tel.counters["compile.program_hits"] == 2
+    assert tel.counters["compile.xla_compile_s"] == 0.0
+    assert tel.counters.get("compile.xla_compiles", 0) == 0
+    assert all(s.recompiles == 0 for s in tel.prof.sites.values())
+    assert [set(r["origins"]) for r in tel.requests] == [{"held"}] * 2
+    assert sum(r["dispatches"] for r in tel.requests) == \
+        sum(p["dispatches"] for p in tel.prof.programs)
+    path = str(tmp_path / "held.json")
+    tel.write_metrics(path, result={
+        "ok": True, "distinct": 21, "generated": 43,
+        "diameter": res[0].diameter, "truncated": False})
+    out = io.StringIO()
+    assert main(["top", path], out=out) == 0
+    assert [ln.split()[:2] for ln in out.getvalue().splitlines()
+            if ln.split()[1:2] == ["held"]] == [
+        ["bfs.host_keys", "held"], ["bfs.resident_run", "held"]]
+    out = io.StringIO()
+    assert main(["report", path], out=out) == 0
+    text = out.getvalue()
+    assert "programs: 2 held by the process (no trace, no load), 0 made " \
+        "new, 0 unkeyed" in text
+    assert "dispatches by origin {'held': " in text
 
 
 def test_a_backend_that_keeps_no_executable_gives_a_record_without_bytes():
@@ -401,6 +454,7 @@ def test_counts_verdicts_and_lowered_text_with_and_without_a_recorder():
     from jaxmc.engine.explore import format_trace
 
     def answer(tel):
+        forget_programs()   # each engine's own program, under its recorder
         with obs.use(tel):
             sess = _session("resident", tel, "portoy", "portoy_bad")
             res = sess.explore()

@@ -109,6 +109,85 @@ class TestSiteRegistry:
         assert wrapped.profiler_site == "t.wrapped"
 
 
+class TestHeldPrograms:
+    """The third origin (ISSUE 37): a program the process already holds
+    enters a later recorder as a copy of the record its executable got
+    when it came into being — no jax here, a fake jitted callable."""
+
+    BYTES = {"argument_bytes": 40, "output_bytes": 8, "alias_bytes": 0,
+             "temp_bytes": 100, "hbm_bytes": 148}
+
+    def _made(self, monkeypatch, site="t.held"):
+        from jaxmc.obs import prof as prof_mod
+        monkeypatch.setattr(prof_mod, "_executable_bytes",
+                            lambda fn, args, kwargs: dict(self.BYTES))
+        wrapped = wrap(site, Recompiler(every=10 ** 6), key=(4, 2))
+        assert wrapped.program is None
+        first = obs.Telemetry()
+        with obs.use(first):
+            # a fake whose cache grows on its first call only
+            wrapped.__wrapped__._cache_size = \
+                lambda f=wrapped.__wrapped__: min(f.calls, 1)
+            wrapped(1)
+            wrapped(2)
+        return wrapped, first
+
+    def test_the_maker_leaves_its_record_on_the_wrapper(self, monkeypatch):
+        wrapped, first = self._made(monkeypatch)
+        (rec,) = first.prof.programs
+        assert wrapped.program is rec
+        assert rec["origin"] == "compiled" and rec["dispatches"] == 2
+        assert rec["key"] == [4, 2] and rec["hbm_bytes"] == 148
+
+    def test_hold_copies_the_record_and_publishes_the_gauges(
+            self, monkeypatch):
+        wrapped, first = self._made(monkeypatch)
+        later = obs.Telemetry()
+        with obs.use(later):
+            rec = later.prof.hold(wrapped, later)
+            for i in range(3):
+                wrapped(i)
+        assert later.prof.programs == [rec]
+        assert rec == dict(self.BYTES, site="t.held", key=[4, 2],
+                           origin="held", xla_s=0.0, dispatches=3)
+        assert later.gauges["program.temp_bytes"] == 100
+        assert later.gauges["program.hbm_bytes"] == 148
+        # nothing grew: no recompile is charged to the later recorder
+        assert later.prof.sites["t.held"].recompiles == 0
+        assert later.prof.sites["t.held"].dispatches == 3
+        # the maker's record is its own: origin and count untouched
+        assert first.prof.programs[0]["origin"] == "compiled"
+        assert first.prof.programs[0]["dispatches"] == 2
+        block = json.loads(json.dumps(later.summary()))["prof"]
+        assert block["programs"] == [rec]
+
+    def test_a_program_without_a_record_is_not_held(self):
+        never = wrap("t.never", lambda x: x)
+        tel = obs.Telemetry()
+        assert tel.prof.hold(never, tel) is None
+        assert tel.prof.programs == []
+        assert "program.hbm_bytes" not in tel.gauges
+
+    def test_the_registry_tells_the_active_recorder(self, monkeypatch):
+        from jaxmc.compile import cache
+        wrapped, _ = self._made(monkeypatch)
+        monkeypatch.setattr(cache, "_PROGRAMS", cache.OrderedDict())
+        assert cache.held_program("t.held", "sig", (4, 2),
+                                  lambda: wrapped) is wrapped
+        later = obs.Telemetry()
+        with obs.use(later):
+            assert cache.held_program(
+                "t.held", "sig", (4, 2),
+                lambda: pytest.fail("made again")) is wrapped
+            with later.request("search"):
+                wrapped(7)
+        assert later.counters["compile.program_hits"] == 1
+        assert later.counters["compile.xla_compile_s"] == 0.0
+        assert [p["origin"] for p in later.prof.programs] == ["held"]
+        (req,) = later.requests
+        assert req["origins"] == {"held": 1} and req["dispatches"] == 1
+
+
 class TestMeasuredPeak:
     """`prof.hbm.peak_bytes` is what the device reports
     (`memory_stats()`), never a model; absent where it reports none."""
@@ -313,6 +392,39 @@ class TestObsTop:
         (step_ln,) = [ln for ln in lines if ln.split()[:1] == ["step"]]
         assert run_ln.split()[1:] == ["1", "61.250s"]
         assert lines.index(run_ln) < lines.index(step_ln)  # by seconds
+
+    def test_top_prints_a_held_program(self, tmp_path):
+        path = self._artifact(tmp_path)
+        art = json.loads(open(path).read())
+        art["prof"]["programs"] = [{
+            "site": "bfs.resident_run", "key": [64, 8, 32, 16, 8],
+            "origin": "held", "xla_s": 0.0, "dispatches": 3,
+            "argument_bytes": 4096, "output_bytes": 1024,
+            "alias_bytes": 0, "temp_bytes": 2048, "hbm_bytes": 7168}]
+        art["counters"] = {"compile.program_hits": 2,
+                           "compile.program_misses": 1,
+                           "compile.program_sig_s": 0.0123}
+        art["phases"][0]["count"] = 1
+        open(path, "w").write(json.dumps(art))
+        buf = io.StringIO()
+        assert obs_main(["top", path], out=buf) == 0
+        (row,) = [ln for ln in buf.getvalue().splitlines()
+                  if ln.split()[:2] == ["bfs.resident_run", "held"]]
+        assert row.split()[2:4] == ["0.00s", "3"]
+        assert "7.0KB" in row and "[64, 8, 32, 16, 8]" in row
+        buf = io.StringIO()
+        assert obs_main(["report", path], out=buf) == 0
+        assert ("programs: 2 held by the process (no trace, no load), "
+                "1 made new, 0 unkeyed (no program signature); signing "
+                "took 0.0123s") in buf.getvalue()
+
+    def test_the_schema_documents_the_third_origin(self):
+        from jaxmc.obs import schema
+        doc = open(schema.__file__, encoding="utf-8").read()
+        for name in ('"compiled" | "loaded" | "held"',
+                     "compile.program_hits", "compile.program_misses",
+                     "compile.program_unkeyed", "compile.program_sig_s"):
+            assert name in doc, name
 
     def test_top_exits_2_without_prof_block(self, tmp_path, capfd):
         rc = obs_main(["top", self._artifact(tmp_path,

@@ -271,20 +271,108 @@ def test_owner_survived_and_nothing_was_refused(stream):
     assert counters["serve.cold_runs"] == 12
 
 
-def test_later_engines_load_their_programs(stream):
-    """A new signature builds a new engine that asks for its executable
-    again: with the daemon's persistent cache it is LOADED, which is what
-    the benchmark's `window_recompiles` counts as no recompile."""
-    origins = {}
-    for key, job in stream["jobs"].items():
-        if job["kind"] != "edit":
-            continue
-        for p in job["art"]["prof"].get("programs", []):
-            if p["site"] == "bfs.resident_run":
-                origins.setdefault(key[2], []).append(p["origin"])
-    for label, got in origins.items():
-        assert len(got) == 6 and got.count("compiled") <= 1, (label, got)
-        assert got.count("loaded") >= 5, (label, got)
+def _searched(res):
+    return (res["generated"], res["distinct"], res["diameter"])
+
+
+@pytest.mark.parametrize("label", sorted(SUITE))
+def test_later_engines_dispatch_the_program_the_owner_holds(stream, label):
+    """A new signature builds a new engine, and since ISSUE 37 an edit
+    that left the model unchanged no longer asks jax for its executable
+    again: the cfg's FIRST job in the owner compiles (the daemon's cache
+    is a new tmp dir), each later edit takes the search program and the
+    host-keys program from the process's registry (`serve.program_hits`,
+    origin `held` with the first job's bytes) — and still builds, searches
+    from the init states and writes its own finalized checkpoint."""
+    edits = sorted((j for k, j in stream["jobs"].items()
+                    if j["kind"] == "edit" and k[2] == label),
+                   key=lambda j: j["rec"]["started_at"])
+    assert len(edits) == 6
+    first, later = edits[0], edits[1:]
+
+    def programs(job):
+        return {p["site"]: p for p in job["art"]["prof"]["programs"]}
+
+    made = programs(first)
+    assert made["bfs.resident_run"]["origin"] == "compiled"
+    assert first["art"]["serve"]["program_hits"] == 0
+    for job in later:
+        art, sv = job["art"], job["art"]["serve"]
+        assert sv["warm_engine"] is False
+        assert sv["resumed_from_checkpoint"] is False
+        assert sv["program_hits"] >= 2         # host_keys and run
+        assert sv["window_recompiles"] == 0
+        assert sv["persistent_cache_hits"] == 0   # nothing was loaded
+        assert art["counters"]["compile.xla_compile_s"] == 0.0
+        assert "compile.program_misses" not in art["counters"]
+        got = programs(job)
+        assert set(got) == {"bfs.host_keys", "bfs.resident_run"}
+        for site, p in got.items():
+            assert p["origin"] == "held" and p["xla_s"] == 0.0
+            assert p["dispatches"] >= 1
+            assert p["key"] == made[site]["key"]
+            assert p["hbm_bytes"] == made[site]["hbm_bytes"]
+        assert art["gauges"]["program.hbm_bytes"] == \
+            made["bfs.resident_run"]["hbm_bytes"]
+        phases = {p["name"] for p in art["phases"]}
+        assert {"engine_build", "search", "checkpoint.write"} <= phases
+        assert _searched(art["result"]) == _searched(first["art"]["result"])
+
+
+def test_the_in_process_path_holds_programs_too(tmp_path, monkeypatch):
+    """`JAXMC_SERVE_DEVICE_OWNER=0`: the daemon's worker thread builds the
+    engines in the daemon's own process, whose registry it is.  A stamped
+    resubmission is cold (a build, a full search, its own checkpoint) on
+    the program the first job made; a byte-identical one replays warm."""
+    from jaxmc.serve import ServeDaemon
+    lib = _bench("lib.py")
+    monkeypatch.setenv("JAXMC_SERVE_DEVICE_OWNER", "0")
+    monkeypatch.setenv("JAXMC_LEDGER", "off")
+    monkeypatch.setenv("JAXMC_PROFILE_STORE", str(tmp_path / "profiles"))
+    cfg = str(tmp_path / "2p3.cfg")
+    with open(cfg, "w", encoding="utf-8") as fh:
+        fh.write(SUITE["2p3"])
+    text = open(SPEC, encoding="utf-8").read()
+    commits = []
+    for k in (1, 2):
+        d = tmp_path / f"commit-{k}"
+        d.mkdir()
+        commits.append(str(d / os.path.basename(SPEC)))
+        with open(commits[-1], "w", encoding="utf-8") as fh:
+            fh.write(lib.stamp_spec(text, f"in-process commit {k}"))
+    daemon = ServeDaemon(str(tmp_path / "spool"), workers=1,
+                         quiet=True).start()
+    try:
+        assert daemon.owner is None
+        client = ServeClient("127.0.0.1", daemon.port)
+        arts, sigs = [], []
+        for spec_path in (commits[0], commits[1], commits[1]):
+            code, body = client.submit(spec_path, cfg, OPTS, tenant="ci-a")
+            assert code == 200, (code, body)
+            rec = client.wait(body["id"], timeout=180, poll_s=0.05)
+            assert rec["status"] == "done", rec
+            code, art = client.result(body["id"])
+            assert code == 200
+            arts.append(art)
+            sigs.append(body["sig"])
+    finally:
+        daemon.shutdown()
+    assert sigs[0] != sigs[1] == sigs[2]
+    first, stamped, rerun = arts
+    assert first["serve"]["program_hits"] == 0
+    sv = stamped["serve"]
+    assert not sv.get("device_owner")
+    assert sv["warm_engine"] is False
+    assert sv["resumed_from_checkpoint"] is False
+    assert sv["program_hits"] >= 2 and sv["window_recompiles"] == 0
+    assert {p["origin"] for p in stamped["prof"]["programs"]} == {"held"}
+    assert {"engine_build", "search", "checkpoint.write"} <= \
+        {p["name"] for p in stamped["phases"]}
+    assert rerun["serve"]["warm_engine"] is True
+    assert rerun["serve"]["resumed_from_checkpoint"] is True
+    assert rerun["serve"]["program_hits"] == 0     # nothing dispatched
+    assert _searched(first["result"]) == _searched(stamped["result"]) == \
+        _searched(rerun["result"]) == (256, 166, 6)
 
 
 def test_daemon_and_owner_are_gone(stream):

@@ -18,6 +18,7 @@ import threading
 import pytest
 
 from jaxmc import obs
+from jaxmc.compile.cache import forget_programs
 from jaxmc.engine.explore import format_trace
 from jaxmc.session import CheckSession, SessionConfig
 
@@ -236,6 +237,9 @@ def test_lowered_program_names_all_seven_kernels(engine):
 
 
 def _checked(spec, cfg, engine, tel, profile_dir=None, **opts):
+    # the engine's own programs, not an earlier engine's of the same
+    # test (the process's registry, ISSUE 37): compiles are compared
+    forget_programs()
     with obs.use(tel):
         sess = _session(spec, cfg, engine, tel, **opts)
         if profile_dir is None:
