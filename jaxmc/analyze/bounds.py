@@ -1444,8 +1444,16 @@ def _lift_walk(e, safe: bool, consts: set, pinned: set,
     if e is None:
         return
     if isinstance(e, A.Ident):
-        if e.name in consts and not safe:
-            pinned.add(e.name)
+        if e.name in consts:
+            if not safe:
+                pinned.add(e.name)
+            return
+        # a bare reference to a parameterless operator (`Next == Tick
+        # \/ Wrap`, `\E d \in Lim` with `Lim == 1..K`) is its body in
+        # THIS position
+        d = defs.get(e.name)
+        if isinstance(d, OpClosure) and not d.params:
+            _lift_body(e.name, d, safe, consts, pinned, defs, seen_ops)
         return
     if isinstance(e, (A.Num, A.Str, A.Bool, A.At)):
         return
@@ -1469,13 +1477,13 @@ def _lift_walk(e, safe: bool, consts: set, pinned: set,
             return
         d = defs.get(e.name)
         if isinstance(d, OpClosure):
-            # user operator: walk its body ONCE (occurrences inside are
-            # classified by their own contexts); call-site arguments are
-            # conservatively pinned — the body may route a parameter
-            # into a static-only position
-            if e.name not in seen_ops:
-                seen_ops.add(e.name)
-                _lift_walk(d.body, True, consts, pinned, defs, seen_ops)
+            # user operator: its body in the position of the call (an
+            # operator applied inside a quantifier domain or a `..`
+            # hands its RESULT to a static-only position, so every
+            # constant it computes with is pinned there); call-site
+            # arguments are conservatively pinned — the body may route
+            # a parameter into a static-only position
+            _lift_body(e.name, d, safe, consts, pinned, defs, seen_ops)
             for a in e.args:
                 _lift_walk(a, False, consts, pinned, defs, seen_ops)
             return
@@ -1555,6 +1563,17 @@ def _lift_walk(e, safe: bool, consts: set, pinned: set,
                 _lift_walk(x, False, consts, pinned, defs, seen_ops)
 
 
+def _lift_body(name: str, d, safe: bool, consts: set, pinned: set,
+               defs: Dict[str, Any], seen_ops: set) -> None:
+    """Walk a user operator's body once per context flag: a body first
+    met in a value position is walked again when a pinned one reaches
+    it (occurrences inside are still classified by their own contexts
+    below that)."""
+    if (name, safe) not in seen_ops:
+        seen_ops.add((name, safe))
+        _lift_walk(d.body, safe, consts, pinned, defs, seen_ops)
+
+
 def _flat_nodes(v):
     for x in v:
         if isinstance(x, A.Node):
@@ -1594,15 +1613,31 @@ def _pin_all(e, consts: set, pinned: set, defs: Dict[str, Any],
 def liftable_constants(model) -> Tuple[str, ...]:
     """Sorted cfg CONSTANT names whose values may become per-model
     batch lanes: plain ints (not bools — bool lanes would change guard
-    structure) used only in value positions across Init, Next, the
-    checked predicates, and every reachable operator body."""
+    structure) used only in value positions across Next, the checked
+    predicates, and every operator body they reach — by application or
+    by bare reference (`Next == Tick \\/ Wrap`), each body in the
+    position that reaches it: a constant in `\\E d \\in 1..K` under a
+    bare `Step` is pinned.  It must be — the donor build raises
+    nothing there (the static path reads the donor's concrete value)
+    and the other members would be checked against the donor's domain
+    (tests/test_batch.py::TestLiftWalk).
+
+    Init is NOT walked: no device program is traced from it.  Every
+    member of a cohort enumerates its own init states on the host with
+    its own constants (`TpuExplorer._prepare_init`), and the shared
+    lane plan is built over the union of the members' samples with
+    their proven bounds interval-merged (backend/batch.py), so a
+    constant that shapes Init alone (`money \\in [Procs -> 1..MaxMoney]`)
+    shapes no compiled code, whichever way the cfg names Init
+    (`SPECIFICATION` hands it over as a bare reference, `INIT` as its
+    body)."""
     consts = {n for n, v in model.cfg.constants.items()
               if type(model.defs.get(n)) is int}
     if not consts:
         return ()
     pinned: set = set()
     seen_ops: set = set()
-    tops = [model.init, model.next]
+    tops = [model.next]
     tops += [ex for _n, ex in model.invariants]
     tops += [ex for _n, ex in model.constraints]
     tops += [ex for _n, ex in model.action_constraints]

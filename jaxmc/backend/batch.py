@@ -99,7 +99,10 @@ class BatchDispatcher:
         self._vstep = obs.prof_wrap("batch.vstep",
                                     jax.jit(jax.vmap(self._core)))
         self._cvecs = jnp.asarray(np.ascontiguousarray(cvecs, np.int32))
-        self.tel = tel
+        # the cohort's recorder: every superstep's spans, the site's
+        # launch seconds and the vmapped program's record land here,
+        # whichever member's thread completes the barrier
+        self.tel = tel if tel is not None else obs.NullTelemetry()
         self._cv = threading.Condition()
         self._active: set = set(range(self.B))
         self._pending: Dict[int, Tuple[np.ndarray, int]] = {}
@@ -155,55 +158,88 @@ class BatchDispatcher:
     # ---- the superstep -------------------------------------------------
     def _step(self, slot: int, frontier_p, fcount: int
               ) -> Dict[str, Any]:
+        t0 = time.perf_counter()
+        fired = 0.0
         with self._cv:
             self._pending[slot] = (np.asarray(frontier_p, np.int32),
                                    fcount)
             if set(self._pending) >= self._active:
+                t1 = time.perf_counter()
                 self._fire_locked()
+                fired = time.perf_counter() - t1
             while slot not in self._results:
                 self._cv.wait(0.5)
             res = self._results.pop(slot)
-            if isinstance(res, BaseException):
-                # the shared dispatch failed: EVERY waiter gets the
-                # error (not just the thread that fired) — each member
-                # fails its own run and deregisters, so the cohort
-                # never deadlocks on a lane that cannot re-fire
-                raise RuntimeError(
-                    f"vmapped batch dispatch failed: "
-                    f"{type(res).__name__}: {res}") from res
-            return res
+        # the member's own recorder (the member thread's current one):
+        # its seconds in this step that were not the firing itself —
+        # the wait for the lock, for the slower members' chunks and for
+        # the dispatch another thread ran
+        obs.current().counter("batch.barrier_wait_s",
+                              time.perf_counter() - t0 - fired)
+        if isinstance(res, BaseException):
+            # the shared dispatch failed: EVERY waiter gets the
+            # error (not just the thread that fired) — each member
+            # fails its own run and deregisters, so the cohort
+            # never deadlocks on a lane that cannot re-fire
+            raise RuntimeError(
+                f"vmapped batch dispatch failed: "
+                f"{type(res).__name__}: {res}") from res
+        return res
 
     def _fire_locked(self) -> None:
         """One vmapped dispatch over every pending member lane (caller
         holds the condition).  A dispatch failure is distributed to
-        every pending slot as its result — see _step."""
+        every pending slot as its result — see _step.
+
+        In the cohort's recorder, whichever member's thread fires: the
+        span `batch.dispatch` (upload, the vmapped program, every
+        output fetched: a synchronous round trip, and the span a
+        trace's dispatches are counted by) and float counters round it, which
+        cost a dispatch no event — `batch.stack_s` (the host stacks
+        the pending chunks into one [B, CH, PW] block),
+        `batch.unstack_s` (each member handed its slice),
+        `batch.upload_s` and `batch.fetch_s` (the round trip's two
+        ends, round the site's own launch seconds) and
+        `batch.first_dispatch_s` (the cohort's first call alone:
+        trace, lower, compile or load)."""
+        rec = self.tel
         slots = sorted(self._pending)
         width = len(slots)
-        fr = np.full((self.B, self.CH, self.PW), SENTINEL, np.int32)
-        fc = np.zeros(self.B, np.int32)
-        for s in slots:
-            bf, c = self._pending[s]
-            fr[s] = bf
-            fc[s] = c
-        self._pending.clear()
+        with rec.timed("batch.stack_s"):
+            fr = np.full((self.B, self.CH, self.PW), SENTINEL, np.int32)
+            fc = np.zeros(self.B, np.int32)
+            for s in slots:
+                bf, c = self._pending[s]
+                fr[s] = bf
+                fc[s] = c
+            self._pending.clear()
         try:
-            out = self._vstep(jnp.asarray(fr), jnp.asarray(fc),
-                              self._cvecs)
-            out_np = {k: np.asarray(v) for k, v in out.items()}
+            with obs.use_local(rec), rec.span("batch.dispatch"):
+                t0 = time.perf_counter()
+                args = (jnp.asarray(fr), jnp.asarray(fc), self._cvecs)
+                t1 = time.perf_counter()
+                out = self._vstep(*args)
+                t2 = time.perf_counter()
+                out_np = {k: np.asarray(v) for k, v in out.items()}
+                rec.counter("batch.upload_s", t1 - t0)
+                rec.counter("batch.fetch_s", time.perf_counter() - t2)
+                if not self.dispatches:
+                    rec.counter("batch.first_dispatch_s", t2 - t1)
         except Exception as ex:  # noqa: BLE001 — XLA runtime/OOM/
             # compile failures land on every waiting member
             for s in slots:
                 self._results[s] = ex
             self._cv.notify_all()
             return
-        for s in slots:
-            self._results[s] = {k: v[s] for k, v in out_np.items()}
+        with rec.timed("batch.unstack_s"):
+            for s in slots:
+                self._results[s] = {k: v[s] for k, v in out_np.items()}
         self.dispatches += 1
         self.max_width = max(self.max_width, width)
         self.widths.append(width)
-        if self.tel is not None:
-            self.tel.gauge("batch.width", width)
-            self.tel.counter("batch.dispatches")
+        rec.gauge("batch.width", width)
+        rec.counter("batch.dispatches")
+        rec.counter("batch.lane_steps", width)
         self._cv.notify_all()
 
 
@@ -249,6 +285,15 @@ class BatchCheckEngine:
 
     # ---- compat proof + build -----------------------------------------
     def build(self) -> "BatchCheckEngine":
+        """Span `batch.build` in the cohort's recorder, which is also
+        this thread's recorder while it lasts: the donor engine's own
+        build spans and gauges (`layout_sample`, `compile_arm`, ...)
+        land beside `load`, `batch_sample` and `engine_build`."""
+        with obs.use_local(self.tel), \
+                self.tel.span("batch.build", members=len(self.cfgs)):
+            return self._build()
+
+    def _build(self) -> "BatchCheckEngine":
         from ..analyze.bounds import (infer_state_bounds,
                                       liftable_constants,
                                       merge_element_bounds,
@@ -405,6 +450,10 @@ class BatchCheckEngine:
         member, device work through the shared dispatcher.  Returns the
         members with .result (or .error) filled."""
         assert self.dispatcher is not None, "build() first"
+        with self.tel.span("batch.run", members=len(self.members)):
+            return self._run()
+
+    def _run(self) -> List[BatchMember]:
         disp = self.dispatcher
         disp.reset()
         for mem in self.members:

@@ -124,20 +124,30 @@ def _any_fast(x) -> bool:
     return bool(jnp.any(x))
 
 
-def _take_rows_fast(x, idx) -> np.ndarray:
-    """Row-gather returning numpy: fancy-index for host arrays, device
-    jnp.take (avoids transferring the full block) for device arrays."""
-    if isinstance(x, np.ndarray):
-        return x[idx]
-    return np.asarray(jnp.take(x, jnp.asarray(idx, dtype=jnp.int32),
-                               axis=0))
-
-
 def _pow2_at_least(n: int, lo: int = 256) -> int:
     c = lo
     while c < n:
         c *= 2
     return c
+
+
+def _take_rows_fast(x, idx) -> np.ndarray:
+    """Row-gather returning numpy: fancy-index for host arrays, device
+    jnp.take (avoids transferring the full block) for device arrays.
+
+    The index is padded to a power of two (row 0, cut off again on the
+    host): an eager `jnp.take` is one XLA program PER INDEX LENGTH, and
+    the host_seen loop's lengths are its chunks' new-row counts — up to
+    PR 38 a solo job compiled, or loaded from the persistent cache, two
+    programs a chunk: 292 and 530 for the two primer jobs of the cell
+    `ci-cohort-4p`, 79 s of XLA on a chip where 68 of them missed the
+    cache (PERF.md section 6, PR 39)."""
+    if isinstance(x, np.ndarray):
+        return x[idx]
+    n = len(idx)
+    pad = np.zeros(_pow2_at_least(n), np.int32)
+    pad[:n] = idx
+    return np.asarray(jnp.take(x, jnp.asarray(pad), axis=0))[:n]
 
 
 def fingerprint128(rows):
@@ -3928,6 +3938,23 @@ class TpuExplorer:
         # the whole cohort
         hstep = self._hstep_override(CH) \
             if self._hstep_override is not None else self._get_hstep(CH)
+        # the host's side of a level on `time.perf_counter`, published
+        # as counters `hostseen.*` at the end of every level (a level
+        # that ends the search early keeps its own to itself)
+        hs = dict.fromkeys(("step_s", "store_s", "loop_s"), 0.0)
+        hs.update(store_keys=0, chunks=0)
+
+        def _hs_flush(tail_s):
+            tel.counter("hostseen.chunks", hs["chunks"])
+            tel.counter("hostseen.step_s", hs["step_s"])
+            tel.counter("hostseen.store_s", hs["store_s"])
+            tel.counter("hostseen.store_keys", hs["store_keys"])
+            tel.counter("hostseen.book_s",
+                        hs["loop_s"] - hs["step_s"] - hs["store_s"])
+            tel.counter("hostseen.tail_s", tail_s)
+            hs.update(step_s=0.0, store_s=0.0, loop_s=0.0,
+                      store_keys=0, chunks=0)
+
         while len(frontier_np) > 0:
             # chaos sites: simulated hard crash / terminal device failure
             # entering a level (no-ops unless JAXMC_FAULTS names them)
@@ -3977,9 +4004,14 @@ class TpuExplorer:
                 c = min(CH, ll - b)
                 bf = np.full((CH, self.PW), SENTINEL, np.int32)
                 bf[:c] = fnp[b:b + c]
-                return b, c, bf, hstep(bf, c)
+                ts = time.perf_counter()
+                out = hstep(bf, c)
+                hs["step_s"] += time.perf_counter() - ts
+                hs["chunks"] += 1
+                return b, c, bf, out
 
             nxt = None  # one-slot prefetch: the chunk dispatched early
+            loop_t0 = time.perf_counter()
             for base in range(0, L, CH):
                 _b, cn, buf, out = nxt if nxt is not None \
                     else _dispatch(base)
@@ -4036,7 +4068,9 @@ class TpuExplorer:
                     vidx = np.nonzero(cvalid)[0]
                     found = np.zeros(len(cvalid), dtype=bool)
                     if len(vidx):
+                        ts = time.perf_counter()
                         found[vidx] = store.contains(keys[vidx][:, 1:])
+                        hs["store_s"] += time.perf_counter() - ts
                     keep, n_amp, n_exp = _por_mask_np(
                         found, cvalid, por_plan["inst_arm"],
                         por_plan["arm_safe"], self.A, CH)
@@ -4069,13 +4103,15 @@ class TpuExplorer:
                     # [A*CH, W] tensor per chunk would hold the whole
                     # level expansion in host RAM)
                     eidx = np.nonzero(cvalid & explore)[0]
-                    erows = np.asarray(jnp.take(
-                        out["cand"], jnp.asarray(eidx, dtype=jnp.int32),
-                        axis=0)) if len(eidx) \
+                    erows = _take_rows_fast(out["cand"], eidx) \
+                        if len(eidx) \
                         else np.zeros((0, self.PW), np.int32)
                     lvl_edges.append((erows, base + eidx % CH))
                 valid_idx = np.nonzero(cvalid)[0]
+                ts = time.perf_counter()
                 new_mask = store.insert(keys[valid_idx][:, 1:])
+                hs["store_s"] += time.perf_counter() - ts
+                hs["store_keys"] += len(valid_idx)
                 new_idx = valid_idx[new_mask]
                 if not len(new_idx):
                     continue
@@ -4128,6 +4164,8 @@ class TpuExplorer:
                     # the level's chunks
                     break
 
+            tail_t0 = time.perf_counter()
+            hs["loop_s"] += tail_t0 - loop_t0
             if self.fb_arms and inv_hit is None:
                 # hybrid: interpreter-enumerate the fallback arms over
                 # this level's frontier and splice the results into the
@@ -4207,6 +4245,7 @@ class TpuExplorer:
                       new=len(sel), distinct=distinct, seen=len(store),
                       wall_s=round(time.time() - lvl_t0, 6))
             self._fp_occupancy = len(store)
+            _hs_flush(time.perf_counter() - tail_t0)
             depth += 1
             if self.max_states and distinct >= self.max_states:
                 self.log("-- state limit reached, search truncated")

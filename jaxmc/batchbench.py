@@ -27,8 +27,14 @@ liftable constant values), with two measured legs:
     near 1x in this container (measured 0.95-1.1x; BASELINE.md), and a
     wall-based gate would only measure machine noise (identical legs
     swing 2x run-to-run here).  The warm win is LATENCY-bound: it
-    needs a device whose per-dispatch cost dwarfs the host bookkeeping;
-    on an accelerator it is not measured.  The warm artifacts are written for
+    needs a device whose per-dispatch cost dwarfs the host bookkeeping.
+    On an accelerator the cohort path is measured since PR 39 — not
+    this leg but the served one, a commit's matrix of four cfgs as one
+    cohort on a TPU v5e (the benchmark cell `ci-cohort-4p`; numbers in
+    PERF.md sections 5 and 6 and in README "Continuous batching") —
+    and what it shows is the other way round: a vmapped dispatch is a
+    synchronous host round trip there and the device is idle nearly
+    all of a commit.  The warm artifacts are written for
     inspection (`obs report`/`obs diff` by hand).
 
 Per-member counts must be BIT-IDENTICAL between legs in BOTH scenarios

@@ -450,8 +450,42 @@ def cmd_report(args, out=sys.stdout) -> int:
               f"load), {made} made new, {unkeyed} unkeyed (no program "
               f"signature); signing took "
               f"{c.get('compile.program_sig_s', 0.0):.4f}s", file=out)
+    _cohort_lines(s, out)
     _searches_table(s.get("requests") or [], out)
     return 0 if rows else 1
+
+
+def _cohort_lines(s: Dict[str, Any], out) -> None:
+    """Where a vmapped cohort's wall went (ISSUE 39, backend/batch.py):
+    a `cohort:` line from the leader's artifact (the build, the first
+    call of the vmapped program, the run and what its supersteps cost
+    the host) and a `host_seen:` line from any member's, a solo
+    `--host-seen` run included (the host's side of its levels)."""
+    c = s.get("counters", {})
+    ph = {p["name"]: p["wall_s"] for p in s.get("phases", [])}
+    n = c.get("batch.dispatches")
+    if n:
+        fire = ph.get("batch.dispatch", 0.0) + \
+            c.get("batch.stack_s", 0.0) + c.get("batch.unstack_s", 0.0)
+        print(f"cohort: build {_fmt_s(ph.get('batch.build'))}, first "
+              f"call {_fmt_s(c.get('batch.first_dispatch_s'))}, run "
+              f"{_fmt_s(ph.get('batch.run'))}; {n} dispatches, "
+              f"{c.get('batch.lane_steps', 0)} member lanes filled; "
+              f"firing them {_fmt_s(fire)} (stack "
+              f"{_fmt_s(c.get('batch.stack_s'))}, upload "
+              f"{_fmt_s(c.get('batch.upload_s'))}, fetch "
+              f"{_fmt_s(c.get('batch.fetch_s'))}, unstack "
+              f"{_fmt_s(c.get('batch.unstack_s'))})", file=out)
+    if c.get("hostseen.chunks"):
+        wait = c.get("batch.barrier_wait_s")
+        print(f"host_seen: {c['hostseen.chunks']} chunks; step "
+              f"{_fmt_s(c.get('hostseen.step_s'))}"
+              + (f" (barrier wait {_fmt_s(wait)})"
+                 if wait is not None else "")
+              + f", store {_fmt_s(c.get('hostseen.store_s'))} for "
+              f"{c.get('hostseen.store_keys', 0)} keys, bookkeeping "
+              f"{_fmt_s(c.get('hostseen.book_s'))}, level tails "
+              f"{_fmt_s(c.get('hostseen.tail_s'))}", file=out)
 
 
 # ------------------------------------------------------------------ diff

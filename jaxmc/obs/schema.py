@@ -599,6 +599,57 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       `rid` (nothing reads a stream by search).  `python -m jaxmc.obs
       report` prints the searches' median and largest wall and the
       piece that grew in the slowest.
+
+  (PR 39, still jaxmc.metrics/4 — all additive/optional; a vmapped
+   cohort's wall accounted for, backend/batch.py + the host_seen loop
+   of backend/bfs.py, ISSUE 39:)
+    - the COHORT's recorder is its leader's (serve/owner.py
+      `run_vbatch` hands `BatchCheckEngine` the first member's): the
+      one build, the supersteps and the vmapped program's record reach
+      the client in the leader's artifact (up to PR 38 they went to
+      the process's recorder and to whichever member's thread fired).
+      Spans `batch.build` (all of `build()`: every member's `load`
+      in ITS artifact, `batch_sample`, `engine_build` and the donor's
+      own build spans beside it) and `batch.run` (all of `run()`);
+      per superstep `batch.dispatch` (upload, the vmapped program,
+      every output fetched: a synchronous round trip under the
+      dispatcher's lock; the span a trace's dispatches are counted by,
+      `jaxmc.batch.dispatch`).
+    - float counters in the leader's artifact, seconds on
+      `time.perf_counter` (no event a dispatch: a span there costs
+      ~24 us with a job's trace file open): `batch.stack_s` (the
+      pending chunks stacked into one [B, CH, PW] block on the host),
+      `batch.unstack_s` (each member handed its slice),
+      `batch.upload_s` and `batch.fetch_s` (the
+      round trip's two ends, round the site's own launch seconds:
+      prof site `batch.vstep`), `batch.first_dispatch_s` (the
+      cohort's FIRST call alone: `jax.jit(jax.vmap(core))` is made
+      anew for every cohort, so it traces, lowers and compiles or
+      loads); counters `batch.dispatches` and `batch.lane_steps` (the
+      sum of the dispatches' widths: lane_steps / (members x
+      dispatches) is the share of member lanes that held a chunk).
+      The program record of `batch.vstep` (`prof.programs`, gauges
+      `program.temp_bytes` / `.hbm_bytes`) lands there too.
+    - every MEMBER's artifact: float counter `batch.barrier_wait_s`
+      (its seconds inside supersteps that were not the firing
+      itself: the lock, the slower members' chunks, the dispatch
+      another thread ran) and the host_seen loop's own, published at
+      the end of every level, a solo `--host-seen` run's too (a level
+      that ends the search early keeps its own to itself):
+      `hostseen.chunks`, `hostseen.step_s` (inside the step's call:
+      solo, the asynchronous enqueue; in a cohort the whole
+      superstep), `hostseen.store_s` / `hostseen.store_keys` (the
+      native fingerprint store's `insert`, the key columns' copy
+      included, and `contains` under POR; keys handed to it),
+      `hostseen.book_s` (the rest of the chunk loop: outputs forced
+      and fetched, `np.nonzero`, the new rows' takes, provenance) and
+      `hostseen.tail_s` (from the chunk loop's end to the level's:
+      concatenation, the next frontier, the level record).
+    - serve job artifacts and records: a cohort's `serve` block and
+      its members' records say `device_owner` as a solo job's do
+      (`daemon._run_vbatch`; up to PR 38 a cohort's said nothing).
+    - `python -m jaxmc.obs report` prints a `cohort:` line (leader)
+      and a `host_seen:` line (any member, any `--host-seen` run).
 """
 
 from __future__ import annotations

@@ -1507,6 +1507,9 @@ class ServeDaemon:
             summary = mres["summary"]
             sv = summary.setdefault("serve", {})
             sv["cost_estimate"] = jobs[0].get("cost_estimate")
+            # where the cohort ran, as a solo job's block says it
+            # (`run_vbatch` is one runner for both places)
+            sv["device_owner"] = self.owner is not None
             resumed = bool(sv.get("resumed_from_checkpoint"))
             status = "drained" if mres.get("drained") else "done"
             publish = self._publishable(jobs)
@@ -1516,6 +1519,7 @@ class ServeDaemon:
                             ok=mres["ok"], distinct=mres["distinct"],
                             generated=mres["generated"],
                             warm_engine=False,
+                            device_owner=self.owner is not None,
                             resumed_from_checkpoint=resumed,
                             batch_occupancy=occupancy,
                             daemon=self.daemon_id,

@@ -109,9 +109,13 @@ def run_vbatch(members_desc: List[Dict[str, Any]]) -> Dict[str, Any]:
             "backend": cfg.backend, "spec": md["spec"],
             "cfg": md.get("cfg"), "env": obs.environment_meta()}))
     try:
+        # the LEADER's recorder is the cohort's: the one build, the
+        # supersteps' spans and the vmapped program's record reach the
+        # client in the leader's artifact (the members' own recorders
+        # keep their load, search, store and checkpoint)
         be = BatchCheckEngine(
-            cfgs, tels=tels, tags=[md["jids"][0] for md in members_desc]
-        ).build()
+            cfgs, tels=tels, tags=[md["jids"][0] for md in members_desc],
+            tel=tels[0]).build()
     except BatchIncompatible as ex:
         for jt in tels:
             jt.close()

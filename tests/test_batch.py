@@ -183,6 +183,112 @@ class TestVmappedEngine:
             BatchCheckEngine(cfgs).build()
 
 
+#: ISSUE 39: what `liftable_constants` walks.  One module, four ways a
+#: constant can reach a quantifier's domain (a static-only position: the
+#: kernel enumerates it at trace time, with the DONOR's concrete value)
+#: or stay out of one; (Next's body, the constants that lift)
+_LIFT_HEAD = ("---- MODULE lw ----\nEXTENDS Naturals\n"
+              "CONSTANTS K, M\nVARIABLES x\n"
+              "Init == x \\in 1..M\n")
+_LIFT_TAIL = ("Spec == Init /\\ [][Next]_x\n"
+              "Inv == x <= 10 + M\n====\n")
+_LIFT_CASES = {
+    # a bare reference to the operator that holds the domain
+    "bare-reference": (
+        "Step == \\E d \\in 1..K : x + d <= 10 /\\ x' = x + d\n"
+        "Next == Step \\/ (x' = x)\n", ("M",)),
+    # the domain itself behind a bare reference
+    "domain-by-name": (
+        "Dom == 1..K\n"
+        "Next == (\\E d \\in Dom : x + d <= 10 /\\ x' = x + d)"
+        " \\/ (x' = x)\n", ("M",)),
+    # an applied operator whose RESULT is the domain's end
+    "applied-in-domain": (
+        "Lim(y) == K + y\n"
+        "Next == (\\E d \\in 1..Lim(0) : x + d <= 10 /\\ x' = x + d)"
+        " \\/ (x' = x)\n", ("M",)),
+    # value positions only, behind the same bare reference: K lifts
+    "value-only": (
+        "Step == x + K <= 10 /\\ x' = x + K\n"
+        "Next == Step \\/ (x' = x)\n", ("K", "M")),
+}
+
+
+def _lift_model(tmp_path, case, k=2, m=3, form="SPECIFICATION Spec"):
+    spec = str(tmp_path / "lw.tla")
+    with open(spec, "w") as f:
+        f.write(_LIFT_HEAD + _LIFT_CASES[case][0] + _LIFT_TAIL)
+    cfg = str(tmp_path / f"k{k}m{m}{form[:4]}.cfg")
+    with open(cfg, "w") as f:
+        f.write(f"{form}\nINVARIANT Inv\nCONSTANTS\n  K = {k}\n"
+                f"  M = {m}\n")
+    return spec, cfg
+
+
+class TestLiftWalk:
+    """What a cohort rests on (ISSUE 39): a constant lifts only where
+    no position that reaches it is static.  Up to PR 38 a bare
+    reference to a parameterless operator was not followed (so `K`
+    under `Step` lifted) and the build raised nothing: the static path
+    reads the donor's concrete value, and the other members were
+    checked against the donor's domain."""
+
+    @pytest.mark.parametrize("form", ["SPECIFICATION Spec",
+                                      "INIT Init\nNEXT Next"],
+                             ids=["specification", "init-next"])
+    @pytest.mark.parametrize("case", sorted(_LIFT_CASES))
+    def test_what_lifts(self, tmp_path, case, form):
+        from jaxmc.analyze.bounds import liftable_constants
+        spec, cfg = _lift_model(tmp_path, case, form=form)
+        # M shapes Init alone (`1..M`) and is a value in Inv: it lifts
+        # under both forms of cfg — no device program is traced from
+        # Init, every member enumerates its own init states on the host
+        assert liftable_constants(load_model(spec, cfg, True)) == \
+            _LIFT_CASES[case][1]
+
+    @pytest.mark.parametrize("case", ["bare-reference", "domain-by-name",
+                                      "applied-in-domain"])
+    def test_a_constant_in_a_domain_never_rides_a_lane(self, tmp_path,
+                                                       case):
+        from jaxmc.backend.batch import BatchCheckEngine, \
+            BatchIncompatible
+        pairs = [_lift_model(tmp_path, case, k=k) for k in (2, 3)]
+        cfgs = [SessionConfig(spec=s, cfg=c, backend="jax",
+                              platform="cpu", host_seen=True,
+                              no_deadlock=True) for s, c in pairs]
+        # two classes: the daemon never claims them together ...
+        assert len({batch_profile(c).bsig for c in cfgs}) == 2
+        # ... and the engine refuses them where it is handed both (the
+        # parent built them and answered K = 3 with K = 2's counts)
+        with pytest.raises(BatchIncompatible, match="constant K"):
+            BatchCheckEngine(cfgs).build()
+        exact = [Explorer(load_model(s, c, True)).run()
+                 for s, c in pairs]
+        assert exact[0].generated != exact[1].generated
+
+    @pytest.mark.parametrize("form", ["SPECIFICATION Spec",
+                                      "INIT Init\nNEXT Next"],
+                             ids=["specification", "init-next"])
+    def test_a_constant_of_init_alone_rides_a_lane_exactly(self, tmp_path,
+                                                           form):
+        from jaxmc.backend.batch import BatchCheckEngine
+        pairs = [_lift_model(tmp_path, "bare-reference", m=m, form=form)
+                 for m in (2, 3, 5)]
+        cfgs = [SessionConfig(spec=s, cfg=c, backend="jax",
+                              platform="cpu", host_seen=True,
+                              no_deadlock=True) for s, c in pairs]
+        assert len({batch_profile(c).bsig for c in cfgs}) == 1
+        be = BatchCheckEngine(cfgs).build()
+        assert be.lift_names == ("M",)
+        for mem, (s, c) in zip(be.run(), pairs):
+            exact = Explorer(load_model(s, c, True)).run()
+            assert mem.error is None
+            assert (mem.result.generated, mem.result.distinct,
+                    mem.result.diameter, mem.result.ok) == \
+                (exact.generated, exact.distinct, exact.diameter,
+                 exact.ok)
+
+
 class TestStructuralMerge:
     """Structural batch-bound merge (ISSUE 18): the donor keeps
     per-element EB trees — the interval-union over members — instead of
@@ -243,14 +349,29 @@ class TestStructuralMerge:
     def msgstoy_cohort(self, tmp_path_factory):
         # same module, Cap=2 vs Cap=3: `msgs` is a per-process table,
         # so the donor layout depends on MERGED per-element bounds
+        # msgstoy with Tick's filter off Cap: there `Cap` stands in the
+        # predicate of a set that is a quantifier's DOMAIN, a pinned
+        # position since ISSUE 39 (`liftable_constants` follows the bare
+        # reference `Tick` now), and Cap would not lift
+        from jaxmc.analyze.bounds import liftable_constants
         from jaxmc.backend.batch import BatchCheckEngine
-        spec = os.path.join(SPECS, "msgstoy.tla")
-        cfg2 = os.path.join(SPECS, "msgstoy.cfg")
-        cfg3 = str(tmp_path_factory.mktemp("msgstoy") / "cap3.cfg")
-        with open(cfg3, "w") as f:
-            f.write("INIT Init\nNEXT Next\nINVARIANT DoneOK\n"
-                    "CONSTANTS\n  Procs = {p1, p2, p3}\n  Cap = 3\n"
-                    "  T = 2\n  P1 = p1\n")
+        tmp = tmp_path_factory.mktemp("msgstoy")
+        text = open(os.path.join(SPECS, "msgstoy.tla")).read()
+        assert "clock[m] < Cap" in text
+        spec = str(tmp / "msgstoy.tla")
+        with open(spec, "w") as f:
+            f.write(text.replace("clock[m] < Cap", "clock[m] < 2"))
+        cfg2, cfg3 = str(tmp / "cap2.cfg"), str(tmp / "cap3.cfg")
+        for path, cap in ((cfg2, 2), (cfg3, 3)):
+            with open(path, "w") as f:
+                f.write("INIT Init\nNEXT Next\nINVARIANT DoneOK\n"
+                        "CONSTANTS\n  Procs = {p1, p2, p3}\n"
+                        f"  Cap = {cap}\n  T = 2\n  P1 = p1\n")
+        assert liftable_constants(load_model(spec, cfg2, False)) == \
+            ("Cap",)
+        assert liftable_constants(load_model(
+            os.path.join(SPECS, "msgstoy.tla"),
+            os.path.join(SPECS, "msgstoy.cfg"), False)) == ()
         cfgs = [SessionConfig(spec=spec, cfg=c, backend="jax",
                               platform="cpu", host_seen=True)
                 for c in (cfg2, cfg3)]

@@ -286,7 +286,10 @@ def test_later_engines_dispatch_the_program_the_owner_holds(stream, label):
     from the init states and writes its own finalized checkpoint."""
     edits = sorted((j for k, j in stream["jobs"].items()
                     if j["kind"] == "edit" and k[2] == label),
-                   key=lambda j: j["rec"]["started_at"])
+                   key=lambda j: j["rec"]["finished_at"])
+    # (by `finished_at`: the owner is serial, so the job it ran first
+    # ended first; two workers mark `started_at` before either has the
+    # owner's pipe, in either order)
     assert len(edits) == 6
     first, later = edits[0], edits[1:]
 
