@@ -24,7 +24,13 @@ compiled device program:
            shared BatchDispatcher, which waits until every ACTIVE
            member has a pending chunk and then runs ONE
            jit(vmap(hstep_core)) over [B, CH, PW] frontiers + [B]
-           counts + [B, n_lift] constant vectors.
+           counts + [B, n_lift] constant vectors.  The same program
+           lays each member's nine outputs into ONE int32 block
+           (`_pack_lane`), the dispatcher fetches the live members'
+           blocks in ONE device-to-host transfer, and a member reads
+           the nine names back as numpy views into its block
+           (`_unpack_lane`): what a member sees is what the solo step
+           returns, as host arrays.
   ragged   per-member frontier occupancy is handled by the step's own
            validity masks (fcount per lane); a member that finishes —
            exhaustion, violation, truncation, drain — DEREGISTERS and
@@ -84,6 +90,82 @@ class _MergedBounds:
         return out
 
 
+# ---- the superstep's result block -------------------------------------
+# What a superstep brings back is ONE int32 block a member, words-major:
+# [PW + K + 1, C + _HDR], the C = A * CH candidate slots in the minor
+# dimension.  Rows 0..PW-1 are the packed candidate words (`cand`
+# transposed), the next K the key lanes (`keys` transposed), the last
+# row a flags word a slot; the _HDR columns past the slots are the
+# header: `gen` and `overflow` in the flags row, zeros above.  Where C
+# is a multiple of 128 (any chunk of 128 rows or more) the chip's
+# default layout for the block is the (8, 128) tiling it computes in,
+# with no temporaries; a slots-major [C, PW + K + 1] block is laid
+# words-major on the device all the same, its transpose left to the
+# transfer, and fetches no faster (PERF.md section 6, PR 40).
+_HDR = 128
+_F_CVALID, _F_INV_OK, _F_EXPLORE, _F_ASSERT, _F_DEAD = 1, 2, 4, 8, 16
+
+
+def _pack_lane(out: Dict[str, Any]):
+    """hstep_core's nine outputs of ONE member as its int32 block (on
+    the device, inside the vmapped program)."""
+    A, CH = out["assert_bad"].shape
+    C = A * CH
+
+    def bits(x, flag):
+        return jnp.where(x, jnp.int32(flag), jnp.int32(0))
+
+    flags = bits(out["cvalid"], _F_CVALID) \
+        | bits(out["inv_ok"], _F_INV_OK) \
+        | bits(out["explore"], _F_EXPLORE) \
+        | bits(out["assert_bad"].reshape(C), _F_ASSERT) \
+        | jnp.pad(bits(out["dead"], _F_DEAD), (0, C - CH))
+    body = jnp.concatenate(
+        [out["cand"].T, out["keys"].T, flags[None]], axis=0)
+    head = jnp.zeros((body.shape[0], _HDR), jnp.int32) \
+        .at[-1, 0].set(out["gen"]).at[-1, 1].set(out["overflow"])
+    return jnp.concatenate([body, head], axis=1)
+
+
+def _packed(core):
+    """vmap of `core` with every member's outputs laid into its block:
+    (frontiers [B, CH, PW], fcounts [B], cvecs [B, n_lift]) ->
+    int32 [B, PW + K + 1, C + _HDR]."""
+    def packed_core(frontier_p, fcount, cvec):
+        return _pack_lane(core(frontier_p, fcount, cvec))
+
+    return jax.vmap(packed_core)
+
+
+def _unpack_lane(blk: np.ndarray, PW: int, CH: int) -> Dict[str, Any]:
+    """One member's block back as hstep_core's nine names: numpy VIEWS
+    into `blk` for the words (`cand` [C, PW], `keys` [C, K]: transposed
+    views, so a row take `keys[idx]` gathers columns of the block and
+    nothing transposes the whole of it), bools decoded from the flags
+    row, `gen` / `overflow` the header's int32 scalars."""
+    C = blk.shape[1] - _HDR
+    flags = blk[-1, :C]
+    return dict(
+        cand=blk[:PW, :C].T, keys=blk[PW:-1, :C].T,
+        cvalid=(flags & _F_CVALID) != 0,
+        inv_ok=(flags & _F_INV_OK) != 0,
+        explore=(flags & _F_EXPLORE) != 0,
+        assert_bad=((flags & _F_ASSERT) != 0).reshape(C // CH, CH),
+        dead=(flags[:CH] & _F_DEAD) != 0,
+        gen=blk[-1, C], overflow=blk[-1, C + 1])
+
+
+def _live_lanes(blk, idx):
+    return blk.at[idx].get(mode="promise_in_bounds",
+                           indices_are_sorted=True, unique_indices=True)
+
+
+# the live lanes of a block, gathered on the device before the fetch:
+# one small program per WIDTH (the index's length), shared by every
+# cohort of the process
+_take_lanes = obs.prof_wrap("batch.take", jax.jit(_live_lanes))
+
+
 class BatchDispatcher:
     """The superstep barrier: collects one pending device chunk per
     ACTIVE member, runs ONE vmapped dispatch, hands each member its
@@ -97,7 +179,7 @@ class BatchDispatcher:
         self.PW = donor.PW
         self._core = donor._hstep_core(self.CH)
         self._vstep = obs.prof_wrap("batch.vstep",
-                                    jax.jit(jax.vmap(self._core)))
+                                    jax.jit(_packed(self._core)))
         self._cvecs = jnp.asarray(np.ascontiguousarray(cvecs, np.int32))
         # the cohort's recorder: every superstep's spans, the site's
         # launch seconds and the vmapped program's record land here,
@@ -106,7 +188,8 @@ class BatchDispatcher:
         self._cv = threading.Condition()
         self._active: set = set(range(self.B))
         self._pending: Dict[int, Tuple[np.ndarray, int]] = {}
-        self._results: Dict[int, Dict[str, Any]] = {}
+        self._results: Dict[int, Any] = {}
+        self._lane_idx: Dict[Tuple[int, ...], Any] = {}
         self._gen = 0            # dispatch generation (wakeup marker)
         self.dispatches = 0
         self.max_width = 0
@@ -184,24 +267,42 @@ class BatchDispatcher:
             raise RuntimeError(
                 f"vmapped batch dispatch failed: "
                 f"{type(res).__name__}: {res}") from res
-        return res
+        return _unpack_lane(res, self.PW, self.CH)
+
+    def _lanes(self, slots: Tuple[int, ...]):
+        """The device index of the live lanes `slots`, made once a
+        membership: members leave one by one, so a run sees few."""
+        idx = self._lane_idx.get(slots)
+        if idx is None:
+            idx = self._lane_idx[slots] = jnp.asarray(
+                np.asarray(slots, np.int32))
+        return idx
 
     def _fire_locked(self) -> None:
         """One vmapped dispatch over every pending member lane (caller
         holds the condition).  A dispatch failure is distributed to
         every pending slot as its result — see _step.
 
+        What comes back is the program's packed result, one int32
+        block a member, in ONE transfer: all B blocks where every lane
+        is pending, else the pending lanes' alone, gathered on the
+        device first (`_take_lanes`: an idle lane's block is sentinel
+        padding nobody reads).  A member gets its block as it arrived
+        and decodes it on its own thread, outside the lock (`_step`).
+
         In the cohort's recorder, whichever member's thread fires: the
-        span `batch.dispatch` (upload, the vmapped program, every
-        output fetched: a synchronous round trip, and the span a
-        trace's dispatches are counted by) and float counters round it, which
-        cost a dispatch no event — `batch.stack_s` (the host stacks
-        the pending chunks into one [B, CH, PW] block),
-        `batch.unstack_s` (each member handed its slice),
-        `batch.upload_s` and `batch.fetch_s` (the round trip's two
-        ends, round the site's own launch seconds) and
-        `batch.first_dispatch_s` (the cohort's first call alone:
-        trace, lower, compile or load)."""
+        span `batch.dispatch` (upload, the vmapped program, the block
+        fetched: a synchronous round trip, and the span a trace's
+        dispatches are counted by) and counters round it, which cost a
+        dispatch no event — `batch.stack_s` (the host stacks the
+        pending chunks into one [B, CH, PW] block), `batch.unstack_s`
+        (each member handed its block), `batch.upload_s` and
+        `batch.fetch_s` (the round trip's two ends, round the site's
+        own launch seconds; the fetch holds the wait for the program
+        and the live lanes' gather), `batch.fetch_transfers` (device-
+        to-host transfers: one a dispatch) and `batch.fetch_mb` (bytes
+        brought back / 10^6), and `batch.first_dispatch_s` (the
+        cohort's first call alone: trace, lower, compile or load)."""
         rec = self.tel
         slots = sorted(self._pending)
         width = len(slots)
@@ -218,11 +319,15 @@ class BatchDispatcher:
                 t0 = time.perf_counter()
                 args = (jnp.asarray(fr), jnp.asarray(fc), self._cvecs)
                 t1 = time.perf_counter()
-                out = self._vstep(*args)
+                blk = self._vstep(*args)
                 t2 = time.perf_counter()
-                out_np = {k: np.asarray(v) for k, v in out.items()}
+                if width < self.B:
+                    blk = _take_lanes(blk, self._lanes(tuple(slots)))
+                blk = np.asarray(blk)
                 rec.counter("batch.upload_s", t1 - t0)
                 rec.counter("batch.fetch_s", time.perf_counter() - t2)
+                rec.counter("batch.fetch_transfers")
+                rec.counter("batch.fetch_mb", blk.nbytes / 1e6)
                 if not self.dispatches:
                     rec.counter("batch.first_dispatch_s", t2 - t1)
         except Exception as ex:  # noqa: BLE001 — XLA runtime/OOM/
@@ -232,8 +337,8 @@ class BatchDispatcher:
             self._cv.notify_all()
             return
         with rec.timed("batch.unstack_s"):
-            for s in slots:
-                self._results[s] = {k: v[s] for k, v in out_np.items()}
+            for i, s in enumerate(slots):
+                self._results[s] = blk[i]
         self.dispatches += 1
         self.max_width = max(self.max_width, width)
         self.widths.append(width)

@@ -116,9 +116,10 @@ def filter_init_states(model, layout, init_rows):
 def _any_fast(x) -> bool:
     """bool(any(x)) without lifting a HOST array onto the device: the
     batched host_seen loop receives numpy step outputs (the vmapped
-    dispatcher converts once for all members), and an eager jnp.any on
-    those pays a host->device->host round trip PER CALL, which at
-    thousands of supersteps dominated the batch win."""
+    dispatcher fetches one packed block a superstep and a member's
+    outputs are numpy views into it, backend/batch.py), and an eager
+    jnp.any on those pays a host->device->host round trip PER CALL,
+    which at thousands of supersteps dominated the batch win."""
     if isinstance(x, np.ndarray):
         return bool(np.any(x))
     return bool(jnp.any(x))

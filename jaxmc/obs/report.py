@@ -467,6 +467,10 @@ def _cohort_lines(s: Dict[str, Any], out) -> None:
     if n:
         fire = ph.get("batch.dispatch", 0.0) + \
             c.get("batch.stack_s", 0.0) + c.get("batch.unstack_s", 0.0)
+        # what came back (ISSUE 40): one transfer a dispatch
+        back = (f" in {c['batch.fetch_transfers']} transfers, "
+                f"{c.get('batch.fetch_mb', 0.0):.1f} MB"
+                if "batch.fetch_transfers" in c else "")
         print(f"cohort: build {_fmt_s(ph.get('batch.build'))}, first "
               f"call {_fmt_s(c.get('batch.first_dispatch_s'))}, run "
               f"{_fmt_s(ph.get('batch.run'))}; {n} dispatches, "
@@ -474,7 +478,7 @@ def _cohort_lines(s: Dict[str, Any], out) -> None:
               f"firing them {_fmt_s(fire)} (stack "
               f"{_fmt_s(c.get('batch.stack_s'))}, upload "
               f"{_fmt_s(c.get('batch.upload_s'))}, fetch "
-              f"{_fmt_s(c.get('batch.fetch_s'))}, unstack "
+              f"{_fmt_s(c.get('batch.fetch_s'))}{back}, unstack "
               f"{_fmt_s(c.get('batch.unstack_s'))})", file=out)
     if c.get("hostseen.chunks"):
         wait = c.get("batch.barrier_wait_s")

@@ -612,7 +612,7 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       in ITS artifact, `batch_sample`, `engine_build` and the donor's
       own build spans beside it) and `batch.run` (all of `run()`);
       per superstep `batch.dispatch` (upload, the vmapped program,
-      every output fetched: a synchronous round trip under the
+      its result block fetched: a synchronous round trip under the
       dispatcher's lock; the span a trace's dispatches are counted by,
       `jaxmc.batch.dispatch`).
     - float counters in the leader's artifact, seconds on
@@ -630,6 +630,18 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       dispatches) is the share of member lanes that held a chunk).
       The program record of `batch.vstep` (`prof.programs`, gauges
       `program.temp_bytes` / `.hbm_bytes`) lands there too.
+    - (PR 40) beside `batch.fetch_s`: counter `batch.fetch_transfers`
+      (device-to-host transfers: ONE a superstep, so it equals
+      `batch.dispatches`; up to PR 39 the nine outputs came one by
+      one, nine transfers) and float counter `batch.fetch_mb` (bytes
+      brought back / 10^6).  The vmapped program packs a member's nine
+      outputs into one int32 block [PW + K + 1, C + 128], words-major
+      (candidate words, key lanes, one flags word a slot; `gen` and
+      `overflow` in the 128 header columns), and a superstep of fewer
+      than B live lanes gathers those lanes on the device first: prof
+      site `batch.take`, one small program per width, shared by every
+      cohort of a process (its record lands in the artifact of the
+      cohort whose call made the executable).
     - every MEMBER's artifact: float counter `batch.barrier_wait_s`
       (its seconds inside supersteps that were not the firing
       itself: the lock, the slower members' chunks, the dispatch

@@ -159,6 +159,139 @@ class TestVmappedEngine:
             assert ok[v].ok and ok[v].violation is None
             assert not ok[v].truncated
 
+    def test_which_supersteps_have_which_width(self, batch_run):
+        # the cohort the parity tests above run is ragged: a level is
+        # one chunk here, so a member rides one lane a level until it
+        # is done.  `bad` violates at level 5 and leaves after five
+        # supersteps of width 4; `c` (diameter 19) exhausts after 20,
+        # `a` (27) after 28, and `b` (39) runs the last twelve ALONE:
+        # one live lane beside three idle ones, whose block is the only
+        # one fetched (ISSUE 40)
+        be, members = batch_run
+        assert [m.result.diameter for m in members] == [27, 39, 19, 5]
+        assert be.dispatcher.widths == \
+            [4] * 5 + [3] * 15 + [2] * 8 + [1] * 12
+        assert be.dispatcher.dispatches == 40
+
+    @pytest.fixture(scope="class")
+    def superstep(self, batch_run):
+        """A dispatcher of the cohort's own (fresh barrier state, its
+        own recorder), one real stacked input — the widest superstep's
+        of a re-run, all four lanes live — and what `vmap(hstep_core)`
+        itself answers for it."""
+        import jax
+        import numpy as np
+        from jaxmc import obs
+        from jaxmc.backend.batch import BatchDispatcher
+        be, members = batch_run
+        seen = []
+        real = be.dispatcher._vstep
+
+        def spy(fr, fc, cv):
+            seen.append((np.asarray(fr), np.asarray(fc)))
+            return real(fr, fc, cv)
+
+        be.dispatcher._vstep = spy
+        try:
+            be.run()
+        finally:
+            be.dispatcher._vstep = real
+        fr, fc = max((x for x in seen if (x[1] > 0).all()),
+                     key=lambda x: int(x[1].sum()))
+        cvecs = np.stack([m.engine._cvec for m in members])
+        disp = BatchDispatcher(members[0].engine, cvecs,
+                               tel=obs.Telemetry())
+        ref = jax.jit(jax.vmap(disp._core))(fr, fc, disp._cvecs)
+        return disp, fr, fc, {k: np.asarray(v) for k, v in ref.items()}
+
+    @pytest.mark.parametrize("slots", [(2,), (1, 3), (0, 1, 3),
+                                       (0, 1, 2, 3)],
+                             ids=lambda s: f"width{len(s)}")
+    def test_pack_fetch_unpack_is_the_identity(self, superstep, slots):
+        # what a member is handed answers all nine names with the
+        # dtypes, shapes and values of vmap(hstep_core)'s own outputs:
+        # the full block (width 4) and the live lanes alone (1-3),
+        # through the members' own surface, one thread a live member
+        import numpy as np
+        disp, fr, fc, ref = superstep
+        disp.reset()
+        for s in set(range(disp.B)) - set(slots):
+            disp.deregister(s)
+        c0 = dict(disp.tel.counters)
+        got = {}
+
+        def member(s):
+            got[s] = disp.hstep_factory(s)(disp.CH)(fr[s], fc[s])
+
+        ts = [threading.Thread(target=member, args=(s,)) for s in slots]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(60)
+        assert sorted(got) == list(slots)
+        assert disp.widths == [len(slots)]
+        for s in slots:
+            assert sorted(got[s]) == sorted(ref)
+            for k, want in ref.items():
+                have = np.asarray(got[s][k])
+                assert have.dtype == want.dtype, (k, have.dtype)
+                assert have.shape == want[s].shape, (k, have.shape)
+                assert np.array_equal(have, want[s]), (s, k)
+        assert int(ref["gen"].sum()) > 0 and ref["cvalid"].any()
+        # ONE transfer, of the live lanes' blocks and nothing else
+        c = disp.tel.counters
+        assert c["batch.fetch_transfers"] - \
+            c0.get("batch.fetch_transfers", 0) == 1
+        rows = disp.PW + ref["keys"].shape[-1] + 1
+        lane_mb = rows * (ref["cvalid"].shape[-1] + 128) * 4 / 1e6
+        assert c["batch.fetch_mb"] - c0.get("batch.fetch_mb", 0.0) == \
+            pytest.approx(len(slots) * lane_mb)
+
+    def test_a_failed_dispatch_reaches_every_waiting_member(
+            self, superstep):
+        # `_fire_locked`'s except branch: the thread that completes the
+        # barrier runs the dispatch, and its failure is handed to EVERY
+        # member as that member's own error — nobody is left waiting
+        # on a lane that cannot fire again
+        disp, fr, fc, _ref = superstep
+        disp.reset()
+        real = disp._vstep
+
+        def boom(*_a):
+            raise FloatingPointError("injected: the device fell over")
+
+        disp._vstep = boom
+        n0 = disp.tel.counters.get("batch.dispatches", 0)
+        errs = {}
+
+        def member(s):
+            try:
+                disp.hstep_factory(s)(disp.CH)(fr[s], fc[s])
+            except BaseException as ex:  # noqa: BLE001
+                errs[s] = ex
+            finally:
+                disp.deregister(s)
+
+        ts = [threading.Thread(target=member, args=(s,), daemon=True)
+              for s in range(disp.B)]
+        try:
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(30)
+            assert not [t for t in ts if t.is_alive()], "deadlock"
+        finally:
+            disp._vstep = real
+        assert sorted(errs) == list(range(disp.B))
+        for ex in errs.values():
+            assert isinstance(ex, RuntimeError)
+            assert "vmapped batch dispatch failed" in str(ex)
+            assert isinstance(ex.__cause__, FloatingPointError)
+        assert disp.dispatches == 0 and not disp._results
+        # nothing was fetched, and nothing says it was
+        assert disp.tel.counters.get("batch.fetch_transfers", 0) == \
+            disp.tel.counters.get("batch.dispatches", 0) == n0
+
     def test_member_counts_differ(self, batch_run):
         # NON-identical jobs: the whole point vs PR 7's coalescing
         _be, members = batch_run

@@ -288,6 +288,9 @@ def test_the_cohorts_spans_and_counters_reach_the_client(served, commit):
             "batch.run", "search", "checkpoint.write"} <= set(ph)
     assert ph["batch.dispatch"]["count"] == n
     assert c["batch.dispatches"] == n
+    # ONE device-to-host transfer a superstep (ISSUE 40; nine before)
+    assert c["batch.fetch_transfers"] == n
+    assert c["batch.fetch_mb"] > 0
     assert n <= c["batch.lane_steps"] <= 4 * n
     for name in ("batch.stack_s", "batch.unstack_s", "batch.upload_s",
                  "batch.fetch_s", "batch.first_dispatch_s"):
@@ -297,8 +300,12 @@ def test_the_cohorts_spans_and_counters_reach_the_client(served, commit):
     assert art["prof"]["sites"]["batch.vstep"]["dispatches"] == n
     assert [p["site"] for p in art["prof"]["programs"]
             if p["site"] == "batch.vstep"] == ["batch.vstep"]
+    # a ragged cohort's narrower supersteps gather their live lanes on
+    # the device before the one fetch (ISSUE 40)
+    assert 0 < art["prof"]["sites"]["batch.take"]["dispatches"] < n
     for j in rest:
         assert "batch.vstep" not in j["art"]["prof"]["sites"]
+        assert "batch.take" not in j["art"]["prof"]["sites"]
         assert not [k for k in j["art"]["counters"]
                     if k.startswith("batch.") and
                     k != "batch.barrier_wait_s"]
@@ -410,6 +417,7 @@ def test_the_schema_documents_the_cohorts_names():
     for name in ("batch.build", "batch.run", "batch.dispatch",
                  "batch.stack_s", "batch.unstack_s", "batch.upload_s",
                  "batch.fetch_s", "batch.first_dispatch_s",
+                 "batch.fetch_transfers", "batch.fetch_mb",
                  "batch.lane_steps", "batch.barrier_wait_s",
                  "hostseen.chunks", "hostseen.step_s", "hostseen.store_s",
                  "hostseen.store_keys", "hostseen.book_s",
@@ -422,7 +430,8 @@ def test_report_prints_where_a_cohorts_wall_went(served, tmp_path):
 
     from jaxmc.obs.report import main as obs_main
     lead, rest = _leader(served, "ci-b", 2)
-    for job, want, miss in ((lead, ("cohort: build ", "host_seen: "), ()),
+    for job, want, miss in ((lead, ("cohort: build ", "host_seen: ",
+                                    " transfers, "), ()),
                             (rest[0], ("host_seen: ",), ("cohort: ",))):
         path = str(tmp_path / (job["id"] + ".json"))
         with open(path, "w", encoding="utf-8") as fh:
