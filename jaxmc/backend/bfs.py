@@ -22,6 +22,7 @@ store_trace=False for benchmark runs.
 
 from __future__ import annotations
 
+import math
 import os
 import threading
 import time
@@ -249,20 +250,26 @@ def _lsd_sort(key_cols, extra_cols):
     radix over the key words, least-significant first).  Equivalent to
     `lax.sort(key_cols + extra_cols, num_keys=len(key_cols))` with
     key_cols[0] most significant — but multi-key sort comparators
-    explode XLA compile time inside while loops, and both resident
-    engines (single-chip tpu/bfs.py and the mesh superstep,
-    tpu/mesh.py) run this under lax.while_loop.  Returns the
-    (key_cols, extra_cols) lists co-sorted."""
-    cols = list(key_cols) + list(extra_cols)
+    explode XLA compile time inside while loops, and the single-chip
+    resident engine runs this under lax.while_loop.
+
+    The passes are ONE lax.sort in a lax.fori_loop (ISSUE 42): the
+    columns ride the carry with the pass's key first, and each turn
+    hands them on rotated, so that the next key leads and after the
+    last pass every column is back in its place.  XLA:TPU's compile
+    seconds follow the sort INSTRUCTIONS of a program (3-5 s each at
+    2^20 slots and over: PERF.md §6, PR 42), and _rank_merge holds one
+    such chain a rung of its ladder.  Returns the (key_cols,
+    extra_cols) lists co-sorted."""
     nk = len(key_cols)
-    for kj in range(nk - 1, -1, -1):  # least-significant first
-        rest = [c for i, c in enumerate(cols) if i != kj]
-        res = lax.sort(tuple([cols[kj]] + rest), num_keys=1,
-                       is_stable=True)
-        out_rest = list(res[1:])
-        cols = [res[0] if i == kj else out_rest.pop(0)
-                for i in range(len(cols))]
-    return cols[:nk], cols[nk:]
+    cols = tuple(reversed(key_cols)) + tuple(extra_cols)
+
+    def one_pass(_, cols):
+        res = lax.sort(cols, num_keys=1, is_stable=True)
+        return tuple(res[1:nk]) + (res[0],) + tuple(res[nk:])
+
+    cols = lax.fori_loop(0, nk, one_pass, cols)
+    return list(reversed(cols[:nk])), list(cols[nk:])
 
 
 @jax.named_scope("jaxmc.merge.probe")
@@ -438,8 +445,53 @@ def _merge_blocks(seen_count2, sc: int):
     return jnp.minimum(blocks, most)
 
 
+# The smallest rung of _rank_merge's sort ladder (ISSUE 42).  A level's
+# candidates sit in a prefix of the N key slots (13-24 % of them hold a
+# key in the benchmark's resident cells: PERF.md §5), and a sort's cost
+# follows the slots it is given, so the keys are sorted over the
+# smallest of a static ladder of prefixes that holds them: N, N/2, N/4
+# ... down to this floor.  Every rung is one more lax.sort for XLA to
+# compile (_lsd_sort's passes are a loop; PERF.md §6, PR 42 has the
+# seconds: +3 s cold for the six rungs under 2^21, +11 s for the eight
+# under 2^23), so the ladder stops where a rung saves microseconds.
+# The tests lower it to cut toy shapes into several rungs.
+_SORT_RUNG_MIN = 1 << 15
+
+
+def _sort_rungs(n: int) -> Tuple[int, ...]:
+    """The ladder: prefixes of n key slots _rank_merge may sort instead
+    of all n, descending from n by halves while a half is no shorter
+    than _SORT_RUNG_MIN.  A static function of the key shape alone."""
+    rungs = [n]
+    while rungs[-1] // 2 >= max(_SORT_RUNG_MIN, 1):
+        rungs.append(rungs[-1] // 2)
+    return tuple(rungs)
+
+
+def _sort_rung_index(n_prefix, n: int):
+    """Which rung of _sort_rungs(n) sorts a level whose valid keys sit
+    in keys[0:n_prefix]: the smallest that holds them (the whole n past
+    it).  The ONE rung rule: the kernel switches on it with a traced
+    count and the engines count `search.slots_sorted` with it (Python
+    ints work too)."""
+    rungs = np.asarray(_sort_rungs(n))
+    if isinstance(n_prefix, (int, np.integer)):
+        return max(int(np.sum(rungs >= n_prefix)) - 1, 0)
+    return jnp.maximum(
+        jnp.sum(jnp.asarray(rungs) >= n_prefix, dtype=jnp.int32) - 1, 0)
+
+
+def _sort_unit(n: int) -> int:
+    """The slots the rungs of n are whole multiples of (the smallest
+    rung where n is a power of two): a dispatch's loop carry counts
+    sorted slots in this unit, as it counts blocks and not slots for
+    the probe and the build, so hundreds of levels fit an int32."""
+    return math.gcd(*_sort_rungs(n))
+
+
 @jax.named_scope("jaxmc.merge.scatter")
-def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
+def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
+                n_prefix=None):
     """The O(new) seen-merge core SHARED by the single-chip resident
     level, the level engine and the mesh engine's shards (ISSUE 10;
     the _candidate_block_fn-style shared-plumbing pattern): the seen
@@ -495,20 +547,53 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
       merge_blocks  blocks of seen2 built (× _merge_block_rows(SC) = the
                  slots behind `search.slots_merged`).
 
+      sort_slots  key slots the sort was given: the rung's (N without
+                 n_prefix; the slots behind `search.slots_sorted`).
+
     multikey=True sorts the candidate keys with ONE stable multi-key
     lax.sort instead of the LSD chain (the level and mesh engines use
     it); the single-chip resident engine keeps the LSD chain its
-    compile envelope was measured with."""
+    compile envelope was measured with.
+
+    n_prefix (traced) promises what _seen_probe's n_live promises for
+    queries: every VALID key sits in keys[0:n_prefix] (invalid rows may
+    sit among them).  The sort then follows the rows that exist (ISSUE
+    42): a lax.switch on _sort_rung_index runs the LSD chain over the
+    smallest prefix of _sort_rungs(N) that holds them and leaves the
+    rows past it where they are — invalid already, their sidx the
+    arange.  The valid rows come out first and in the full sort's
+    stable order; only the order of the invalid rows among themselves
+    differs, and nothing below reads it.  Without it there is no
+    ladder and no switch: the level and the mesh engines' keys are no
+    prefix, and they keep the program they had."""
     sidx = jnp.arange(N, dtype=jnp.int32)
+    rungs = (N,) if n_prefix is None or multikey else _sort_rungs(N)
+    sort_slots = N
     with jax.named_scope("jaxmc.merge.sort"):
         if multikey:
             res = lax.sort(tuple(keys[:, j] for j in range(K)) + (sidx,),
                            num_keys=K, is_stable=True)
             kc = list(res[:K])
             sidx_s = res[K]
-        else:
+        elif len(rungs) == 1:
             kc, ec = _lsd_sort([keys[:, j] for j in range(K)], [sidx])
             sidx_s = ec[0]
+        else:
+            def sort_prefix(r):
+                def branch(cols):
+                    kc, ec = _lsd_sort([c[:r] for c in cols[:K]],
+                                       [cols[K][:r]])
+                    return tuple(
+                        lax.dynamic_update_slice(c, s, (0,))
+                        for c, s in zip(cols, kc + ec))
+                return branch
+
+            rung = _sort_rung_index(n_prefix, N)
+            res = lax.switch(rung, [sort_prefix(r) for r in rungs],
+                             tuple(keys[:, j] for j in range(K)) + (sidx,))
+            kc = list(res[:K])
+            sidx_s = res[K]
+            sort_slots = jnp.asarray(rungs, jnp.int32)[rung]
     skeys = jnp.stack(kc, axis=1)
     # the table is read only once the keys are sorted: without the tie
     # XLA:TPU starts fetching the probe's copy of it into fast memory
@@ -619,7 +704,7 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
     return dict(new_count=new_count, nk_sidx=nk_sidx, seen2=seen2,
                 seen_count2=seen_count2,
                 probe_blocks=_probe_blocks(n_live, N),
-                merge_blocks=merge_blocks)
+                merge_blocks=merge_blocks, sort_slots=sort_slots)
 
 
 @jax.named_scope("jaxmc.keys")
@@ -2782,7 +2867,11 @@ class TpuExplorer:
             # device time on the v5e; ledger, PR 24), so the sort work
             # is O(new), not O(seen), per level; only the blocks of the
             # table that hold a live row after the level are built.
-            rm = _rank_merge(seen, seen_count, acc_keys, AccCap, SC, K)
+            # Every chunk appended its block at acc_n, so the valid keys
+            # are a prefix of the accumulator and the sort takes the
+            # smallest rung of its ladder that holds it (ISSUE 42).
+            rm = _rank_merge(seen, seen_count, acc_keys, AccCap, SC, K,
+                             n_prefix=jnp.minimum(acc_n, AccCap))
             with jax.named_scope("jaxmc.compact"):
                 new_count = rm["new_count"]
                 nvalid = jnp.arange(AccCap) < new_count
@@ -2842,23 +2931,25 @@ class TpuExplorer:
             return (seen2, seen_count2, front_rows, explore_count, gen,
                     explore_count, stat, inv_bad_which, bad_row, ovcode,
                     pora, porx, porm, rm["probe_blocks"],
-                    rm["merge_blocks"])
+                    rm["merge_blocks"],
+                    rm["sort_slots"] // _sort_unit(AccCap))
 
         def run(seen, seen_count, frontier, fcount, distinct,
                 gen_lo, gen_hi, depth, max_states, maxlvl):
             def cond(carry):
                 (_, _, _, _, _, _, _, _, lvls, stat, _, _, _,
-                 _, _, _, _, _) = carry
+                 _, _, _, _, _, _) = carry
                 return (stat == ST_CONTINUE) & (lvls < maxlvl)
 
             def body(carry):
                 (seen, seen_count, frontier, fcount, distinct,
                  gen_lo, gen_hi, depth, lvls, stat, which, brow,
-                 ovcode, pora, porx, porm, pblocks, mblocks) = carry
+                 ovcode, pora, porx, porm, pblocks, mblocks,
+                 sunits) = carry
                 (seen2, seen_count2, front2, fcount2, gen_l, kept,
                  lstat, lwhich, lbrow, lovcode, lpora, lporx,
-                 lporm, lpblocks, lmblocks) = level(seen, seen_count,
-                                                    frontier, fcount)
+                 lporm, lpblocks, lmblocks, lsunits) = level(
+                     seen, seen_count, frontier, fcount)
                 ovf = (lstat == ST_OVF_SEEN) | (lstat == ST_OVF_FRONT) | \
                     (lstat == ST_OVF_ACC) | (lstat == ST_OVF_VC) | \
                     (lstat == ST_OVF_LANES)
@@ -2905,29 +2996,34 @@ class TpuExplorer:
                         jnp.where(lstat == ST_OVF_LANES, lovcode,
                                   ovcode), pora2, porx2, porm2,
                         # work done, not work kept: a rolled-back level
-                        # searched and built its blocks too (as
-                        # slots_sorted)
-                        pblocks + lpblocks, mblocks + lmblocks)
+                        # sorted its rung and searched and built its
+                        # blocks too
+                        pblocks + lpblocks, mblocks + lmblocks,
+                        sunits + lsunits)
 
             carry0 = (seen, seen_count, frontier, fcount, distinct,
                       gen_lo, gen_hi, depth, jnp.int32(0),
                       jnp.int32(ST_CONTINUE), jnp.int32(-1),
                       jnp.full((PW,), SENTINEL, jnp.int32),
                       jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                      jnp.int32(0), jnp.int32(0), jnp.int32(0))
+                      jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                      jnp.int32(0))
             (seen, seen_count, frontier, fcount, distinct, gen_lo,
              gen_hi, depth, _, stat, which, brow, ovcode, pora, porx,
-             porm, pblocks, mblocks) = \
+             porm, pblocks, mblocks, sunits) = \
                 lax.while_loop(cond, body, carry0)
             # indices 0-8 are the PR-6 summary; 9-11 are the per-
             # dispatch POR counters (ISSUE 18; zero when POR is off);
             # 12 is the query blocks the merge's probe searched over
             # the dispatch's levels (ISSUE 27: search.slots_probed), 13
             # the blocks of seen2 it built (ISSUE 29:
-            # search.slots_merged)
+            # search.slots_merged), 14 the slots its key sorts were
+            # given, in units of _sort_unit(AccCap) (ISSUE 42:
+            # search.slots_sorted)
             summary = jnp.stack([stat, seen_count, fcount, distinct,
                                  gen_lo, gen_hi, depth, which, ovcode,
-                                 pora, porx, porm, pblocks, mblocks])
+                                 pora, porx, porm, pblocks, mblocks,
+                                 sunits])
             return seen, frontier, summary, brow
 
         # DONATED dispatch (ISSUE 6): the seen table (arg 0) and the
@@ -3616,6 +3712,7 @@ class TpuExplorer:
                 self._por_stats["masked"] += int(summary[11])
                 probe_blocks = int(summary[12])
                 merge_blocks = int(summary[13])
+                sort_units = int(summary[14])
                 # cold-tier filter (ISSUE 12): after a spill the device
                 # table restarted empty, so a committed level's frontier
                 # may hold rows whose keys live in the host/disk runs —
@@ -3660,13 +3757,15 @@ class TpuExplorer:
                           seen=seen_count, status=stat,
                           fresh_compile=fresh_compile,
                           wall_s=round(disp_wall, 6))
-            # work against capacity: every level the dispatch ran sorted
-            # AccCap slots and rewrote SC seen rows, whatever was valid (a
-            # level that ended in a rollback or a verdict ran too, and
-            # left depth where it was)
+            # work against capacity: every level the dispatch ran (one
+            # that ended in a rollback or a verdict ran too, and left
+            # depth where it was) sorted the rung of AccCap's ladder
+            # that held its candidates: the dispatch's loop carry
+            # summed them
             lvls = depth - depth_in + (stat in grow_flag or stat in (
                 ST_OVF_LANES, ST_DEADLOCK, ST_ASSERT))
-            tel.counter("search.slots_sorted", lvls * caps["AccCap"])
+            tel.counter("search.slots_sorted", sort_units
+                        * _sort_unit(caps["AccCap"]))
             tel.counter("search.rows_valid", generated - gen_in)
             # ... and binary-searched only the query blocks that held a
             # valid key: the dispatch's loop carry counted them

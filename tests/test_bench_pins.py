@@ -271,9 +271,10 @@ def test_the_deep_pins_are_the_corpus_manifests():
 def test_resident_engine_in_the_deep_proportions(tmp_path, monkeypatch):
     """3 procs / MaxMoney 4 against the plain reference, the capacities in
     the deep pins' proportions (SC : AccCap : FCap = 4 : 2 : 1, the table
-    512 merge blocks, the keys 64 query blocks) and the search split over
-    several dispatches: counts, and the two block counters equal to what
-    the reference's levels give by the kernels' own rules."""
+    512 merge blocks, the keys 64 query blocks and a sort ladder of nine
+    rungs) and the search split over several dispatches: counts, and the
+    two block counters and the sorted slots equal to what the reference's
+    levels give by the kernels' own rules."""
     pytest.importorskip("jax")
     from jaxmc.backend import bfs
     want = _reference().explore(3, 4)
@@ -284,15 +285,19 @@ def test_resident_engine_in_the_deep_proportions(tmp_path, monkeypatch):
     deep = _pins("transfer_scaled_4p")["res_caps"]
     assert (deep["SC"] // bfs._merge_block_rows(deep["SC"]),
             deep["AccCap"] // bfs._probe_block_rows(deep["AccCap"]),
+            deep["AccCap"] // bfs._sort_rungs(deep["AccCap"])[-1],
             deep["SC"] // deep["AccCap"], deep["AccCap"] // deep["FCap"]) \
-        == (512, 64, 2, 2)
+        == (512, 64, 256, 2, 2)
     monkeypatch.setattr(bfs, "_MERGE_BLOCK_ROWS", caps["SC"] // 512)
     monkeypatch.setattr(bfs, "_PROBE_BLOCK_MIN", 4)
+    monkeypatch.setattr(bfs, "_SORT_RUNG_MIN", caps["AccCap"] // 256)
     B, QB = bfs._merge_block_rows(caps["SC"]), \
         bfs._probe_block_rows(caps["AccCap"])
+    rungs = bfs._sort_rungs(caps["AccCap"])
     assert (caps["SC"] // B, caps["AccCap"] // QB,
+            caps["AccCap"] // rungs[-1],
             caps["SC"] // caps["AccCap"], caps["AccCap"] // caps["FCap"]) \
-        == (512, 64, 2, 2)
+        == (512, 64, 256, 2, 2)
     tel = obs.Telemetry()
     with obs.use(tel):
         sess = CheckSession(SessionConfig(
@@ -316,7 +321,11 @@ def test_resident_engine_in_the_deep_proportions(tmp_path, monkeypatch):
     assert c["search.slots_probed"] == sum(-(-cand // QB) * QB
                                            for _, cand, _ in levels)
     assert c["search.seen_slots"] == len(levels) * caps["SC"]
-    assert c["search.slots_sorted"] == len(levels) * caps["AccCap"]
+    # each level's candidates on the smallest rung that holds them
+    sorted_on = [rungs[bfs._sort_rung_index(cand, caps["AccCap"])]
+                 for _, cand, _ in levels]
+    assert len(set(sorted_on)) >= 3 and min(sorted_on) < caps["AccCap"]
+    assert c["search.slots_sorted"] == sum(sorted_on)
 
 
 def test_the_ooc_cells_spill_schedule_as_arithmetic():
@@ -349,11 +358,15 @@ def test_the_ooc_cells_spill_schedule_as_arithmetic():
         _cold_spills(pins["procs"], pins["max_money"], 1 << 19)
 
 
-def test_the_engine_spills_as_the_arithmetic_says(tmp_path):
+def test_the_engine_spills_as_the_arithmetic_says(tmp_path, monkeypatch):
     """The resident engine at 4 procs / MaxMoney 2 under a cap of 2^12
     rows: three spills, 67 cold duplicates, and every `tier.*` counter
-    equal to `_cold_spills`, on two searches of one session."""
+    equal to `_cold_spills`, on two searches of one session.  The sort's
+    ladder cut to eight rungs: a level sorts the rung that holds its
+    candidates, the three a spill rolled back both times."""
     pytest.importorskip("jax")
+    from jaxmc.backend import bfs
+    monkeypatch.setattr(bfs, "_SORT_RUNG_MIN", 64)
     sim = _cold_spills(4, 2, 1 << 12)
     assert sim["spills"] == [[4, 1728], [5, 1452], [6, 1499]]
     assert (sim["redone_rows"], sim["keys_probed"], sim["keys_dropped"],
@@ -387,6 +400,12 @@ def test_the_engine_spills_as_the_arithmetic_says(tmp_path):
                 sim["distinct"] - sim["levels"][0][0]
             assert rise["search.rows_valid"] == \
                 sim["generated"] - sim["levels"][0][0]
+            rungs = bfs._sort_rungs(caps["AccCap"])
+            sorted_on = [rungs[bfs._sort_rung_index(cand, caps["AccCap"])]
+                         for _, cand, _, _ in sim["levels"]]
+            assert len(rungs) == 8 and len(set(sorted_on)) >= 3
+            assert rise["search.slots_sorted"] == sum(sorted_on) + sum(
+                sorted_on[depth] for depth, _ in sim["spills"])
             # one span a spill; pull, keys and probe a probed level; a
             # push where a level had cold duplicates
             count = {p["name"]: p["count"] - spans.get(p["name"], 0)

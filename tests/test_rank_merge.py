@@ -648,8 +648,14 @@ def test_rank_merge_builds_seen2_in_a_traced_loop(K, multikey,
                   for loops, operand, result in _gathers(jaxpr.jaxpr)
                   if operand[1] == K)
     assert rows == [(1, (MB, K), (MB, K)), (1, (N, K), (MB, K))], rows
-    names = [eqn.primitive.name for eqn in jaxpr.jaxpr.eqns]
-    assert "scan" not in names, names
+    # the one scan there may be is the LSD chain's (ISSUE 42: the five
+    # passes as one sort in a loop); it gathers nothing
+    for eqn in jaxpr.jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            inner = [e.primitive.name
+                     for e in eqn.params["jaxpr"].jaxpr.eqns]
+            assert "sort" in inner and "gather" not in inner, inner
+            assert not multikey
 
 
 # ------------------------------------------- the engines' one dedup merge
@@ -764,3 +770,137 @@ def test_engine_programs_build_seen2_by_block_in_a_traced_loop(
     fresh = [loops for loops, operand, result in found
              if operand == (n_keys, K) and result == (B, K)]
     assert window == fresh == [outer + 1], (window, fresh)
+
+
+# ---- the sort sized by the live prefix (ISSUE 42) ----
+#
+# N = 128 key slots and a floor of 8: the ladder 128, 64, 32, 16, 8.  A
+# level's valid keys sit in keys[0:n_prefix] — with invalid rows AMONG
+# them, the form the POR filter leaves (bfs.py, `keys_c = jnp.where(
+# keep_c ...)`) — and every row past the prefix is what the accumulator
+# starts as, SENTINEL in every lane.
+LN, LSC, RUNG_MIN = 128, 256, 8
+RUNGS = (128, 64, 32, 16, 8)
+PREFIXES = sorted({n for r in RUNGS for n in (r - 1, r, r + 1)
+                   if n <= LN} | {0, 1})
+
+
+def test_sort_rungs_are_static_in_the_key_shape(monkeypatch):
+    monkeypatch.setattr(bfs, "_SORT_RUNG_MIN", RUNG_MIN)
+    assert bfs._sort_rungs(LN) == RUNGS
+    assert bfs._sort_unit(LN) == 8
+    # not a power of two: halves rounded down, none under the floor
+    assert bfs._sort_rungs(100) == (100, 50, 25, 12)
+    assert bfs._sort_unit(100) == 1
+    # shorter than two floors: one rung, and _rank_merge has no switch
+    assert bfs._sort_rungs(15) == (15,)
+    assert bfs._sort_unit(15) == 15
+    for n in (LN, 100):
+        rungs = bfs._sort_rungs(n)
+        for p in range(n + 9):
+            i = bfs._sort_rung_index(p, n)
+            assert int(bfs._sort_rung_index(jnp.int32(p), n)) == i
+            # the smallest rung that holds the prefix; all n past it
+            assert rungs[i] >= min(p, n)
+            assert i == len(rungs) - 1 or rungs[i + 1] < p
+    monkeypatch.undo()
+    # the benchmark cells' accumulators: 3, 7 and 9 rungs down to 2^15
+    assert [len(bfs._sort_rungs(1 << s)) for s in (17, 21, 23)] \
+        == [3, 7, 9]
+    assert bfs._sort_rungs(1 << 17)[-1] == bfs._sort_unit(1 << 23) \
+        == 1 << 15
+
+
+def _prefix_case(n_prefix, K, rng):
+    """(seen table, seen count, keys): duplicates, keys already seen
+    and invalid rows inside keys[0:n_prefix], nothing valid past it."""
+    words = rng.integers(-3, 4, size=(LN, K - 1)).astype(np.int32)
+    pool = _lexsorted(rng.integers(-3, 4, size=(LSC, K - 1))
+                      .astype(np.int32))
+    n_seen = min(LSC // 4, len(pool))
+    swords = pool[np.sort(rng.choice(len(pool), n_seen, replace=False))]
+    valid = (rng.random(LN) < 0.8) & (np.arange(LN) < n_prefix)
+    keys = _keys(words, valid, K)
+    # masked rows inside the prefix carry lane 1; the rows no chunk
+    # wrote carry SENTINEL there too
+    keys[n_prefix:, 0] = SENTINEL
+    return _table(swords, LSC, K), n_seen, keys
+
+
+@pytest.mark.parametrize("n_prefix", PREFIXES)
+@pytest.mark.parametrize("K", [3, 5])
+def test_rank_merge_sorts_the_rung_that_holds_the_prefix(
+        K, n_prefix, monkeypatch):
+    """With n_prefix the merge answers what it answers without, and
+    sorts the one rung the rule names."""
+    monkeypatch.setattr(bfs, "_SORT_RUNG_MIN", RUNG_MIN)
+    monkeypatch.setattr(bfs, "_MERGE_BLOCK_ROWS", 64)
+    assert bfs._sort_rungs(LN) == RUNGS
+    laddered = jax.jit(lambda s, c, k, n: _rank_merge(
+        s, c, k, LN, LSC, K, n_prefix=n))
+    plain = jax.jit(lambda s, c, k: _rank_merge(s, c, k, LN, LSC, K))
+    for trial in range(3):
+        rng = np.random.default_rng([K, n_prefix, trial, 42])
+        seen, n_seen, keys = _prefix_case(n_prefix, K, rng)
+        # a promise kept with room to spare changes the rung alone
+        for promised in {n_prefix, min(n_prefix + 5, LN), LN + 3}:
+            got = laddered(jnp.asarray(seen), jnp.int32(n_seen),
+                           jnp.asarray(keys), jnp.int32(promised))
+            want = plain(jnp.asarray(seen), jnp.int32(n_seen),
+                         jnp.asarray(keys))
+            for name in ANSWER + ("probe_blocks", "merge_blocks"):
+                assert np.array_equal(np.asarray(got[name]),
+                                      np.asarray(want[name])), \
+                    (name, trial, promised)
+            assert int(want["sort_slots"]) == LN
+            assert int(got["sort_slots"]) == RUNGS[
+                bfs._sort_rung_index(promised, LN)]
+        ref = _np_reference(seen, n_seen, keys, LSC, K)
+        for name in ANSWER:
+            assert np.array_equal(np.asarray(got[name]), ref[name]), name
+
+
+def _conds(jaxpr, scope=""):
+    """The name stack of every `cond` (lax.switch, lax.cond) under a
+    jaxpr."""
+    out = []
+    for eqn in jaxpr.eqns:
+        stack = scope + "/" + str(eqn.source_info.name_stack)
+        if eqn.primitive.name == "cond":
+            out.append(stack)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _conds(sub, stack)
+    return out
+
+
+@pytest.mark.parametrize("program,ladders", [
+    (_level_step, 0), (_resident_run, 1), (_mesh_superstep, 0)],
+    ids=["level_step", "resident_run", "mesh_superstep"])
+def test_the_sort_ladder_is_in_the_resident_program_alone(
+        program, ladders, monkeypatch):
+    """The resident level's candidates are a prefix of its accumulator
+    and its merge switches between the rungs, once, under
+    jaxmc.merge.sort; the level and the mesh engines' key slots are no
+    prefix, they pass no n_prefix and their programs branch nowhere."""
+    monkeypatch.setattr(bfs, "_SORT_RUNG_MIN", 32)
+    ex, fn, args, n_keys = program(1 << 14, 64)
+    found = _conds(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert len(found) == ladders, found
+    for stack in found:
+        assert "jaxmc.merge.sort" in stack, stack
+    if ladders:
+        rungs = bfs._sort_rungs(n_keys)
+        assert len(rungs) >= 3
+        # ONE sort a rung: the LSD passes are a loop over one sort of
+        # the K key columns and the row index (XLA:TPU's compile
+        # seconds follow the sort instructions of a program)
+        text = fn.lower(*args).as_text()
+        chains = sorted(
+            (int(types[0].split("x")[0]) for types in (
+                re.findall(r"tensor<([^>]*)>", m)
+                for m in _SORT.findall(text))
+             if len(types) == ex.K + 1), reverse=True)
+        assert tuple(chains) == rungs, (rungs, chains)

@@ -407,8 +407,12 @@ def test_capacity_counters_by_hand(engine):
         assert c["search.slots_sorted"] == 6 * 2 * 256
         assert c["search.seen_slots"] == 6 * 1024
     else:
-        # the caps handed in: each level sorts AccCap slots and rewrites
-        # SC seen rows, whatever the level holds
+        # the caps handed in: each level rewrites SC seen rows, whatever
+        # it holds, and sorts the smallest rung of AccCap's ladder that
+        # holds its 2 to 12 candidates — the one rung there is under two
+        # floors (test_slots_sorted_by_hand cuts it)
+        from jaxmc.backend.bfs import _sort_rungs
+        assert _sort_rungs(1 << 13) == (1 << 13,)
         assert c["search.slots_sorted"] == 6 * (1 << 13)
         assert c["search.seen_slots"] == 6 * (1 << 12)
 
@@ -481,7 +485,35 @@ def test_slots_probed_by_hand(engine, monkeypatch):
         # are 1 + 1 + 2 + 2 + 3 + 3 blocks
         assert bfs._probe_block_rows(128) == 4
         assert c["search.slots_probed"] == 12 * 4
-        assert c["search.slots_sorted"] == 6 * 128
+        assert c["search.slots_sorted"] == 6 * bfs._sort_rungs(128)[-1] \
+            == 6 * 128
+
+
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+def test_slots_sorted_by_hand(engine, monkeypatch):
+    """constoy a third time: level k = 0..5 hands the merge 2 (k + 1)
+    valid keys.  The level engine sorts its whole candidate block a
+    level; the resident engine's candidates are a prefix of its
+    accumulator, and `search.slots_sorted` is the sum over the levels of
+    the smallest rung of AccCap, AccCap / 2, ... down to the floor that
+    holds them (ISSUE 42)."""
+    pytest.importorskip("jax")
+    from jaxmc.backend import bfs
+    monkeypatch.setattr(bfs, "_SORT_RUNG_MIN", 4)
+    tel = obs.Telemetry()
+    caps = {"SC": 256, "FCap": 64, "AccCap": 128, "VC": 64}
+    res = _checked("constoy", "constoy", engine, tel,
+                   **({"res_caps": caps, "chunk": 64}
+                      if engine == "resident" else {}))
+    assert (res.generated, res.distinct) == (43, 21)
+    c = tel.counters
+    if engine == "level":
+        assert c["search.slots_sorted"] == 6 * 512
+    else:
+        # 2, 4, 6, 8, 10, 12 keys on the rungs 128, 64, 32, 16, 8, 4
+        assert bfs._sort_rungs(128) == (128, 64, 32, 16, 8, 4)
+        assert c["search.slots_sorted"] == 4 + 4 + 8 + 8 + 16 + 16
+    assert c["search.rows_valid"] == 42
 
 
 @pytest.mark.parametrize("engine", sorted(ENGINES))
