@@ -8,7 +8,7 @@ Three consumers, one parse-time pass:
       `analyze.proven_lanes`), replacing sampled+guarded widths where
       the proof converges.  JAXMC_ANALYZE_BOUNDS=0 disables.
   demotion prediction (analyze/verdicts.py)  the kernel2 CompileError
-      classification as a syntactic scan; tpu/bfs.py skips building arms with
+      classification as a syntactic scan; backend/bfs.py skips building arms with
       a verdict (gauge `analyze.arm_verdicts`, counter
       `analyze.predicted_demotions`), with the exact build-time reason
       wording.  JAXMC_ANALYZE_PREDICT=0 disables.

@@ -10,7 +10,7 @@ r"""`python -m jaxmc.analyze` — the static-analysis CLI (ISSUE 9).
         lint-only fixtures (Case.lint_expect) must produce exactly
         their expected diagnostic classes.  Reference-rooted pairs emit
         a parseable SKIP line when /root/reference is not mounted.
-        Exit 1 on any violation — `make bench-check` gates on it.
+        Exit 1 on any violation.
 
     python -m jaxmc.analyze pylint [PATH]...
         the builtin Python checker (analyze/pylint.py) over jaxmc's own

@@ -4,7 +4,7 @@ r"""Per-arm compilability prediction (ISSUE 9 tentpole, consumer 2).
 trace time — after grounding and (for recursive operators) after an
 exponentially expensive unroll attempt.  This module recasts the
 CompileError classification as a syntactic/type scan over the arm's AST so
-`tpu/bfs.py` can skip the doomed build outright, generalizing the corpus
+`backend/bfs.py` can skip the doomed build outright, generalizing the corpus
 manifest's measured `pin_interp_arms` pins to derived ones.
 
 Prediction policy — a verdict is issued ONLY when the build is certain

@@ -1,6 +1,6 @@
 r"""Device-agnostic backend layer (ISSUE 11).
 
-`jaxmc/tpu/` grew three engines (bfs/mesh/multihost) that were TPU-named
+`jaxmc/tpu/` (gone since ISSUE 43) grew three engines (bfs/mesh/multihost) that were TPU-named
 but already ran anywhere XLA does; no round since r01 has produced a
 real device number because the engine layer was welded to that name and
 to whatever platform jax initialized first.  This package makes
@@ -23,8 +23,7 @@ to whatever platform jax initialized first.  This package makes
                       stamps the verdict + per-candidate probe walls
                       into telemetry (`backend.oracle_choice`).
 
-The engines live in jaxmc/backend/{bfs,mesh,multihost}.py;
-jaxmc/tpu/ remains as thin import shims for compatibility.  This
+The engines live in jaxmc/backend/{bfs,mesh,multihost}.py.  This
 module itself never imports jax at import time — `python -m jaxmc.obs`
 must keep working in an interp-only environment.
 """

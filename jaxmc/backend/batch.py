@@ -63,7 +63,7 @@ from .bfs import SENTINEL, TpuExplorer, _pow2_at_least
 
 class BatchIncompatible(Exception):
     """The cohort cannot share one program; the message names why.  The
-    caller (serve daemon, batchbench) falls back to solo runs."""
+    caller (the serve daemon) falls back to solo runs."""
 
 
 @dataclass

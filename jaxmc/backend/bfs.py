@@ -927,7 +927,7 @@ class TpuExplorer:
         # model's arms ALL demote to the interpreter — skip grounding +
         # kernel construction + forced tracing entirely instead of
         # paying minutes of futile XLA work (MCInnerSerial burned 213s
-        # building 13 kernels it then demoted, SWEEP_JAX_r05).
+        # building 13 kernels it then demoted, in the r05 jax sweep).
         self.pin_interp_arms = pin_interp_arms
         self._res_caps_hint = dict(res_caps) if res_caps else None
         self.cap_profile = cap_profile
@@ -1828,7 +1828,7 @@ class TpuExplorer:
         block of capacity FC and produce the flat candidate block with
         its dedup keys, packed rows and fault scalars.  Both mesh step
         builders (the legacy exchange step and the device-resident
-        level step, tpu/mesh.py) start from exactly this closure so the
+        level step, backend/mesh.py) start from exactly this closure so the
         candidate semantics — validity masking, pack-guard overflow
         folding (OV_PACK under kernel codes), assert/deadlock
         provenance — cannot drift between them.

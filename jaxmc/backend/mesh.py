@@ -73,8 +73,9 @@ Parity features (VERDICT r2 #5, preserved by the resident loop):
 
 The driver validates this path with N virtual CPU devices via
 __graft_entry__.dryrun_multichip (no multi-chip hardware needed) on the
-raft workload; `make multichip-check` / `make multichip-bench`
-(jaxmc/meshbench.py) run the parity and scaling legs.
+raft workload; tests/test_mesh_session.py and tests/test_mesh_resident.py
+hold the parity legs on virtual CPU devices, and the benchmark cell
+`mesh-recheck-4p` measures the engine on four chips.
 """
 
 from __future__ import annotations
@@ -802,7 +803,7 @@ class MeshExplorer(TpuExplorer):
 
         O(new) and O(valid): the exchanged block is ~95% masked padding
         (its 5-key sort over all R rows was 11.6s of a 25s step wall on
-        transfer_scaled D=1 — XLA:CPU, MULTICHIP_r07), so the valid rows are
+        transfer_scaled D=1 — XLA:CPU, round r07), so the valid rows are
         first compacted to a [VC]-bounded block — stably, so candidate
         order and therefore counts/traces are unchanged — then only
         those keys are sorted, deduped against
@@ -2032,7 +2033,7 @@ class MeshExplorer(TpuExplorer):
         # exactly the one-level program run) and adapts to measured
         # dispatch wall so progress, checkpoint and drain attention
         # keep their cadence, like the single-chip resident maxlvl
-        # controller (tpu/bfs.py)
+        # controller (backend/bfs.py)
         maxlvl = self._ss_fixed or min(self._mesh_maxlvl_warm,
                                        _SS_RINGCAP)
         target_s = max(1.0, min(

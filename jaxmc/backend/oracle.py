@@ -32,7 +32,7 @@ Telemetry (obs satellite):
 CLI: `python -m jaxmc.backend.oracle [--smoke] [--deadline S]` prints
 one parseable `ORACLE <platform> ...` line per candidate plus the
 verdict; --smoke exits non-zero when the oracle blows its deadline or
-finds no live platform (the `make backend-check` gate).
+finds no live platform.
 """
 
 from __future__ import annotations
@@ -234,7 +234,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         os.environ.get("JAXMC_ORACLE_DEADLINE", "10")))
     ap.add_argument("--smoke", action="store_true",
                     help="exit 1 unless a live platform was chosen "
-                         "inside the deadline (make backend-check)")
+                         "inside the deadline")
     args = ap.parse_args(argv)
     v = preflight(deadline_s=args.deadline, use_cache=False)
     for plat, pr in v["probes"].items():

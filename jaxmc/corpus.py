@@ -45,7 +45,7 @@ class Case:
     # subset (recursion/CHOOSE-heavy — the interp remains its checker)
     jax: str = "skip"
     # the pinned EXPANSION MODE (ISSUE 5): "compiled" | "hybrid" |
-    # "interp-arms" as observed in SWEEP_JAX_r05. A case that SLIDES
+    # "interp-arms" as observed in the r05 jax sweep. A case that SLIDES
     # toward the interpreter (compiled -> hybrid/interp-arms, hybrid ->
     # interp-arms) FAILS the sweep — a silent demotion is a perf
     # regression, not a pass. Cases pinned "interp-arms" skip kernel
@@ -70,21 +70,12 @@ class Case:
     grow_cap: Optional[int] = None
     kv_cap: Optional[int] = None
     # steady-state RESIDENT capacity buckets for this case (ISSUE 6):
-    # the manifest-recorded floor for {SC, FCap, AccCap, VC} so a bench
-    # or kernelbench run compiles ONCE and never grows mid-window.  The
+    # the manifest-recorded floor for {SC, FCap, AccCap, VC} so a run
+    # seeded from it (chip_smoke.py, tests/test_kernel2.py) compiles
+    # ONCE and never grows mid-window.  The
     # persisted capacity profile (compile/cache.py) max-merges over
     # this; the manifest value is the committed, review-able record.
     res_caps: Optional[dict] = None
-    # MESH capacity record (ISSUE 8): {SC, FC, TRL, GAM16} per-SHARD
-    # buckets for the mesh-resident engine at the bench device counts
-    # (measured at D=4 in this container; max-merged with the per-
-    # (D, exchange) learned profile, so other D start close and learn
-    # the rest).  jaxmc.meshbench passes it to MeshExplorer(mesh_caps=).
-    # PR 10 adds the optional MSL key — the superstep controller's
-    # learned levels-per-dispatch — so a cold engine skips the
-    # 1 -> 2 -> 4 dispatch ramp and `mesh.host_syncs` drops below the
-    # level count from the first run.
-    mesh_caps: Optional[dict] = None
     # LINT surface (ISSUE 9, `make lint-corpus`): diagnostic codes this
     # pair is WAIVED for (intentional fixture constructs — each waiver
     # carries a comment at the case naming why), and, for lint-only
@@ -206,12 +197,10 @@ CASES: List[Case] = [
          cfg="specs/transfer_scaled.cfg",
          distinct=153701, generated=311153, slow=True, jax="yes",
          mode="compiled",
-         # kernelbench rung (ISSUE 6): steady resident buckets so the
-         # warm-up compile covers the whole run
+         # steady resident buckets (ISSUE 6) so the warm-up compile
+         # covers the whole run
          res_caps={"SC": 1 << 18, "FCap": 1 << 16, "AccCap": 1 << 17,
-                   "VC": 1 << 13, "chunk": 2048},
-         mesh_caps={"SC": 1 << 17, "FC": 1 << 13, "TRL": 32,
-                    "GAM16": 32, "MSL": 32}),
+                   "VC": 1 << 13, "chunk": 2048}),
     # the chip's REAL rung (ISSUE 21, chip_smoke.py legs B/C/E): four
     # processes.  Counts confirmed by the exact interpreter (--workers
     # 8, 686 s here): 13 BFS levels, largest frontier 1,883,904.
@@ -224,9 +213,7 @@ CASES: List[Case] = [
          distinct=9394019, generated=24035597, slow=True, jax="yes",
          mode="compiled",
          res_caps={"SC": 1 << 24, "FCap": 1 << 22, "AccCap": 1 << 23,
-                   "VC": 1 << 14, "chunk": 2048},
-         mesh_caps={"SC": 1 << 22, "FC": 1 << 19, "TRL": 32,
-                    "GAM16": 32, "MSL": 32}),
+                   "VC": 1 << 14, "chunk": 2048}),
     # the FLOOR rung (ISSUE 21): what chip_smoke.py's leg B runs —
     # the real rung's cold resident run alone costs more XLA compile
     # time than the smoke's 1200 s contract leaves (PERF.md).  Counts
@@ -240,9 +227,7 @@ CASES: List[Case] = [
          cfg="specs/MCraft_micro.cfg", includes=("examples",),
          distinct=694, generated=6185, jax="yes", mode="compiled",
          res_caps={"SC": 1 << 12, "FCap": 1 << 9, "AccCap": 1 << 12,
-                   "VC": 1 << 11, "chunk": 256},
-         mesh_caps={"SC": 1 << 12, "FC": 1 << 9, "TRL": 32,
-                    "GAM16": 32, "MSL": 32}),
+                   "VC": 1 << 11, "chunk": 256}),
     # mode=compiled proven by the BENCH_r02 resident-mode completion
     # (resident refuses hybrid/interp-arms outright)
     Case("specs/MCraftMicro.tla", root="repo",
@@ -252,10 +237,7 @@ CASES: List[Case] = [
          # the full rung's steady caps (one warm-up compile
          # covers the run; the persisted profile max-merges over this)
          res_caps={"SC": 1 << 18, "FCap": 1 << 16, "AccCap": 1 << 17,
-                   "VC": 1 << 13},
-         # meshbench rung (ISSUE 8): per-shard mesh-resident buckets
-         mesh_caps={"SC": 1 << 17, "FC": 1 << 14, "TRL": 64,
-                    "GAM16": 32, "MSL": 64}),
+                   "VC": 1 << 13}),
     Case("specs/MCtextbookSI.tla", root="repo",
          cfg="specs/MCtextbookSI_small.cfg", includes=("examples",),
          distinct=569, generated=945, jax="yes", mode="interp-arms"),
@@ -278,8 +260,8 @@ CASES: List[Case] = [
          slow=True),
     # VIEW/CONSTRAINT parity fixtures (PR 3), now first-class manifest
     # cases: cfg VIEW compiles on the jax backend since ISSUE 6 (dedup
-    # keys on the compiled view's value lanes), and both serve as
-    # kernelbench rungs with committed res_caps records
+    # keys on the compiled view's value lanes), both with committed
+    # res_caps records
     Case("specs/viewtoy.tla", root="repo", cfg="specs/viewtoy.cfg",
          distinct=5, generated=11, jax="yes", mode="compiled",
          res_caps={"SC": 256, "FCap": 64, "AccCap": 128, "VC": 64,
@@ -293,8 +275,8 @@ CASES: List[Case] = [
                    "chunk": 64}),
     # cross-model batching fixture family (ISSUE 13): one module, four
     # cfgs differing ONLY in liftable constant values — layout-
-    # compatible by construction, so the serve fleet and `make
-    # batch-check` can prove the vmapped multi-model engine in
+    # compatible by construction, so the serve fleet and
+    # tests/test_batch.py can prove the vmapped multi-model engine in
     # containers without /root/reference.  batchtoy_bad's Bound sits
     # below the reachable x maximum: the mixed-batch scenario (one
     # member violates, the rest run to exhaustion).
@@ -313,24 +295,20 @@ CASES: List[Case] = [
     Case("specs/batchtoy.tla", root="repo",
          cfg="specs/batchtoy_bad.cfg",
          expect="violation:invariant", jax="yes", mode="compiled"),
-    # bench-scale kernelbench rungs (ISSUE 6): wide-shallow variants of
-    # the VIEW/SYMMETRY fixtures sized so states/sec measures
-    # throughput; `make bench-check`'s kernel-vs-interp leg gates the
-    # cpu-XLA kernel against the serial interpreter on each
+    # bench-scale rungs (ISSUE 6): wide-shallow variants of the
+    # VIEW/SYMMETRY fixtures; tests/test_kernel2.py holds the packed
+    # resident kernel to these pins, tests/test_mesh_session.py the
+    # sharded engine at D=2 and D=4
     Case("specs/viewtoy_scaled.tla", root="repo",
          cfg="specs/viewtoy_scaled.cfg",
          distinct=18432, generated=239617, jax="yes", mode="compiled",
          res_caps={"SC": 1 << 15, "FCap": 1 << 12, "AccCap": 1 << 15,
-                   "VC": 1 << 13, "chunk": 1024},
-         # measured mesh-resident shard caps at D=4 in this container
-         # (SC grew 256 -> 65536 over 9 redo recompiles without it)
-         mesh_caps={"SC": 1 << 16, "FC": 1 << 11, "TRL": 32,
-                    "GAM16": 32, "MSL": 32}),
+                   "VC": 1 << 13, "chunk": 1024}),
     # out-of-core overflow fixture (ISSUE 12): a wide-state rung whose
-    # exact dedup keys cost >7x a fingerprint; `make ooc-check` forces
-    # a device seen cap at ~17% of its state count and pins the capped
-    # (tier-spilling) and fingerprint-mode runs bit-identical to this
-    # uncapped record.  NoMeet (the ooc_scaled_bad.cfg violation rung)
+    # exact dedup keys cost >7x a fingerprint; tests/test_tiers.py
+    # forces a device seen cap at ~17% of its state count and pins the
+    # capped (tier-spilling) and fingerprint-mode runs bit-identical to
+    # this uncapped record.  NoMeet (the ooc_scaled_bad.cfg violation rung)
     # is deliberately unused here — JMC301 waived.
     Case("specs/ooc_scaled.tla", root="repo",
          cfg="specs/ooc_scaled.cfg",
@@ -342,9 +320,7 @@ CASES: List[Case] = [
          cfg="specs/symtoy_scaled.cfg", no_deadlock=True,
          distinct=10725, generated=65365, jax="yes", mode="compiled",
          res_caps={"SC": 1 << 15, "FCap": 1 << 12, "AccCap": 1 << 14,
-                   "VC": 1 << 13, "chunk": 1024},
-         mesh_caps={"SC": 1 << 15, "FC": 1 << 11, "TRL": 32,
-                    "GAM16": 32, "MSL": 32}),
+                   "VC": 1 << 13, "chunk": 1024}),
     # device SYMMETRY toys (orbit-canonical counts; deadlock expected
     # when every process exhausts its turns)
     Case("specs/symtoy.tla", root="repo", cfg="specs/symtoy.cfg",
@@ -367,8 +343,8 @@ CASES: List[Case] = [
     # POR fixture family (ISSUE 15): independent per-element counters,
     # so the Step arms pairwise commute (analyze/independence.py) and
     # the --por persistent-set filter gets its measured reduction.
-    # Unreduced counts pinned here; `make por-check` runs the reduced
-    # legs and gates verdict parity + >=30% explored-state reduction.
+    # Unreduced counts pinned here; tests/test_independence.py runs the
+    # reduced legs: verdict parity + >=30% explored-state reduction.
     # JMC301 waived on all three: Bounded/NoFire are deliberate spare
     # predicates — each cfg checks the subset its rung needs
     Case("specs/portoy.tla", root="repo", cfg="specs/portoy.cfg",
@@ -385,8 +361,8 @@ CASES: List[Case] = [
     # raft-shaped dynamic-key fixture (ISSUE 18): per-process message
     # table msgs[self] (element-commuting Send arms), a DYNAMIC \E arm
     # whose binder key resolves to a domain key set, and a CONSTANT-
-    # keyed element read.  Unreduced counts pinned here; the por-check
-    # device legs gate >=30% reduction with por.engine=device
+    # keyed element read.  Unreduced counts pinned here; the device POR
+    # test holds >=30% reduction with por.engine=device
     Case("specs/msgstoy.tla", root="repo", cfg="specs/msgstoy.cfg",
          no_deadlock=True, distinct=324, generated=1108,
          jax="yes", mode="compiled"),
@@ -421,7 +397,7 @@ def mode_pins_enabled() -> bool:
 
 
 def case_for_cfg(cfg_basename: str) -> Optional[Case]:
-    """Manifest lookup by cfg basename (the harnesses and chip_smoke.py
+    """Manifest lookup by cfg basename (the tests and chip_smoke.py
     assert their counts against the pinned totals)."""
     for c in CASES:
         p = c.cfg_path()

@@ -46,7 +46,7 @@ Sites wired in this PR:
                       (mode=flip) file behind
     device_init_fail  device init raises (session.py retries)
     compile_fail      a per-arm kernel compile raises transiently
-                      (tpu/bfs.py retries)
+                      (backend/bfs.py retries)
     device_run_fail   the device search loop raises entering a level
                       (cli.py demotes to the parallel CPU engine)
     tier_io_error     a hierarchical-seen-set disk write fails
@@ -67,7 +67,7 @@ tests/test_cache_guard.py):
                       <dir>/.quarantine and the cache stays enabled
 
 Fleet-serving sites (ISSUE 19, serve/{queue,daemon}.py — the chaos
-surface for `make fleet-check` and tests/test_chaos.py):
+surface of tests/test_chaos.py):
 
     daemon_kill       the serve daemon SIGKILLs itself mid-run, right
                       after marking jobs running (ctx: job=<id>,
@@ -87,7 +87,7 @@ surface for `make fleet-check` and tests/test_chaos.py):
                       named `serve.spool_degraded` event (HTTP 503,
                       never a raw 500)
 
-Mesh sites (ISSUE 8, tpu/mesh.py — evaluated at ENGINE BUILD time, not
+Mesh sites (ISSUE 8, backend/mesh.py — evaluated at ENGINE BUILD time, not
 per dispatch, because the routing is compiled into the jitted step):
 
     mesh_skew         the owner-routing hash collapses to shard 0 on

@@ -1,14 +1,13 @@
 r"""Persistent run ledger (ISSUE 17): the perf trajectory as a
 first-class, queryable, self-gating artifact.
 
-Before this, the states/sec trajectory lived in loose `BENCH_r*.json` /
-`MULTICHIP_r*.json` files compared pairwise by hand-picked `obs diff`
-invocations — a regression between gate runs was invisible unless
-someone happened to diff the right pair.  The ledger is the cross-run
-memory:
+Before this, the states/sec trajectory lived in loose `BENCH_r*.json`
+files compared pairwise by hand-picked `obs diff` invocations — a
+regression between runs was invisible unless someone happened to diff
+the right pair.  The ledger is the cross-run memory:
 
-  append    every bench child, `make *-check` gate leg and serve job
-            appends one compact line (rung, states/sec, platform, env
+  append    every run that writes a metrics artifact and every serve
+            job appends one compact line (rung, states/sec, platform, env
             fingerprint, source, job signature) to an append-only JSONL
             (default ~/.cache/jaxmc/ledger.jsonl; JAXMC_LEDGER overrides
             the path, JAXMC_LEDGER=off disables).  Appends are
@@ -23,9 +22,8 @@ memory:
             best-of-`--window`), with env-change attribution reused
             from `obs diff` (report._env_changes) so a drop caused by a
             jax upgrade or a device-count change reads as such.
-  --import  backfills artifacts (driver BENCH_r* records,
-            MULTICHIP_r02..r08, any --metrics-out JSON) through
-            report.load_record so the trajectory starts at r01.
+  --import  backfills artifacts (driver BENCH_r* records, any
+            --metrics-out JSON) through report.load_record.
 
 Pure stdlib (no jax): the CLI must work in interp-only environments.
 Writers call `append_summary` which NEVER raises — a full disk or a
@@ -213,21 +211,11 @@ def _parse_ts(v) -> Optional[float]:
 
 
 def entries_from_artifact(path: str) -> List[Dict[str, Any]]:
-    """Ledger entries for one committed artifact via report.load_record
-    — one per run for metrics/bench shapes, one per (rung, devices)
-    curve point for multichip scaling artifacts."""
+    """Ledger entries for one artifact via report.load_record — one
+    per run, metrics and bench shapes alike."""
     rec = report.load_record(path)
     mtime = os.path.getmtime(path)
     env = report._effective_env(rec)
-    if rec["kind"] == "multichip":
-        ts = _parse_ts(rec["summary"].get("generated_at")) or mtime
-        out = []
-        for key, pt in rec["curve"].items():
-            out.append(make_entry(
-                key, pt.get("states_per_sec_per_chip"), ts,
-                run=rec["label"], kind="multichip",
-                platform=rec["platform"], env=env, source=path))
-        return out
     if rec["kind"] == "bench":
         return [make_entry(
             "bench", rec["states_per_sec"], mtime,

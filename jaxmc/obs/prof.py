@@ -1,8 +1,8 @@
 r"""Device profiler (ISSUE 17): per-dispatch attribution.
 
 PR 11 left one perf target unmet — merge wall <30% of step wall — partly
-because nothing below the PHASE level said where device time went:
-`phase_walls` names "the fused step is slow", not which dispatch site,
+because nothing below the PHASE level said where device time went: a
+phase wall names "the fused step is slow", not which dispatch site,
 buffer traffic, or recompile paid for it.  This module is the missing
 layer:
 
@@ -19,7 +19,7 @@ layer:
             ready and charges the wall to the site, and sums argument /
             result bytes per dispatch.  Synchronization cannot change
             counts or traces — profile-on vs profile-off stays
-            bit-identical (pinned by tests and `make prof-check`).
+            bit-identical (pinned by tests/test_prof.py).
   xla       cheap + the CLI wraps the run in a jax.profiler capture
             (no Python tracer) to a named artifact dir: the run a user
             has, with the program's spans (`jaxmc.<span>`, obs/
@@ -372,7 +372,7 @@ def wrap(name: str, fn, key=None):
 
 def attribution(summary: Dict[str, Any]) -> Dict[str, Any]:
     """How much of the measured search wall the named sites explain —
-    the `make prof-check` acceptance metric.  Pure dict math (no jax):
+    a share of two walls of one run.  Pure dict math (no jax):
     works on any jaxmc.metrics/4 artifact."""
     prof = summary.get("prof") or {}
     sites = prof.get("sites") or {}

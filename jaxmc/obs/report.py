@@ -86,8 +86,6 @@ def load_record(path: str) -> Dict[str, Any]:
     for ext in (".json", ".jsonl"):
         if label.endswith(ext):
             label = label[:-len(ext)]
-    if str(obj.get("schema", "")).startswith("jaxmc.multichip/"):
-        return _from_multichip(obj, path, label)
     if "schema" in obj and "phases" in obj:
         return _from_metrics(obj, path, label)
     if "parsed" in obj and isinstance(obj["parsed"], dict):
@@ -143,30 +141,6 @@ def _from_metrics(s: Dict[str, Any], path: str, label: str
         "env": env,
         "result": res,
         "summary": s,
-    }
-
-
-def _from_multichip(s: Dict[str, Any], path: str, label: str
-                    ) -> Dict[str, Any]:
-    """A MULTICHIP_r*.json scaling artifact (jaxmc.multichip/1,
-    jaxmc/meshbench.py): one record whose `curve` maps each
-    (rung, devices) point to its per-chip rate, so `obs diff` can gate
-    r07-vs-r06 states/sec/chip per rung (ISSUE 10 CI satellite)."""
-    curve: Dict[str, Dict[str, Any]] = {}
-    for rung in s.get("rungs", []):
-        for pt in rung.get("curve", []) or []:
-            if "error" in pt:
-                continue
-            curve[f"{rung['rung']}@D{pt['devices']}"] = pt
-    return {
-        "path": path, "label": label, "kind": "multichip",
-        "states_per_sec": None,
-        "backend": "mesh", "platform": s.get("platform", "cpu"),
-        "rank": _RANK.get(s.get("platform", "cpu"), 1),
-        "mode": s.get("mode"), "wall_s": None,
-        "phases": {}, "env": s.get("env") or {},
-        "result": {"ok": s.get("ok")},
-        "curve": curve, "summary": s,
     }
 
 
@@ -263,40 +237,6 @@ def _searches_table(requests: List[Dict[str, Any]], out) -> None:
 def cmd_report(args, out=sys.stdout) -> int:
     rec = load_record(args.file)
     print(f"== {rec['label']} ({rec['kind']}: {args.file})", file=out)
-    if rec["kind"] == "multichip":
-        print(f"  platform={rec['platform']}  mode={rec['mode']}  "
-              f"ok={rec['result'].get('ok')}", file=out)
-        for key, pt in rec["curve"].items():
-            bits = [f"{pt.get('states_per_sec_per_chip', 0):,.0f} "
-                    f"st/s/chip",
-                    f"syncs={pt.get('host_syncs')}/"
-                    f"{pt.get('levels')} lvls"]
-            # `merge` / `phase_walls`: only the committed
-            # MULTICHIP_r07/r08.json carry them (schema.py)
-            if pt.get("merge"):
-                bits.append(f"merge={pt['merge']}")
-            pw = pt.get("phase_walls")
-            if isinstance(pw, dict):
-                # tolerate missing-phase rows: a probe that hit its cap
-                # early reports what it measured
-                bits.append(
-                    f"walls expand={pw.get('expand_s', '-')}s "
-                    f"exchange={pw.get('exchange_s', '-')}s "
-                    f"merge(rank)={pw.get('merge_rank_s', '-')}s "
-                    f"merge(fullsort)="
-                    f"{pw.get('merge_fullsort_s', '-')}s")
-                # ISSUE 11 acceptance metric: (expand+merge)/step — the
-                # fused one-level step timed by the same probe
-                if isinstance(pw.get("hot_share"), (int, float)):
-                    bits.append(
-                        f"hot_share={pw['hot_share']:.0%} of "
-                        f"step={pw.get('step_s', '-')}s")
-            elif pw is not None:
-                # a malformed row is a fact about the artifact, not a
-                # rendering crash
-                bits.append(f"walls=(malformed: {type(pw).__name__})")
-            print(f"  {key:<28} " + "  ".join(bits), file=out)
-        return 0
     env = rec["env"]
     bits = [f"backend={rec['backend']}", f"platform={rec['platform']}"]
     if rec["mode"]:
@@ -497,8 +437,7 @@ def _cohort_lines(s: Dict[str, Any], out) -> None:
 def _effective_env(rec: Dict[str, Any]) -> Dict[str, Any]:
     """The record's env dict with platform/device_count backfilled from
     the record itself (ISSUE 11 satellite): metrics artifacts written
-    by interp runs (and multichip artifacts, which carry the platform
-    top-level) leave env.platform None, so a backend swap between two
+    by interp runs leave env.platform None, so a backend swap between two
     artifacts used to surface as an unexplained REGRESS instead of an
     attributed environment change."""
     env = dict(rec.get("env") or {})
@@ -530,8 +469,8 @@ def find_regressions(prev: Dict[str, Any], cur: Dict[str, Any],
     jax upgrade (or a lost device) reads as such.  `ignore_phases`
     names phases excluded from the per-phase wall gate (cold-start
     one-shot walls like compile_arm are load-sensitive in a way the
-    measured search window is not — the backend-check gate skips
-    them); the states/sec and demotion gates always apply."""
+    measured search window is not); the states/sec and demotion
+    gates always apply."""
     flags = []
     step = f"{prev['label']} -> {cur['label']}"
     d = _pct(cur["states_per_sec"], prev["states_per_sec"])
@@ -594,85 +533,14 @@ def find_regressions(prev: Dict[str, Any], cur: Dict[str, Any],
     return flags
 
 
-def _diff_multichip(recs: List[Dict[str, Any]], threshold: float,
-                    fail_on_regress: bool, out) -> int:
-    """Scaling-artifact trajectory (ISSUE 10 CI satellite): per
-    (rung, D) states/sec/chip across MULTICHIP_r* artifacts, a REGRESS
-    flag when a later artifact's per-chip rate drops past the
-    threshold on any shared point."""
-    keys: List[str] = []
-    for r in recs:
-        for k in r["curve"]:
-            if k not in keys:
-                keys.append(k)
-    lw = max([5] + [len(r["label"]) for r in recs])
-    kw = max([10] + [len(k) for k in keys])
-    print(f"{'point':<{kw}}  "
-          + "  ".join(f"{r['label']:>{max(lw, 12)}}" for r in recs),
-          file=out)
-    for k in keys:
-        cells = []
-        for r in recs:
-            pt = r["curve"].get(k)
-            cells.append(_fmt_rate(pt.get("states_per_sec_per_chip")
-                                   if pt else None))
-        print(f"{k:<{kw}}  "
-              + "  ".join(f"{c:>{max(lw, 12)}}" for c in cells),
-              file=out)
-    flags: List[str] = []
-    for prev, cur in zip(recs, recs[1:]):
-        step = f"{prev['label']} -> {cur['label']}"
-        step_flagged = False
-        for k in keys:
-            a, b = prev["curve"].get(k), cur["curve"].get(k)
-            if not a or not b:
-                continue
-            d = _pct(b.get("states_per_sec_per_chip"),
-                     a.get("states_per_sec_per_chip"))
-            if d is not None and d < -threshold:
-                step_flagged = True
-                flags.append(
-                    f"REGRESS states/sec/chip {k} {step}: "
-                    f"{_fmt_rate(a['states_per_sec_per_chip'])} -> "
-                    f"{_fmt_rate(b['states_per_sec_per_chip'])} "
-                    f"({d:+.1f}%)")
-        if step_flagged:
-            # attribute a platform/device swap (ISSUE 11 satellite): a
-            # cpu-virtual-device baseline diffed against a real-chip
-            # artifact is an environment change, not a bare REGRESS
-            env = _env_changes(_effective_env(prev),
-                               _effective_env(cur))
-            if env:
-                flags.append(f"  note {step}: environment changed "
-                             f"({'; '.join(env)})")
-    print("", file=out)
-    if flags:
-        print("regressions:", file=out)
-        for f in flags:
-            print(f"  {f}", file=out)
-    else:
-        print(f"no regressions flagged (threshold {threshold:.0f}%).",
-              file=out)
-    return 1 if (flags and fail_on_regress) else 0
-
-
 def _record_ts(rec: Dict[str, Any]) -> float:
     """The record's recorded timestamp for trajectory ordering:
-    metrics artifacts carry started_at, multichip artifacts
-    generated_at (ISO string); bench rollups carry neither, so the
+    metrics artifacts carry started_at; bench rollups do not, so the
     file mtime stands in."""
     s = rec.get("summary") or {}
     ts = s.get("started_at")
     if isinstance(ts, (int, float)):
         return float(ts)
-    gen = s.get("generated_at")
-    if isinstance(gen, str):
-        import datetime
-        try:
-            return datetime.datetime.fromisoformat(
-                gen.replace("Z", "+00:00")).timestamp()
-        except ValueError:
-            pass
     try:
         return os.path.getmtime(rec["path"])
     except OSError:
@@ -719,9 +587,6 @@ def cmd_diff(args, out=sys.stdout) -> int:
         print("error: diff needs at least two artifacts",
               file=sys.stderr)
         return 2
-    if all(r["kind"] == "multichip" for r in recs):
-        return _diff_multichip(recs, args.threshold,
-                               args.fail_on_regress, out)
     # trajectory table: one row per run, the shared top phases as columns
     phase_tot: Dict[str, float] = {}
     for r in recs:
@@ -803,7 +668,7 @@ def main(argv: Optional[List[str]] = None, out=sys.stdout) -> int:
                         "(default 30s)")
     t.add_argument("--fail-on-orphans", action="store_true",
                    help="exit 1 when any lane's parent span resolves "
-                        "to no known process (trace-check gate)")
+                        "to no known process")
     tp = sub.add_parser(
         "top",
         help="per-dispatch-site profile table (wall, share, "
@@ -820,12 +685,11 @@ def main(argv: Optional[List[str]] = None, out=sys.stdout) -> int:
                    help="ledger JSONL (default: JAXMC_LEDGER or "
                         "~/.cache/jaxmc/ledger.jsonl)")
     h.add_argument("--rung", default=None,
-                   help="restrict to one rung (e.g. transfer_scaled, "
-                        "or a multichip point like philtoy@D8)")
+                   help="restrict to one rung (e.g. transfer_scaled)")
     h.add_argument("--import", dest="import_files", nargs="+",
                    default=None, metavar="ARTIFACT",
-                   help="backfill committed artifacts (BENCH_r*.json, "
-                        "MULTICHIP_r*.json, --metrics-out JSONs; "
+                   help="backfill artifacts (BENCH_r*.json, "
+                        "--metrics-out JSONs; "
                         "globs ok) into the ledger first — "
                         "content-addressed, so re-importing is "
                         "idempotent")
@@ -840,7 +704,7 @@ def main(argv: Optional[List[str]] = None, out=sys.stdout) -> int:
                         "best-of reference (default 5)")
     h.add_argument("--fail-on-regress", action="store_true",
                    help="exit 1 when the latest run of any rendered "
-                        "rung regressed (prof-check gate)")
+                        "rung regressed")
     args = ap.parse_args(argv)
     try:
         if args.cmd == "report":

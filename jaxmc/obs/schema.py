@@ -126,7 +126,7 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       runtime guard), and `dedup.mode` — "exact" | "fp128" with a
       "-packed" suffix when the key basis is the packed row or
       "-view" when cfg VIEW keys the dedup;
-    - buffer donation (tpu/bfs.py): gauge `device.donation` (bool —
+    - buffer donation (backend/bfs.py): gauge `device.donation` (bool —
       seen/frontier donated into the jitted steps; off on XLA:CPU by
       default, JAXMC_DONATE forces);
     - capacity profiles (compile/cache.py): gauge `profile.status` —
@@ -134,12 +134,7 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       "degraded:<named reason>" (stale layout signature, foreign
       schema, module mismatch, unreadable, malformed caps — a degraded
       profile falls back to the overflow-growth path, never a crash);
-      counters `profile.hits` / `profile.saves` / `profile.degrades`;
-    - kernelbench artifacts (jaxmc/kernelbench.py): ordinary
-      jaxmc.metrics/2 summaries whose `result.wall_s` is the
-      min-of-repeats steady wall (warm-up excluded), gauge
-      `kernelbench.note` carries the measurement methodology; the
-      kernel-vs-interp leg feeds them to `obs diff --fail-on-regress`.
+      counters `profile.hits` / `profile.saves` / `profile.degrades`.
 
   (PR 7, still jaxmc.metrics/2 — all additive/optional; the
    checking-as-a-service surface:)
@@ -170,13 +165,13 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       existing `load` / `device_init` / `engine_build` / `search` /
       `search_fallback` phases are now emitted by CheckSession — same
       names, same meaning, whether the CLI or the serve daemon drives.
-    - fused arm groups (tpu/bfs.py): gauge `expand.fused_groups` — the
+    - fused arm groups (backend/bfs.py): gauge `expand.fused_groups` — the
       number of fused expansion jits when a many-instance model splits
       per arm-group (JAXMC_FUSED_MAX_INSTANCES instances per group)
       instead of per action.
 
   (PR 8, still jaxmc.metrics/2 — all additive/optional; the mesh-
-   resident multi-chip surface, tpu/mesh.py + jaxmc/meshbench.py:)
+   resident multi-chip surface, backend/mesh.py:)
     - exchange strategy: gauges `mesh.exchange` ("a2a" | "gather"),
       `mesh.devices`; the strategy + gamma are also logged once per
       run.  Since PR 33 also `mesh.compact_form` ("runs" | "scatter":
@@ -205,13 +200,6 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       `spill`, `max_bucket`, and the existing `fresh_compile` flag
       (so `window_recompiles` computes for mesh runs exactly like
       serve jobs).
-    - multichip artifacts: MULTICHIP_r*.json (schema
-      jaxmc.multichip/1, jaxmc/meshbench.py) — per-rung scaling curves
-      [{devices, exchange, states_per_sec, states_per_sec_per_chip,
-      window_recompiles, host_syncs, levels, exchange_bytes_per_level,
-      shard_balance, a2a_*}]; per-leg jaxmc.metrics/2 artifacts carry
-      the same numbers in a top-level `multichip` block and gate via
-      `obs diff --fail-on-regress`.
 
   (PR 9, still jaxmc.metrics/2 — all additive/optional; the static-
    analysis surface, jaxmc/analyze/*:)
@@ -239,10 +227,10 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       gate.
 
   (PR 10, still jaxmc.metrics/2 — all additive/optional; the mesh
-   rank-merge + superstep surface, tpu/mesh.py + jaxmc/meshbench.py:)
+   rank-merge + superstep surface, backend/mesh.py:)
     - the mesh engine re-stamps `dedup.mode` at run start (the PR-6
       gauge was stamped before the mesh subclass forced fp128 keys,
-      so multichip artifacts carried a stale value).  The shard-local
+      so mesh artifacts carried a stale value).  The shard-local
       merge is bfs._rank_merge and nothing else (PR 28): no gauge
       names it.
     - supersteps: `mesh.host_syncs` now counts SUPERSTEPS — one
@@ -253,17 +241,6 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       `mesh.superstep_levels` (deepest fused dispatch).  Mesh level
       records gain `superstep` (which dispatch the level rode) and
       their `wall_s` is the dispatch wall amortized over its levels.
-    - multichip artifacts add per-point `supersteps` and
-      `superstep_levels`; `python -m jaxmc.obs diff` accepts two+
-      jaxmc.multichip/1 artifacts directly and gates per-(rung, D)
-      states/sec/chip with REGRESS flags.  The COMMITTED
-      MULTICHIP_r07/r08.json also carry per-point `merge` and
-      `phase_walls {expand_s, exchange_s, merge_rank_s,
-      merge_fullsort_s, step_s, hot_share}`: XLA:CPU host walls from
-      a probe that timed the rank merge beside the full-sort merge it
-      replaced; probe and full-sort merge were removed in PR 28.
-      Nothing writes these keys any more; `obs report` still renders
-      them from those files.
     - serve warm-registry eviction (ROADMAP item 3): counter
       `serve.evictions` + trace event `serve.evicted {sig}` when the
       bounded LRU (JAXMC_SERVE_WARM_MAX, default 32) drops the
@@ -439,7 +416,7 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       Cheap and xla modes record counts/recompiles only; wall adds
       the sync + byte surfaces.  Profiling NEVER changes results:
       counts and traces stay bit-identical profile-on vs profile-off
-      (pinned by tests and `make prof-check`).  Gauge `compile.by_fun`
+      (pinned by tests/test_prof.py).  Gauge `compile.by_fun`
       {program: [compiles, seconds]} splits `compile.xla_compile_s`.
     - watchdog heartbeat events gain optional `device_mem_bytes` (the
       PROCESS's measured device peak) next to `rss_bytes`; stall events

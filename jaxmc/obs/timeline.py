@@ -20,8 +20,8 @@ Diagnostics:
   heartbeat/stall events render with their stalled_for/threshold fields
                  (the PR-2 grammar), so a stalled lane is visible inline.
 
-The last line is machine-parseable (the trace-check gate asserts on
-it):
+The last line is machine-parseable (tests/test_serve.py and
+tests/test_chaos.py assert on it):
 
     summary: files=N processes=N lanes=N events=N orphans=N gaps=N
 

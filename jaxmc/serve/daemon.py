@@ -164,7 +164,7 @@ class ServeDaemon:
         self.host = host
         self.port = port
         self.n_workers = max(1, int(workers))
-        # env override so subprocess daemons (fleetbench, chaos tests)
+        # env override so subprocess daemons (the fleet and chaos tests)
         # can tighten the checkpoint cadence takeover resumes ride on
         self.checkpoint_every = _fenv("JAXMC_SERVE_CKPT_EVERY",
                                       checkpoint_every)

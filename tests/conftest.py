@@ -48,6 +48,71 @@ needs_reference = pytest.mark.skipif(
            f"environment only; point JAXMC_REFERENCE at a checkout)")
 
 
+# A deliberately SLOW interp job for the serve / fleet / drain tests
+# (moved here from the deleted trace-check harness, ISSUE 43): ~230
+# distinct states over 21 levels at bound=20, a frontier wide enough
+# (> workers*4) that the interp fork pool really forks, a CONSTRAINT tight
+# enough that the analyze interval fixpoint converges BEFORE widening and
+# proves an estimate (so `search.progress_est` exists); the \A guard costs
+# ~q interpreter steps a successor, which is what makes the search last
+# seconds instead of milliseconds.
+SLOW_SPEC = """\
+-------------------------- MODULE traceload --------------------------
+EXTENDS Naturals
+
+VARIABLES a, b
+
+Slow == \\A i \\in 1 .. {q} : i + a >= 0
+
+Init == a = 0 /\\ b = 0
+
+Next == \\/ a' = a + 1 /\\ b' = b /\\ Slow
+        \\/ b' = b + 1 /\\ a' = a /\\ Slow
+
+Bound == a + b <= {bound}
+
+TypeInv == a >= 0 /\\ b >= 0
+
+Spec == Init /\\ [][Next]_<<a, b>>
+======================================================================
+"""
+
+SLOW_CFG = """\
+SPECIFICATION Spec
+CONSTRAINT Bound
+INVARIANT TypeInv
+CHECK_DEADLOCK FALSE
+"""
+
+
+def write_slow_spec(spec_dir, name, q, bound):
+    """`<spec_dir>/<name>.tla` + `.cfg` of the slow job; returns the spec."""
+    os.makedirs(str(spec_dir), exist_ok=True)
+    spec = os.path.join(str(spec_dir), f"{name}.tla")
+    with open(spec, "w", encoding="utf-8") as fh:
+        fh.write(SLOW_SPEC.format(q=q, bound=bound)
+                 .replace("MODULE traceload", f"MODULE {name}"))
+    with open(os.path.join(str(spec_dir), f"{name}.cfg"), "w",
+              encoding="utf-8") as fh:
+        fh.write(SLOW_CFG)
+    return spec
+
+
+def timeline_counts(traces):
+    """`obs timeline --fail-on-orphans` over trace files: (exit code, the
+    counts of its machine-parseable `summary:` line, the whole output)."""
+    import io
+    from jaxmc.obs.report import main as obs_main
+    buf = io.StringIO()
+    rc = obs_main(["timeline", "--fail-on-orphans"] + list(traces), out=buf)
+    out = buf.getvalue()
+    summary = [ln for ln in out.splitlines()
+               if ln.startswith("summary: ")][-1]
+    return rc, {k: int(v) for k, v in
+                (kv.split("=") for kv in
+                 summary[len("summary: "):].split())}, out
+
+
 @pytest.fixture(autouse=True)
 def _forget_programs():
     """The program registry (ISSUE 37, jaxmc/compile/cache.py) is state

@@ -105,7 +105,7 @@ def test_inference_proves_expected_fixture_bounds():
 
 def _device_run(name, env, **kw):
     from jaxmc import obs
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     old = {k: os.environ.get(k) for k in env}
     os.environ.update(env)
     tel = obs.Telemetry()

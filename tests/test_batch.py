@@ -304,6 +304,29 @@ class TestVmappedEngine:
             assert (mem.result.distinct, mem.result.generated) == \
                 (exp.distinct, exp.generated)
 
+    def test_deep_rung_cohort_parity_cold_and_warm(self):
+        # the deep-narrow batchtoy_bench1-4 rungs (hundreds of levels, a
+        # handful of states each: the dispatch-bound shape a cohort is
+        # for; the warm leg of the deleted `make batch-check`, ISSUE 43):
+        # every member's answer is its solo run's, the cohort rides one
+        # program at full width, and a second run on the WARM engine
+        # repeats the answer
+        from jaxmc.backend.batch import BatchCheckEngine
+        from jaxmc.backend.bfs import TpuExplorer
+        names = [f"bench{i}" for i in (1, 2, 3, 4)]
+        solos = [TpuExplorer(load_model(BT, btcfg(v), False),
+                             host_seen=True, store_trace=False).run()
+                 for v in names]
+        assert len({r.distinct for r in solos}) == 4
+        be = BatchCheckEngine([session_cfg(v, no_trace=True)
+                               for v in names]).build()
+        for _ in range(2):
+            members = be.run()
+            for v, solo, mem in zip(names, solos, members):
+                assert mem.error is None, f"{v}: {mem.error}"
+                assert _result_tuple(mem.result) == _result_tuple(solo), v
+            assert be.dispatcher.max_width == 4
+
     def test_incompatible_cohort_refused(self):
         from jaxmc.backend.batch import (BatchCheckEngine,
                                          BatchIncompatible)

@@ -257,7 +257,7 @@ def test_the_engine_climbs_the_ladder_the_arithmetic_gives(tmp_path):
 
 
 def test_the_deep_pins_are_the_corpus_manifests():
-    """`jaxmc/corpus.py` seeds kernelbench and chip_smoke.py from the same
+    """`jaxmc/corpus.py` seeds chip_smoke.py from the same
     capacities (its AccCap 2^22 could not hold the widest level)."""
     from jaxmc.corpus import CASES
     pins = _pins("transfer_scaled_4p")

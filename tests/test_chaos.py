@@ -364,7 +364,7 @@ class TestTraceContextChaos:
         import signal
         import time
         from jaxmc.obs.report import main as obs_main
-        from jaxmc.tracecheck import _SLOW_CFG, _SLOW_SPEC
+        from conftest import SLOW_CFG as _SLOW_CFG, SLOW_SPEC as _SLOW_SPEC
 
         spec = str(tmp_path / "traceload.tla")
         with open(spec, "w") as fh:
@@ -420,12 +420,270 @@ class TestTraceContextChaos:
 
 # ------------------------------------------------ fleet serving chaos
 
+class _Fleet:
+    """Subprocess daemons sharing one spool, found through their
+    heartbeat records (`serve.json` is last-writer-wins, so a daemon's
+    own port lives in `spool/daemons/<id>.json` alone)."""
+
+    def __init__(self, spool, env, trace_dir=None):
+        self.spool, self.env, self.trace_dir = spool, dict(env), trace_dir
+        self.procs = []
+
+    def start(self, n=1):
+        for _ in range(n):
+            args = [sys.executable, "-m", "jaxmc.serve", "run",
+                    "--spool", self.spool, "--workers", "1", "--quiet"]
+            if self.trace_dir:
+                args += ["--trace", os.path.join(
+                    self.trace_dir,
+                    f"daemon{len(self.procs)}.trace.jsonl")]
+            self.procs.append(subprocess.Popen(
+                args, cwd=REPO, stdout=subprocess.DEVNULL,
+                stderr=subprocess.DEVNULL,
+                env=dict(os.environ, JAX_PLATFORMS="cpu", **self.env)))
+
+    def daemons(self):
+        """Heartbeat records of OUR live daemons (matched by pid)."""
+        import glob
+        pids = {p.pid for p in self.procs if p.poll() is None}
+        out = []
+        for path in sorted(glob.glob(
+                os.path.join(self.spool, "daemons", "*.json"))):
+            try:
+                with open(path) as fh:
+                    rec = json.load(fh)
+            except (OSError, ValueError):
+                continue
+            if rec.get("pid") in pids:
+                out.append(rec)
+        return out
+
+    def wait_up(self, n, timeout=120.0):
+        import time
+        deadline = time.time() + timeout
+        while time.time() < deadline:
+            recs = self.daemons()
+            if len(recs) >= n:
+                return recs
+            time.sleep(0.1)
+        raise AssertionError(
+            f"only {len(self.daemons())}/{n} daemons heartbeating")
+
+    @staticmethod
+    def client(rec):
+        from jaxmc.serve.protocol import ServeClient
+        return ServeClient(rec.get("host", "127.0.0.1"), rec["port"])
+
+    def record(self, jid):
+        """A job record straight off the spool: it must be readable with
+        every daemon dead."""
+        for sub in ("jobs", "quarantine"):
+            try:
+                with open(os.path.join(self.spool, sub,
+                                       f"{jid}.json")) as fh:
+                    return json.load(fh)
+            except (OSError, ValueError):
+                continue
+        return None
+
+    def wait_job(self, jid, statuses, timeout=180.0):
+        import time
+        deadline = time.time() + timeout
+        last = None
+        while time.time() < deadline:
+            rec = self.record(jid)
+            if rec is not None:
+                last = rec.get("status")
+                if last in statuses:
+                    return rec
+            time.sleep(0.1)
+        raise AssertionError(f"job {jid} still {last!r}, wanted {statuses}")
+
+    def metric_total(self, name):
+        import urllib.request
+        total = 0.0
+        for rec in self.daemons():
+            try:
+                with urllib.request.urlopen(
+                        f"http://127.0.0.1:{rec['port']}/metrics",
+                        timeout=10) as resp:
+                    text = resp.read().decode()
+            except OSError:
+                continue
+            for ln in text.splitlines():
+                if ln.startswith(name + " "):
+                    total += float(ln.rsplit(" ", 1)[1])
+        return total
+
+    def stop(self, graceful=True, timeout=30.0):
+        import time
+        for p in self.procs:
+            if p.poll() is None and graceful:
+                p.terminate()   # SIGTERM: cooperative drain, exit 0
+        deadline = time.time() + timeout
+        for p in self.procs:
+            if p.poll() is None:
+                try:
+                    p.wait(max(0.1, deadline - time.time()))
+                except subprocess.TimeoutExpired:
+                    p.kill()
+                    p.wait(10)
+
+
+class TestFleetLegs:
+    """The fleet substrate end to end (ISSUE 19), as the deleted `make
+    fleet-check` drove it (ISSUE 43): several daemon PROCESSES on one
+    durable spool, interp jobs, real SIGKILLs.  Seconds each.  (The
+    admission leg needs no second process:
+    tests/test_serve.py::TestAdmission.)"""
+
+    def test_sigkill_takeover_resumes_with_the_solo_counts(self, tmp_path):
+        import signal
+        import time
+        from conftest import write_slow_spec
+        spec = write_slow_spec(tmp_path / "specs", "takeoverload",
+                               q=1500, bound=20)
+        opts = {"backend": "interp", "progress_every": 2}
+        solo = _Fleet(str(tmp_path / "spool_solo"),
+                      {"JAXMC_SERVE_CKPT_EVERY": "0.3"})
+        solo.start(1)
+        try:
+            code, job = solo.client(solo.wait_up(1)[0]).submit(
+                spec, None, opts)
+            assert code == 200, job
+            ref = solo.wait_job(job["id"], ("done",))
+        finally:
+            solo.stop()
+        assert ref["ok"] is True and ref["distinct"] > 200
+
+        fleet = _Fleet(str(tmp_path / "spool_fleet"), {
+            "JAXMC_SERVE_CKPT_EVERY": "0.3", "JAXMC_LEASE_TTL": "1.5",
+            "JAXMC_LEASE_AFFINITY_GRACE": "0.2"})
+        fleet.start(3)
+        try:
+            recs = fleet.wait_up(3)
+            code, job = fleet.client(recs[0]).submit(spec, None, opts)
+            assert code == 200, job
+            jid = job["id"]
+            owner = fleet.wait_job(jid, ("running",), 120)["daemon"]
+            time.sleep(1.0)     # let a spool checkpoint land
+            # heartbeat ids are `d<pid>-<hex>`: the SIGKILL needs no
+            # side channel
+            os.kill(int(owner[1:].split("-", 1)[0]), signal.SIGKILL)
+            done = fleet.wait_job(jid, ("done", "failed", "quarantined"))
+            assert done["status"] == "done", done
+            # a peer went through the lease steal, resumed from the spool
+            # checkpoint and answered what the undisturbed run answered
+            assert done["daemon"] != owner
+            assert done["stolen_by"] == done["daemon"]
+            assert "stolen" in done.get("requeue_note", "")
+            assert (done["generated"], done["distinct"], done["ok"]) == \
+                (ref["generated"], ref["distinct"], ref["ok"])
+            assert fleet.metric_total("jaxmc_serve_takeovers") >= 1
+        finally:
+            fleet.stop()
+
+    def test_warm_hit_routing_beats_round_robin_and_traces_stitch(
+            self, tmp_path):
+        import glob
+        import time
+        from conftest import timeline_counts, write_slow_spec
+        spec = write_slow_spec(tmp_path / "specs", "routeload",
+                               q=200, bound=12)
+        opts = {"backend": "interp"}
+        trace_dir = str(tmp_path / "traces")
+        os.makedirs(trace_dir)
+        fleet = _Fleet(str(tmp_path / "spool"), {
+            # nothing rides the fast lane: cold signatures DEFER to the
+            # fleet scan and warm affinity decides who runs them
+            "JAXMC_SERVE_FASTLANE_BOUND": "0",
+            "JAXMC_LEASE_AFFINITY_GRACE": "5.0"}, trace_dir=trace_dir)
+        fleet.start(1)      # A alone first: a fleet of one runs locally
+        try:
+            rec_a = fleet.wait_up(1)[0]
+            code, job = fleet.client(rec_a).submit(spec, None, opts)
+            assert code == 200, job
+            fleet.wait_job(job["id"], ("done",))
+            fleet.start(2)  # two cold peers join
+            recs = fleet.wait_up(3)
+            time.sleep(1.5)  # every fleet scan sees three daemons
+            jids = []
+            for i in range(4):  # identical jobs, round-robin over ports
+                code, job = fleet.client(recs[i % 3]).submit(
+                    spec, None, opts)
+                assert code == 200, job
+                jids.append(job["id"])
+            owners = [fleet.wait_job(j, ("done",))["daemon"] for j in jids]
+            share = owners.count(rec_a["id"]) / len(owners)
+            assert share > 1 / 3, (rec_a["id"], owners)
+            # ... by routing, not by luck
+            assert fleet.metric_total("jaxmc_serve_jobs_deferred") >= 1
+            assert fleet.metric_total(
+                "jaxmc_serve_affinity_adoptions") >= 1
+            fleet.stop(graceful=True)
+            traces = sorted(glob.glob(os.path.join(
+                trace_dir, "*.trace.jsonl"))) + sorted(glob.glob(
+                    os.path.join(fleet.spool, "results",
+                                 "*.trace.jsonl")))
+            rc, counts, out = timeline_counts(traces)
+            assert rc == 0 and counts["orphans"] == 0, out[-800:]
+            assert counts["processes"] >= 3, counts
+        finally:
+            fleet.stop()
+
+    def test_poison_job_is_quarantined_after_the_retry_budget(
+            self, tmp_path):
+        import time
+        from conftest import write_slow_spec
+        spec = write_slow_spec(tmp_path / "specs", "poisonload",
+                               q=50, bound=6)
+        retries = 2
+        state = str(tmp_path / "fault_state")
+        os.makedirs(state)
+        fleet = _Fleet(str(tmp_path / "spool"), {
+            # every daemon that marks this spec running SIGKILLs itself;
+            # the latch directory is SHARED, so respawned lives spend one
+            # cross-daemon budget
+            "JAXMC_FAULTS": "daemon_kill:spec=poisonload.tla:n=99",
+            "JAXMC_FAULTS_STATE": state,
+            "JAXMC_JOB_RETRIES": str(retries), "JAXMC_LEASE_TTL": "1.0",
+            "JAXMC_LEASE_AFFINITY_GRACE": "0.1",
+            "JAXMC_SERVE_CKPT_EVERY": "0.3"})
+        fleet.start(2)
+        try:
+            code, job = fleet.client(fleet.wait_up(2)[0]).submit(
+                spec, None, {"backend": "interp"})
+            assert code == 200, job
+            jid = job["id"]
+            qpath = os.path.join(fleet.spool, "quarantine", f"{jid}.json")
+            deadline = time.time() + 180
+            respawns = 0
+            while time.time() < deadline and not os.path.exists(qpath):
+                live = sum(1 for p in fleet.procs if p.poll() is None)
+                while live < 2 and respawns < 8:    # the supervisor
+                    fleet.start(1)
+                    live += 1
+                    respawns += 1
+                time.sleep(0.2)
+            rec = fleet.record(jid) or {}
+            assert rec.get("status") == "quarantined", (rec, respawns)
+            assert "poison" in rec["verdict"]
+            assert rec["retries_spent"] == retries
+            assert rec.get("fault_context")
+            # a live daemon answers for the id with that verdict, no 404
+            code, got = fleet.client(fleet.wait_up(1)[0]).job(jid)
+            assert code == 200 and got["status"] == "quarantined"
+            assert got["verdict"] == rec["verdict"]
+        finally:
+            fleet.stop(graceful=False)
+
+
 @pytest.mark.slow
 class TestFleetChaos:
     """ISSUE 19: subprocess daemons sharing one durable spool, under
     the daemon_kill / lease_stall fault sites.  Slow-marked (multi-
-    second subprocess scenarios) — `make fleet-check` runs the full
-    acceptance versions; these pin the two leg shapes as tests."""
+    second subprocess scenarios); the acceptance legs themselves are
+    `TestFleetLegs` above, in tier-1."""
 
     def _start_daemon(self, spool, extra_env=None):
         env = dict(os.environ, JAX_PLATFORMS="cpu", **(extra_env or {}))
@@ -534,7 +792,7 @@ class TestFleetChaos:
         import urllib.request
         from jaxmc.serve import JobQueue
         from jaxmc.serve.protocol import ServeClient
-        from jaxmc.tracecheck import _SLOW_CFG, _SLOW_SPEC
+        from conftest import SLOW_CFG as _SLOW_CFG, SLOW_SPEC as _SLOW_SPEC
 
         spec = str(tmp_path / "stallload.tla")
         with open(spec, "w") as fh:

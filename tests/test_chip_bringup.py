@@ -71,6 +71,57 @@ def test_served_tpu_job_without_a_chip_fails_with_the_platform_named(
         d.shutdown()
 
 
+# -------------------------------------- the preflight oracle (--backend auto)
+
+def test_oracle_answers_inside_its_deadline_and_live_platforms_agree(
+        tmp_path):
+    """What the deleted `make backend-check` held (ISSUE 43): the oracle
+    finds a live platform inside the deadline it was given, a dead
+    platform is a named reason and never a failure, and every LIVE
+    platform answers the same pinned leg with the same counts (here the
+    CPU alone is live; on a machine with a chip the chip joins in)."""
+    from jaxmc import obs
+    from jaxmc.backend import oracle
+    deadline = 60.0      # generous: a loaded box must not decide this
+    tel = obs.Telemetry()
+    try:
+        v = oracle.preflight(deadline_s=deadline, tel=tel,
+                             use_cache=False)
+    finally:
+        oracle.reset_cache_for_tests()
+    assert v["platform"] is not None, v["reason"]
+    assert v["wall_s"] <= deadline
+    assert set(v["probes"]) == {"tpu", "gpu", "cpu"}
+    assert v["probes"]["cpu"]["live"] and v["probes"]["cpu"]["devices"] >= 1
+    live = [p for p, pr in v["probes"].items() if pr.get("live")]
+    for plat, pr in v["probes"].items():
+        if plat not in live:
+            assert pr.get("error"), (plat, pr)      # a SKIP has a reason
+    assert v["platform"] in live
+    assert tel.gauges["backend.oracle_choice"] == v["platform"]
+    assert tel.gauges["backend.oracle_probe"] == v["probes"]
+    counts = {}
+    for plat in live:
+        m = str(tmp_path / f"{plat}.json")
+        # the child pins its own platform; the suite's JAX_PLATFORMS=cpu
+        # would override the pin on an accelerator
+        env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+        r = subprocess.run(
+            [sys.executable, "-m", "jaxmc", "check",
+             os.path.join(SPECS, "viewtoy_scaled.tla"),
+             "--backend", plat, "--resident", "--no-trace", "--quiet",
+             "--max-states", "4000", "--metrics-out", m],
+            cwd=REPO, capture_output=True, text=True, timeout=300, env=env)
+        assert r.returncode in (0, 3), (plat, r.stderr[-600:])
+        art = json.load(open(m))
+        assert art["result"]["ok"] and art["result"]["truncated"]
+        assert art["env"]["platform"] == plat
+        counts[plat] = (art["result"]["generated"],
+                        art["result"]["distinct"])
+    # max_states is judged a level, so the cut is the same on any target
+    assert set(counts.values()) == {(43109, 5219)}, counts
+
+
 # ------------------------------------------------ one process per chip
 
 def test_environment_meta_never_initializes_a_backend():

@@ -310,7 +310,7 @@ Spec == Init /\ [][Next]_<<r>>
 
     def test_symtoy_proven_element_lanes_device_parity(self):
         pytest.importorskip("jax")
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         ri = Explorer(load("symtoy", no_deadlock=True)).run()
         runs = {}
         for tag, env in (("on", {}), ("off",
@@ -357,7 +357,7 @@ class TestVerdictClasses:
     def test_dyntoy_predicted_equals_built(self):
         pytest.importorskip("jax")
         from jaxmc import native_store
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         from jaxmc.analyze import predict_arm_demotions
         from jaxmc.compile.ground import (DYN_NESTED_MSG,
                                           DYN_SHAPE_MSG, split_arms)
@@ -458,7 +458,7 @@ Spec == Init /\ [][Next]_<<n>>
 # ------------------------------------------------- regroup parity
 
 def _device_run(model, tel=None, **kw):
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     tel = tel or obs.Telemetry()
     with obs.use(tel):
         ex = TpuExplorer(model, **kw)
@@ -500,7 +500,7 @@ class TestRegroupParity:
         from jaxmc import native_store
         if not native_store.is_available():
             pytest.skip("needs the native store")
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         monkeypatch.setenv("JAXMC_FUSED_MAX_INSTANCES", "2")
         base, _ = _device_run(load("portoy", "portoy_bad"),
                               host_seen=True)
@@ -516,7 +516,7 @@ class TestRegroupParity:
 
     def test_mesh_d2_grouped_byte_identical(self, monkeypatch):
         pytest.importorskip("jax")
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         monkeypatch.setenv("JAXMC_FUSED_MAX_INSTANCES", "2")
         monkeypatch.setenv("JAXMC_MESH_GROUPED", "1")
         results = {}
@@ -571,6 +571,33 @@ class TestPOR:
         assert full.ok and red.ok
         assert red.distinct <= 0.7 * full.distinct, \
             f"{red.distinct} vs {full.distinct}: < 30% reduction"
+
+    @pytest.mark.parametrize("name,cfg", [("portoy", "portoy_ok"),
+                                          ("msgstoy", "msgstoy")])
+    def test_device_por_thirty_percent_reduction(self, name, cfg):
+        """The ample mask INSIDE the fused device step, on the static
+        (portoy) and the dynamic-key (msgstoy) fixture: the unreduced
+        device run meets the manifest pins, the reduced one keeps the
+        verdict with >= 30% fewer distinct states and no interpreter
+        demotion (leg 5 of the deleted `make por-check`, ISSUE 43)."""
+        pytest.importorskip("jax")
+        from jaxmc import native_store
+        if not native_store.is_available():
+            pytest.skip("host_seen needs the native store")
+        from jaxmc.corpus import case_for_cfg
+        pin = case_for_cfg(cfg + ".cfg")
+        full, _ = _device_run(load(name, cfg, no_deadlock=True),
+                              host_seen=True)
+        assert full.ok and (full.generated, full.distinct) == \
+            (pin.generated, pin.distinct)
+        red, tel = _device_run(load(name, cfg, no_deadlock=True),
+                               host_seen=True, por=True)
+        assert red.ok
+        assert tel.gauges.get("por.engine") == "device"
+        assert tel.gauges.get("por.enabled") is True
+        assert tel.gauges.get("por.device_masked_arms", 0) > 0
+        assert red.distinct <= 0.7 * pin.distinct, \
+            f"{red.distinct} vs {pin.distinct}: < 30% reduction"
 
     def test_por_deadlock_verdict_and_replay(self):
         m = load("portoy", "portoy")
@@ -707,7 +734,7 @@ class TestPredictedCapacityRung:
         pytest.importorskip("jax")
         # transfer-style racing counters: no estimate, no prediction —
         # the ladder falls through to the platform defaults as before
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         tel = obs.Telemetry()
         with obs.use(tel):
             ex = TpuExplorer(load("viewtoy"), store_trace=False,

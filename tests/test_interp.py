@@ -484,7 +484,7 @@ class TestView:
     def test_view_compiles_on_jax_backend(self, tmp_path):
         # ISSUE 6: cfg VIEW compiles — the device dedup keys on the
         # view's value lanes, matching the interp's collapsed counts
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         ri = Explorer(self._model(tmp_path, True)).run()
         ex = TpuExplorer(self._model(tmp_path, True), store_trace=True)
         assert ex.view_fn is not None

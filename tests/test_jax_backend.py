@@ -57,21 +57,21 @@ class TestLayout:
 class TestDeviceBFS:
     @needs_reference
     def test_atomic_add_counts(self):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         model = load(os.path.join(REFERENCE, "atomic_add.tla"))
         r = TpuExplorer(model).run()
         assert r.ok and r.distinct == 5 and r.generated == 7
 
     @needs_reference
     def test_pcal_intro_matches_interp(self, pcal_model):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         r = TpuExplorer(pcal_model).run()
         assert r.ok
         assert r.distinct == 3800     # == interpreter == oracle counts
         assert r.generated == 5850
 
     def test_buggy_assert_found_with_trace(self):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         model = load(os.path.join(SPECS, "pcal_intro_buggy.tla"))
         r = TpuExplorer(model).run()
         assert not r.ok and r.violation.kind == "assert"
@@ -93,7 +93,7 @@ class TestDeviceBFS:
             assert succ in succs
 
     def test_invariant_violation(self):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         cfg = ModelConfig(specification="Spec",
                           invariants=["MoneyInvariant"])
         model = load(os.path.join(SPECS, "pcal_intro_buggy.tla"), cfg)
@@ -127,7 +127,7 @@ class TestMesh:
     @needs_reference
     def test_pcal_intro_mesh_counts(self, pcal_model):
         import jax
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         assert len(jax.devices()) >= 8
         r = MeshExplorer(pcal_model).run()
         assert r.ok
@@ -136,7 +136,7 @@ class TestMesh:
 
     @needs_reference
     def test_atomic_add_mesh(self):
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         model = load(os.path.join(REFERENCE, "atomic_add.tla"))
         r = MeshExplorer(model).run()
         assert r.ok and r.distinct == 5 and r.generated == 7
@@ -145,7 +145,7 @@ class TestMesh:
     # checkpoint/resume ----
 
     def test_mesh_assert_violation_trace_replays(self):
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         model = load(os.path.join(SPECS, "pcal_intro_buggy.tla"))
         r = MeshExplorer(model).run()
         assert not r.ok and r.violation.kind == "assert"
@@ -155,7 +155,7 @@ class TestMesh:
         _replay_trace(model, r.violation.trace)
 
     def test_mesh_invariant_violation_named_with_trace(self):
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         cfg = ModelConfig(specification="Spec",
                           invariants=["MoneyInvariant"])
         model = load(os.path.join(SPECS, "pcal_intro_buggy.tla"), cfg)
@@ -169,7 +169,7 @@ class TestMesh:
 
     @needs_reference
     def test_mesh_checkpoint_resume_exact(self, pcal_model, tmp_path):
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         ck = str(tmp_path / "mesh.ck")
         r1 = MeshExplorer(pcal_model, max_states=1000,
                           checkpoint_path=ck, checkpoint_every=0).run()
@@ -184,7 +184,7 @@ class TestMesh:
         # hash-routed all_to_all exchange (SURVEY §2.3 comm rows): same
         # exact counts as the all_gather path, provenance intact through
         # the routed src-index lane
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         r = MeshExplorer(pcal_model, exchange="a2a").run()
         assert r.ok
         assert r.distinct == 3800 and r.generated == 5850
@@ -199,7 +199,7 @@ class TestMesh:
         # force a tiny capacity factor: the first level must overflow
         # the per-peer bucket, double gamma (possibly repeatedly), and
         # still finish with EXACT counts
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         ex = MeshExplorer(pcal_model, exchange="a2a")
         ex._a2a_gamma = 0.05
         r = ex.run()
@@ -208,7 +208,7 @@ class TestMesh:
         assert ex._a2a_gamma > 0.05  # growth actually happened
 
     def test_mesh_deadlock_trace(self, tmp_path):
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         spec = tmp_path / "countdown.tla"
         spec.write_text("""---- MODULE countdown ----
 EXTENDS Naturals
@@ -255,7 +255,7 @@ class TestHostSeen:
         if not native_store.is_available():
             import pytest
             pytest.skip("no native toolchain")
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         cfg = parse_cfg(open(os.path.join(REFERENCE, "pcal_intro.cfg")).read())
         model = load(os.path.join(REFERENCE, "pcal_intro.tla"), cfg)
         r = TpuExplorer(model, host_seen=True).run()
@@ -266,7 +266,7 @@ class TestHostSeen:
         if not native_store.is_available():
             import pytest
             pytest.skip("no native toolchain")
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         model = load(os.path.join(SPECS, "pcal_intro_buggy.tla"))
         r = TpuExplorer(model, host_seen=True).run()
         assert not r.ok and r.violation.kind == "assert"
@@ -281,7 +281,7 @@ class TestDeviceSymmetry:
 
     def test_symtoy_reduced_counts_match_interp(self):
         from jaxmc.engine.explore import Explorer
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         cfg = parse_cfg(open(os.path.join(SPECS, "symtoy.cfg")).read())
         cfg.check_deadlock = False
         model = load(os.path.join(SPECS, "symtoy.tla"), cfg)
@@ -301,7 +301,7 @@ class TestDeviceSymmetry:
         # canonical representative or device counts inflate (and seen is
         # seeded with duplicate canonical fingerprints)
         from jaxmc.engine.explore import Explorer
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         cfg = parse_cfg(
             open(os.path.join(SPECS, "symtoy_multiinit.cfg")).read())
         cfg.check_deadlock = False
@@ -322,7 +322,7 @@ class TestDeviceSymmetry:
         # the corpus's symmetry workhorse (MCPaxos's symmetry is the
         # identity over its singleton sets): growset-of-records lanes
         # exercise the element-remap + segment re-sort transform
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         d = os.path.join(REFERENCE, "examples", "Paxos")
         cfg = parse_cfg(open(os.path.join(d, "MCVoting.cfg")).read())
         cfg.check_deadlock = False
@@ -346,7 +346,7 @@ class TestDeviceCheckpoint:
 
     @needs_reference
     def test_level_mode_resume_exact(self, tmp_path):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         ckp = str(tmp_path / "ck.pkl")
         model = self._pcal()
         r1 = TpuExplorer(model, checkpoint_path=ckp,
@@ -359,7 +359,7 @@ class TestDeviceCheckpoint:
         assert r2.diameter == r1.diameter
 
     def test_level_mode_resume_finds_violation_with_trace(self, tmp_path):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         ckp = str(tmp_path / "ck.pkl")
         model = load(os.path.join(SPECS, "pcal_intro_buggy.tla"))
         r1 = TpuExplorer(model, checkpoint_path=ckp,
@@ -375,7 +375,7 @@ class TestDeviceCheckpoint:
         from jaxmc import native_store
         if not native_store.is_available():
             pytest.skip("no native toolchain")
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         ckp = str(tmp_path / "ck.pkl")
         model = self._pcal()
         r1 = TpuExplorer(model, host_seen=True, checkpoint_path=ckp,
@@ -387,7 +387,7 @@ class TestDeviceCheckpoint:
 
     @needs_reference
     def test_resident_resume_exact(self, tmp_path):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         ckp = str(tmp_path / "ck.pkl")
         model = self._pcal()
         ex = TpuExplorer(model, resident=True, chunk=256,
@@ -404,7 +404,7 @@ class TestDeviceCheckpoint:
 
     @needs_reference
     def test_resume_mode_mismatch_rejected(self, tmp_path):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         ckp = str(tmp_path / "ck.pkl")
         model = self._pcal()
         TpuExplorer(model, checkpoint_path=ckp,
@@ -430,7 +430,7 @@ class TestResident:
         # flagship workload at the scale that completes (pinned 6185/694
         # in test_kernel2 for interp/host_seen); small chunk exercises
         # the multi-chunk accumulator path
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         ex = TpuExplorer(self._raft_micro(), resident=True, chunk=128)
         r = ex.run()
         assert r.ok
@@ -445,7 +445,7 @@ class TestResident:
     def test_resident_growth_redo_exactness(self):
         # tiny starting caps force every grow-and-redo status (each
         # growth recompiles, hence slow-marked); counts stay exact
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         ex = TpuExplorer(self._raft_micro(), resident=True, chunk=128)
         ex._res_caps = {"SC": 1 << 8, "FCap": 128, "AccCap": 1 << 9,
                         "VC": 1 << 8}
@@ -460,7 +460,7 @@ class TestResident:
         # report the same diameter as the interp backend (regression:
         # the level loop used to advance depth before exiting)
         from jaxmc.engine.explore import Explorer
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         spec = tmp_path / "cnt.tla"
         spec.write_text("""---- MODULE cnt ----
 EXTENDS Naturals
@@ -482,14 +482,14 @@ Spec == Init /\\ [][Next]_x
         # mutually exclusive seen-set homes: must be diagnosed up front,
         # not silently resolved in favor of one mode
         from jaxmc.compile.vspec import CompileError
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         with pytest.raises(CompileError, match="mutually exclusive"):
             TpuExplorer(self._raft_micro(), resident=True, host_seen=True)
 
     @needs_reference
     def test_resident_rejects_temporal_models(self):
         from jaxmc.compile.vspec import CompileError
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         path = os.path.join(REFERENCE, "examples", "SpecifyingSystems",
                             "HourClock", "HourClock2.tla")
         cfg = parse_cfg(open(os.path.join(
@@ -516,7 +516,7 @@ class TestCorpusOnDevice:
         from jaxmc import native_store
         if not native_store.is_available():
             pytest.skip("no native toolchain")
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         spec = os.path.join(REFERENCE, rel)
         cfg = parse_cfg(open(spec[:-4] + ".cfg", encoding="utf-8",
                              errors="replace").read())
@@ -533,7 +533,7 @@ class TestRefinementOnDevice:
 
     @needs_reference
     def test_hourclock2_equivalence_checked(self):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         d = os.path.join(REFERENCE, "examples/SpecifyingSystems/HourClock")
         cfg = parse_cfg(open(os.path.join(d, "HourClock2.cfg")).read())
         model = load(os.path.join(d, "HourClock2.tla"), cfg)
@@ -544,7 +544,7 @@ class TestRefinementOnDevice:
 
     @needs_reference
     def test_alternating_bit_abcspec_checked(self):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         d = os.path.join(REFERENCE, "examples/SpecifyingSystems/TLC")
         cfg = parse_cfg(open(os.path.join(d, "MCAlternatingBit.cfg")).read())
         model = load(os.path.join(d, "MCAlternatingBit.tla"), cfg)
@@ -556,7 +556,7 @@ class TestRefinementOnDevice:
         assert not any("NOT checked" in w for w in r.warnings), r.warnings
 
     def test_non_refinement_detected(self, tmp_path):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         spec = tmp_path / "badhc.tla"
         spec.write_text("""---- MODULE badhc ----
 EXTENDS Naturals
@@ -622,7 +622,7 @@ Cycles == []<><<Next>>_hr
     def _pair(self, tmp_path, name, text, cfg):
         """(level engine, interpreter) results on one inline spec."""
         from jaxmc.engine.explore import Explorer
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         p = tmp_path / name
         p.write_text(text)
         return (TpuExplorer(load(str(p), cfg)).run(),
@@ -684,7 +684,7 @@ def test_mesh_raft_micro_counts():
     # the flagship wide-state workload shards: MCraftMicro on an 8-device
     # mesh matches the interp/single-chip counts exactly
     import jax
-    from jaxmc.tpu.mesh import MeshExplorer
+    from jaxmc.backend.mesh import MeshExplorer
     assert len(jax.devices()) >= 8
     ldr = Loader([os.path.join(REFERENCE, "examples"), SPECS])
     model = bind_model(
@@ -700,7 +700,7 @@ def test_mesh_innerfifo_counts():
     # mesh-vs-interp equality on a corpus model with constraints and a
     # canonically-sorted container (the fp128-key dedup path)
     import jax
-    from jaxmc.tpu.mesh import MeshExplorer
+    from jaxmc.backend.mesh import MeshExplorer
     assert len(jax.devices()) >= 8
     d = os.path.join(REFERENCE, "examples/SpecifyingSystems/FIFO")
     cfg = parse_cfg(open(os.path.join(d, "MCInnerFIFO.cfg")).read())
@@ -720,7 +720,7 @@ class TestHybrid:
         # MCConsensus's Inv uses IsFiniteSet (uncompilable): the
         # invariant demotes to host evaluation over decoded rows while
         # the actions stay compiled; counts match the interp pin
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         d = os.path.join(REFERENCE, "examples/Paxos")
         cfg = parse_cfg(open(os.path.join(d, "MCConsensus.cfg")).read())
         cfg.check_deadlock = False
@@ -736,7 +736,7 @@ class TestHybrid:
         # AsynchInterface's Send leaves val' nondeterministic (val' \in
         # Data): that arm demotes to interpreter enumeration, Rcv stays
         # compiled; counts match the interp pin
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         d = os.path.join(REFERENCE,
                          "examples/SpecifyingSystems/AsynchronousInterface")
         cfg = parse_cfg(open(os.path.join(d, "AsynchInterface.cfg")).read())
@@ -751,7 +751,7 @@ class TestHybrid:
         # level mode cannot interleave interpreter work: a spec that
         # needs hybrid execution is rejected with a MODE error (fix is
         # a flag, not a different backend)
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         from jaxmc.compile.vspec import ModeError
         d = os.path.join(REFERENCE, "examples/Paxos")
         cfg = parse_cfg(open(os.path.join(d, "MCConsensus.cfg")).read())
@@ -766,7 +766,7 @@ class TestHybrid:
         # demotion (False + abort flag); the abort fires on a reachable
         # state, the engine demotes those arms to the interpreter,
         # restarts, and the counts match the interp pin exactly
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         d = os.path.join(REFERENCE, "examples/Paxos")
         cfg = parse_cfg(open(os.path.join(d, "MCPaxos.cfg")).read())
         model = load(os.path.join(d, "MCPaxos.tla"), cfg)
@@ -782,7 +782,7 @@ class TestHybrid:
         # CHOOSE-heavy), so the device contributes hashing/dedup while
         # the interpreter enumerates — first SI-class workload running
         # through the device engine, counts exact
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         ldr = Loader([os.path.join(REFERENCE, "examples"), SPECS])
         model = bind_model(
             ldr.load_path(os.path.join(SPECS, "MCserializableSI.tla")),
@@ -826,7 +826,7 @@ class TestScalarUnions:
         # previously rejected with "cannot merge shapes enum and fcn";
         # Req/Rsp arms demote (memInt' nondeterminism via Send/Reply),
         # Do(p) stays compiled
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         d = os.path.join(REFERENCE,
                          "examples/SpecifyingSystems/CachingMemory")
         cfg = parse_cfg(open(os.path.join(d,
@@ -840,7 +840,7 @@ class TestScalarUnions:
         # THE golden run: the corpus's only captured full TLC output
         # (testout2:265-266 — TLC 1.57 took 22 hours) reproduced on the
         # device backend: 6181 generated / 195 distinct, diameter 5
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         d = os.path.join(REFERENCE,
                          "examples/SpecifyingSystems/AdvancedExamples")
         cfg = parse_cfg(open(os.path.join(d, "MCInnerSerial.cfg")).read())
@@ -853,7 +853,7 @@ class TestScalarUnions:
         # liveness PROPERTIES check through the hybrid edge stream on a
         # scalar-union model: LM_Inner_LISpec + LM_Inner_Liveness verify
         # with no "NOT checked" warnings beyond the host_seen note
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         d = os.path.join(REFERENCE, "examples/SpecifyingSystems/Liveness")
         cfg = parse_cfg(open(os.path.join(
             d, "MCLiveWriteThroughCache.cfg")).read())
@@ -889,7 +889,7 @@ def test_mcraft_3s_mid4_completes_exhaustively():
     # with strided adaptive relayout (one relayout recovered the
     # message variant the sampler missed). ~46 min on the contended
     # 1-core dev box at 6.6k st/s steady state.
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     ldr = Loader([os.path.join(REFERENCE, "examples"), SPECS])
     model = bind_model(
         ldr.load_path(os.path.join(SPECS, "MCraftMicro.tla")),
@@ -928,8 +928,8 @@ Inv == x + y < 5
 
     # single-chip reference: MeshExplorer over this process's 8 virtual
     # devices (same global device count as 2 procs x 4 below)
-    from jaxmc.tpu.mesh import MeshExplorer
-    from jaxmc.tpu.multihost import fmt_trace_line
+    from jaxmc.backend.mesh import MeshExplorer
+    from jaxmc.backend.multihost import fmt_trace_line
     model = load(str(spec), parse_cfg(cfgp.read_text()))
     r = MeshExplorer(model).run()
     assert not r.ok and r.violation.kind == "invariant"
@@ -949,7 +949,7 @@ Inv == x + y < 5
         log = tmp_path / f"mh{pid}.log"
         logs.append(log)
         procs.append(subprocess.Popen(
-            [_sys.executable, "-m", "jaxmc.tpu.multihost",
+            [_sys.executable, "-m", "jaxmc.backend.multihost",
              "--process-id", str(pid), "--num-processes", "2",
              "--coordinator", f"localhost:{port}",
              "--local-devices", "4",
@@ -985,7 +985,7 @@ class TestMeshRefinementTemporal:
 
     @needs_reference
     def test_mesh_hourclock2_refinement_checked(self):
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         d = os.path.join(REFERENCE, "examples/SpecifyingSystems/HourClock")
         cfg = parse_cfg(open(os.path.join(d, "HourClock2.cfg")).read())
         model = load(os.path.join(d, "HourClock2.tla"), cfg)
@@ -994,7 +994,7 @@ class TestMeshRefinementTemporal:
         assert not any("NOT checked" in w for w in r.warnings), r.warnings
 
     def test_mesh_non_refinement_detected(self, tmp_path):
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         spec = tmp_path / "badhc.tla"
         spec.write_text("""---- MODULE badhc ----
 EXTENDS Naturals
@@ -1020,7 +1020,7 @@ JumpSpec == HCini /\\ [][Jump]_hr
         # SentLeadsToRcvd (under ABSpec fairness) + ABCSpec refinement
         # verified over the mesh's streamed behavior graph — the exact
         # deliverable model of VERDICT r3 #9
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         d = os.path.join(REFERENCE, "examples/SpecifyingSystems/TLC")
         cfg = parse_cfg(open(os.path.join(d, "MCAlternatingBit.cfg")).read())
         model = load(os.path.join(d, "MCAlternatingBit.tla"), cfg)
@@ -1037,7 +1037,7 @@ def test_per_arm_demotion_keeps_siblings_compiled(tmp_path):
     # arms stay compiled — and the hybrid run still matches the
     # interpreter exactly. Before this, the whole conjunction was a
     # single arm and any demotion sent 100% of the model to the interp.
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     from jaxmc.engine.explore import Explorer
     spec = tmp_path / "armgran.tla"
     spec.write_text("""---- MODULE armgran ----
@@ -1074,7 +1074,7 @@ def test_adaptive_relayout_recovers_unobserved_variant(tmp_path):
     # encode fail mid-search; the engine re-samples from the abort-time
     # frontier, rebuilds the layout with the variant present, restarts,
     # and completes with exact counts — no arm demotion needed
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     from jaxmc.engine.explore import Explorer
     spec = tmp_path / "deepvar.tla"
     spec.write_text("""---- MODULE deepvar ----

@@ -62,7 +62,7 @@ def interp_successors(model, st):
     ("specs/transfer_scaled.tla", "specs/transfer_scaled.cfg"),
 ])
 def test_kernel_matches_interp_transfer(specrel, cfgrel):
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     root = os.path.dirname(SPECS)
     model = bind_model(
         Loader([]).load_path(os.path.join(root, specrel)),
@@ -81,9 +81,37 @@ def test_kernel_matches_interp_transfer(specrel, cfgrel):
         assert ks == interp_successors(model, st)
 
 
+@pytest.mark.parametrize("name", ["viewtoy_scaled", "symtoy_scaled"])
+def test_resident_kernel_counts_equal_the_pins_at_bench_scale(name):
+    """cfg VIEW and cfg SYMMETRY at bench scale: the packed resident kernel,
+    at the manifest's own capacity record, counts what the manifest pins —
+    and the interpreter meets the same pins in
+    `tests/test_corpus.py::test_corpus_case[<name>.cfg]`, so the two engines
+    are bit-identical on the whole run, not only per transition.  A second
+    search on the warm engine repeats the answer.  (The plain wide rung,
+    transfer_scaled: `tests/test_spans.py::...::
+    test_transfer_scaled_meets_its_pins`.)"""
+    from jaxmc.backend.bfs import TpuExplorer
+    from jaxmc.corpus import case_for_cfg
+    case = case_for_cfg(name + ".cfg")
+    cfg = parse_cfg(open(case.cfg_path()).read())
+    cfg.check_deadlock = not case.no_deadlock
+    model = bind_model(Loader([SPECS]).load_path(case.spec_path()), cfg)
+    caps = dict(case.res_caps)
+    ex = TpuExplorer(model, store_trace=False, resident=True,
+                     cap_profile=False, chunk=caps.pop("chunk"),
+                     res_caps=caps)
+    assert not ex.plan.identity, "the rung is there for the PACKED lanes"
+    for _ in range(2):
+        r = ex.run()
+        assert (r.ok, r.truncated) == (True, False)
+        assert (r.generated, r.distinct) == \
+            (case.generated, case.distinct)
+
+
 @pytest.mark.slow
 def test_kernel_matches_interp_raft_tiny():
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     from jaxmc.compile.vspec import Bounds
     root = os.path.dirname(SPECS)
     ldr = Loader([os.path.join(REFERENCE, "examples")])
@@ -106,7 +134,7 @@ def test_nested_dynamic_exists_rejected(tmp_path):
     # silently explore only diagonal (i == j) pairs — the compiler must
     # reject instead (exactness contract: compile exactly or not at all)
     from jaxmc.compile.ground import CompileError
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     spec = tmp_path / "nested_dyn.tla"
     spec.write_text(r"""---- MODULE nested_dyn ----
 EXTENDS Naturals, Sequences
@@ -128,7 +156,7 @@ def test_sibling_dynamic_exists_rejected(tmp_path):
     # action with distinct $slotv markers — same diagonal-only hazard as
     # the nested form, caught at action-compile time
     from jaxmc.compile.ground import CompileError
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     spec = tmp_path / "sibling_dyn.tla"
     spec.write_text(r"""---- MODULE sibling_dyn ----
 EXTENDS Naturals, Sequences
@@ -156,7 +184,7 @@ def _load_micro():
 def test_raft_micro_differential_default():
     # default-selected fast slice of the raft kernel-vs-interp
     # differential (the full sweep on MCraft_tiny is slow-marked above)
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     from jaxmc.engine.simulate import sample_states
     model = _load_micro()
     ex = TpuExplorer(model, store_trace=False)
@@ -174,7 +202,7 @@ def test_raft_micro_whole_run_equivalence():
     # generated/distinct counts from the interpreter and the jax backend
     # on a raft model (MCraftMicro bounds raft.tla's message-bag domain so
     # the search is finite; reference hot path raft.tla:482-493)
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     from jaxmc import native_store
     ri = Explorer(_load_micro()).run()
     assert ri.ok
@@ -190,7 +218,7 @@ def test_raft_micro_whole_run_equivalence():
 def test_raft_3s_bench_whole_run_equivalence():
     # backend count-equivalence on the BENCHMARK model itself (bench.py's
     # workload): ~3.5min interp + ~6min jax on CPU
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     from jaxmc import native_store
 
     def load_bench():
@@ -215,7 +243,7 @@ def test_recursive_operator_demotes_predicate_with_named_reason(tmp_path):
     # strict frames (no guard-demotion recovery), so the predicate must
     # land in fb_invs with that reason — while the non-recursive action
     # arm still compiles.
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     from jaxmc import native_store
     if not native_store.is_available():
         pytest.skip("hybrid (demoted invariant) needs the native store")

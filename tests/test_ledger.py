@@ -1,8 +1,8 @@
 r"""Persistent run ledger (ISSUE 17, jaxmc/obs/ledger.py): append /
 flock-concurrency / torn-line tolerance, artifact backfill over
-BENCH_r*-shaped records + the committed MULTICHIP_r* history,
-trajectory rendering via `python -m jaxmc.obs history`, and the --fail-on-regress gate firing
-(exit 1) on a synthesized degraded run.
+BENCH_r*-shaped records and `--metrics-out` artifacts, trajectory
+rendering via `python -m jaxmc.obs history`, and the --fail-on-regress
+gate firing (exit 1) on a synthesized degraded run.
 
 Pure stdlib + tmp ledgers throughout — conftest pins JAXMC_LEDGER=off
 so nothing here (or anywhere in the suite) touches ~/.cache/jaxmc.
@@ -19,8 +19,6 @@ from jaxmc.obs import ledger
 from jaxmc.obs.report import main as obs_main
 
 pytestmark = pytest.mark.obs
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def mk_summary(rate=5000.0, ts=1000.0, platform="cpu", env=None):
@@ -115,21 +113,24 @@ class TestBackfill:
                 "vs_baseline": 2.694}}))
         (tmp_path / "BENCH_r03.json").write_text(json.dumps({
             "n": 3, "rc": 124, "parsed": None}))
+        # ... and a `--metrics-out` artifact, in the shape a run writes
+        (tmp_path / "run_a.json").write_text(json.dumps(mk_summary()))
         pats = [str(tmp_path / "BENCH_r*.json"),
-                os.path.join(REPO, "MULTICHIP_r*.json")]
+                str(tmp_path / "run_*.json")]
         skipped = []
         n = ledger.import_artifacts(pats, lp, skipped=skipped)
-        assert n > 0
+        assert n == 2
         ents = ledger.read_entries(lp)
         assert len(ents) == n
-        # bench runs land on the shared "bench" rung; multichip curve
-        # points land on per-(rung, D) keys like transfer_scaled@D2
-        rungs = {e["rung"] for e in ents}
-        assert "bench" in rungs
-        assert any("@D" in r for r in rungs), rungs
-        # pre-/1 multichip artifacts and dead bench runs are recorded
-        # as skips, never import failures
-        assert all(":" in s for s in skipped)
+        # bench runs land on the shared "bench" rung, a metrics artifact
+        # on the rung its file is named for, at generated / wall
+        by_rung = {e["rung"]: e for e in ents}
+        assert set(by_rung) == {"bench", "run_a"}
+        assert by_rung["run_a"]["kind"] == "metrics"
+        assert by_rung["run_a"]["states_per_sec"] == 5000.0
+        assert by_rung["run_a"]["platform"] == "cpu"
+        # dead bench runs are recorded as skips, never import failures
+        assert len(skipped) == 1 and "BENCH_r03" in skipped[0]
         # content addressing: the same import is a no-op
         assert ledger.import_artifacts(pats, lp) == 0
         assert len(ledger.read_entries(lp)) == n

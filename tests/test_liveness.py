@@ -196,10 +196,10 @@ class TestDeviceLiveness:
     """The jax backend streams the behavior graph (kept states, edges,
     parents, labels) to the host and runs the SAME LivenessChecker the
     interp uses — verdict parity on every corpus liveness model the
-    kernel compiler accepts (tpu/bfs.py _LiveGraph/_check_live)."""
+    kernel compiler accepts (backend/bfs.py _LiveGraph/_check_live)."""
 
     def run_jax(self, spec_path, cfg_text=None, cfg_path=None, **kw):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         cfg = parse_cfg(cfg_text if cfg_text is not None
                         else open(cfg_path).read())
         m = Loader([os.path.dirname(spec_path)]).load_path(spec_path)

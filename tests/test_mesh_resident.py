@@ -79,28 +79,28 @@ def mesh4():
 
 class TestExchangeDefault:
     def test_a2a_default_for_multidevice(self):
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         me = MeshExplorer(load("constoy"))
         assert me.D > 1 and me.exchange == "a2a"
         assert me._exchange_src == "default"
 
     def test_env_override(self, monkeypatch):
         monkeypatch.setenv("JAXMC_MESH_EXCHANGE", "gather")
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         me = MeshExplorer(load("constoy"))
         assert me.exchange == "gather"
         assert me._exchange_src == "JAXMC_MESH_EXCHANGE"
 
     def test_explicit_arg_beats_env(self, monkeypatch):
         monkeypatch.setenv("JAXMC_MESH_EXCHANGE", "gather")
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         me = MeshExplorer(load("constoy"), exchange="a2a")
         assert me.exchange == "a2a"
 
     def test_single_device_defaults_gather(self):
         import jax
         from jax.sharding import Mesh
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         me = MeshExplorer(load("constoy"),
                           mesh=Mesh(np.array(jax.devices()[:1]),
                                     ("d",)))
@@ -110,7 +110,7 @@ class TestExchangeDefault:
 class TestResidentLoop:
     def test_host_syncs_counts_supersteps_scalars_only(self):
         from jaxmc import obs
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         from jaxmc.engine.explore import Explorer
         ri = Explorer(load("constoy")).run()
         tel = obs.Telemetry()
@@ -136,7 +136,7 @@ class TestResidentLoop:
     def test_superstep_one_pins_one_sync_per_level(self, monkeypatch):
         monkeypatch.setenv("JAXMC_MESH_SUPERSTEP", "1")
         from jaxmc import obs
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         tel = obs.Telemetry()
         with obs.use(tel):
             r = MeshExplorer(load("constoy"), exchange="a2a").run()
@@ -145,7 +145,7 @@ class TestResidentLoop:
 
     def test_second_run_zero_window_recompiles(self):
         from jaxmc import obs
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         tel = obs.Telemetry()
         with obs.use(tel):
             me = MeshExplorer(load("constoy"), exchange="a2a")
@@ -161,7 +161,7 @@ class TestResidentLoop:
         # run 1 persists the (module, layout_sig, D, exchange) profile;
         # a FRESH engine loads it, compiles exactly once, never grows
         from jaxmc import obs
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         MeshExplorer(load("viewtoy"), exchange="a2a").run()
         tel = obs.Telemetry()
         with obs.use(tel):
@@ -179,7 +179,7 @@ class TestResidentLoop:
         assert p4 != p8
 
     def test_gather_and_a2a_bit_identical(self):
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         rg = MeshExplorer(load("constoy"), exchange="gather").run()
         ra = MeshExplorer(load("constoy"), exchange="a2a").run()
         assert (rg.generated, rg.distinct, rg.ok) == \
@@ -187,7 +187,7 @@ class TestResidentLoop:
 
     def test_d4_counts_and_view_symmetry_parity(self):
         from jaxmc.engine.explore import Explorer
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         for name, kw in (("viewtoy", {}),
                          ("symtoy", dict(no_deadlock=True))):
             ri = Explorer(load(name, **kw)).run()
@@ -200,7 +200,7 @@ class TestResidentLoop:
         # the resident loop and the legacy host loop must report the
         # SAME counterexample (rows ride the device ring vs per-level
         # host pulls — one provenance contract)
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         r_res = MeshExplorer(load("pcal_intro_buggy"),
                              exchange="a2a").run()
         os.environ["JAXMC_MESH_RESIDENT"] = "0"
@@ -221,7 +221,7 @@ def _store_occupancy(model):
     """Distinct dedup keys the host_seen engine's native store ends
     with (`len(store)`): a count that shares nothing with
     bfs._rank_merge or the mesh's seen shards."""
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     ex = TpuExplorer(model, host_seen=True)
     r = ex.run()
     return r, ex._fp_occupancy
@@ -241,7 +241,7 @@ class TestMergeStrategies:
         counted (distinct), so the two numbers differ and a stale or
         re-counted shard tail shows."""
         from jaxmc.engine.explore import Explorer
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         ri = Explorer(load("constoy")).run()
         rh, occ = _store_occupancy(load("constoy"))
         me = MeshExplorer(load("constoy"), mesh=meshd(D))
@@ -263,7 +263,7 @@ class TestMergeStrategies:
         from jaxmc.engine.explore import Explorer
         from jaxmc.sem.enumerate import (enumerate_init, enumerate_next,
                                          label_str)
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         model = load("pcal_intro_buggy")
         ri = Explorer(model).run()
         r = MeshExplorer(load("pcal_intro_buggy"), exchange="a2a").run()
@@ -293,7 +293,7 @@ class TestMergeStrategies:
         key basis (cfg VIEW lanes / orbit-canonical packing) must
         dedup in the shards as it does in the store."""
         from jaxmc.engine.explore import Explorer
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         for name, kw in (("viewtoy", {}),
                          ("symtoy", dict(no_deadlock=True))):
             ri = Explorer(load(name, **kw)).run()
@@ -312,7 +312,7 @@ class TestMergeStrategies:
         the spill pass and the most imbalanced merge inputs."""
         from jaxmc import faults
         from jaxmc.engine.explore import Explorer
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         ri = Explorer(load("constoy")).run()
         _, occ = _store_occupancy(load("constoy"))
         monkeypatch.setenv("JAXMC_FAULTS", "mesh_skew:n=1")
@@ -331,7 +331,7 @@ class TestSuperstep:
 
     def test_superstep_vs_one_level_violation_parity(self,
                                                      monkeypatch):
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         monkeypatch.setenv("JAXMC_MESH_SUPERSTEP", "8")
         rs = MeshExplorer(load("pcal_intro_buggy"),
                           exchange="a2a").run()
@@ -354,7 +354,7 @@ class TestSuperstep:
         # and redo with counts/trace identical to a generously-capped
         # run
         from jaxmc import obs
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         monkeypatch.setenv("JAXMC_MESH_SUPERSTEP", "8")
         tel = obs.Telemetry()
         with obs.use(tel):
@@ -379,7 +379,7 @@ class TestSuperstep:
         # superstep boundary, checkpoint, report drained=True — and a
         # resume must answer bit-identically to an uninterrupted run
         from jaxmc import drain, obs
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         monkeypatch.setenv("JAXMC_MESH_SUPERSTEP", "2")
         ck = str(tmp_path / "mesh_drain.ck")
 
@@ -413,7 +413,7 @@ class TestSuperstep:
 
 class TestCheckpointResume:
     def test_truncate_resume_parity_a2a_d4(self, tmp_path):
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         ck = str(tmp_path / "mesh.ck")
         r1 = MeshExplorer(load("pcal_intro_buggy"), mesh=mesh4(),
                           exchange="a2a", max_states=20,
@@ -435,7 +435,7 @@ class TestCheckpointResume:
         # engine's level boundary), resume from its checkpoint, and
         # require bit-identical totals + trace vs an uninterrupted run
         from jaxmc import faults
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         ck = str(tmp_path / "mesh_kill.ck")
         code = f"""
 import os
@@ -447,7 +447,7 @@ import sys
 sys.path.insert(0, {REPO!r})
 from jaxmc.front.cfg import ModelConfig
 from jaxmc.sem.modules import Loader, bind_model
-from jaxmc.tpu.mesh import MeshExplorer
+from jaxmc.backend.mesh import MeshExplorer
 m = bind_model(Loader([{SPECS!r}]).load_path(
     os.path.join({SPECS!r}, "pcal_intro_buggy.tla")),
     ModelConfig(specification="Spec"))
@@ -479,7 +479,7 @@ class TestForcedSpill:
         from jaxmc import faults
         monkeypatch.setenv("JAXMC_FAULTS", "mesh_skew")
         faults.reset_for_tests()
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         from jaxmc.engine.explore import Explorer
         ri = Explorer(load("constoy")).run()
         me = MeshExplorer(load("constoy"), exchange="a2a")
@@ -502,7 +502,7 @@ class TestForcedSpill:
         # ONE-CHIP engine, which routes nothing
         from jaxmc import faults, obs
         from jaxmc.backend.bfs import TpuExplorer
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         r0 = TpuExplorer(load("pcal_intro_buggy")).run()
         monkeypatch.setenv("JAXMC_FAULTS", "mesh_skew:n=3")
         faults.reset_for_tests()
@@ -545,7 +545,7 @@ class TestEdgeStream:
         # would silently skip refinement/liveness checks on them)
         import time as _t
         import jax.numpy as jnp
-        from jaxmc.tpu.mesh import MeshExplorer
+        from jaxmc.backend.mesh import MeshExplorer
         me = MeshExplorer(load("viewtoy_scaled"), exchange="gather")
         me.collect_edges = True   # forces the edge-stream outputs
         init_rows, explored, n_init, err = me._prepare_init(
@@ -563,31 +563,46 @@ class TestEdgeStream:
         assert int(eexp0.sum()) == tot_gen
 
 
-class TestMeshbenchChild:
-    def test_child_leg_constoy_d2(self, tmp_path):
-        out = str(tmp_path / "leg.json")
-        env = dict(os.environ, PYTHONPATH=REPO,
-                   JAXMC_PROFILE_STORE=str(tmp_path / "prof"))
-        p = subprocess.run(
-            [sys.executable, "-m", "jaxmc.meshbench", "child",
-             "--spec", "specs/constoy.tla", "--devices", "2",
-             "--timed", "--metrics-out", out],
-            capture_output=True, text=True, cwd=REPO, env=env,
-            timeout=600)
-        assert p.returncode == 0, p.stderr[-800:]
-        line = [l for l in p.stdout.splitlines()
-                if l.startswith("MESHBENCH_RESULT ")][0]
-        r = json.loads(line[len("MESHBENCH_RESULT "):])
-        assert r["ok"] and r["devices"] == 2
-        assert (r["generated"], r["distinct"]) == (43, 21)
-        assert r["window_recompiles"] == 0       # warm timed window
+class TestWarmSessionLegD2:
+    def test_warm_leg_constoy_d2(self, tmp_path):
+        # the warm-window leg the deleted mesh harness ran in a child
+        # process (ISSUE 43), on the normal path: `SessionConfig(devices=2)`
+        # twice on conftest's virtual devices; the second search is the
+        # window
+        from jaxmc import obs
+        from jaxmc.session import CheckSession, SessionConfig
+        tel = obs.Telemetry()
+        with obs.use(tel):
+            sess = CheckSession(SessionConfig(
+                spec=os.path.join(SPECS, "constoy.tla"), backend="jax",
+                platform="cpu", devices=2), tel=tel)
+            sess.explore()
+            site = tel.prof.sites["mesh.superstep"]
+            lvl0, sync0, disp0 = (len(tel.levels),
+                                  tel.counters["mesh.host_syncs"],
+                                  site.dispatches)
+            r = sess.explore()
+        levels = len(tel.levels) - lvl0
+        host_syncs = tel.counters["mesh.host_syncs"] - sync0
+        supersteps = site.dispatches - disp0
+        assert r.ok and sess.engine.D == 2
+        assert tel.gauges["mesh.devices"] == 2
+        assert (r.generated, r.distinct) == (43, 21)
+        # warm window: no program compiled inside it
+        assert not any(lv.get("fresh_compile")
+                       for lv in tel.levels[lvl0:])
         # scalar-ring reads only: one per superstep, never more than
         # the level count — and the warm window (learned MSL) must
         # actually fuse levels
-        assert r["supersteps"] == r["host_syncs"] <= r["levels"]
-        assert r["host_syncs"] < r["levels"]
-        assert r["exchange"] == "a2a"
+        assert supersteps == host_syncs <= levels
+        assert host_syncs < levels
+        assert sess.engine.exchange == "a2a"
+        assert tel.gauges["mesh.exchange"] == "a2a"
+        out = str(tmp_path / "leg.json")
+        tel.write_metrics(out, result={
+            "ok": bool(r.ok), "distinct": int(r.distinct),
+            "generated": int(r.generated)})
         art = json.load(open(out))
         assert art["schema"] == "jaxmc.metrics/4"
-        assert art["multichip"]["devices"] == 2
-        assert art["multichip"]["supersteps"] == r["supersteps"]
+        assert art["gauges"]["mesh.devices"] == 2
+        assert art["counters"]["mesh.host_syncs"] == sync0 + supersteps

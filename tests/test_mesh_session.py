@@ -99,6 +99,41 @@ def test_mesh_session_equals_reference_and_resident(size, seed, tmp_path,
     assert mesh.layout_sig == res.layout_sig
 
 
+#: per-shard capacity records for the two bench-scale fixtures, as measured
+#: at D=4 on virtual CPU devices (SC grows 256 -> 65536 over nine recompiles
+#: without one); powers of two, good for D=2 as well
+BENCH_RUNGS = {
+    "viewtoy_scaled": {"SC": 1 << 16, "FC": 1 << 11, "TRL": 32,
+                       "GAM16": 32, "MSL": 32},
+    "symtoy_scaled": {"SC": 1 << 15, "FC": 1 << 11, "TRL": 32,
+                      "GAM16": 32, "MSL": 32},
+}
+
+
+@pytest.mark.parametrize("devices", [2, 4])
+@pytest.mark.parametrize("name", sorted(BENCH_RUNGS))
+def test_view_and_symmetry_at_bench_scale_meet_the_pins(name, devices):
+    """cfg VIEW and cfg SYMMETRY at bench scale on the sharded engine: the
+    manifest's counts (the interpreter's:
+    `tests/test_corpus.py::test_corpus_case`) at D=2 and D=4, and never
+    more scalar-ring reads than levels — a sync per level or fewer, no row
+    traffic in the level loop (the parity legs of the deleted mesh harness,
+    ISSUE 43)."""
+    from jaxmc.corpus import case_for_cfg
+    case = case_for_cfg(name + ".cfg")
+    tel = obs.Telemetry()
+    with obs.use(tel):
+        sess = _session(case.spec_path(), case.cfg_path(), tel,
+                        devices=devices, no_trace=True,
+                        no_deadlock=case.no_deadlock,
+                        res_caps=dict(BENCH_RUNGS[name]))
+        res = sess.explore()
+    assert res.ok and not res.truncated
+    assert (res.generated, res.distinct) == (case.generated, case.distinct)
+    assert sess.engine.D == devices and sess.engine.exchange == "a2a"
+    assert 1 <= tel.counters["mesh.host_syncs"] <= len(tel.levels)
+
+
 def test_explore_again_is_a_warm_rerun(tmp_path):
     cfg = tmp_path / "t.cfg"
     cfg.write_text(_cfg_text(2, 3, 0))

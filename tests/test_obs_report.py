@@ -141,56 +141,6 @@ class TestDiff:
         for label in ("a", "r07", "c"):
             assert label in out
 
-    def test_multichip_artifact_diff_gates_per_chip_rate(
-            self, tmp_path):
-        # ISSUE 10 CI satellite: two jaxmc.multichip/1 scaling
-        # artifacts diff directly — per-(rung, D) states/sec/chip
-        # drops raise REGRESS and gate the exit code
-        def art(path, rate):
-            obj = {"schema": "jaxmc.multichip/1", "platform": "cpu",
-                   "mode": "mesh-resident", "ok": True,
-                   "rungs": [{"rung": "toy", "curve": [
-                       {"devices": 2, "states_per_sec_per_chip": rate,
-                        "host_syncs": 3, "levels": 6,
-                        "merge": "rank"}]}]}
-            p = str(tmp_path / path)
-            json.dump(obj, open(p, "w"))
-            return p
-        a, b = art("r06.json", 1000.0), art("r07.json", 400.0)
-        rc, out = run_cli(["diff", "--fail-on-regress",
-                           "--threshold", "25", a, b])
-        assert rc == 1 and "REGRESS states/sec/chip toy@D2" in out
-        rc, out = run_cli(["diff", "--fail-on-regress", a, a])
-        assert rc == 0 and "no regressions" in out
-        rc, out = run_cli(["report", a])
-        assert rc == 0 and "toy@D2" in out and "syncs=3/6" in out
-
-    def test_multichip_regress_attributes_platform_swap(self,
-                                                        tmp_path):
-        # ISSUE 11 satellite: a backend swap between two multichip
-        # artifacts must read as an ATTRIBUTED environment change
-        # alongside the REGRESS, not an unexplained drop (the platform
-        # lives top-level in the artifact, env.platform is None)
-        def art(path, rate, platform):
-            obj = {"schema": "jaxmc.multichip/1", "platform": platform,
-                   "mode": "mesh-resident", "ok": True,
-                   "env": {"jax_version": "0.4.37", "platform": None,
-                           "device_count": None},
-                   "rungs": [{"rung": "toy", "curve": [
-                       {"devices": 2, "states_per_sec_per_chip": rate,
-                        "host_syncs": 3, "levels": 6,
-                        "merge": "rank"}]}]}
-            p = str(tmp_path / path)
-            json.dump(obj, open(p, "w"))
-            return p
-        a = art("r07.json", 9000.0, "tpu")
-        b = art("r08.json", 1000.0, "cpu")
-        rc, out = run_cli(["diff", "--fail-on-regress", a, b])
-        assert rc == 1
-        assert "REGRESS states/sec/chip toy@D2" in out
-        assert "environment changed" in out
-        assert "platform: tpu -> cpu" in out
-
     def test_metrics_regress_attributes_platform_swap(self, tmp_path):
         # same attribution on plain metrics artifacts whose env block
         # predates the platform field (env.platform None, platform
@@ -214,73 +164,6 @@ class TestDiff:
         a = mk_artifact(tmp_path / "a.json", rate=1000.0,
                         platform="cpu", phases={"search": 1.0})
         assert report.main(["diff", a]) == 2
-
-
-class TestPhaseWallsParsing:
-    """`phase_walls` rows in multichip artifacts (ISSUE 11 satellite;
-    since PR 28 only the committed MULTICHIP_r07/r08.json carry them —
-    the probe that wrote them is gone, the reader stays): missing-phase
-    and malformed rows render instead of crashing, and the hot-share
-    acceptance metric surfaces when the probe timed the fused step."""
-
-    def art(self, tmp_path, name, pw):
-        obj = {"schema": "jaxmc.multichip/1", "platform": "cpu",
-               "mode": "mesh-resident", "ok": True,
-               "rungs": [{"rung": "toy", "curve": [
-                   {"devices": 2, "states_per_sec_per_chip": 1000.0,
-                    "host_syncs": 3, "levels": 6, "merge": "rank",
-                    "phase_walls": pw}]}]}
-        p = str(tmp_path / name)
-        json.dump(obj, open(p, "w"))
-        return p
-
-    def test_full_row_renders_hot_share(self, tmp_path):
-        p = self.art(tmp_path, "full.json",
-                     {"levels": 4, "expand_s": 1.0, "exchange_s": 0.1,
-                      "merge_rank_s": 2.0, "merge_fullsort_s": 3.5,
-                      "merge_s": 2.0, "step_levels": 4,
-                      "step_s": 12.0, "hot_share": 0.25})
-        rc, out = run_cli(["report", p])
-        assert rc == 0
-        assert "merge(rank)=2.0s" in out
-        assert "merge(fullsort)=3.5s" in out
-        assert "hot_share=25%" in out and "step=12.0s" in out
-
-    def test_missing_phase_rows_render_dashes(self, tmp_path):
-        # a probe that outgrew its caps before timing the fused step
-        # reports only what it measured — older artifacts (r07) also
-        # lack step_s/hot_share entirely
-        p = self.art(tmp_path, "partial.json", {"expand_s": 1.0})
-        rc, out = run_cli(["report", p])
-        assert rc == 0
-        assert "expand=1.0s" in out
-        assert "merge(rank)=-s" in out
-        assert "hot_share" not in out
-
-    def test_malformed_row_named_not_fatal(self, tmp_path):
-        for bad, tname in ((["not", "a", "dict"], "list"),
-                           ("walls", "str"), (3.5, "float")):
-            p = self.art(tmp_path, f"bad_{tname}.json", bad)
-            rc, out = run_cli(["report", p])
-            assert rc == 0, out
-            assert f"walls=(malformed: {tname})" in out
-
-    def test_absent_row_is_silent(self, tmp_path):
-        p = self.art(tmp_path, "none.json", None)
-        rc, out = run_cli(["report", p])
-        assert rc == 0
-        assert "walls" not in out
-
-    def test_repo_r07_artifact_renders(self):
-        # the committed scaling artifact keeps parsing as the schema
-        # grows fields
-        r07 = os.path.join(REPO, "MULTICHIP_r07.json")
-        if not os.path.exists(r07):
-            pytest.skip("MULTICHIP_r07.json not present")
-        rc, out = run_cli(["report", r07])
-        assert rc == 0
-        assert "transfer_scaled@D1" in out
-        assert "merge(rank)=" in out
 
 
 class TestOracleHighlights:

@@ -167,7 +167,7 @@ def test_pack_overflow_guard_raises():
 # ---------------------------------------------------------------- layer 3
 
 def _device_counts(name, mode, env):
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     kw = dict(store_trace=mode != "resident")
     if mode == "resident":
         kw["resident"] = True
@@ -219,7 +219,7 @@ def test_trace_parity_packed_vs_unpacked_vs_interp():
     equally-short counterexample with identical counts (the two engines
     legitimately tie-break equal-depth candidates differently — a
     pre-existing, disclosed difference independent of packing)."""
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     spec = os.path.join(SPECS, "pcal_intro_buggy.tla")
     from jaxmc.front.cfg import ModelConfig
 
@@ -258,7 +258,7 @@ def test_symmetry_composes_with_view(tmp_path):
     orbit's CANONICAL representative (the interp's state_fingerprint
     order), or symmetric states count as distinct — the review repro
     that caught the original view-of-raw-row keying."""
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     spec = tmp_path / "symview.tla"
     spec.write_text("""---- MODULE symview ----
 EXTENDS Naturals, FiniteSets, TLC
@@ -297,7 +297,7 @@ def test_mesh_packed_rows_survive_sharded_path(exchange):
     exchanges PACKED candidate rows (a2a payloads shrink to K+PW+1
     words) and still produces interp-identical counts — repo-local, so
     the sharded path stays covered without the reference tree."""
-    from jaxmc.tpu.mesh import MeshExplorer
+    from jaxmc.backend.mesh import MeshExplorer
     ri = Explorer(load("constoy")).run()
     me = MeshExplorer(load("constoy"), exchange=exchange,
                       store_trace=True)
@@ -311,7 +311,7 @@ def test_symtoy_trace_parity_on_violation():
     """symtoy's deadlock-with-checking-on violation: packed and
     unpacked device traces match the interpreter's (SYMMETRY canonical
     keys, original stored rows)."""
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
 
     def mk():
         m = bind_model(

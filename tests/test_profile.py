@@ -36,7 +36,7 @@ def store(tmp_path, monkeypatch):
 
 
 def _run_resident(tel=None, **kw):
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     with obs.use(tel or obs.NullTelemetry()):
         ex = TpuExplorer(load_model(), store_trace=False, resident=True,
                          **kw)
@@ -58,7 +58,7 @@ def test_profile_saved_and_drives_zero_window_recompiles(store):
     # profile; after its one warm-up run, a timed re-run must report
     # zero fresh compiles — the window_recompiles == 0 contract
     tel2 = obs.Telemetry()
-    from jaxmc.tpu.bfs import TpuExplorer
+    from jaxmc.backend.bfs import TpuExplorer
     with obs.use(tel2):
         ex2 = TpuExplorer(load_model(), store_trace=False, resident=True)
         assert tel2.gauges.get("profile.status") == "loaded"

@@ -20,6 +20,7 @@ Covers the acceptance surface end to end:
 
 import json
 import os
+import re
 import signal
 import subprocess
 import sys
@@ -477,3 +478,158 @@ class TestDeviceOwnerDefault:
         monkeypatch.setenv("JAXMC_SERVE_DEVICE_OWNER", "0")
         d = ServeDaemon(str(tmp_path / "spool"), workers=1, quiet=True)
         assert d.owner is None
+
+
+# ---- harvested from the deleted `make fleet-check` / `make trace-check`
+# ---- harnesses (ISSUE 43): what they asserted, as tier-1 tests
+
+class TestAdmission:
+    """A depth-bounded daemon under a submit burst: the overflow gets a
+    FAST 429 with `Retry-After` and the queue gauges in the body, the
+    admission counter moves, and every ACCEPTED job still completes."""
+
+    def test_burst_over_depth_bound_answers_429(self, spool, tmp_path,
+                                                monkeypatch):
+        from conftest import write_slow_spec
+        monkeypatch.setenv("JAXMC_SERVE_MAX_DEPTH", "2")
+        slow = write_slow_spec(tmp_path / "specs", "admitload",
+                               q=600, bound=16)
+        d = ServeDaemon(spool, workers=1, quiet=True).start()
+        try:
+            assert d.max_depth == 2
+            c = client(d)
+            accepted, rejected = [], []
+            for _ in range(8):
+                code, job = c.submit(slow, None, {"backend": "interp"},
+                                     tenant="burst")
+                assert code in (200, 429), (code, job)
+                if code == 200:
+                    accepted.append(job["id"])
+                else:
+                    rejected.append((dict(c.last_headers), job))
+            # one running + one pending fill the bound; the rest bounce
+            assert len(accepted) == 2 and len(rejected) == 6
+            for headers, body in rejected:
+                assert float(headers["Retry-After"]) >= 1
+                assert body["reason"] == "queue_full"
+                assert body["queue_depth"] == 2 == body["max_depth"]
+                assert body["tenant"] == "burst"
+                assert body["retry_after_s"] >= 1.0
+                assert "admission refused" in body["error"]
+            assert d.tel.counters["serve.admission_rejected"] == 6
+            assert "jaxmc_serve_admission_rejected 6" in d.metrics_text()
+            for jid in accepted:
+                done = c.wait(jid, timeout=180)
+                assert done["status"] == "done", done
+                assert done["ok"] is True
+            # the bound is on depth, not a latch: room again, 200 again
+            code, job = c.submit(slow, None, {"backend": "interp"},
+                                 tenant="burst")
+            assert code == 200, job
+            assert c.wait(job["id"], timeout=180)["status"] == "done"
+        finally:
+            d.shutdown()
+
+
+#: one Prometheus text 0.0.4 sample line: name{labels}? value
+_PROM_SAMPLE = re.compile(
+    r"^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^{}]*\})?"
+    r" -?\d+(\.\d+)?([eE][-+]?\d+)?$")
+
+
+def _prom_value(text, name, jid=None):
+    want = name + ('{job="%s"} ' % jid if jid else " ")
+    for ln in text.splitlines():
+        if ln.startswith(want):
+            return float(ln.rsplit(" ", 1)[1])
+    return None
+
+
+class TestLiveObservability:
+    """One daemon's observability surface, scraped while a job runs: a
+    slow interp job with a fork pool, its identical resubmission, and a
+    jax job in the device-owner process."""
+
+    def test_metrics_parse_progress_moves_and_timeline_stitches(
+            self, spool, tmp_path, monkeypatch):
+        import glob
+        import urllib.request
+        from conftest import timeline_counts, write_slow_spec
+        monkeypatch.setenv("JAXMC_SERVE_DEVICE_OWNER", "1")
+        monkeypatch.setenv("JAXMC_PROFILE_STORE",
+                           str(tmp_path / "profiles"))
+        monkeypatch.setenv("JAXMC_HEARTBEAT_EVERY", "2")
+        slow = write_slow_spec(tmp_path / "specs", "traceload",
+                               q=1500, bound=20)
+        opts = {"backend": "interp", "workers": 2, "progress_every": 2}
+        daemon_trace = str(tmp_path / "daemon.trace.jsonl")
+        d = ServeDaemon(spool, workers=2, trace=daemon_trace,
+                        quiet=True).start()
+
+        def scrape():
+            with urllib.request.urlopen(
+                    f"http://127.0.0.1:{d.port}/metrics",
+                    timeout=10) as resp:
+                assert "text/plain" in resp.headers.get("Content-Type")
+                return resp.read().decode()
+
+        try:
+            c = client(d)
+            code, job = c.submit(slow, None, opts)
+            assert code == 200, job
+            jid = job["id"]
+            est, bad_lines, events_midrun = [], [], False
+            deadline = time.time() + 240
+            while True:
+                _, rec = c.job(jid)
+                st = rec.get("status")
+                text = scrape()
+                bad_lines += [ln for ln in text.splitlines()
+                              if ln and not ln.startswith("#")
+                              and not _PROM_SAMPLE.match(ln)]
+                v = _prom_value(text, "jaxmc_search_progress_est", jid)
+                if v is not None and st == "running":
+                    est.append(v)
+                if not events_midrun and st == "running":
+                    ecode, ebody = c._request("GET",
+                                              f"/jobs/{jid}/events")
+                    events_midrun = ecode == 200 and \
+                        bool(ebody.get("events"))
+                if st in ("done", "failed", "drained"):
+                    break
+                assert time.time() < deadline, f"slow job still {st!r}"
+                time.sleep(0.3)
+            assert st == "done", rec
+            # every sample line of every scrape is Prometheus text
+            assert not bad_lines, bad_lines[:3]
+            # the per-job progress estimate is there and MOVES mid-run
+            assert len(set(est)) >= 2 and est[-1] > est[0], est[:8]
+            assert all(0.0 <= v <= 1.0 for v in est), est
+            # the bounded event ring answers while the job runs
+            assert events_midrun
+
+            # the identical resubmission is a warm hit
+            code, wjob = c.submit(slow, None, opts)
+            assert code == 200, wjob
+            assert c.wait(wjob["id"], timeout=240)["status"] == "done"
+            text = scrape()
+            assert _prom_value(text, "jaxmc_serve_warm_hits") >= 1
+            assert _prom_value(text, "jaxmc_serve_jobs_submitted") >= 2
+            assert _prom_value(text, "jaxmc_serve_queue_depth") is not None
+
+            # a jax job goes to the device-owner process: a third kind
+            # of OS process in the trace
+            code, ojob = c.submit(spec("constoy"), None, JAX_OPTS)
+            assert code == 200, ojob
+            orec = c.wait(ojob["id"], timeout=240)
+            assert orec["status"] == "done", orec
+            assert (orec["generated"], orec["distinct"]) == (43, 21)
+
+            # daemon + fork workers + owner: one timeline, no orphans
+            traces = [daemon_trace] + sorted(glob.glob(
+                os.path.join(spool, "results", "*.trace.jsonl")))
+            rc, counts, out = timeline_counts(traces)
+            assert rc == 0 and counts["orphans"] == 0, out[-800:]
+            assert counts["processes"] >= 3, counts
+        finally:
+            d.shutdown()

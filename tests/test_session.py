@@ -211,7 +211,7 @@ class TestFusedGroups:
 
     @pytest.mark.parametrize("name", ["constoy", "viewtoy"])
     def test_grouped_counts_match_interp(self, name, monkeypatch):
-        from jaxmc.tpu.bfs import TpuExplorer
+        from jaxmc.backend.bfs import TpuExplorer
         # cap 1 instance per fused group: every action becomes its own
         # fused group, the maximal split — counts must not move
         monkeypatch.setenv("JAXMC_FUSED_MAX_INSTANCES", "1")

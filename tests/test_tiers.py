@@ -415,6 +415,50 @@ class TestCappedExhaustive:
         assert (res.generated, res.distinct) == OOC_WANT
         assert res.tiers and res.tiers["spills"] > 0
 
+    def test_capped_counterexample_byte_identical(self, tmp_path):
+        # the violation rung (ooc_scaled_bad.cfg, NoMeet): the capped run
+        # spills before it reaches the violation, and the counterexample it
+        # renders is the uncapped run's, byte for byte (the deleted `make
+        # ooc-check` leg 4, ISSUE 43)
+        from jaxmc.backend.bfs import TpuExplorer
+        from jaxmc.engine.explore import format_trace
+        plain = TpuExplorer(load("ooc_scaled", "ooc_scaled_bad")).run()
+        capped = TpuExplorer(load("ooc_scaled", "ooc_scaled_bad"),
+                             **_capped_kw(tmp_path)).run()
+        assert plain.tiers is None
+        assert capped.tiers and capped.tiers["spills"] > 0
+        for res in (plain, capped):
+            assert not res.ok and res.violation.kind == "invariant"
+            assert res.violation.name == "NoMeet"
+        assert (capped.generated, capped.distinct, capped.diameter) == \
+            (plain.generated, plain.distinct, plain.diameter)
+        text = format_trace(plain.violation)
+        assert text.startswith("Error: Invariant NoMeet is violated.")
+        assert len(text.splitlines()) > 20
+        assert format_trace(capped.violation) == text
+
+    def test_capped_fingerprint_parity_and_key_words_ratio(self, tmp_path):
+        # --seen fingerprint under the same cap (leg 3 of the deleted `make
+        # ooc-check`): the manifest pins through both cold tiers, a
+        # collision probability in the result, and >= 4x the states a tier
+        # row holds (exact key words over fingerprint key words)
+        from jaxmc.backend.bfs import TpuExplorer
+        fp = TpuExplorer(load("ooc_scaled"), seen_mode="fingerprint",
+                         **_capped_kw(tmp_path))
+        res = fp.run()
+        assert res.ok and not res.truncated
+        assert (res.generated, res.distinct) == OOC_WANT
+        assert res.seen_mode == "fingerprint"
+        assert res.collision_p is not None and 0 < res.collision_p < 1e-20
+        assert res.tiers["spills"] > 0 and res.tiers["disk_keys"] > 0
+        exact = TpuExplorer(load("ooc_scaled"), seen_mode="exact")
+        assert exact.K == exact.PW + 1 and fp.K == 5
+        assert exact.K / fp.K >= 4.0
+        # ... and the uncapped exact run is what both are held to
+        plain = exact.run()
+        assert (plain.generated, plain.distinct) == OOC_WANT
+        assert plain.seen_mode == "exact" and plain.tiers is None
+
     def test_engine_io_degrade_keeps_exact_counts(self, tmp_path,
                                                   monkeypatch):
         # end-to-end fault containment: the disk tier dies mid-search,

@@ -19,7 +19,7 @@ jax.config.update("jax_platforms", "cpu")
 
 from jaxmc.front.cfg import parse_cfg  # noqa: E402
 from jaxmc.sem.modules import Loader, bind_model  # noqa: E402
-from jaxmc.tpu.bfs import TpuExplorer  # noqa: E402
+from jaxmc.backend.bfs import TpuExplorer  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SPECS = os.path.join(REPO, "specs")
