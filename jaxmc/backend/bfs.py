@@ -17,7 +17,10 @@ Capacities are power-of-two buckets that grow on demand, so jit recompiles
 O(log N) times; all shapes inside a step are static (XLA/TPU requirement).
 Parent provenance rides the sorts as a non-key operand and is streamed to
 host per level for counterexample reconstruction — disable with
-store_trace=False for benchmark runs.
+store_trace=False for benchmark runs.  The resident engine carries no
+provenance through its sorts: with store_trace it logs each level's new
+frontier rows on the device and, at a violation, one more dispatch walks
+the log back by re-expansion (`_make_trace_walk`).
 """
 
 from __future__ import annotations
@@ -68,6 +71,7 @@ ST_OVF_FRONT = 7    # frontier capacity: grow FCap, redo level
 ST_OVF_ACC = 8      # level-accumulator capacity: grow AccCap, redo level
 ST_OVF_VC = 9       # per-chunk valid-candidate capacity: grow VC, redo level
 ST_OVF_LANES = 10   # a container outgrew its lane capacity: hard abort
+ST_OVF_LOG = 11     # state-log capacity (traces kept): grow LogCap, redo level
 
 SYMMETRY_WARNING = (
     "cfg SYMMETRY NOT applied on the jax backend: counts are "
@@ -1376,6 +1380,7 @@ class TpuExplorer:
             int, Tuple[List[Callable], List[np.ndarray]]] = {}
         self._newcheck_cache: Dict[int, Callable] = {}
         self._res_cache: Dict[Tuple[int, ...], Callable] = {}
+        self._walk_cache: Dict[Tuple[int, int, int], Callable] = {}
         self._hostkeys_cache: Dict[int, Callable] = {}
         self._pkeys_cache: Dict[int, Callable] = {}
         # capacities learned by previous resident runs on this instance:
@@ -1399,7 +1404,6 @@ class TpuExplorer:
                     "resident mode cannot check temporal properties "
                     "(the behavior graph stays on device) - use the "
                     "level/host_seen device modes")
-            self.store_trace = False
             # resident dedup keys are always 128-bit fingerprints: the
             # rank-merge binary search and the LSD key sorts are built
             # for a fixed 4-word key
@@ -1481,7 +1485,7 @@ class TpuExplorer:
             prof = load_capacity_profile(
                 model.module.name, self._layout_sig(), tel=tel,
                 variant=self.backend_desc.profile_variant(),
-                optional=("TIERK",))
+                optional=("TIERK", "LogCap"))
             if not prof and not self._res_caps_hint:
                 # PREDICTED capacity rung (ISSUE 15, below `learned`):
                 # a converged bounds fixpoint proves a state-count
@@ -1779,16 +1783,18 @@ class TpuExplorer:
         self.init_states = enumerate_init(model.init, base_ctx,
                                           model.vars)
 
-    def _expand_fn(self):
+    def _expand_fn(self, scope: str = "jaxmc.expand"):
         """The (state x action) expansion closure shared by both step
-        builders; slotted kernels vmap over a traced slot index."""
+        builders; slotted kernels vmap over a traced slot index.
+        `scope` names its device operations in a trace (the trace walk
+        re-expands under its own name: a reader takes the innermost)."""
         acts = self.compiled
         if not acts:
             # hybrid with every arm demoted: a zero-instance expansion
             # (jnp.stack refuses empty lists; shapes stay [0, FC(, W)])
             W = self.W
 
-            @jax.named_scope("jaxmc.expand")
+            @jax.named_scope(scope)
             def expand_none(frontier):
                 FC = frontier.shape[0]
                 z = jnp.zeros((0, FC), bool)
@@ -1798,7 +1804,7 @@ class TpuExplorer:
 
             return expand_none
 
-        @jax.named_scope("jaxmc.expand")
+        @jax.named_scope(scope)
         def expand(frontier):
             ens, aoks, ovs, succs = [], [], [], []
             for ca in acts:
@@ -2660,12 +2666,16 @@ class TpuExplorer:
     # pre-level state) and report a grow-and-redo status, so counts stay
     # exact across regrowth.
 
-    def _get_resident_run(self, SC, FCap, AccCap, VC, CH):
+    def _get_resident_run(self, SC, FCap, AccCap, VC, CH, LogCap=0,
+                          LV=0):
         # maxlvl (levels per dispatch) is a TRACED argument, not part of
         # the compile key: the host adapts it to measured dispatch wall
         # time (so --checkpoint/--progress-every fire at useful
-        # intervals, advisor r2) without recompiling
-        key = (SC, FCap, AccCap, VC, CH)
+        # intervals, advisor r2) without recompiling.  LogCap > 0 is
+        # the program that keeps the state log (ISSUE 44): a key of its
+        # own (with LV, the levels a dispatch may run, which sizes its
+        # row counts), the untraced program's stays what it was
+        key = (SC, FCap, AccCap, VC, CH) + ((LogCap, LV) if LogCap else ())
         if key in self._res_cache:
             obs.current().counter("compile.cache_hits")
             return self._res_cache[key]
@@ -2678,8 +2688,9 @@ class TpuExplorer:
         self._res_cache[key] = jitted
         return jitted
 
-    def _make_resident_run(self, SC, FCap, AccCap, VC, CH):
-        key = (SC, FCap, AccCap, VC, CH)
+    def _make_resident_run(self, SC, FCap, AccCap, VC, CH, LogCap=0,
+                           LV=0):
+        key = (SC, FCap, AccCap, VC, CH) + ((LogCap, LV) if LogCap else ())
         A, W, K, PW = self.A, self.W, self.K, self.PW
         plan = self.plan
         C = A * CH
@@ -2935,17 +2946,23 @@ class TpuExplorer:
                     rm["sort_slots"] // _sort_unit(AccCap))
 
         def run(seen, seen_count, frontier, fcount, distinct,
-                gen_lo, gen_hi, depth, max_states, maxlvl):
+                gen_lo, gen_hi, depth, max_states, maxlvl, *logged):
+            # `logged` (LogCap > 0 alone): the state log [LogCap + FCap,
+            # PW] and the rows it holds.  Every level that the search
+            # goes on from appends its new frontier — the rows level()
+            # hands back — as one block at log_n; the FCap rows past
+            # LogCap are there so that the block is never clamped into
+            # rows written before.  The carry's last three are the log,
+            # log_n and the rows each of this dispatch's levels added.
             def cond(carry):
-                (_, _, _, _, _, _, _, _, lvls, stat, _, _, _,
-                 _, _, _, _, _, _) = carry
+                lvls, stat = carry[8], carry[9]
                 return (stat == ST_CONTINUE) & (lvls < maxlvl)
 
             def body(carry):
                 (seen, seen_count, frontier, fcount, distinct,
                  gen_lo, gen_hi, depth, lvls, stat, which, brow,
                  ovcode, pora, porx, porm, pblocks, mblocks,
-                 sunits) = carry
+                 sunits) = carry[:19]
                 (seen2, seen_count2, front2, fcount2, gen_l, kept,
                  lstat, lwhich, lbrow, lovcode, lpora, lporx,
                  lporm, lpblocks, lmblocks, lsunits) = level(
@@ -2953,6 +2970,23 @@ class TpuExplorer:
                 ovf = (lstat == ST_OVF_SEEN) | (lstat == ST_OVF_FRONT) | \
                     (lstat == ST_OVF_ACC) | (lstat == ST_OVF_VC) | \
                     (lstat == ST_OVF_LANES)
+                logged2 = ()
+                if LogCap:
+                    log, log_n, lvl_rows = carry[19:]
+                    with jax.named_scope("jaxmc.trace.log"):
+                        # a level that ends the search in a verdict is
+                        # not logged: the walk reads the levels BEFORE
+                        # the bad row's
+                        lstat = jnp.where(
+                            (lstat == ST_CONTINUE) &
+                            (log_n + fcount2 > LogCap), ST_OVF_LOG, lstat)
+                        ovf = ovf | (lstat == ST_OVF_LOG)
+                        keep = lstat == ST_CONTINUE
+                        log2 = lax.dynamic_update_slice(
+                            log, front2, (log_n, jnp.int32(0)))
+                        added = jnp.where(keep, fcount2, 0)
+                        logged2 = (log2, log_n + added,
+                                   lvl_rows.at[lvls].set(added))
                 # overflow rolls the whole level back (growable caps are
                 # redone after growth; lane overflow aborts with the
                 # last completed level's exact counts).  The select
@@ -2999,7 +3033,7 @@ class TpuExplorer:
                         # sorted its rung and searched and built its
                         # blocks too
                         pblocks + lpblocks, mblocks + lmblocks,
-                        sunits + lsunits)
+                        sunits + lsunits) + logged2
 
             carry0 = (seen, seen_count, frontier, fcount, distinct,
                       gen_lo, gen_hi, depth, jnp.int32(0),
@@ -3008,10 +3042,12 @@ class TpuExplorer:
                       jnp.int32(0), jnp.int32(0), jnp.int32(0),
                       jnp.int32(0), jnp.int32(0), jnp.int32(0),
                       jnp.int32(0))
+            if LogCap:
+                carry0 += logged + (jnp.zeros((LV,), jnp.int32),)
+            out = lax.while_loop(cond, body, carry0)
             (seen, seen_count, frontier, fcount, distinct, gen_lo,
              gen_hi, depth, _, stat, which, brow, ovcode, pora, porx,
-             porm, pblocks, mblocks, sunits) = \
-                lax.while_loop(cond, body, carry0)
+             porm, pblocks, mblocks, sunits) = out[:19]
             # indices 0-8 are the PR-6 summary; 9-11 are the per-
             # dispatch POR counters (ISSUE 18; zero when POR is off);
             # 12 is the query blocks the merge's probe searched over
@@ -3024,15 +3060,148 @@ class TpuExplorer:
                                  gen_lo, gen_hi, depth, which, ovcode,
                                  pora, porx, porm, pblocks, mblocks,
                                  sunits])
+            if LogCap:
+                # ... and, where the log is kept, 15 is the rows it
+                # holds and 16.. the rows each level of the dispatch
+                # added (the host keeps the levels' offsets from them):
+                # one block, one fetch
+                log, log_n, lvl_rows = out[19:]
+                summary = jnp.concatenate([summary, log_n[None],
+                                           lvl_rows])
+                return seen, frontier, summary, brow, log
             return seen, frontier, summary, brow
 
         # DONATED dispatch (ISSUE 6): the seen table (arg 0) and the
         # packed frontier (arg 2) — the two big device buffers — update
-        # in place across dispatches instead of copying per batch
-        donate = (0, 2) if self.donate else ()
+        # in place across dispatches instead of copying per batch (the
+        # state log, arg 10, with them)
+        donate = ((0, 2, 10) if LogCap else (0, 2)) if self.donate else ()
         return obs.prof_wrap("bfs.resident_run", jax.jit(
             run, static_argnames=(), donate_argnums=donate), key=key)
 
+    def _get_trace_walk(self, LP, WCH, LMAX):
+        key = (LP, WCH, LMAX)
+        walk = self._walk_cache.get(key)
+        if walk is None:
+            walk = self._walk_cache[key] = self._held_program(
+                "bfs.trace_walk", key,
+                lambda: self._make_trace_walk(*key))
+        return walk
+
+    def _make_trace_walk(self, LP, WCH, LMAX):
+        """The backward walk over the resident engine's state log
+        (ISSUE 44), TLC's own way to an error trace: nothing of a path
+        is kept during the search, it is generated again.  `log`
+        [LP, PW] holds levels 0..depth-1 one after another, level l at
+        offs[l]:offs[l+1]; `target` is the bad row, at level `depth`.
+        For l = depth-1 .. 0 the logged level is expanded again in
+        chunks of WCH rows with the search's own compiled arms, every
+        successor is packed and compared with the target word for word
+        (the stored form: under SYMMETRY or a VIEW the key is of the
+        canonical row or the view, the stored row the state itself),
+        and the lowest (slot, action) that matches becomes the target.
+        A level stops at the first chunk that holds a match.  One block
+        comes back, [LMAX + 2, PW + 1]: row l the state at level l with
+        the action that led TO it in the last column (-1 at level 0),
+        and in the last row the log rows expanded and whether every
+        level found its parent."""
+        A, W, PW = self.A, self.W, self.PW
+        plan = self.plan
+        C = A * WCH
+        expand = self._expand_fn(scope="jaxmc.trace.walk")
+
+        @jax.named_scope("jaxmc.trace.walk")
+        def walk(log, offs, depth, target):
+            def chunk_cond(c):
+                ci, found = c[0], c[1]
+                return (ci < c[4]) & ~found
+
+            def chunk_body(c):
+                ci, _, prow, act, nchunks, start, n, tgt = c
+                base = ci * WCH
+                chunk_p = lax.dynamic_slice(log, (start + base, 0),
+                                            (WCH, PW))
+                fvalid = (jnp.arange(WCH) + base) < n
+                en, _, _, succ = expand(plan.unpack_rows(chunk_p))
+                packed, _ = plan.pack_rows(succ.reshape(C, W))
+                hit = jnp.all(packed == tgt[None, :], axis=1) \
+                    .reshape(A, WCH) & en & fvalid[None, :]
+                flat = hit.T.reshape(-1)       # slot-major: lowest slot,
+                j = jnp.argmax(flat).astype(jnp.int32)    # then action
+                found = jnp.any(flat)
+                row = lax.dynamic_slice(chunk_p, (j // A, 0), (1, PW))[0]
+                return (ci + 1, found, jnp.where(found, row, prow),
+                        jnp.where(found, j % A, act), nchunks, start, n,
+                        tgt)
+
+            def level_cond(c):
+                return (c[0] >= 0) & c[4]
+
+            def level_body(c):
+                lvl, tgt, block, nexp, _ = c
+                start = offs[lvl]
+                n = offs[lvl + 1] - start
+                ci, found, prow, act, *_ = lax.while_loop(
+                    chunk_cond, chunk_body,
+                    (jnp.int32(0), jnp.asarray(False), tgt,
+                     jnp.int32(-1), (n + WCH - 1) // WCH, start, n, tgt))
+                block = lax.dynamic_update_slice(
+                    block, prow[None, :], (lvl, 0))
+                block = lax.dynamic_update_slice(
+                    block, act[None, None], (lvl + 1, PW))
+                return (lvl - 1, prow, block,
+                        nexp + jnp.minimum(ci * WCH, n), found)
+
+            block = jnp.full((LMAX + 2, PW + 1), -1, jnp.int32)
+            block = lax.dynamic_update_slice(
+                block, target[None, :], (depth, 0))
+            _, _, block, nexp, ok = lax.while_loop(
+                level_cond, level_body,
+                (depth - 1, target, block, jnp.int32(0),
+                 jnp.asarray(True)))
+            tail = jnp.full((PW + 1,), -1, jnp.int32) \
+                .at[0].set(nexp).at[1].set(ok.astype(jnp.int32))
+            return lax.dynamic_update_slice(block, tail[None, :],
+                                            (LMAX + 1, 0))
+
+        return obs.prof_wrap("bfs.trace_walk", jax.jit(walk),
+                             key=(LP, WCH, LMAX))
+
+    def _count_logged(self, rows: int) -> None:
+        """Rows the search appended to its state log, and their bytes."""
+        tel = obs.current()
+        tel.counter("search.log_rows", rows)
+        tel.counter("search.log_bytes", 4 * self.PW * rows)
+
+    def _walk_trace(self, log, lvl_off, depth: int, brow, FCap: int,
+                    CH: int):
+        """The counterexample behind `brow`, found at level `depth` of
+        a resident search that kept its log: ONE dispatch (the walk),
+        one fetch, then the decode — `_trace_to`'s form, a list of
+        (state, label) from "Initial predicate" on.  None where a
+        level held no parent of its target (never, on a log the search
+        wrote: said loudly by the caller, not papered over)."""
+        tel = obs.current()
+        with tel.span("search.trace", depth=depth):
+            with tel.span("trace.walk"):
+                LMAX = _pow2_at_least(depth + 1, lo=8)
+                offs = np.zeros(LMAX + 1, np.int32)
+                offs[:depth + 1] = lvl_off[:depth + 1]
+                walk = self._get_trace_walk(int(log.shape[0]),
+                                            min(8 * CH, FCap), LMAX)
+                block = np.asarray(walk(log, jnp.asarray(offs),
+                                        jnp.int32(depth), brow))
+            with tel.span("trace.decode"):
+                nexp, ok = int(block[LMAX + 1, 0]), bool(block[LMAX + 1, 1])
+                tel.counter("search.trace_rows_expanded", nexp)
+                if not ok:
+                    return None
+                trace = [(self.layout.decode_packed(block[lvl, :self.PW]),
+                          "Initial predicate" if lvl == 0 else
+                          self.labels_flat[int(block[lvl, self.PW])])
+                         for lvl in range(depth + 1)]
+                tel.counter("search.trace_len", len(trace))
+        return trace
 
     def _save_caps_profile(self, caps: Dict[str, int],
                            variant: str = "",
@@ -3458,11 +3627,20 @@ class TpuExplorer:
         tel = obs.current()
         layout = self.layout
         W, K = self.W, self.K
-        warnings = ["resident mode: search runs device-side end to end; "
-                    "no counterexample traces (rerun with the level/"
-                    "host_seen device modes or the interp for a trace)",
-                    "resident mode (W={}): dedup on 128-bit fingerprints; "
+        warnings = ["resident mode (W={}): dedup on 128-bit fingerprints; "
                     "collision probability < n^2 * 2^-129".format(W)]
+        # the state log a counterexample is walked back over (ISSUE 44)
+        # is kept unless the caller gave the trace up (store_trace
+        # False: --no-trace) or something else takes it away, which is
+        # said by name
+        no_trace_why = None if self.store_trace else "--no-trace"
+        if self.store_trace and self.resume_from:
+            no_trace_why = "--resume"
+            warnings.append(
+                "resident mode: no counterexample trace, given up by "
+                "--resume (a checkpoint carries no state log; rerun "
+                "without --resume for a trace)")
+        keep_log = no_trace_why is None
         warnings.extend(self._temporal_warnings())
         warnings.extend(self._symmetry_warnings())
         warnings.extend(self._por_warnings())
@@ -3482,7 +3660,7 @@ class TpuExplorer:
         # start generous; on CPU (tests) stay small to keep compiles fast
         on_accel = jax.devices()[0].platform != "cpu"
         if self._res_caps is not None:
-            caps = self._res_caps
+            caps = dict(self._res_caps)
         elif self._res_caps_hint:
             # caller-supplied steady-state caps (the corpus manifest's
             # res_caps record or a persisted capacity profile) are the
@@ -3524,6 +3702,18 @@ class TpuExplorer:
         # slice of the accumulator taken for the next frontier
         caps["VC"] = min(caps["VC"], self.A * CH)
         caps["AccCap"] = max(caps["AccCap"], 2 * caps["VC"], caps["FCap"])
+        if keep_log:
+            # the log's capacity in rows, a cap like the others (pinned,
+            # hinted or learned; else it starts where SC starts), and it
+            # must seat the initial frontier
+            given = (self._res_caps or self._res_caps_hint or {}) \
+                .get("LogCap")
+            caps["LogCap"] = max(
+                _pow2_at_least(int(given), lo=256) if given
+                else (1 << 20 if on_accel else 1 << 15),
+                _pow2_at_least(max(n_init, 1), lo=256))
+        else:
+            caps.pop("LogCap", None)
         # levels per dispatch: the host only sees status (and can only
         # checkpoint / log progress) between dispatches, so maxlvl adapts
         # to measured dispatch wall time — targeting the tighter of
@@ -3623,8 +3813,17 @@ class TpuExplorer:
                      jnp.int32(fcount), jnp.int32(distinct),
                      jnp.int32(gen_lo), jnp.int32(gen_hi),
                      jnp.int32(depth))
+            # the state log starts as level 0, the initial frontier;
+            # lvl_off[l] is where level l begins in it
+            log, lvl_off = None, [0, fcount]
+            if keep_log:
+                tel.counter("search.seed_bytes", fr_head.nbytes)
+                log = self._device_table(
+                    (caps["LogCap"] + caps["FCap"], self.PW), fr_head)
+                self._count_logged(fcount)
         grow_flag = {ST_OVF_SEEN: "SC", ST_OVF_FRONT: "FCap",
-                     ST_OVF_ACC: "AccCap", ST_OVF_VC: "VC"}
+                     ST_OVF_ACC: "AccCap", ST_OVF_VC: "VC",
+                     ST_OVF_LOG: "LogCap"}
         # first progress line immediately (ISSUE 2): short runs get at
         # least one record; same format as the interval lines below
         self.log(f"Progress({depth}): {generated} states generated, "
@@ -3651,7 +3850,8 @@ class TpuExplorer:
                                        t0, warnings, None,
                                        truncated=True, drained=True)
             ck_key = (caps["SC"], caps["FCap"], caps["AccCap"],
-                      caps["VC"], CH)
+                      caps["VC"], CH) + (
+                (caps["LogCap"], self._res_maxlvl) if keep_log else ())
             new_here = ck_key not in self._res_cache
             runf = self._get_resident_run(*ck_key)
             # a program the process already holds (ISSUE 37) has its
@@ -3666,7 +3866,8 @@ class TpuExplorer:
             # other engines' definitions
             tel.gauge("search.table_bytes", 4 * (
                 caps["SC"] * K + caps["FCap"] * self.PW
-                + caps["AccCap"] * (K + self.PW)))
+                + caps["AccCap"] * (K + self.PW)
+                + (int(log.shape[0]) * self.PW if keep_log else 0)))
             t_disp = time.time()
             # once the run has spilled (ISSUE 12), every level needs a
             # cold-tier probe at the host boundary: pin the dispatch to
@@ -3675,8 +3876,13 @@ class TpuExplorer:
                                and self._tiers.active) else maxlvl
             with tel.span("search.dispatch", maxlvl=eff_maxlvl,
                           fresh_compile=fresh_compile):
-                seen, frontier, summary, brow = runf(
-                    *state, max_states, jnp.int32(eff_maxlvl))
+                if keep_log:
+                    seen, frontier, summary, brow, log = runf(
+                        *state, max_states, jnp.int32(eff_maxlvl), log,
+                        jnp.int32(lvl_off[-1]))
+                else:
+                    seen, frontier, summary, brow = runf(
+                        *state, max_states, jnp.int32(eff_maxlvl))
                 jax.block_until_ready(summary)
             disp_wall = time.time() - t_disp
             # adapt levels-per-dispatch toward the host-attention target;
@@ -3713,6 +3919,15 @@ class TpuExplorer:
                 probe_blocks = int(summary[12])
                 merge_blocks = int(summary[13])
                 sort_units = int(summary[14])
+                if keep_log:
+                    # a level that added no row ended the search, was
+                    # rolled back or never ran
+                    logged_in = lvl_off[-1]
+                    for added in summary[16:]:
+                        if added:
+                            lvl_off.append(lvl_off[-1] + int(added))
+                    assert lvl_off[-1] == int(summary[15])
+                    self._count_logged(lvl_off[-1] - logged_in)
                 # cold-tier filter (ISSUE 12): after a spill the device
                 # table restarted empty, so a committed level's frontier
                 # may hold rows whose keys live in the host/disk runs —
@@ -3839,6 +4054,12 @@ class TpuExplorer:
                     pad = jnp.full((caps[what] - old, self.PW), SENTINEL,
                                    jnp.int32)
                     frontier = jnp.concatenate([frontier, pad])
+                if keep_log and what in ("FCap", "LogCap"):
+                    # the log is LogCap rows and one frontier block past
+                    # them: either growth lengthens it
+                    log = jnp.concatenate([log, jnp.full(
+                        (caps[what] - old, self.PW), SENTINEL,
+                        jnp.int32)])
                 # keep the cap invariants: AccCap >= 2*VC (block-append
                 # headroom) and AccCap >= FCap ([:FCap] frontier slice of
                 # the accumulator) — by x4 steps of AccCap's OWN ladder,
@@ -3889,9 +4110,10 @@ class TpuExplorer:
                         self._save_caps_profile(
                             dict(caps, TIERK=_pow2_at_least(
                                 max(len(self._tiers), 1), lo=256)),
-                            optional=("TIERK",))
+                            optional=("TIERK", "LogCap"))
                     else:
-                        self._save_caps_profile(caps)
+                        self._save_caps_profile(caps,
+                                                optional=("LogCap",))
                     if self.checkpoint_path and self.final_checkpoint:
                         # COMPLETED-run checkpoint (serve warm resume): an
                         # empty frontier over the full seen set — resuming
@@ -3906,7 +4128,7 @@ class TpuExplorer:
                                            depth - 1, t0, warnings)
             elif stat == ST_TRUNC:
                 self.log("-- state limit reached, search truncated")
-                self._save_caps_profile(caps)
+                self._save_caps_profile(caps, optional=("LogCap",))
                 if self.checkpoint_path:
                     # a truncated resident run is RESUMABLE (ISSUE 5):
                     # truncation lands on a level boundary inside the
@@ -3942,16 +4164,36 @@ class TpuExplorer:
                     False, distinct, generated, depth, t0, warnings,
                     Violation("error", "capacity overflow", [], msg))
             else:
-                st = layout.decode_packed(np.asarray(brow))
-                note = "state reached by resident-mode search (no trace)"
+                # a violating search learned its capacities too: the
+                # rerun after the fix compiles once
+                self._res_maxlvl_warm = min(max(depth + 1, maxlvl),
+                                            self._res_maxlvl)
+                self._save_caps_profile(caps, optional=("LogCap",))
+                trace = None
+                if keep_log:
+                    trace = self._walk_trace(log, lvl_off, depth, brow,
+                                             caps["FCap"], CH)
+                    if trace is None:
+                        no_trace_why = "a failed walk"
+                        warnings.append(
+                            f"resident mode: no counterexample trace: "
+                            f"the walk over the state log found no "
+                            f"parent of its target at some level below "
+                            f"{depth} (a fault of the engine: please "
+                            f"report it; the level engine gives the "
+                            f"trace)")
+                if trace is None:
+                    trace = [(layout.decode_packed(np.asarray(brow)),
+                              f"state reached by resident-mode search "
+                              f"(no trace: {no_trace_why})")]
                 if stat == ST_INV:
                     nm = self.inv_fns[which][0] if 0 <= which < \
                         len(self.inv_fns) else "invariant"
-                    v = Violation("invariant", nm, [(st, note)])
+                    v = Violation("invariant", nm, trace)
                 elif stat == ST_DEADLOCK:
-                    v = Violation("deadlock", "deadlock", [(st, note)])
+                    v = Violation("deadlock", "deadlock", trace)
                 else:
-                    v = Violation("assert", "Assert", [(st, note)],
+                    v = Violation("assert", "Assert", trace,
                                   "assertion failed in an enabled action")
                 return self._mk_result(False, distinct, generated, depth,
                                        t0, warnings, v)
@@ -4655,6 +4897,7 @@ class TpuExplorer:
         # (jits, inst_blocks) would scatter past the shrunken A
         self._hstep_group_jits.clear()
         self._res_cache.clear()
+        self._walk_cache.clear()
         obs.current().counter("expand.recovery_demotions", len(idxset))
         return labels
 

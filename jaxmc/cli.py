@@ -503,7 +503,10 @@ def main(argv=None) -> int:
                    help="jax backend: run the WHOLE search device-side "
                         "(frontier, fingerprint set, level loop in one "
                         "jitted while_loop, zero host syncs per level); "
-                        "no traces, no temporal properties")
+                        "a violation's counterexample is walked back "
+                        "over a state log kept on the device unless "
+                        "--no-trace (which saves the log's memory); no "
+                        "temporal properties")
     c.add_argument("--devices", type=int, default=None, metavar="N",
                    help="device backends: shard the frontier and the "
                         "seen set over the first N devices of the "

@@ -639,6 +639,29 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       (`daemon._run_vbatch`; up to PR 38 a cohort's said nothing).
     - `python -m jaxmc.obs report` prints a `cohort:` line (leader)
       and a `host_seen:` line (any member, any `--host-seen` run).
+
+  (PR 44, still jaxmc.metrics/4 — all additive/optional; counterexample
+   traces on the resident engine, backend/bfs.py, ISSUE 44.  A resident
+   search that keeps no trace (`--no-trace`) emits NONE of these:)
+    - spans `search.trace {depth}` (all of a violating search's
+      reconstruction) and inside it `trace.walk` (the ONE dispatch of
+      the backward walk and the one fetch of its result block) and
+      `trace.decode` (rows to states and labels).  Device scopes
+      `jaxmc.trace.log` (each level's new frontier rows appended to
+      the state log, inside the resident loop) and `jaxmc.trace.walk`
+      (the logged levels expanded again, successors packed and
+      compared with the target; its expansion carries this scope, not
+      `jaxmc.expand`).  Prof site `bfs.trace_walk`, keyed (log rows,
+      walk chunk, levels bucket); the traced search program is site
+      `bfs.resident_run` with (LogCap, levels a dispatch) ending its key.
+    - counters `search.log_rows` / `search.log_bytes` (rows the search
+      appended to its log — the initial frontier and every level it
+      went on from — and rows x PW x 4), `search.trace_rows_expanded`
+      (log rows of the chunks the walk visited: a level stops at the
+      first chunk that holds a parent) and `search.trace_len` (states
+      of the behaviour returned).  `search.table_bytes` counts the
+      log's table ((LogCap + FCap) x PW x 4) and `search.seed_bytes`
+      the initial frontier a second time, where a log is kept.
 """
 
 from __future__ import annotations

@@ -37,6 +37,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TRANSFER = os.path.join(REPO, "bench", "specs", "transfer_scaled.tla")
 #: the resident engine's accelerator defaults (`bfs._run_resident`)
 DEFAULTS = {"SC": 1 << 20, "FCap": 1 << 16, "AccCap": 1 << 17, "VC": 1 << 14}
+#: ... and where the state log of a search that keeps traces starts (PR 44)
+LOG_DEFAULT = 1 << 20
 
 
 def _pins(name):
@@ -87,8 +89,12 @@ def _cold_ladder(levels, initial, defaults, cap=None):
     `cap` a full table at the cap spills (and the level is redone against
     an empty one) instead of growing."""
     caps = dict(defaults)
-    programs, seen = [dict(caps)], initial
-    for _, cand, new in levels:
+    programs, seen, logged = [dict(caps)], initial, initial
+    for lvl, (_, cand, new) in enumerate(levels):
+        # with "LogCap" among the defaults the search keeps its state log
+        # and ENDS at the last level given (a violation, PR 44): every
+        # level it goes on from is appended, after the three checks above
+        goes_on = "LogCap" in caps and lvl < len(levels) - 1
         while True:
             if cand + caps["VC"] > caps["AccCap"]:
                 what = "AccCap"
@@ -100,6 +106,8 @@ def _cold_ladder(levels, initial, defaults, cap=None):
                 what = "SC"
             elif new > caps["FCap"]:
                 what = "FCap"
+            elif goes_on and logged + new > caps["LogCap"]:
+                what = "LogCap"
             else:
                 break
             caps[what] *= 4
@@ -107,6 +115,7 @@ def _cold_ladder(levels, initial, defaults, cap=None):
                 caps["AccCap"], max(2 * caps["VC"], caps["FCap"]))
             programs.append(dict(caps))
         seen += new
+        logged += new if goes_on else 0
     return programs
 
 
@@ -172,14 +181,28 @@ def _cold_spills(procs, max_money, cap):
 
 def test_there_are_resident_pins():
     assert {"transfer_scaled", "transfer_scaled_4p8",
-            "transfer_scaled_4p", "transfer_scaled_4p8_ooc"} \
-        <= set(RESIDENT_PINS)
+            "transfer_scaled_4p", "transfer_scaled_4p8_ooc",
+            "transfer_violation_4p"} <= set(RESIDENT_PINS)
 
 
 @pytest.mark.parametrize("name", RESIDENT_PINS)
 def test_res_caps_are_the_ladder_steps_that_hold_the_levels(name):
     pins = _pins(name)
-    caps, levels = pins["res_caps"], pins["levels"]
+    caps, levels = dict(pins["res_caps"]), pins["levels"]
+    log_cap = caps.pop("LogCap", None)
+    if log_cap is not None:
+        # a search that keeps its state log and ENDS in a violation (PR
+        # 44): the log holds the initial frontier and every level the
+        # search went on from — not the last, which holds the bad row —
+        # and LogCap is the step of its own x4 ladder that seats them
+        assert pins["verdict"] == "invariant"
+        logged = levels[0][0] + sum(new for _, _, new in levels[:-1])
+        assert logged == pins["logged_rows"]
+        assert log_cap == _ladder_step(LOG_DEFAULT, logged)
+        assert pins["trace_len"] == pins["diameter"] + 1 == len(levels) + 1
+        # ... and what one cold run that keeps traces leaves, log and all
+        assert _cold_ladder(levels, levels[0][0], dict(
+            DEFAULTS, LogCap=LOG_DEFAULT))[-1] == pins["res_caps"]
     assert sum(c for _, c, _ in levels) + levels[0][0] == pins["generated"]
     initial = pins["distinct"] - sum(new for _, _, new in levels)
     assert initial == levels[0][0]
@@ -215,6 +238,14 @@ def test_the_real_rungs_cold_ladder():
     assert len(ladder) == 7
     assert ladder[1] == dict(DEFAULTS, FCap=1 << 18, AccCap=1 << 19)
     assert ladder[-1] == pins["res_caps"]
+    # the same rung cut off at the violation, traces kept: one more
+    # program, the log's one growth (PR 44)
+    viol = _pins("transfer_violation_4p")
+    ladder = _cold_ladder(viol["levels"], viol["levels"][0][0],
+                          dict(DEFAULTS, LogCap=LOG_DEFAULT))
+    assert len(ladder) == 8 and ladder[-1] == viol["res_caps"]
+    assert [a["LogCap"] != b["LogCap"] for a, b in
+            zip(ladder, ladder[1:])].count(True) == 1
 
 
 def _toy_cfg(tmp_path, procs, max_money):
