@@ -181,6 +181,14 @@ def fingerprint128(rows):
 # every 256th 251).  Blocks under 2^12 rows bought nothing (3-process
 # cell: 2^12 28.1, 2^11 27.5).  The tests lower the floor and the
 # stride to cut toy shapes into several blocks and groups.
+#
+# What a block of SORTED queries does since ISSUE 45, where the table
+# has more rows than _probe_window_rows(SC): its first query and its
+# last live one are searched in full against the whole table; if their
+# answers lie under W rows apart, the window of the table between them
+# is copied out and the block's samples, narrow searches and final
+# gather read the copy (_PROBE_WINDOW_ROWS says why); if not, the block
+# runs against the whole table as before.  One lax.cond a block.
 _PROBE_BLOCK_MIN = 1 << 12
 _PROBE_SAMPLE = 16
 
@@ -276,7 +284,115 @@ def _lsd_sort(key_cols, extra_cols):
     return list(reversed(cols[:nk])), list(cols[nk:])
 
 
+# The rows of the seen table one block of SORTED queries searches
+# (ISSUE 45).  The resident engine's keys are 128-bit fingerprints, so a
+# block of QB ascending queries answers into QB x seen_count / n_live
+# consecutive rows of the table, and on the TPU v5e a gather is cheap
+# only from an operand that fits the compiler's fast memory: a probed
+# row cost 46.5 ns while the table's four key words (67 MB at SC 2^22)
+# sat there and 167.7 ns at SC 2^24 (268 MB), where they cannot
+# (ledger, PR 44; PERF.md section 5).  So a block whose answers span
+# under W rows cuts that window out of the table and searches the copy.
+# The probe alone at desk-deep-4p's shapes, a call a level with the
+# pinned counts (s a search's worth; my chip runs, PR 45, PERF.md
+# section 6): the whole table 3.668; W 2^19 1.177, 2^20 1.179 (177 of
+# 190 blocks), 2^21 1.094 (185), 2^22 1.085 (188) — all four copies sit
+# in fast memory.  The whole cell chose between them: 2^21 4,429,925
+# states/s, 2^22 3,921,980, 2^20 3,856,735, 2^19 3,860,847 against the
+# parent's 2,887,434; at every W but 2^21 the merge's BUILD, whose loop
+# body is the same text in all five programs, ran 1.4 x slower (PERF.md
+# section 7: placement, not the window).  The tests lower it to give
+# toy tables a window.
+_PROBE_WINDOW_ROWS = 1 << 21
+
+
+def _probe_window_rows(sc: int) -> int:
+    """W: the rows of the window of the seen table that one block of
+    sorted queries searches, a static function of the table's capacity
+    alone.  W == sc means no window: the program is the one it was."""
+    return min(sc, _PROBE_WINDOW_ROWS)
+
+
 @jax.named_scope("jaxmc.merge.probe")
+def _probe_by_block(seen, seen_count, keys, SC, n_live, sorted_keys):
+    """_seen_probe, and beside its answers the number of blocks that
+    searched a window of the table (None where the probe has none:
+    unsorted keys, or a table of no more than W rows)."""
+    n = keys.shape[0]
+    words = keys[:, 1:]
+    seen_words = seen[:, 1:]
+    kw = words.shape[1]
+    qb = _probe_block_rows(n)
+    W = _probe_window_rows(SC)
+    windowed = sorted_keys and W < SC
+    live = n if n_live is None else jnp.minimum(n_live, n)
+    every = _PROBE_SAMPLE
+    # rows of a block searched in full first: every `every`-th, the last
+    at_s = np.append(np.arange(0, qb, every), qb - 1)
+
+    def whole(q, at):
+        if sorted_keys:
+            lb_s = _lower_bound(seen_words, seen_count, q[at_s], SC)
+            lb_s = jnp.where(at + at_s < live, lb_s, seen_count)
+            lb_b = _lower_bound(seen_words, seen_count, q, SC,
+                                jnp.repeat(lb_s[:-1], every)[:qb],
+                                jnp.repeat(lb_s[1:], every)[:qb])
+        else:
+            lb_b = _lower_bound(seen_words, seen_count, q, SC)
+        at_lb = jnp.take(seen_words, jnp.clip(lb_b, 0, SC - 1), axis=0)
+        found_b = (lb_b < seen_count) & jnp.all(at_lb == q, axis=1)
+        return found_b, lb_b
+
+    def in_window(q, at, w_lo, w_hi, w0):
+        # the same searches over table rows [w0, w0 + W), every rank
+        # counted from w0; the samples lie between the block's two ends
+        window = lax.dynamic_slice(seen_words, (w0, 0), (W, kw))
+        lo, hi = w_lo - w0, w_hi - w0
+        lb_s = _lower_bound(window, None, q[at_s], W,
+                            jnp.broadcast_to(lo, at_s.shape),
+                            jnp.broadcast_to(hi, at_s.shape))
+        lb_s = jnp.where(at + at_s < live, lb_s, hi)
+        lb_b = _lower_bound(window, None, q, W,
+                            jnp.repeat(lb_s[:-1], every)[:qb],
+                            jnp.repeat(lb_s[1:], every)[:qb])
+        at_lb = jnp.take(window, jnp.clip(lb_b, 0, W - 1), axis=0)
+        lb_b = lb_b + w0
+        found_b = (lb_b < seen_count) & jnp.all(at_lb == q, axis=1)
+        return found_b, lb_b
+
+    def block(b, out):
+        found, lb = out[:2]
+        at = jnp.minimum(b * qb, n - qb)
+        q = lax.dynamic_slice(words, (at, 0), (qb, kw))
+        took = ()
+        if windowed:
+            # the block's first query and its last LIVE one, searched in
+            # full: every live answer lies between theirs.  The rows
+            # past n_live are not trusted to ascend and take no part
+            last = jnp.clip(live - 1 - at, 0, qb - 1)
+            ends = jnp.concatenate(
+                [q[:1], lax.dynamic_slice(q, (last, 0), (1, kw))])
+            w = _lower_bound(seen_words, seen_count, ends, SC)
+            w0 = jnp.clip(w[0], 0, SC - W)
+            # ... and fits where the row AT the last answer is inside
+            fits = w[1] < w0 + W
+            found_b, lb_b = lax.cond(
+                fits, lambda: in_window(q, at, w[0], w[1], w0),
+                lambda: whole(q, at))
+            took = (out[2] + fits.astype(jnp.int32),)
+        else:
+            found_b, lb_b = whole(q, at)
+        return (lax.dynamic_update_slice(found, found_b, (at,)),
+                lax.dynamic_update_slice(lb, lb_b, (at,))) + took
+
+    # zeros of the keys' TYPE, as _lower_bound's lo: the carry leaves
+    # the loop device-varying under shard_map
+    lb0 = words[:, 0] - words[:, 0]
+    out = lax.fori_loop(0, _probe_blocks(live, n), block,
+                        (lb0 != 0, lb0) + ((lb0[0],) if windowed else ()))
+    return out if windowed else out + (None,)
+
+
 def _seen_probe(seen, seen_count, keys, SC, n_live=None,
                 sorted_keys=False):
     """Membership of each key row in the seen table's sorted valid
@@ -306,38 +422,23 @@ def _seen_probe(seen, seen_count, keys, SC, n_live=None,
     the QB rows instead of 18-21.  Rows past n_live are not trusted to
     ascend: a sample among them answers seen_count.
 
+    Monotone answers also mean that a block touches ONE range of the
+    table, from its first query's answer to its last live query's.
+    Where the table has more than W = _probe_window_rows(SC) rows
+    (ISSUE 45), a sorted block searches those two queries in full
+    first, and if the range is under W rows it copies that window of
+    the table (lax.dynamic_slice, clamped to the table's end) and runs
+    the samples, the narrow searches and the final gather against the
+    copy, ranks shifted by the window's start and back: one lax.cond a
+    block, the other branch the search of the whole table.  Every row
+    of keys[0:n_live] gets the answer it had; the rows past n_live of
+    a windowed block get a rank inside the window instead of
+    seen_count, and nobody reads them.  With W >= SC, or unsorted
+    keys, there is no window and no branch.
+
     Returns (found [N] bool, lb [N] int32 lower-bound rank)."""
-    n = keys.shape[0]
-    words = keys[:, 1:]
-    seen_words = seen[:, 1:]
-    qb = _probe_block_rows(n)
-    live = n if n_live is None else jnp.minimum(n_live, n)
-    every = _PROBE_SAMPLE
-    # rows of a block searched in full first: every `every`-th, the last
-    at_s = np.append(np.arange(0, qb, every), qb - 1)
-
-    def block(b, out):
-        found, lb = out
-        at = jnp.minimum(b * qb, n - qb)
-        q = lax.dynamic_slice(words, (at, 0), (qb, words.shape[1]))
-        if sorted_keys:
-            lb_s = _lower_bound(seen_words, seen_count, q[at_s], SC)
-            lb_s = jnp.where(at + at_s < live, lb_s, seen_count)
-            lb_b = _lower_bound(seen_words, seen_count, q, SC,
-                                jnp.repeat(lb_s[:-1], every)[:qb],
-                                jnp.repeat(lb_s[1:], every)[:qb])
-        else:
-            lb_b = _lower_bound(seen_words, seen_count, q, SC)
-        at_lb = jnp.take(seen_words, jnp.clip(lb_b, 0, SC - 1), axis=0)
-        found_b = (lb_b < seen_count) & jnp.all(at_lb == q, axis=1)
-        return (lax.dynamic_update_slice(found, found_b, (at,)),
-                lax.dynamic_update_slice(lb, lb_b, (at,)))
-
-    # zeros of the keys' TYPE, as _lower_bound's lo: the carry leaves
-    # the loop device-varying under shard_map
-    lb0 = words[:, 0] - words[:, 0]
-    return lax.fori_loop(0, _probe_blocks(live, n), block,
-                         (lb0 != 0, lb0))
+    return _probe_by_block(seen, seen_count, keys, SC, n_live,
+                           sorted_keys)[:2]
 
 
 @jax.named_scope("jaxmc.expand")
@@ -548,6 +649,10 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
       seen_count2  seen_count + new_count (NOT cropped to SC).
       probe_blocks  query blocks the probe searched (× _probe_block_rows(N)
                  = the slots behind `search.slots_probed`).
+      window_blocks  those of them that searched a window of the table
+                 (ISSUE 45; × _probe_block_rows(N) = the slots behind
+                 `search.slots_windowed`); None where SC is no more
+                 than _probe_window_rows(SC) and the probe has none.
       merge_blocks  blocks of seen2 built (× _merge_block_rows(SC) = the
                  slots behind `search.slots_merged`).
 
@@ -614,8 +719,8 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
     # reads found or lb of an invalid row (`new` masks them, pos_n is
     # used where `new`)
     n_live = jnp.sum(svalid, dtype=jnp.int32)
-    found, lb = _seen_probe(seen, seen_count, skeys, SC, n_live,
-                            sorted_keys=True)
+    found, lb, window_blocks = _probe_by_block(seen, seen_count, skeys, SC,
+                                               n_live, sorted_keys=True)
     new = svalid & ~found & neq_prev
     new_count = jnp.sum(new, dtype=jnp.int32)
     seen_count2 = seen_count + new_count
@@ -708,6 +813,7 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
     return dict(new_count=new_count, nk_sidx=nk_sidx, seen2=seen2,
                 seen_count2=seen_count2,
                 probe_blocks=_probe_blocks(n_live, N),
+                window_blocks=window_blocks,
                 merge_blocks=merge_blocks, sort_slots=sort_slots)
 
 
@@ -2712,6 +2818,9 @@ class TpuExplorer:
         if por:
             por_inst = jnp.asarray(por_plan["inst_arm"])
             por_safe_v = jnp.asarray(por_plan["arm_safe"])
+        # the merge's probe searches windows of a table this large, and
+        # the program then counts the blocks that did (ISSUE 45)
+        windowed = _probe_window_rows(SC) < SC
 
         def level(seen, seen_count, frontier, fcount):
             # frontier is PACKED [FCap, PW]; each chunk unpacks to lanes
@@ -2943,7 +3052,8 @@ class TpuExplorer:
                     explore_count, stat, inv_bad_which, bad_row, ovcode,
                     pora, porx, porm, rm["probe_blocks"],
                     rm["merge_blocks"],
-                    rm["sort_slots"] // _sort_unit(AccCap))
+                    rm["sort_slots"] // _sort_unit(AccCap)) + \
+                ((rm["window_blocks"],) if windowed else ())
 
         def run(seen, seen_count, frontier, fcount, distinct,
                 gen_lo, gen_hi, depth, max_states, maxlvl, *logged):
@@ -2963,16 +3073,16 @@ class TpuExplorer:
                  gen_lo, gen_hi, depth, lvls, stat, which, brow,
                  ovcode, pora, porx, porm, pblocks, mblocks,
                  sunits) = carry[:19]
+                lvl = level(seen, seen_count, frontier, fcount)
                 (seen2, seen_count2, front2, fcount2, gen_l, kept,
                  lstat, lwhich, lbrow, lovcode, lpora, lporx,
-                 lporm, lpblocks, lmblocks, lsunits) = level(
-                     seen, seen_count, frontier, fcount)
+                 lporm, lpblocks, lmblocks, lsunits) = lvl[:16]
                 ovf = (lstat == ST_OVF_SEEN) | (lstat == ST_OVF_FRONT) | \
                     (lstat == ST_OVF_ACC) | (lstat == ST_OVF_VC) | \
                     (lstat == ST_OVF_LANES)
                 logged2 = ()
                 if LogCap:
-                    log, log_n, lvl_rows = carry[19:]
+                    log, log_n, lvl_rows = carry[19:22]
                     with jax.named_scope("jaxmc.trace.log"):
                         # a level that ends the search in a verdict is
                         # not logged: the walk reads the levels BEFORE
@@ -3033,7 +3143,8 @@ class TpuExplorer:
                         # sorted its rung and searched and built its
                         # blocks too
                         pblocks + lpblocks, mblocks + lmblocks,
-                        sunits + lsunits) + logged2
+                        sunits + lsunits) + logged2 + \
+                    ((carry[-1] + lvl[16],) if windowed else ())
 
             carry0 = (seen, seen_count, frontier, fcount, distinct,
                       gen_lo, gen_hi, depth, jnp.int32(0),
@@ -3044,6 +3155,10 @@ class TpuExplorer:
                       jnp.int32(0))
             if LogCap:
                 carry0 += logged + (jnp.zeros((LV,), jnp.int32),)
+            if windowed:
+                # the carry's last: the query blocks that searched a
+                # window of the table (ISSUE 45), counted as pblocks is
+                carry0 += (jnp.int32(0),)
             out = lax.while_loop(cond, body, carry0)
             (seen, seen_count, frontier, fcount, distinct, gen_lo,
              gen_hi, depth, _, stat, which, brow, ovcode, pora, porx,
@@ -3065,9 +3180,16 @@ class TpuExplorer:
                 # holds and 16.. the rows each level of the dispatch
                 # added (the host keeps the levels' offsets from them):
                 # one block, one fetch
-                log, log_n, lvl_rows = out[19:]
+                log, log_n, lvl_rows = out[19:22]
                 summary = jnp.concatenate([summary, log_n[None],
                                            lvl_rows])
+            if windowed:
+                # ... and, where the probe has a window, the summary's
+                # LAST word is the blocks that searched it (ISSUE 45:
+                # search.slots_windowed); a program without one keeps
+                # the summary it had
+                summary = jnp.concatenate([summary, out[-1][None]])
+            if LogCap:
                 return seen, frontier, summary, brow, log
             return seen, frontier, summary, brow
 
@@ -3421,7 +3543,7 @@ class TpuExplorer:
                     # this module's tuning constants, which a trace
                     # reads too (tests patch them)
                     FP_THRESHOLD, _PROBE_BLOCK_MIN, _PROBE_SAMPLE,
-                    _MERGE_BLOCK_ROWS)),
+                    _MERGE_BLOCK_ROWS, _PROBE_WINDOW_ROWS)),
                 (self.backend_desc.platform,
                  jax.devices()[0].device_kind,
                  self.backend_desc.profile_ns,
@@ -3899,6 +4021,12 @@ class TpuExplorer:
                 maxlvl = min(self._res_maxlvl, maxlvl * 2)
             with tel.span("search.fetch"):
                 summary = np.asarray(summary)
+                # a program whose probe has a window (ISSUE 45) says
+                # last how many of its query blocks searched it
+                window_blocks = None
+                if _probe_window_rows(caps["SC"]) < caps["SC"]:
+                    window_blocks = int(summary[-1])
+                    summary = summary[:-1]
                 fcount_in, gen_in, dist_in, depth_in = \
                     fcount, generated, distinct, depth
                 stat = int(summary[0])
@@ -3986,6 +4114,9 @@ class TpuExplorer:
             # valid key: the dispatch's loop carry counted them
             tel.counter("search.slots_probed", probe_blocks
                         * _probe_block_rows(caps["AccCap"]))
+            if window_blocks is not None:
+                tel.counter("search.slots_windowed", window_blocks
+                            * _probe_block_rows(caps["AccCap"]))
             tel.counter("search.seen_slots", lvls * caps["SC"])
             # ... and built only the blocks of the table that held a
             # live row after each level: counted in the carry as well

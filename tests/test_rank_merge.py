@@ -24,7 +24,15 @@ incoming table with the blocks that hold a live row rebuilt in place
 blocks of sorted rows alone; the cases below land seen_count2 on every
 kind of block border over consecutive merges and hold the rows past
 the built blocks to what came in, and a fourth lowering guard keeps a
-whole-table gather out of the three engines' programs."""
+whole-table gather out of the three engines' programs.
+
+Since ISSUE 45 a block of SORTED queries searches a window of the
+table where the table has more rows than W = `_probe_window_rows(SC)`
+and the block's answers span fewer: the last section lowers W to give
+toy tables a window and holds the windowed answers to the whole-table
+probe's (the form up to PR 44, kept here alone) and to bisection, at
+every border of the window, and the choice each block makes to the rule
+as arithmetic."""
 
 import functools
 import re
@@ -38,8 +46,9 @@ from jax import lax  # noqa: E402
 
 from jaxmc.backend import bfs  # noqa: E402
 from jaxmc.backend.bfs import (  # noqa: E402
-    SENTINEL, _lsd_sort, _merge_block_rows, _merge_blocks,
-    _probe_block_rows, _probe_blocks, _rank_merge, _seen_probe)
+    SENTINEL, _lower_bound, _lsd_sort, _merge_block_rows, _merge_blocks,
+    _probe_block_rows, _probe_blocks, _probe_by_block, _probe_window_rows,
+    _rank_merge, _seen_probe)
 
 
 def _scatter_rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
@@ -876,21 +885,33 @@ def _conds(jaxpr, scope=""):
     return out
 
 
+@pytest.mark.parametrize("window", [None, 1 << 10],
+                         ids=["no_window", "windowed"])
 @pytest.mark.parametrize("program,ladders", [
     (_level_step, 0), (_resident_run, 1), (_mesh_superstep, 0)],
     ids=["level_step", "resident_run", "mesh_superstep"])
 def test_the_sort_ladder_is_in_the_resident_program_alone(
-        program, ladders, monkeypatch):
+        program, ladders, window, monkeypatch):
     """The resident level's candidates are a prefix of its accumulator
     and its merge switches between the rungs, once, under
     jaxmc.merge.sort; the level and the mesh engines' key slots are no
-    prefix, they pass no n_prefix and their programs branch nowhere."""
+    prefix, they pass no n_prefix and their programs branch nowhere.
+    Where a table has more rows than the probe's window (ISSUE 45; the
+    floor lowered here, no engine's tables but the resident's are that
+    large) a program holds exactly one conditional more, under
+    jaxmc.merge.probe: a block's choice between the window and the
+    whole table."""
     monkeypatch.setattr(bfs, "_SORT_RUNG_MIN", 32)
+    if window:
+        monkeypatch.setattr(bfs, "_PROBE_WINDOW_ROWS", window)
     ex, fn, args, n_keys = program(1 << 14, 64)
     found = _conds(jax.make_jaxpr(fn)(*args).jaxpr)
-    assert len(found) == ladders, found
-    for stack in found:
-        assert "jaxmc.merge.sort" in stack, stack
+    assert len(found) == ladders + bool(window), found
+    assert sum("jaxmc.merge.probe" in stack for stack in found) \
+        == bool(window), found
+    assert sum("jaxmc.merge.sort" in stack
+               and "jaxmc.merge.probe" not in stack
+               for stack in found) == ladders, found
     if ladders:
         rungs = bfs._sort_rungs(n_keys)
         assert len(rungs) >= 3
@@ -904,3 +925,336 @@ def test_the_sort_ladder_is_in_the_resident_program_alone(
                 for m in _SORT.findall(text))
              if len(types) == ex.K + 1), reverse=True)
         assert tuple(chains) == rungs, (rungs, chains)
+
+
+# ---- the probe searches a window of the table (ISSUE 45) ----
+#
+# A table of WSC = 256 rows, a window floor of WW = 32, the file's 48
+# query slots in three blocks of QB = 16.  The table's j-th row holds
+# 10 * j in its last word, so the query 10 * j answers (found, j) and
+# 10 * j + 5 answers (not found, j + 1).
+
+WSC, WW = 256, 32
+
+
+def _whole_table_probe(seen, seen_count, keys, SC, n_live, sorted_keys):
+    """`_seen_probe` as it stood up to PR 44, word for word: every block
+    against the whole table.  The windowed probe's oracle, and the text
+    a table of no more than W rows must still lower to."""
+    n = keys.shape[0]
+    words = keys[:, 1:]
+    seen_words = seen[:, 1:]
+    qb = _probe_block_rows(n)
+    live = n if n_live is None else jnp.minimum(n_live, n)
+    every = bfs._PROBE_SAMPLE
+    at_s = np.append(np.arange(0, qb, every), qb - 1)
+
+    def block(b, out):
+        found, lb = out
+        at = jnp.minimum(b * qb, n - qb)
+        q = lax.dynamic_slice(words, (at, 0), (qb, words.shape[1]))
+        if sorted_keys:
+            lb_s = _lower_bound(seen_words, seen_count, q[at_s], SC)
+            lb_s = jnp.where(at + at_s < live, lb_s, seen_count)
+            lb_b = _lower_bound(seen_words, seen_count, q, SC,
+                                jnp.repeat(lb_s[:-1], every)[:qb],
+                                jnp.repeat(lb_s[1:], every)[:qb])
+        else:
+            lb_b = _lower_bound(seen_words, seen_count, q, SC)
+        at_lb = jnp.take(seen_words, jnp.clip(lb_b, 0, SC - 1), axis=0)
+        found_b = (lb_b < seen_count) & jnp.all(at_lb == q, axis=1)
+        return (lax.dynamic_update_slice(found, found_b, (at,)),
+                lax.dynamic_update_slice(lb, lb_b, (at,)))
+
+    lb0 = words[:, 0] - words[:, 0]
+    return lax.fori_loop(0, _probe_blocks(live, n), block,
+                         (lb0 != 0, lb0))
+
+
+def _np_window_blocks(seen, n_seen, keys, live, SC, W):
+    """The blocks that take the window, by the rule as arithmetic: a
+    block's first and last LIVE query bisected, the window started at
+    the first answer and clamped to the table's end, taken where the
+    row AT the last answer lies inside."""
+    n = len(keys)
+    qb = _probe_block_rows(n)
+    lb = _np_probe(seen, n_seen, keys)[1]
+    took = []
+    for b in range(-(-min(live, n) // qb)):
+        at = min(b * qb, n - qb)
+        w_lo, w_hi = lb[at], lb[min(live - 1, at + qb - 1)]
+        w0 = min(max(w_lo, 0), SC - W)
+        took.append(bool(w_hi < w0 + W))
+    return took
+
+
+def _tens(K, values):
+    """Key words whose last is each value and whose others are 0."""
+    w = np.zeros((len(values), K - 1), np.int32)
+    w[:, -1] = values
+    return w
+
+
+def _sorted_keys(words, live, n, K):
+    """n key slots: `words` (ascending) as the live prefix, the rest
+    invalid and SENTINEL, as _rank_merge's sort leaves them."""
+    assert len(words) == live
+    return _keys(np.concatenate([
+        words, np.zeros((n - live, K - 1), np.int32)]),
+        np.arange(n) < live, K)
+
+
+def _window_case(name, K=5):
+    """(table, n_seen, keys, live, the blocks that take the window)."""
+    rows = np.arange(WSC) * 10
+    n_seen, live = 200, N
+    first = lambda j, m: list(rows[j:j + m])   # m hits from row j on
+    if name == "first_row":
+        # block 0 starts AT its window's first row, 40, and hits it
+        q = first(40, 16) + first(80, 16) + first(120, 16)
+        took = [True, True, True]
+    elif name == "last_row":
+        # block 0's last answer is the window's last row, 40 + W - 1
+        q = first(40, 15) + [rows[40 + WW - 1]] + first(80, 32)
+        took = [True, True, True]
+    elif name == "one_past":
+        # the last queries lie past every row: lb == seen_count, inside
+        # the window of a block that starts 22 rows before the end
+        n_seen = 72
+        q = first(8, 16) + first(30, 16) + first(50, 12) + [999] * 4
+        took = [True, True, True]
+    elif name == "span_W":
+        # a miss before row 40 to a miss before row 40 + W - 1: the
+        # answers span exactly W rows, the window
+        q = [rows[40] - 5] + first(40, 14) + [rows[40 + WW - 1] - 5] + \
+            first(80, 32)
+        took = [True, True, True]
+    elif name == "span_W_plus_1":
+        # ... and one row more: the whole table
+        q = [rows[40] - 5] + first(40, 14) + [rows[40 + WW] - 5] + \
+            first(80, 32)
+        took = [False, True, True]
+    elif name == "clamped_end":
+        # a full table; the last block starts 16 rows before its end, so
+        # its window is clamped to [SC - W, SC)
+        n_seen = WSC
+        q = first(100, 16) + first(200, 16) + first(WSC - 16, 10) + \
+            [rows[WSC - 6] + 5] * 6
+        took = [True, True, True]
+    elif name == "partial_last_block":
+        # four live rows in the last block searched: the SENTINEL rows
+        # after them would answer seen_count, 150 rows on
+        live = 20
+        q = first(40, 16) + first(60, 4)
+        took = [True, True]
+    elif name == "duplicates_across_a_border":
+        # one hit five times, over the border of blocks 0 and 1; one
+        # miss six times over that of blocks 1 and 2
+        q = first(40, 14) + [rows[54]] * 5 + first(55, 9) + \
+            [rows[63] + 5] * 6 + first(64, 14)
+        took = [True, True, True]
+    else:
+        raise AssertionError(name)
+    assert len(q) == live and sorted(q) == list(q)
+    return (_table(_tens(K, rows[:n_seen]), WSC, K), n_seen,
+            _sorted_keys(_tens(K, q), live, N, K), live, took)
+
+
+WINDOW_CASES = ("first_row", "last_row", "one_past", "span_W",
+                "span_W_plus_1", "clamped_end", "partial_last_block",
+                "duplicates_across_a_border")
+
+
+def _windowed_probe():
+    """A fresh jit a test: the window's floor is read when a shape is
+    first traced, and jit caches by function."""
+    return jax.jit(
+        lambda s, c, k, n: _probe_by_block(s, c, k, WSC, n, True))
+
+
+def _check_window(seen, n_seen, keys, live, took=None):
+    found, lb, blocks = _windowed_probe()(
+        jnp.asarray(seen), jnp.int32(n_seen), jnp.asarray(keys),
+        jnp.int32(live))
+    whole_found, whole_lb = jax.jit(
+        lambda s, c, k, n: _whole_table_probe(s, c, k, WSC, n, True))(
+        jnp.asarray(seen), jnp.int32(n_seen), jnp.asarray(keys),
+        jnp.int32(live))
+    want_found, want_lb = _np_probe(seen, n_seen, keys)
+    rule = _np_window_blocks(seen, n_seen, keys, live, WSC, WW)
+    if took is not None:
+        assert rule == took, rule
+    assert int(blocks) == sum(rule)
+    for got, whole, want in ((found, whole_found, want_found),
+                             (lb, whole_lb, want_lb)):
+        assert np.array_equal(np.asarray(got)[:live],
+                              np.asarray(whole)[:live])
+        assert np.array_equal(np.asarray(got)[:live], want[:live])
+    return rule
+
+
+@pytest.fixture
+def _toy_window(monkeypatch):
+    monkeypatch.setattr(bfs, "_PROBE_WINDOW_ROWS", WW)
+    assert _probe_window_rows(WSC) == WW and _probe_window_rows(WW) == WW
+
+
+@pytest.mark.parametrize("case", WINDOW_CASES)
+def test_windowed_probe_at_the_windows_borders(case, _toy_window):
+    seen, n_seen, keys, live, took = _window_case(case)
+    _check_window(seen, n_seen, keys, live, took)
+    if case == "one_past":
+        assert _np_probe(seen, n_seen, keys)[1][live - 1] == n_seen
+    if case == "clamped_end":
+        assert _np_probe(seen, n_seen, keys)[1][2 * QB] > WSC - WW
+
+
+@pytest.mark.parametrize("trial", range(4))
+def test_windowed_probe_of_exact_keys_in_a_clustered_table(trial,
+                                                           _toy_window):
+    """K = 3, the level engine's exact keys: nothing spreads them, so
+    most of the table sits in one narrow range of keys and a block of
+    queries over it spans far more rows than W.  Some blocks take the
+    window and some the whole table, and every answer is bisection's."""
+    K = 3
+    rng = np.random.default_rng([trial, 450])
+    n_seen = int(rng.integers(WSC // 2, WSC + 1))
+    dense = np.stack([np.full(4 * WSC, 3), rng.integers(
+        0, 400, 4 * WSC)], axis=1)
+    sparse = rng.integers(-40, 40, size=(WSC // 4, K - 1))
+    pool = _lexsorted(np.concatenate([dense, sparse]).astype(np.int32))
+    swords = pool[np.sort(rng.choice(len(pool), n_seen, replace=False))]
+    live = int(rng.integers(2 * QB + 1, N + 1))
+    qwords = np.concatenate([
+        swords[rng.choice(n_seen, live // 2)],
+        rng.integers(-40, 40, size=(live - live // 2, K - 1))])
+    qwords = qwords[np.lexsort(tuple(
+        qwords[:, j] for j in reversed(range(K - 1))))].astype(np.int32)
+    rule = _check_window(_table(swords, WSC, K), n_seen,
+                         _sorted_keys(qwords, live, N, K), live)
+    if trial == 0:
+        assert True in rule and False in rule, rule
+
+
+def test_a_table_of_no_more_than_W_rows_lowers_to_the_form_it_had(
+        monkeypatch):
+    """W >= SC: no window and no branch — the text of the probe up to
+    PR 44, which the level engine's step, the mesh's shards and the
+    small resident tables keep."""
+    def lowered(probe, sorted_keys):
+        def merge_probe(seen, count, keys, n_live):
+            return tuple(probe(seen, count, keys, WSC, n_live,
+                               sorted_keys))[:2]
+        return jax.jit(merge_probe).lower(
+            jax.ShapeDtypeStruct((WSC, 5), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((N, 5), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+
+    for floor in (WSC, 2 * WSC):
+        monkeypatch.setattr(bfs, "_PROBE_WINDOW_ROWS", floor)
+        for sorted_keys in (True, False):
+            text = lowered(_probe_by_block, sorted_keys)
+            assert text == lowered(_whole_table_probe, sorted_keys)
+            assert "stablehlo.case" not in text and \
+                "stablehlo.if" not in text
+    # ... and with a window the sorted form branches, the unsorted form
+    # (the POR filter's) never
+    monkeypatch.setattr(bfs, "_PROBE_WINDOW_ROWS", WW)
+    assert lowered(_probe_by_block, False) == \
+        lowered(_whole_table_probe, False)
+    text = lowered(_probe_by_block, True)
+    assert "stablehlo.case" in text or "stablehlo.if" in text
+
+
+def _window_levels(K, rng):
+    """Three levels of candidates for a table of WSC rows that starts
+    with a few: the windows move as the table fills."""
+    pool = _lexsorted(rng.integers(-30, 31, size=(8 * WSC, K - 1))
+                      .astype(np.int32))
+    start = pool[np.sort(rng.choice(len(pool), 40, replace=False))]
+    levels = []
+    for lvl in range(3):
+        live = int(rng.integers(QB + 1, N + 1))
+        valid = np.zeros(N, bool)
+        valid[rng.choice(N, live, replace=False)] = True
+        levels.append(_keys(pool[rng.choice(len(pool), N)], valid, K))
+    return _table(start, WSC, K), len(start), levels
+
+
+def _np_window_merges(table, count, levels, K):
+    """(final table, final count, windowed blocks a level) by numpy."""
+    blocks = []
+    for keys in levels:
+        order = np.lexsort(tuple(keys[:, j] for j in reversed(range(K))))
+        skeys = keys[order]
+        live = int((skeys[:, 0] == 0).sum())
+        blocks.append(sum(_np_window_blocks(table, count, skeys, live,
+                                            WSC, WW)))
+        want = _np_reference(table, count, keys, WSC, K)
+        table, count = want["seen2"], want["seen_count2"]
+    return table, count, blocks
+
+
+def test_windowed_merge_inside_a_while_loop(_toy_window):
+    K = 5
+    rng = np.random.default_rng(4501)
+    table, count, levels = _window_levels(K, rng)
+
+    @jax.jit
+    def run(table, count, keys3):
+        def body(carry):
+            lvl, table, count, blocks = carry
+            rm = _rank_merge(table, count, keys3[lvl], N, WSC, K)
+            return (lvl + 1, rm["seen2"], rm["seen_count2"],
+                    blocks.at[lvl].set(rm["window_blocks"]))
+        return lax.while_loop(lambda c: c[0] < 3, body,
+                              (jnp.int32(0), table, count,
+                               jnp.zeros((3,), jnp.int32)))[1:]
+
+    seen2, count2, blocks = run(jnp.asarray(table), jnp.int32(count),
+                                jnp.asarray(np.stack(levels)))
+    want_table, want_count, want_blocks = _np_window_merges(
+        table, count, levels, K)
+    assert int(count2) == want_count
+    assert np.array_equal(np.asarray(seen2)[:want_count],
+                          want_table[:want_count])
+    assert list(np.asarray(blocks)) == want_blocks
+    assert sum(want_blocks) > 0
+
+
+def test_windowed_merge_under_shard_map(_toy_window):
+    """Four shards, each its own table and candidates: each block's
+    choice is its own shard's (a device-varying predicate)."""
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+    D, K = 4, 5
+    if len(jax.devices()) < D:
+        pytest.skip("needs four (virtual) devices")
+    mesh = Mesh(np.array(jax.devices()[:D]), ("d",))
+
+    def shard(table, count, keys):
+        rm = _rank_merge(table[0], count[0], keys[0], N, WSC, K, True)
+        return tuple(rm[name][None] for name in
+                     ("seen2", "seen_count2", "window_blocks"))
+
+    step = jax.jit(shard_map(shard, mesh=mesh, in_specs=(P("d"),) * 3,
+                             out_specs=(P("d"),) * 3))
+    cases = [_window_levels(K, np.random.default_rng([d, 4502]))
+             for d in range(D)]
+    tables = [c[0] for c in cases]
+    counts = [c[1] for c in cases]
+    total = 0
+    for lvl in range(3):
+        keys = [c[2][lvl] for c in cases]
+        seen2, count2, blocks = step(
+            jnp.asarray(np.stack(tables)), jnp.asarray(counts, jnp.int32),
+            jnp.asarray(np.stack(keys)))
+        for d in range(D):
+            t, n, b = _np_window_merges(tables[d], counts[d], [keys[d]], K)
+            assert int(count2[d]) == n
+            assert np.array_equal(np.asarray(seen2)[d][:n], t[:n])
+            assert int(blocks[d]) == b[0]
+            tables[d], counts[d] = t, n
+            total += b[0]
+    assert total > 0
