@@ -594,6 +594,52 @@ def _sort_unit(n: int) -> int:
     return math.gcd(*_sort_rungs(n))
 
 
+def _compact_block_rows(acc_cap: int, fcap: int) -> int:
+    """RB: the rows of one block of the resident level's compaction
+    gathers (ISSUE 46), a static function of the program's shapes alone
+    — the block _rank_merge wrote nk_sidx in, _probe_block_rows of the
+    AccCap index slots, never over the FCap rows of the frontier."""
+    return min(_probe_block_rows(acc_cap), fcap)
+
+
+def _compact_blocks(n, cap: int, rb: int):
+    """How many blocks of rb rows _gather_prefix runs to put n rows
+    into a buffer of cap: ceil(min(n, cap) / rb).  The ONE block rule:
+    the kernel bounds its loop with it on a traced count and the engine
+    counts `search.slots_compacted` with it (Python ints work too)."""
+    if isinstance(n, (int, np.integer)):
+        return (min(int(n), cap) + (rb - 1)) // rb
+    return (jnp.minimum(n, cap) + (rb - 1)) // rb
+
+
+def _gather_prefix(rows, idx, n, cap: int, rb: int):
+    """(out, blocks): out [cap, w] is rows[idx[i]] for i < min(n, cap),
+    SENTINEL from there on — the compaction that follows the rows that
+    exist (ISSUE 46): a lax loop over blocks of rb indices, bounded on
+    the traced count as _rank_merge's index_block and _probe_by_block
+    are, and `blocks` the turns it ran (_compact_blocks).  idx reads 0
+    past n and XLA cannot know: a jnp.take over all of idx fetches row
+    0 for every slot that holds no row.  The last block starts at
+    cap - rb where rb does not divide cap and writes its neighbour's
+    rows again: the same values."""
+    w = rows.shape[1]
+    hi = rows.shape[0] - 1
+    blocks = _compact_blocks(n, cap, rb)
+    n = jnp.minimum(n, cap)
+
+    def block(b, out):
+        at = jnp.minimum(b * rb, cap - rb)
+        got = jnp.take(
+            rows, jnp.clip(lax.dynamic_slice(idx, (at,), (rb,)), 0, hi),
+            axis=0)
+        live = (at + jnp.arange(rb, dtype=jnp.int32)) < n
+        return lax.dynamic_update_slice(
+            out, jnp.where(live[:, None], got, SENTINEL), (at, 0))
+
+    return lax.fori_loop(0, blocks, block,
+                         jnp.full((cap, w), SENTINEL, jnp.int32)), blocks
+
+
 @jax.named_scope("jaxmc.merge.scatter")
 def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
                 n_prefix=None):
@@ -638,7 +684,11 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
     Returns dict:
       new_count  how many sorted candidate keys are genuinely new
       nk_sidx    [N] each compacted new key's ORIGINAL row index in
-                 `keys` (key-sorted order; ties keep first occurrence)
+                 `keys` (key-sorted order; ties keep first occurrence):
+                 the new keys' in [0, new_count), 0 from there on.  The
+                 engines fetch the new ROWS through that prefix — the
+                 resident level in blocks bounded on new_count
+                 (_gather_prefix, ISSUE 46)
       seen2      [SC, K] merged table — sorted valid prefix of length
                  seen_count + new_count, invalid tail (lane 1, SENTINEL
                  data in the blocks built; the incoming rows past
@@ -2821,6 +2871,7 @@ class TpuExplorer:
         # the merge's probe searches windows of a table this large, and
         # the program then counts the blocks that did (ISSUE 45)
         windowed = _probe_window_rows(SC) < SC
+        RB = _compact_block_rows(AccCap, FCap)
 
         def level(seen, seen_count, frontier, fcount):
             # frontier is PACKED [FCap, PW]; each chunk unpacks to lanes
@@ -2990,41 +3041,55 @@ class TpuExplorer:
             # Every chunk appended its block at acc_n, so the valid keys
             # are a prefix of the accumulator and the sort takes the
             # smallest rung of its ladder that holds it (ISSUE 42).
+            # What comes back names the new rows in a prefix as well
+            # (nk_sidx[0:new_count]), and the compaction below reads
+            # that prefix and no slot past its last block (ISSUE 46).
             rm = _rank_merge(seen, seen_count, acc_keys, AccCap, SC, K,
                              n_prefix=jnp.minimum(acc_n, AccCap))
-            with jax.named_scope("jaxmc.compact"):
-                new_count = rm["new_count"]
-                nvalid = jnp.arange(AccCap) < new_count
-                new_rows = jnp.take(acc_rows,
-                                    jnp.clip(rm["nk_sidx"], 0, AccCap - 1),
-                                    axis=0)
-                new_rows = jnp.where(nvalid[:, None], new_rows, SENTINEL)
+            new_count = rm["new_count"]
             seen2 = rm["seen2"]
             seen_count2 = rm["seen_count2"]
 
-            with jax.named_scope("jaxmc.scan"):
-                # constraints: violating states stay fingerprinted in seen2
-                # but are discarded (not distinct / checked / explored).
-                # new_rows are PACKED; the predicate kernels read lanes
-                new_rows_u = plan.unpack_rows(new_rows) \
-                    if (con_fns or inv_fns) else new_rows
-                explore = nvalid
-                for nm, f in con_fns:
-                    explore = explore & jax.vmap(f)(new_rows_u)
-                explore_count = jnp.sum(explore, dtype=jnp.int32)
+            # ---- the next frontier: the level's new rows ----
+            # rm["nk_sidx"] names them FIRST and in key order, so the
+            # rows that exist are its prefix [0, new_count): they are
+            # gathered in blocks of RB bounded on that count
+            # (_gather_prefix, ISSUE 46), not over every AccCap slot.
+            if not con_fns:
+                # no CONSTRAINT can deselect a row: the new rows ARE the
+                # frontier, and the blocks write straight into it
+                explore_count = new_count
+                with jax.named_scope("jaxmc.compact"):
+                    front_rows, cblocks = _gather_prefix(
+                        acc_rows, rm["nk_sidx"], new_count, FCap, RB)
+            else:
+                with jax.named_scope("jaxmc.compact"):
+                    new_rows, nblocks = _gather_prefix(
+                        acc_rows, rm["nk_sidx"], new_count, AccCap, RB)
+                with jax.named_scope("jaxmc.scan"):
+                    # constraints: violating states stay fingerprinted
+                    # in seen2 but are discarded (not distinct / checked
+                    # / explored).  new_rows are PACKED; the predicate
+                    # kernels read lanes
+                    new_rows_u = plan.unpack_rows(new_rows)
+                    explore = jnp.arange(AccCap) < new_count
+                    for nm, f in con_fns:
+                        explore = explore & jax.vmap(f)(new_rows_u)
+                    explore_count = jnp.sum(explore, dtype=jnp.int32)
+                with jax.named_scope("jaxmc.compact"):
+                    # the rows a CONSTRAINT discards must leave the
+                    # frontier: a stable sort names the kept ones first
+                    # (over every slot: no cell prices this branch), and
+                    # the blocks gather only those
+                    idx4 = jnp.arange(AccCap, dtype=jnp.int32)
+                    ops4 = ((1 - explore.astype(jnp.int32)), idx4)
+                    comp4 = lax.sort(ops4, num_keys=1, is_stable=True)
+                    front_rows, cblocks = _gather_prefix(
+                        new_rows, comp4[1], explore_count, FCap, RB)
+                    cblocks = cblocks + nblocks
             stat = jnp.where((stat == ST_CONTINUE) &
                              (explore_count > FCap), ST_OVF_FRONT, stat)
-
-            with jax.named_scope("jaxmc.compact"):
-                idx4 = jnp.arange(AccCap, dtype=jnp.int32)
-                ops4 = ((1 - explore.astype(jnp.int32)), idx4)
-                comp4 = lax.sort(ops4, num_keys=1, is_stable=True)
-                fidx = comp4[1][:FCap]
-                front_rows = jnp.take(new_rows,
-                                      jnp.clip(fidx, 0, AccCap - 1), axis=0)
-                frontvalid = jnp.arange(FCap) < explore_count
-                front_rows = jnp.where(frontvalid[:, None], front_rows,
-                                       SENTINEL)
+            frontvalid = jnp.arange(FCap) < explore_count
 
             with jax.named_scope("jaxmc.scan"):
                 inv_bad_any = jnp.asarray(False)
@@ -3052,7 +3117,7 @@ class TpuExplorer:
                     explore_count, stat, inv_bad_which, bad_row, ovcode,
                     pora, porx, porm, rm["probe_blocks"],
                     rm["merge_blocks"],
-                    rm["sort_slots"] // _sort_unit(AccCap)) + \
+                    rm["sort_slots"] // _sort_unit(AccCap), cblocks) + \
                 ((rm["window_blocks"],) if windowed else ())
 
         def run(seen, seen_count, frontier, fcount, distinct,
@@ -3072,17 +3137,18 @@ class TpuExplorer:
                 (seen, seen_count, frontier, fcount, distinct,
                  gen_lo, gen_hi, depth, lvls, stat, which, brow,
                  ovcode, pora, porx, porm, pblocks, mblocks,
-                 sunits) = carry[:19]
+                 sunits, cblocks) = carry[:20]
                 lvl = level(seen, seen_count, frontier, fcount)
                 (seen2, seen_count2, front2, fcount2, gen_l, kept,
                  lstat, lwhich, lbrow, lovcode, lpora, lporx,
-                 lporm, lpblocks, lmblocks, lsunits) = lvl[:16]
+                 lporm, lpblocks, lmblocks, lsunits,
+                 lcblocks) = lvl[:17]
                 ovf = (lstat == ST_OVF_SEEN) | (lstat == ST_OVF_FRONT) | \
                     (lstat == ST_OVF_ACC) | (lstat == ST_OVF_VC) | \
                     (lstat == ST_OVF_LANES)
                 logged2 = ()
                 if LogCap:
-                    log, log_n, lvl_rows = carry[19:22]
+                    log, log_n, lvl_rows = carry[20:23]
                     with jax.named_scope("jaxmc.trace.log"):
                         # a level that ends the search in a verdict is
                         # not logged: the walk reads the levels BEFORE
@@ -3140,11 +3206,12 @@ class TpuExplorer:
                         jnp.where(lstat == ST_OVF_LANES, lovcode,
                                   ovcode), pora2, porx2, porm2,
                         # work done, not work kept: a rolled-back level
-                        # sorted its rung and searched and built its
-                        # blocks too
+                        # sorted its rung and searched, built and
+                        # gathered its blocks too
                         pblocks + lpblocks, mblocks + lmblocks,
-                        sunits + lsunits) + logged2 + \
-                    ((carry[-1] + lvl[16],) if windowed else ())
+                        sunits + lsunits, cblocks + lcblocks) + \
+                    logged2 + \
+                    ((carry[-1] + lvl[17],) if windowed else ())
 
             carry0 = (seen, seen_count, frontier, fcount, distinct,
                       gen_lo, gen_hi, depth, jnp.int32(0),
@@ -3152,7 +3219,7 @@ class TpuExplorer:
                       jnp.full((PW,), SENTINEL, jnp.int32),
                       jnp.int32(0), jnp.int32(0), jnp.int32(0),
                       jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                      jnp.int32(0))
+                      jnp.int32(0), jnp.int32(0))
             if LogCap:
                 carry0 += logged + (jnp.zeros((LV,), jnp.int32),)
             if windowed:
@@ -3162,7 +3229,7 @@ class TpuExplorer:
             out = lax.while_loop(cond, body, carry0)
             (seen, seen_count, frontier, fcount, distinct, gen_lo,
              gen_hi, depth, _, stat, which, brow, ovcode, pora, porx,
-             porm, pblocks, mblocks, sunits) = out[:19]
+             porm, pblocks, mblocks, sunits, cblocks) = out[:20]
             # indices 0-8 are the PR-6 summary; 9-11 are the per-
             # dispatch POR counters (ISSUE 18; zero when POR is off);
             # 12 is the query blocks the merge's probe searched over
@@ -3170,17 +3237,18 @@ class TpuExplorer:
             # the blocks of seen2 it built (ISSUE 29:
             # search.slots_merged), 14 the slots its key sorts were
             # given, in units of _sort_unit(AccCap) (ISSUE 42:
-            # search.slots_sorted)
+            # search.slots_sorted), 15 the blocks of RB rows the
+            # compaction's gathers ran (ISSUE 46: search.slots_compacted)
             summary = jnp.stack([stat, seen_count, fcount, distinct,
                                  gen_lo, gen_hi, depth, which, ovcode,
                                  pora, porx, porm, pblocks, mblocks,
-                                 sunits])
+                                 sunits, cblocks])
             if LogCap:
-                # ... and, where the log is kept, 15 is the rows it
-                # holds and 16.. the rows each level of the dispatch
+                # ... and, where the log is kept, 16 is the rows it
+                # holds and 17.. the rows each level of the dispatch
                 # added (the host keeps the levels' offsets from them):
                 # one block, one fetch
-                log, log_n, lvl_rows = out[19:22]
+                log, log_n, lvl_rows = out[20:23]
                 summary = jnp.concatenate([summary, log_n[None],
                                            lvl_rows])
             if windowed:
@@ -3820,8 +3888,11 @@ class TpuExplorer:
                                                         lo=CH))
         # VC can never usefully exceed the dense candidate-grid size
         # A*CH (and must not: [:VC] slices of C-row arrays assume VC<=C);
-        # AccCap must cover both one VC block past acc_n and the [:FCap]
-        # slice of the accumulator taken for the next frontier
+        # AccCap must cover one VC block past acc_n, and is kept no
+        # smaller than FCap: a level's new rows sit among its AccCap
+        # candidates, so a frontier the accumulator could not fill
+        # would be capacity nothing uses (the compaction's block,
+        # _compact_block_rows, is cut to FCap either way)
         caps["VC"] = min(caps["VC"], self.A * CH)
         caps["AccCap"] = max(caps["AccCap"], 2 * caps["VC"], caps["FCap"])
         if keep_log:
@@ -4047,14 +4118,15 @@ class TpuExplorer:
                 probe_blocks = int(summary[12])
                 merge_blocks = int(summary[13])
                 sort_units = int(summary[14])
+                compact_blocks = int(summary[15])
                 if keep_log:
                     # a level that added no row ended the search, was
                     # rolled back or never ran
                     logged_in = lvl_off[-1]
-                    for added in summary[16:]:
+                    for added in summary[17:]:
                         if added:
                             lvl_off.append(lvl_off[-1] + int(added))
-                    assert lvl_off[-1] == int(summary[15])
+                    assert lvl_off[-1] == int(summary[16])
                     self._count_logged(lvl_off[-1] - logged_in)
                 # cold-tier filter (ISSUE 12): after a spill the device
                 # table restarted empty, so a committed level's frontier
@@ -4123,6 +4195,11 @@ class TpuExplorer:
             tel.counter("search.slots_merged", merge_blocks
                         * _merge_block_rows(caps["SC"]))
             tel.counter("search.rows_new", distinct - dist_in)
+            # ... and gathered the new rows into the next frontier in
+            # blocks bounded by their count (ISSUE 46): counted there too
+            tel.counter("search.slots_compacted", compact_blocks
+                        * _compact_block_rows(caps["AccCap"],
+                                              caps["FCap"]))
             if redo_after_spill and generated > gen_in:
                 # the level a spill rolled back has now run a second
                 # time (one level a dispatch once tiers are active):
@@ -4192,13 +4269,14 @@ class TpuExplorer:
                         (caps[what] - old, self.PW), SENTINEL,
                         jnp.int32)])
                 # keep the cap invariants: AccCap >= 2*VC (block-append
-                # headroom) and AccCap >= FCap ([:FCap] frontier slice of
-                # the accumulator) — by x4 steps of AccCap's OWN ladder,
-                # so that what a cold run leaves follows from the model's
-                # levels alone and not from which overflow came first (a
-                # bare max() put AccCap on FCap's ladder: the 4-process
-                # rung then ended at AccCap 2^24 where 2^23 holds it,
-                # after 9 programs where 7 do: PERF.md §6, PR 30)
+                # headroom) and AccCap >= FCap (the frontier is filled
+                # from the accumulator's rows) — by x4 steps of AccCap's
+                # OWN ladder, so that what a cold run leaves follows from
+                # the model's levels alone and not from which overflow
+                # came first (a bare max() put AccCap on FCap's ladder:
+                # the 4-process rung then ended at AccCap 2^24 where 2^23
+                # holds it, after 9 programs where 7 do: PERF.md §6,
+                # PR 30)
                 while caps["AccCap"] < max(2 * caps["VC"], caps["FCap"]):
                     caps["AccCap"] *= 4
                 self.log(f"-- resident: growing {what} to {caps[what]} "
