@@ -46,6 +46,11 @@ smaller rungs, and say so:
   E  four chips, when >= 4 devices are visible: the sharded engine on
      the REAL rung in one process; with fewer the leg SAYS it did not
      run (a statement, not a pass)
+  F  cfg SYMMETRY over a real group: the resident engine on
+     specs/transfer_symmetry_5p3.cfg (five interchangeable processes,
+     S5: 29,382 / 9,336 where the unreduced model has 545,822 states);
+     the device must canonicalise in the SORTED form
+     (compile/symmetry2.py) — an unreduced fallback is a failure
 
 Any leg that fails, times out, demotes, or reports a platform other
 than the one asked for ends the smoke non-zero with no result line.
@@ -87,6 +92,7 @@ RUNGS = {
         "D": ("transfer_scaled.tla", "transfer_scaled.cfg"),
         "D_variant": ("batchtoy.tla", "batchtoy_b.cfg"),
         "E": ("transfer_scaled.tla", "transfer_scaled_4p.cfg"),
+        "F": ("transfer_symmetry.tla", "transfer_symmetry_5p3.cfg"),
     },
     "rehearsal": {
         "A": ("constoy.tla", "constoy.cfg"),
@@ -96,6 +102,7 @@ RUNGS = {
         "D": ("constoy.tla", "constoy.cfg"),
         "D_variant": ("batchtoy.tla", "batchtoy_b.cfg"),
         "E": ("symtoy_scaled.tla", "symtoy_scaled.cfg"),
+        "F": ("transfer_symmetry.tla", "transfer_symmetry_3p4.cfg"),
     },
 }
 
@@ -425,6 +432,28 @@ class Smoke:
              f"creation")
 
 
+    def leg_f(self) -> None:
+        say(f"leg F: cfg SYMMETRY on the resident engine, "
+            f"{self.rungs['F'][1]}: the sorted canonicaliser")
+        case = self.pin("F")
+        a, out = self.check("F_sym", "F", ["--resident", "--no-trace"],
+                            no_deadlock=case.no_deadlock)
+        g = a["gauges"]
+        need(g.get("symmetry.form") == "sorted",
+             f"F_sym: symmetry.form={g.get('symmetry.form')!r}, group "
+             f"order {g.get('symmetry.group_order')!r}: the reduction "
+             f"did not run on the device in the sorted form")
+        need("SYMMETRY NOT applied" not in out,
+             "F_sym: the run warns that SYMMETRY was not applied")
+        self.assert_counts("F_sym", a["result"], case)
+        need(a["counters"].get("search.canon_rows")
+             == a["result"].get("generated"),
+             f"F_sym: search.canon_rows="
+             f"{a['counters'].get('search.canon_rows')!r}")
+        say(f"  [F_sym] symmetry.form=sorted group_order="
+            f"{g.get('symmetry.group_order')}")
+
+
 def _tail(path: str, n: int = 600) -> str:
     try:
         with open(path, errors="replace") as fh:
@@ -438,7 +467,7 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse-on-cpu", action="store_true",
                     help="toy-size plumbing rehearsal on XLA:CPU — NOT "
                          "a chip run, prints no result object")
-    ap.add_argument("--legs", default="A,B,C,D,E",
+    ap.add_argument("--legs", default="A,B,C,D,E,F",
                     help="comma-separated subset (debugging one leg; "
                          "a partial run prints no result object)")
     ap.add_argument("--leg-timeout", type=float, default=_LEG_TIMEOUT_S,
@@ -474,7 +503,7 @@ def main(argv=None) -> int:
     if smoke.rehearsal:
         say("chip_smoke: rehearsal passed — NOT a chip run, no result")
         return 0
-    if legs != ["A", "B", "C", "D", "E"]:
+    if legs != ["A", "B", "C", "D", "E", "F"]:
         say("chip_smoke: partial run — no result")
         return 0
     print(json.dumps({"ok": True, "device": smoke.device}), flush=True)

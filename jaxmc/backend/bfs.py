@@ -867,6 +867,14 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
                 merge_blocks=merge_blocks, sort_slots=sort_slots)
 
 
+@jax.named_scope("jaxmc.canon")
+def _canon(canon_fn, rows):
+    """The cfg SYMMETRY canonicaliser (compile/symmetry2.py) over a block
+    of unpacked rows, under a device scope of its own inside
+    `jaxmc.keys`."""
+    return canon_fn(rows)
+
+
 @jax.named_scope("jaxmc.keys")
 def _keys_of_rows(plan, view_fn, canon_fn, fp_mode, rows, valid):
     """(keys, packed_rows, pack_ovf) for a block of UNPACKED rows.
@@ -900,13 +908,13 @@ def _keys_of_rows(plan, view_fn, canon_fn, fp_mode, rows, valid):
         # 2-process SYMMETRY+VIEW repro, 17/9 vs the interp's 12/6)
         vrows = rows
         if canon_fn is not None:
-            vrows = jnp.where(valid[:, None], canon_fn(rows),
+            vrows = jnp.where(valid[:, None], _canon(canon_fn, rows),
                               rows)
         kb = jax.vmap(view_fn)(vrows)
         if kb.ndim == 1:
             kb = kb[:, None]
     elif canon_fn is not None:
-        crows = jnp.where(valid[:, None], canon_fn(rows), rows)
+        crows = jnp.where(valid[:, None], _canon(canon_fn, rows), rows)
         kb, cpovf = plan.pack_rows(crows)
         kb = jnp.where(valid[:, None], kb, SENTINEL)
         pack_ovf = pack_ovf | jnp.any(cpovf & valid)
@@ -1361,6 +1369,14 @@ class TpuExplorer:
         self.sym_identity = (model.symmetry is not None
                              and self.canon_fn is None
                              and self._sym_fallback is None)
+        # which canonicaliser runs on the device: "sorted", "unrolled"
+        # (symmetry2.build_canon2) or "none" (identity group, fallback,
+        # no SYMMETRY); disclosed where a cfg declares SYMMETRY
+        self.sym_form = getattr(self.canon_fn, "form", "none")
+        if model.symmetry is not None:
+            tel.gauge("symmetry.form", self.sym_form)
+            tel.gauge("symmetry.group_order",
+                      getattr(self.canon_fn, "group_order", 1))
         # predicates likewise force-traced; uncompilable ones demote to
         # host-side interpreter evaluation over decoded rows (hybrid).
         # A TRACE-TIME BUDGET (JAXMC_PRED_TRACE_BUDGET seconds, default
@@ -1875,7 +1891,7 @@ class TpuExplorer:
         "backend_desc", "bounds", "layout", "kc", "plan", "compiled",
         "actions", "arms", "_ca_arm", "fb_arms", "fb_invs", "fb_cons",
         "inv_fns", "constraint_fns", "canon_fn", "_sym_fallback",
-        "sym_identity", "view_fn", "view_width", "refiners",
+        "sym_identity", "sym_form", "view_fn", "view_width", "refiners",
         "unrefined", "live_obligations", "live_unsupported",
         "collect_edges", "hybrid", "_demotable", "labels_flat",
         "arm_verdicts", "A", "W", "PW", "K", "fp_mode", "key_width",
@@ -2140,6 +2156,24 @@ class TpuExplorer:
         it with its init states and tables."""
         return partial(_keys_of_rows, self.plan, self.view_fn,
                        self.canon_fn, self.fp_mode)
+
+    def _canon_host(self, rows_np: np.ndarray) -> np.ndarray:
+        """The canonicaliser over host rows (the initial states), numpy in
+        and out, through ONE jitted program in blocks of at most 2^16
+        rows: a cfg whose Init is a function space hands over a quarter
+        of a million rows, and an eager call traces op by op at that
+        size."""
+        n = len(rows_np)
+        blk = min(_pow2_at_least(n, lo=8), 1 << 16)
+        jf = jax.jit(self.canon_fn)
+        out = np.empty((n, self.W), np.int32)
+        buf = np.empty((blk, self.W), np.int32)
+        for i in range(0, n, blk):
+            part = rows_np[i:i + blk]
+            buf[:len(part)] = part
+            buf[len(part):] = part[:1]
+            out[i:i + blk] = np.asarray(jf(buf))[:len(part)]
+        return out
 
     def _host_keys(self, rows_np):
         """Host-side (keys, packed, pack_ovf) over unpacked numpy rows —
@@ -3484,6 +3518,9 @@ class TpuExplorer:
             return cached + (None,)
         layout = self.layout
         raw = [layout.encode(st) for st in self.init_states]
+        # TLC counts EVERY initial state as generated, also one whose
+        # SYMMETRY orbit or VIEW value an earlier one already stored
+        self._init_generated = len(raw)
         if raw and self.canon_fn is not None:
             # cfg SYMMETRY: dedup/count init states by their orbit's
             # canonical representative, matching the interp's add_state
@@ -3492,7 +3529,7 @@ class TpuExplorer:
             # device counts and seed `seen` with duplicate canonical
             # fingerprints, breaking the sorted-unique invariant the
             # resident rank-merge relies on.
-            raw = list(np.asarray(self.canon_fn(np.stack(raw))))
+            raw = list(self._canon_host(np.stack(raw)))
         if raw and self.view_fn is not None:
             # cfg VIEW: init states sharing a view value count ONCE
             # (TLC fingerprints the view) — keep the first state per key
@@ -3532,6 +3569,12 @@ class TpuExplorer:
         self.log(f"Finished computing initial states: {distinct} distinct "
                  f"state{'s' if distinct != 1 else ''} generated.")
         self._init_prep = (init_rows, explored_init, n_init)
+        # the rows are what every later search reads: let the interpreter
+        # states go.  A cfg whose Init is a function space keeps a
+        # quarter of a million of them (1.3 M dicts and functions), and
+        # every full collection of the garbage collector walks them all —
+        # 0.4 s here, a search in fifty 1.3 s late on the chip (ISSUE 47)
+        self.init_states = None
         return init_rows, explored_init, n_init, None
 
     # ---- checkpoint/resume (device backends) ----
@@ -3604,7 +3647,8 @@ class TpuExplorer:
                         for k, v in por_plan.items()),
                     self.donate, self.seen_cap is not None,
                     self.seen_mode_req, self._lift_names,
-                    self.canon_fn is not None, self.sym_identity,
+                    self.canon_fn is not None, self.sym_form,
+                    self.sym_identity,
                     self._sym_fallback, self.sample_cfg,
                     sorted(vars(self.bounds).items()),
                     getattr(self, "D", None),
@@ -3840,7 +3884,7 @@ class TpuExplorer:
                 self._prepare_init(t0, warnings)
         if err is not None:
             return err
-        generated = n_init
+        generated = self._init_generated
         distinct = len(explored_init)
 
         CH = _pow2_at_least(self.chunk, lo=64)
@@ -4441,7 +4485,7 @@ class TpuExplorer:
             self._prepare_init(t0, warnings)
         if err is not None:
             return err
-        generated = n_init
+        generated = self._init_generated
         distinct = len(explored_init)
 
         store = native_store.FingerprintStore()
@@ -5181,7 +5225,7 @@ class TpuExplorer:
                 self._prepare_init(t0, warnings)
         if err is not None:
             return err
-        generated = n_init
+        generated = self._init_generated
         distinct = len(explored_init)
 
         # seed.keys_s / .tables_s / .upload_s: the host's pieces of the
@@ -5538,6 +5582,10 @@ class TpuExplorer:
         self._por_finish(self._por_stats["ample"],
                          self._por_stats["expanded"],
                          self._por_stats["masked"], distinct)
+        if self.canon_fn is not None:
+            # every generated state went through the canonicaliser once:
+            # successors on the device, the initial states on the host
+            tel.counter("search.canon_rows", generated)
         seen_mode = "fingerprint" if self.fp_mode else "exact"
         collision_p = None
         if self.fp_mode:

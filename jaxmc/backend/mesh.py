@@ -1896,7 +1896,7 @@ class MeshExplorer(TpuExplorer):
         ends when the fills are enqueued.  Returns what
         `_mesh_supersteps` takes."""
         D, K, PW = self.D, self.K, self.PW
-        generated = len(explored_mask)
+        generated = self._init_generated
         distinct = int(explored_mask.sum())
         hint = self._mesh_caps_hint
         # the host's pieces of the seed on the program's own clock
@@ -2495,7 +2495,7 @@ class MeshExplorer(TpuExplorer):
             self._prepare_init(t0, warnings)
         if err is not None:
             return err
-        generated = n_init
+        generated = self._init_generated
         explored_mask = np.zeros(n_init, bool)
         explored_mask[explored_init] = True
         distinct = int(explored_mask.sum())

@@ -383,7 +383,18 @@ def main(argv=None) -> int:
 
     c = sub.add_parser("check", help="model-check a spec")
     c.add_argument("spec")
-    c.add_argument("--cfg", default=None)
+    c.add_argument("--cfg", default=None,
+                   help="the model's .cfg (default: <spec>.cfg). A "
+                        "SYMMETRY line reduces on every backend; the "
+                        "device engines canonicalise rows by SORTING "
+                        "the per-member sub-vectors where the group is "
+                        "a product of full symmetric groups "
+                        "(Permutations(S)) whose members the state only "
+                        "indexes by — any |S| — and otherwise by "
+                        "unrolling one transform per group element, up "
+                        "to JAXMC_SYM_GROUP_LIMIT (64) of them; above "
+                        "that the device search runs UNREDUCED with a "
+                        "warning (gauge symmetry.form says which)")
     c.add_argument("-I", "--include", action="append", default=[],
                    help="extra module search directories (MC shims "
                         "extending reference specs)")

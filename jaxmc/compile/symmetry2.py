@@ -17,10 +17,23 @@ Encodings that cannot be permuted exactly (a permuted domain member
 missing from a layout universe, heterogeneous per-key function specs
 inside one orbit) raise CompileError; TpuExplorer then falls back to the
 unreduced search with the existing SYMMETRY warning.
+
+Two forms compute that minimum (`build_canon2`, ISSUE 47).  The UNROLLED
+form applies every non-identity element of the closed group to the row
+and keeps the least result: general, and a program that grows with the
+order of the group, so it refuses groups over JAXMC_SYM_GROUP_LIMIT.  The
+SORTED form serves what users write most — `Permutations(S)` over a set
+of interchangeable processes that the state only INDEXES by (function
+domains, membership lanes): permuting the members then permutes
+equal-width chunks of the row, and the least row is the one whose
+members stand sorted by their chunks, which a fixed compare-exchange
+network over |S| members finds elementwise, whatever |S|! is.  Both give
+the same row bit for bit where both apply (tests/test_symmetry_sort.py).
 """
 
 from __future__ import annotations
 
+import math
 import os
 from typing import Callable, Dict, Optional
 
@@ -269,12 +282,214 @@ def _seg_tf(spec: VS, pd: Dict, uni: EnumUniverse,
     raise AssertionError(k)
 
 
+class _NotSortable(Exception):
+    """The sorted form does not apply to this model and layout; the
+    message says why (the unrolled form takes over)."""
+
+
+def _network(n: int):
+    """Compare-exchange pairs (i < j) of Batcher's odd-even merge sort
+    over n wires: O(n log^2 n) comparators, fixed whatever the data."""
+    pairs = []
+    p = 1
+    while p < n:
+        k = p
+        while k >= 1:
+            for j in range(k % p, n - k, 2 * k):
+                for i in range(min(k, n - j - k)):
+                    if (i + j) // (2 * p) == (i + j + k) // (2 * p):
+                        pairs.append((i + j, i + j + k))
+            k //= 2
+        p *= 2
+    return pairs
+
+
+def _may_hold(av, ids) -> bool:
+    """Can a value the bounds analyzer abstracts as `av`
+    (analyze/bounds.py, "abstract values") hold one of the model values
+    `ids` ANYWHERE inside it?  Unknown means yes."""
+    if av is None:
+        return False                    # an empty container's element
+    tag = av[0]
+    if tag in ("int", "bool"):
+        return False
+    if tag == "enum":
+        return av[1] is None or any(id(v) in ids for v in av[1])
+    if tag in ("set", "seq"):
+        return _may_hold(av[1], ids)
+    if tag == "fun":
+        return _may_hold(av[1], ids) or _may_hold(av[2], ids)
+    if tag == "rec":
+        return any(_may_hold(f, ids) for _, f in av[1])
+    return True                         # blob, or a tag not known here
+
+
+def _value_av(av, key):
+    """The abstract value of f[key] for a function or record `av`."""
+    if av is not None and av[0] == "fun":
+        return av[2]
+    if av is not None and av[0] == "rec":
+        return dict(av[1]).get(key, ("blob",))
+    return ("blob",)
+
+
+def _member_lanes(model, layout, members) -> list:
+    """For each of `members` (model values, one factor of the group), the
+    row lanes that belong to it, ascending; raises _NotSortable where the
+    members are not mere POSITIONS of the layout.
+
+    A member is a position where it is a key of a `fcn` / `pfcn` lane
+    block or an element of a `set` block's universe: permuting the
+    members then permutes equal-width chunks of the row.  It is a VALUE
+    where an enum lane can hold it (`owner` in specs/symtoy.tla): that is
+    read off the bounds analyzer's converged abstract state
+    (`model._bounds_report`), whose enum components list every value they
+    can hold; no report, or one that cannot say, is taken as "can".
+
+    The order of the members is the order of their positions, which has
+    to be the same in every block: within a block the chunks then stand
+    member by member, so that sorting the members by their lanes in row
+    order minimises the row lexicographically (build_canon2)."""
+    rep = getattr(model, "_bounds_report", None)
+    env = getattr(rep, "env", None)     # a cohort's merged report has none
+    if env is None or not rep.converged:
+        raise _NotSortable("no converged bounds report says which lanes "
+                           "can hold a member")
+    ids = {id(m) for m in members}
+    lanes = {id(m): [] for m in members}
+    order = []
+
+    def clean(spec, av, why):
+        """No member below here, as a position or as a value."""
+        if _may_hold(av, ids):
+            raise _NotSortable(f"{why} can hold a member as a value")
+        stack = [spec]
+        while stack:
+            sp = stack.pop()
+            if any(id(k) in ids for k in sp.dom):
+                raise _NotSortable(f"{why} is indexed by members again")
+            stack.extend(sp.elems)
+            stack.extend(x for x in (sp.elem, sp.val) if x is not None)
+            stack.extend(f for _, fs in sp.variants for f in fs)
+
+    def positions(spec, why):
+        here = [k for k in spec.dom if id(k) in ids]
+        if here and len(here) != len(members):
+            raise _NotSortable(f"{why} holds some members and not others")
+        if here:
+            if not order:
+                order.extend(here)
+            if [id(k) for k in here] != [id(k) for k in order]:
+                raise _NotSortable(f"{why} orders the members differently")
+        return bool(here)
+
+    def walk(spec, off, av, why):
+        k = spec.kind
+        if k in ("justempty", "int", "bool"):
+            return
+        if k == "enum":
+            if _may_hold(av, ids):
+                raise _NotSortable(f"{why} can hold a member as a value")
+        elif k in ("fcn", "pfcn"):
+            bit = 1 if k == "pfcn" else 0
+            keyed = positions(spec, why)
+            chunk = None
+            for key, el in zip(spec.dom, spec.elems):
+                sub = _value_av(av, key)
+                if keyed and id(key) in ids:
+                    if chunk is not None and el != chunk:
+                        raise _NotSortable(
+                            f"{why} has members of different shapes")
+                    chunk = el
+                    clean(el, sub, f"{why}[{key}]")
+                    lanes[id(key)].extend(
+                        range(off, off + bit + el.width))
+                else:
+                    walk(el, off + bit, sub, f"{why}[{key}]")
+                off += bit + el.width
+        elif k == "set":
+            if positions(spec, why):
+                for i, key in enumerate(spec.dom):
+                    if id(key) in ids:
+                        lanes[id(key)].append(off + i)
+        else:   # seq, growset, union, kvtable: no member below, at all
+            clean(spec, av, why)
+
+    off = 0
+    for v in layout.vars:
+        walk(layout.specs[v], off, env.get(v, ("blob",)), v)
+        off += layout.specs[v].width
+    if not order:
+        raise _NotSortable("the members index no lane of the layout")
+    return [lanes[id(m)] for m in order]
+
+
+def _sorted_canon(factors) -> Callable:
+    """Canonicaliser for a product of full symmetric groups whose members
+    are positions: `factors` holds, for each group, its members' lanes
+    ([n][k], `_member_lanes`).  Each group's members are sorted by their
+    k lanes (lexicographically, signed, as `lex_lt` below compares rows)
+    with a fixed compare-exchange network, elementwise over the batch:
+    no gather, no table of permutations, a program whose size does not
+    know the order of the group."""
+    def lex_lt(a, b):
+        lt = a[-1] < b[-1]
+        for x, y in zip(reversed(a[:-1]), reversed(b[:-1])):
+            lt = (x < y) | ((x == y) & lt)
+        return lt
+
+    def canon_rows(rows):
+        rows = jnp.asarray(rows)
+        cols = [rows[:, i] for i in range(rows.shape[1])]
+        for lanes in factors:
+            sub = [[cols[i] for i in member] for member in lanes]
+            for i, j in _network(len(sub)):
+                a, b = sub[i], sub[j]
+                swap = lex_lt(b, a)
+                sub[i] = [jnp.where(swap, y, x) for x, y in zip(a, b)]
+                sub[j] = [jnp.where(swap, x, y) for x, y in zip(a, b)]
+            for member, vals in zip(lanes, sub):
+                for i, v in zip(member, vals):
+                    cols[i] = v
+        return jnp.stack(cols, axis=1)
+    return canon_rows
+
+
+def _tagged(fn: Callable, form: str, group_order: int) -> Callable:
+    """`fn` with what the engines disclose about it: `form` ("sorted" or
+    "unrolled": part of `TpuExplorer._program_sig`) and `group_order`
+    (elements of the group, the identity included)."""
+    fn.form, fn.group_order = form, group_order
+    return fn
+
+
 def build_canon2(model, layout) -> Optional[Callable]:
-    """Canonicalizer over encoded rows: vmapped fn(rows [N, W]) -> rows,
-    each row replaced by the lexicographic minimum of its symmetry
-    orbit. None when the model declares no (non-identity) symmetry.
-    Raises CompileError when some lane encoding cannot be permuted."""
-    from ..sem.symmetry import symmetry_group
+    """Canonicalizer over encoded rows: fn(rows [N, W]) -> rows, each
+    row replaced by the lexicographic minimum of its symmetry orbit.
+    None when the model declares no (non-identity) symmetry.  Raises
+    CompileError when some lane encoding cannot be permuted.
+
+    Two forms, chosen by the model alone (the result's `.form`):
+
+    "sorted" — the group is a product of full symmetric groups
+    (`sem.symmetry.symmetric_factors`: what `Permutations(S)` closes to)
+    and the members are mere positions of the layout (`_member_lanes`).
+    Permuting members permutes equal chunks of the row, block by block
+    in one member order, so the least row of the orbit is the one whose
+    members stand sorted by their lanes in row order: a sorting network
+    over |S| members (`_sorted_canon`).
+
+    "unrolled" — everything else: one row transform per non-identity
+    element of the closed group, and the least of their results."""
+    from ..sem.symmetry import symmetric_factors, symmetry_group
+    factors = symmetric_factors(model)
+    if factors is not None:
+        try:
+            lanes = [_member_lanes(model, layout, o) for o in factors]
+            return _tagged(_sorted_canon(lanes), "sorted", math.prod(
+                math.factorial(len(o)) for o in factors))
+        except _NotSortable:
+            pass
     perms = symmetry_group(model)
     if not perms:
         return None
@@ -325,4 +540,4 @@ def build_canon2(model, layout) -> Optional[Callable]:
             best = jnp.where(lex_lt(cand, best), cand, best)
         return best
 
-    return jax.vmap(canon_row)
+    return _tagged(jax.vmap(canon_row), "unrolled", len(perms) + 1)

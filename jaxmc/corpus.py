@@ -321,6 +321,22 @@ CASES: List[Case] = [
          distinct=10725, generated=65365, jax="yes", mode="compiled",
          res_caps={"SC": 1 << 15, "FCap": 1 << 12, "AccCap": 1 << 14,
                    "VC": 1 << 13, "chunk": 1024}),
+    # SYMMETRY over a real group in the SORTED form (ISSUE 47,
+    # compile/symmetry2.py): the transfer race under Permutations(Procs),
+    # S3 and S5.  Counts are TLC's with symmetry — every initial state
+    # generated, orbits distinct — as bench/reference/
+    # transfer_symmetry.py and the exact interpreter give them
+    # (tests/test_symmetry_sort.py); unreduced 5,799 and 545,822 distinct
+    Case("specs/transfer_symmetry.tla", root="repo",
+         cfg="specs/transfer_symmetry_3p4.cfg",
+         distinct=1148, generated=2369, jax="yes", mode="compiled",
+         res_caps={"SC": 1 << 12, "FCap": 256, "AccCap": 1 << 11,
+                   "VC": 512, "chunk": 256}),
+    Case("specs/transfer_symmetry.tla", root="repo",
+         cfg="specs/transfer_symmetry_5p3.cfg",
+         distinct=9336, generated=29382, jax="yes", mode="compiled",
+         res_caps={"SC": 1 << 14, "FCap": 1 << 11, "AccCap": 1 << 14,
+                   "VC": 1 << 12, "chunk": 512}),
     # device SYMMETRY toys (orbit-canonical counts; deadlock expected
     # when every process exhausts its turns)
     Case("specs/symtoy.tla", root="repo", cfg="specs/symtoy.cfg",
@@ -503,7 +519,7 @@ def run_case(case: Case, backend: str = "interp"):
             sym_note = ""
             if model.symmetry is not None:
                 if ex.canon_fn is not None:
-                    sym_note = ", sym=device-reduced"
+                    sym_note = f", sym=device-reduced ({ex.sym_form})"
                 elif ex._sym_fallback:
                     sym_note = (", sym=UNREDUCED-FALLBACK (counts "
                                 "diverge from TLC's reduced ones)")

@@ -104,23 +104,65 @@ def make_canonicalizer(model: Model):
     """cfg SYMMETRY (TLC.tla:13-14 Permutations): canonicalize each state
     to the least representative under the declared permutation set, the
     standard symmetry reduction (SURVEY.md §5). Returns None when no
-    symmetry is declared or every permutation is the identity."""
+    symmetry is declared or every permutation is the identity.
+
+    The least of the orbit is found over the whole closed group, variable
+    by variable: a state's key is the tuple of its variables' keys
+    (`sort_key` of a tuple compares element by element), so the least
+    state takes the least image of the first variable, among the
+    permutations that give it the least image of the second, and so on.
+    Each stage — (variable, value, surviving permutations) -> (least image,
+    who gives it) — is remembered: a value such as `money`, which no step
+    of the transfer race changes, meets the whole group once and not once
+    a state.  Exact, and independent of the device's canonicalisers
+    (compile/symmetry2.py), whose oracle this is."""
     from ..sem.symmetry import symmetry_group
     perms = symmetry_group(model)
     if not perms:
         return None
+    perms = [None] + perms                 # index 0: the identity
+    alive_of: List[Tuple[int, ...]] = [tuple(range(len(perms)))]
+    alive_id: Dict[Tuple[int, ...], int] = {alive_of[0]: 0}
+    memo: Dict[Any, Any] = {}
+
+    def stage(var, val, aid):
+        hit = memo.get((var, val, aid))
+        if hit is None:
+            best = best_key = None
+            keep: List[int] = []
+            for i in alive_of[aid]:
+                cand = _apply_perm(val, perms[i]) if i else val
+                k = sort_key(cand)
+                if best_key is None or k < best_key:
+                    best, best_key, keep = cand, k, [i]
+                elif k == best_key:
+                    keep.append(i)
+            kept = tuple(keep)
+            if kept not in alive_id:
+                alive_id[kept] = len(alive_of)
+                alive_of.append(kept)
+            if len(memo) >= _CANON_MEMO_MAX:
+                memo.clear()
+            hit = memo[(var, val, aid)] = (best, alive_id[kept])
+        return hit
 
     def canon(state: Dict[str, Any]) -> Dict[str, Any]:
-        best = state
-        best_key = sort_key(tuple(state[v] for v in model.vars))
-        for pd in perms:
-            cand = {v: _apply_perm(state[v], pd) for v in model.vars}
-            k = sort_key(tuple(cand[v] for v in model.vars))
-            if k < best_key:
-                best, best_key = cand, k
-        return best
+        out, aid = {}, 0
+        for v in model.vars:
+            val = state[v]
+            if type(val) in (int, bool, str):
+                out[v] = val               # no permutation moves it
+            else:
+                out[v], aid = stage(v, val, aid)
+        return out
 
     return canon
+
+
+#: stages `make_canonicalizer` remembers before it starts over (values
+#: that never recur — message bags — would otherwise grow it with the
+#: state space)
+_CANON_MEMO_MAX = 1 << 18
 
 
 def liveness_setup(model: Model, refiners, view_expr):
@@ -542,9 +584,11 @@ class Explorer:
         init_count = 0
         for st in inits:
             sid, new = add_state(st, None, "Initial predicate", 0)
+            # TLC counts EVERY initial state as generated, also one whose
+            # SYMMETRY orbit or VIEW value an earlier one already stored
+            generated += 1
             if not new:
                 continue
-            generated += 1
             if sid is None:
                 continue  # discarded by CONSTRAINT
             init_count += 1

@@ -816,9 +816,9 @@ class ParallelExplorer(Explorer):
             init_count = 0
             for st in inits:
                 sid, new = add_state(st, None, "Initial predicate", 0)
+                generated += 1   # every initial state, as TLC counts
                 if not new:
                     continue
-                generated += 1
                 if sid is None:
                     continue  # discarded by CONSTRAINT
                 init_count += 1

@@ -111,7 +111,7 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       (`[demoted arms: <label>: <reason>; ...]`) whenever arms demote
       unpinned; a pinned case that slides toward the interpreter is a
       FAIL with detail "REGRESSION: expansion mode slid ...".
-    - symmetry disclosure is three-way: `sym=device-reduced`,
+    - symmetry disclosure is three-way: `sym=device-reduced (<form>)`,
       `sym=identity` (identity permutation group — no divergence), or
       `sym=UNREDUCED-FALLBACK (...)` (a genuine CompileError fallback;
       the only case where counts diverge from TLC's reduced ones).
@@ -662,6 +662,35 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       of the behaviour returned).  `search.table_bytes` counts the
       log's table ((LogCap + FCap) x PW x 4) and `search.seed_bytes`
       the initial frontier a second time, where a log is kept.
+
+  (PR 47, still jaxmc.metrics/4 — all additive/optional; SYMMETRY over a
+   real group, compile/symmetry2.py, ISSUE 47.  A cfg WITHOUT a SYMMETRY
+   line emits NONE of these, and its lowered programs are byte-identical
+   to what they were:)
+    - gauges `symmetry.form` — which canonicaliser the device runs:
+      "sorted" (the group is a product of full symmetric groups whose
+      members are mere positions of the layout: a sorting network over
+      the per-member sub-vectors), "unrolled" (one row transform per
+      element of the closed group, at most JAXMC_SYM_GROUP_LIMIT of
+      them) or "none" (identity group, or the unreduced fallback) — and
+      `symmetry.group_order` (elements of the group the device reduces
+      by, the identity included; 1 where the form is "none").  The
+      three-way disclosure above says the form too:
+      `sym=device-reduced (sorted)`.  The form is part of
+      `TpuExplorer._program_sig`.
+    - device scope `jaxmc.canon`, INSIDE `jaxmc.keys`, around the
+      canonicaliser on every engine (the level step, the resident loop,
+      the mesh, `bfs.host_keys`): a trace reduction that takes the
+      innermost `jaxmc.*` component (bench/spans.py) books its
+      operations apart from pack and fingerprint.
+    - counter `search.canon_rows` — rows the canonicaliser took in a
+      search: every generated state once (the successors on the device,
+      the initial states on the host's side of the same function), so it
+      equals the search's `generated`.
+    - `generated` itself counts EVERY initial state, as TLC does, also
+      one whose SYMMETRY orbit or VIEW value an earlier one already
+      stored (until PR 47 only the first of each was counted, on every
+      engine and in the interpreter alike); `distinct` is unchanged.
 """
 
 from __future__ import annotations

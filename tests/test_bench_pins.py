@@ -182,7 +182,8 @@ def _cold_spills(procs, max_money, cap):
 def test_there_are_resident_pins():
     assert {"transfer_scaled", "transfer_scaled_4p8",
             "transfer_scaled_4p", "transfer_scaled_4p8_ooc",
-            "transfer_violation_4p"} <= set(RESIDENT_PINS)
+            "transfer_violation_4p",
+            "transfer_symmetry_5p"} <= set(RESIDENT_PINS)
 
 
 @pytest.mark.parametrize("name", RESIDENT_PINS)
@@ -203,7 +204,10 @@ def test_res_caps_are_the_ladder_steps_that_hold_the_levels(name):
         # ... and what one cold run that keeps traces leaves, log and all
         assert _cold_ladder(levels, levels[0][0], dict(
             DEFAULTS, LogCap=LOG_DEFAULT))[-1] == pins["res_caps"]
-    assert sum(c for _, c, _ in levels) + levels[0][0] == pins["generated"]
+    # under SYMMETRY every initial state is generated and the orbits are
+    # the frontier (PR 47): the pins say how many were generated
+    assert sum(c for _, c, _ in levels) + pins.get(
+        "initial_generated", levels[0][0]) == pins["generated"]
     initial = pins["distinct"] - sum(new for _, _, new in levels)
     assert initial == levels[0][0]
     assert caps["VC"] == DEFAULTS["VC"]
@@ -246,6 +250,16 @@ def test_the_real_rungs_cold_ladder():
     assert len(ladder) == 8 and ladder[-1] == viol["res_caps"]
     assert [a["LogCap"] != b["LogCap"] for a, b in
             zip(ladder, ladder[1:])].count(True) == 1
+    # five processes under SYMMETRY Perms (PR 47): the orbits are the
+    # levels, 4,368 of the 248,832 initial states the first; eight
+    # programs, and the frontier stays at 2^20 — its largest, 1,038,158,
+    # is within 1 % of it — where the 4-process rung's needs 2^22
+    sym = _pins("transfer_symmetry_5p")
+    ladder = _cold_ladder(sym["levels"], sym["levels"][0][0], DEFAULTS)
+    assert len(ladder) == 8 and ladder[-1] == sym["res_caps"]
+    assert ladder[1] == dict(DEFAULTS, AccCap=1 << 19)
+    assert [p["FCap"] for p in ladder][-1] == 1 << 20
+    assert sym["initial_generated"] == sym["max_money"] ** sym["procs"]
 
 
 def _toy_cfg(tmp_path, procs, max_money):

@@ -218,9 +218,21 @@ def test_chip_smoke_rehearsal_check_legs(tmp_path):
     assert os.listdir(tmp_path / "cache" / "profiles")
 
 
+def test_chip_smoke_rehearsal_symmetry_leg(tmp_path):
+    """Leg F (ISSUE 47): cfg SYMMETRY on the resident engine must reduce
+    on the device in the sorted form, with the manifest's counts."""
+    r = _rehearse(tmp_path, "F")
+    assert "[F_sym] symmetry.form=sorted group_order=6" in r.stdout
+    assert "[F_sym] counts 2369 generated / 1148 distinct == pin" in r.stdout
+    with open(tmp_path / "out" / "F_sym.json") as fh:
+        art = json.load(fh)
+    assert art["gauges"]["symmetry.form"] == "sorted"
+    assert art["counters"]["search.canon_rows"] == 2369
+
+
 @pytest.mark.slow
 def test_chip_smoke_rehearsal_all_legs(tmp_path):
-    r = _rehearse(tmp_path, "A,B,C,D,E")
+    r = _rehearse(tmp_path, "A,B,C,D,E,F")
     assert "daemon_holds_device=False" in r.stdout
     assert "SIGTERM -> clean drain" in r.stdout
     assert "[E_mesh] counts" in r.stdout
