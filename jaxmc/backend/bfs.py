@@ -36,6 +36,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .. import obs
 from ..sem.modules import Model, satisfies_constraints
@@ -313,6 +314,29 @@ def _probe_window_rows(sc: int) -> int:
     return min(sc, _PROBE_WINDOW_ROWS)
 
 
+# The most key slots whose sorted keys _rank_merge's build gathers from
+# WHOLE (ISSUE 48).  A block of the build takes its new rows from the
+# level's sorted keys, and on the TPU v5e that gather is cheap only
+# while its operand fits the compiler's fast memory: a built row cost
+# 4.9 ns at N 2^21 (desk-recheck-4p8, [2^21, 5] sat there) and 17.6-27.1
+# at N 2^23 (168 MB, HBM; ledger, PR 47).  Over this many slots the
+# level's new keys are compacted once and a block reads a B-row window
+# of them (_rank_merge says how); at or under it the program is the one
+# it was.  The tests lower it to give toy shapes the window.
+_BUILD_WHOLE_KEYS = 1 << 21
+# the layout the seen table is held to in that form: dim 0 minor, {0,1}
+# in XLA's notation — the one it arrives in (_rank_merge says why)
+_WORDS_MAJOR = Layout(major_to_minor=(1, 0))
+
+
+def _build_form(n: int) -> str:
+    """Where a block of _rank_merge's build takes its new rows from, a
+    static function of the key slots alone (gauge merge.build_form):
+    "window", a slice of the level's compacted new keys, or "whole",
+    the sorted keys."""
+    return "window" if n > _BUILD_WHOLE_KEYS else "whole"
+
+
 @jax.named_scope("jaxmc.merge.probe")
 def _probe_by_block(seen, seen_count, keys, SC, n_live, sorted_keys):
     """_seen_probe, and beside its answers the number of blocks that
@@ -524,8 +548,11 @@ def _por_mask_np(found, cvalid, inst_arm, arm_safe, A, FC):
 # 265 / 252 / 119 / -, 2^18 337 / 89 / 50 / 185, 2^17 324 / 51 / 23 /
 # 119, 2^16 185 / 18.1 / 18.7 / 97, 2^15 181 / 15.6 / 16.9 / 91 —
 # not monotone (2^17 and 2^18 are slower than 2^19 at SC 2^22), so one
-# size that was best at every shape, not a share of SC.  The tests
-# lower it to cut toy tables into several blocks.
+# size that was best at every shape, not a share of SC.  A block's NEW
+# rows were gathered from all N sorted keys up to PR 47 — the 26 ns case
+# above at N 2^23 — and come from a B-row window of the compacted new
+# keys since (ISSUE 48, _BUILD_WHOLE_KEYS).  The tests lower it to cut
+# toy tables into several blocks.
 _MERGE_BLOCK_ROWS = 1 << 15
 
 
@@ -612,7 +639,7 @@ def _compact_blocks(n, cap: int, rb: int):
     return (jnp.minimum(n, cap) + (rb - 1)) // rb
 
 
-def _gather_prefix(rows, idx, n, cap: int, rb: int):
+def _gather_prefix(rows, idx, n, cap: int, rb: int, zero=None):
     """(out, blocks): out [cap, w] is rows[idx[i]] for i < min(n, cap),
     SENTINEL from there on — the compaction that follows the rows that
     exist (ISSUE 46): a lax loop over blocks of rb indices, bounded on
@@ -621,11 +648,16 @@ def _gather_prefix(rows, idx, n, cap: int, rb: int):
     past n and XLA cannot know: a jnp.take over all of idx fetches row
     0 for every slot that holds no row.  The last block starts at
     cap - rb where rb does not divide cap and writes its neighbour's
-    rows again: the same values."""
+    rows again: the same values.  `zero`, where given, is a 0 of the
+    operands' TYPE that the carry starts from (_rank_merge's: under
+    shard_map the loop leaves it device-varying)."""
     w = rows.shape[1]
     hi = rows.shape[0] - 1
     blocks = _compact_blocks(n, cap, rb)
     n = jnp.minimum(n, cap)
+    out0 = jnp.full((cap, w), SENTINEL, jnp.int32)
+    if zero is not None:
+        out0 = out0 + zero
 
     def block(b, out):
         at = jnp.minimum(b * rb, cap - rb)
@@ -636,8 +668,7 @@ def _gather_prefix(rows, idx, n, cap: int, rb: int):
         return lax.dynamic_update_slice(
             out, jnp.where(live[:, None], got, SENTINEL), (at, 0))
 
-    return lax.fori_loop(0, blocks, block,
-                         jnp.full((cap, w), SENTINEL, jnp.int32)), blocks
+    return lax.fori_loop(0, blocks, block, out0), blocks
 
 
 @jax.named_scope("jaxmc.merge.scatter")
@@ -676,6 +707,16 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
     blocks of sorted rows.  Every row past seen_count2 is left as it
     came in.
 
+    A block takes its new rows from a window too, where the level has
+    more key slots than _BUILD_WHOLE_KEYS (ISSUE 48; _build_form(N)):
+    the new keys are compacted once into newk [N, K], the j-th new key
+    at row j (_gather_prefix through nk_sidx, blocks of QB bounded on
+    new_count), and the new rows of B consecutive output rows are
+    consecutive rows of it, from the count of new rows below the block
+    on — one dynamic_slice of B rows and a gather inside it, as for the
+    seen rows.  At or under it a block gathers them from all N sorted
+    keys, the program it was.
+
     seen [SC, K] (validity lane first, prefix sorted by the K-1 data
     words; every row from seen_count on INVALID, lane != 0), seen_count
     traced scalar, keys [N, K] unsorted candidate keys (invalid rows:
@@ -705,6 +746,10 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
                  than _probe_window_rows(SC) and the probe has none.
       merge_blocks  blocks of seen2 built (× _merge_block_rows(SC) = the
                  slots behind `search.slots_merged`).
+      newkey_blocks  blocks of QB index slots the compaction of the new
+                 keys ran (ISSUE 48; × _probe_block_rows(N) = the slots
+                 behind `search.slots_keyed`); None where
+                 _build_form(N) is "whole" and there is none.
 
       sort_slots  key slots the sort was given: the rung's (N without
                  n_prefix; the slots behind `search.slots_sorted`).
@@ -829,6 +874,21 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
          jnp.full((P,), -1, jnp.int32) + zero))
     c = jnp.cumsum((src >= 0).astype(jnp.int32))
 
+    # The new rows of a block (ISSUE 48): the row at position p that is
+    # new is the (c[p] - 1)-th new key — a new key parked past SC has
+    # every new key in the table before it, so c ranks as npos does —
+    # and a block holds at most B of them, consecutive from the
+    # c[p0 - 1]-th.  With the new keys compacted in rank order (ties
+    # keep the first occurrence and equal keys are equal words, so
+    # keys[nk_sidx[j]] IS the j-th new sorted key) they are a window of
+    # WN rows that always fits: no branch.
+    windowed = _build_form(N) == "window"
+    newkey_blocks = None
+    if windowed:
+        WN = min(B, N)
+        newk, newkey_blocks = _gather_prefix(keys, nk_sidx, new_count, N,
+                                             QB, zero=zero)
+
     tail = jnp.concatenate([jnp.ones((1, 1), jnp.int32),
                             jnp.full((1, K - 1), SENTINEL, jnp.int32)],
                            axis=1)
@@ -844,14 +904,23 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
         p0 = (merge_blocks - 1 - i) * B
         src_b = lax.dynamic_slice(src, (p0,), (B,))
         is_new = src_b >= 0
-        src_s = p0 + jnp.arange(B, dtype=jnp.int32) \
-            - lax.dynamic_slice(c, (p0,), (B,))
+        rows_b = p0 + jnp.arange(B, dtype=jnp.int32)
+        c_b = lax.dynamic_slice(c, (p0,), (B,))
+        src_s = rows_b - c_b
         # the first seen row the block can need: in [0, p0]
         at = src_s[0] + is_new[0]
         window = lax.dynamic_slice(table, (at, 0), (B, K))
         from_seen = jnp.take(window, jnp.clip(src_s - at, 0, B - 1),
                              axis=0)
-        from_new = jnp.take(skeys, jnp.clip(src_b, 0, N - 1), axis=0)
+        if windowed:
+            # the new keys below the block: c[p0 - 1], clamped so that
+            # the slice stays inside newk
+            j0 = jnp.minimum(c_b[0] - is_new[0], N - WN)
+            nwin = lax.dynamic_slice(newk, (j0, 0), (WN, K))
+            from_new = jnp.take(nwin, jnp.clip(c_b - 1 - j0, 0, WN - 1),
+                                axis=0)
+        else:
+            from_new = jnp.take(skeys, jnp.clip(src_b, 0, N - 1), axis=0)
         is_seen = (src_s < seen_count)[:, None]
         rows = jnp.where(is_new[:, None], from_new,
                          jnp.where(is_seen, from_seen, tail))
@@ -859,12 +928,25 @@ def _rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
 
     if P != SC:
         seen = jnp.concatenate([seen, jnp.broadcast_to(tail, (P - SC, K))])
+    # In the window form BOTH of a block's gathers read row-major copies
+    # of B-row slices, the select of their results is row-major, and
+    # XLA:TPU's layout assignment then carries the whole table so —
+    # s32[SC,5]{1,0:T(8,128)}, a row padded to 128 lanes: 8 GB for
+    # desk-deep-4p's 336 MB table, and the program does not fit the chip
+    # (compiled for a described v5e, PERF.md §6, PR 48).  Held to the
+    # layout it arrives in on both sides of the loop, the table stays
+    # {0,1} and a block pays one [B, K] relayout, as it did.
+    if windowed:
+        seen = with_layout_constraint(seen, _WORDS_MAJOR)
     seen2 = lax.fori_loop(0, merge_blocks, block, seen)[:SC]
+    if windowed:
+        seen2 = with_layout_constraint(seen2, _WORDS_MAJOR)
     return dict(new_count=new_count, nk_sidx=nk_sidx, seen2=seen2,
                 seen_count2=seen_count2,
                 probe_blocks=_probe_blocks(n_live, N),
                 window_blocks=window_blocks,
-                merge_blocks=merge_blocks, sort_slots=sort_slots)
+                merge_blocks=merge_blocks, newkey_blocks=newkey_blocks,
+                sort_slots=sort_slots)
 
 
 @jax.named_scope("jaxmc.canon")
@@ -2903,8 +2985,12 @@ class TpuExplorer:
             por_inst = jnp.asarray(por_plan["inst_arm"])
             por_safe_v = jnp.asarray(por_plan["arm_safe"])
         # the merge's probe searches windows of a table this large, and
-        # the program then counts the blocks that did (ISSUE 45)
+        # the program then counts the blocks that did (ISSUE 45); its
+        # build reads windows of the new keys of a level this large, and
+        # the program counts the blocks their compaction ran (ISSUE 48)
         windowed = _probe_window_rows(SC) < SC
+        keyed = _build_form(AccCap) == "window"
+        n_opt = keyed + windowed
         RB = _compact_block_rows(AccCap, FCap)
 
         def level(seen, seen_count, frontier, fcount):
@@ -3152,6 +3238,7 @@ class TpuExplorer:
                     pora, porx, porm, rm["probe_blocks"],
                     rm["merge_blocks"],
                     rm["sort_slots"] // _sort_unit(AccCap), cblocks) + \
+                ((rm["newkey_blocks"],) if keyed else ()) + \
                 ((rm["window_blocks"],) if windowed else ())
 
         def run(seen, seen_count, frontier, fcount, distinct,
@@ -3244,8 +3331,9 @@ class TpuExplorer:
                         # gathered its blocks too
                         pblocks + lpblocks, mblocks + lmblocks,
                         sunits + lsunits, cblocks + lcblocks) + \
-                    logged2 + \
-                    ((carry[-1] + lvl[17],) if windowed else ())
+                    logged2 + tuple(
+                        had + ran for had, ran in
+                        zip(carry[len(carry) - n_opt:], lvl[17:]))
 
             carry0 = (seen, seen_count, frontier, fcount, distinct,
                       gen_lo, gen_hi, depth, jnp.int32(0),
@@ -3256,10 +3344,11 @@ class TpuExplorer:
                       jnp.int32(0), jnp.int32(0))
             if LogCap:
                 carry0 += logged + (jnp.zeros((LV,), jnp.int32),)
-            if windowed:
-                # the carry's last: the query blocks that searched a
-                # window of the table (ISSUE 45), counted as pblocks is
-                carry0 += (jnp.int32(0),)
+            # the carry's last, where the program has them: the blocks
+            # the new keys' compaction ran (ISSUE 48), then the query
+            # blocks that searched a window of the table (ISSUE 45),
+            # counted as pblocks is
+            carry0 += (jnp.int32(0),) * n_opt
             out = lax.while_loop(cond, body, carry0)
             (seen, seen_count, frontier, fcount, distinct, gen_lo,
              gen_hi, depth, _, stat, which, brow, ovcode, pora, porx,
@@ -3285,12 +3374,16 @@ class TpuExplorer:
                 log, log_n, lvl_rows = out[20:23]
                 summary = jnp.concatenate([summary, log_n[None],
                                            lvl_rows])
-            if windowed:
+            if n_opt:
                 # ... and, where the probe has a window, the summary's
                 # LAST word is the blocks that searched it (ISSUE 45:
-                # search.slots_windowed); a program without one keeps
-                # the summary it had
-                summary = jnp.concatenate([summary, out[-1][None]])
+                # search.slots_windowed); where the build has one, the
+                # word before that (the last, without the probe's) is
+                # the blocks the new keys' compaction ran (ISSUE 48:
+                # search.slots_keyed); a program without them keeps the
+                # summary it had
+                summary = jnp.concatenate(
+                    [summary] + [w[None] for w in out[len(out) - n_opt:]])
             if LogCap:
                 return seen, frontier, summary, brow, log
             return seen, frontier, summary, brow
@@ -3655,7 +3748,8 @@ class TpuExplorer:
                     # this module's tuning constants, which a trace
                     # reads too (tests patch them)
                     FP_THRESHOLD, _PROBE_BLOCK_MIN, _PROBE_SAMPLE,
-                    _MERGE_BLOCK_ROWS, _PROBE_WINDOW_ROWS)),
+                    _MERGE_BLOCK_ROWS, _PROBE_WINDOW_ROWS,
+                    _BUILD_WHOLE_KEYS)),
                 (self.backend_desc.platform,
                  jax.devices()[0].device_kind,
                  self.backend_desc.profile_ns,
@@ -4101,6 +4195,7 @@ class TpuExplorer:
             # level accumulator's keys and rows (re-made every level at
             # AccCap and sorted whole); bench/SPANS.deep.md has the
             # other engines' definitions
+            tel.gauge("merge.build_form", _build_form(caps["AccCap"]))
             tel.gauge("search.table_bytes", 4 * (
                 caps["SC"] * K + caps["FCap"] * self.PW
                 + caps["AccCap"] * (K + self.PW)
@@ -4141,6 +4236,13 @@ class TpuExplorer:
                 window_blocks = None
                 if _probe_window_rows(caps["SC"]) < caps["SC"]:
                     window_blocks = int(summary[-1])
+                    summary = summary[:-1]
+                # ... and one whose build reads windows of the new keys
+                # (ISSUE 48), before that, how many blocks their
+                # compaction ran
+                newkey_blocks = None
+                if _build_form(caps["AccCap"]) == "window":
+                    newkey_blocks = int(summary[-1])
                     summary = summary[:-1]
                 fcount_in, gen_in, dist_in, depth_in = \
                     fcount, generated, distinct, depth
@@ -4238,6 +4340,9 @@ class TpuExplorer:
             # live row after each level: counted in the carry as well
             tel.counter("search.slots_merged", merge_blocks
                         * _merge_block_rows(caps["SC"]))
+            if newkey_blocks is not None:
+                tel.counter("search.slots_keyed", newkey_blocks
+                            * _probe_block_rows(caps["AccCap"]))
             tel.counter("search.rows_new", distinct - dist_in)
             # ... and gathered the new rows into the next frontier in
             # blocks bounded by their count (ISSUE 46): counted there too
@@ -5333,6 +5438,7 @@ class TpuExplorer:
                 step = self._get_step(SC, FC)
                 # the tables carried from level to level (the candidate
                 # block lives inside the step)
+                tel.gauge("merge.build_form", _build_form(self.A * FC))
                 tel.gauge("search.table_bytes",
                           4 * (SC * K + FC * self.PW))
                 out = step(seen, seen_count, frontier, fcount)

@@ -96,8 +96,8 @@ from ..sem.modules import Model
 from ..engine.explore import CheckResult, Violation
 from ..compile.vspec import ModeError
 from ..compile.kernel2 import OV_DEMOTED, OV_PACK
-from .bfs import (SENTINEL, TpuExplorer, _LiveGraph, _merge_block_rows,
-                  _por_mask, _pow2_at_least, _probe_block_rows,
+from .bfs import (SENTINEL, TpuExplorer, _LiveGraph, _build_form,
+                  _merge_block_rows, _por_mask, _pow2_at_least, _probe_block_rows,
                   _rank_merge, _seen_probe)
 
 _BIG = np.int32(2 ** 31 - 1)
@@ -2106,6 +2106,7 @@ class MeshExplorer(TpuExplorer):
             # on each of the D shards, whatever was valid
             R = D * (B + SB) if B else D * C  # rows a shard receives
             n_keys = self._merge_out_rows(R, VC)
+            tel.gauge("merge.build_form", _build_form(n_keys))
             tel.counter("search.slots_sorted", nlv * D * n_keys)
             tel.counter("search.seen_slots", nlv * D * SC)
             # ... and binary-searched only the query blocks that held a

@@ -32,7 +32,16 @@ and the block's answers span fewer: the last section lowers W to give
 toy tables a window and holds the windowed answers to the whole-table
 probe's (the form up to PR 44, kept here alone) and to bisection, at
 every border of the window, and the choice each block makes to the rule
-as arithmetic."""
+as arithmetic.
+
+Since ISSUE 48 a block of the build takes its NEW rows from a window as
+well, where the level has more key slots than `_BUILD_WHOLE_KEYS`: the
+new keys compacted once, a slice of B of them a block.  The last
+section lowers that floor to give the toy shapes the window form and
+holds its answers to the whole form's (the function up to PR 47, kept
+here alone), to the row-scatter oracle's and to numpy's, over the
+scenarios above and at the window's own borders; at or under the floor
+the function must lower to the text it had."""
 
 import functools
 import re
@@ -46,9 +55,10 @@ from jax import lax  # noqa: E402
 
 from jaxmc.backend import bfs  # noqa: E402
 from jaxmc.backend.bfs import (  # noqa: E402
-    SENTINEL, _lower_bound, _lsd_sort, _merge_block_rows, _merge_blocks,
-    _probe_block_rows, _probe_blocks, _probe_by_block, _probe_window_rows,
-    _rank_merge, _seen_probe)
+    SENTINEL, _build_form, _lower_bound, _lsd_sort, _merge_block_rows,
+    _merge_blocks, _probe_block_rows, _probe_blocks, _probe_by_block,
+    _probe_window_rows, _rank_merge, _seen_probe, _sort_rung_index,
+    _sort_rungs)
 
 
 def _scatter_rank_merge(seen, seen_count, keys, N, SC, K, multikey=False):
@@ -1258,3 +1268,361 @@ def test_windowed_merge_under_shard_map(_toy_window):
             tables[d], counts[d] = t, n
             total += b[0]
     assert total > 0
+
+
+# ---- the build's new rows from a window (ISSUE 48) ----
+#
+# N = 48 key slots and QB = 16: the compaction of a level's new keys
+# runs ceil(new_count / 16) blocks.  The floor at 0 gives every shape
+# of this file the window form; the default leaves them the whole form.
+
+
+def _whole_rank_merge(seen, seen_count, keys, N, SC, K, multikey=False,
+                n_prefix=None):
+    """_rank_merge as it was up to PR 47, kept verbatim (comments
+    apart): every block of the build gathers its new rows from all N
+    sorted keys.  The oracle of the window form's answers, and the text
+    a program of no more than _BUILD_WHOLE_KEYS key slots must still
+    lower to."""
+    sidx = jnp.arange(N, dtype=jnp.int32)
+    rungs = (N,) if n_prefix is None or multikey else _sort_rungs(N)
+    sort_slots = N
+    with jax.named_scope("jaxmc.merge.sort"):
+        if multikey:
+            res = lax.sort(tuple(keys[:, j] for j in range(K)) + (sidx,),
+                           num_keys=K, is_stable=True)
+            kc = list(res[:K])
+            sidx_s = res[K]
+        elif len(rungs) == 1:
+            kc, ec = _lsd_sort([keys[:, j] for j in range(K)], [sidx])
+            sidx_s = ec[0]
+        else:
+            def sort_prefix(r):
+                def branch(cols):
+                    kc, ec = _lsd_sort([c[:r] for c in cols[:K]],
+                                       [cols[K][:r]])
+                    return tuple(
+                        lax.dynamic_update_slice(c, s, (0,))
+                        for c, s in zip(cols, kc + ec))
+                return branch
+
+            rung = _sort_rung_index(n_prefix, N)
+            res = lax.switch(rung, [sort_prefix(r) for r in rungs],
+                             tuple(keys[:, j] for j in range(K)) + (sidx,))
+            kc = list(res[:K])
+            sidx_s = res[K]
+            sort_slots = jnp.asarray(rungs, jnp.int32)[rung]
+    skeys = jnp.stack(kc, axis=1)
+    skeys, seen = lax.optimization_barrier((skeys, seen))
+    svalid = skeys[:, 0] == 0
+    neq_prev = jnp.concatenate([
+        jnp.array([True]),
+        jnp.any(skeys[1:] != skeys[:-1], axis=1)])
+
+    n_live = jnp.sum(svalid, dtype=jnp.int32)
+    found, lb, window_blocks = _probe_by_block(seen, seen_count, skeys, SC,
+                                               n_live, sorted_keys=True)
+    new = svalid & ~found & neq_prev
+    new_count = jnp.sum(new, dtype=jnp.int32)
+    seen_count2 = seen_count + new_count
+
+    B = _merge_block_rows(SC)
+    P = -(-SC // B) * B
+    QB = _probe_block_rows(N)
+    npos = jnp.cumsum(new.astype(jnp.int32)) - 1
+    pos_n = lb + npos
+    nk_tgt = jnp.where(new, npos, N + sidx)
+    src_tgt = jnp.where(new & (pos_n < SC), pos_n, P + sidx)
+
+    def index_block(b, out):
+        nk_sidx, src = out
+        at = jnp.minimum(b * QB, N - QB)
+        rows = at + jnp.arange(QB, dtype=jnp.int32)
+        nk_sidx = nk_sidx.at[lax.dynamic_slice(nk_tgt, (at,), (QB,))] \
+            .set(lax.dynamic_slice(sidx_s, (at,), (QB,)), mode="drop",
+                 unique_indices=True)
+        src = src.at[lax.dynamic_slice(src_tgt, (at,), (QB,))] \
+            .set(rows, mode="drop", unique_indices=True)
+        return nk_sidx, src
+
+    zero = n_live - n_live
+    nk_sidx, src = lax.fori_loop(
+        0, _probe_blocks(n_live, N), index_block,
+        (jnp.zeros((N,), jnp.int32) + zero,
+         jnp.full((P,), -1, jnp.int32) + zero))
+    c = jnp.cumsum((src >= 0).astype(jnp.int32))
+
+    tail = jnp.concatenate([jnp.ones((1, 1), jnp.int32),
+                            jnp.full((1, K - 1), SENTINEL, jnp.int32)],
+                           axis=1)
+    merge_blocks = _merge_blocks(seen_count2, SC)
+
+    def block(i, table):
+        p0 = (merge_blocks - 1 - i) * B
+        src_b = lax.dynamic_slice(src, (p0,), (B,))
+        is_new = src_b >= 0
+        src_s = p0 + jnp.arange(B, dtype=jnp.int32) \
+            - lax.dynamic_slice(c, (p0,), (B,))
+        at = src_s[0] + is_new[0]
+        window = lax.dynamic_slice(table, (at, 0), (B, K))
+        from_seen = jnp.take(window, jnp.clip(src_s - at, 0, B - 1),
+                             axis=0)
+        from_new = jnp.take(skeys, jnp.clip(src_b, 0, N - 1), axis=0)
+        is_seen = (src_s < seen_count)[:, None]
+        rows = jnp.where(is_new[:, None], from_new,
+                         jnp.where(is_seen, from_seen, tail))
+        return lax.dynamic_update_slice(table, rows, (p0, 0))
+
+    if P != SC:
+        seen = jnp.concatenate([seen, jnp.broadcast_to(tail, (P - SC, K))])
+    seen2 = lax.fori_loop(0, merge_blocks, block, seen)[:SC]
+    return dict(new_count=new_count, nk_sidx=nk_sidx, seen2=seen2,
+                seen_count2=seen_count2,
+                probe_blocks=_probe_blocks(n_live, N),
+                window_blocks=window_blocks,
+                merge_blocks=merge_blocks, sort_slots=sort_slots)
+
+
+@pytest.fixture
+def _window_build(monkeypatch):
+    """Call it to lower the floor: what is traced from then on builds
+    from windows of the new keys."""
+    def lower_the_floor():
+        monkeypatch.setattr(bfs, "_BUILD_WHOLE_KEYS", 0)
+        assert _build_form(N) == _build_form(1) == "window"
+    assert _build_form(N) == _build_form(1 << 21) == "whole"
+    assert _build_form((1 << 21) + 1) == "window"
+    return lower_the_floor
+
+
+KEYED = ANSWER + ("probe_blocks", "merge_blocks", "sort_slots")
+# one jit a block size, as _FNS: each is first traced with the floor at 0
+_KEYED_FNS = {name: jax.jit(functools.partial(_rank_merge),
+                            static_argnums=(3, 4, 5, 6)) for name in BLOCKS}
+
+
+def _check_keyed(got, want, ref, tag):
+    """The window form's answers: the whole form's bit for bit (the
+    work counters too), numpy's, and the blocks the compaction ran."""
+    for name in KEYED:
+        assert np.array_equal(np.asarray(got[name]),
+                              np.asarray(want[name])), (name, "whole", tag)
+    for name in ANSWER:
+        assert np.array_equal(np.asarray(got[name]), ref[name]), \
+            (name, "numpy", tag)
+    assert want.get("newkey_blocks") is None
+    assert int(got["newkey_blocks"]) == -(-ref["new_count"] // PROBE_MIN)
+
+
+@pytest.mark.parametrize("blocks", BLOCKS)
+@pytest.mark.parametrize("scenario", SCENARIOS)
+@pytest.mark.parametrize("multikey", [False, True])
+@pytest.mark.parametrize("K", [3, 5])
+def test_windowed_build_equals_the_whole_build_and_set_union(
+        K, multikey, scenario, blocks, monkeypatch, _window_build):
+    monkeypatch.setattr(bfs, "_MERGE_BLOCK_ROWS", BLOCKS[blocks])
+    cases = [_case(scenario, K, np.random.default_rng(
+        [K, int(multikey), SCENARIOS.index(scenario), trial, 48]))
+        for trial in range(4)]
+
+    def answers(fn):
+        return [fn(jnp.asarray(seen), jnp.int32(n_seen), jnp.asarray(keys),
+                   N, SC, K, multikey) for seen, n_seen, keys in cases]
+
+    wants = answers(_FNS[blocks])
+    _window_build()
+    for trial, (got, want) in enumerate(zip(answers(_KEYED_FNS[blocks]),
+                                            wants)):
+        seen, n_seen, keys = cases[trial]
+        _check_keyed(got, want, _np_reference(seen, n_seen, keys, SC, K),
+                     trial)
+        if scenario == "overflow":
+            assert int(got["seen_count2"]) > SC
+
+
+def _border(name, K, rng):
+    """(seen table, seen count, keys, rows of a block of seen2) at a
+    border of the window of new keys."""
+    uni = _lexsorted(rng.integers(-99, 100, size=(8 * SC, K - 1))
+                     .astype(np.int32))
+    block, valid = 16, np.ones(N, bool)
+    if name == "no_new_key":
+        swords, kwords = uni[:SC // 2], uni[rng.integers(0, SC // 2, N)]
+    elif name == "every_key_new":
+        swords, kwords = uni[:0], uni[rng.permutation(N)]
+    elif name == "one_key_many_times":
+        swords, kwords = uni[::2][:20], np.repeat(uni[7:8], N, axis=0)
+    elif name == "parked_past_SC":
+        # 56 seen rows and 48 new keys among and after them: the last
+        # new keys take positions past the table's 64
+        swords = uni[0:2 * 56:2]
+        kwords = uni[1:2 * 56:2][-N:][rng.permutation(N)]
+    elif name == "P_over_SC":
+        # blocks of 24 rows: P = 72 rows of src and c for SC = 64
+        block = 24
+        swords, kwords = uni[0:40:2], uni[1:2 * N:2][rng.permutation(N)]
+    elif name == "clamped_slice":
+        # ten seen rows below 48 new keys: the block at row 48 has 38
+        # new keys below it, and N - B = 32 is where its slice starts
+        swords, kwords = uni[:10], uni[10:10 + N][rng.permutation(N)]
+    else:
+        # new_count an exact multiple of QB = 16: the compaction's last
+        # block is full
+        n_new = {"one_full_block": 16, "two_full_blocks": 32}[name]
+        swords = uni[:20]
+        kwords = np.concatenate([uni[20:20 + n_new],
+                                 uni[rng.integers(0, 20, N - n_new)]])
+        # four of the seen keys' slots hold no key at all
+        mix = rng.permutation(N)
+        kwords, valid = kwords[mix], mix < N - 4
+    return _table(swords, SC, K), len(swords), _keys(kwords, valid, K), block
+
+
+BORDERS = ("no_new_key", "every_key_new", "one_key_many_times",
+           "parked_past_SC", "P_over_SC", "clamped_slice",
+           "one_full_block", "two_full_blocks")
+
+
+@pytest.mark.parametrize("border", BORDERS)
+@pytest.mark.parametrize("K", [3, 5])
+def test_windowed_build_at_the_windows_borders(K, border, monkeypatch,
+                                               _window_build):
+    rng = np.random.default_rng([K, BORDERS.index(border), 4801])
+    seen, n_seen, keys, block = _border(border, K, rng)
+    monkeypatch.setattr(bfs, "_MERGE_BLOCK_ROWS", block)
+    args = (jnp.asarray(seen), jnp.int32(n_seen), jnp.asarray(keys),
+            N, SC, K)
+    # jits of this test's own (jit caches by the function, hence the
+    # partials): the block size is read when they trace
+    want = jax.jit(functools.partial(_whole_rank_merge),
+                   static_argnums=(3, 4, 5))(*args)
+    _window_build()
+    got = jax.jit(functools.partial(_rank_merge),
+                  static_argnums=(3, 4, 5))(*args)
+    ref = _np_reference(seen, n_seen, keys, SC, K)
+    _check_keyed(got, want, ref, border)
+    new, blocks = ref["new_count"], int(got["newkey_blocks"])
+    assert (new, blocks) == {
+        "no_new_key": (0, 0), "every_key_new": (N, 3),
+        "one_key_many_times": (1, 1), "parked_past_SC": (N, 3),
+        "P_over_SC": (N, 3), "clamped_slice": (N, 3),
+        "one_full_block": (16, 1), "two_full_blocks": (32, 2)}[border]
+    if border == "parked_past_SC":
+        assert ref["seen_count2"] > SC
+        # new keys fell off the table, and seen rows with them
+        table = {tuple(r) for r in ref["seen2"][:, 1:]}
+        fresh = [tuple(keys[i, 1:]) for i in ref["nk_sidx"][:new]]
+        assert 0 < sum(w in table for w in fresh) < new
+        assert any(tuple(r) not in table for r in seen[:n_seen, 1:])
+    if border == "clamped_slice":
+        # rows 48..57 are the 39th..48th new keys
+        assert np.array_equal(ref["seen2"][48:58, 1:],
+                              _lexsorted(keys[:, 1:])[38:])
+
+
+@pytest.mark.parametrize("landing", LANDINGS)
+def test_windowed_build_inside_a_while_loop(landing, _toy_merge_blocks,
+                                            _window_build):
+    """The resident engine's form, the compaction's blocks counted in
+    the carry as the program counts them."""
+    K = 5
+    _window_build()
+    rng = np.random.default_rng([list(LANDINGS).index(landing), 4802])
+    table, count, levels = _levels(landing, K, rng)
+
+    @jax.jit
+    def run(table, count, keys3):
+        def body(carry):
+            lvl, table, count, blocks = carry
+            rm = _rank_merge(table, count, keys3[lvl], N, SC, K)
+            return (lvl + 1, rm["seen2"], rm["seen_count2"],
+                    blocks.at[lvl].set(rm["newkey_blocks"]))
+        return lax.while_loop(lambda c: c[0] < 3, body,
+                              (jnp.int32(0), table, count,
+                               jnp.zeros((3,), jnp.int32)))[1:]
+
+    seen2, count2, blocks = run(jnp.asarray(table), jnp.int32(count),
+                                jnp.asarray(np.stack(levels)))
+    want_blocks = []
+    for keys in levels:
+        want = _np_live_merge(table, count, keys, K)
+        table, count = want["seen2"], want["seen_count2"]
+        want_blocks.append(-(-want["new_count"] // PROBE_MIN))
+    assert np.array_equal(np.asarray(seen2), table)
+    assert int(count2) == count
+    assert list(np.asarray(blocks)) == want_blocks
+
+
+@pytest.mark.parametrize("shift", [0, 1])
+def test_windowed_build_under_shard_map(shift, _toy_merge_blocks,
+                                        _window_build):
+    """A mesh shard's form: each shard compacts its own new keys, its
+    loop bounded on its own count (a device-varying carry)."""
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+    D, K = 4, 5
+    if len(jax.devices()) < D:
+        pytest.skip("needs four (virtual) devices")
+    _window_build()
+    mesh = Mesh(np.array(jax.devices()[:D]), ("d",))
+    names = ANSWER + ("merge_blocks", "newkey_blocks")
+
+    def shard(table, count, keys):
+        rm = _rank_merge(table[0], count[0], keys[0], N, SC, K, True)
+        return tuple(rm[name][None] for name in names)
+
+    step = jax.jit(shard_map(shard, mesh=mesh, in_specs=(P("d"),) * 3,
+                             out_specs=(P("d"),) * len(names)))
+    lands = [list(LANDINGS)[(d + shift) % len(LANDINGS)] for d in range(D)]
+    cases = [_levels(nm, K, np.random.default_rng([d, shift, 4803]))
+             for d, nm in enumerate(lands)]
+    tables = [c[0] for c in cases]
+    counts = [c[1] for c in cases]
+    for lvl in range(3):
+        keys = [c[2][lvl] for c in cases]
+        got = step(jnp.asarray(np.stack(tables)),
+                   jnp.asarray(counts, jnp.int32),
+                   jnp.asarray(np.stack(keys)))
+        for d in range(D):
+            got_d = {name: np.asarray(g)[d] for name, g in zip(names, got)}
+            want = _check_level(got_d, tables[d], counts[d], keys[d], K,
+                                (lands[d], lvl))
+            assert int(got_d["newkey_blocks"]) == \
+                -(-want["new_count"] // PROBE_MIN)
+            tables[d], counts[d] = want["seen2"], want["seen_count2"]
+
+
+def _lowered_merge(fn, n, sc, K=5, multikey=False, prefix=False):
+    """The text of one merge of n key slots into a table of sc, lowered
+    on shapes alone: (seen, seen_count, keys[, n_prefix])."""
+    def merge(seen, count, keys, *n_prefix):
+        rm = fn(seen, count, keys, n, sc, K, multikey, *n_prefix)
+        return tuple(v for v in rm.values() if v is not None)
+    return jax.jit(merge).lower(
+        jax.ShapeDtypeStruct((sc, K), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((n, K), jnp.int32),
+        *[jax.ShapeDtypeStruct((), jnp.int32)] * prefix).as_text()
+
+
+@pytest.mark.parametrize("multikey,prefix", [(False, False), (False, True),
+                                             (True, False)],
+                         ids=["lsd", "ladder", "multikey"])
+def test_no_more_key_slots_than_the_floor_lowers_to_the_form_it_had(
+        multikey, prefix, monkeypatch):
+    """_build_form(N) == "whole": no compaction of the new keys and the
+    text of the function up to PR 47 — the level engine's step, the
+    mesh's shards and the small resident programs keep their programs;
+    over the floor the text has one loop more."""
+    for floor in (N, 2 * N):
+        monkeypatch.setattr(bfs, "_BUILD_WHOLE_KEYS", floor)
+        text = _lowered_merge(_rank_merge, N, SC, 5, multikey, prefix)
+        assert text == _lowered_merge(_whole_rank_merge, N, SC, 5, multikey,
+                                      prefix)
+    monkeypatch.setattr(bfs, "_BUILD_WHOLE_KEYS", N - 1)
+    keyed = _lowered_merge(_rank_merge, N, SC, 5, multikey, prefix)
+    assert keyed.count("stablehlo.while") == \
+        text.count("stablehlo.while") + 1
+    # ... and no scatter more: the compaction is a gather
+    for operand, indices, updates in _scatters(_rank_merge, 5, multikey):
+        assert (indices, updates) == (f"{QB}x1xi32", f"{QB}xi32")
