@@ -3781,11 +3781,14 @@ class TpuExplorer:
             # resumed dedup set is exactly the crashed run's
             payload["tiers"] = self._tiers.dump()
         try:
-            with obs.current().span("checkpoint.write", mode=mode):
-                _ckpt.write_checkpoint(
+            with obs.current().span("checkpoint.write",
+                                    mode=mode) as sp:
+                # the size of the file it wrote, after the rename
+                sp.attrs["bytes"] = n = _ckpt.write_checkpoint(
                     self.checkpoint_path, "device",
                     {"module": self.model.module.name, "mode": mode},
                     payload)
+                obs.current().counter("checkpoint.bytes", n)
         except _ckpt.CkptError as ex:
             # a failed periodic write must not kill the search: keep
             # running on the previous checkpoint

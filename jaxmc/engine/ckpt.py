@@ -185,8 +185,11 @@ def write_periodic(path: str, kind: str, meta: Dict[str, Any],
     import time
     t_ck = time.time()
     try:
-        with tel.span("checkpoint.write", **(span_attrs or {})):
-            write_checkpoint(path, kind, meta, payload)
+        with tel.span("checkpoint.write", **(span_attrs or {})) as sp:
+            # the size of the file it wrote, after the rename
+            sp.attrs["bytes"] = n = write_checkpoint(path, kind, meta,
+                                                     payload)
+            tel.counter("checkpoint.bytes", n)
     except CkptError as ex:
         tel.counter("checkpoint.write_failures")
         log(f"WARNING: checkpoint write failed ({ex}); the run "

@@ -391,8 +391,44 @@ def cmd_report(args, out=sys.stdout) -> int:
               f"signature); signing took "
               f"{c.get('compile.program_sig_s', 0.0):.4f}s", file=out)
     _cohort_lines(s, out)
+    _stations_line(s, out)
     _searches_table(s.get("requests") or [], out)
     return 0 if rows else 1
+
+
+def _stations_line(s: Dict[str, Any], out) -> None:
+    """Where a SERVED job's wall went between the POST's record and the
+    verdict's (ISSUE 49, serve/protocol.py "A job's clock"): one
+    `stations:` line from the `serve` block — the queue (record made ->
+    a worker's claim), the wait for the device owner, the owner's
+    envelope (pipe, config, summary, pickling: everything of the
+    request that is not the run), the run where it ran and the
+    publish (the artifact's write).  A job no owner ran has the queue
+    and the whole alone."""
+    sv = s.get("serve")
+    st = sv.get("stations") if isinstance(sv, dict) else None
+    if not isinstance(st, dict) or "claimed_at" not in st \
+            or "submitted_at" not in st:
+        return
+
+    def _ms(x) -> str:  # stations are milliseconds apart: _fmt_s
+        return "-" if x is None else f"{x:.3f}s"  # rounds to 0.01
+    cells = [f"queue {_ms(st['claimed_at'] - st['submitted_at'])}"]
+    if "owner_sent_at" in st:
+        cells += [f"owner wait {_ms(sv.get('owner_wait_s'))}",
+                  f"envelope {_ms(sv.get('owner_envelope_s'))}"
+                  + (f" (the owner spawned: "
+                     f"{_ms(sv['owner_spawn_s'])} coming up)"
+                     if sv.get("owner_spawn_s") is not None else ""),
+                  f"run {_ms(sv.get('job_wall_s'))}",
+                  f"publish {_ms(sv.get('publish_s'))}"]
+    else:
+        cells.append("no owner station (the job ran in the daemon)")
+    if "finished_at" in st:
+        cells.append(
+            f"record to record "
+            f"{_ms(st['finished_at'] - st['submitted_at'])}")
+    print("stations: " + " · ".join(cells), file=out)
 
 
 def _cohort_lines(s: Dict[str, Any], out) -> None:

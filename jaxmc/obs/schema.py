@@ -691,6 +691,32 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       one whose SYMMETRY orbit or VIEW value an earlier one already
       stored (until PR 47 only the first of each was counted, on every
       engine and in the interpreter alike); `distinct` is unchanged.
+
+  (PR 49, still jaxmc.metrics/4 — all additive/optional; a served job's
+   stations, serve/daemon.py + serve/owner.py + serve/queue.py,
+   ISSUE 49.  Nothing of it exists outside `jaxmc.serve` but the
+   checkpoint's bytes:)
+    - the `serve` block of a served job's artifact gains `stations`
+      (wall-clock marks `submitted_at`, `enqueued_at`, `claimed_at`,
+      `owner_spawned_at`?, `owner_sent_at`, `owner_began_at`,
+      `owner_ended_at`, `owner_received_at`, `finished_at`: the same
+      numbers the job's record carries; absent where the job passed
+      none) and the seconds `owner_wait_s`, `owner_envelope_s`,
+      `publish_s`, `owner_spawn_s`? (serve/protocol.py has the table);
+      `python -m jaxmc.obs report` prints one `stations:` line.
+    - daemon recorder: the `job` / `vbatch` span of an owner-run job
+      has two children, `job.owner_wait` (the worker wants
+      `DeviceOwner._lock` -> it holds it and a live owner) and
+      `job.owner_run` (the request into the pipe -> its answer out).
+    - owner process: ONE envelope span a job on the job's own recorder
+      — `job` (`run_solo`) or, on the leader's, `vbatch` {members}
+      (`run_vbatch`) — opened as the recorder is made and closed before
+      the summary: in the owner's device trace (`jaxmc.job`,
+      `jaxmc.vbatch`) a device-idle piece is under a job or under none.
+    - span `checkpoint.write` gains the attribute `bytes` and every
+      recorder the counter `checkpoint.bytes`: the size of each file
+      the span wrote, after the rename (engine/ckpt.py
+      `write_periodic`, `bfs._write_ck`).
 """
 
 from __future__ import annotations

@@ -19,9 +19,11 @@ engines already emit into a live signal:
 
 The liveness signal is `Telemetry.progress_seq`, bumped on every span
 open/close and level record, so the watchdog needs no cooperation from
-the engines. Everything is best-effort: a watchdog failure must never
-break a run (the tick body is exception-proofed), and the thread is a
-daemon so it can never hold a process open.
+the engines.  A recorder that legitimately sits with NO span open — the
+serve daemon's, between jobs — passes `idle_ok=True`: only quiet UNDER
+an open span is then a stall.  Everything is best-effort: a watchdog
+failure must never break a run (the tick body is exception-proofed), and
+the thread is a daemon so it can never hold a process open.
 
 Knobs (env, all optional):
   JAXMC_HEARTBEAT_EVERY  seconds between beats        (default 10)
@@ -64,7 +66,7 @@ class Watchdog:
                  stall_factor: Optional[float] = None,
                  min_stall_s: Optional[float] = None,
                  on_stall: Callable[[str], None] = _default_on_stall,
-                 clock=time.time):
+                 clock=time.time, idle_ok: bool = False):
         def _env(name, default):
             try:
                 return float(os.environ.get(name, ""))
@@ -79,6 +81,11 @@ class Watchdog:
         self.min_stall_s = min_stall_s if min_stall_s is not None \
             else _env("JAXMC_STALL_MIN_S", 30.0)
         self.on_stall = on_stall
+        # a recorder with NO open span is waiting for work, not wedged
+        # (the serve daemon's fleet recorder between jobs: it counted a
+        # stall for every 30 s without a submission); a run's recorder
+        # keeps the default, where quiet between two spans is news
+        self.idle_ok = idle_ok
         self._clock = clock
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -134,7 +141,8 @@ class Watchdog:
     def _tick(self, now: float) -> None:
         tel = self.tel
         snap = tel.watch_snapshot()
-        moved = snap["progress_seq"] != self._last_seq
+        moved = snap["progress_seq"] != self._last_seq or \
+            (self.idle_ok and not snap["open_spans"])
         if moved:
             self._last_seq = snap["progress_seq"]
             self._last_change_t = now

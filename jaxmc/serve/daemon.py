@@ -50,7 +50,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from .. import drain, obs
 from ..session import CheckSession
-from .protocol import BadJob, build_config, job_signature
+from .protocol import STATIONS, BadJob, build_config, job_signature
 from .queue import JobQueue
 
 
@@ -159,7 +159,10 @@ class ServeDaemon:
         self._lost: set = set()
         self._fleet_thread: Optional[threading.Thread] = None
         self._fleet_size = 1
-        self.wd = obs.Watchdog(self.tel)
+        # between jobs the fleet recorder has no span open: that is a
+        # daemon waiting for work, not a stall (a job's own watchdog
+        # lives where the job runs)
+        self.wd = obs.Watchdog(self.tel, idle_ok=True)
         self.metrics_out = metrics_out
         self.host = host
         self.port = port
@@ -1060,6 +1063,50 @@ class ServeDaemon:
                 self._done_series[j] = (now, series)
                 self._done_series.move_to_end(j)
 
+    def _publish_job(self, j: Dict[str, Any], summary: Dict[str, Any],
+                     status: str, claimed_at: float,
+                     sent: Optional[Dict[str, float]] = None,
+                     **fields) -> None:
+        """One job's artifact and final record, with its STATIONS
+        (serve/protocol.py "A job's clock"): the record's own, the
+        owner request's (`sent`: DeviceOwner.request's, None where no
+        owner ran the job) and the two the owner stamped into its
+        summary, and beside them in the `serve` block the differences
+        an operator wants without arithmetic.  A station the job did
+        not pass is left out, never defaulted.  The artifact is written
+        twice: `finished_at` is stamped AFTER its write, as it always
+        was, `publish_s` holds that write, and the second carries
+        both."""
+        sv = dict(summary.get("serve") or {})
+        art = dict(summary, serve=sv)
+        st = {k: j.get(k) for k in ("submitted_at", "enqueued_at")}
+        st["claimed_at"] = claimed_at
+        owner = sv.pop("stations", None)
+        if sent is not None:
+            st.update(owner or {}, **sent)
+        self.q.save_result(j["id"], art)
+        st["finished_at"] = time.time()
+        sv["stations"] = {k: st[k] for k in STATIONS
+                          if st.get(k) is not None}
+        if sent is not None:
+            sv["owner_wait_s"] = round(
+                st["owner_sent_at"] - claimed_at, 6)
+            sv["owner_envelope_s"] = round(
+                st["owner_received_at"] - st["owner_sent_at"]
+                - (sv.get("job_wall_s") or 0.0), 6)
+            sv["publish_s"] = round(
+                st["finished_at"] - st["owner_received_at"], 6)
+            if "owner_spawned_at" in st and "owner_began_at" in st:
+                # the child's coming up, from the process started to
+                # its first job begun: what the spawn put into this
+                # job's `owner_envelope_s`
+                sv["owner_spawn_s"] = round(
+                    st["owner_began_at"] - st["owner_spawned_at"], 6)
+        self.q.save_result(j["id"], art)
+        self.q.mark(j["id"], status, **fields,
+                    **{k: v for k, v in sv["stations"].items()
+                       if k != "submitted_at"})
+
     def _run_batch(self, job: Dict[str, Any],
                    followers: List[Dict[str, Any]]) -> None:
         jid, sig = job["id"], job["sig"]
@@ -1103,7 +1150,7 @@ class ServeDaemon:
         t0 = time.time()
         for j in [job] + followers:
             self.q.mark(j["id"], "running", started_at=t0,
-                        daemon=self.daemon_id,
+                        claimed_at=t0, daemon=self.daemon_id,
                         batch_leader=jid if j is not job else None)
         if followers:
             self.tel.counter("serve.batched_jobs", len(followers))
@@ -1229,15 +1276,14 @@ class ServeDaemon:
         if not publish:
             return  # every member was stolen mid-run; the thief answers
         for j in publish:
-            self.q.save_result(j["id"], summary)
-            self.q.mark(j["id"], status, finished_at=time.time(),
-                        ok=res.ok, distinct=res.distinct,
-                        generated=res.generated,
-                        warm_engine=warm_engine,
-                        resumed_from_checkpoint=resumed,
-                        window_recompiles=window_recompiles,
-                        daemon=self.daemon_id,
-                        batch_leader=jid if j is not job else None)
+            self._publish_job(
+                j, summary, status, t0,
+                ok=res.ok, distinct=res.distinct,
+                generated=res.generated, warm_engine=warm_engine,
+                resumed_from_checkpoint=resumed,
+                window_recompiles=window_recompiles,
+                daemon=self.daemon_id,
+                batch_leader=jid if j is not job else None)
         if drained:
             self.tel.counter("serve.jobs_drained", len(publish))
             self.log(f"serve: job {jid} drained at a safe boundary "
@@ -1261,8 +1307,11 @@ class ServeDaemon:
         jid, sig = job["id"], job["sig"]
         jobs = [job] + followers
         for j in jobs:
+            # `started_at` is the worker's CLAIM (`claimed_at`, the same
+            # instant): the owner may be busy with another worker's job
+            # for a long while yet (`owner_wait_s` says how long)
             self.q.mark(j["id"], "running", started_at=t0,
-                        daemon=self.daemon_id,
+                        claimed_at=t0, daemon=self.daemon_id,
                         batch_leader=jid if j is not job else None)
         if followers:
             self.tel.counter("serve.batched_jobs", len(followers))
@@ -1270,6 +1319,7 @@ class ServeDaemon:
         from .. import faults
         faults.kill_self("daemon_kill", job=jid, kind="solo",
                          spec=os.path.basename(job["spec"]))
+        sent: Dict[str, float] = {}
         md = {"spec": job["spec"], "cfg": job.get("cfg"),
               "options": job.get("options"), "sig": sig,
               "jids": [j["id"] for j in jobs],
@@ -1280,8 +1330,9 @@ class ServeDaemon:
         with self.tel.span("job", id=jid, sig=sig, spec=job["spec"],
                            owner=True, batched=len(followers)):
             try:
-                resp = self.owner.request({"kind": "solo",
-                                           "member": md})
+                resp = self.owner.request(
+                    {"kind": "solo", "member": md}, stations=sent,
+                    tel=self.tel)
             except OwnerDied as ex:
                 if ex.timed_out:
                     # policy kill: requeueing would livelock (the
@@ -1319,14 +1370,14 @@ class ServeDaemon:
         if not publish:
             return  # stolen mid-run; the thief's re-run answers
         for j in publish:
-            self.q.save_result(j["id"], summary)
-            self.q.mark(j["id"], status, finished_at=time.time(),
-                        ok=resp["ok"], distinct=resp["distinct"],
-                        generated=resp["generated"],
-                        warm_engine=warm_engine, device_owner=True,
-                        resumed_from_checkpoint=resumed,
-                        daemon=self.daemon_id,
-                        batch_leader=jid if j is not job else None)
+            self._publish_job(
+                j, summary, status, t0, sent,
+                ok=resp["ok"], distinct=resp["distinct"],
+                generated=resp["generated"],
+                warm_engine=warm_engine, device_owner=True,
+                resumed_from_checkpoint=resumed,
+                daemon=self.daemon_id,
+                batch_leader=jid if j is not job else None)
         self._register_done_artifact([j["id"] for j in publish],
                                      summary)
         if status == "drained":
@@ -1381,7 +1432,7 @@ class ServeDaemon:
         for s in order:
             for j in groups[s]:
                 self.q.mark(j["id"], "running", started_at=t0,
-                            daemon=self.daemon_id,
+                            claimed_at=t0, daemon=self.daemon_id,
                             batch_leader=jid
                             if j["id"] != jid else None,
                             bsig=job.get("bsig"))
@@ -1409,6 +1460,10 @@ class ServeDaemon:
                 self._cv.notify_all()
 
         resp = None
+        # the owner request's stations: None where the cohort runs in
+        # this process, whose jobs then carry no owner station at all
+        sent: Optional[Dict[str, float]] = \
+            {} if self.owner is not None else None
         with self.tel.span("vbatch", id=jid, bsig=job.get("bsig"),
                            members=len(order),
                            jobs=sum(len(groups[s]) for s in order)):
@@ -1416,7 +1471,8 @@ class ServeDaemon:
                 from .owner import OwnerDied
                 try:
                     resp = self.owner.request(
-                        {"kind": "vbatch", "members": desc})
+                        {"kind": "vbatch", "members": desc},
+                        stations=sent, tel=self.tel)
                 except OwnerDied as ex:
                     if ex.timed_out:
                         # policy kill, not a death: requeueing would
@@ -1514,17 +1570,14 @@ class ServeDaemon:
             status = "drained" if mres.get("drained") else "done"
             publish = self._publishable(jobs)
             for j in publish:
-                self.q.save_result(j["id"], summary)
-                self.q.mark(j["id"], status, finished_at=time.time(),
-                            ok=mres["ok"], distinct=mres["distinct"],
-                            generated=mres["generated"],
-                            warm_engine=False,
-                            device_owner=self.owner is not None,
-                            resumed_from_checkpoint=resumed,
-                            batch_occupancy=occupancy,
-                            daemon=self.daemon_id,
-                            batch_leader=jid
-                            if j["id"] != jid else None)
+                self._publish_job(
+                    j, summary, status, t0, sent,
+                    ok=mres["ok"], distinct=mres["distinct"],
+                    generated=mres["generated"], warm_engine=False,
+                    device_owner=self.owner is not None,
+                    resumed_from_checkpoint=resumed,
+                    batch_occupancy=occupancy, daemon=self.daemon_id,
+                    batch_leader=jid if j["id"] != jid else None)
             self._register_done_artifact([j["id"] for j in publish],
                                          summary)
             if status == "drained":

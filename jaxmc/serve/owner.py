@@ -87,7 +87,7 @@ def run_vbatch(members_desc: List[Dict[str, Any]]) -> Dict[str, Any]:
     from ..backend.batch import BatchCheckEngine, BatchIncompatible
     from .protocol import build_config
     t0 = time.time()
-    cfgs, tels = [], []
+    cfgs, tels, envelope = [], [], None
     for md in members_desc:
         cfg = build_config(md["spec"], md.get("cfg"), md.get("options"))
         if md.get("checkpoint"):
@@ -108,6 +108,13 @@ def run_vbatch(members_desc: List[Dict[str, Any]]) -> Dict[str, Any]:
             "sig": md.get("sig"), "bsig": md.get("bsig"),
             "backend": cfg.backend, "spec": md["spec"],
             "cfg": md.get("cfg"), "env": obs.environment_meta()}))
+        if envelope is None:
+            # the cohort's envelope, on the leader's recorder as soon as
+            # it exists: in the owner's device trace every idle piece is
+            # then under a job's envelope or under none (the owner
+            # waiting for the daemon)
+            envelope = tels[0].span(
+                "vbatch", members=len(members_desc)).__enter__()
     try:
         # the LEADER's recorder is the cohort's: the one build, the
         # supersteps' spans and the vmapped program's record reach the
@@ -117,12 +124,15 @@ def run_vbatch(members_desc: List[Dict[str, Any]]) -> Dict[str, Any]:
             cfgs, tels=tels, tags=[md["jids"][0] for md in members_desc],
             tel=tels[0]).build()
     except BatchIncompatible as ex:
+        envelope.done(error="BatchIncompatible")
         for jt in tels:
             jt.close()
         return {"incompatible": str(ex)}
     members = be.run()
     disp = be.dispatcher
-    wall = time.time() - t0
+    envelope.done()
+    t1 = time.time()
+    wall = t1 - t0
     out: List[Dict[str, Any]] = []
     for md, cfg, mem, jt in zip(members_desc, cfgs, members, tels):
         if mem.error is not None:
@@ -156,6 +166,9 @@ def run_vbatch(members_desc: List[Dict[str, Any]]) -> Dict[str, Any]:
             "batch_dispatches": disp.dispatches,
             "lifted_consts": list(be.lift_names),
             "job_wall_s": round(wall, 6),
+            # the owner's two stations (serve/protocol.py): every
+            # member carries the cohort's
+            "stations": {"owner_began_at": t0, "owner_ended_at": t1},
         }))
     return {"members": out, "occupancy": disp.max_width,
             "dispatches": disp.dispatches,
@@ -219,6 +232,8 @@ def run_solo(md: Dict[str, Any]) -> Dict[str, Any]:
         "sig": md.get("sig"), "backend": cfg.backend,
         "spec": md["spec"], "cfg": md.get("cfg"),
         "env": obs.environment_meta()})
+    # the job's envelope on its own recorder (see run_vbatch)
+    envelope = jt.span("job").__enter__()
     sig = md.get("sig")
     entry = _WARM.get(sig) if sig else None
     # the warm/replay decision (a completed entry AND its finalized
@@ -265,16 +280,20 @@ def run_solo(md: Dict[str, Any]) -> Dict[str, Any]:
                         _WARM.popitem(last=False)
     except Exception as ex:  # noqa: BLE001 — the job's failure is its
         # verdict; the owner loop must survive to serve the next one
+        envelope.done(error=type(ex).__name__)
         jt.close()
         return {"error": f"{type(ex).__name__}: {ex}"}
     finally:
         wd.stop()
+    envelope.done()
+    t1 = time.time()
     return _member_summary(res, jt, cfg.backend, md["spec"], {
         "sig": sig, "warm_engine": warm_engine,
         "resumed_from_checkpoint": resumed,
         "device_owner": True,
         "batched_with": [],
-        "job_wall_s": round(time.time() - t0, 6),
+        "job_wall_s": round(t1 - t0, 6),
+        "stations": {"owner_began_at": t0, "owner_ended_at": t1},
     }, finished_on=sess.finished_on)
 
 
@@ -365,44 +384,74 @@ class DeviceOwner:
                  f"(pid {self._proc.pid})")
 
     def request(self, req: Dict[str, Any],
-                timeout: Optional[float] = None) -> Dict[str, Any]:
+                timeout: Optional[float] = None,
+                stations: Optional[Dict[str, float]] = None,
+                tel=None) -> Dict[str, Any]:
         """Send one request; block for the response.  Raises OwnerDied
         if the child dies or the deadline passes — the owner is then
-        torn down so the next request respawns a fresh one."""
-        with self._lock:
-            # the deadline starts when THIS request is actually sent:
-            # time spent waiting behind another worker's long job must
-            # not count against it (a healthy owner would be killed)
-            deadline = time.time() + (timeout if timeout is not None
-                                      else self.timeout)
-            if not self.alive():
-                self._spawn_locked()
-            try:
-                self._conn.send(req)
-            except (BrokenPipeError, OSError):
-                # a broken pipe makes the child unusable even if it is
-                # still alive: kill it so the next request respawns
-                self._kill_locked()
-                raise OwnerDied("owner pipe closed on send")
-            while True:
+        torn down so the next request respawns a fresh one.
+
+        THIS call's stations (serve/protocol.py) go into `stations`,
+        never onto the shared handle (two workers call this):
+        `owner_spawned_at` where the call had to spawn the owner,
+        `owner_sent_at`, `owner_received_at`.  On `tel` the same two
+        borders cut the caller's open span into `job.owner_wait` (the
+        lock wanted -> the lock held and an owner alive: another
+        worker's whole job, a spawn) and `job.owner_run` (the request
+        into the pipe -> its answer out of it)."""
+        st = stations if stations is not None else {}
+        tel = tel if tel is not None else obs.NullTelemetry()
+        span = tel.span("job.owner_wait").__enter__()
+        try:
+            with self._lock:
+                # the deadline starts when THIS request is actually
+                # sent: time spent waiting behind another worker's long
+                # job must not count against it (a healthy owner would
+                # be killed)
+                deadline = time.time() + (timeout if timeout is not None
+                                          else self.timeout)
+                if not self.alive():
+                    self._spawn_locked()
+                    st["owner_spawned_at"] = time.time()
+                # stamped as the request ENTERS the pipe: the owner can
+                # begin before the line after `send` runs (0.17 ms seen)
+                st["owner_sent_at"] = time.time()
+                span.done()
+                span = tel.span("job.owner_run").__enter__()
                 try:
-                    if self._conn.poll(0.2):
-                        return self._conn.recv()
-                except (EOFError, OSError):
+                    self._conn.send(req)
+                except (BrokenPipeError, OSError):
+                    # a broken pipe makes the child unusable even if it
+                    # is still alive: kill it so the next request
+                    # respawns
                     self._kill_locked()
-                    raise OwnerDied("owner pipe closed mid-request")
-                if not self._proc.is_alive():
-                    self._reap_locked()
-                    raise OwnerDied(
-                        f"owner process died (exitcode "
-                        f"{self._proc.exitcode if self._proc else '?'})")
-                if time.time() > deadline:
-                    self._kill_locked()
-                    raise OwnerDied(
-                        "owner request exceeded "
-                        "JAXMC_SERVE_OWNER_TIMEOUT "
-                        f"({self.timeout:.0f}s); raise it for "
-                        "longer-running cohorts", timed_out=True)
+                    raise OwnerDied("owner pipe closed on send")
+                while True:
+                    try:
+                        if self._conn.poll(0.2):
+                            resp = self._conn.recv()
+                            st["owner_received_at"] = time.time()
+                            return resp
+                    except (EOFError, OSError):
+                        self._kill_locked()
+                        raise OwnerDied("owner pipe closed mid-request")
+                    if not self._proc.is_alive():
+                        self._reap_locked()
+                        raise OwnerDied(
+                            f"owner process died (exitcode "
+                            f"{self._proc.exitcode if self._proc else '?'})")
+                    if time.time() > deadline:
+                        self._kill_locked()
+                        raise OwnerDied(
+                            "owner request exceeded "
+                            "JAXMC_SERVE_OWNER_TIMEOUT "
+                            f"({self.timeout:.0f}s); raise it for "
+                            "longer-running cohorts", timed_out=True)
+        except OwnerDied:
+            span.done(error="OwnerDied")
+            raise
+        finally:
+            span.done()
 
     def _reap_locked(self) -> None:
         if self._conn is not None:

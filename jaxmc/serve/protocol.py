@@ -39,7 +39,8 @@ Endpoints (all JSON bodies/responses; the daemon binds 127.0.0.1):
 
 A job record: {id, sig, status: queued|running|done|failed|drained|
 quarantined, submitted_at, started_at?, finished_at?, spec, cfg,
-options, batch_leader?, error?, tenant?, daemon?, stolen_by?}.
+options, batch_leader?, error?, tenant?, daemon?, stolen_by?} and the
+other STATIONS the job has passed so far ("A job's clock" below).
 `daemon` names the fleet member that ran (or is running) the job;
 `stolen_by` appears after a lease-expiry takeover.  A QUARANTINED job
 (its owner died JAXMC_JOB_RETRIES times across the fleet) answers
@@ -69,12 +70,54 @@ The `serve` block of a served job's artifact (GET /jobs/<id>/result):
                            an edit that left the model unchanged
   batched_with             ids answered by the same run
   cost_estimate            analyze's state-space estimate, if any
+  stations                 the job's wall-clock marks (`STATIONS`, below),
+                           the same numbers its record carries
+  owner_wait_s             `owner_sent_at - claimed_at`: a CLAIMED job
+                           waiting for the device owner (the other
+                           worker's whole job or cohort, a spawn)
+  owner_envelope_s         `owner_received_at - owner_sent_at -
+                           job_wall_s`: what the request cost beside the
+                           run (pipe both ways, pickling, the summary)
+  publish_s                `finished_at - owner_received_at`: the
+                           artifact's write
+  owner_spawn_s            only where the request spawned the owner:
+                           `owner_began_at - owner_spawned_at`, the child
+                           coming up (it is inside `owner_envelope_s`)
 
-A job's clock, as far as the record keeps it: `submitted_at` (the spool's
-hard write in `submit()`), `started_at` (a worker marked it running) and
-`finished_at` (the final record's write, after the artifact's); the
-artifact's `job_wall_s` is the share of `started_at -> finished_at` spent
-in the run itself.
+A job's clock: the STATIONS, `time.time()` marks of one host in the order
+a job passes them, each in the record (GET /jobs/<id>) under its name and
+all in the artifact's `serve.stations`; a station the job did not pass is
+ABSENT, never zero.
+
+  submitted_at       daemon   the record is made (`queue.new_job`; lint,
+                              signature and batch profile lie before it)
+  enqueued_at        daemon   the spool's hard write of it is down
+  claimed_at         daemon   a worker claimed the job and marked it
+                              `running`: `started_at`, same instant, kept
+                              under its old name
+  owner_spawned_at   daemon   only in the request that had to spawn the
+                              owner: its process is started
+  owner_sent_at      daemon   `DeviceOwner.request` holds the lock and a
+                              live owner: the request enters the pipe
+  owner_began_at     owner    `run_solo` / `run_vbatch` begins (`t0`)
+  owner_ended_at     owner    ... and ends: `job_wall_s` is their
+                              difference
+  owner_received_at  daemon   the answer is out of the pipe
+  finished_at        daemon   after the artifact's write (the artifact
+                              is then written once more, to carry it)
+
+`started_at` is the worker's CLAIM, not the run's start: with more workers
+than owners a `running` job may not have begun — the owner is busy with
+another worker's job — and `owner_wait_s` says for how long.  Followers
+and the members of a vbatch carry their leader's owner stations; a job the
+daemon's own thread answered (`JAXMC_SERVE_DEVICE_OWNER=0`, an interp job
+and its replay) has the daemon's four and no `owner_*` key.  `python -m
+jaxmc.obs report` prints them as one `stations:` line.  The same borders
+are SPANS on the clock a device trace has: in the daemon's recorder the
+`job` / `vbatch` span holds `job.owner_wait` (the lock wanted -> held)
+and `job.owner_run` (into the pipe -> out of it); in the owner ONE
+envelope span a job, `job` or `vbatch`, on the job's (the leader's) own
+recorder, so its artifact's `phases` and the owner's trace carry it.
 
 Job SIGNATURES (`job_signature`) hash the spec/cfg CONTENTS plus every
 result-affecting option (session.SessionConfig.job_signature_fields),
@@ -108,6 +151,12 @@ OPTION_FIELDS = (
 
 JOB_STATUSES = ("queued", "running", "done", "failed", "drained",
                 "quarantined")
+
+#: a served job's stations in the order it passes them ("A job's clock"
+#: above): keys of the record and of the artifact's `serve.stations`
+STATIONS = ("submitted_at", "enqueued_at", "claimed_at",
+            "owner_spawned_at", "owner_sent_at", "owner_began_at",
+            "owner_ended_at", "owner_received_at", "finished_at")
 
 
 class BadJob(ValueError):

@@ -167,6 +167,12 @@ class JobQueue:
         }
         job.update({k: v for k, v in extra.items() if v is not None})
         self.save(job)
+        # the station after `submitted_at` (serve/protocol.py): the
+        # spool's hard write — its retries and backoff included — is
+        # down, the record durable; kept in the record itself, for the
+        # worker that claims the job reads it from there
+        job["enqueued_at"] = time.time()
+        self.save(job)
         return job
 
     def save(self, job: Dict[str, Any]) -> None:

@@ -165,3 +165,50 @@ class TestStall:
         clk.t += 31
         wd._tick(clk.t)  # callback error swallowed
         assert tel.counters["watchdog.stalls"] == 1
+
+    def test_an_idle_daemon_is_not_a_stall_a_long_owner_run_is(
+            self, tmp_path):
+        """The serve daemon's fleet recorder (`idle_ok=True`, ISSUE 49): a
+        daemon with no span open is waiting for work — the "stall" a
+        healthy traced window once counted was that, while the harness
+        wrote its trace — and a stall under open job spans names the WAIT
+        for the owner apart from the owner's RUN."""
+        tel, wd, clk, trace, msgs = mk(tmp_path, idle_ok=True)
+        wd._tick(clk.t)
+        clk.t += 95                  # no job for a minute and a half
+        wd._tick(clk.t)
+        assert "watchdog.stalls" not in tel.counters and not msgs
+        # the default, a run's recorder, does count quiet between spans
+        (tmp_path / "run").mkdir()
+        tel2, wd2, clk2, _, msgs2 = mk(tmp_path / "run")
+        wd2._tick(clk2.t)
+        clk2.t += 31
+        wd2._tick(clk2.t)
+        assert tel2.counters["watchdog.stalls"] == 1
+        assert "no open span" in msgs2[0]
+        # two workers, one owner: one job runs, the other waits for it
+        import threading
+        opened, release = threading.Barrier(3), threading.Event()
+
+        def worker(child):
+            with tel.span("job"), tel.span(child):
+                opened.wait()
+                release.wait()
+        threads = [threading.Thread(target=worker, args=(c,))
+                   for c in ("job.owner_run", "job.owner_wait")]
+        for t in threads:
+            t.start()
+        opened.wait()
+        wd._tick(clk.t)              # latch
+        clk.t += 31
+        wd._tick(clk.t)
+        release.set()
+        for t in threads:
+            t.join()
+        (st,) = [e for e in events(trace) if e["ev"] == "stall"]
+        assert sorted(st["open_spans"]) == ["job", "job", "job.owner_run",
+                                            "job.owner_wait"]
+        assert "job.owner_run" in msgs[0] and "job.owner_wait" in msgs[0]
+        clk.t += 31                  # idle again once both are answered
+        wd._tick(clk.t)
+        assert tel.counters["watchdog.stalls"] == 1
