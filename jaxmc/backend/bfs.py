@@ -2404,14 +2404,17 @@ class TpuExplorer:
 
     def _tier_probe(self, keys: np.ndarray) -> np.ndarray:
         """[n] bool, True where a key lives in a cold run; the probe's
-        span and its two counters."""
+        span and its three counters."""
         tel = obs.current()
+        verified = self._tiers.keys_verified
         with tel.span("tier.probe", keys=len(keys),
                       runs=len(self._tiers.host_runs)
                       + len(self._tiers.disk_runs)):
             dup = self._tiers.probe(keys)
         tel.counter("tier.keys_probed", len(keys))
         tel.counter("tier.keys_dropped", int(dup.sum()))
+        tel.counter("tier.keys_verified",
+                    self._tiers.keys_verified - verified)
         return dup
 
     # ---- jitted level step, compiled per (seen_cap, frontier_cap) ----

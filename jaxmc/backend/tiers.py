@@ -28,6 +28,12 @@ Key order: rows of int32 words compared signed-lexicographically — the
 device sort order.  `_keyview` maps that order monotonically onto
 unsigned big-endian bytes so np.searchsorted over a void view probes
 whole rows at once (memmap-friendly: disk runs are never copied in).
+A host run keeps its keys' leading 8 bytes beside it as a sorted column
+of native integers (its "fence"), and a probe meets a run there first:
+only the queries whose leading bytes the fence holds go on to the
+whole-row compare.  A disk run has no fence: every query takes its
+whole-row search.  The fence is a necessary condition: the whole-row
+search over the whole run decides every hit.
 
 Failure containment: a disk write that fails (ENOSPC, a dead mount, or
 the `tier_io_error` fault site) DEGRADES the store to host-tier-only
@@ -41,7 +47,7 @@ import ctypes
 import os
 import tempfile
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -71,6 +77,27 @@ def _rowview(b: np.ndarray) -> np.ndarray:
     one opaque 4*kd-byte row each — a VIEW, no copy, so searchsorted
     over a memmapped disk run touches only O(log n) pages."""
     return b.view(np.dtype((np.void, b.shape[1] * 4))).reshape(-1)
+
+
+def _lead_column(kb: np.ndarray) -> np.ndarray:
+    """[n, kd] keybyte array -> [n] native unsigned integers holding
+    each row's leading 8 bytes (uint64; the one word as uint32 where
+    kd is 1), contiguous: the order of the column is the order of the
+    rows as far as those bytes tell them apart, and numpy sorts,
+    searches and compares it with its native integer loops where the
+    void view takes a compare function a step."""
+    if kb.shape[1] == 1:
+        return kb[:, 0].astype(np.uint32)
+    return (np.ascontiguousarray(kb[:, :2]).view(">u8").reshape(-1)
+            .astype(np.uint64))
+
+
+def _held(col: np.ndarray, lead: np.ndarray) -> np.ndarray:
+    """Indices of the entries of `lead` — queries' leading bytes, any
+    order — that are in the sorted native column `col` (never empty):
+    numpy's native binary search."""
+    lb = np.searchsorted(col, lead)
+    return np.flatnonzero(col[np.minimum(lb, len(col) - 1)] == lead)
 
 
 def _keyview(a: np.ndarray) -> np.ndarray:
@@ -140,9 +167,14 @@ class TieredSeen:
     words whose raw byte order equals the rows' signed-lex order), so
     `probe` binary-searches each run as a zero-copy void view: no
     per-probe conversion of the host tier, O(log n) page touches per
-    memmapped disk run.  `dump`/`load` serialize the whole hierarchy
+    memmapped disk run.  A HOST run also keeps its fence (_lead_column:
+    derived, never dumped); a disk run keeps none — it would hold half
+    the run again in RAM.  `dump`/`load` serialize the whole hierarchy
     for checkpoints (int32 in the payload — portable).  All sizes are
-    in KEYS; bytes = keys * key_words * 4."""
+    in KEYS, the host budget too: a key on disk is
+    key_words * 4 bytes, a key in a HOST run that and its fence's 8
+    (4 where key_words is 1) — 24 bytes of RAM a fingerprint key, so
+    the default budget of 2^22 keys is 96 MiB."""
 
     #: host runs beyond this count compact into one (LSM fan-in)
     MAX_HOST_RUNS = 4
@@ -173,6 +205,11 @@ class TieredSeen:
         self.reset()
 
     # ---- sizing ------------------------------------------------------
+
+    @property
+    def host_runs(self) -> List[np.ndarray]:
+        """The host tier's runs (keybyte form), oldest first."""
+        return [run for run, _ in self._host]
 
     @property
     def host_keys(self) -> int:
@@ -207,7 +244,10 @@ class TieredSeen:
                 os.unlink(p)
             except OSError:
                 pass
-        self.host_runs: List[np.ndarray] = []  # keybyte form
+        # (run in keybyte form, its fence): the fence is None from the
+        # run's admission to the end of the spill() or load() that
+        # admitted it (_fence_up), never at a probe
+        self._host: List[Tuple[np.ndarray, Optional[np.ndarray]]] = []
         self.disk_runs = []
         self._retired = keep
         self._disk_keys = 0
@@ -216,6 +256,7 @@ class TieredSeen:
         self.spills = 0
         self.compactions = 0
         self.probe_wall_s = 0.0
+        self.keys_verified = 0
 
     # ---- spill / compaction ------------------------------------------
 
@@ -235,20 +276,29 @@ class TieredSeen:
         obs.current().counter("tier.spills")
         # keybyte form once, at admission — probes then view, never
         # convert (the host tier is probed every level after a spill)
-        self.host_runs.append(_to_keybytes(run))
+        self._host.append((_to_keybytes(run), None))
         self.log(f"-- tier: spilled {len(run)} keys to host "
                  f"(host={self.host_keys} disk={self._disk_keys} keys)")
-        if len(self.host_runs) > self.MAX_HOST_RUNS:
+        if len(self._host) > self.MAX_HOST_RUNS:
             self._compact_host()
         if self.host_keys > self.host_budget_keys:
             self._flush_to_disk()
+        self._fence_up()
+
+    def _fence_up(self) -> None:
+        """A fence for every host run that has none: the last step of
+        a spill() or a load(), after their compactions and flushes, so
+        that none is built for a run about to be merged or written
+        out."""
+        self._host = [(run, _lead_column(run) if fence is None
+                       else fence) for run, fence in self._host]
 
     def _compact_host(self) -> None:
         merged = self.host_runs[0]
         for r in self.host_runs[1:]:
             merged = _merge_sorted(merged, r, _rowview(merged),
                                    _rowview(r))
-        self.host_runs = [merged]
+        self._host = [(merged, None)]
         self.compactions += 1
         obs.current().counter("tier.compactions")
 
@@ -291,7 +341,7 @@ class TieredSeen:
             return
         self.disk_runs.append(path)
         self._disk_keys += len(run)
-        self.host_runs = []
+        self._host = []
         self.log(f"-- tier: flushed {len(run)} keys to disk "
                  f"({os.path.basename(path)})")
         if len(self.disk_runs) > self.MAX_DISK_RUNS:
@@ -345,17 +395,30 @@ class TieredSeen:
 
     def probe(self, keys: np.ndarray) -> np.ndarray:
         """[n, key_words] query rows -> [n] bool, True where the key is
-        present in ANY cold run (host or disk).  One vectorized binary
-        search per run; disk runs stream through np.memmap."""
+        present in ANY cold run (host or disk), for queries in any
+        order.  A host run is met at its fence first — the queries'
+        leading 8 bytes as native integers against the run's — and only
+        the queries whose leading bytes ARE in the run take the
+        whole-row search that decides (where the fence is the whole
+        key, key_words <= 2, its answer is the answer).  A disk run has
+        no fence: it streams through np.memmap and its whole-row search
+        takes every query.  `keys_verified` counts the queries that
+        went on to a run's deciding compare, summed over runs."""
         keys = np.ascontiguousarray(keys, np.int32)
         n = len(keys)
         hit = np.zeros(n, bool)
         if n == 0 or not self.active:
             return hit
         t0 = time.time()
-        vq = _keyview(keys)
-        for run in self.host_runs:
-            self._probe_view(_rowview(run), vq, hit)
+        kq = _to_keybytes(keys)
+        lead = _lead_column(kq)
+        vq = _rowview(kq)
+        for run, fence in self._host:
+            cand = _held(fence, lead)
+            self.keys_verified += len(cand)
+            if self.key_words > 2 and len(cand):
+                cand = cand[self._probe_view(_rowview(run), vq[cand])]
+            hit[cand] = True
         for path in self.disk_runs:
             try:
                 run = np.load(path, mmap_mode="r")
@@ -369,20 +432,17 @@ class TieredSeen:
             # keybyte on disk: the void view is a VIEW of the memmap,
             # so each query's binary search touches O(log n) pages and
             # the run is never materialized in RAM
-            self._probe_view(_rowview(run), vq, hit)
+            hit |= self._probe_view(_rowview(run), vq)
+        self.keys_verified += n * len(self.disk_runs)
         self.probe_wall_s += time.time() - t0
         return hit
 
     @staticmethod
-    def _probe_view(vr: np.ndarray, vq: np.ndarray,
-                    hit: np.ndarray) -> None:
-        miss = ~hit
-        if not miss.any():
-            return
-        q = vq[miss]
+    def _probe_view(vr: np.ndarray, q: np.ndarray) -> np.ndarray:
+        """[m] bool: which of the void queries `q` are rows of the
+        sorted void view `vr` (never empty)."""
         lb = np.searchsorted(vr, q, side="left")
-        found = (lb < len(vr)) & (vr[np.minimum(lb, len(vr) - 1)] == q)
-        hit[miss] = found
+        return vr[np.minimum(lb, len(vr) - 1)] == q
 
     # ---- checkpoint serialization ------------------------------------
 
@@ -444,8 +504,8 @@ class TieredSeen:
                 f"tier checkpoint has key_words="
                 f"{payload.get('key_words')}, this engine uses "
                 f"{self.key_words} (layout/seen-mode mismatch)")
-        self.host_runs = [_to_keybytes(r)
-                          for r in payload.get("host", [])]
+        self._host = [(_to_keybytes(r), None)
+                      for r in payload.get("host", [])]
         self.spills = int(payload.get("spills", 0))
         self.compactions = int(payload.get("compactions", 0))
         for p in payload.get("disk_paths", []):
@@ -476,12 +536,13 @@ class TieredSeen:
             if self.spill_dir is None:
                 self.spill_dir = os.path.dirname(p)
         for run in payload.get("disk", []):
-            self.host_runs.append(_to_keybytes(
-                np.ascontiguousarray(run, np.int32)))
+            self._host.append((_to_keybytes(
+                np.ascontiguousarray(run, np.int32)), None))
             if self.host_keys > self.host_budget_keys:
                 self._flush_to_disk()
-        if len(self.host_runs) > self.MAX_HOST_RUNS:
+        if len(self._host) > self.MAX_HOST_RUNS:
             self._compact_host()
+        self._fence_up()
 
     # ---- stats -------------------------------------------------------
 
@@ -492,7 +553,8 @@ class TieredSeen:
                "disk_runs": len(self.disk_runs),
                "spills": self.spills,
                "compactions": self.compactions,
-               "probe_wall_s": round(self.probe_wall_s, 6)}
+               "probe_wall_s": round(self.probe_wall_s, 6),
+               "keys_verified": self.keys_verified}
         if self.io_degraded:
             out["io_degraded"] = self.io_degraded
         return out

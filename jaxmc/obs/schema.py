@@ -271,7 +271,8 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       probed level the spans `tier.pull {rows}`, `tier.keys {rows}`,
       `tier.probe {keys, runs}`, `tier.push {rows}`; counters
       `tier.keys_probed` / `tier.keys_dropped` / `tier.redone_rows`
-      (candidates of levels a spill rolled back and ran again);
+      (candidates of levels a spill rolled back and ran again; since
+      ISSUE 50 also `tier.keys_verified`, below);
       gauge `tier.cap_breached` (rows a table grew to past a cap
       that could not seat one level's candidates); `tier.occupancy`
       is published at the end of every search of a capped engine
@@ -717,6 +718,25 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       recorder the counter `checkpoint.bytes`: the size of each file
       the span wrote, after the rename (engine/ckpt.py
       `write_periodic`, `bfs._write_ck`).
+
+  (PR 50, still jaxmc.metrics/4 — all additive/optional; the cold probe
+   meets a host run at its fence, backend/tiers.py, ISSUE 50.  Only a
+   capped search that probed a cold run emits it:)
+    - counter `tier.keys_verified`, beside `tier.keys_probed` /
+      `tier.keys_dropped` (bfs._tier_probe): the queries that went on
+      to a run's deciding whole-row compare, summed over the runs a
+      probe searched.  A HOST run is met at its fence first (its
+      keys' leading 8 bytes as a sorted native column,
+      tiers._lead_column, searched natively) and passes on only the
+      queries whose leading bytes it holds, so there the count is the
+      hits plus the keys that share 8 leading bytes with a stranger
+      (with 128-bit fingerprints: the hits); a DISK run has no fence
+      and every query counts.  Always >= `tier.keys_dropped` and <=
+      `tier.keys_probed` x runs.  `result.tiers` (stats()) gains
+      `keys_verified`, the search's total.  The span `tier.probe
+      {keys, runs}` covers what it covered: all of `TieredSeen.probe`
+      — the queries' leading bytes, every host run's fence and
+      whole-row search, every disk run's whole-row search.
 """
 
 from __future__ import annotations

@@ -138,13 +138,15 @@ def _cold_spills(procs, max_money, cap):
     itself (a cold duplicate too: it sits in the table AND in a run from
     then on); once a run exists the host probes each level's device-new
     keys against the runs and drops the duplicates before they are counted
-    or explored.  The levels come back as [frontier, candidates,
-    device-new, new]."""
+    or explored (`keys_verified`: a probed key once for every run it is in
+    — what passes a run's fence where no two keys share their leading 8
+    bytes and the runs stay apart).  The levels come back as [frontier,
+    candidates, device-new, new]."""
     ref = _reference()
     codec = ref._Codec(procs, max_money)
     frontier = hot = np.unique(ref._init_states(codec))
     runs, out = [], {"spills": [], "redone_rows": 0, "keys_probed": 0,
-                     "keys_dropped": 0, "levels": []}
+                     "keys_dropped": 0, "keys_verified": 0, "levels": []}
     generated = distinct = int(frontier.size)
     depth = 0
     while True:
@@ -163,6 +165,9 @@ def _cold_spills(procs, max_money, cap):
             dup = np.isin(device_new, np.concatenate(runs))
             out["keys_probed"] += int(device_new.size)
             out["keys_dropped"] += int(dup.sum())
+            out["keys_verified"] += sum(
+                int(np.isin(device_new, run, assume_unique=True).sum())
+                for run in runs)
             new = device_new[~dup]
         out["levels"].append([int(frontier.size), int(succ.size),
                               int(device_new.size), int(new.size)])
@@ -391,6 +396,9 @@ def test_the_ooc_cells_spill_schedule_as_arithmetic():
                              [7, 341856]]
     assert (sim["spilled_keys"], sim["redone_rows"], sim["keys_probed"],
             sim["keys_dropped"]) == (1444808, 3367456, 1470128, 9852)
+    # 968 of the keys dropped lie in two runs when they are asked for:
+    # `tier_verify_share` reads 0.736 where nothing else passes a fence
+    assert sim["keys_verified"] == 10820
     assert len(sim["spills"]) == TieredSeen.MAX_HOST_RUNS
     assert sim["cold_keys"] <= 1 << 22  # TieredSeen's default host budget
     # the tables admitted every distinct key once and every cold duplicate
@@ -439,6 +447,14 @@ def test_the_engine_spills_as_the_arithmetic_says(tmp_path, monkeypatch):
                 assert rise["tier." + key] == sim[key], key
             assert tel.gauges["tier.occupancy"]["host"] == sim["cold_keys"]
             assert res.tiers["host_keys"] == sim["cold_keys"]
+            # the keys that passed a run's fence and took the whole-row
+            # compare: every key dropped did, none more than once a run —
+            # and no two 128-bit fingerprints here share 64 leading bits,
+            # so exactly the keys a run holds
+            assert sim["keys_dropped"] <= rise["tier.keys_verified"] <= \
+                sim["keys_probed"] * len(sim["spills"])
+            assert rise["tier.keys_verified"] == sim["keys_verified"] == \
+                res.tiers["keys_verified"]
             # `search.*` keep their meaning: new rows after the cold
             # duplicates are taken off, generated rows once
             assert rise["search.rows_new"] == \

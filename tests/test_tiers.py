@@ -39,7 +39,9 @@ import numpy as np
 import pytest
 
 from jaxmc import faults, obs
-from jaxmc.backend.tiers import TieredSeen, _keyview, _np_rank_merge
+from jaxmc.backend.tiers import (TieredSeen, _from_keybytes, _held,
+                                 _keyview, _lead_column, _np_rank_merge,
+                                 _to_keybytes)
 from jaxmc.front.cfg import ModelConfig, parse_cfg
 from jaxmc.sem.modules import Loader, bind_model
 
@@ -365,6 +367,285 @@ class TestTieredSeen:
             t.probe(r[:5])
 
 
+# ------------------------------------------------ the ordered probe
+
+#: words a fingerprint never avoids: the signed order's two ends, the
+#: sign change, and a few small ones so that leading words COLLIDE
+_EDGE = np.array([-(1 << 31), (1 << 31) - 1, -1, 0, 1, -2, 2], np.int64)
+_LAYOUTS = ("host", "compacted", "flushed", "mixed", "reloaded")
+_RUN_KEYS = 90
+
+
+def _collide_rows(rng, n, kd):
+    """Unique sorted rows that COLLIDE in front: the words before the
+    last come from _EDGE in the two leading places (the last word is
+    small), so many rows share their leading 8 bytes and differ behind
+    them, and a made-up query often meets a row."""
+    a = rng.integers(-(1 << 31), 1 << 31, (n, kd), dtype=np.int64)
+    lead = min(kd - 1, 2)
+    a[:, :lead] = _EDGE[rng.integers(0, len(_EDGE), (n, lead))]
+    a[:, -1] = rng.integers(-500, 500, n)
+    return _sorted_rows(np.unique(a.astype(np.int32), axis=0))
+
+
+def _spread_rows(rng, n, kd):
+    """Unique sorted rows of words drawn from all of int32, as
+    fingerprints are: a fence's buckets hold a key or two."""
+    a = rng.integers(-(1 << 31), 1 << 31, (n, kd), dtype=np.int64)
+    return _sorted_rows(np.unique(a.astype(np.int32), axis=0))
+
+
+_ROWS = {"collide": _collide_rows, "spread": _spread_rows}
+
+
+def _laid_out(tmp_path, kd, layout, runs):
+    """A store that holds `runs` as the layout says: three host runs;
+    one compacted host run; disk runs alone; a disk run and a host run;
+    the mixed store dumped and loaded into one with room, where every
+    run is a host run again and its fence made anew."""
+    budget = {"host": 10 ** 9, "compacted": 10 ** 9, "flushed": 1,
+              "mixed": _RUN_KEYS + _RUN_KEYS // 2,
+              "reloaded": _RUN_KEYS + _RUN_KEYS // 2}[layout]
+    t = TieredSeen(kd, host_budget_keys=budget,
+                   spill_dir=str(tmp_path / "spill"))
+    for r in runs:
+        t.spill(r)
+    if layout == "compacted":
+        assert len(t.host_runs) == 1 and t.compactions >= 1
+    if layout == "reloaded":
+        payload = t.dump()
+        t = TieredSeen(kd, spill_dir=str(tmp_path / "again"))
+        t.load(payload)
+    assert (len(t.host_runs), len(t.disk_runs)) == {
+        "host": (3, 0), "compacted": (1, 0), "flushed": (0, 3),
+        "mixed": (1, 1), "reloaded": (2, 0)}[layout]
+    return t
+
+
+def _runs_for(layout, rng, kd, make=_collide_rows):
+    n = TieredSeen.MAX_HOST_RUNS + 1 if layout == "compacted" else 3
+    return [make(rng, _RUN_KEYS, kd) for _ in range(n)]
+
+
+def _expect_verified(t, queries):
+    """`keys_verified` from the store's runs as they lie, not from its
+    probe: a host run passes on the queries whose leading 8 bytes (4
+    where the key is one word) are some row's; a disk run every one."""
+    lead = min(t.key_words, 2)
+    total = len(queries) * len(t.disk_runs)
+    for run in t.host_runs:
+        heads = {tuple(r[:lead]) for r in _from_keybytes(run).tolist()}
+        total += sum(tuple(q[:lead]) in heads for q in queries.tolist())
+    return total
+
+
+def _probe_and_check(t, queries, oracle):
+    before = t.keys_verified
+    got = t.probe(queries)
+    assert got.dtype == bool and got.shape == (len(queries),)
+    assert got.tolist() == [tuple(q) in oracle for q in queries.tolist()]
+    rise = t.keys_verified - before
+    assert rise == _expect_verified(t, queries)
+    assert t.stats()["keys_verified"] == t.keys_verified
+    assert int(got.sum()) <= rise <= len(queries) * (
+        len(t.host_runs) + len(t.disk_runs))
+    return got
+
+
+class TestOrderedProbe:
+    """`TieredSeen.probe` against a plain oracle (a set of row tuples):
+    the answer is exact whatever order the queries come in, whatever
+    the key's width, and wherever a run lies."""
+
+    @pytest.mark.parametrize("keys", ("spread", "crowded", "both",
+                                      "one_key", "twins"))
+    @pytest.mark.parametrize("kd", (1, 2, 4))
+    def test_the_fence_finds_the_leading_bytes_a_run_holds(self, kd, keys):
+        """`_held` against a set of the column's values: keys spread as
+        fingerprints are, keys that crowd a few leading values, both in
+        one run, a run of one key, rows that share their leading bytes
+        (the column holds a value many times: found once)."""
+        rng = np.random.default_rng(len(keys) * 10 + kd)
+        wide = rng.integers(-(1 << 31), 1 << 31, (300, kd), dtype=np.int64)
+        tight = wide.copy()
+        tight[:, :2] = rng.integers(0, 40, (300, min(kd, 2)))
+        rows = {"spread": wide, "crowded": tight,
+                "both": np.vstack([wide[:150], tight[:150]]),
+                "one_key": wide[:1],
+                "twins": np.repeat(wide[:60], 5, axis=0)}[keys].copy()
+        if keys == "twins" and kd > 2:
+            rows[:, -1] = np.arange(len(rows))
+        run = _to_keybytes(_sorted_rows(np.unique(
+            rows.astype(np.int32), axis=0)))
+        col = _lead_column(run)
+        held = set(col.tolist())
+        lead = np.concatenate([
+            col[rng.integers(0, len(col), 200)],
+            _lead_column(_to_keybytes(np.vstack([wide, tight])
+                                      .astype(np.int32))),
+            np.array([0, np.iinfo(col.dtype).max], col.dtype)])
+        lead = lead[rng.permutation(len(lead))]
+        got = _held(col, lead)
+        assert sorted(got.tolist()) == [
+            i for i, v in enumerate(lead.tolist()) if v in held]
+        assert len(_held(col, lead[:0])) == 0
+
+    @pytest.mark.parametrize("order", ("random", "ascending",
+                                       "descending"))
+    @pytest.mark.parametrize("layout", _LAYOUTS)
+    @pytest.mark.parametrize("rows", sorted(_ROWS))
+    @pytest.mark.parametrize("kd", (1, 2, 3, 4, 5))
+    def test_equals_the_set_oracle(self, tmp_path, kd, rows, layout,
+                                   order):
+        rng = np.random.default_rng(1000 * kd + len(layout))
+        runs = _runs_for(layout, rng, kd, make=_ROWS[rows])
+        oracle = {tuple(r) for run in runs for r in run.tolist()}
+        t = _laid_out(tmp_path, kd, layout, runs)
+        assert len(t) >= len(oracle)   # keys IN RUNS: a twin counts twice
+        every = np.vstack(runs)
+        inside = every[rng.integers(0, len(every), 120)]
+        near = inside.copy()           # same leading bytes, another tail
+        near[:, -1] ^= rng.integers(1, 64, len(near), dtype=np.int32)
+        q = np.vstack([inside, near, _ROWS[rows](rng, 150, kd),
+                       inside[:40]])   # the last block: duplicates
+        if order == "random":
+            q = q[rng.permutation(len(q))]
+        else:
+            q = _sorted_rows(q)[::1 if order == "ascending" else -1]
+        got = _probe_and_check(t, q, oracle)
+        assert got.any() and not got.all()
+
+    @pytest.mark.parametrize("kind", ("empty", "all_hits", "no_hit",
+                                      "one_key_many_times"))
+    @pytest.mark.parametrize("kd", (1, 2, 3, 4, 5))
+    def test_edge_queries(self, tmp_path, kd, kind):
+        rng = np.random.default_rng(77 + kd)
+        runs = _runs_for("mixed", rng, kd)
+        oracle = {tuple(r) for run in runs for r in run.tolist()}
+        t = _laid_out(tmp_path, kd, "mixed", runs)
+        every = np.vstack(runs)
+        if kind == "empty":
+            q = np.zeros((0, kd), np.int32)
+        elif kind == "all_hits":
+            q = every[rng.permutation(len(every))]
+        elif kind == "no_hit":
+            # the word no row has in its last place
+            q = every[rng.permutation(len(every))[:100]].copy()
+            q[:, -1] = 10 ** 6
+        else:
+            q = np.repeat(every[5:6], 64, axis=0)
+        got = _probe_and_check(t, q, oracle)
+        assert len(got) == len(q)
+        if kind != "empty":
+            assert got.all() == got.any() == (kind != "no_hit")
+
+    @pytest.mark.parametrize("layout", ("host", "compacted", "mixed"))
+    @pytest.mark.parametrize("kd", (3, 4, 5))
+    def test_rows_that_all_share_their_leading_bytes(self, tmp_path, kd,
+                                                     layout):
+        """The fence at its worst: every row of every run, and every
+        query, starts with the same 8 bytes, so each query passes each
+        fence and the whole-row search decides them all."""
+        def same_head(rng, n, kd):
+            a = np.empty((n, kd), np.int32)
+            a[:, :2] = (-7, 1 << 30)
+            a[:, 2:-1] = 3
+            a[:, -1] = rng.permutation(10 * n)[:n] - 5 * n
+            return _sorted_rows(a)
+        rng = np.random.default_rng(31 * kd)
+        runs = _runs_for(layout, rng, kd, make=same_head)
+        oracle = {tuple(r) for run in runs for r in run.tolist()}
+        t = _laid_out(tmp_path, kd, layout, runs)
+        q = same_head(rng, 400, kd)[rng.permutation(400)]
+        before = t.keys_verified
+        got = _probe_and_check(t, q, oracle)
+        assert t.keys_verified - before == len(q) * (
+            len(t.host_runs) + len(t.disk_runs))
+        assert got.any() and not got.all()
+
+    @pytest.mark.parametrize("case", ("stays", "compacted", "flushed",
+                                      "loaded"))
+    def test_a_fence_is_built_only_for_a_run_that_stays(
+            self, tmp_path, monkeypatch, case):
+        """The fence comes last in a spill() or a load(), after their
+        compactions and flushes: a run that stays keeps the fence it
+        has, a spill that compacts builds the merged run's alone, one
+        that flushes builds none, a load one a host run it leaves."""
+        from jaxmc.backend import tiers
+        built = []
+        real = tiers._lead_column
+        monkeypatch.setattr(
+            tiers, "_lead_column",
+            lambda kb: built.append(len(kb)) or real(kb))
+        rng = np.random.default_rng(7)
+        runs = [_rand_runs(rng, 40, 0, kd=4)[0] for _ in range(5)]
+        t = TieredSeen(4, host_budget_keys=(
+            150 if case in ("flushed", "loaded") else 1 << 20),
+            spill_dir=str(tmp_path / "spill"))
+        if case == "loaded":
+            t.load({"key_words": 4, "host": runs[:2], "spills": 5,
+                    "compactions": 0, "disk": runs[2:]})
+            # 80 + 40 keys stay under the budget, the fourth run passes
+            # it and all go to disk as one; the fifth stays on the host
+            assert (len(t.host_runs), len(t.disk_runs)) == (1, 1)
+            assert built == [len(runs[4])]
+            return
+        for run in runs[:3]:
+            t.spill(run)
+        kept = [fence for _, fence in t._host]
+        assert built == [len(r) for r in runs[:3]]
+        t.spill(runs[3])
+        if case == "flushed":       # 160 keys pass the budget of 150
+            assert (t.host_runs, len(t.disk_runs)) == ([], 1)
+            assert len(built) == 3
+            return
+        assert all(a is b for a, b in zip(kept, (f for _, f in t._host)))
+        assert len(built) == 4
+        if case == "compacted":     # a fifth run: five compact into one
+            t.spill(runs[4])
+            assert len(t.host_runs) == 1 and t.compactions == 1
+            assert built[4:] == [len(t.host_runs[0])]
+        assert all(fence is not None for _, fence in t._host)
+
+    def test_the_fence_is_derived_and_the_formats_are_the_old_ones(
+            self, tmp_path):
+        """A payload as every earlier PR wrote it loads and probes; a
+        new dump has the same keys and int32 rows; a run file is the
+        keybyte array alone; the fence never leaves the process."""
+        rng = np.random.default_rng(50)
+        a, b = _rand_runs(rng, 80, 70, kd=4)
+        c, _ = _rand_runs(rng, 60, 0, kd=4)
+        old = {"key_words": 4, "host": [a], "spills": 3,
+               "compactions": 0, "disk": [b, c]}
+        t = TieredSeen(4, host_budget_keys=100,
+                       spill_dir=str(tmp_path / "spill"))
+        t.load(old)   # a and b pass the budget and go to disk; c stays
+        assert len(t.disk_runs) == 1 and len(t.host_runs) == 1
+        for run, col in t._host:
+            assert col.dtype == np.uint64 and col.flags.c_contiguous
+            assert np.array_equal(col, _lead_column(run))
+            assert np.array_equal(np.sort(col), col)
+        oracle = {tuple(r) for r in np.vstack([a, b, c]).tolist()}
+        q = np.vstack([a[::3], b[::4], c[::5], a[:20] + 1])
+        _probe_and_check(t, q, oracle)
+        payload = t.dump()
+        assert set(payload) == {"key_words", "host", "spills",
+                                "compactions", "disk"}
+        for run in payload["host"] + payload["disk"]:
+            assert run.dtype == np.int32 and run.shape[1] == 4
+        for path in t.disk_runs:
+            on_disk = np.load(path)
+            assert on_disk.dtype == np.dtype(">u4")
+            assert on_disk.ndim == 2 and on_disk.shape[1] == 4
+        assert sorted(os.listdir(t.spill_dir)) == sorted(
+            os.path.basename(p) for p in t.disk_runs)
+        one = TieredSeen(1)
+        one.spill(np.array([[-5], [0], [7]], np.int32))
+        assert one._host[0][1].dtype == np.uint32
+        assert one.probe(np.array([[7], [6], [-5]], np.int32)).tolist() \
+            == [True, False, True]
+
+
 # ------------------------------------------------ capped engine parity
 
 def _capped_kw(tmp_path, cap=OOC_CAP, host=OOC_HOST_KEYS):
@@ -561,6 +842,13 @@ class TestColdTiersLastOneSearch:
                     tel.gauges["tier.occupancy"]["host"]
                 assert rise["tier.keys_probed"] > \
                     rise["tier.keys_dropped"] > 0
+                # what passed a run's fence: every key found did, and
+                # no key more than once a run
+                assert rise["tier.keys_dropped"] <= \
+                    rise["tier.keys_verified"] <= \
+                    rise["tier.keys_probed"] * res.tiers["spills"]
+                assert res.tiers["keys_verified"] == \
+                    rise["tier.keys_verified"]
                 # every search does what the first did: same spills, same
                 # probes, nothing carried over
                 firsts = firsts or rise
