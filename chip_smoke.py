@@ -51,6 +51,11 @@ smaller rungs, and say so:
      S5: 29,382 / 9,336 where the unreduced model has 545,822 states);
      the device must canonicalise in the SORTED form
      (compile/symmetry2.py) — an unreduced fallback is a failure
+  G  a spec bounded by the cfg's CONSTRAINT alone: the resident engine
+     on specs/transfer_retry_3p.cfg (a retry loop whose counter nothing
+     in the spec bounds: 16,553 / 5,515, 3,219 rows fingerprinted and
+     discarded); the device must judge the constraint itself (gauge
+     `constraint.compiled` 1) — an interpreter fallback is a failure
 
 Any leg that fails, times out, demotes, or reports a platform other
 than the one asked for ends the smoke non-zero with no result line.
@@ -93,6 +98,7 @@ RUNGS = {
         "D_variant": ("batchtoy.tla", "batchtoy_b.cfg"),
         "E": ("transfer_scaled.tla", "transfer_scaled_4p.cfg"),
         "F": ("transfer_symmetry.tla", "transfer_symmetry_5p3.cfg"),
+        "G": ("transfer_retry.tla", "transfer_retry_3p.cfg"),
     },
     "rehearsal": {
         "A": ("constoy.tla", "constoy.cfg"),
@@ -103,6 +109,7 @@ RUNGS = {
         "D_variant": ("batchtoy.tla", "batchtoy_b.cfg"),
         "E": ("symtoy_scaled.tla", "symtoy_scaled.cfg"),
         "F": ("transfer_symmetry.tla", "transfer_symmetry_3p4.cfg"),
+        "G": ("transfer_retry.tla", "transfer_retry_2p.cfg"),
     },
 }
 
@@ -453,6 +460,31 @@ class Smoke:
         say(f"  [F_sym] symmetry.form=sorted group_order="
             f"{g.get('symmetry.group_order')}")
 
+    def leg_g(self) -> None:
+        say(f"leg G: a cfg CONSTRAINT on the resident engine, "
+            f"{self.rungs['G'][1]}: judged on the device")
+        case = self.pin("G")
+        a, _ = self.check("G_con", "G", ["--resident", "--no-trace"],
+                          no_deadlock=case.no_deadlock)
+        g, c = a["gauges"], a["counters"]
+        need(g.get("constraint.compiled") == 1
+             and g.get("expand.constraints_interp") == 0,
+             f"G_con: constraint.compiled="
+             f"{g.get('constraint.compiled')!r}, "
+             f"expand.constraints_interp="
+             f"{g.get('expand.constraints_interp')!r}: the constraint "
+             f"is not judged on the device")
+        self.assert_counts("G_con", a["result"], case)
+        need(c.get("search.rows_discarded", 0) > 0
+             and c.get("search.slots_constrained", 0) > 0,
+             f"G_con: search.rows_discarded="
+             f"{c.get('search.rows_discarded')!r}, "
+             f"search.slots_constrained="
+             f"{c.get('search.slots_constrained')!r}")
+        say(f"  [G_con] constraint.compiled=1 rows_discarded="
+            f"{c['search.rows_discarded']} slots_constrained="
+            f"{c['search.slots_constrained']}")
+
 
 def _tail(path: str, n: int = 600) -> str:
     try:
@@ -467,7 +499,7 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse-on-cpu", action="store_true",
                     help="toy-size plumbing rehearsal on XLA:CPU — NOT "
                          "a chip run, prints no result object")
-    ap.add_argument("--legs", default="A,B,C,D,E,F",
+    ap.add_argument("--legs", default="A,B,C,D,E,F,G",
                     help="comma-separated subset (debugging one leg; "
                          "a partial run prints no result object)")
     ap.add_argument("--leg-timeout", type=float, default=_LEG_TIMEOUT_S,
@@ -503,7 +535,7 @@ def main(argv=None) -> int:
     if smoke.rehearsal:
         say("chip_smoke: rehearsal passed — NOT a chip run, no result")
         return 0
-    if legs != ["A", "B", "C", "D", "E", "F"]:
+    if legs != ["A", "B", "C", "D", "E", "F", "G"]:
         say("chip_smoke: partial run — no result")
         return 0
     print(json.dumps({"ok": True, "device": smoke.device}), flush=True)

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 from .. import obs
 from ..compile.vspec import Bounds, CompileError, ModeError
 from ..engine.simulate import sample_states
-from .bfs import SENTINEL, TpuExplorer, _pow2_at_least
+from .bfs import KEY_FN, SENTINEL, TpuExplorer, _pow2_at_least
 
 
 class BatchIncompatible(Exception):
@@ -539,6 +539,9 @@ class BatchCheckEngine:
                 elif ck.get("layout_sig") != eng._layout_sig():
                     why = ("lane layout differs from the checkpoint's "
                            "(solo or different-cohort checkpoint)")
+                elif eng.fp_mode and ck.get("key_fn") != KEY_FN:
+                    why = ("dedup keys made by another fingerprint "
+                           "function (an older jaxmc)")
             except (CkptError, OSError, ValueError) as ex:
                 why = str(ex)
             if why is None:

@@ -8,10 +8,11 @@ deduplicates by lexicographic multi-key sort (jax.lax.sort).
 Two dedup modes:
   exact  (narrow layouts, W <= FP_THRESHOLD): sort keys are all W state
          lanes — zero collision risk, stronger than TLC.
-  fp128  (wide layouts — raft's W is ~1-2k lanes): sort keys are four
-         independent 32-bit mixes of the row (a 128-bit fingerprint, vs
-         TLC's 64-bit, testout2:261-264); the collision probability is
-         reported in the result like TLC reports its estimate.
+  fp128  (wide layouts — raft's W is ~1-2k lanes): sort keys are the
+         four words of a 128-bit fingerprint of the row (vs TLC's
+         64-bit, testout2:261-264); the collision probability is
+         reported in the result like TLC reports its estimate.  A key
+         basis of at most four words is permuted, not hashed: exact.
 
 Capacities are power-of-two buckets that grow on demand, so jit recompiles
 O(log N) times; all shapes inside a step are static (XLA/TPU requirement).
@@ -78,8 +79,13 @@ SYMMETRY_WARNING = (
     "cfg SYMMETRY NOT applied on the jax backend: counts are "
     "unreduced and will exceed the interp/TLC reduced counts")
 
-_FP_MIX = [(0x9E3779B1, 0x85EBCA6B), (0xC2B2AE35, 0x27D4EB2F),
-           (0x165667B1, 0x9E3779B1), (0x85EBCA6B, 0xC2B2AE35)]
+# fingerprint128's start state (the first hex digits of pi) and the
+# rounds of its 128-bit permutation (Chaskey's own count)
+_FP_SEED = (0x243F6A88, 0x85A308D3, 0x13198A2E, 0x03707344)
+_FP_ROUNDS = 8
+# ... and its name in a checkpoint: a seen table's fingerprints are only
+# met again by the function that made them
+KEY_FN = "chaskey8/xor4"
 
 
 @lru_cache(maxsize=8)
@@ -157,20 +163,65 @@ def _take_rows_fast(x, idx) -> np.ndarray:
     return np.asarray(jnp.take(x, jnp.asarray(pad), axis=0))[:n]
 
 
+def _rotl(x, r: int):
+    return (x << jnp.uint32(r)) | (x >> jnp.uint32(32 - r))
+
+
+def _fp_permute(v0, v1, v2, v3):
+    """Chaskey's permutation of four 32-bit words (Mouha et al., SAC
+    2014): adds, rotations and xors alone, each step invertible, so the
+    whole is a BIJECTION of the 128 bits."""
+    for _ in range(_FP_ROUNDS):
+        v0 = v0 + v1
+        v1 = _rotl(v1, 5) ^ v0
+        v0 = _rotl(v0, 16)
+        v2 = v2 + v3
+        v3 = _rotl(v3, 8) ^ v2
+        v0 = v0 + v3
+        v3 = _rotl(v3, 13) ^ v0
+        v2 = v2 + v1
+        v1 = _rotl(v1, 7) ^ v2
+        v2 = _rotl(v2, 16)
+    return v0, v1, v2, v3
+
+
 def fingerprint128(rows):
-    """rows [N, W] i32 -> [N, 4] i32 (four independent 32-bit mixes)."""
+    """rows [N, W] i32 -> [N, 4] i32: a 128-bit state absorbs the row
+    four words at a time (xor) and is permuted after each four.
+
+    A row of at most four words — every bit-packed layout of the
+    benchmark's cells — is xored into the state ONCE and permuted, in
+    straight-line code: the key is a bijection of the row and dedup on
+    it is EXACT.  A wider row is absorbed by a scan over its blocks of
+    four (the whole permutation after each, so a difference in one
+    block cannot be met by one in the next; one loop body whatever the
+    width: unrolled, XLA:CPU took 20 s for 32 words and rising).
+
+    What this replaced (ISSUE 51) ran four FNV-style lanes, h = (h ^
+    w * m1) * m2 a word, each closed by a bijective finaliser.  A
+    product carries a difference UPWARD only, so two rows that differ
+    in the top bits of their words alone differed in the top bits of
+    each lane alone: `desk-constraint-4p` holds two states (pc[p2],
+    tries[p4]; bits 26-31 of the two packed words) whose four lanes all
+    met, one state of 8,320,026 was taken for seen, and of its
+    12,929,810 rows 10,938 pairs met in the first two lanes, where 64
+    honest bits give none."""
     u = rows.astype(jnp.uint32)
-    out = []
-    for j, (m1, m2) in enumerate(_FP_MIX):
-        h = jnp.full(rows.shape[0], 2166136261 + j * 0x9E3779B1,
-                     jnp.uint32)
-        for i in range(rows.shape[1]):
-            h = (h ^ (u[:, i] * jnp.uint32(m1))) * jnp.uint32(m2)
-        h = h ^ (h >> 15)
-        h = h * jnp.uint32(0x2C1B3C6D)
-        h = h ^ (h >> 12)
-        out.append(h.astype(jnp.int32))
-    return jnp.stack(out, axis=1)
+    n, width = rows.shape
+    v = tuple(jnp.full(n, s, jnp.uint32) for s in _FP_SEED)
+    if width <= 4:
+        v = _fp_permute(*(v[j] ^ u[:, j] if j < width else v[j]
+                          for j in range(4)))
+    else:
+        nb = -(-width // 4)
+        # [nb, 4, N]: a block's four words as rows, N minor
+        blocks = jnp.pad(u, ((0, 0), (0, 4 * nb - width))).T \
+            .reshape(nb, 4, n)
+
+        def absorb(v, blk):
+            return _fp_permute(*(v[j] ^ blk[j] for j in range(4))), None
+        v, _ = lax.scan(absorb, v, blocks)
+    return jnp.stack(v, axis=1).astype(jnp.int32)
 
 
 # The shape of the seen-table probe (ISSUE 27), from its tail measured
@@ -1604,6 +1655,9 @@ class TpuExplorer:
         tel.gauge("expand.compiled_instances", self.A)
         tel.gauge("expand.invariants_interp", len(self.fb_invs))
         tel.gauge("expand.constraints_interp", len(self.fb_cons))
+        if model.constraints:
+            # ... and how many the device judges itself (ISSUE 51)
+            tel.gauge("constraint.compiled", len(self.constraint_fns))
         tel.gauge("expand.mode",
                   "compiled" if not self.fb_arms
                   else ("hybrid" if self.A else "interp-arms"))
@@ -2528,7 +2582,11 @@ class TpuExplorer:
                 nvalid = jnp.arange(C) < new_count
                 new_rows = jnp.where(nvalid[:, None], new_rows, SENTINEL)
 
-            with jax.named_scope("jaxmc.scan"):
+            # a scope of its own where the cfg has a CONSTRAINT, so that a
+            # trace prices the branch (ISSUE 51); a program without one
+            # keeps the name it had
+            with jax.named_scope("jaxmc.constraint" if con_fns
+                                 else "jaxmc.scan"):
                 # constraints FIRST: violating states are fingerprinted (they
                 # are in seen2 above) but discarded — never counted distinct,
                 # never invariant-checked, never explored. TLC semantics,
@@ -2593,7 +2651,8 @@ class TpuExplorer:
             if front_keys is not None:
                 out["front_keys"] = front_keys
             if need_edges:
-                with jax.named_scope("jaxmc.scan"):
+                with jax.named_scope("jaxmc.constraint" if con_fns
+                                     else "jaxmc.scan"):
                     exp_all = cvalid
                     for nm, f in con_fns:
                         exp_all = exp_all & jax.vmap(f)(cand_u)
@@ -3189,7 +3248,7 @@ class TpuExplorer:
                 with jax.named_scope("jaxmc.compact"):
                     new_rows, nblocks = _gather_prefix(
                         acc_rows, rm["nk_sidx"], new_count, AccCap, RB)
-                with jax.named_scope("jaxmc.scan"):
+                with jax.named_scope("jaxmc.constraint"):
                     # constraints: violating states stay fingerprinted
                     # in seen2 but are discarded (not distinct / checked
                     # / explored).  new_rows are PACKED; the predicate
@@ -3199,10 +3258,11 @@ class TpuExplorer:
                     for nm, f in con_fns:
                         explore = explore & jax.vmap(f)(new_rows_u)
                     explore_count = jnp.sum(explore, dtype=jnp.int32)
-                with jax.named_scope("jaxmc.compact"):
                     # the rows a CONSTRAINT discards must leave the
                     # frontier: a stable sort names the kept ones first
-                    # (over every slot: no cell prices this branch), and
+                    # (over every AccCap slot, as the predicates ran:
+                    # `search.slots_constrained`; the cell
+                    # `desk-constraint-4p` prices it, ISSUE 51), and
                     # the blocks gather only those
                     idx4 = jnp.arange(AccCap, dtype=jnp.int32)
                     ops4 = ((1 - explore.astype(jnp.int32)), idx4)
@@ -3561,7 +3621,7 @@ class TpuExplorer:
     def _pack_ovf_msg(self) -> str:
         return ("a value escaped its bit-packed lane's profiled range "
                 "(compile/pack.py profiles raw-int lanes from sampled "
-                "states with a 3x margin): deepen --sample or rerun "
+                "states with a margin): deepen --sample or rerun "
                 "with JAXMC_PACK=0 (unpacked lanes) — counts stay exact "
                 "either way")
 
@@ -3777,7 +3837,8 @@ class TpuExplorer:
         from ..engine import ckpt as _ckpt
         payload = dict(mode=mode, module=self.model.module.name,
                        vars=list(self.model.vars),
-                       layout_sig=self._layout_sig(), **state)
+                       layout_sig=self._layout_sig(),
+                       key_fn=KEY_FN if self.fp_mode else None, **state)
         if self._tiers is not None and self._tiers.active:
             # the FULL tier hierarchy rides every checkpoint (ISSUE 12):
             # kill/resume mid-spill restores host and disk runs, so the
@@ -3820,6 +3881,11 @@ class TpuExplorer:
                 "cannot resume: the lane layout differs from the "
                 "checkpoint's (different --seq-cap/--grow-cap/--kv-cap "
                 "or a changed model?)")
+        if self.fp_mode and ck.get("key_fn") != KEY_FN:
+            raise CkptError(
+                "cannot resume: the checkpoint's dedup keys were made by "
+                "another fingerprint function (an older jaxmc); run the "
+                "search from the start")
         if ck.get("tiers") is not None:
             # restore the cold tiers BEFORE any step compiles, so the
             # resumed engine probes (and its steps stream keys) from
@@ -4250,8 +4316,8 @@ class TpuExplorer:
                 if _build_form(caps["AccCap"]) == "window":
                     newkey_blocks = int(summary[-1])
                     summary = summary[:-1]
-                fcount_in, gen_in, dist_in, depth_in = \
-                    fcount, generated, distinct, depth
+                fcount_in, gen_in, dist_in, depth_in, seen_in = \
+                    fcount, generated, distinct, depth, seen_count
                 stat = int(summary[0])
                 seen_count = int(summary[1])
                 fcount = int(summary[2])
@@ -4261,6 +4327,11 @@ class TpuExplorer:
                 depth = int(summary[6])
                 which = int(summary[7])
                 ovcode = int(summary[8])
+                # rows that entered the seen table and that a CONSTRAINT
+                # kept out of the frontier (ISSUE 51): the host has both
+                # terms, the carry need not grow.  Taken before the cold
+                # tiers' filter below: a cold duplicate is no discard
+                discarded = (seen_count - seen_in) - (distinct - dist_in)
                 # per-dispatch POR deltas: run() zero-seeds them per
                 # dispatch and rolls back overflowed levels, so summing
                 # across dispatches (including redos) never double-counts
@@ -4355,6 +4426,13 @@ class TpuExplorer:
             tel.counter("search.slots_compacted", compact_blocks
                         * _compact_block_rows(caps["AccCap"],
                                               caps["FCap"]))
+            if self.constraint_fns:
+                # ... and judged every slot of the level's buffer of new
+                # rows, and sorted as many, whatever held a row (ISSUE
+                # 51): a rolled-back level too, as the sorts count theirs
+                tel.counter("search.slots_constrained",
+                            lvls * caps["AccCap"])
+                tel.counter("search.rows_discarded", discarded)
             if redo_after_spill and generated > gen_in:
                 # the level a spill rolled back has now run a second
                 # time (one level a dispatch once tiers are active):
@@ -5527,6 +5605,10 @@ class TpuExplorer:
                     else front_count
                 distinct += kept_count  # kept states only (discards excluded)
                 seen = out["seen"]
+                # what the step put into the table and a CONSTRAINT kept
+                # out of the frontier (cold duplicates are no discards)
+                discarded = int(out["seen_count"]) - seen_count \
+                    - front_count
                 seen_count = int(out["seen_count"])
                 tel.level(depth, frontier=fcount, generated=int(out["gen"]),
                           new=kept_count, distinct=distinct, seen=seen_count,
@@ -5548,6 +5630,11 @@ class TpuExplorer:
                         _merge_blocks(seen_count, SC)
                         * _merge_block_rows(SC))
             tel.counter("search.rows_new", kept_count)
+            if self.constraint_fns:
+                # the predicates ran over every slot of the candidate
+                # block (ISSUE 51)
+                tel.counter("search.slots_constrained", C)
+                tel.counter("search.rows_discarded", discarded)
             self._fp_occupancy = seen_count
 
             with tel.span("level.rows"):

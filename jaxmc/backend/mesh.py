@@ -670,7 +670,7 @@ class MeshExplorer(TpuExplorer):
 
         @jax.named_scope("jaxmc.compact")
         def finish_scatter(new_rows, new_src, nvalid):
-            with jax.named_scope("jaxmc.scan"):
+            with jax.named_scope("jaxmc.constraint"):
                 new_rows_u = plan.unpack_rows(new_rows)
                 explore = nvalid
                 for nm, f in con_fns:
@@ -2023,6 +2023,10 @@ class MeshExplorer(TpuExplorer):
         D, PW = self.D, self.PW
         last_progress = last_ck = time.time()
         lvl_frontier = int(np.sum(np.asarray(fcount)))
+        # the shards' seen rows at the last committed level: what a level
+        # adds beyond its kept rows a CONSTRAINT discarded (ISSUE 51)
+        seen_sum = int(np.sum(np.asarray(seen_count))) \
+            if self.constraint_fns else 0
         # rank-merge valid-candidate capacity (ISSUE 11): starts at the
         # learned/heuristic value, grows by rollback-and-redo exactly
         # like SC/FC/TRL when a level's valid exchanged rows outgrow it
@@ -2108,6 +2112,10 @@ class MeshExplorer(TpuExplorer):
             n_keys = self._merge_out_rows(R, VC)
             tel.gauge("merge.build_form", _build_form(n_keys))
             tel.counter("search.slots_sorted", nlv * D * n_keys)
+            if self.constraint_fns:
+                # ... and judged every slot of the merge's block of new
+                # rows on each shard (ISSUE 51)
+                tel.counter("search.slots_constrained", nlv * D * n_keys)
             tel.counter("search.seen_slots", nlv * D * SC)
             # ... and binary-searched only the query blocks that held a
             # valid key on each shard: the ring carries their number a
@@ -2208,6 +2216,7 @@ class MeshExplorer(TpuExplorer):
                             # cap
                             seen, seen_count = self._mesh_tier_spill(
                                 seen, seen_count, SC)
+                            seen_sum = 0
                             # the rolled-back level runs a second time
                             tel.counter("tier.redone_rows",
                                         int(scal[_S_GEN]))
@@ -2312,6 +2321,10 @@ class MeshExplorer(TpuExplorer):
                 self._por_stats["masked"] += int(scal[_S_PORM])
                 sum_seen = int(scal[_S_SUMS])
                 max_seen = int(scal[_S_MAXS])
+                if self.constraint_fns:
+                    tel.counter("search.rows_discarded",
+                                sum_seen - seen_sum - int(scal[_S_NEW]))
+                seen_sum = sum_seen
                 self._fp_occupancy = sum_seen
                 if sum_seen:
                     self._shard_balance = max_seen / (sum_seen / D)

@@ -244,15 +244,23 @@ class LanePlan:
                     bits[i] = 32
                     continue
                 olo, ohi = int(obs_lo[i]), int(obs_hi[i])
-                # symmetric margin of one observed span (floor 4) on
-                # both sides, then 4x the resulting code count (+2
-                # bits): BFS-depth-growing counters routinely reach a
-                # multiple of the sampled max, and a spurious OV_PACK
-                # abort costs a whole run — two extra bits per guarded
-                # lane is cheap insurance
+                # margin of one observed span (floor 4) on both sides,
+                # then 4x the resulting code count (+2 bits):
+                # BFS-depth-growing counters routinely reach a multiple
+                # of the sampled max, and a spurious OV_PACK abort costs
+                # a whole run — two extra bits per guarded lane is cheap
+                # insurance.  A lane never seen below 0 is a count or a
+                # length and the extra codes go above it; a lane seen
+                # NEGATIVE is a signed quantity and nothing says which
+                # way the search takes it, so the same codes are split
+                # evenly (ISSUE 51: `alice` of the transfer specs counts
+                # DOWN, was packed for [-10, 77] from walks that saw
+                # [-3, 4], and the model reaches -12)
                 span = max(ohi - olo, 4)
-                lo = olo - span
-                hi = lo + (ohi + span - lo + 1) * 4 - 1
+                codes = (ohi - olo + 2 * span + 1) * 4
+                lo = olo - span if olo >= 0 \
+                    else olo - (codes - (ohi - olo + 1)) // 2
+                hi = lo + codes - 1
                 guarded[i] = True
             else:
                 # structural OR analyzer-proven bound; extend with the

@@ -337,6 +337,22 @@ CASES: List[Case] = [
          distinct=9336, generated=29382, jax="yes", mode="compiled",
          res_caps={"SC": 1 << 14, "FCap": 1 << 11, "AccCap": 1 << 14,
                    "VC": 1 << 12, "chunk": 512}),
+    # a spec bounded by the cfg's CONSTRAINT alone (ISSUE 51): the
+    # transfer race with a retry loop whose counter nothing in the spec
+    # bounds.  Counts are TLC's under a CONSTRAINT — a discarded successor
+    # is generated and fingerprinted, not distinct — as bench/reference/
+    # transfer_retry.py and the exact interpreter give them
+    # (tests/test_retry_constraint.py); 3,219 and 366 rows discarded
+    Case("specs/transfer_retry.tla", root="repo",
+         cfg="specs/transfer_retry_3p.cfg",
+         distinct=5515, generated=16553, jax="yes", mode="compiled",
+         res_caps={"SC": 1 << 14, "FCap": 1 << 10, "AccCap": 1 << 12,
+                   "VC": 512, "chunk": 256}),
+    Case("specs/transfer_retry.tla", root="repo",
+         cfg="specs/transfer_retry_2p.cfg",
+         distinct=1289, generated=2587, jax="yes", mode="compiled",
+         res_caps={"SC": 1 << 12, "FCap": 256, "AccCap": 1 << 10,
+                   "VC": 256, "chunk": 128}),
     # device SYMMETRY toys (orbit-canonical counts; deadlock expected
     # when every process exhausts its turns)
     Case("specs/symtoy.tla", root="repo", cfg="specs/symtoy.cfg",

@@ -737,6 +737,36 @@ jaxmc.metrics/2 artifact minus the new optional surface, so readers and
       {keys, runs}` covers what it covered: all of `TieredSeen.probe`
       — the queries' leading bytes, every host run's fence and
       whole-row search, every disk run's whole-row search.
+
+  (PR 51, still jaxmc.metrics/4 — all additive/optional; a cfg's
+   CONSTRAINT at size, ISSUE 51.  A cfg WITHOUT a CONSTRAINT emits NONE
+   of these, and its lowered programs are byte-identical to what they
+   were:)
+    - gauge `constraint.compiled` — the CONSTRAINTs compiled for the
+      device (beside `expand.constraints_interp`, those the interpreter
+      judges on the host: an engine with any of those is hybrid).
+    - device scope `jaxmc.constraint` — on the resident engine round
+      the unpack of the level's new rows, the predicates over them, the
+      stable sort that names the kept rows first and the kept rows'
+      gather into the frontier (the FIRST gather of the new rows stays
+      under `jaxmc.compact`; up to PR 50 the predicates read
+      `jaxmc.scan` and the sort and second gather `jaxmc.compact`); on
+      the level engine and the mesh round the unpack and the predicates.
+    - counter `search.rows_discarded` — rows that entered the seen table
+      in a search and that a CONSTRAINT kept out of the frontier: TLC
+      fingerprints a constraint-violating successor and then discards it
+      (not distinct, not invariant-checked, not explored).  Host
+      arithmetic on what every dispatch already reports: the rise of the
+      table's occupancy less the rise of `distinct`.  With it,
+      `search.rows_new` + `search.rows_discarded` is what the seen table
+      gained (the initial states apart); under `--seen-cap` a state that
+      was spilled, met again and discarded again counts again.
+    - counter `search.slots_constrained` — the slots the predicates (and
+      on the resident engine the sort) ran over: levels run x AccCap on
+      the resident engine (a rolled-back level counted, as
+      `search.slots_sorted` counts its own), the candidate block C a
+      level on the level engine, levels x D x the merge's key slots on
+      the mesh.
 """
 
 from __future__ import annotations

@@ -57,20 +57,27 @@ def _no_capacity_profiles(monkeypatch):
     monkeypatch.setenv("JAXMC_CAP_PROFILE", "0")
 
 
+def _entered(level):
+    """The rows a level puts into the seen table: its new rows, or — in a
+    fourth column, under a CONSTRAINT (PR 51) — the rows fingerprinted,
+    kept and discarded alike."""
+    return level[3] if len(level) > 3 else level[2]
+
+
 def _needs(levels, initial, vc, cap=None):
     """What a whole search asks of each capacity, from the reference's
-    levels [frontier, candidates, new]: the three inequalities.  Under a
-    device cap the table spills before it grows, so it need only seat the
-    widest level's candidates beside nothing."""
+    levels [frontier, candidates, new(, entered the table)]: the three
+    inequalities.  Under a device cap the table spills before it grows,
+    so it need only seat the widest level's candidates beside nothing."""
     seen, most = initial, 0
-    for _, cand, new in levels:
-        most = max(most, seen + cand)  # every candidate could be new
-        seen += new
+    for level in levels:
+        most = max(most, seen + level[1])  # every candidate could be new
+        seen += _entered(level)
     if cap is not None:
-        most = max(cand for _, cand, _ in levels)
+        most = max(level[1] for level in levels)
     return {"SC": most,
-            "FCap": max(max(f, new) for f, _, new in levels),
-            "AccCap": max(cand for _, cand, _ in levels) + vc}
+            "FCap": max(max(level[0], level[2]) for level in levels),
+            "AccCap": max(level[1] for level in levels) + vc}
 
 
 def _ladder_step(default, need):
@@ -90,7 +97,8 @@ def _cold_ladder(levels, initial, defaults, cap=None):
     an empty one) instead of growing."""
     caps = dict(defaults)
     programs, seen, logged = [dict(caps)], initial, initial
-    for lvl, (_, cand, new) in enumerate(levels):
+    for lvl, level in enumerate(levels):
+        cand, new = level[1], level[2]
         # with "LogCap" among the defaults the search keeps its state log
         # and ENDS at the last level given (a violation, PR 44): every
         # level it goes on from is appended, after the three checks above
@@ -114,7 +122,7 @@ def _cold_ladder(levels, initial, defaults, cap=None):
             caps["AccCap"] = _ladder_step(
                 caps["AccCap"], max(2 * caps["VC"], caps["FCap"]))
             programs.append(dict(caps))
-        seen += new
+        seen += _entered(level)
         logged += new if goes_on else 0
     return programs
 
@@ -188,7 +196,8 @@ def test_there_are_resident_pins():
     assert {"transfer_scaled", "transfer_scaled_4p8",
             "transfer_scaled_4p", "transfer_scaled_4p8_ooc",
             "transfer_violation_4p",
-            "transfer_symmetry_5p"} <= set(RESIDENT_PINS)
+            "transfer_symmetry_5p",
+            "transfer_retry_4p"} <= set(RESIDENT_PINS)
 
 
 @pytest.mark.parametrize("name", RESIDENT_PINS)
@@ -216,6 +225,16 @@ def test_res_caps_are_the_ladder_steps_that_hold_the_levels(name):
     initial = pins["distinct"] - sum(new for _, _, new in levels)
     assert initial == levels[0][0]
     assert caps["VC"] == DEFAULTS["VC"]
+    if "fingerprinted_levels" in pins:
+        # under a CONSTRAINT (PR 51) the table holds the rows it
+        # discarded too: SC follows the rows that ENTER it, FCap the rows
+        # kept
+        entered = pins["fingerprinted_levels"]
+        assert len(entered) == len(levels)
+        assert all(e >= new for e, (_, _, new) in zip(entered, levels))
+        assert pins["fingerprinted"] == initial + sum(entered) == \
+            pins["distinct"] + pins["discarded"]
+        levels = [level + [e] for level, e in zip(levels, entered)]
     cap = pins.get("seen_cap")
     if cap is not None:
         # under a cap the table holds a level's DEVICE-new rows, its cold
@@ -265,6 +284,21 @@ def test_the_real_rungs_cold_ladder():
     assert ladder[1] == dict(DEFAULTS, AccCap=1 << 19)
     assert [p["FCap"] for p in ladder][-1] == 1 << 20
     assert sym["initial_generated"] == sym["max_money"] ** sym["procs"]
+    # four processes that retry, bounded by the cfg's CONSTRAINT alone
+    # (PR 51): eight programs too, the same four capacities at the end;
+    # the table is sized by the rows that enter it — by the kept rows
+    # alone, 8,320,026, SC would stop at 2^24 as well, but seen +
+    # candidates reads 12,930,041 where the kept rows give 8,320,257
+    retry = _pins("transfer_retry_4p")
+    levels = [level + [e] for level, e in zip(
+        retry["levels"], retry["fingerprinted_levels"])]
+    ladder = _cold_ladder(levels, levels[0][0], DEFAULTS)
+    assert len(ladder) == 8 and ladder[-1] == retry["res_caps"] == \
+        sym["res_caps"]
+    assert [k for a, b in zip(ladder, ladder[1:]) for k in a
+            if a[k] != b[k]] == ["AccCap", "FCap", "AccCap", "SC", "FCap",
+                                 "AccCap", "SC"]
+    assert _needs(levels, levels[0][0], DEFAULTS["VC"])["SC"] == 12930041
 
 
 def _toy_cfg(tmp_path, procs, max_money):

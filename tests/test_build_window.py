@@ -156,7 +156,7 @@ def test_only_the_resident_engine_counts_keyed_slots(engine, tmp_path,
 # the mesh, and for the level engine (desk-default-3p) the candidate
 # grid of its largest step, 10 expand instances x FC 2^16.
 WINDOW_PINS = {"transfer_scaled_4p", "transfer_symmetry_5p",
-               "transfer_violation_4p"}
+               "transfer_violation_4p", "transfer_retry_4p"}
 LEVEL_ENGINE_3P = "the level engine, 3p"
 
 

@@ -119,7 +119,10 @@ def test_oracle_answers_inside_its_deadline_and_live_platforms_agree(
         counts[plat] = (art["result"]["generated"],
                         art["result"]["distinct"])
     # max_states is judged a level, so the cut is the same on any target
-    assert set(counts.values()) == {(43109, 5219)}, counts
+    # (the spec has a VIEW: which state stands for a view's class is the
+    # first in KEY order, so the counts of a cut run are the key function's
+    # — 43109 / 5219 under the fingerprint up to ISSUE 51)
+    assert set(counts.values()) == {(43252, 5258)}, counts
 
 
 # ------------------------------------------------ one process per chip
@@ -230,9 +233,23 @@ def test_chip_smoke_rehearsal_symmetry_leg(tmp_path):
     assert art["counters"]["search.canon_rows"] == 2369
 
 
+def test_chip_smoke_rehearsal_constraint_leg(tmp_path):
+    """Leg G (ISSUE 51): a cfg CONSTRAINT on the resident engine must be
+    judged on the device, with the manifest's counts."""
+    r = _rehearse(tmp_path, "G")
+    assert "[G_con] counts 2587 generated / 1289 distinct == pin" in r.stdout
+    assert "[G_con] constraint.compiled=1 rows_discarded=366 " in r.stdout
+    with open(tmp_path / "out" / "G_con.json") as fh:
+        art = json.load(fh)
+    assert art["gauges"]["constraint.compiled"] == 1
+    assert art["gauges"]["expand.constraints_interp"] == 0
+    assert art["counters"]["search.rows_discarded"] == 366
+    assert art["counters"]["search.slots_constrained"] > 0
+
+
 @pytest.mark.slow
 def test_chip_smoke_rehearsal_all_legs(tmp_path):
-    r = _rehearse(tmp_path, "A,B,C,D,E,F")
+    r = _rehearse(tmp_path, "A,B,C,D,E,F,G")
     assert "daemon_holds_device=False" in r.stdout
     assert "SIGTERM -> clean drain" in r.stdout
     assert "[E_mesh] counts" in r.stdout

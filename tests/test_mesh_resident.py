@@ -271,8 +271,13 @@ class TestMergeStrategies:
         assert r.violation.kind == ri.violation.kind == "assert"
         trace = r.violation.trace
         assert len(trace) == len(ri.violation.trace)
-        assert [a for _, a in trace] == \
-            [a for _, a in ri.violation.trace]
+        # ... the same labels, in the order of ITS behavior: which of the
+        # equally short ones a shard meets first hangs on the order of the
+        # keys (the labels were the interpreter's, one for one, under the
+        # fingerprint up to ISSUE 51 and are a permutation under its
+        # successor)
+        assert sorted(a for _, a in trace) == \
+            sorted(a for _, a in ri.violation.trace)
         ctx = model.ctx()
         assert trace[0][0] in enumerate_init(model.init, ctx,
                                              model.vars)

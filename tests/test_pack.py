@@ -164,6 +164,33 @@ def test_pack_overflow_guard_raises():
     assert bool(np.asarray(ovf)[0])
 
 
+@pytest.mark.parametrize("seen, packed_for", [
+    # never seen below 0 — a count or a length: one span below, the extra
+    # codes above, as since ISSUE 6
+    ((0, 5), (-5, 58)),
+    ((2, 4), (-2, 41)),
+    # seen negative — a signed quantity: the same codes split evenly.  The
+    # transfer specs' `alice`, which counts DOWN: walks that saw [-3, 4]
+    # packed it for [-10, 77] and desk-constraint-4p reaches -12 (ISSUE 51)
+    ((-3, 4), (-43, 44)),
+    ((-1, 4), (-30, 33)),
+    ((-20, -10), (-76, 47)),
+])
+def test_an_observed_lane_seen_negative_gets_its_margin_on_both_sides(
+        seen, packed_for):
+    from jaxmc.compile.pack import LanePlan, _LaneClass
+    lanes = [_LaneClass(None, None, True, False, False)] * 2
+    plan = LanePlan(2, lanes, np.array([seen[0], 0]),
+                    np.array([seen[1], 1000]), np.array([True, True]))
+    lo = int(plan.bias[0])
+    assert (lo, lo + int(plan.allowed[0])) == packed_for
+    # free in bits: the old one-sided rule spent as many
+    span = max(seen[1] - seen[0], 4)
+    assert int(plan.allowed[0]) + 1 == (seen[1] - seen[0] + 2 * span + 1) * 4
+    edge = np.array([[packed_for[0], 0], [packed_for[1], 0]], np.int32)
+    assert (plan.unpack_np(plan.pack_np(edge)) == edge).all()
+
+
 # ---------------------------------------------------------------- layer 3
 
 def _device_counts(name, mode, env):

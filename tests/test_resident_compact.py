@@ -263,7 +263,7 @@ def test_a_resumed_checkpoint_ends_on_the_full_counts(tmp_path,
 
 # ------------------------------------------------------ the program's text
 
-def _post_merge_compact_ops(sess):
+def _post_merge_compact_ops(sess, scope="compact"):
     """The ops of the resident program's lowered text that sit under
     `jaxmc.compact` in the LEVEL's body and not in a chunk's (whose own
     compaction sorts and takes over the candidate grid)."""
@@ -278,9 +278,9 @@ def _post_merge_compact_ops(sess):
         jax.ShapeDtypeStruct((caps["SC"], ex.K), jnp.int32), i32,
         jax.ShapeDtypeStruct((caps["FCap"], ex.PW), jnp.int32),
         *([i32] * 7)).as_text(debug_info=True)
-    scope = re.compile(
-        r'"jit\(run\)/while/body/jaxmc\.compact/([^"]*)"')
-    return [m.group(1) for m in scope.finditer(text)]
+    found = re.compile(
+        r'"jit\(run\)/while/body/jaxmc\.%s/([^"]*)"' % scope)
+    return [m.group(1) for m in found.finditer(text)]
 
 
 @pytest.mark.parametrize("constraint", [False, True],
@@ -289,8 +289,10 @@ def test_no_sort_after_the_merge_where_no_constraint_exists(
         constraint, tmp_path, monkeypatch):
     """The one static branch: without a CONSTRAINT the level's
     `jaxmc.compact` after the merge is the block loop alone — no sort,
-    no take outside the loop; with one the sort that names the kept
-    rows is there."""
+    no take outside the loop; with one it is the new rows' block loop
+    alone as well, and the sort that names the kept rows and the kept
+    rows' block loop are there under a scope of their own (ISSUE 51:
+    `jaxmc.constraint`)."""
     (tmp_path / "grid.tla").write_text(GRID)
     (tmp_path / "grid.cfg").write_text(
         "SPECIFICATION Spec\nINVARIANT InBox\n"
@@ -302,4 +304,7 @@ def test_no_sort_after_the_merge_where_no_constraint_exists(
     assert res.ok and bool(sess.engine.constraint_fns) == constraint
     ops = _post_merge_compact_ops(sess)
     assert "while/body/jit(_take)" in ops and "jit(_take)" not in ops
-    assert ("sort" in ops) == constraint
+    assert "sort" not in ops
+    judged = _post_merge_compact_ops(sess, "constraint")
+    assert ("sort" in judged) == constraint == bool(judged)
+    assert ("while/body/jit(_take)" in judged) == constraint
