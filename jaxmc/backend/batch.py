@@ -58,6 +58,7 @@ import jax.numpy as jnp
 from .. import obs
 from ..compile.vspec import Bounds, CompileError, ModeError
 from ..engine.simulate import sample_states
+from ..sem.enumerate import enumerate_init
 from .bfs import KEY_FN, SENTINEL, TpuExplorer, _pow2_at_least
 
 
@@ -448,13 +449,19 @@ class BatchCheckEngine:
         bfs_n, walks, depth = tuple(c0.sample)
         extra: List[Dict[str, Any]] = []
         reports = []
+        # a follower's Init is walked once, here, and the list handed on
+        # to its engine (the donor walks its own, ISSUE 52)
+        follower_inits: List[List[Dict[str, Any]]] = []
         with self.tel.span("batch_sample", members=len(models)):
             for m in models:
                 reports.append(infer_state_bounds(m))
                 if m is not m0:
+                    inits = enumerate_init(m.init, m.ctx(), m.vars)
+                    follower_inits.append(inits)
                     extra.extend(sample_states(m, bfs_states=bfs_n,
                                                n_walks=walks,
-                                               walk_depth=depth))
+                                               walk_depth=depth,
+                                               inits=inits))
         merged = merge_lane_bounds(
             [r.lane_bounds() if r is not None and r.converged else None
              for r in reports])
@@ -490,7 +497,8 @@ class BatchCheckEngine:
             raise BatchIncompatible(f"donor engine not batchable: "
                                     f"{reason}")
         self.members[0].engine = donor
-        for mem, c in zip(self.members[1:], self.cfgs[1:]):
+        for mem, c, inits in zip(self.members[1:], self.cfgs[1:],
+                                 follower_inits):
             mem.engine = TpuExplorer(
                 mem.model, donor=donor, log=self.log,
                 max_states=c0.max_states,
@@ -499,7 +507,8 @@ class BatchCheckEngine:
                 checkpoint_path=c.checkpoint,
                 checkpoint_every=c.checkpoint_every,
                 resume_from=c.resume,
-                final_checkpoint=c.final_checkpoint)
+                final_checkpoint=c.final_checkpoint,
+                inits=inits)
         self._validate_resumes()
         cvecs = np.stack([mem.engine._cvec for mem in self.members]) \
             if lift else np.zeros((len(self.members), 0), np.int32)

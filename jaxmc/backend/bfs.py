@@ -104,12 +104,21 @@ def _table_program(sharding):
                    out_shardings=sharding)
 
 
+def _init_walks(tel) -> int:
+    """Enumerations of an Init that `tel` has counted
+    (`sem/enumerate.py::enumerate_init`); 0 on the null recorder."""
+    return getattr(tel, "counters", {}).get("init.enumerations", 0)
+
+
 def filter_init_states(model, layout, init_rows):
     """Apply TLC's CONSTRAINT-discard semantics to encoded init rows:
     returns (explored_indices, (invariant_name, state) | None). Violating
     inits are fingerprinted by the caller but never counted distinct,
     invariant-checked, or explored; invariants run on kept inits only
-    (host-side interpreter — init sets are small)."""
+    (host-side interpreter, a decode and an evaluation a DISTINCT row:
+    1,728 to 20,736 rows in the transfer cfgs at three and four
+    processes, and under SYMMETRY at five the 4,368 orbits of 248,832
+    initial states)."""
     from ..sem.modules import satisfies_constraints
     from ..sem.eval import eval_expr, _bool
     explored = []
@@ -1153,7 +1162,8 @@ class TpuExplorer:
                  host_tier_keys: Optional[int] = None,
                  lift_consts: Optional[Tuple[str, ...]] = None,
                  por: bool = False,
-                 donor: Optional["TpuExplorer"] = None):
+                 donor: Optional["TpuExplorer"] = None,
+                 inits: Optional[List[Dict[str, Any]]] = None):
         # cross-model batching (ISSUE 13): `lift_consts` compiles the
         # named CONSTANTs as traced kernel inputs instead of baked
         # scalars, so one compiled program serves every model that
@@ -1179,7 +1189,7 @@ class TpuExplorer:
                 checkpoint_path=checkpoint_path,
                 checkpoint_every=checkpoint_every,
                 resume_from=resume_from,
-                final_checkpoint=final_checkpoint)
+                final_checkpoint=final_checkpoint, inits=inits)
             return
         self._lift_names: Tuple[str, ...] = tuple(lift_consts or ())
         if self._lift_names and not host_seen:
@@ -1235,13 +1245,17 @@ class TpuExplorer:
         self._last_frontier_np: Optional[np.ndarray] = None
 
         tel = obs.current()
-        base_ctx = model.ctx()
-        self.init_states = enumerate_init(model.init, base_ctx, model.vars)
+        # Init is walked ONCE and the list handed on (ISSUE 52): to the
+        # sampler, whose samples begin with it, and through the layout
+        # build, which encodes every sample, to the first search
+        self._init_walks0 = _init_walks(tel)
+        self.init_states = self._enumerate_init(inits)
         bfs_n, walks, depth = sample_cfg
         with tel.span("layout_sample", bfs_states=bfs_n, walks=walks,
                       walk_depth=depth):
             sampled = sample_states(model, bfs_states=bfs_n,
-                                    n_walks=walks, walk_depth=depth)
+                                    n_walks=walks, walk_depth=depth,
+                                    inits=self.init_states)
         sampled = list(sampled) + self.extra_samples
         # static bounds inference (ISSUE 9): a converged interval proof
         # turns observed-range guarded int lanes into proven-width lanes
@@ -1270,8 +1284,16 @@ class TpuExplorer:
                 tel.gauge("analyze.bounds_converged",
                           bool(rep.converged))
         with tel.span("layout_build", samples=len(sampled)):
-            self.layout = build_layout2(model, sampled, self.bounds,
-                                        static_bounds=self._static_bounds)
+            self.layout, rows = build_layout2(
+                model, sampled, self.bounds,
+                static_bounds=self._static_bounds)
+        # the initial states' rows, for `_prepare_init` (a copy: the
+        # samples' matrix goes); None where the layout could not encode
+        # one of them, and the first search raises what encoding it does
+        n = len(self.init_states)
+        self._init_rows_built: Optional[np.ndarray] = \
+            rows[:n].copy() if len(rows) >= n else None
+        del sampled, rows
         self.kc = KernelCtx(model, self.layout, self.bounds)
         # per-model lifted-constant values, in _lift_names order: the
         # runtime input vector the shared kernels read instead of baked
@@ -2043,7 +2065,8 @@ class TpuExplorer:
     def _clone_from_donor(self, donor: "TpuExplorer", model: Model,
                           log, max_states, store_trace, progress_every,
                           checkpoint_path, checkpoint_every,
-                          resume_from, final_checkpoint) -> None:
+                          resume_from, final_checkpoint,
+                          inits=None) -> None:
         """FOLLOWER construction (ISSUE 13): reuse the donor's layout
         and compiled kernels wholesale — zero sampling, zero bounds
         fixpoint, zero kernel builds — binding only this member's
@@ -2087,9 +2110,23 @@ class TpuExplorer:
         self._cvec = np.asarray([int(model.defs[n])
                                  for n in self._lift_names], np.int32)
         self._cvec_dev = None
-        base_ctx = model.ctx()
-        self.init_states = enumerate_init(model.init, base_ctx,
-                                          model.vars)
+        # a follower built no layout: it encodes its own initial states
+        self._init_walks0 = None
+        self._init_rows_built = None
+        self.init_states = self._enumerate_init(inits)
+
+    def _enumerate_init(self, inits: Optional[List[Dict[str, Any]]]
+                        ) -> List[Dict[str, Any]]:
+        """The model's initial states: `inits` where the caller has
+        already walked Init (a cohort samples its members before their
+        engines exist), else one walk, under span `init_enumerate`."""
+        if inits is not None:
+            return inits
+        model = self.model
+        with obs.current().span("init_enumerate") as sp:
+            inits = enumerate_init(model.init, model.ctx(), model.vars)
+            sp.attrs["states"] = len(inits)
+        return inits
 
     def _expand_fn(self, scope: str = "jaxmc.expand"):
         """The (state x action) expansion closure shared by both step
@@ -3673,11 +3710,24 @@ class TpuExplorer:
         if cached is not None:
             return cached + (None,)
         layout = self.layout
-        raw = [layout.encode(st) for st in self.init_states]
+        tel = obs.current()
+        # the rows the layout build made of the initial states (ISSUE
+        # 52), else they are encoded here: a follower of a cohort, or a
+        # build that met a sample the layout refuses, whose encoding
+        # raises here what it always raised
+        raw = self._init_rows_built
+        self._init_rows_built = None
+        reused = raw is not None
+        if not reused:
+            raw = np.zeros((0, self.W), np.int32)
+            if self.init_states:
+                raw = np.stack([layout.encode(st)
+                                for st in self.init_states])
+        tel.gauge("layout.init_rows_reused", 1.0 if reused else 0.0)
         # TLC counts EVERY initial state as generated, also one whose
         # SYMMETRY orbit or VIEW value an earlier one already stored
         self._init_generated = len(raw)
-        if raw and self.canon_fn is not None:
+        if len(raw) and self.canon_fn is not None:
             # cfg SYMMETRY: dedup/count init states by their orbit's
             # canonical representative, matching the interp's add_state
             # (which canonicalizes BEFORE the seen probe). Without this,
@@ -3685,26 +3735,19 @@ class TpuExplorer:
             # device counts and seed `seen` with duplicate canonical
             # fingerprints, breaking the sorted-unique invariant the
             # resident rank-merge relies on.
-            raw = list(self._canon_host(np.stack(raw)))
-        if raw and self.view_fn is not None:
+            raw = self._canon_host(raw)
+        keyed = raw
+        if len(raw) and self.view_fn is not None:
             # cfg VIEW: init states sharing a view value count ONCE
             # (TLC fingerprints the view) — keep the first state per key
-            kb = np.asarray(jax.vmap(self.view_fn)(
-                jnp.asarray(np.stack(raw))))
-            if kb.ndim == 1:
-                kb = kb[:, None]
-            rows: Dict[bytes, np.ndarray] = {}
-            for i, rr in enumerate(raw):
-                rows.setdefault(np.ascontiguousarray(kb[i]).tobytes(),
-                                np.asarray(rr, np.int32))
-            init_rows = np.stack(list(rows.values()))
-        else:
-            rows = {}
-            for rr in raw:
-                rows[np.asarray(rr, np.int32).tobytes()] = True
-            init_rows = np.stack([np.frombuffer(kk, dtype=np.int32)
-                                  for kk in rows.keys()]) \
-                if rows else np.zeros((0, self.W), np.int32)
+            keyed = np.asarray(jax.vmap(self.view_fn)(jnp.asarray(raw)))
+            if keyed.ndim == 1:
+                keyed = keyed[:, None]
+        # one row a key, the first that has it, in their order
+        first: Dict[bytes, int] = {}
+        for i, kk in enumerate(np.ascontiguousarray(keyed)):
+            first.setdefault(kk.tobytes(), i)
+        init_rows = raw[list(first.values())]
         n_init = len(init_rows)
         explored_init, init_viol = filter_init_states(self.model, layout,
                                                       init_rows)
@@ -3725,6 +3768,11 @@ class TpuExplorer:
         self.log(f"Finished computing initial states: {distinct} distinct "
                  f"state{'s' if distinct != 1 else ''} generated.")
         self._init_prep = (init_rows, explored_init, n_init)
+        if self._init_walks0 is not None:
+            # walks of Init from this engine's entry to here: 1, or a
+            # consumer has gone back to walking it for itself
+            tel.gauge("layout.init_enumerations",
+                      _init_walks(tel) - self._init_walks0)
         # the rows are what every later search reads: let the interpreter
         # states go.  A cfg whose Init is a function space keeps a
         # quarter of a million of them (1.3 M dicts and functions), and

@@ -2335,43 +2335,60 @@ class Layout2:
 def build_layout2(model: Model, sampled_states: List[Dict[str, Any]],
                   bounds: Bounds,
                   static_bounds: Optional[Dict[str, Tuple[int, int]]]
-                  = None) -> Layout2:
-    from .vspec import (apply_bounds, collect_enums_from_value, infer)
+                  = None) -> Tuple[Layout2, np.ndarray]:
+    """The layout of `sampled_states`, and their rows under it: an int32
+    matrix of the samples' encodings in order, up to the first sample
+    the layout cannot encode.  An engine's samples begin with its
+    initial states, so its first search takes their rows from here and
+    encodes nothing again (ISSUE 52).
+
+    ONE pass over the samples infers (`vspec.Shapes`: each state's enums
+    and shapes, identical shapes folded by identity), a second encodes
+    under the merged specs, which only the whole first pass knows."""
+    from .vspec import Shapes, apply_bounds, collect_enums_from_value
     from .. import obs
     uni = EnumUniverse()
     # enum universe: every sampled value + every string literal in the
     # module AST + cfg model values (guards may compare against literals
     # no sampled state contains)
+    shapes = Shapes(uni)
+    merged: Dict[str, VS] = {}
     for st in sampled_states:
-        for v in st.values():
-            collect_enums_from_value(v, uni)
+        # in the state's own order: a value's enums enter the universe
+        # as it is inferred, and the universe's order is the layout's
+        for var, v in st.items():
+            merged[var] = shapes.merge(merged.get(var), shapes.infer(v))
     for d in model.defs.values():
         if not isinstance(d, OpClosure):
             collect_enums_from_value(d, uni)
     _collect_ast_strings(model, uni)
-    specs: Dict[str, VS] = {}
-    for var in model.vars:
-        sp = None
-        for st in sampled_states:
-            s2 = infer(st[var], uni)
-            sp = s2 if sp is None else vs_merge(sp, s2)
-        specs[var] = apply_bounds(sp, bounds)
-    lay = Layout2(tuple(model.vars), specs, uni)
+    vars = tuple(model.vars)
+    specs = {var: apply_bounds(merged[var], bounds) for var in vars}
+    lay = Layout2(vars, specs, uni)
     # bit-packed lane plan (ISSUE 6): structural bounds + observed int
     # ranges over the encoded sample rows decide per-lane bit widths
     from .pack import build_lane_plan
-    sample_rows = []
-    for st in sampled_states:
+    lanes: List[int] = []
+    n_rows = 0
+    first_skipped = None
+    for i, st in enumerate(sampled_states):
+        n0 = len(lanes)
         try:
-            sample_rows.append(lay.encode(st))
+            for var in vars:
+                vs_encode(st[var], specs[var], uni, lanes)
+            n_rows += 1
         except (CompileError, EvalError):
             # a sampled state the merged layout cannot encode would have
             # failed the search anyway; the plan just profiles without it
-            continue
+            del lanes[n0:]
+            if first_skipped is None:
+                first_skipped = i
+    sample_rows = np.asarray(lanes, np.int32).reshape(n_rows, lay.width)
     lay.plan = build_lane_plan(lay, sample_rows, static_bounds)
     tel = obs.current()
     tel.gauge("layout.enum_universe", len(uni.values))
     tel.gauge("layout.samples", len(sampled_states))
+    tel.gauge("layout.infer_distinct", shapes.distinct)
     tel.gauge("layout.packed_width_lanes", lay.plan.packed_width)
     tel.gauge("layout.bits_per_state", lay.plan.bits_per_state)
     tel.gauge("layout.pack_ratio",
@@ -2381,7 +2398,7 @@ def build_layout2(model: Model, sampled_states: List[Dict[str, Any]],
     # guarded lanes whose width now comes from the bounds analyzer —
     # read against layout.pack_guarded_lanes (the two are disjoint)
     tel.gauge("analyze.proven_lanes", lay.plan.proven_lanes)
-    return lay
+    return lay, sample_rows[:first_skipped]
 
 
 def _collect_ast_strings(model: Model, uni: EnumUniverse):

@@ -488,10 +488,11 @@ def identity_plan(width: int) -> LanePlan:
             np.zeros(width, bool), force_identity=True)
 
 
-def build_lane_plan(layout, sample_rows: List[np.ndarray],
+def build_lane_plan(layout, sample_rows: np.ndarray,
                     static_bounds: Optional[Dict[str, Tuple[int, int]]]
                     = None) -> LanePlan:
-    """Plan for a Layout2 from its specs + the encoded sample rows.
+    """Plan for a Layout2 from its specs + the encoded sample rows (a
+    matrix, a row a sample; a list of rows does too).
 
     static_bounds (ISSUE 9): per-variable PROVEN summary intervals from
     jaxmc/analyze/bounds.py — every raw-int lane under such a variable
@@ -506,8 +507,8 @@ def build_lane_plan(layout, sample_rows: List[np.ndarray],
     if len(classes) != W:
         # a walk-order defect would corrupt every row: refuse to pack
         return identity_plan(W)
-    if sample_rows:
-        mat = np.asarray(np.stack(sample_rows), np.int64)
+    if len(sample_rows):
+        mat = np.asarray(sample_rows, np.int64)
         sent_l = np.asarray([c.sent_ok for c in classes])
         is_sent = (mat == SENTINEL_LANE) & sent_l[None, :]
         real = ~is_sent

@@ -136,50 +136,127 @@ class VS:
 
 
 _EMPTY_MARKER = VS("empty")
+_INT, _BOOL, _ENUM, _EMPTYSET = (VS("int"), VS("bool"), VS("enum"),
+                                 VS("emptyset"))
+
+
+class Shapes:
+    """`infer` and `merge` with memory, for one pass over many values.
+
+    The shape of a function is a function of its keys and its elements'
+    shapes, the shape of a set of its members (where all are enums) or
+    of their shapes: every shape made is kept under that, so a quarter
+    of a million functions over one domain with integer elements are ONE
+    object, and folding them is one `merge` (a cfg whose Init is a
+    function space spent 8 s re-inferring and re-merging one shape,
+    ISSUE 52).  Shapes are named by `id` in the keys: the memory holds
+    every shape it has handed out, so no id is reused under it.
+
+    A value's strings and model values enter the universe on the way, in
+    `collect_enums_from_value`'s order (the universe's order is the enum
+    lanes' encoding, so it is part of the layout): a function over keys
+    not met before is collected whole first, one over known keys can
+    only bring new values, and brings them in the same order."""
+
+    def __init__(self, uni: EnumUniverse):
+        self.uni = uni
+        # (keys, their types) -> {ids of element shapes -> (shape, elems)}
+        self._fcns: Dict[Tuple, Dict[Tuple, Tuple]] = {}
+        self._sets: Dict[Tuple, Tuple] = {}
+        self._merged: Dict[Tuple[int, int], Tuple] = {}
+        self.distinct = 0  # shapes inferred afresh
+
+    def infer(self, v) -> VS:
+        """Shape of a single observed value."""
+        t = type(v)
+        if t is int:
+            return _INT
+        if t is bool:
+            return _BOOL
+        if isinstance(v, (str, ModelValue)):
+            self.uni.add(v)
+            return _ENUM
+        if isinstance(v, Fcn):
+            d = v.d
+            if not d:
+                return _EMPTY_MARKER
+            # the types ride along: <<x>> is a sequence, [TRUE |-> x] is
+            # not, and (1,) == (True,)
+            dom = (tuple(d), tuple(map(type, d)))
+            by_elems = self._fcns.get(dom)
+            if by_elems is None:
+                collect_enums_from_value(v, self.uni)
+                by_elems = self._fcns[dom] = {}
+            elems = [self.infer(x) for x in d.values()]
+            ids = tuple(map(id, elems))
+            made = by_elems.get(ids)
+            if made is None:
+                self.distinct += 1
+                made = by_elems[ids] = (_fcn_shape(d, elems), elems)
+            return made[0]
+        if isinstance(v, frozenset):
+            if not v:
+                return _EMPTYSET
+            collect_enums_from_value(v, self.uni)
+            members = sorted(v, key=sort_key)
+            mspecs = [self.infer(m) for m in members]
+            enums = all(s.kind == "enum" for s in mspecs)
+            key = ("set", tuple(members)) if enums else \
+                ("growset", tuple(map(id, mspecs)))
+            made = self._sets.get(key)
+            if made is None:
+                self.distinct += 1
+                if enums:
+                    shape = VS("set", dom=key[1])
+                else:
+                    elem = mspecs[0]
+                    for s in mspecs[1:]:
+                        elem = merge(elem, s)
+                    shape = VS("growset", cap=len(members), elem=elem)
+                made = self._sets[key] = (shape, mspecs)
+            return made[0]
+        raise CompileError(f"cannot infer a lane encoding for {fmt(v)}")
+
+    def merge(self, a: Optional[VS], b: VS) -> VS:
+        """`merge(a, b)`, made once for a pair of shapes (by identity);
+        `b` alone where there is no `a` yet."""
+        if a is None:
+            return b
+        key = (id(a), id(b))
+        got = self._merged.get(key)
+        if got is None:
+            m = merge(a, b)
+            # an operand where it is the bound: the fold then meets the
+            # same pair again, not a fresh equal object every state
+            m = a if m == a else b if m == b else m
+            got = self._merged[key] = (m, a, b)
+        return got[0]
+
+
+def _fcn_shape(d: Dict, elems: List[VS]) -> VS:
+    """Shape of a non-empty function from its dict and its elements'
+    shapes (in the dict's order)."""
+    keys = sorted(d, key=sort_key)
+    spec_of = dict(zip(d, elems))
+    if all(isinstance(k, int) and not isinstance(k, bool) for k in keys) \
+            and keys == list(range(1, len(keys) + 1)):
+        try:
+            elem = None
+            for k in keys:
+                s = spec_of[k]
+                elem = s if elem is None else merge(elem, s)
+            return VS("seq", cap=len(keys), elem=elem)
+        except CompileError:
+            # heterogeneous tuple (<<data, bit>> pairs in
+            # AlternatingBit): a fixed int-keyed record, not a sequence
+            pass
+    return VS("fcn", dom=tuple(keys),
+              elems=tuple(spec_of[k] for k in keys))
 
 
 def infer(v, uni: EnumUniverse) -> VS:
     """Shape of a single observed value."""
-    if isinstance(v, bool):
-        return VS("bool")
-    if isinstance(v, int):
-        return VS("int")
-    if isinstance(v, (str, ModelValue)):
-        uni.add(v)
-        return VS("enum")
-    if isinstance(v, Fcn):
-        if len(v.d) == 0:
-            return _EMPTY_MARKER
-        keys = sorted(v.d.keys(), key=sort_key)
-        if all(isinstance(k, int) and not isinstance(k, bool) for k in keys) \
-                and keys == list(range(1, len(keys) + 1)):
-            try:
-                elem = None
-                for k in keys:
-                    s = infer(v.d[k], uni)
-                    elem = s if elem is None else merge(elem, s)
-                return VS("seq", cap=len(keys), elem=elem)
-            except CompileError:
-                # heterogeneous tuple (<<data, bit>> pairs in
-                # AlternatingBit): a fixed int-keyed record, not a sequence
-                pass
-        for k in keys:
-            if isinstance(k, (str, ModelValue)):
-                uni.add(k)
-        elems = tuple(infer(v.d[k], uni) for k in keys)
-        return VS("fcn", dom=tuple(keys), elems=elems)
-    if isinstance(v, frozenset):
-        if not v:
-            return VS("emptyset")
-        members = sorted(v, key=sort_key)
-        mspecs = [infer(m, uni) for m in members]
-        if all(s.kind == "enum" for s in mspecs):
-            return VS("set", dom=tuple(members))
-        elem = mspecs[0]
-        for s in mspecs[1:]:
-            elem = merge(elem, s)
-        return VS("growset", cap=len(members), elem=elem)
-    raise CompileError(f"cannot infer a lane encoding for {fmt(v)}")
+    return Shapes(uni).infer(v)
 
 
 def _is_record(spec: VS) -> bool:
@@ -436,11 +513,18 @@ def encode(v, spec: VS, uni: EnumUniverse, out: List[int]):
     elif k == "enum":
         out.append(uni.index(v))
     elif k == "fcn":
-        if not isinstance(v, Fcn) or set(map(_hk, v.d)) != set(map(_hk,
-                                                                   spec.dom)):
+        d = v.d if isinstance(v, Fcn) else None
+        if d is not None and tuple(d) == spec.dom and \
+                tuple(map(type, d)) == tuple(map(type, spec.dom)):
+            # the function holds the domain's keys in the domain's order
+            # (the types too: 1 == True): no table of keys to build
+            for val, es in zip(d.values(), spec.elems):
+                encode(val, es, uni, out)
+            return
+        if d is None or set(map(_hk, d)) != set(map(_hk, spec.dom)):
             raise CompileError(f"expected function over {spec.dom}, "
                                f"got {fmt(v)}")
-        lookup = {_hk(kk): val for kk, val in v.d.items()}
+        lookup = {_hk(kk): val for kk, val in d.items()}
         for kk, es in zip(spec.dom, spec.elems):
             encode(lookup[_hk(kk)], es, uni, out)
     elif k == "seq":

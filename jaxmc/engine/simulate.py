@@ -11,11 +11,12 @@ from __future__ import annotations
 
 import random
 from collections import deque
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 from ..sem.eval import TLCAssertFailure, eval_expr, _bool
 from ..sem.enumerate import enumerate_init, enumerate_next, label_str
 from ..sem.modules import Model
+from ..sem.values import _has_bool, fmt
 from .explore import Violation
 
 
@@ -81,11 +82,34 @@ def random_walks(model: Model, n_walks: int, depth: int,
     return None
 
 
+def state_key(st: Dict, vars) -> tuple:
+    """The sampler's dedup key: the state's values in `vars` order, equal
+    exactly when the states are spelled alike (`fmt`, TLC's print).
+    Values hash and compare by content (`Fcn.__hash__`), so the key costs
+    a hash where a spelling costs a walk of every function (3.2 s of a
+    248,832-state Init, ISSUE 52) — but Python's `True == 1` would merge
+    a BOOLEAN-valued state with an integer-valued one at any depth of a
+    function or a set, which the spelling keeps apart (`TRUE` / `1`): a
+    value that holds a BOOLEAN anywhere carries its spelling along.  (Up
+    to ISSUE 52 the key was `repr`, which spells a SET in Python's
+    iteration order: a state with a set of records could be sampled
+    twice, and how often depended on PYTHONHASHSEED.)"""
+    return tuple([(v, fmt(v)) if _has_bool(v) else v
+                  for v in map(st.__getitem__, vars)])
+
+
 def sample_states(model: Model, bfs_states: int = 1500,
                   n_walks: int = 60, walk_depth: int = 60,
-                  seed: int = 0) -> List[Dict]:
+                  seed: int = 0,
+                  inits: Optional[List[Dict]] = None) -> List[Dict]:
     """States for layout inference: BFS prefix (covers the breadth of early
     actions) + random walks (cover depth: leaders, full logs, elections).
+
+    `inits` is Init already enumerated (the engine that asks has walked it
+    once and hands the list on; a large Init is seconds a walk); without
+    it the sampler enumerates.  Every initial state is sampled, so where
+    Init alone has `bfs_states` states the BFS prefix is empty and the
+    sample is Init plus what the walks find.
 
     Constraint-violating states are excluded: the checker discards them
     (TLC semantics), so including them would size container capacities for
@@ -101,14 +125,16 @@ def sample_states(model: Model, bfs_states: int = 1500,
     def in_bounds(st):
         return satisfies_constraints(model, st)
 
-    inits = enumerate_init(model.init, ctx, model.vars)
+    if inits is None:
+        inits = enumerate_init(model.init, ctx, model.vars)
     states = [st for st in inits if in_bounds(st)]
     # ALL inits are sampled (discarded ones are still fingerprinted, so
     # the layout must encode them); only kept inits seed the expansion
     out = list(inits)
+    vars = tuple(model.vars)
 
     def key(s):
-        return tuple(sorted((k, repr(v)) for k, v in s.items()))
+        return state_key(s, vars)
 
     seen = {key(s) for s in out}
     q = deque(states)

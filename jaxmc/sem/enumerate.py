@@ -316,6 +316,11 @@ def label_str(label) -> str:
 
 def enumerate_init(init: A.Node, base_ctx: Ctx,
                    vars: Tuple[str, ...]) -> List[Dict[str, Any]]:
+    # every walk of an Init is counted on the run's recorder: a device
+    # build that walks a large one twice pays seconds for the second
+    # (ISSUE 52; `layout.init_enumerations` reads this back)
+    from .. import obs
+    obs.current().counter("init.enumerations")
     w = Walker("init", vars)
     out = []
     for partial, _ in w.walk(init, base_ctx, {}, None):

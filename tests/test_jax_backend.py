@@ -39,7 +39,7 @@ class TestLayout:
         from jaxmc.sem.enumerate import enumerate_init
         inits = enumerate_init(pcal_model.init, pcal_model.ctx(),
                                pcal_model.vars)
-        lay = build_layout2(pcal_model, inits, Bounds())
+        lay, _ = build_layout2(pcal_model, inits, Bounds())
         for st in inits[:10]:
             row = lay.encode(st)
             back = lay.decode(row)

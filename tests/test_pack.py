@@ -53,7 +53,7 @@ def layout_and_rows(name, bfs=300, walks=20, depth=30):
     m = load(name)
     sampled = list(sample_states(m, bfs_states=bfs, n_walks=walks,
                                  walk_depth=depth))
-    lay = build_layout2(m, sampled, Bounds())
+    lay, _ = build_layout2(m, sampled, Bounds())
     rows = np.stack([lay.encode(st) for st in sampled])
     return m, lay, rows
 
